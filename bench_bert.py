@@ -12,18 +12,19 @@ import time
 
 import numpy as np
 
-from deepspeed_tpu.utils.chip_probe import (assert_platform, emit_result,
-                                            is_tpu,
-                                            require_backend, resolve_metric,
-                                            run_guarded)
+from deepspeed_tpu.utils.device import (cpu_requested, emit_result,
+                                        require_device)
 
 REF_TFLOPS = 64.0  # docs/_posts/2020-05-28-fastest-bert-training.md:37
-METRIC = resolve_metric("bert_large_mlm_tflops_per_chip",
-                        "bert_tiny_cpu_smoke_tflops")
+# the smoke name under an explicit JAX_PLATFORMS=cpu: a CPU run is never
+# filed under the device metric
+METRIC = ("bert_tiny_cpu_smoke_tflops" if cpu_requested()
+          else "bert_large_mlm_tflops_per_chip")
 
 
 def main():
-    platform = require_backend(METRIC)
+    # the TPU, or the CPU when it was asked for by name; anything else raises
+    dev = require_device("tpu")
 
     import jax
     import jax.numpy as jnp
@@ -31,14 +32,13 @@ def main():
     import deepspeed_tpu
     from deepspeed_tpu.models.bert import BertConfig, BertForTraining
 
-    assert_platform(METRIC, platform)
-    on_tpu = is_tpu(platform)
+    on_tpu = dev["platform"] == "tpu"
     if on_tpu:
         cfg = BertConfig.bert_large(dtype=jnp.bfloat16, remat=True,
                                     remat_policy="dots",
                                     max_position_embeddings=512)
         batch, seq, steps = 64, 128, 10
-    else:  # CPU smoke: tiny proxy so the script runs anywhere
+    else:  # JAX_PLATFORMS=cpu was asked for: a tiny run, under the smoke name
         cfg = BertConfig.tiny(dtype=jnp.float32)
         batch, seq, steps = 8, 32, 3
 
@@ -62,8 +62,7 @@ def main():
     batch_data = {"input_ids": ids, "labels": labels.astype(np.int32)}
 
     def _sync():
-        np.asarray(jax.device_get(
-            jax.tree_util.tree_leaves(engine.state.params)[0]))
+        jax.block_until_ready(engine.state.params)
 
     loss = engine(batch_data)
     engine.backward(loss)
@@ -99,4 +98,4 @@ def main():
 
 
 if __name__ == "__main__":
-    run_guarded(METRIC, main)
+    main()

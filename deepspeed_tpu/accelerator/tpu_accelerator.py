@@ -7,8 +7,9 @@ device inventory from ``jax.local_devices()``, memory from PJRT
 ``jax.profiler.TraceAnnotation`` (xprof), synchronization via a devicized
 fence.
 
-On backends whose PJRT client reports no memory stats (CPU, some
-tunneled clients), byte counts fall back to live-array accounting: the sum
+On a backend whose PJRT client reports no memory stats (the CPU, where
+``memory_stats()`` returns ``None``), byte counts come from live-array
+accounting: the sum
 of ``nbytes`` of this process's live ``jax.Array`` shards on the device,
 with a process-local high-water mark standing in for the allocator's peak
 counter. That undercounts XLA scratch/temp buffers but tracks the
@@ -61,14 +62,14 @@ class TpuAccelerator(Accelerator):
 
     # --- execution ----------------------------------------------------
     def synchronize(self, device_index=None) -> None:
-        """Fence the async dispatch queue: put a scalar on the device and
-        fetch it back — a real round-trip even through remote tunnels
-        (``block_until_ready`` alone can return early on proxy clients)."""
+        """Fence the async dispatch queue: a device runs its programs in
+        the order they were enqueued, so a trivial one that has finished
+        means everything dispatched before it has."""
         import jax
         import numpy as np
 
         d = self.device(device_index)
-        np.asarray(jax.device_get(jax.device_put(np.zeros((), np.int32), d)))
+        (jax.device_put(np.zeros((), np.int32), d) + 1).block_until_ready()
 
     # --- RNG ----------------------------------------------------------
     def manual_seed(self, seed: int) -> None:
@@ -82,11 +83,7 @@ class TpuAccelerator(Accelerator):
         import jax
 
         d = self.device(device_index)
-        stats = None
-        try:
-            stats = d.memory_stats()
-        except Exception:  # tunneled clients may not implement the call
-            pass
+        stats = d.memory_stats()
         if stats:
             return {
                 "bytes_in_use": int(stats.get("bytes_in_use", 0)),
@@ -95,7 +92,7 @@ class TpuAccelerator(Accelerator):
                 "largest_alloc_size": int(stats.get("largest_alloc_size", 0)),
                 "source": "pjrt",
             }
-        # Fallback: live jax.Array shards resident on this device.
+        # No allocator stats (CPU): live jax.Array shards on this device.
         in_use = 0
         for a in jax.live_arrays():
             for shard in getattr(a, "addressable_shards", []):

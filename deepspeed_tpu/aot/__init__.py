@@ -14,10 +14,6 @@ executables; this package is the persistence tier on top:
   serialize every cached executable) and restore (:class:`AOTStore`
   pre-populates dispatch: a watched function consults the store before
   paying ``lower().compile()``).
-
-Hard compat gate: ``utils/compat.aot_serialization_safe`` — jaxlib
-< 0.5 segfaults deserializing multi-device CPU executables, so those
-environments record a loud ``aot.disabled`` event and compile normally.
 """
 
 from deepspeed_tpu.aot.bundle import (AOT_BUNDLE_VERSION,

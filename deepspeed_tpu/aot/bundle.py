@@ -54,9 +54,7 @@ def serialize_compiled(compiled) -> bytes:
 
 
 def deserialize_compiled(blob: bytes):
-    """Blob bytes -> callable loaded executable. Caller must have
-    consulted ``compat.aot_serialization_safe`` first — on the known
-    crashy matrix this is a native SIGSEGV, not a Python error."""
+    """Blob bytes -> callable loaded executable."""
     from jax.experimental import serialize_executable
 
     payload, in_tree, out_tree = pickle.loads(blob)

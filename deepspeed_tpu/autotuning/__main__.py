@@ -14,14 +14,16 @@ import jax
 from deepspeed_tpu.autotuning import Autotuner, AutotuningConfig
 from deepspeed_tpu.autotuning.cost_model import (ChipSpec,
                                                  probe_devices_subprocess)
+from deepspeed_tpu.utils.device import peaks
 
 
 def _pin_parent_to_cpu():
-    # Pin the parent to CPU BEFORE any backend touch: the TPU is a
-    # single-client device, and a parent holding the libtpu client would
-    # make every trial subprocess fail with "TPU already in use". Param
-    # counting (jax.eval_shape) is host-side and doesn't need the chip;
-    # chip identity is probed in a throwaway subprocess instead. (The
+    # Pin the parent to CPU BEFORE any backend touch: a chip belongs to one
+    # process until it exits, and a parent holding it would make every
+    # trial subprocess fail. `jax_platforms` set here, before the first
+    # backend starts, is all it takes. Param counting (jax.eval_shape) is
+    # host-side and doesn't need the chip; chip identity is probed in a
+    # throwaway subprocess that exits before the first trial starts. (The
     # --live path does the opposite on purpose: its measurements run
     # in-process on whatever backend the operator launched with.)
     jax.config.update("jax_platforms", "cpu")
@@ -86,9 +88,9 @@ def main(argv=None):
     model_cfg = _PRESETS[args.model]
     seq = args.seq_len or model_cfg.get("n_positions", 1024)
     platform, kind, n_dev, hbm_bytes = probe_devices_subprocess()
-    chip = ChipSpec.from_kind(kind)
+    chip = ChipSpec.from_kind(kind)  # unknown kind: DeviceError
     hbm_gib = (args.hbm_gib if args.hbm_gib is not None
-               else (hbm_bytes / (1 << 30) if hbm_bytes else 16.0))
+               else (hbm_bytes or peaks(kind).hbm_bytes) / (1 << 30))
     atc = AutotuningConfig(
         enabled=True,
         tuner_type=args.tuner,

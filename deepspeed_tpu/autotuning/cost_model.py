@@ -15,8 +15,8 @@ from typing import Optional
 
 from deepspeed_tpu.autotuning.space import Candidate, ModelProfile
 
-# Conservative achievable fractions of nominal peak (PERF.md: a single
-# large bf16 matmul sustains ~63% of nominal on v5e; HBM streams ~80%).
+# Assumed achievable fractions of nominal peak (priors for ranking only;
+# the model-based tuner recalibrates against the trials it runs).
 _MXU_EFF = 0.6
 _HBM_EFF = 0.8
 
@@ -27,41 +27,35 @@ _REMAT_RECOMPUTE = {"none": 0.0, "dots": 0.05, "full": 1.0}
 
 @dataclasses.dataclass
 class ChipSpec:
-    peak_flops: float = 197e12   # v5e bf16
-    hbm_bandwidth: float = 819e9  # v5e HBM GB/s
+    """The roofline's two rates for one chip, read from the one peaks table
+    (``utils/device.py:PEAKS``). No default chip: an unknown
+    ``device_kind`` raises ``DeviceError``."""
+    peak_flops: float
+    hbm_bandwidth: float
 
     @staticmethod
     def from_kind(kind: str) -> "ChipSpec":
-        table = {
-            "v5 lite": ChipSpec(197e12, 819e9),
-            "v5e": ChipSpec(197e12, 819e9),
-            "v5p": ChipSpec(459e12, 2765e9),
-            "v4": ChipSpec(275e12, 1228e9),
-            "v6 lite": ChipSpec(918e12, 1640e9),
-        }
-        for k, v in table.items():
-            if k in kind.lower():
-                return v
-        return ChipSpec()
+        from deepspeed_tpu.utils.device import peaks
+
+        p = peaks(kind)
+        return ChipSpec(p.bf16_flops, p.hbm_bandwidth)
 
     @staticmethod
     def detect() -> "ChipSpec":
-        try:
-            import jax
+        import jax
 
-            kind = getattr(jax.devices()[0], "device_kind", "")
-        except Exception:
-            kind = ""
-        return ChipSpec.from_kind(kind)
+        return ChipSpec.from_kind(jax.devices()[0].device_kind)
 
 
 def probe_devices_subprocess():
     """(platform, device_kind, device_count, hbm_bytes|None) of the DEFAULT
-    jax backend, probed in a throwaway subprocess.
+    jax backend, probed in a throwaway subprocess that has exited before
+    any trial starts.
 
     The autotuner parent must never initialize the TPU runtime itself — a
-    parent holding the libtpu client would make every trial subprocess fail
-    with "TPU already in use" (single-client hardware). See __main__.py.
+    chip belongs to one process until it exits, so a parent holding it would
+    make every trial subprocess fail (see __main__.py). A probe that fails
+    raises: nothing is assumed about a device that did not answer.
     """
     import json as _json
     import subprocess
@@ -70,21 +64,12 @@ def probe_devices_subprocess():
     code = (
         "import jax, json\n"
         "d = jax.devices()[0]\n"
-        "try:\n"
-        "    hbm = (d.memory_stats() or {}).get('bytes_limit')\n"
-        "except Exception:\n"
-        "    hbm = None\n"
-        "print('\\n' + json.dumps([d.platform, "
-        "getattr(d, 'device_kind', ''), jax.device_count(), hbm]))")
-    try:
-        out = subprocess.run([sys.executable, "-c", code],
-                             capture_output=True, text=True, timeout=120)
-        for line in reversed(out.stdout.strip().splitlines()):
-            if line.startswith("["):
-                return tuple(_json.loads(line))
-    except Exception:
-        pass
-    return ("unknown", "", 1, None)
+        "hbm = (d.memory_stats() or {}).get('bytes_limit')\n"
+        "print('\\n' + json.dumps([d.platform, d.device_kind, "
+        "jax.device_count(), hbm]))")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120, check=True)
+    return tuple(_json.loads(out.stdout.strip().splitlines()[-1]))
 
 
 def predict_step_time(profile: ModelProfile, cand: Candidate,
@@ -125,8 +110,6 @@ def xla_cost_analysis(fn, *args):
 
     compiled = jax.jit(fn).lower(*args).compile()
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returned a 1-list
-        cost = cost[0]
     return {
         "flops": float(cost.get("flops", 0.0)),
         "bytes_accessed": float(cost.get("bytes accessed", 0.0)),

@@ -192,13 +192,11 @@ def _bthd_serves() -> bool:
         return False
     if _on_tpu():
         return True
-    try:  # interpret-mode testing on CPU
-        from jax._src import config as _jax_config
+    # interpret-mode testing on CPU
+    from jax._src import config as _jax_config
 
-        return (_jax_config.pallas_tpu_interpret_mode_context_manager.value
-                is not None)
-    except Exception:
-        return False
+    return (_jax_config.pallas_tpu_interpret_mode_context_manager.value
+            is not None)
 
 
 def _dense_init(scale=0.02):
@@ -530,20 +528,20 @@ class CausalSelfAttention(nn.Module):
             if (cfg.attn_layout == "bthd" and bias is None
                     and attention_mask is None and not self.window
                     and cfg.use_flash is not False and _bthd_serves()):
+                from deepspeed_tpu.ops.attention import record_dispatch
                 from deepspeed_tpu.ops.flash_attention import (
-                    flash_attention_bthd_tp)
+                    flash_attention_bthd_tp, flash_ineligible)
 
-                try:
+                # Decided from the shapes before the call. A shape the
+                # strided kernel cannot serve (no Pallas-legal head group,
+                # a multiple of 8 or all heads, fits its VMEM budget at
+                # any tile) goes to the standard dispatch below, which
+                # makes and counts its own decision.
+                if flash_ineligible(q4.shape, k.shape, layout="bthd") is None:
+                    record_dispatch("flash_bthd")
                     y_btc = flash_attention_bthd_tp(
                         q4, k, v, causal=True,
                         softmax_scale=cfg.attn_scale).reshape(B, T, C)
-                except ValueError:
-                    # kernel-ineligible shape — seq not divisible by the
-                    # block size, or no Pallas-legal head group (multiple
-                    # of 8 / all heads) fits the strided kernel's VMEM
-                    # budget: fall through to the standard dispatch,
-                    # which has its own XLA fallback
-                    y_btc = None
             if y_btc is None:
                 k = k.transpose(0, 2, 1, 3)
                 v = v.transpose(0, 2, 1, 3)

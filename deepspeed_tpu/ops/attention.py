@@ -6,18 +6,34 @@ kernel (replacing the reference's fused CUDA attention in
 behind the same signature and is selected automatically on TPU.
 """
 
-import functools
-from typing import Optional
+import collections
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
+
+
+# Which path served each attention call, counted while tracing (a compiled
+# program replays its choice without passing here again):
+#   "flash" / "flash_bthd"  the Pallas kernel, folded / strided layout
+#   "xla"                   the reference, because the call asked for it
+#                           (mask, bias, dropout, use_flash=False, no TPU)
+#   "xla_ineligible"        the reference, because the kernel was wanted
+#                           but cannot serve these shapes
+_dispatch_counts = collections.Counter()
+
+
+def record_dispatch(path: str):
+    _dispatch_counts[path] += 1
+
+
+def dispatch_counts() -> Dict[str, int]:
+    """Copy of the per-path attention dispatch counts of this process."""
+    return dict(_dispatch_counts)
 
 
 _FORCE_DECODE_KERNEL = False  # tests flip this to exercise the Pallas path
@@ -78,6 +94,7 @@ def attention(q, k, v, mask=None, causal=True, softmax_scale=None,
             _warn_fallback(q.shape, k.shape,
                            "additive logits bias (ALiBi/rpe) — the Pallas "
                            "kernels don't consume it")
+        record_dispatch("xla")
         return attention_reference(q, k, v, mask=mask, causal=causal,
                                    softmax_scale=softmax_scale,
                                    dropout_rate=dropout_rate,
@@ -113,15 +130,23 @@ def attention(q, k, v, mask=None, causal=True, softmax_scale=None,
     if use_flash is None:
         use_flash = _on_tpu() and dropout_rate == 0.0 and mask is None
     if use_flash:
-        try:
-            from deepspeed_tpu.ops.flash_attention import flash_attention
+        from deepspeed_tpu.ops.flash_attention import (
+            flash_attention_sharded, flash_ineligible)
 
-            return flash_attention(q, k, v, causal=causal, softmax_scale=softmax_scale)
-        except (ImportError, NotImplementedError, ValueError) as e:
-            # e.g. seq not divisible by the kernel block size — fall back to
-            # the XLA path, but SAY so: silently losing the kernel is a perf
-            # cliff the user should see (once per offending shape)
-            _warn_fallback(q.shape, k.shape, repr(e))
+        # decided from the shapes, before the call: an error raised by the
+        # kernel itself is a fault and propagates
+        reason = flash_ineligible(q.shape, k.shape, layout="bhtd")
+        if reason is None:
+            record_dispatch("flash")
+            return flash_attention_sharded(q, k, v, causal=causal,
+                                           softmax_scale=softmax_scale)
+        # e.g. seq not divisible by the kernel block size — take the XLA
+        # path, but SAY so: losing the kernel is a perf cliff the user
+        # should see (once per offending shape) and a counter can assert on
+        _warn_fallback(q.shape, k.shape, reason)
+        record_dispatch("xla_ineligible")
+    else:
+        record_dispatch("xla")
     return attention_reference(q, k, v, mask=mask, causal=causal,
                                softmax_scale=softmax_scale,
                                dropout_rate=dropout_rate, dropout_rng=dropout_rng)
@@ -139,5 +164,5 @@ def _warn_fallback(q_shape, k_shape, reason: str):
 
     logger.warning(
         f"flash_attention unavailable for q{tuple(q_shape)} k{tuple(k_shape)} "
-        f"({reason}); falling back to dense XLA attention — pad the sequence "
+        f"({reason}); taking the dense XLA attention path — pad the sequence "
         f"to a multiple of the kernel block (512) to regain the fused kernel")

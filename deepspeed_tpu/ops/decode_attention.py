@@ -434,8 +434,9 @@ def decode_attention_paged_int8(q, k_pool, v_pool, k_scale, v_scale,
 # ---------------------------------------------------------------------------
 # Tensor-parallel wrappers: heads partitioned over the tp mesh axis.
 #
-# A pallas_call is a custom call GSPMD cannot partition, so under a tp>1
-# mesh the kernel runs inside shard_map: each tp shard keeps its LOCAL
+# A pallas_call is a custom call GSPMD cannot partition, so on a mesh the
+# kernel runs inside the shard_map ops/kernel_mesh.py plans (the one place
+# that decides where a kernel call sits): each tp shard keeps its LOCAL
 # head group (queries, append caches and paged pools are all stored
 # head-sharded by the SpecLayout / decode_cache_specs, so no data moves
 # to get here) and runs the identical kernel on heads/tp heads. Decode
@@ -446,56 +447,27 @@ def decode_attention_paged_int8(q, k_pool, v_pool, k_scale, v_scale,
 # ---------------------------------------------------------------------------
 
 
-def _tp_mesh_axis(mesh, axis, heads: int, batch: int):
-    """(mesh, resolved tp axis name, batch-dim spec entry), or
-    (None, axis, None) when the plain kernel should serve (no live tp
-    axis / heads not divisible). The axis name resolves through the
-    legacy alias ("model"-named user meshes keep their TP), and the
-    batch entry keeps the data axis sharding the batch dim INSIDE the
-    shard_map — omitting it would all-gather the batch whenever tp
-    composes with data>1."""
-    if mesh is None:
-        from deepspeed_tpu.parallel.topology import get_topology
-
-        topo = get_topology(create_if_missing=False)
-        mesh = topo.mesh if topo is not None else None
-    if mesh is None:
-        return None, axis, None
-    from deepspeed_tpu.parallel.topology import (axis_spec_entry,
-                                                 resolve_axis_name)
-    from deepspeed_tpu.runtime.zero.partition import BATCH_AXES
-
-    axis = resolve_axis_name(mesh, axis)
-    tp = int(mesh.shape.get(axis, 1))
-    if tp <= 1 or heads % tp:
-        return None, axis, None
-    return mesh, axis, axis_spec_entry(mesh, BATCH_AXES, batch)
-
-
 def decode_attention_tp(q, k_cache, v_cache, cache_index,
                         softmax_scale=None, block_k=None, mesh=None,
                         axis=None):
     """TP-aware :func:`decode_attention`: [B, S, H, D] append caches and
     [B, T_q, H, D] queries head-sharded over ``axis``, one kernel call
-    per shard. Falls back to the plain kernel when tp is inactive."""
+    per shard. The plain kernel where :func:`~deepspeed_tpu.ops.
+    kernel_mesh.kernel_mesh_plan` asks for no ``shard_map``."""
     from jax.sharding import PartitionSpec as P
 
-    from deepspeed_tpu.parallel.topology import AXIS_TP
-    from deepspeed_tpu.utils.compat import shard_map
+    from deepspeed_tpu.ops.kernel_mesh import kernel_mesh_plan
 
-    axis = axis or AXIS_TP
-    mesh, axis, batch = _tp_mesh_axis(mesh, axis, q.shape[2], q.shape[0])
-    if mesh is None:
-        return decode_attention(q, k_cache, v_cache, cache_index,
-                                softmax_scale=softmax_scale,
+    def kernel(qs, ks, vs, idx):
+        return decode_attention(qs, ks, vs, idx, softmax_scale=softmax_scale,
                                 block_k=block_k)
-    hs = P(batch, None, axis, None)
-    fn = shard_map(
-        lambda qs, ks, vs, idx: decode_attention(
-            qs, ks, vs, idx, softmax_scale=softmax_scale, block_k=block_k),
-        mesh=mesh, in_specs=(hs, hs, hs, P()), out_specs=hs,
-        check_vma=False)
-    return fn(q, k_cache, v_cache, jnp.asarray(cache_index, jnp.int32))
+
+    plan = kernel_mesh_plan(q.shape[0], q.shape[2], mesh=mesh, axis=axis)
+    if plan is None:
+        return kernel(q, k_cache, v_cache, cache_index)
+    hs = P(plan.batch, None, plan.heads, None)
+    return plan.shard_map(kernel, (hs, hs, hs, P()), hs)(
+        q, k_cache, v_cache, jnp.asarray(cache_index, jnp.int32))
 
 
 def decode_attention_paged_tp(q, k_pool, v_pool, block_tables, lengths,
@@ -503,29 +475,27 @@ def decode_attention_paged_tp(q, k_pool, v_pool, block_tables, lengths,
     """TP-aware :func:`decode_attention_paged`: the shared block pools
     live tp-sharded on their head dim (per-shard KV pools — each tp
     shard holds heads/tp of every pool block), block tables/lengths
-    replicated. Falls back to the plain kernel when tp is inactive."""
+    follow the batch."""
     from jax.sharding import PartitionSpec as P
 
-    from deepspeed_tpu.parallel.topology import AXIS_TP
-    from deepspeed_tpu.utils.compat import shard_map
+    from deepspeed_tpu.ops.kernel_mesh import kernel_mesh_plan
 
-    axis = axis or AXIS_TP
-    mesh, axis, batch = _tp_mesh_axis(mesh, axis, q.shape[2], q.shape[0])
-    if mesh is None:
-        return decode_attention_paged(q, k_pool, v_pool, block_tables,
-                                      lengths, softmax_scale=softmax_scale)
+    def kernel(qs, ks, vs, t, ln):
+        return decode_attention_paged(qs, ks, vs, t, ln,
+                                      softmax_scale=softmax_scale)
+
+    plan = kernel_mesh_plan(q.shape[0], q.shape[2], mesh=mesh, axis=axis)
+    if plan is None:
+        return kernel(q, k_pool, v_pool, block_tables, lengths)
     # pools are the SHARED per-replica cache: head-sharded over tp,
     # replicated over data; per-row operands follow the batch entry
-    qs_spec = P(batch, None, axis, None)
-    pool_spec = P(None, None, axis, None)
-    fn = shard_map(
-        lambda qs, ks, vs, t, ln: decode_attention_paged(
-            qs, ks, vs, t, ln, softmax_scale=softmax_scale),
-        mesh=mesh,
-        in_specs=(qs_spec, pool_spec, pool_spec, P(batch), P(batch)),
-        out_specs=qs_spec, check_vma=False)
-    return fn(q, k_pool, v_pool, jnp.asarray(block_tables, jnp.int32),
-              jnp.asarray(lengths, jnp.int32))
+    qs_spec = P(plan.batch, None, plan.heads, None)
+    pool_spec = P(None, None, plan.heads, None)
+    return plan.shard_map(
+        kernel,
+        (qs_spec, pool_spec, pool_spec, P(plan.batch), P(plan.batch)),
+        qs_spec)(q, k_pool, v_pool, jnp.asarray(block_tables, jnp.int32),
+                 jnp.asarray(lengths, jnp.int32))
 
 
 def decode_attention_paged_int8_tp(q, k_pool, v_pool, k_scale, v_scale,
@@ -536,24 +506,22 @@ def decode_attention_paged_int8_tp(q, k_pool, v_pool, k_scale, v_scale,
     their f32 scale side pools head-sharded over ``axis``."""
     from jax.sharding import PartitionSpec as P
 
-    from deepspeed_tpu.parallel.topology import AXIS_TP
-    from deepspeed_tpu.utils.compat import shard_map
+    from deepspeed_tpu.ops.kernel_mesh import kernel_mesh_plan
 
-    axis = axis or AXIS_TP
-    mesh, axis, batch = _tp_mesh_axis(mesh, axis, q.shape[2], q.shape[0])
-    if mesh is None:
-        return decode_attention_paged_int8(
-            q, k_pool, v_pool, k_scale, v_scale, block_tables, lengths,
-            softmax_scale=softmax_scale)
-    qs_spec = P(batch, None, axis, None)
-    pool_spec = P(None, None, axis, None)
-    fn = shard_map(
-        lambda qs, ks, vs, kss, vss, t, ln: decode_attention_paged_int8(
-            qs, ks, vs, kss, vss, t, ln, softmax_scale=softmax_scale),
-        mesh=mesh,
-        in_specs=(qs_spec, pool_spec, pool_spec, pool_spec, pool_spec,
-                  P(batch), P(batch)),
-        out_specs=qs_spec, check_vma=False)
-    return fn(q, k_pool, v_pool, k_scale, v_scale,
-              jnp.asarray(block_tables, jnp.int32),
-              jnp.asarray(lengths, jnp.int32))
+    def kernel(qs, ks, vs, kss, vss, t, ln):
+        return decode_attention_paged_int8(qs, ks, vs, kss, vss, t, ln,
+                                           softmax_scale=softmax_scale)
+
+    plan = kernel_mesh_plan(q.shape[0], q.shape[2], mesh=mesh, axis=axis)
+    if plan is None:
+        return kernel(q, k_pool, v_pool, k_scale, v_scale, block_tables,
+                      lengths)
+    qs_spec = P(plan.batch, None, plan.heads, None)
+    pool_spec = P(None, None, plan.heads, None)
+    return plan.shard_map(
+        kernel,
+        (qs_spec, pool_spec, pool_spec, pool_spec, pool_spec,
+         P(plan.batch), P(plan.batch)),
+        qs_spec)(q, k_pool, v_pool, k_scale, v_scale,
+                 jnp.asarray(block_tables, jnp.int32),
+                 jnp.asarray(lengths, jnp.int32))

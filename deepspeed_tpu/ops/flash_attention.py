@@ -645,6 +645,34 @@ def _resolved_tiles(block_q, block_k):
                                      DEFAULT_BLOCK_K))
 
 
+def flash_ineligible(q_shape, k_shape, layout, block_q=None, block_k=None):
+    """Why the kernel cannot serve these shapes, or ``None`` when it can.
+
+    The dispatchers ask this BEFORE calling the kernel, so the XLA path is
+    taken by a decision made from the shapes (and counted,
+    ``ops.attention.dispatch_counts``) and never by catching what the
+    kernel raises. ``layout``: ``"bhtd"`` (folded, [B, H, T, D]) or
+    ``"bthd"`` (strided, [B, T, H, D], judged on the head group one shard
+    of :func:`flash_attention_bthd_tp` hands the kernel). The tile rules
+    themselves live in ``_block_sizes`` / ``_bthd_tiles``, which the
+    kernels call again; what they raise is the reason."""
+    block_q, block_k = _resolved_tiles(block_q, block_k)
+    try:
+        if layout == "bthd":
+            from deepspeed_tpu.ops.kernel_mesh import kernel_mesh_plan
+
+            _, seq_q, heads, head_dim = q_shape
+            plan = kernel_mesh_plan(q_shape[0], heads, seqlen=seq_q)
+            if plan is not None:
+                heads //= plan.size(plan.heads) * plan.size(plan.seq)
+            _bthd_tiles(seq_q, k_shape[1], heads, head_dim, block_q, block_k)
+        else:
+            _block_sizes(q_shape[-2], k_shape[-2], block_q, block_k)
+    except ValueError as e:
+        return str(e)
+    return None
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention_bthd(q, k, v, causal=True, softmax_scale=None,
                          block_q=None, block_k=None):
@@ -731,6 +759,27 @@ def _fa_bwd(causal, softmax_scale, block_q, block_k, res, g):
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
+def flash_attention_sharded(q, k, v, causal=True, softmax_scale=None):
+    """:func:`flash_attention` ([B, H, T, D]) as the dispatcher calls it:
+    the plain kernel, or the kernel inside the ``shard_map`` that
+    :func:`~deepspeed_tpu.ops.kernel_mesh.kernel_mesh_plan` asks for, with
+    the batch over the data axes and the heads over tp where they divide.
+    Attention never reduces across batch or heads, so no collective."""
+    from jax.sharding import PartitionSpec as P
+
+    from deepspeed_tpu.ops.kernel_mesh import kernel_mesh_plan
+
+    def kernel(qs, ks, vs):
+        return flash_attention(qs, ks, vs, causal=causal,
+                               softmax_scale=softmax_scale)
+
+    plan = kernel_mesh_plan(q.shape[0], q.shape[1])
+    if plan is None:
+        return kernel(q, k, v)
+    spec = P(plan.batch, plan.heads, None, None)
+    return plan.shard_map(kernel, (spec, spec, spec), spec)(q, k, v)
+
+
 def flash_attention_bthd_tp(q, k, v, causal=True, softmax_scale=None,
                             block_q=None, block_k=None, mesh=None,
                             axis=None, seq_axis=None):
@@ -751,62 +800,34 @@ def flash_attention_bthd_tp(q, k, v, causal=True, softmax_scale=None,
     mirror all_to_all in the backward pass. sp participates only when
     the post-tp head group divides by sp and the sequence divides by sp;
     with sp inactive the emitted program is the exact tp-only one (and
-    with tp also inactive, the plain kernel) — zero-overhead fallbacks
-    pinned by the parity tests."""
+    on a one-device mesh, the plain kernel) — zero-overhead fallbacks
+    pinned by the parity tests. Which ``shard_map`` (none, the whole
+    mesh, or the axes an enclosing one left Auto) is
+    :func:`~deepspeed_tpu.ops.kernel_mesh.kernel_mesh_plan`'s decision."""
     from jax.sharding import PartitionSpec as P
 
-    from deepspeed_tpu.parallel.topology import (AXIS_SEQ, AXIS_TP,
-                                                 axis_spec_entry,
-                                                 get_topology,
-                                                 resolve_axis_name)
-    from deepspeed_tpu.runtime.zero.partition import BATCH_AXES
-    from deepspeed_tpu.utils.compat import shard_map
+    from deepspeed_tpu.ops.kernel_mesh import kernel_mesh_plan
 
-    axis = axis or AXIS_TP
-    seq_axis = seq_axis or AXIS_SEQ
-    if mesh is None:
-        topo = get_topology(create_if_missing=False)
-        mesh = topo.mesh if topo is not None else None
-    if mesh is not None:
-        axis = resolve_axis_name(mesh, axis)
-        seq_axis = resolve_axis_name(mesh, seq_axis)
-    tp = int(mesh.shape.get(axis, 1)) if mesh is not None else 1
-    sp = int(mesh.shape.get(seq_axis, 1)) if mesh is not None else 1
-    heads, seqlen = q.shape[2], q.shape[1]
-    if tp > 1 and heads % tp:
-        tp = 1
-    local_heads = heads // tp
-    # sp joins only when both the post-tp head group and the tokens
-    # divide; otherwise it degrades to the tp-only (or plain) program
-    if sp > 1 and (local_heads % sp or seqlen % sp):
-        sp = 1
-    if tp <= 1 and sp <= 1:
-        return flash_attention_bthd(q, k, v, causal=causal,
-                                    softmax_scale=softmax_scale,
-                                    block_q=block_q, block_k=block_k)
+    plan = kernel_mesh_plan(q.shape[0], q.shape[2], seqlen=q.shape[1],
+                            mesh=mesh, axis=axis, seq_axis=seq_axis)
+    sp_axis = plan.seq if plan is not None else None
 
     def local_attn(qs, ks, vs):
-        if sp > 1:
+        if sp_axis:
             # Ulysses leg 1: trade local heads for the full sequence
             qs, ks, vs = (jax.lax.all_to_all(
-                t, seq_axis, split_axis=2, concat_axis=1, tiled=True)
+                t, sp_axis, split_axis=2, concat_axis=1, tiled=True)
                 for t in (qs, ks, vs))
         o = flash_attention_bthd(qs, ks, vs, causal=causal,
                                  softmax_scale=softmax_scale,
                                  block_q=block_q, block_k=block_k)
-        if sp > 1:
+        if sp_axis:
             # Ulysses leg 2: give the sequence back, regain the heads
-            o = jax.lax.all_to_all(o, seq_axis, split_axis=1,
+            o = jax.lax.all_to_all(o, sp_axis, split_axis=1,
                                    concat_axis=2, tiled=True)
         return o
 
-    # batch stays data-sharded INSIDE the shard_map (omitting the entry
-    # would all-gather the batch whenever tp/sp compose with data>1)
-    batch = axis_spec_entry(mesh, BATCH_AXES, q.shape[0])
-    hs = P(batch,
-           seq_axis if sp > 1 else None,
-           axis if tp > 1 else None,
-           None)
-    fn = shard_map(local_attn, mesh=mesh, in_specs=(hs, hs, hs),
-                   out_specs=hs, check_vma=False)
-    return fn(q, k, v)
+    if plan is None:
+        return local_attn(q, k, v)
+    hs = P(plan.batch, plan.seq, plan.heads, None)
+    return plan.shard_map(local_attn, (hs, hs, hs), hs)(q, k, v)

@@ -456,11 +456,6 @@ class AOTConfig(DeepSpeedConfigModel):
       live runtime raises instead of warning + compiling normally.
       (Not named ``strict``: the base config model's constructor
       consumes that kwarg for auto-value handling.)
-
-    Environments where executable deserialization is known-crashy
-    (jaxlib < 0.5 multi-device CPU — ``utils/compat.
-    aot_serialization_safe``) skip capture/restore with a loud
-    ``aot``/``disabled`` telemetry event and compile normally.
     """
 
     enabled: bool = False

@@ -2216,20 +2216,12 @@ class DeepSpeedEngine:
             tuned_hash=self._config.tuned_artifact_hash)
 
     def _aot_supported(self, what: str) -> bool:
-        """The hard compat gate, loudly: jaxlib < 0.5 segfaults (native
-        crash) deserializing CPU executables, and multi-process
-        executables span devices no single process can rebind. Emits
-        the ``aot``/``disabled`` event so the stream records WHY a
-        restart ran cold."""
-        from deepspeed_tpu.utils.compat import aot_serialization_safe
-
-        if jax.process_count() > 1:
-            reason = "multi-process executables are not AOT-shippable"
-        elif not aot_serialization_safe():
-            reason = ("jaxlib < 0.5 CPU executable (de)serialization is "
-                      "known to segfault (compat.aot_serialization_safe)")
-        else:
+        """Multi-process executables span devices no single process can
+        rebind, so they are not shipped. Emits the ``aot``/``disabled``
+        event so the stream records WHY a restart ran cold."""
+        if jax.process_count() == 1:
             return True
+        reason = "multi-process executables are not AOT-shippable"
         logger.warning(f"[aot] {what} skipped: {reason}; falling back to "
                        "normal compilation")
         self.telemetry.emit("aot", "disabled", step=self.global_steps,

@@ -37,8 +37,7 @@ from deepspeed_tpu.runtime.pipe.schedule import (InterleavedSchedule,
                                                  TrainSchedule,
                                                  ZeroBubbleSchedule)
 from deepspeed_tpu.runtime.zero.partition import replicated
-from deepspeed_tpu.utils.compat import (partial_auto_shard_map_safe,
-                                        shard_map)
+from deepspeed_tpu.utils.compat import shard_map
 from deepspeed_tpu.utils.logging import log_dist
 
 
@@ -326,18 +325,6 @@ class PipelineEngine(DeepSpeedEngine):
                 "ZeRO-3 is incompatible with pipeline parallelism "
                 "(reference parity: engine.py asserts the same); use stage<=2")
         n_stages = self.topology.get_pipe_parallel_world_size()
-        auto_extent = [f"{ax}={n}" for ax, n in self.mesh.shape.items()
-                       if ax != AXIS_PIPE and n > 1]
-        if auto_extent and not partial_auto_shard_map_safe():
-            # jax < 0.5 cannot compile the pipe-manual shard_map next to
-            # live auto axes — the backward pass SIGABRTs inside XLA
-            # (IsManualSubgroup CHECK) instead of raising. Refuse with a
-            # Python error before any compile is attempted.
-            raise RuntimeError(
-                "pipeline parallelism composed with other mesh axes "
-                f"({', '.join(auto_extent)}) requires jax >= 0.5; this "
-                "runtime hard-crashes compiling the partially-manual "
-                "program. Use a pipe-only mesh or upgrade jax.")
         pipe_cfg = self._config.pipeline_config
         self.pipe_schedule = pipe_cfg.schedule
         self.virtual_stages = (pipe_cfg.virtual_stages

@@ -155,6 +155,10 @@ class WatchedFunction:
         self._fallback = False
         self.compiles = 0
 
+    def programs(self):
+        """The compiled executables held so far (one per signature)."""
+        return list(self._cache.values())
+
     def __getattr__(self, item):
         if item == "_fn":  # not yet in __dict__ (copy/pickle protocols)
             raise AttributeError(item)

@@ -1,147 +1,90 @@
-"""JAX version compatibility shims.
+"""The one door to the JAX APIs whose spelling this repo does not want
+scattered over its call sites (``tools/lint`` rule GL02 keeps them here):
+``shard_map``, the Pallas TPU compiler parameters and interpret mode, and
+the arming of the persistent compilation cache.
 
-The repo targets the current jax API surface (``jax.shard_map`` with
-``check_vma=``); older runtimes (< 0.5) ship ``shard_map`` under
-``jax.experimental.shard_map`` with the ``check_rep=`` spelling of the same
-knob. Every shard_map call site in the tree routes through this module so
-the fallback logic lives in exactly one place.
+Written for the one installation there is (jax 0.9): no branch here asks
+which JAX is running.
 """
 
 import contextlib
-import inspect
+import os
 
-
-def _resolve_shard_map():
-    try:
-        from jax import shard_map as sm  # jax >= 0.5
-        return sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-        return sm
-
-
-def persistent_compilation_cache_safe() -> bool:
-    """Whether arming JAX's persistent compilation cache is safe here.
-
-    jaxlib < 0.5 segfaults (SIGSEGV/SIGABRT, not a Python error)
-    deserializing its own cached **multi-device CPU** executables: a cold
-    run passes and writes entries, every warm run dies re-loading them —
-    which turned the whole virtual-8-device test suite into a one-shot.
-    On those versions the cache must stay off for CPU; TPU executables
-    round-trip fine everywhere we have run them."""
-    import jax
-
-    try:
-        version = tuple(int(p) for p in jax.__version__.split(".")[:2])
-    except ValueError:
-        return True
-    if version >= (0, 5):
-        return True
-    return jax.default_backend() != "cpu"
-
-
-def aot_serialization_safe() -> bool:
-    """Whether AOT executable serialize/deserialize
-    (``jax.experimental.serialize_executable``) is safe here.
-
-    Reuses the :func:`persistent_compilation_cache_safe` matrix — the
-    failure is the same native one: jaxlib < 0.5 SIGSEGVs (a hard
-    crash, not a Python error) deserializing CPU executables in a fresh
-    process. Probed empirically on 0.4.37: a trivial jit round-trips,
-    but a real engine train-step program (donation + sharded state)
-    segfaults at deserialize even compiled over a single-device mesh —
-    so the CPU leg is gated wholesale, not just multi-device. TPU
-    executables round-trip fine everywhere we have run them. The AOT
-    layer must consult this BEFORE any serialize/deserialize and fall
-    back loudly (``aot``/``disabled`` telemetry event + normal
-    compilation), never crash."""
-    return persistent_compilation_cache_safe()
-
-
-def partial_auto_shard_map_safe() -> bool:
-    """Whether a *partially manual* ``shard_map`` (manual over ``pipe``,
-    auto/GSPMD over data/model axes of size > 1) lowers and compiles here.
-
-    jax < 0.5 cannot build that program: the forward lowers
-    ``axis_index`` to a bare ``partition-id`` HLO that the SPMD
-    partitioner rejects (``UNIMPLEMENTED: PartitionId instruction is not
-    supported``), and the backward dies harder — a CHECK failure
-    (``sharding.IsManualSubgroup()`` in hlo_sharding_util.cc) that
-    SIGABRTs the whole process rather than raising. Probed empirically on
-    0.4.37: pipe-only meshes (every non-pipe axis size 1) are fine on the
-    same runtime; any auto axis of size > 1 next to the manual pipe axis
-    is fatal. Callers composing the pipelined shard_map with live
-    data/model axes must consult this and refuse loudly BEFORE compile —
-    a Python error beats an uncatchable native abort."""
-    import jax
-
-    try:
-        version = tuple(int(p) for p in jax.__version__.split(".")[:2])
-    except ValueError:
-        return True
-    return version >= (0, 5)
+import jax
 
 
 def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` under its current name; older runtimes
-    (< 0.5) ship the same dataclass as ``TPUCompilerParams``. Every
-    Pallas kernel in the tree routes its ``compiler_params=`` through
-    here so the rename lives in exactly one place."""
+    """``pltpu.CompilerParams`` — every Pallas kernel in the tree routes
+    its ``compiler_params=`` through here."""
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
 def tpu_interpret_mode():
-    """``pltpu.force_tpu_interpret_mode()`` where it exists (jax >= 0.5);
-    on older runtimes, an equivalent context that rewrites every
-    ``pl.pallas_call`` in its scope to ``interpret=True`` — the same
-    CPU-emulation the real context flips via jax config."""
+    """Context that runs every ``pl.pallas_call`` traced inside it in the
+    TPU interpreter on the CPU.
+
+    The interpreter's callbacks run JAX ops of their own on the default
+    device: block on the interpreted program's outputs before dispatching
+    other work, or the two can wait on each other forever."""
     from jax.experimental.pallas import tpu as pltpu
 
-    if hasattr(pltpu, "force_tpu_interpret_mode"):
-        return pltpu.force_tpu_interpret_mode()
-    return _patched_interpret_mode()
+    return pltpu.force_tpu_interpret_mode()
+
+
+def shard_map(f, mesh, in_specs, out_specs, check_vma=None, axis_names=None):
+    """``jax.shard_map``; ``check_vma`` / ``axis_names`` left at ``None``
+    keep JAX's own defaults."""
+    kwargs = {}
+    if check_vma is not None:
+        kwargs["check_vma"] = check_vma
+    if axis_names is not None:
+        kwargs["axis_names"] = axis_names
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
+
+
+# <checkout>/.jax_compile_cache, listed in .gitignore. The path is part of
+# the cache's key, so it is fixed: never $HOME, a temp name, a pid or a time.
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_compile_cache")
+
+
+def arm_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return the directory it uses.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it, and no
+    other directory is set in code; where it is not, the cache lives in one
+    fixed directory inside the checkout. Used by ``chip_smoke.py``, the
+    bench scripts and ``tests/conftest.py``."""
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = _CHECKOUT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # every program counts, however small or quick to compile
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return cache_dir
 
 
 @contextlib.contextmanager
-def _patched_interpret_mode():
-    import jax.experimental.pallas as pl
+def compilation_cache_off():
+    """The persistent compilation cache out of the way for a block: for a
+    measurement whose cold compile must BE one, and for compiles whose
+    entries could not be read back (a described chip that is not
+    attached). Also keeps a program that the cache handed back out of an
+    AOT bundle: the installed jaxlib's CPU backend serializes such an
+    executable without its fused kernels."""
+    from jax.experimental.compilation_cache import compilation_cache
 
-    orig = pl.pallas_call
-
-    def interpreted(*args, **kwargs):
-        kwargs.setdefault("interpret", True)
-        return orig(*args, **kwargs)
-
-    pl.pallas_call = interpreted
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
     try:
         yield
     finally:
-        pl.pallas_call = orig
-
-
-_SM_PARAMS = None  # resolved lazily from the resolved shard_map's signature
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma=None, axis_names=None,
-              **kwargs):
-    """``jax.shard_map`` with new-API kwargs translated for older jax:
-    ``check_vma`` -> ``check_rep``, and ``axis_names`` (the *manual* axes)
-    -> its complement ``auto`` (the axes left to the partitioner)."""
-    global _SM_PARAMS
-    sm = _resolve_shard_map()
-    if _SM_PARAMS is None:
-        _SM_PARAMS = frozenset(inspect.signature(sm).parameters)
-    if check_vma is not None:
-        kwargs["check_vma" if "check_vma" in _SM_PARAMS
-               else "check_rep"] = check_vma
-    if axis_names is not None:
-        if "axis_names" in _SM_PARAMS:
-            kwargs["axis_names"] = axis_names
-        else:
-            kwargs["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()

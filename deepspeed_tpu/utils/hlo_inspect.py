@@ -16,11 +16,17 @@ from typing import Dict, List, Optional, Sequence, Tuple
 COLLECTIVE_OPS = ("all-reduce", "all-gather", "all-to-all",
                   "reduce-scatter", "collective-permute")
 
-# `u8[8,513]{1,0}` — dtype + dims (scalar shapes print as `f32[]`)
-_SHAPE_RE = re.compile(r"\b(pred|[suf]\d+|bf16)\[([\d,]*)\]")
+# `u8[8,513]{1,0}` — dtype + dims (scalar shapes print as `f32[]`); the
+# TPU compiler appends a tiling to the layout, `{1,0:T(8,128)(2,1)S(1)}`
+_SHAPE_RE = re.compile(r"\b(pred|[sufc]\d+|bf16|f8\w+)\[([\d,]*)\]")
+# `  ROOT %all-reduce.3 = <type> all-reduce(%fusion.1), channel_id=...`:
+# the installed XLA prints operands by NAME only, so an operand's shape is
+# the result type of the instruction that defines it.
+# (pre-optimization text, ``lowered.as_text(dialect="hlo")``, drops the `%`)
+_DEF_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*")
+_NAME_RE = re.compile(r"%?([\w.\-]+)\s*(?:,|$)")
 _OP_RE = re.compile(
-    r"=\s*(?:\([^)]*\)|\S+)\s+(" + "|".join(COLLECTIVE_OPS) +
-    r")(?:-start)?\(")
+    r"\s*(" + "|".join(COLLECTIVE_OPS) + r")(?:-start)?\(")
 
 # the two spellings XLA prints for replica_groups:
 #   literal    `replica_groups={{0,1},{2,3}}`
@@ -108,6 +114,28 @@ def _shape_bytes(dtype: str, dims: str) -> int:
     return (n * _dtype_bits(dtype) + 7) // 8
 
 
+def _balanced(text: str, open_at: int) -> int:
+    """Index of the ``)`` closing the ``(`` at ``text[open_at]``."""
+    depth = 0
+    for i in range(open_at, len(text)):
+        if text[i] == "(":
+            depth += 1
+        elif text[i] == ")":
+            depth -= 1
+            if depth == 0:
+                return i
+    return len(text) - 1
+
+
+def _result_type(rest: str) -> str:
+    """The type that opens an instruction's right-hand side: one array
+    type (no blanks, though its tiling holds parentheses) or a
+    parenthesised tuple of them."""
+    if rest.startswith("("):
+        return rest[:_balanced(rest, 0) + 1]
+    return rest.split(None, 1)[0] if rest else ""
+
+
 def parse_collectives(hlo_text: str) -> List[Dict]:
     """Collective ops of a compiled-HLO module as
     ``{op, operands: [(dtype, bytes)], operand_bytes}`` dicts.
@@ -115,24 +143,27 @@ def parse_collectives(hlo_text: str) -> List[Dict]:
     ``operand_bytes`` is the per-member contribution each device feeds the
     collective — the honest wire-size proxy (an all-gather *result* is
     world× larger but each member only sends its operand).
+
+    Instruction names repeat across computations (``%param_0`` of every
+    fusion) but a definition precedes its uses inside one computation, so
+    one pass that keeps the latest definition resolves every operand.
     """
     out = []
+    defined: Dict[str, List[Tuple[str, str]]] = {}
     for line in hlo_text.splitlines():
-        m = _OP_RE.search(line)
+        definition = _DEF_RE.match(line)
+        if not definition:
+            continue
+        rest = line[definition.end():]
+        rtype = _result_type(rest)
+        defined[definition.group(1)] = _SHAPE_RE.findall(rtype)
+        m = _OP_RE.match(rest, len(rtype))
         if not m:
             continue
-        args = line[m.end():]
-        depth = 1
-        for i, c in enumerate(args):
-            if c == "(":
-                depth += 1
-            elif c == ")":
-                depth -= 1
-                if depth == 0:
-                    args = args[:i]
-                    break
-        operands = [(d, _shape_bytes(d, dims))
-                    for d, dims in _SHAPE_RE.findall(args)]
+        args = rest[m.end():_balanced(rest, m.end() - 1)]
+        shapes = [s for name in _NAME_RE.findall(args)
+                  for s in defined.get(name, ())]
+        operands = [(d, _shape_bytes(d, dims)) for d, dims in shapes]
         groups = parse_replica_groups(line)
         out.append({
             "op": m.group(1),

@@ -1,4 +1,4 @@
-"""BAD: every jax-0.4.x-breaking API used directly, one per line."""
+"""BAD: every routed JAX API used directly, one per line."""
 
 import jax
 from jax.experimental.shard_map import shard_map
@@ -11,7 +11,7 @@ def sharded(fn, mesh, specs):
 
 
 def compile_params():
-    return pltpu.TPUCompilerParams(dimension_semantics=("parallel",))
+    return pltpu.CompilerParams(dimension_semantics=("parallel",))
 
 
 def interpret():

@@ -1,7 +1,7 @@
 """GOOD: the same capabilities, all routed through the compat shim."""
 
 from deepspeed_tpu.utils.compat import (
-    persistent_compilation_cache_safe,
+    arm_compilation_cache,
     shard_map,
     tpu_compiler_params,
     tpu_interpret_mode,
@@ -20,7 +20,5 @@ def interpret():
     return tpu_interpret_mode()
 
 
-def arm_cache(path):
-    if not persistent_compilation_cache_safe():
-        return False
-    return True
+def arm_cache():
+    return arm_compilation_cache()

@@ -1,13 +1,13 @@
 """The shim itself is the ONE exempt module: raw API access lives here."""
 
-from jax.experimental.shard_map import shard_map  # noqa: F401
+import jax
+from jax import shard_map  # noqa: F401
 
 
 def tpu_compiler_params(**kwargs):
     from jax.experimental.pallas import tpu as pltpu
 
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
 def tpu_interpret_mode():
@@ -16,5 +16,5 @@ def tpu_interpret_mode():
     return pltpu.force_tpu_interpret_mode()
 
 
-def persistent_compilation_cache_safe():
-    return False
+def arm_compilation_cache():
+    jax.config.update("jax_compilation_cache_dir", ".jax_compile_cache")

@@ -57,8 +57,14 @@ def test_seed_roundtrip():
 
 
 def test_memory_stats_tracks_live_arrays():
+    import gc
+
     acc = get_accelerator()
     d = acc.device(0)
+    # live-array accounting counts garbage that is not collected yet: were
+    # an earlier test's arrays freed between the two readings, the growth
+    # below would be understated
+    gc.collect()
     acc.reset_peak_memory_stats(0)
     base = acc.memory_allocated(0)
     big = jax.device_put(np.ones((512, 512), np.float32), d)

@@ -1,19 +1,11 @@
 """AOT program cache (``deepspeed_tpu/aot``): bundle format, dispatch
-pre-population, checkpoint shipping, and the hard compat gate.
+pre-population and checkpoint shipping.
 
-Native executable (de)serialization is known-crashy on this jaxlib
-(``compat.aot_serialization_safe`` — a SIGSEGV, not a Python error), so
-the suite splits the proof:
-
-- the bundle FORMAT and tooling are tested with real serialized bytes
-  (the serialize side is safe; nothing here deserializes natively);
+- the bundle FORMAT and tooling are tested with real serialized bytes;
 - the DISPATCH path (store hit -> zero compiles) is tested with a fake
   store holding the real compiled object, and end-to-end through the
   engine with the serialize/deserialize pair monkeypatched to a
-  registry — everything except jax's own serializer runs for real;
-- the compat-gated environment pins the loud fallback: capture/restore
-  skipped with an ``aot``/``disabled`` event, normal compilation, and a
-  checkpoint that still restores bit-exactly.
+  registry — everything except jax's own serializer runs for real.
 """
 
 import json
@@ -36,7 +28,6 @@ from deepspeed_tpu.runtime.checkpoint_engine.checkpoint_engine import (
 from deepspeed_tpu.telemetry import Telemetry
 from deepspeed_tpu.telemetry import compile_watch
 from deepspeed_tpu.telemetry.jit_watch import signature_fingerprint
-from deepspeed_tpu.utils.compat import aot_serialization_safe
 from deepspeed_tpu.utils.fingerprint import (diff_fingerprint,
                                              fingerprint_hash,
                                              topology_fingerprint)
@@ -276,34 +267,6 @@ class TestEngineAOT:
         disabled.destroy()
         assert text_absent == text_disabled
 
-    # deliberately NOT heavy: this is the satellite regression for the
-    # known-crashy container — tier-1 must prove the gate holds (on
-    # gate-safe runtimes the skipif retires it instead)
-    @pytest.mark.skipif(aot_serialization_safe(), reason="this leg pins "
-                        "the compat-gated environment only")
-    def test_compat_gate_falls_back_loudly(self, tmp_path):
-        """Satellite regression: on jaxlib < 0.5 CPU the save skips
-        capture with a loud ``aot``/``disabled`` event, ships no bundle,
-        and the checkpoint still restores bit-exactly through normal
-        compilation — the suite-killing segfault can never happen."""
-        engine, ids = _tiny_engine()
-        _step(engine, ids)
-        engine.save_checkpoint(str(tmp_path), tag="t1")
-        events = [e for e in engine.telemetry.tail(50)
-                  if e["kind"] == "aot"]
-        assert [e["name"] for e in events] == ["disabled"]
-        assert "segfault" in events[0]["data"]["reason"]
-        assert not [f for f in os.listdir(os.path.join(str(tmp_path), "t1"))
-                    if f.startswith("aot_")]
-        p_saved = _first_param(engine)
-        engine.destroy()
-
-        fresh, ids = _tiny_engine()
-        fresh.load_checkpoint(str(tmp_path), tag="t1")
-        assert (_first_param(fresh) == p_saved).all()
-        _step(fresh, ids)  # compiles normally, no crash
-        fresh.destroy()
-
     @pytest.mark.heavy
     def test_signature_stable_across_restart(self, tmp_path):
         """The invariant the AOT store keys on: a fresh engine that
@@ -337,8 +300,6 @@ class TestEngineAOT:
         the real path): resume + first step records ZERO backend
         compiles for the steady-state programs."""
         from deepspeed_tpu.aot import capture as cap
-        from deepspeed_tpu.utils import compat
-
         registry = {}
 
         def fake_serialize(compiled):
@@ -349,7 +310,6 @@ class TestEngineAOT:
         monkeypatch.setattr(cap, "serialize_compiled", fake_serialize)
         monkeypatch.setattr(cap, "deserialize_compiled",
                             lambda blob: registry[blob])
-        monkeypatch.setattr(compat, "aot_serialization_safe", lambda: True)
 
         saver, ids = _tiny_engine()
         _step(saver, ids)
@@ -379,8 +339,6 @@ class TestEngineAOT:
     def test_identity_mismatch_disables_store(self, tmp_path,
                                               monkeypatch):
         from deepspeed_tpu.aot import capture as cap
-        from deepspeed_tpu.utils import compat
-
         registry = {}
         monkeypatch.setattr(
             cap, "serialize_compiled",
@@ -388,7 +346,6 @@ class TestEngineAOT:
             and f"p{len(registry)-1}".encode())
         monkeypatch.setattr(cap, "deserialize_compiled",
                             lambda blob: registry[blob])
-        monkeypatch.setattr(compat, "aot_serialization_safe", lambda: True)
 
         saver, ids = _tiny_engine()
         _step(saver, ids)
@@ -477,6 +434,6 @@ class TestAotPackTool:
         r = subprocess.run(
             [sys.executable, os.path.join(REPO, "tools", "aot_pack.py"),
              tag, "--verify"],
-            capture_output=True, text=True, cwd=REPO)
+            capture_output=True, text=True, cwd=REPO, timeout=300)
         assert r.returncode == 0, r.stderr
         assert "every blob matches" in r.stdout

@@ -16,6 +16,11 @@ TINY = {"preset": "gpt2",
                    "vocab_size": 256, "n_positions": 64, "dtype": "float32"}}
 
 
+# the tuner plans for a chip it can name; the CPU these tests run on has
+# no peaks (utils/device.py), so they name the v5e
+V5E = ChipSpec.from_kind("TPU v5 lite")
+
+
 def _profile():
     return ModelProfile(n_params=125_000_000, n_layer=12, n_embd=768,
                         vocab_size=50257, seq_len=1024)
@@ -68,13 +73,13 @@ class TestMemoryModel:
 class TestCostModel:
     def test_bigger_batch_amortizes_overhead(self):
         p = _profile()
-        chip = ChipSpec()
+        chip = V5E
         assert (predict_throughput(p, Candidate(16, 0, "dots"), chip)
                 >= predict_throughput(p, Candidate(1, 0, "dots"), chip))
 
     def test_full_remat_costs_flops(self):
         p = _profile()
-        chip = ChipSpec()
+        chip = V5E
         assert (predict_throughput(p, Candidate(16, 0, "dots"), chip)
                 > predict_throughput(p, Candidate(16, 0, "full"), chip))
 
@@ -82,7 +87,7 @@ class TestCostModel:
         p = _profile()
         space = [Candidate(1, 0, "full"), Candidate(16, 0, "dots"),
                  Candidate(4, 0, "full")]
-        tuner = get_tuner("model_based", space, p, ChipSpec())
+        tuner = get_tuner("model_based", space, p, V5E)
         ordered = tuner.order()
         preds = [predict_throughput(p, c, tuner.chip) for c in ordered]
         assert preds == sorted(preds, reverse=True)
@@ -91,7 +96,7 @@ class TestCostModel:
         p = _profile()
         space = [Candidate(m, 0, "dots") for m in (1, 2, 4)]
         for kind in ("gridsearch", "random"):
-            assert set(get_tuner(kind, space, p).order()) == set(space)
+            assert set(get_tuner(kind, space, p, V5E).order()) == set(space)
 
 
 class TestProfileModel:
@@ -115,7 +120,7 @@ class TestAutotunerEndToEnd:
         base = {"optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
                 "steps_per_print": 10_000}
         best = Autotuner(model_spec=TINY, base_ds_config=base, config=atc,
-                         seq_len=32).tune()
+                         seq_len=32, chip=V5E).tune()
         assert best is not None and best["tokens_per_sec"] > 0
         assert best["candidate"]["micro_batch"] in (2, 4)
         assert os.path.exists(tmp_path / "best_config.json")
@@ -132,7 +137,7 @@ class TestAutotunerEndToEnd:
         base = {"optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
                 "steps_per_print": 10_000}
         best = Autotuner(model_spec=TINY, base_ds_config=base, config=atc,
-                         seq_len=32).tune()
+                         seq_len=32, chip=V5E).tune()
         summary = json.loads((tmp_path / "summary.json").read_text())
         assert len(summary["trials"]) == 2
         assert sum(t["ok"] for t in summary["trials"]) == 1

@@ -123,7 +123,7 @@ class TestDeepSpeedCheckpoint:
         out = str(tmp_path / "consolidated.npz")
         r = subprocess.run([sys.executable, "bin/zero_to_fp32",
                             str(tmp_path), out], capture_output=True,
-                           text=True, cwd="/root/repo")
+                           text=True, cwd="/root/repo", timeout=300)
         assert r.returncode == 0, r.stderr
         loaded = np.load(out)
         np.testing.assert_allclose(loaded["wte"], sd["wte"])

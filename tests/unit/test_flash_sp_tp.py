@@ -5,9 +5,11 @@
 (heads traded for the full sequence and back), tp stays collective-free.
 Proofs: parity vs the dense attention oracle in interpret mode (forward
 and grads, through BOTH mesh axes), zero-overhead fallbacks (sp=1
-emits the exact tp-only program; tp=1/sp=1 the plain kernel — pinned
-byte-identical on lowered HLO), and the divisibility degrade (a head
-group sp cannot split falls back to tp-only with no all-to-all).
+emits the exact tp-only program; a one-device mesh the plain kernel —
+pinned byte-identical on lowered HLO; any larger mesh wraps the kernel in
+a shard_map, which the chip's compiler requires), and the divisibility
+degrade (a head group sp cannot split falls back to tp-only with no
+all-to-all).
 """
 
 import jax
@@ -73,6 +75,12 @@ class TestSpTpParity:
 
         with tpu_interpret_mode():
             gf = jax.jit(jax.grad(loss_sp, argnums=(0, 1, 2)))(q, k, v)
+            # The interpreter's io_callbacks run JAX ops of their own on
+            # the default device. Work dispatched there while the
+            # interpreted program is still in flight (the oracle's eager
+            # grad below) queues ahead of them and the two wait on each
+            # other forever, so finish the interpreted program first.
+            jax.block_until_ready(gf)
         gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
         for a, b in zip(gf, gr):
             scale = float(jnp.max(jnp.abs(b))) + 1e-9
@@ -110,8 +118,8 @@ class TestZeroOverheadFallbacks:
         assert a == b  # determinism of the comparison itself
         assert "all-to-all" not in a and "all_to_all" not in a
 
-    def test_tp1_sp1_is_the_plain_kernel(self):
-        mesh = _mesh(data=8, seq=1, tp=1)
+    def test_one_device_mesh_is_the_plain_kernel(self):
+        mesh = _mesh(data=1, seq=1, tp=1)
         q, k, v = _qkv_bthd()
         with tpu_interpret_mode():
             via_tp = jax.jit(lambda *t: flash_attention_bthd_tp(
@@ -121,6 +129,23 @@ class TestZeroOverheadFallbacks:
                 *t, causal=True, block_q=128,
                 block_k=128)).lower(q, k, v).as_text()
         assert via_tp == plain
+
+    def test_data_only_mesh_still_wraps_the_kernel(self):
+        """tp=1, sp=1 over a data axis: no axis splits the heads or the
+        tokens, but a Mosaic kernel cannot be partitioned by GSPMD (the
+        chip's compiler refuses it), so the call must sit in a shard_map
+        with the batch over the data axis — and still be the oracle.
+        (data=4: eight interpreted shards of this size, one thread each,
+        starve the interpreter's callbacks of the CPU client's threads.)"""
+        mesh = _mesh(data=4, seq=1, tp=1)
+        q, k, v = _qkv_bthd(B=4)
+        with tpu_interpret_mode():
+            fn = jax.jit(lambda *t: flash_attention_bthd_tp(
+                *t, causal=True, block_q=128, block_k=128, mesh=mesh))
+            o = np.asarray(fn(q, k, v))
+            assert "shard_map" in str(jax.make_jaxpr(fn)(q, k, v))
+        np.testing.assert_allclose(o, np.asarray(_oracle(q, k, v)),
+                                   rtol=2e-3, atol=2e-3)
 
     def test_indivisible_head_group_degrades_to_tp_only(self):
         """H/tp = 1 head cannot split over sp=2: the sp legs must drop
@@ -141,3 +166,144 @@ class TestZeroOverheadFallbacks:
         """The positive control for the two pins above."""
         hlo = self._lowered(_mesh(), *_qkv_bthd())
         assert "all-to-all" in hlo or "all_to_all" in hlo
+
+
+def _topology(**axis_sizes):
+    from deepspeed_tpu.parallel.topology import set_topology
+
+    n = int(np.prod(list(axis_sizes.values())))
+    topo = MeshTopology(axis_sizes=axis_sizes, devices=jax.devices()[:n])
+    set_topology(topo)
+    return topo
+
+
+class TestKernelMeshPlan:
+    """``ops/kernel_mesh.kernel_mesh_plan``: the one decision where a
+    Mosaic kernel call sits. Every flash and decode wrapper asks it."""
+
+    def _plan(self, **kw):
+        from deepspeed_tpu.ops.kernel_mesh import kernel_mesh_plan
+
+        return kernel_mesh_plan(8, 4, **kw)
+
+    def test_no_mesh_and_one_device_mesh_are_the_plain_kernel(self):
+        assert self._plan() is None
+        assert self._plan(mesh=_mesh(data=1, seq=1, tp=1)) is None
+
+    def test_whole_mesh_from_gspmd_code(self):
+        mesh = _mesh(data=2, seq=2, tp=2)
+        plan = self._plan(mesh=mesh, seqlen=256)
+        assert plan.mesh is mesh and plan.axis_names is None
+        assert (plan.batch, plan.heads, plan.seq) == ("data", "tp", "seq")
+        assert plan.size(plan.heads) * plan.size(plan.seq) == 4
+        # sp is asked for by passing the token count, and needs the
+        # post-tp head group (4/2) and the tokens to divide
+        assert self._plan(mesh=mesh).seq is None
+        assert self._plan(mesh=mesh, seqlen=255).seq is None
+
+    def test_global_topology_is_the_default_mesh(self):
+        topo = _topology(data=4, tp=2)
+        plan = self._plan()
+        assert plan.mesh is topo.mesh
+        assert (plan.batch, plan.heads) == ("data", "tp")
+
+    def test_indivisible_dims_stay_whole(self):
+        from deepspeed_tpu.ops.kernel_mesh import kernel_mesh_plan
+
+        plan = kernel_mesh_plan(3, 3, mesh=_mesh(data=2, seq=1, tp=2))
+        assert plan is not None  # still a shard_map: GSPMD cannot have it
+        assert (plan.batch, plan.heads) == (None, None)
+
+    def test_inside_a_fully_manual_shard_map_is_the_plain_kernel(self):
+        """The Ulysses and ring bodies: every axis is manual already, the
+        operands are the local shards, and a second shard_map over the
+        same mesh is an error at trace."""
+        from jax.sharding import PartitionSpec as P
+
+        from deepspeed_tpu.utils.compat import shard_map
+
+        mesh = _mesh(data=2, seq=2, tp=2)
+        seen = []
+
+        def body(x):
+            seen.append(self._plan(mesh=mesh, seqlen=256))
+            return x
+
+        jax.make_jaxpr(shard_map(body, mesh=mesh, in_specs=P("data"),
+                                 out_specs=P("data")))(jnp.zeros((8,)))
+        assert seen == [None]
+
+    def test_inside_a_partly_manual_shard_map_takes_the_auto_axes(self):
+        """The pipeline engine's shard_map is manual over ``pipe`` only.
+        The chip's compiler wants EVERY axis manual around a Mosaic
+        kernel, so the plan nests over all the axes left Auto, on the
+        context's mesh, and never names the manual one in a spec."""
+        from jax.sharding import PartitionSpec as P
+
+        from deepspeed_tpu.utils.compat import shard_map
+
+        mesh = _topology(pipe=2, data=2, tp=2).mesh
+        seen = []
+
+        def body(x):
+            seen.append(self._plan())
+            return x
+
+        jax.make_jaxpr(shard_map(body, mesh=mesh, in_specs=P("pipe"),
+                                 out_specs=P("pipe"), axis_names={"pipe"},
+                                 check_vma=False))(jnp.zeros((8,)))
+        (plan,) = seen
+        assert plan.axis_names == frozenset(mesh.axis_names) - {"pipe"}
+        assert plan.mesh.manual_axes == ("pipe",)
+        assert (plan.batch, plan.heads) == ("data", "tp")
+
+
+class TestInsideAnEnclosingShardMap:
+    def test_ulysses_with_the_flash_kernel_matches_dense_oracle(self):
+        """``ulysses_attention(use_flash=True)``, the TPU default: the
+        dispatcher is re-entered inside the Ulysses shard_map and must
+        call the plain kernel there."""
+        from deepspeed_tpu.ops.ulysses_attention import ulysses_attention
+
+        topo = _topology(data=2, seq=2)
+        q, k, v = (t.transpose(0, 2, 1, 3) for t in _qkv_bthd())
+        with tpu_interpret_mode():
+            o = jax.jit(lambda *t: ulysses_attention(
+                *t, mesh=topo.mesh, use_flash=True))(q, k, v)
+            jax.block_until_ready(o)
+        np.testing.assert_allclose(
+            np.asarray(o), np.asarray(attention_reference(q, k, v)),
+            rtol=2e-3, atol=2e-3)
+
+    @pytest.mark.parametrize("layout", ["bhtd", "bthd"])
+    def test_flash_inside_the_pipe_manual_shard_map_traces(self, layout):
+        """Both dispatch paths under the pipeline engine's shard_map
+        (``axis_names={"pipe"}``): the kernel sits in a NESTED shard_map
+        over the remaining axes. Traced only: the CPU interpreter's
+        callbacks refuse a partly manual context, the chip's compiler
+        does not (``test_chip_compile.py`` compiles this case)."""
+        from jax.sharding import PartitionSpec as P
+
+        from deepspeed_tpu.ops.attention import attention
+        from deepspeed_tpu.utils.compat import shard_map
+
+        mesh = _topology(pipe=2, data=2, tp=2).mesh
+        q, k, v = _qkv_bthd()
+        if layout == "bhtd":
+            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+
+        def stage(q, k, v):
+            if layout == "bhtd":
+                return attention(q, k, v, use_flash=True)
+            return flash_attention_bthd_tp(q, k, v, block_q=128,
+                                           block_k=128)
+
+        def loss(q, k, v):
+            return jnp.sum(shard_map(
+                stage, mesh=mesh, in_specs=(P(), P(), P()), out_specs=P(),
+                axis_names={"pipe"}, check_vma=False)(q, k, v) ** 2)
+
+        text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
+            q, k, v))
+        assert "pallas_call" in text
+        assert text.count("shard_map") >= 2  # the stage's, and the kernel's

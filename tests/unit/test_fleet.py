@@ -1425,7 +1425,7 @@ class TestTraceGenCLI:
         return subprocess.run(
             [sys.executable, os.path.join(REPO, "tools", "trace_gen.py"),
              *args],
-            capture_output=True, text=True, cwd=REPO)
+            capture_output=True, text=True, cwd=REPO, timeout=300)
 
     def test_writes_deterministic_jsonl(self, tmp_path):
         out = str(tmp_path / "t.jsonl")

@@ -14,6 +14,7 @@ from deepspeed_tpu.models.gpt2 import (
     gpt2_loss_fn,
 )
 from deepspeed_tpu.parallel.topology import reset_topology
+from deepspeed_tpu.utils.compat import tpu_interpret_mode
 
 
 @pytest.fixture(autouse=True)

@@ -98,7 +98,7 @@ class TestGL02:
                           for f in by_code(fixture_run("gl02", "bad"),
                                            "GL02"))
         for api in ("shard_map", "serialize_executable",
-                    "TPUCompilerParams", "force_tpu_interpret_mode",
+                    "CompilerParams", "force_tpu_interpret_mode",
                     "persistent-cache arming"):
             assert api in msgs, f"GL02 missed {api}"
 
@@ -509,7 +509,7 @@ class TestCLI:
         return subprocess.run(
             [sys.executable, os.path.join(REPO, "tools", "lint.py"),
              *args],
-            capture_output=True, text=True, cwd=REPO)
+            capture_output=True, text=True, cwd=REPO, timeout=300)
 
     def test_exit_2_on_findings(self):
         root = os.path.join(FIXTURES, "gl01", "bad")

@@ -21,15 +21,6 @@ from deepspeed_tpu.runtime.pipe.schedule import (BackwardPass, ForwardPass,
                                                  TrainSchedule,
                                                  ZeroBubbleSchedule)
 from deepspeed_tpu.runtime.pipe.module import partition_balanced
-from deepspeed_tpu.utils.compat import partial_auto_shard_map_safe
-
-# jax < 0.5 cannot compile the pipe-manual shard_map composed with live
-# auto axes (data > 1): the engine refuses with RuntimeError before XLA
-# gets a chance to SIGABRT. Pipe-only meshes work on every runtime.
-needs_partial_auto = pytest.mark.skipif(
-    not partial_auto_shard_map_safe(),
-    reason="pipe x data composition requires jax >= 0.5 "
-           "(partial-auto shard_map lowering)")
 
 
 def _collect(schedule):
@@ -146,7 +137,6 @@ def _batch(rows, seq=32, seed=0):
 
 
 class TestPipelineEngine:
-    @needs_partial_auto
     def test_matches_single_stage(self):
         reset_topology()
         devs = jax.devices()
@@ -167,7 +157,6 @@ class TestPipelineEngine:
                         jax.tree_util.tree_leaves(p1)):
             np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-5)
 
-    @needs_partial_auto
     def test_train_batch_decreases_loss(self):
         reset_topology()
         engine = _make_engine(pipe=2, data=2, devices=jax.devices()[:4],
@@ -183,7 +172,6 @@ class TestPipelineEngine:
             _make_engine(pipe=2, data=2, devices=jax.devices()[:4],
                          zero_stage=3)
 
-    @needs_partial_auto
     def test_model_parameters_eager_init(self):
         # regression: state built inside super().__init__ (model_parameters
         # given) must not crash on pipeline setup ordering
@@ -261,10 +249,7 @@ class TestInputResidency:
 
         # the strided layout puts micro-batch t in chunk slot t//P of
         # stage t%P, and the loss still computes (parity covered by
-        # tests/model pipeline gate). The behavioral compile needs the
-        # partial-auto lowering (data=2 rides along as an auto axis).
-        if not partial_auto_shard_map_safe():
-            return
+        # tests/model pipeline gate); data=2 rides along as an auto axis
         ids = np.random.default_rng(0).integers(
             0, cfg.vocab_size, (8, 2, 16)).astype(np.int32)
         params = module.init_params(jax.random.PRNGKey(0), ids[0])

@@ -259,7 +259,7 @@ class TestPipeVizTool:
         return subprocess.run(
             [sys.executable, os.path.join(repo, "tools", "pipe_viz.py"),
              *argv],
-            capture_output=True, text=True, cwd=repo)
+            capture_output=True, text=True, cwd=repo, timeout=300)
 
     @pytest.mark.parametrize("schedule", ["1f1b", "inference",
                                           "interleaved", "zero_bubble"])
@@ -313,7 +313,7 @@ class TestPipeVizTool:
             "sys.exit(pv.main(['--schedule', '1f1b', '--stages', '2',\n"
             "                  '--micro-batches', '4']))\n")
         proc = subprocess.run([sys.executable, str(stub)],
-                              capture_output=True, text=True, cwd=repo)
+                              capture_output=True, text=True, cwd=repo, timeout=300)
         assert proc.returncode == 1
         assert "VALIDATION FAILED" in proc.stderr
         assert "missing backward" in proc.stderr

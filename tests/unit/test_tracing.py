@@ -757,7 +757,7 @@ class TestTraceExportTool:
             [sys.executable, os.path.join(REPO, "tools", "trace_export.py"),
              sink, "-o", out],
             capture_output=True, text=True, cwd=REPO,
-            env={**os.environ, "JAX_PLATFORMS": "cpu"})
+            env={**os.environ, "JAX_PLATFORMS": "cpu"}, timeout=300)
         assert proc.returncode == 0, proc.stderr
         payload = json.loads(open(out).read())
         slices = [e for e in payload["traceEvents"] if e["ph"] == "X"]
@@ -771,7 +771,7 @@ class TestTraceExportTool:
         # 2: no sink at all
         proc = subprocess.run(
             [sys.executable, tool, str(tmp_path / "nope.jsonl")],
-            capture_output=True, text=True, cwd=REPO, env=env)
+            capture_output=True, text=True, cwd=REPO, env=env, timeout=300)
         assert proc.returncode == 2
         # 1: a sink with no span events
         empty = tmp_path / "telemetry.jsonl"
@@ -780,7 +780,7 @@ class TestTraceExportTool:
              "data": {}}) + "\n")
         proc = subprocess.run([sys.executable, tool, str(empty)],
                               capture_output=True, text=True, cwd=REPO,
-                              env=env)
+                              env=env, timeout=300)
         assert proc.returncode == 1
 
     def test_report_renders_request_waterfall(self, tmp_path):

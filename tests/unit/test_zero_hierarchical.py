@@ -72,6 +72,43 @@ class TestReplicaGroupParsing:
         assert received_bytes({"operand_bytes": 100, "group_size": None}) == 0
 
 
+# One line each of what the installed compilers print (operands by NAME; the
+# shape is on the line that defines the name), copied from real programs.
+_CPU_TEXT = """
+  %copy = f32[16,64]{0,1} copy(%param_0)
+  %all-gather = f32[128,64]{0,1} all-gather(%copy), channel_id=1, replica_groups=[2,4]<=[8], dimensions={1}, use_global_device_ids=true
+  %fusion = f32[8]{0} fusion(%all-gather), kind=kLoop, calls=%fused_computation
+"""
+_TPU_TEXT = """
+  %copy-done.1 = bf16[16,128]{1,0:T(8,128)(2,1)S(1)} copy-done(%copy-start.1)
+  %all-gather.7 = bf16[64,128]{1,0:T(8,128)(2,1)S(1)} all-gather(%copy-done.1), channel_id=2, replica_groups=[1,4]<=[4], dimensions={0}, frontend_attributes={async_collective_name="all-gather-start"}
+"""
+_TUPLE_TEXT = """
+  %bitcast = f32[64]{0} bitcast(%p0)
+  %bitcast.1 = u8[8,513]{1,0} bitcast(%p1)
+  ROOT %all-reduce = (f32[64]{0}, u8[8,513]{1,0}) all-reduce(%bitcast, %bitcast.1), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%region_0.0
+"""
+_LOWERED_TEXT = """
+  reshape.4 = f32[64]{0} reshape(Arg_0.1)
+  psum.23 = f32[64]{0} all-reduce(reshape.4), channel_id=1, replica_groups={{0,1}}, to_apply=region_3.4
+"""
+
+
+class TestParseCollectivesText:
+    @pytest.mark.parametrize("text,op,operands,group_size", [
+        (_CPU_TEXT, "all-gather", [("f32", 16 * 64 * 4)], 4),
+        (_TPU_TEXT, "all-gather", [("bf16", 16 * 128 * 2)], 4),
+        (_TUPLE_TEXT, "all-reduce", [("f32", 256), ("u8", 8 * 513)], 4),
+        (_LOWERED_TEXT, "all-reduce", [("f32", 256)], 2),
+    ], ids=["cpu", "tpu_tiled_layout", "tuple_operands", "lowered_no_percent"])
+    def test_operand_shapes_come_from_the_defining_line(
+            self, text, op, operands, group_size):
+        (c,) = parse_collectives(text)  # the fusion USING a collective is none
+        assert (c["op"], c["operands"], c["group_size"]) == (
+            op, operands, group_size)
+        assert c["operand_bytes"] == sum(b for _, b in operands)
+
+
 class TestHierarchicalSpecs:
     def test_param_axes_drop_data(self):
         axes = hierarchical_param_axes()

@@ -18,10 +18,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from deepspeed_tpu.utils.chip_probe import reassert_platform_env
-
-reassert_platform_env()
-
 
 def main():
     p = argparse.ArgumentParser()
@@ -74,12 +70,12 @@ def main():
             return engine.generate(ids, max_new_tokens=16, do_sample=False)
 
     out = step()  # warmup/compile outside the trace
-    np.asarray(jax.device_get(jax.tree_util.tree_leaves(out)[0]))
+    jax.block_until_ready(out)
 
     with jax.profiler.trace(args.out):
         for _ in range(args.steps):
             out = step()
-        np.asarray(jax.device_get(jax.tree_util.tree_leaves(out)[0]))
+        jax.block_until_ready(out)
     print(f"trace written to {args.out} "
           f"({args.steps} {args.what} steps, platform="
           f"{jax.devices()[0].platform})")

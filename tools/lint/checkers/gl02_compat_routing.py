@@ -1,25 +1,17 @@
 """GL02 compat-routing.
 
-Every jax API that segfaulted or renamed under jax 0.4.x must flow
-through the shim in ``deepspeed_tpu/utils/compat.py`` — that module is
-the one place the version matrix lives, and a direct use elsewhere is
-exactly the class of bug that cost PRs 1, 4 and 8 their debugging time:
+The JAX APIs whose spelling has moved under this repo before flow through
+``deepspeed_tpu/utils/compat.py`` and nowhere else, so the next move is a
+one-file change:
 
-- ``shard_map``: ``from jax import shard_map`` breaks on < 0.5 and the
-  ``check_vma``/``check_rep`` kwarg renamed — ``compat.shard_map``.
-- ``TPUCompilerParams``/``CompilerParams``: renamed across 0.4/0.5 —
-  ``compat.tpu_compiler_params``.
-- ``force_tpu_interpret_mode``: missing on < 0.5 —
-  ``compat.tpu_interpret_mode``.
-- ``serialize_executable``: jaxlib < 0.5 SIGSEGVs deserializing CPU
-  executables — gate on ``compat.aot_serialization_safe``.
+- ``shard_map`` — ``compat.shard_map``.
+- Pallas TPU ``CompilerParams`` — ``compat.tpu_compiler_params``.
+- ``force_tpu_interpret_mode`` — ``compat.tpu_interpret_mode``.
+- ``serialize_executable`` — ``aot/bundle.py`` is its one consumer
+  (baselined with its justification).
 - persistent-cache arming (``jax.config.update("jax_compilation_
-  cache_dir", ...)``): warm runs die on < 0.5 CPU — gate on
-  ``compat.persistent_compilation_cache_safe``.
-
-The designed consumers behind the gates (``aot/bundle.py``,
-``utils/chip_probe.py``) carry inline suppressions with their
-justification comments.
+  cache_dir", ...)``) — ``compat.arm_compilation_cache``, which alone
+  decides where the cache lives.
 """
 
 import ast
@@ -39,7 +31,7 @@ def _is_exempt(relpath: str) -> bool:
 class CompatRouting(Checker):
     code = "GL02"
     name = "compat-routing"
-    description = ("jax-0.4.x-breaking APIs (shard_map, CompilerParams, "
+    description = ("routed JAX APIs (shard_map, CompilerParams, "
                    "interpret mode, serialize_executable, persistent-"
                    "cache arming) are forbidden outside utils/compat.py")
 
@@ -76,8 +68,8 @@ class CompatRouting(Checker):
             code=self.code, path=mod.relpath, line=node.lineno,
             col=node.col_offset,
             message=(f"direct use of {api} — route through "
-                     f"deepspeed_tpu.utils.compat.{route} (the jax-0.4.x "
-                     f"rename/segfault matrix lives there)"))
+                     f"deepspeed_tpu.utils.compat.{route} (the one door "
+                     f"to this API)"))
 
     def _check_import(self, mod, node) -> Iterable[Finding]:
         m = node.module or ""
@@ -90,11 +82,11 @@ class CompatRouting(Checker):
                 or (m == "jax.experimental"
                     and "serialize_executable" in names):
             yield self._find(mod, node, "serialize_executable",
-                             "aot_serialization_safe (gate) + aot/bundle")
+                             "(no shim: aot/bundle is the one consumer)")
         if m.startswith("jax.experimental.pallas"):
-            for bad in ("CompilerParams", "TPUCompilerParams"):
-                if bad in names:
-                    yield self._find(mod, node, bad, "tpu_compiler_params")
+            if "CompilerParams" in names:
+                yield self._find(mod, node, "CompilerParams",
+                                 "tpu_compiler_params")
             if "force_tpu_interpret_mode" in names:
                 yield self._find(mod, node, "force_tpu_interpret_mode",
                                  "tpu_interpret_mode")
@@ -107,10 +99,7 @@ class CompatRouting(Checker):
             yield self._find(mod, node, "shard_map", "shard_map")
         elif d.startswith("jax.experimental.serialize_executable"):
             yield self._find(mod, node, "serialize_executable",
-                             "aot_serialization_safe (gate) + aot/bundle")
-        elif d.endswith(".TPUCompilerParams"):
-            yield self._find(mod, node, "TPUCompilerParams",
-                             "tpu_compiler_params")
+                             "(no shim: aot/bundle is the one consumer)")
         elif d.endswith(".CompilerParams") and (
                 "pltpu" in d or "pallas" in d or d.startswith("tpu.")):
             yield self._find(mod, node, "CompilerParams",
@@ -126,9 +115,8 @@ class CompatRouting(Checker):
             if "compilation_cache" in key:
                 yield self._find(
                     mod, node, f"persistent-cache arming ({key!r})",
-                    "persistent_compilation_cache_safe (gate first)")
+                    "arm_compilation_cache")
         elif "compilation_cache" in d and d.rsplit(".", 1)[-1] in (
                 "set_cache_dir", "initialize_cache"):
             yield self._find(mod, node, "persistent-cache arming",
-                             "persistent_compilation_cache_safe (gate "
-                             "first)")
+                             "arm_compilation_cache")

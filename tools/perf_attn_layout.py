@@ -72,16 +72,13 @@ def step_ab():
                     "steps_per_print": 100_000})
         batch = {"input_ids": ids}
         loss = engine(batch); engine.backward(loss); engine.step()
-        np.asarray(jax.device_get(jax.tree_util.tree_leaves(
-            engine.state.params)[0]))
+        jax.block_until_ready(engine.state.params)
         best = float("inf")
         for _ in range(3):
             t0 = time.perf_counter()
             for _ in range(5):
                 loss = engine(batch); engine.backward(loss); engine.step()
-            float(loss)
-            np.asarray(jax.device_get(jax.tree_util.tree_leaves(
-                engine.state.params)[0]))
+            jax.block_until_ready((loss, engine.state.params))
             best = min(best, (time.perf_counter() - t0) / 5)
         print(f"train step {layout}: {1e3 * best:7.1f} ms")
 

@@ -25,16 +25,11 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from deepspeed_tpu.utils.chip_probe import reassert_platform_env
-
-reassert_platform_env()
-
 
 def timeit(fn, *args, steps=20, **kw):
     import jax
 
-    def sync(o):
-        np.asarray(jax.device_get(jax.tree_util.tree_leaves(o)[0]).reshape(-1)[:1])
+    sync = jax.block_until_ready
 
     out = fn(*args, **kw)
     sync(out)

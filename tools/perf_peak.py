@@ -12,8 +12,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 def timeit(fn, *args, steps=20):
     import jax
 
-    def sync(o):
-        np.asarray(jax.device_get(jax.tree_util.tree_leaves(o)[0]))
+    sync = jax.block_until_ready
 
     out = fn(*args)
     sync(out)

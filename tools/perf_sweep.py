@@ -44,8 +44,7 @@ def run_variant(name, cfg_kw, batch, steps=10, seq=1024):
     ids = rng.integers(0, cfg.vocab_size, (batch, seq)).astype(np.int32)
 
     def _sync():
-        np.asarray(jax.device_get(
-            jax.tree_util.tree_leaves(engine.state.params)[0]))
+        jax.block_until_ready(engine.state.params)
 
     loss = engine({"input_ids": ids})
     engine.backward(loss)

@@ -512,7 +512,6 @@ def _train_step_series(cfg, batch, seq, on_tpu, steps=3, ds_overrides=None,
         per_axis = (max(costs, key=lambda c:
                         c.get("collective_operand_bytes") or 0)
                     .get("collective_bytes_per_axis") or {}) if costs else {}
-        est = engine.telemetry.exposed_comm_estimate()
     finally:
         # a failed candidate is tuner EVIDENCE, not a crash — the next
         # candidate must not measure against this one's leaked engine
@@ -536,8 +535,6 @@ def _train_step_series(cfg, batch, seq, on_tpu, steps=3, ds_overrides=None,
         "retraces_in_timed_window": int(retraces),
         "collective_wire_bytes": int(wire),
         "collective_bytes_per_axis": {k: int(v) for k, v in per_axis.items()},
-        "exposed_comm_fraction": (est.get("exposed_comm_fraction")
-                                  if est else None),
         "n_dev": n_dev, "batch": batch, "seq": seq, "steps": steps,
         "ds_overrides": ds_overrides or {},
         "tunables": dict(tunables or {}),
@@ -641,9 +638,6 @@ def _overlap_series(cfg, batch, seq, on_tpu, steps=3):
             flat["collective_bytes_per_axis"],
         "hierarchical_collective_bytes_per_axis":
             hier["collective_bytes_per_axis"],
-        "flat_exposed_comm_fraction": flat["exposed_comm_fraction"],
-        "hierarchical_exposed_comm_fraction":
-            hier["exposed_comm_fraction"],
     }
 
 
@@ -653,8 +647,7 @@ def _tracing_series(cfg, batch, seq, on_tpu, steps=3):
     spans off vs spans on (`telemetry.tracing.enabled`) — so the delta
     is EXACTLY the span layer's host-side bookkeeping (the compiled
     programs are byte-identical by the zero-overhead pin; this series
-    bounds the part the pin can't see). Also reports the static
-    exposed-comm estimate the step spans carried."""
+    bounds the part the pin can't see)."""
 
     # both legs telemetry-enabled: the delta isolates the SPAN layer,
     # not the (always-on-in-this-series) collector stack around it

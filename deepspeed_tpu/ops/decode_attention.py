@@ -134,6 +134,7 @@ def decode_attention(q, k_cache, v_cache, cache_index, softmax_scale=None,
     idx = jnp.asarray(cache_index, jnp.int32).reshape(1)
     return pl.pallas_call(
         kernel,
+        name="decode_attn",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, tq, heads, d), q.dtype),
         compiler_params=tpu_compiler_params(
@@ -297,6 +298,10 @@ def decode_attention_paged(q, k_pool, v_pool, block_tables, lengths,
                                heads=heads, d=d, num_kb=mb)
     tables = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32)
+    # no ``name=`` here or on the int8 twin below: a pallas_call's name is
+    # also a named scope, and the device trace already prints this kernel
+    # under the caller's scope (``attn._paged_kv_attend.N``), which the
+    # benchmark's paged-decode roofline reader matches by that name
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -494,7 +499,7 @@ def decode_attention_paged_tp(q, k_pool, v_pool, block_tables, lengths,
     return plan.shard_map(
         kernel,
         (qs_spec, pool_spec, pool_spec, P(plan.batch), P(plan.batch)),
-        qs_spec)(q, k_pool, v_pool, jnp.asarray(block_tables, jnp.int32),
+        qs_spec, name="paged_kv_attend")(q, k_pool, v_pool, jnp.asarray(block_tables, jnp.int32),
                  jnp.asarray(lengths, jnp.int32))
 
 
@@ -522,6 +527,6 @@ def decode_attention_paged_int8_tp(q, k_pool, v_pool, k_scale, v_scale,
         kernel,
         (qs_spec, pool_spec, pool_spec, pool_spec, pool_spec,
          P(plan.batch), P(plan.batch)),
-        qs_spec)(q, k_pool, v_pool, k_scale, v_scale,
+        qs_spec, name="paged_kv_attend")(q, k_pool, v_pool, k_scale, v_scale,
                  jnp.asarray(block_tables, jnp.int32),
                  jnp.asarray(lengths, jnp.int32))

@@ -173,6 +173,7 @@ def _flash_forward(q, k, v, scale, causal, block_q, block_k):
                                bq=bq, bk=bk, num_kb=num_kb, off=sk - sq)
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[qs, ks, vs],
         out_specs=(os_, ls),
@@ -336,6 +337,7 @@ def _flash_backward(res, g, scale, causal, block_q, block_k):
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, num_kb=num_kb, off=sk - sq),
+        name="flash_bwd_dq",
         grid=(bh // gg, num_qb, num_kb),
         in_specs=[
             _spec((bq, d), lambda bhi, qi, ki: (bhi, qi, 0)),
@@ -355,6 +357,7 @@ def _flash_backward(res, g, scale, causal, block_q, block_k):
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, num_qb=num_qb, off=sk - sq),
+        name="flash_bwd_dkv",
         grid=(bh // gg, num_kb, num_qb),
         in_specs=[
             _spec((bq, d), lambda bhi, ki, qi: (bhi, qi, 0)),
@@ -544,6 +547,7 @@ def _flash_forward_bthd(q, k, v, scale, causal, block_q, block_k):
                                bq=bq, bk=bk, num_kb=num_kb, off=sk - sq)
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[qs, ks, ks],
         out_specs=(os_, ls),
@@ -591,6 +595,7 @@ def _flash_backward_bthd(res, dout, scale, causal, block_q, block_k):
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel_bthd, scale=scale, causal=causal,
                           bq=bq, bk=bk, num_kb=num_kb, off=sk - sq),
+        name="flash_bwd_dq",
         grid=(b * hpg, num_qb, num_kb),
         in_specs=[qs, ks, ks, qs, ls, ls],
         out_specs=qs,
@@ -615,6 +620,7 @@ def _flash_backward_bthd(res, dout, scale, causal, block_q, block_k):
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel_bthd, scale=scale, causal=causal,
                           bq=bq, bk=bk, num_qb=num_qb, off=sk - sq),
+        name="flash_bwd_dkv",
         grid=(b * hpg, num_kb, num_qb),
         in_specs=[qs2, ks2, ks2, qs2, ls2, ls2],
         out_specs=(ks2, ks2),

@@ -44,8 +44,19 @@ class KernelMeshPlan(NamedTuple):
             n *= int(self.mesh.shape[a])
         return n
 
-    def shard_map(self, f, in_specs, out_specs):
+    def shard_map(self, f, in_specs, out_specs, name: Optional[str] = None):
+        """``f`` per shard. A kernel that carries no name of its own
+        (``pl.pallas_call(name=...)``) would print in the device trace
+        as ``shard_map.N``: ``name`` is the scope its body runs in
+        instead."""
         from deepspeed_tpu.utils.compat import shard_map
+
+        if name is not None:
+            body = f
+
+            def f(*args):
+                with jax.named_scope(name):
+                    return body(*args)
 
         return shard_map(f, mesh=self.mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False,

@@ -153,6 +153,7 @@ def stochastic_quantize_tpu(x, seed: int, num_bits: int = 8):
 
     q, scale = pl.pallas_call(
         kernel,
+        name="stochastic_quantize",
         in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
                   pl.BlockSpec(memory_space=pltpu.SMEM)],
         out_specs=(pl.BlockSpec(memory_space=pltpu.VMEM),

@@ -140,6 +140,7 @@ def _sparse_forward_impl(qh, kh, vh, qrow, kcol, cnt, scale, *, nq, nk):
 
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, total=a),
+        name="block_sparse_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(h, a),
@@ -279,6 +280,7 @@ def _sparse_backward(qh, kh, vh, oh, lse, g, lists, scale, nq, nk):
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, total=a),
+        name="block_sparse_bwd_dq",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(h, a),
@@ -310,6 +312,7 @@ def _sparse_backward(qh, kh, vh, oh, lse, g, lists, scale, nq, nk):
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, total=at),
+        name="block_sparse_bwd_dkv",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(h, at),

@@ -287,36 +287,12 @@ class TelemetryTraceConfig(DeepSpeedConfigModel):
 class TelemetryTracingConfig(DeepSpeedConfigModel):
     """``telemetry.tracing``: span-based causal tracing
     (``telemetry/tracing.py``) — serving request traces and training
-    step-phase traces as ``span`` events on the stream, plus a per-step
-    exposed-comm fraction. Off by default; enabling it changes host-side
-    bookkeeping only (the compiled step/decode HLO stays byte-identical,
-    pinned in ``tests/unit/test_tracing.py``)."""
+    step-phase traces as ``span`` events on the stream. Off by default;
+    enabling it changes host-side bookkeeping only (the compiled
+    step/decode HLO stays byte-identical, pinned in
+    ``tests/unit/test_tracing.py``)."""
 
     enabled: bool = False
-    # per-step exposed-comm accounting: profiled from a closed
-    # jax.profiler window where an XPlane parser exists, otherwise a
-    # zero-overlap static estimate from the compiled step's cost model
-    # (labeled as such). The two rates below are the estimate's
-    # denominators; 0 = auto (device-kind defaults).
-    exposed_comm: bool = True
-    ici_gbps: float = 90.0
-    peak_tflops: float = 0.0
-    # per-mesh-axis link-rate overrides (GB/s), e.g. {"data": 25.0} to
-    # price a DCN data axis below the ICI default; axes not listed fall
-    # back to ici_gbps, so {} is numerically the existing single-rate
-    # estimate
-    axis_gbps: Dict[str, float] = Field(default_factory=dict)
-
-    @model_validator(mode="after")
-    def _check(self):
-        if self.ici_gbps < 0 or self.peak_tflops < 0:
-            raise ValueError("telemetry.tracing.ici_gbps/peak_tflops must "
-                             "be >= 0")
-        for axis, rate in self.axis_gbps.items():
-            if rate <= 0:
-                raise ValueError(
-                    f"telemetry.tracing.axis_gbps[{axis!r}] must be > 0")
-        return self
 
 
 class TelemetryFlightRecorderConfig(DeepSpeedConfigModel):

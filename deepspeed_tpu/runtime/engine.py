@@ -431,7 +431,9 @@ class DeepSpeedEngine:
         # attribution of every compiled program's collectives
         self.telemetry.axis_sizes = [
             (a, int(s)) for a, s in self.mesh.shape.items()]
-
+        # the one bracket around host phases: a ds.train.<phase>
+        # profiler annotation always, the JSONL step span under tracing
+        self._bracket = self.telemetry.brackets("train")
 
         # --- resilience (checkpoint integrity + fallback, step sentinel,
         #     hang watchdog — deepspeed_tpu/runtime/resilience) ---
@@ -1211,9 +1213,12 @@ class DeepSpeedEngine:
         if self.wall_clock_breakdown_:
             self.timers(FORWARD_GLOBAL_TIMER).start()
         self.tput_timer.start()
-        batch = self._apply_curriculum(batch)
-        batch = self._shard_batch(batch)
-        self._ensure_state(batch)
+        # the batch's way onto the devices is `data` as much as its fetch
+        # (train_batch brackets that): a host->device copy per step
+        with self._bracket("data", span="data"):
+            batch = self._apply_curriculum(batch)
+            batch = self._shard_batch(batch)
+            self._ensure_state(batch)
         if (self._moq is not None and self._moq_eig_pending
                 and self.eigenvalue is not None):
             # one-time eigenvalue measurement on the first real batch
@@ -1231,8 +1236,7 @@ class DeepSpeedEngine:
             self.flops_profiler.start_profile()
         # span tracing: the fused fwd+bwd(+reduce) dispatch is ONE
         # host-observable phase (JAX compiles them into one program)
-        with self.telemetry.annotation("ds.fwd_bwd"), \
-                self.telemetry.step_trace.phase("fwd_bwd"):
+        with self._bracket("fwd_bwd", span="fwd_bwd"):
             if self._onebit:
                 # fused fwd+bwd+compressed-update program, staged on the
                 # optimizer's warmup/compression flag
@@ -1340,8 +1344,7 @@ class DeepSpeedEngine:
         if at_boundary:
             if self.wall_clock_breakdown_:
                 self.timers(STEP_GLOBAL_TIMER).start()
-            with self.telemetry.annotation("ds.optimizer_step"), \
-                    self.telemetry.step_trace.phase("optimizer"):
+            with self._bracket("optimizer", span="optimizer"):
                 if self._host_offload:
                     self._host_apply()
                 elif self._onebit:
@@ -1462,7 +1465,7 @@ class DeepSpeedEngine:
             if batch is not None:
                 b = batch
             else:
-                with self.telemetry.step_trace.phase("data"):
+                with self._bracket("data", span="data"):
                     b = next(data_iter)
             loss = self.forward(b)
             self.backward(loss)
@@ -2306,12 +2309,18 @@ class DeepSpeedEngine:
             # can legitimately outlast the step timeout — not a hang.
             # Checkpoint IO gets its own trace (it runs between step
             # traces): one ckpt_io span, action-tagged
-            tracer = self.telemetry.tracer
-            with tracer.span("ckpt_io", tracer.new_trace(hint="ckpt"),
-                             action="save", tag=str(tag),
-                             step=self.global_steps):
+            with self._bracket("ckpt_io", span="ckpt_io",
+                               trace=self._ckpt_trace(), action="save",
+                               tag=str(tag), step=self.global_steps):
                 return self._save_checkpoint_impl(save_dir, tag,
                                                   client_state, save_latest)
+
+    def _ckpt_trace(self):
+        """Checkpoint IO's own trace context (None with tracing off)."""
+        tracer = self.telemetry.tracer
+        if not tracer.enabled:
+            return None
+        return {"trace": tracer.new_trace(hint="ckpt")}
 
     def _save_checkpoint_impl(self, save_dir, tag, client_state, save_latest):
         tag = tag or f"global_step{self.global_steps}"
@@ -2461,10 +2470,9 @@ class DeepSpeedEngine:
         with self.resilience.watchdog_suspended():
             # restore IO (verify hashing + deserialize) may outlast the
             # step timeout — not a hang
-            tracer = self.telemetry.tracer
-            with tracer.span("ckpt_io", tracer.new_trace(hint="ckpt"),
-                             action="load", tag=str(tag),
-                             step=self.global_steps):
+            with self._bracket("ckpt_io", span="ckpt_io",
+                               trace=self._ckpt_trace(), action="load",
+                               tag=str(tag), step=self.global_steps):
                 return self._load_checkpoint_resolved(
                     load_dir, tag,
                     load_optimizer_states=load_optimizer_states,

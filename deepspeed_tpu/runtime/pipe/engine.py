@@ -438,7 +438,7 @@ class PipelineEngine(DeepSpeedEngine):
         """One full optimizer step: ``gas`` micro-batches through the
         pipeline (reference ``pipe/engine.py:294``)."""
         if batch is None:
-            with self.telemetry.step_trace.phase("data"):
+            with self._bracket("data", span="data"):
                 parts = [next(data_iter) for _ in range(self.micro_batches)]
                 batch = jax.tree_util.tree_map(
                     # host-side batch assembly from the data iterator (input

@@ -49,6 +49,17 @@ prefills only its tail; with ``kv_cache_dtype: "int8"`` the pools store
 per-row-quantized KV at a quarter of the bytes. All three knobs default
 off, and off means byte-identical compiled programs.
 
+Every host phase of the step loop sits in ONE kind of bracket
+(``telemetry/tracing.py`` ``Brackets``): ``ds.serve.step`` around a
+scheduler iteration, ``.schedule``, ``.prefill`` and ``.decode`` (each
+with ``.dispatch`` and ``.sync`` children) and ``.emit`` inside it. The
+bracket always opens a profiler annotation of that name, adds its
+elapsed time to an always-on ledger (``stats()["phase_seconds"]``), and
+emits the JSONL span under ``telemetry.tracing``. Each request
+snapshots the ledger when it goes live and when it finishes, so its
+record says how much of its decode life went to decode programs, to
+other requests' prefills and to the host loop.
+
 Per-request telemetry (kind ``serving``: TTFT, queue wait, tokens/s,
 shed) rides the unified event stream; the resilience hang watchdog sees
 begin/heartbeat/abandon brackets so a wedged decode collective is a
@@ -56,7 +67,6 @@ detected stall while an idle server is never judged hung.
 """
 
 import collections
-import contextlib
 import time
 from typing import Dict, List, Optional
 
@@ -70,8 +80,12 @@ from deepspeed_tpu.serving.prefix_cache import PrefixCache
 from deepspeed_tpu.serving.request import FINISHED, Request
 from deepspeed_tpu.serving.scheduler import ContinuousBatchingScheduler
 from deepspeed_tpu.serving.spec_decode import build_proposer
-from deepspeed_tpu.telemetry.tracing import end_span, to_ns
+from deepspeed_tpu.telemetry.tracing import StepTrace, end_span, to_ns
 from deepspeed_tpu.utils.logging import log_dist
+
+
+# the bracketed phases that tile a scheduler iteration: the ledger's keys
+_PHASES = ("schedule", "prefill", "decode", "emit")
 
 
 def _model_window(model_config) -> Optional[int]:
@@ -148,6 +162,26 @@ class ServingEngine:
         # span tracer (inert unless telemetry.tracing is on): request
         # traces — queue/prefill/cow/decode legs — ride the event stream
         self._tracer = self.telemetry.tracer
+        # the always-on ledger: cumulative seconds inside the four
+        # phases that tile a scheduler iteration (the brackets add to
+        # them), plus the counts taken at the same boundaries. Plain
+        # Python numbers, no device work. Never reset (each request
+        # holds two snapshots of it); stats() reports it against the
+        # base reset_stats() takes, the registry gets what it gained
+        # since the last publish.
+        self._ledger = {**dict.fromkeys(_PHASES, 0.0), "prefill_calls": 0,
+                        "busy_slot_steps": 0}
+        self._ledger_base = dict(self._ledger)
+        self._ledger_published = dict(self._ledger)
+        self._busy = 0  # active slots of the latest decode step
+        # THE bracket around every host phase of the step loop
+        # (telemetry/tracing.py Brackets): ds.serve.<phase> on the
+        # profiler's clock always, the JSONL span under tracing, the
+        # ledger above from the injected clock
+        self._step_trace = StepTrace(self._tracer, root="serve_step")
+        self._bracket = self.telemetry.brackets(
+            "serve", clock=self.clock, ledger=self._ledger,
+            step_trace=self._step_trace)
         self.sched = ContinuousBatchingScheduler(
             self.config, self.block_mgr, self.max_len, self.buckets,
             clock=self.clock, prefix_cache=self.prefix,
@@ -253,11 +287,19 @@ class ServingEngine:
             lambda s, sh: jax.device_put(jnp.zeros(s.shape, s.dtype), sh),
             shapes["cache"], shardings)
 
-    def _donate(self):
+    def _donate(self, argnum: int = 1):
         # the old pool is dead after every call — donate it so steady-state
         # serving holds ONE pool allocation (CPU jax warns instead of
         # donating; skip there)
-        return (1,) if self._jax.default_backend() != "cpu" else ()
+        return (argnum,) if self._jax.default_backend() != "cpu" else ()
+
+    def _jit(self, fn, program: str, watch: str, donate: int = 1):
+        """``jax.jit`` of one serving program under its own name: the
+        compiled module (and so the profiler's program lane and the HLO
+        dump) reads ``jit_<program>`` instead of ``jit_fn`` for all."""
+        fn.__name__ = fn.__qualname__ = program
+        return self.engine.telemetry.watch_jit(
+            self._jax.jit(fn, donate_argnums=self._donate(donate)), watch)
 
     def _sample(self, logits, rng):
         from deepspeed_tpu.inference.engine import sample_logits
@@ -267,7 +309,7 @@ class ServingEngine:
                              sc.top_k, sc.top_p)
 
     def _build_prefill(self, T: int):
-        jax, jnp = self._jax, self._jnp
+        jnp = self._jnp
         dmodule, dequant = self._dmodule, self.engine._dequantize
         logits_of = self.engine._logits_of
         if self._keyed:
@@ -291,9 +333,8 @@ class ServingEngine:
                                    top_ks, top_ps)
                 return tok, vars_["cache"]
 
-            return self.engine.telemetry.watch_jit(
-                jax.jit(kfn, donate_argnums=self._donate()),
-                f"serving.prefill[T={T}]")
+            return self._jit(kfn, f"serving_prefill_T{T}",
+                             f"serving.prefill[T={T}]")
 
         def fn(qparams, cache, ids, tables, num_valid, rng):
             params = dequant(qparams)
@@ -309,12 +350,11 @@ class ServingEngine:
                 logits, (num_valid - 1)[:, None, None], axis=1)[:, 0]
             return self._sample(last, rng), vars_["cache"]
 
-        return self.engine.telemetry.watch_jit(
-            jax.jit(fn, donate_argnums=self._donate()),
-            f"serving.prefill[T={T}]")
+        return self._jit(fn, f"serving_prefill_T{T}",
+                         f"serving.prefill[T={T}]")
 
     def _build_decode(self):
-        jax, jnp = self._jax, self._jnp
+        jnp = self._jnp
         dmodule, dequant = self._dmodule, self.engine._dequantize
         logits_of = self.engine._logits_of
         if self._keyed:
@@ -337,8 +377,8 @@ class ServingEngine:
                                    temps, top_ks, top_ps)
                 return tok, vars_["cache"]
 
-            return self.engine.telemetry.watch_jit(
-                jax.jit(kfn, donate_argnums=self._donate()),
+            return self._jit(
+                kfn, "serving_decode",
                 f"serving.decode[slots={self.config.decode_slots}]")
 
         def fn(qparams, cache, tokens, tables, lengths, rng):
@@ -352,8 +392,8 @@ class ServingEngine:
             logits = logits_of(out)[:, -1]
             return self._sample(logits, rng), vars_["cache"]
 
-        return self.engine.telemetry.watch_jit(
-            jax.jit(fn, donate_argnums=self._donate()),
+        return self._jit(
+            fn, "serving_decode",
             f"serving.decode[slots={self.config.decode_slots}]")
 
     def _build_chunk(self, T: int):
@@ -363,7 +403,7 @@ class ServingEngine:
         causally. The sampled token at the last REAL position is
         meaningful only on the final chunk — it is the request's first
         generated token."""
-        jax, jnp = self._jax, self._jnp
+        jnp = self._jnp
         dmodule, dequant = self._dmodule, self.engine._dequantize
         logits_of = self.engine._logits_of
         if self._keyed:
@@ -388,9 +428,8 @@ class ServingEngine:
                                    flags, temps, top_ks, top_ps)
                 return tok, vars_["cache"]
 
-            return self.engine.telemetry.watch_jit(
-                jax.jit(kfn, donate_argnums=self._donate()),
-                f"serving.chunk[T={T}]")
+            return self._jit(kfn, f"serving_chunk_T{T}",
+                             f"serving.chunk[T={T}]")
 
         def fn(qparams, cache, ids, tables, lengths, num_valid, rng):
             params = dequant(qparams)
@@ -403,9 +442,8 @@ class ServingEngine:
                 logits, (num_valid - 1)[:, None, None], axis=1)[:, 0]
             return self._sample(last, rng), vars_["cache"]
 
-        return self.engine.telemetry.watch_jit(
-            jax.jit(fn, donate_argnums=self._donate()),
-            f"serving.chunk[T={T}]")
+        return self._jit(fn, f"serving_chunk_T{T}",
+                         f"serving.chunk[T={T}]")
 
     def _build_verify(self):
         """The k-token verify program — speculative decoding's single
@@ -418,7 +456,7 @@ class ServingEngine:
         0's math is the decode program's term for term, so a verify
         step that accepts nothing still emits the identical token the
         plain decode step would have."""
-        jax, jnp = self._jax, self._jnp
+        jnp = self._jnp
         dmodule, dequant = self._dmodule, self.engine._dequantize
         logits_of = self.engine._logits_of
 
@@ -434,8 +472,8 @@ class ServingEngine:
             toks = self._sample(logits.reshape(n * t, v), rng)
             return toks.reshape(n, t), vars_["cache"]
 
-        return self.engine.telemetry.watch_jit(
-            jax.jit(fn, donate_argnums=self._donate()),
+        return self._jit(
+            fn, "serving_verify",
             f"serving.verify[slots={self.config.decode_slots},"
             f"k={self.spec_k}]")
 
@@ -457,9 +495,7 @@ class ServingEngine:
 
             return jax.tree_util.tree_map(copy, cache)
 
-        donate = (0,) if self._jax.default_backend() != "cpu" else ()
-        return self.engine.telemetry.watch_jit(
-            jax.jit(fn, donate_argnums=donate), "serving.cow")
+        return self._jit(fn, "serving_cow", "serving.cow", donate=0)
 
     def _build_migrate(self, B: int):
         """Scatter ``B`` migrated pool blocks (every cache leaf — K/V
@@ -483,10 +519,8 @@ class ServingEngine:
 
             return jax.tree_util.tree_map(scatter, cache, rows)
 
-        donate = (0,) if self._jax.default_backend() != "cpu" else ()
-        return self.engine.telemetry.watch_jit(
-            jax.jit(fn, donate_argnums=donate),
-            f"serving.migrate[blocks={B}]")
+        return self._jit(fn, f"serving_migrate_B{B}",
+                         f"serving.migrate[blocks={B}]", donate=0)
 
     def _next_rng(self):
         self._rng, sub = self._jax.random.split(self._rng)
@@ -538,28 +572,38 @@ class ServingEngine:
         requests into free slots, advance mid-prefill prompts one budgeted
         chunk, then advance every decode-ready sequence one token. Returns
         requests finished this step."""
-        now = self.clock()
         done: List[Request] = []
-        # deadline sweep over running work
-        for slot, req in self.sched.running():
-            if self.sched.expired(req, now):
-                self._finish(req, "deadline", now, done)
-        # splice admissions into free slots (no recompilation: bucket set)
-        admitted, shed = self.sched.admit(now)
-        for req in shed:
-            self._record(req, shed=True, began=True)
-        for slot, req, table in admitted:
-            self._begin(slot, req, table, done)
-        self._prefill_chunks(done)
-        # one decode step for the whole slot batch (mid-prefill slots are
-        # idle decode rows: garbage table, outputs ignored); with
-        # speculation on, the verify program IS the decode step
-        if any(slot not in self._prefilling
-               for slot, _ in self.sched.running()):
-            if self._proposer is not None:
-                self._spec_step(done)
-            else:
-                self._decode_step(done)
+        with self._bracket("step", step=self._step_count + 1,
+                           busy=self._busy, queue_depth=len(self.sched.queue)):
+            with self._bracket("schedule", span="schedule",
+                               ledger="schedule") as ph:
+                now = ph.t0
+                # deadline sweep over running work
+                for slot, req in self.sched.running():
+                    if self.sched.expired(req, now):
+                        self._finish(req, "deadline", now, done)
+                # splice admissions into free slots (no recompilation:
+                # bucket set)
+                admitted, shed = self.sched.admit(now)
+                for req in shed:
+                    self._record(req, shed=True, began=True)
+            for slot, req, table in admitted:
+                self._begin(slot, req, table, done)
+            self._prefill_chunks(done)
+            # one decode step for the whole slot batch (mid-prefill slots
+            # are idle decode rows: garbage table, outputs ignored); with
+            # speculation on, the verify program IS the decode step
+            if any(slot not in self._prefilling
+                   for slot, _ in self.sched.running()):
+                if self._proposer is not None:
+                    self._spec_step(done)
+                else:
+                    self._decode_step(done)
+        if self._step_trace.enabled:
+            g = self.sched.gauges()
+            self._step_trace.flush(self._step_count,
+                                   busy=g.get("slots_busy"),
+                                   queue_depth=g.get("queue_depth"))
         return done
 
     def _begin(self, slot: int, req: Request, table: np.ndarray,
@@ -573,8 +617,8 @@ class ServingEngine:
             # appended to, so the request's own fresh block receives a
             # device copy of its rows before anything else runs; the
             # source unpins once the copy is in flight
-            with self._req_span(req, "cow", src=req.cow[0],
-                                dst=req.cow[1]):
+            with self._bracket("cow", span="cow", trace=req.trace,
+                               src=req.cow[0], dst=req.cow[1]):
                 self._cow_copy(*req.cow)
             self.block_mgr.cow_done(req.request_id)
         if not self.chunk_tokens and req.cached_len == 0:
@@ -585,35 +629,36 @@ class ServingEngine:
         self._pf_pos[slot] = req.cached_len
         req.length = req.cached_len
 
-    def _req_span(self, req: Request, name: str, **attrs):
-        """Span bracket in ``req``'s trace (nullcontext when tracing is
-        off or the request carries no context). Durations are host-side
-        dispatch+sync walltime — the same clock every request timestamp
-        already uses."""
-        if not self._tracer.enabled or req.trace is None:
-            return contextlib.nullcontext()
-        return self._tracer.span(name, req.trace["trace"],
-                                 parent=req.trace.get("serve_id"), **attrs)
-
     def _prefill(self, slot: int, req: Request, table: np.ndarray,
                  done: List[Request]):
         jnp = self._jnp
         T = bucket_for(req.prompt_len, self.buckets)
         if T not in self._prefill_fns:
             self._prefill_fns[T] = self._build_prefill(T)
-        ids = np.zeros((1, T), np.int32)
-        ids[0, :req.prompt_len] = req.prompt
-        tail = (self._req_samp_args(req) if self._keyed
-                else (self._next_rng(),))
-        with self._req_span(req, "prefill", bucket=T,
-                            prompt_len=req.prompt_len):
-            tok, self.cache = self._prefill_fns[T](
-                self.engine.params, self.cache, jnp.asarray(ids),
-                jnp.asarray(table[None]),
-                jnp.asarray([req.prompt_len], jnp.int32), *tail)
-            tok = int(np.asarray(tok)[0])
+        with self._bracket("prefill", span="prefill", trace=req.trace,
+                           ledger="prefill", bucket=T,
+                           prompt_len=req.prompt_len,
+                           request_id=req.request_id) as ph:
+            with self._bracket("prefill.dispatch"):
+                ids = np.zeros((1, T), np.int32)
+                ids[0, :req.prompt_len] = req.prompt
+                tail = (self._req_samp_args(req) if self._keyed
+                        else (self._next_rng(),))
+                tok, self.cache = self._prefill_fns[T](
+                    self.engine.params, self.cache, jnp.asarray(ids),
+                    jnp.asarray(table[None]),
+                    jnp.asarray([req.prompt_len], jnp.int32), *tail)
+            with self._bracket("prefill.sync"):
+                tok = int(np.asarray(tok)[0])
+        self._prefill_done(req, ph)
         req.prefill_chunks = 1
         self._slot_live(slot, req, table, tok, done)
+
+    def _prefill_done(self, req: Request, ph):
+        """One closed prefill bracket: the request's own share of the
+        ledger's ``prefill`` seconds, and the call count."""
+        req.prefill_secs += ph.t1 - ph.t0
+        self._ledger["prefill_calls"] += 1
 
     # ------------------------------------------------------------------
     def _prefill_chunks(self, done: List[Request]):
@@ -659,39 +704,59 @@ class ServingEngine:
         jnp = self._jnp
         if T not in self._chunk_fns:
             self._chunk_fns[T] = self._build_chunk(T)
-        ids = np.zeros((1, T), np.int32)
-        ids[0, :step_len] = req.prompt[pos:pos + step_len]
-        tail = (self._req_samp_args(req) if self._keyed
-                else (self._next_rng(),))
-        with self._req_span(req, "prefill_chunk", pos=pos,
-                            tokens=step_len, bucket=T):
-            tok, self.cache = self._chunk_fns[T](
-                self.engine.params, self.cache, jnp.asarray(ids),
-                jnp.asarray(table[None]), jnp.asarray([pos], jnp.int32),
-                jnp.asarray([step_len], jnp.int32), *tail)
-            return int(np.asarray(tok)[0])
+        with self._bracket("prefill", span="prefill_chunk", trace=req.trace,
+                           ledger="prefill", pos=pos, tokens=step_len,
+                           bucket=T, request_id=req.request_id) as ph:
+            with self._bracket("prefill.dispatch"):
+                ids = np.zeros((1, T), np.int32)
+                ids[0, :step_len] = req.prompt[pos:pos + step_len]
+                tail = (self._req_samp_args(req) if self._keyed
+                        else (self._next_rng(),))
+                tok, self.cache = self._chunk_fns[T](
+                    self.engine.params, self.cache, jnp.asarray(ids),
+                    jnp.asarray(table[None]), jnp.asarray([pos], jnp.int32),
+                    jnp.asarray([step_len], jnp.int32), *tail)
+            with self._bracket("prefill.sync"):
+                tok = int(np.asarray(tok)[0])
+        self._prefill_done(req, ph)
+        return tok
 
     def _slot_live(self, slot: int, req: Request, table: np.ndarray,
                    tok: int, done: List[Request]):
         """Prompt fully pooled: index the prompt for future prefix hits,
         join the decode batch, and emit the first sampled token."""
-        req.first_token_ts = self.clock()
-        req.length = req.prompt_len
-        self._tables[slot] = table
-        self._lengths[slot] = req.prompt_len
-        self._last_tokens[slot] = tok
-        if self._keyed:
-            self._set_samp_slot(slot, req)
-        if self.prefix is not None:
-            # BEFORE any finish: insertion must precede release so a
-            # one-token request's blocks park evictable, not freed
-            self.prefix.insert(req.prompt, table)
-        finished = (tok == req.eos_token_id
-                    or len(req.tokens) + 1 >= req.max_new_tokens)
-        req.emit_token(tok, finished)
-        if finished:
-            reason = "eos" if tok == req.eos_token_id else "max_tokens"
-            self._finish(req, reason, self.clock(), done)
+        with self._bracket("emit", span="emit", ledger="emit") as ph:
+            self._mark_live(req, ph.t0)
+            req.length = req.prompt_len
+            self._tables[slot] = table
+            self._lengths[slot] = req.prompt_len
+            self._last_tokens[slot] = tok
+            if self._keyed:
+                self._set_samp_slot(slot, req)
+            if self.prefix is not None:
+                # BEFORE any finish: insertion must precede release so a
+                # one-token request's blocks park evictable, not freed
+                self.prefix.insert(req.prompt, table)
+            finished = (tok == req.eos_token_id
+                        or len(req.tokens) + 1 >= req.max_new_tokens)
+            req.emit_token(tok, finished)
+            if finished:
+                reason = "eos" if tok == req.eos_token_id else "max_tokens"
+                self._finish(req, reason, self.clock(), done)
+
+    def _ledger_mark(self):
+        """The four ledger numbers a request's record is made from,
+        O(1): seconds in prefill and decode brackets, decode steps and
+        busy-slot steps, all cumulative."""
+        led = self._ledger
+        return (led["prefill"], led["decode"], self._step_count,
+                led["busy_slot_steps"])
+
+    def _mark_live(self, req: Request, now: float):
+        """The request joins the decode batch at ``now``: its first
+        token's timestamp and the ledger as it stood then."""
+        req.first_token_ts = now
+        req.live_mark = self._ledger_mark()
 
     def _set_samp_slot(self, slot: int, req: Request):
         """Load one slot's sampling row from the request's (resolved)
@@ -724,23 +789,49 @@ class ServingEngine:
             self._decode_fn = self._build_decode()
         active = [(s, r) for s, r in self.sched.running()
                   if s not in self._prefilling]
-        tokens = jnp.asarray(self._last_tokens[:, None])
-        tail = (self._slot_samp_args() if self._keyed
-                else (self._next_rng(),))
-        toks, self.cache = self._decode_fn(
-            self.engine.params, self.cache, tokens,
-            jnp.asarray(self._tables), jnp.asarray(self._lengths),
-            *tail)
-        # the ONE designed host sync per decode step: sampled tokens must
-        # reach the host to stream to callers and drive finish logic
-        toks = np.asarray(toks)  # graft-lint: disable=GL04
-        now = self.clock()
+        with self._bracket("decode", span="decode_step", ledger="decode",
+                           active=len(active)) as ph:
+            with self._bracket("decode.dispatch"):
+                tokens = jnp.asarray(self._last_tokens[:, None])
+                tail = (self._slot_samp_args() if self._keyed
+                        else (self._next_rng(),))
+                toks, self.cache = self._decode_fn(
+                    self.engine.params, self.cache, tokens,
+                    jnp.asarray(self._tables), jnp.asarray(self._lengths),
+                    *tail)
+            with self._bracket("decode.sync"):
+                # the ONE designed host sync per decode step: sampled
+                # tokens must reach the host to stream to callers and
+                # drive finish logic
+                toks = np.asarray(toks)  # graft-lint: disable=GL04
+        now = ph.t1
+        self._step_boundary(len(active))
+        with self._bracket("emit", span="emit", ledger="emit"):
+            for slot, req in active:
+                tok = int(toks[slot])
+                req.length += 1
+                self._lengths[slot] = req.length
+                self._last_tokens[slot] = tok
+                finished = (tok == req.eos_token_id
+                            or len(req.tokens) + 1 >= req.max_new_tokens
+                            or req.length + 1 > self.max_len)
+                req.emit_token(tok, finished)
+                if finished:
+                    reason = ("eos" if tok == req.eos_token_id else
+                              "max_tokens" if len(req.tokens)
+                              >= req.max_new_tokens else "window")
+                    self._finish(req, reason, now, done)
+
+    def _step_boundary(self, active: int):
+        """One decode (or verify) step is over: the ledger's counts, the
+        telemetry step boundary, and, under telemetry only, the load
+        gauges (the slot scan is not paid with telemetry off)."""
         self._step_count += 1
-        self.telemetry.on_step_boundary(self._step_count,
-                                        samples=len(active))
+        self._busy = active
+        self._ledger["busy_slot_steps"] += active
+        self.telemetry.on_step_boundary(self._step_count, samples=active)
         # per-step load gauges on the event stream: the router's health
         # signals come from here, not from private scheduler state
-        # (guarded — telemetry off must not pay the slot scan per step)
         if self.telemetry.enabled:
             g = self.gauges()
             self.telemetry.emit("serving", "step.gauges",
@@ -749,20 +840,6 @@ class ServingEngine:
         # host-observed per-step token progress: a server saturated with
         # long generations must not be judged hung between completions
         self.resilience.serving_step_progress()
-        for slot, req in active:
-            tok = int(toks[slot])
-            req.length += 1
-            self._lengths[slot] = req.length
-            self._last_tokens[slot] = tok
-            finished = (tok == req.eos_token_id
-                        or len(req.tokens) + 1 >= req.max_new_tokens
-                        or req.length + 1 > self.max_len)
-            req.emit_token(tok, finished)
-            if finished:
-                reason = ("eos" if tok == req.eos_token_id else
-                          "max_tokens" if len(req.tokens)
-                          >= req.max_new_tokens else "window")
-                self._finish(req, reason, now, done)
 
     def _spec_step(self, done: List[Request]):
         """One speculative decode step: propose draft tokens on the host
@@ -786,9 +863,9 @@ class ServingEngine:
             budget = self.sched.speculative_budget(req, k)
             props: List[int] = []
             if budget > 0:
-                with self._req_span(req, "draft",
-                                    proposer=self._proposer.name,
-                                    budget=budget):
+                with self._bracket("draft", span="draft", trace=req.trace,
+                                   proposer=self._proposer.name,
+                                   budget=budget):
                     props = [int(t) for t in
                              self._proposer.propose(req, budget)][:budget]
             proposals[slot] = props
@@ -807,30 +884,32 @@ class ServingEngine:
                                                req.length + 1 + len(props))
             assert not granted, \
                 "speculative grant without a device table update"
-        t0 = self.clock()
-        toks, self.cache = self._verify_fn(
-            self.engine.params, self.cache, jnp.asarray(tokens),
-            jnp.asarray(self._tables), jnp.asarray(self._lengths),
-            jnp.asarray(num_valid), self._next_rng())
-        # the ONE designed host sync per decode step (same contract as
-        # the non-speculative loop): verified tokens drive commit/finish
-        toks = np.asarray(toks)  # graft-lint: disable=GL04
-        now = self.clock()
+        with self._bracket("decode", span="decode_step", ledger="decode",
+                           active=len(active)) as ph:
+            with self._bracket("decode.dispatch"):
+                toks, self.cache = self._verify_fn(
+                    self.engine.params, self.cache, jnp.asarray(tokens),
+                    jnp.asarray(self._tables), jnp.asarray(self._lengths),
+                    jnp.asarray(num_valid), self._next_rng())
+            with self._bracket("decode.sync"):
+                # the ONE designed host sync per decode step (same
+                # contract as the non-speculative loop): verified tokens
+                # drive commit/finish
+                toks = np.asarray(toks)  # graft-lint: disable=GL04
+        t0, now = ph.t0, ph.t1
         # chaos seam: a replica killed BETWEEN verify and commit has
         # emitted nothing from this window — host state is exactly the
         # pre-step state, so a retry or failover replays cleanly and
         # the router's exactly-once splice sees no speculative token
         raise_if("serving.spec_commit")
-        self._step_count += 1
         self._spec_steps += 1
-        self.telemetry.on_step_boundary(self._step_count,
-                                        samples=len(active))
-        if self.telemetry.enabled:
-            g = self.gauges()
-            self.telemetry.emit("serving", "step.gauges",
-                                step=self._step_count, **g)
-            self._metrics_step_gauges(g)
-        self.resilience.serving_step_progress()
+        self._step_boundary(len(active))
+        with self._bracket("emit", span="emit", ledger="emit"):
+            self._spec_emit(active, proposals, toks, t0, now, done)
+
+    def _spec_emit(self, active, proposals, toks, t0: float, now: float,
+                   done: List[Request]):
+        """Accept, commit and stream every active slot's verified row."""
         for slot, req in active:
             props = proposals[slot]
             accepted = 0
@@ -853,7 +932,8 @@ class ServingEngine:
                     parent=req.trace.get("serve_id"),
                     proposed=len(props), accepted=accepted,
                     request_id=req.request_id)
-            with self._req_span(req, "spec_commit", accepted=accepted):
+            with self._bracket("spec_commit", span="spec_commit",
+                               trace=req.trace, accepted=accepted):
                 finished, reason = self._spec_commit(slot, req, toks[slot],
                                                      accepted)
             if finished:
@@ -899,6 +979,8 @@ class ServingEngine:
                 "decode", req.trace["trace"], to_ns(req.first_token_ts),
                 to_ns(now), parent=req.trace.get("serve_id"),
                 tokens=len(req.tokens), request_id=req.request_id)
+        if req.live_mark is not None:
+            req.finish_mark = self._ledger_mark()
         self.sched.finish(req, reason, now)
         # reset the slot's host-side row: an idle slot computes into the
         # garbage block until the next admission overwrites it
@@ -996,6 +1078,14 @@ class ServingEngine:
         capacity = used * self.config.block_size
         m.gauge("ds_kv_pool_fragmentation").set(
             round(1.0 - committed / capacity, 4) if capacity else 0.0)
+        # the ledger, as counters: what it gained since the last publish
+        now, was = dict(self._ledger), self._ledger_published
+        self._ledger_published = now
+        phase = m.counter("ds_serving_phase_seconds_total", ("phase",))
+        for name in _PHASES:
+            phase.labels(phase=name).inc(now[name] - was[name])
+        m.counter("ds_serving_busy_slot_steps_total").inc(
+            now["busy_slot_steps"] - was["busy_slot_steps"])
 
     # ------------------------------------------------------------------
     def cancel(self, request_id: str, reason: str = "cancelled") -> bool:
@@ -1163,7 +1253,9 @@ class ServingEngine:
         req.accepted_tokens = int(export.get("accepted_tokens") or 0)
         req.submit_ts = now
         if req.tokens:
-            req.first_token_ts = now
+            # a spliced stream is live from the splice: its record's
+            # decode life (and the ledger marks) start here
+            self._mark_live(req, now)
         # router-stamped trace context: the spliced request's replica
         # spans join the CLIENT's trace under the migration attempt
         req.trace = dict(trace) if trace is not None else None
@@ -1278,6 +1370,7 @@ class ServingEngine:
         self._window_accepted_tokens = 0
         self._window_prompt_tokens = 0
         self._window_hit_tokens = 0
+        self._ledger_base = dict(self._ledger)
         self.sched.reset_stats()
 
     def stats(self) -> dict:
@@ -1318,7 +1411,15 @@ class ServingEngine:
             }
         s = self.sched.stats
         total = max(1, s["submitted"])
+        led, base = self._ledger, self._ledger_base
         return {
+            # the always-on ledger since the last reset_stats(): seconds
+            # inside the schedule/prefill/decode/emit brackets, and the
+            # counts taken at the same boundaries
+            "phase_seconds": {k: led[k] - base[k] for k in _PHASES},
+            "prefill_calls": led["prefill_calls"] - base["prefill_calls"],
+            "busy_slot_steps": (led["busy_slot_steps"]
+                                - base["busy_slot_steps"]),
             "prefix_cache": prefix_stats,
             "speculative": spec_stats,
             "finished": s["finished"], "shed": s["shed"],

@@ -38,8 +38,9 @@ from deepspeed_tpu.serving.config import GatewayConfig
 from deepspeed_tpu.serving.tenancy import Tenant, TenantTable
 from deepspeed_tpu.telemetry.registry import NULL_REGISTRY
 from deepspeed_tpu.telemetry.prom import CONTENT_TYPE
-from deepspeed_tpu.telemetry.tracing import (NULL_TRACER, end_span, span_id,
-                                             to_ns, trace_ctx)
+from deepspeed_tpu.telemetry.tracing import (NULL_TRACER, Brackets,
+                                             end_span, span_id, to_ns,
+                                             trace_ctx)
 
 GENERATE_ROUTE = "/v1/generate"
 
@@ -119,6 +120,13 @@ class ServingGateway:
             or NULL_REGISTRY
         self._tracer = getattr(self.telemetry, "tracer", None) \
             or NULL_TRACER
+        # the one bracket (ds.gateway.<phase> profiler annotations): the
+        # backend's telemetry hands in the annotation factory; a backend
+        # with none gets a bracket with no profiler sink (this module
+        # never imports jax, GL01)
+        brackets = getattr(self.telemetry, "brackets", None)
+        self._bracket = (brackets("gateway", clock=clock) if brackets
+                         else Brackets("gateway", clock=clock))
         self.tenants = TenantTable(self.config, clock=clock)
         self._routerlike = (hasattr(backend, "overload")
                             or hasattr(backend, "router"))
@@ -208,7 +216,10 @@ class ServingGateway:
 
     def _pump(self):
         while self._running:
-            self._wake.wait(self.config.poll_secs)
+            # nothing pending: idle for want of work, which a trace must
+            # tell apart from a slow host loop
+            with self._bracket("pump_idle"):
+                self._wake.wait(self.config.poll_secs)
             self._wake.clear()
             while self._running and (self.pending or self._cancels):
                 self.step()
@@ -642,10 +653,13 @@ class _Handler(BaseHTTPRequestHandler):
         try:
             for item in self._pull(gw, handle, stream):
                 if item[0] == "token":
-                    self.wfile.write(_sse("token", {
-                        "token": item[1], "index": index,
-                        "request_id": stream.request_id}))
-                    self.wfile.flush()
+                    # a handler thread: these contend with the pump for
+                    # the interpreter lock
+                    with gw._bracket("sse_write"):
+                        self.wfile.write(_sse("token", {
+                            "token": item[1], "index": index,
+                            "request_id": stream.request_id}))
+                        self.wfile.flush()
                     index += 1
                 elif item[0] == "done":
                     ttft = self._observe_ttft(gw, tenant, stream,

@@ -5,7 +5,10 @@ Lifecycle: ``queued`` -> ``running`` (owns a decode slot + cache blocks)
 ``shed`` straight from submit/queue (reason: ``queue_full`` |
 ``inflight_tokens`` | ``too_long`` | ``deadline``). Timestamps are
 host-monotonic; :meth:`Request.record` turns them into the telemetry
-payload (TTFT, queue wait, tokens/s) the serving event stream carries.
+payload the serving event stream (and the gateway's SSE ``done`` event)
+carries: TTFT, queue wait, tokens/s, and where the decode life went
+(``prefill_ms``; ``decode_steps``, ``decode_ms``, ``blocked_ms``,
+``host_ms``, ``batch_mean``, from two snapshots of the engine's ledger).
 """
 
 import dataclasses
@@ -69,6 +72,14 @@ class Request:
     # verify step; zero when speculation is off or never proposed) ----
     draft_tokens: int = 0             # proposer tokens sent to verify
     accepted_tokens: int = 0          # drafts the target model agreed with
+    # ---- the engine's ledger at two moments (serving/engine.py): when
+    # the request joined the decode batch and when it finished, each
+    # (prefill secs, decode secs, decode steps, busy-slot steps), all
+    # cumulative; prefill_secs is the time inside its OWN prefill calls.
+    # record() turns the pair into where its decode life went. ----
+    prefill_secs: float = 0.0
+    live_mark: Optional[tuple] = None
+    finish_mark: Optional[tuple] = None
     # ---- span-tracing context (telemetry/tracing.py) ----
     # {"trace": id, "parent": span id, ...}: set by the serving engine at
     # submit (tracing enabled), or stamped by the multi-replica router so
@@ -103,10 +114,34 @@ class Request:
         if self.stream is not None:
             self.stream(self, int(token), done)
 
+    def _decode_life(self) -> dict:
+        """Where the time between the first token and the finish went,
+        from the two ledger marks: inside decode programs
+        (``decode_ms``), inside OTHER requests' prefills
+        (``blocked_ms``), and the rest, the host loop (``host_ms``);
+        the three add up to ``finish_ts - first_token_ts`` by
+        construction. All None for a request that never went live or
+        never finished (shed, cancelled, migrated away)."""
+        if self.live_mark is None or self.finish_mark is None:
+            return dict.fromkeys(("prefill_ms", "decode_steps", "decode_ms",
+                                  "blocked_ms", "host_ms", "batch_mean"))
+        prefill, decode, steps, busy = (
+            b - a for a, b in zip(self.live_mark, self.finish_mark))
+        life = max(self.finish_ts - self.first_token_ts, 0.0)
+        return {
+            "prefill_ms": round(1e3 * self.prefill_secs, 3),
+            "decode_steps": steps,
+            "decode_ms": round(1e3 * decode, 3),
+            "blocked_ms": round(1e3 * prefill, 3),
+            "host_ms": round(1e3 * (life - decode - prefill), 3),
+            "batch_mean": round(busy / steps, 3) if steps else None,
+        }
+
     def record(self) -> dict:
         """JSON-safe per-request telemetry payload."""
         gen_secs = max(self.finish_ts - self.first_token_ts, 0.0)
         return {
+            **self._decode_life(),
             "request_id": self.request_id,
             "state": self.state,
             "reason": self.finish_reason,

@@ -91,6 +91,13 @@ SPANS = (
     "verify",         # the shared k-token verify dispatch, per-request view
     "spec_commit",    # accepted-prefix commit + rejected-tail drop
     "shed",           # admission/deadline shed (zero-work terminal span)
+    # serving step level: one trace per scheduler iteration that did work
+    "serve_step",     # root — first phase start -> last phase end
+    #                   (attrs: step, busy, queue_depth)
+    "schedule",       # deadline sweep + admission, host only
+    "decode_step",    # the decode (or verify) program call + its host
+    #                   sync for the whole slot batch (attrs: active)
+    "emit",           # finish logic + stream callbacks of the step's tokens
     "autoscale",      # one fleet scaling action: decision -> executed
     #                   (attrs: action, reason, from_size, to_size, source)
     "migrate",        # one live KV-block migration: export -> transfer ->
@@ -105,7 +112,6 @@ SPANS = (
     "reduce",         # gradient reduction, where host-observable
     "optimizer",      # optimizer apply dispatch
     "ckpt_io",        # checkpoint save/load IO (own trace, between steps)
-    "exposed_comm",   # measured exposed-comm window (profiled trace close)
 )
 
 # the span event envelope's reserved ``data`` keys — everything else in

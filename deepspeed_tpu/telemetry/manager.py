@@ -23,7 +23,6 @@ Collectors (tentpole contract, ISSUE 2):
    exactly ``num_steps`` steps, with markers in the event stream.
 """
 
-import contextlib
 import os
 from typing import Dict, Optional
 
@@ -33,7 +32,8 @@ from deepspeed_tpu.telemetry.jit_watch import (WatchedFunction,
                                                compiled_cost_summary)
 from deepspeed_tpu.telemetry.registry import NULL_REGISTRY
 from deepspeed_tpu.telemetry.sink import JsonlSink, MonitorBridge
-from deepspeed_tpu.telemetry.tracing import NULL_TRACER, StepTrace, Tracer
+from deepspeed_tpu.telemetry.tracing import (NULL_TRACER, Brackets,
+                                             StepTrace, Tracer)
 from deepspeed_tpu.utils.logging import log_dist, logger
 
 
@@ -82,9 +82,6 @@ class Telemetry:
         # checkpoint restore ships a bundle; consulted by
         # WatchedFunction._compile on every dispatch-cache miss
         self._aot_store = None
-        # latest compiled cost summary per watchdog family — the static
-        # exposed-comm estimate's input (tracing collector)
-        self._latest_costs: Dict[str, Dict] = {}
         # span tracer + per-step phase accounting (inert unless
         # telemetry AND telemetry.tracing are both enabled)
         self.tracer = NULL_TRACER
@@ -317,7 +314,6 @@ class Telemetry:
                 hlo_text = None
             cost = compiled_cost_summary(compiled, hlo_text,
                                          axis_sizes=self.axis_sizes)
-            self._latest_costs[family] = cost
             self.emit("step_cost", name, step=self._steps_seen, **cost)
             self._mirror_to_comms_logger(name, cost)
 
@@ -419,7 +415,6 @@ class Telemetry:
                           num_steps=tr.num_steps)
                 log_dist(f"telemetry: stopped jax.profiler trace after "
                          f"{tr.num_steps} step(s) -> {tr.dir}", ranks=[0])
-                self._measure_exposed_comm(step, tr)
             except Exception as e:
                 self.emit("trace_window", self.name, step=step,
                           action="stop_failed", error=str(e)[:200])
@@ -444,67 +439,19 @@ class Telemetry:
                 self.emit("trace_window", self.name, step=step,
                           action="start_failed", error=str(e)[:200])
 
-    def _measure_exposed_comm(self, step: int, tr):
-        """After a profiler window closes: try the MEASURED exposed-comm
-        fraction from the captured device timeline. Where no XPlane
-        parser exists (this container's CPU jaxlib) the gate's reason is
-        recorded once and the per-step static estimate stays the only
-        source — labeled as such everywhere it renders."""
-        if not (self.tracer.enabled and self.config.tracing.exposed_comm):
-            return
-        from deepspeed_tpu.telemetry import exposed_comm as xc
-
-        measured, reason = xc.from_profiler_dir(tr.dir)
-        if measured is None:
-            self.emit("trace_window", self.name, step=step,
-                      action="exposed_comm_unavailable", reason=reason)
-            return
-        import time
-
-        now = time.monotonic_ns()
-        window_ns = measured.get("busy_ns") or 0
-        self.tracer.record_span(
-            "exposed_comm", self.tracer.new_trace(hint=f"profile{step}"),
-            now - window_ns, now, window_steps=tr.num_steps,
-            window_end_step=step, **measured)
-        # the measured number supersedes the static estimate on the
-        # gauge too (its own `source` label keeps both visible)
-        self.metrics.gauge("ds_exposed_comm_fraction", ("source",)).labels(
-            source=str(measured.get("source", "profiled"))).set(
-                measured.get("exposed_comm_fraction") or 0.0)
-
-    def exposed_comm_estimate(self) -> Optional[Dict]:
-        """Static per-step exposed-comm estimate from the costliest
-        compiled program seen so far (the step program, by FLOPs).
-        None until a cost model exists or when disabled. Recomputed only
-        when a compile lands; boundaries between compiles reuse the
-        cached estimate (this runs every step)."""
-        if not (self.tracer.enabled and self.config.tracing.exposed_comm
-                and self._latest_costs):
-            return None
-        cached = getattr(self, "_exposed_cache", None)
-        key = len(self._compile_totals), sum(
-            v["compiles"] for v in self._compile_totals.values())
-        if cached is not None and cached[0] == key:
-            return cached[1]
-        from deepspeed_tpu.telemetry import exposed_comm as xc
-
-        cost = max(self._latest_costs.values(),
-                   key=lambda c: c.get("flops") or 0.0)
-        peak = self.config.tracing.peak_tflops or xc.default_peak_tflops()
-        est = xc.static_estimate(cost, self.config.tracing.ici_gbps, peak,
-                                 axis_gbps=self.config.tracing.axis_gbps)
-        self._exposed_cache = (key, est)
-        return est
-
-    def annotation(self, name: str):
-        """Profiler range for a host-side phase (the ``instrument_w_nvtx``
-        analog): visible in the XPlane trace the window captures."""
-        if not self.enabled or self.config.trace.num_steps <= 0:
-            return contextlib.nullcontext()
+    def brackets(self, layer: str, clock=None, ledger=None,
+                 step_trace=None):
+        """The one bracket for host phases (``telemetry/tracing.py``
+        :class:`Brackets`), bound to this manager's sinks. Telemetry on
+        or off it ALWAYS opens ``ds.<layer>.<phase>`` profiler
+        annotations (the ``instrument_w_nvtx`` analog): tracing.py is
+        jax-free, so the annotation factory is handed in from here."""
         import jax
 
-        return jax.profiler.TraceAnnotation(name)
+        return Brackets(layer, annotate=jax.profiler.TraceAnnotation,
+                        tracer=self.tracer,
+                        step_trace=step_trace or self.step_trace,
+                        clock=clock, ledger=ledger)
 
     # ------------------------------------------------------------------
     # step-boundary hook (one call per optimizer step, from the engines)
@@ -516,17 +463,8 @@ class Telemetry:
         self._steps_seen = step
         if not self.warm and step >= self.config.warmup_steps:
             self.warm = True
-        # the per-step exposed-comm fraction is computed ONCE here and
-        # consumed by all three surfaces — the `step` event field, the
-        # step-trace root attrs (report phase table) and the registry
-        # gauge — so they can never disagree
-        xc = self.exposed_comm_estimate() or {}
-        step_fields = {"samples": samples, "micro_steps": micro_steps}
-        if xc:
-            step_fields["exposed_comm_fraction"] = \
-                xc.get("exposed_comm_fraction")
-            step_fields["exposed_comm_source"] = xc.get("source")
-        self.emit("step", self.name, step=step, **step_fields)
+        self.emit("step", self.name, step=step, samples=samples,
+                  micro_steps=micro_steps)
         m = self.metrics
         if m is not NULL_REGISTRY:
             import time as _time
@@ -540,17 +478,11 @@ class Telemetry:
                 m.gauge("ds_steps_per_sec").set(
                     round(1e9 / (now_ns - self._last_boundary_ns), 4))
             self._last_boundary_ns = now_ns
-            if xc:
-                m.gauge("ds_exposed_comm_fraction", ("source",)).labels(
-                    source=str(xc.get("source"))).set(
-                        xc.get("exposed_comm_fraction") or 0.0)
         if self.step_trace.enabled:
             # flush the step's phase spans (no-op when the engine
-            # bracketed none — the serving decode loop), attaching the
-            # SAME exposed-comm estimate the step event carries; a later
-            # profiled window supersedes it with a measured
-            # `exposed_comm` span
-            self.step_trace.flush(step, **xc)
+            # bracketed none into THIS accounting: the serving engine
+            # keeps its own, flushed per scheduler iteration)
+            self.step_trace.flush(step)
         if (self.config.memory
                 and step % max(1, self.config.sample_every) == 0):
             self._sample_memory(step)

@@ -53,9 +53,6 @@ NAMES = {
         "gauge", "step rate over the last boundary interval"),
     "ds_samples_total": (
         "counter", "training samples consumed at step boundaries"),
-    "ds_exposed_comm_fraction": (
-        "gauge", "per-step exposed-communication fraction "
-                 "(label source: profiled|static_estimate)"),
     "ds_compiles_total": (
         "counter", "XLA compiles per watchdog family"),
     "ds_retraces_after_warmup_total": (
@@ -87,6 +84,12 @@ NAMES = {
         "counter", "terminal requests, by outcome (finished|shed)"),
     "ds_serving_tokens_total": (
         "counter", "generated tokens delivered by finished requests"),
+    "ds_serving_phase_seconds_total": (
+        "counter", "seconds of the step loop inside each bracketed phase "
+                   "(label phase: schedule|prefill|decode|emit)"),
+    "ds_serving_busy_slot_steps_total": (
+        "counter", "sum over decode steps of the slots that decoded: "
+                   "over ds_steps_total, the mean decode batch"),
     "ds_serving_queue_depth": (
         "gauge", "admission queue depth at the last decode step"),
     "ds_serving_slots_busy": (

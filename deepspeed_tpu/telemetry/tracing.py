@@ -6,16 +6,25 @@ by causally-related work, its own ``span`` id, an optional ``parent``
 span id, and monotonic ``start_ns``/``end_ns`` bounds. Two trace shapes
 ride the stream:
 
-- **serving request traces** — one trace per request: submit -> queue ->
-  admission -> each prefill chunk -> copy-on-write -> decode segment ->
-  finish/shed (plus, under speculative decoding, per-step
-  ``draft``/``verify``/``spec_commit`` legs), and (behind the
-  multi-replica router) one ``attempt`` subtree per replica dispatch, so
-  a failover CONTINUES the same trace on the survivor instead of
-  starting a new one.
-- **training step traces** — one trace per optimizer step with phase
-  children (``data``/``fwd_bwd``/``optimizer``/...) and an
-  exposed-comm-fraction attribute (``telemetry/exposed_comm.py``).
+- **serving request traces** — one trace per request: ``serve`` root ->
+  ``queue`` -> ``cow`` -> ``prefill`` or each ``prefill_chunk`` ->
+  one ``decode`` segment -> finish/``shed`` (plus, under speculative
+  decoding, per-step ``draft``/``verify``/``spec_commit`` legs), and
+  (behind the multi-replica router) one ``attempt`` subtree per replica
+  dispatch, so a failover CONTINUES the same trace on the survivor
+  instead of starting a new one.
+- **serving step traces** — one trace per scheduler iteration that did
+  work: a ``serve_step`` root with ``schedule``/``decode_step``/``emit``
+  children (the request-scoped prefill spans above carry the rest).
+- **training step traces** — one trace per optimizer step: a ``step``
+  root with phase children (``data``/``fwd_bwd``/``optimizer``);
+  checkpoint IO gets its own ``ckpt_io`` trace.
+
+Every host block is bracketed ONE way, by :class:`Brackets`: the bracket
+always opens a profiler annotation ``ds.<layer>.<phase>`` (so any
+profiler session sees the phase on the device trace's clock, telemetry
+on or off), emits the JSONL span above when ``telemetry.tracing`` is on,
+and adds its elapsed time to the owner's always-on ledger.
 
 Design rules, all load-bearing:
 
@@ -38,7 +47,6 @@ This module is host-only (no jax imports — GL01-pinned) so the serving
 policy tier and the report tooling can load it anywhere.
 """
 
-import contextlib
 import itertools
 import time
 from typing import Callable, Dict, List, Optional
@@ -147,17 +155,6 @@ class Tracer:
                           monotonic_ns() if start_ns is None
                           else int(start_ns), dict(attrs))
 
-    @contextlib.contextmanager
-    def span(self, name: str, trace: str, parent: Optional[str] = None,
-             **attrs):
-        """Context-managed span around a host-side block."""
-        handle = self.begin(name, trace, parent=parent, **attrs)
-        try:
-            yield handle
-        finally:
-            if handle is not None:
-                handle.end()
-
 
 def end_span(handle: Optional[SpanHandle], end_ns: Optional[int] = None,
              **attrs) -> None:
@@ -173,71 +170,159 @@ def span_id(handle: Optional[SpanHandle]) -> Optional[str]:
 # shared inert instance for components built without telemetry
 NULL_TRACER = Tracer(emit=None, enabled=False)
 
-_NULL_CTX = contextlib.nullcontext()
-
-
 class StepTrace:
-    """Per-optimizer-step phase accounting for the training engines.
+    """Per-step phase accounting: the step-scoped sink of
+    :class:`Brackets`.
 
-    The engine brackets host-observable phases (``data`` fetch, the
-    ``fwd_bwd`` dispatch, the ``optimizer`` apply) with :meth:`phase`;
-    at the step boundary the telemetry manager calls :meth:`flush`,
-    which emits one ``step`` root span covering first-phase-start ->
-    boundary plus one child span per recorded phase, all under a fresh
-    per-step trace id. With tracing off, ``phase`` is one attribute read
-    returning a shared nullcontext — no clock reads, no allocation.
+    The training engines bracket host-observable phases (``data`` fetch,
+    the ``fwd_bwd`` dispatch, the ``optimizer`` apply) and the serving
+    engine its scheduler iteration (``schedule``/``decode_step``/
+    ``emit``); each closed bracket lands here through :meth:`mark`. At
+    the step boundary :meth:`flush` emits one root span covering
+    first-phase-start -> boundary plus one child span per recorded
+    phase, all under a fresh per-step trace id. With tracing off nothing
+    is recorded and ``flush`` is an attribute read.
 
     Phase durations are HOST-side dispatch walltimes: under JAX's async
     dispatch a phase that merely enqueues device work reads as cheap
     unless an existing fence (loss fetch, donation pressure) already
     serializes it. That is by design — adding fences to make the numbers
     "device-true" would violate the no-added-host-syncs contract; the
-    device-true comm/compute split is the exposed-comm attribute's job.
+    device-true picture is the profiler's, where the same brackets show
+    as ``ds.*`` annotations beside the device operations.
     """
 
-    def __init__(self, tracer: Tracer, rank: int = 0):
+    def __init__(self, tracer: Tracer, rank: int = 0, root: str = "step"):
         self.tracer = tracer
         self.enabled = tracer.enabled
         self.rank = rank
+        self.root = root
         self._phases: List[tuple] = []
 
-    @contextlib.contextmanager
-    def _phase_cm(self, name: str, attrs: Dict):
-        t0 = monotonic_ns()
-        try:
-            yield
-        finally:
-            self._phases.append((name, t0, monotonic_ns(), attrs))
-
-    def phase(self, name: str, **attrs):
-        """Bracket one host-side phase of the current step."""
-        if not self.enabled:
-            return _NULL_CTX
-        return self._phase_cm(name, attrs)
-
     def mark(self, name: str, start_ns: int, end_ns: int, **attrs) -> None:
-        """Record an already-timed phase (callers that can't hold a
-        context manager open across their control flow)."""
+        """Record one timed phase of the current step."""
         if self.enabled:
             self._phases.append((name, int(start_ns), int(end_ns), attrs))
 
     def flush(self, step: int, **step_attrs) -> Optional[str]:
         """Emit the step's root span + phase children and reset. No-op
-        (returns None) when nothing was recorded — engines that never
-        bracket phases (the serving decode loop) emit no empty steps."""
+        (returns None) when nothing was recorded: a step that bracketed
+        no phase emits no empty root."""
         if not self.enabled or not self._phases:
             self._phases = []
             return None
         phases, self._phases = self._phases, []
         trace = self.tracer.new_trace(hint=f"step{step}-r{self.rank}")
         start = min(t0 for _, t0, _, _ in phases)
+        end = max(t1 for _, _, t1, _ in phases)
         root = self.tracer.record_span(
-            "step", trace, start, monotonic_ns(), step=int(step),
-            **step_attrs)
+            self.root, trace, start, end, step=int(step), **step_attrs)
         for name, t0, t1, attrs in phases:
             self.tracer.record_span(name, trace, t0, t1, parent=root,
                                     **attrs)
         return trace
+
+
+# ``trace=`` default of a bracket: the span belongs to the current step
+STEP_SCOPE = object()
+
+
+class _Open:
+    """One open bracket (see :class:`Brackets`). ``t0``/``t1`` are the
+    owner's clock at entry/exit, read only when a sink needs them (a
+    ledger key or an active span sink): callers that bracket a fence may
+    take their ``now`` from ``t1`` instead of reading the clock again."""
+
+    __slots__ = ("_b", "_name", "_span", "_trace", "_ledger", "_attrs",
+                 "_ann", "t0", "t1")
+
+    def __init__(self, b, name, span, trace, ledger, attrs):
+        self._b = b
+        self._name = name
+        self._span = span
+        self._trace = trace
+        self._ledger = ledger
+        self._attrs = attrs
+        self.t0 = self.t1 = None
+
+    def __enter__(self):
+        b = self._b
+        if b.annotate is not None:
+            self._ann = ann = (b.annotate(self._name, **self._attrs)
+                               if self._attrs else b.annotate(self._name))
+            ann.__enter__()
+        if self._ledger is not None or self._span is not None:
+            self.t0 = b.clock()
+        return self
+
+    def __exit__(self, *exc):
+        b = self._b
+        if self.t0 is not None:
+            self.t1 = t1 = b.clock()
+            if self._ledger is not None:
+                b.ledger[self._ledger] += t1 - self.t0
+            if self._span is not None:
+                self._emit(b, to_ns(self.t0), to_ns(t1))
+        if b.annotate is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+    def _emit(self, b, start_ns, end_ns):
+        trace = self._trace
+        if trace is STEP_SCOPE:
+            b.step_trace.mark(self._span, start_ns, end_ns, **self._attrs)
+        else:
+            b.tracer.record_span(self._span, trace["trace"], start_ns,
+                                 end_ns, parent=trace.get("serve_id"),
+                                 **self._attrs)
+
+
+class Brackets:
+    """THE bracket around a host block: ``with brackets("decode",
+    ledger="decode"): ...``. One context manager, three sinks:
+
+    - **always** a profiler annotation ``ds.<layer>.<phase>`` (``attrs``
+      ride along as its metadata), telemetry on or off: with no profiler
+      session that is a sub-microsecond no-op, with one the phase lies on
+      the device trace's own clock. This module stays jax-free: the
+      annotation factory (``jax.profiler.TraceAnnotation``) is handed in
+      by the telemetry manager; without one (a gateway over a backend
+      with no telemetry) the bracket keeps its other two sinks.
+    - with ``span=`` (a literal registered in
+      :data:`telemetry.events.SPANS`, GL05) and ``telemetry.tracing``
+      on, the completed JSONL span: in the request's trace when
+      ``trace=`` is a request's context (``None`` = that request carries
+      none: no span), else through the owner's :class:`StepTrace`.
+    - with ``ledger=`` a key of the owner's ledger dict, the elapsed
+      seconds added to it, read from the owner's injected clock so that
+      fake-clock tests stay exact.
+    """
+
+    def __init__(self, layer: str, annotate: Optional[Callable] = None,
+                 tracer: Tracer = None, step_trace: Optional[StepTrace] = None,
+                 clock: Optional[Callable] = None,
+                 ledger: Optional[Dict[str, float]] = None):
+        self.prefix = f"ds.{layer}."
+        self.annotate = annotate
+        self.tracer = tracer if tracer is not None else NULL_TRACER
+        self._tracing = self.tracer.enabled  # fixed at construction
+        self.step_trace = step_trace
+        self.clock = clock if clock is not None else time.monotonic
+        self.ledger = ledger
+
+    def __call__(self, phase: str, span: Optional[str] = None,
+                 trace=STEP_SCOPE, ledger: Optional[str] = None, **attrs):
+        if span is not None and not (self._tracing
+                                     and self._span_sink(trace)):
+            span = None
+        return _Open(self, self.prefix + phase, span, trace, ledger, attrs)
+
+    def _span_sink(self, trace) -> bool:
+        """Whether a span bracketed now has somewhere to go: the
+        request's trace context, or the step accounting."""
+        if trace is STEP_SCOPE:
+            return self.step_trace is not None and self.step_trace.enabled
+        return trace is not None
 
 
 def trace_ctx(trace: str, parent: Optional[str] = None,
@@ -248,5 +333,6 @@ def trace_ctx(trace: str, parent: Optional[str] = None,
     return {"trace": trace, "parent": parent, **attrs}
 
 
-__all__ = ["SPANS", "Tracer", "StepTrace", "SpanHandle", "NULL_TRACER",
-           "end_span", "span_id", "to_ns", "monotonic_ns", "trace_ctx"]
+__all__ = ["SPANS", "Tracer", "StepTrace", "SpanHandle", "Brackets",
+           "STEP_SCOPE", "NULL_TRACER", "end_span", "span_id", "to_ns",
+           "monotonic_ns", "trace_ctx"]

@@ -12,16 +12,16 @@ class ServingEngine:
     def trace(self):
         self.telemetry.emit("span", "prefil", step=1)        # typo name
         self._tracer.record_span("dequeue", "t1", 0, 1)      # unregistered
-        with self._tracer.span("warmup", "t1"):              # unregistered
+        with self._bracket("warmup", span="warmup"):         # unregistered
             pass
-        with self.telemetry.step_trace.phase("fwdbwd"):      # unregistered
-            pass
+        self.telemetry.step_trace.mark("fwdbwd", 0, 1)       # unregistered
 
     def spec_step(self):
         # speculative-decoding near-misses: the registered names are
         # draft / verify / spec_commit — drift stays pinned
         self._tracer.record_span("drafts", "t1", 0, 1)       # near-miss
-        with self._tracer.span("commit", "t1"):              # unregistered
+        with self._bracket("spec_commit", span="commit",     # unregistered
+                           trace=None):
             pass
 
     def migrate_step(self):

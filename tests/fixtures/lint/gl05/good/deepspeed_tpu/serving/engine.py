@@ -15,17 +15,18 @@ class ServingEngine:
         self.telemetry.emit("span", "queue", step=1)
         self._tracer.record_span("decode", "t1", 0, 1)
         self._tracer.record_span(name_from_caller, "t1", 0, 1)  # dynamic
-        with self._tracer.span("request", "t1"):
+        with self._bracket("anything.goes", span="request"):
             pass
-        with self.telemetry.step_trace.phase("queue"):
+        with self._bracket("decode.dispatch"):  # annotation only: no span
             pass
+        self.telemetry.step_trace.mark("queue", 0, 1)
 
     def spec_step(self):
         # speculative decoding's registered span names
-        with self._tracer.span("draft", "t1"):
+        with self._bracket("draft", span="draft", trace=None):
             pass
         self._tracer.record_span("verify", "t1", 0, 1)
-        with self._tracer.span("spec_commit", "t1"):
+        with self._bracket("spec_commit", span="spec_commit", trace=None):
             pass
 
     def migrate_step(self):
@@ -35,7 +36,6 @@ class ServingEngine:
     def gateway_step(self):
         # the HTTP front door's registered kind + span names
         self.telemetry.emit("gateway", "request.finished", step=1)
-        with self._tracer.span("gateway", "t1"):
-            pass
+        self._tracer.begin("gateway", "t1")
         self._tracer.record_span("auth", "t1", 0, 1)
         self._tracer.record_span("quota", "t1", 0, 1)

@@ -177,3 +177,140 @@ def test_flash_inside_the_pipe_manual_shard_map(topo):
         return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
     assert "tpu_custom_call" in _compiled_text(stage_grads, q, q, q)
+
+
+# ---------------------------------------------------------------------------
+# a name on every kernel: the HLO instruction the device trace prints
+def _kernel_names(text):
+    """Instruction names of the Mosaic kernels in a compiled program."""
+    import re
+
+    return [m.group(1) for m in re.finditer(
+        r"^\s*(?:ROOT )?%([\w.\-]+) = [^\n]*custom_call_target="
+        r'"tpu_custom_call"', text, re.M)]
+
+
+FLASH_NAMES = ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+
+
+def _stems(names, known=FLASH_NAMES):
+    """Which known kernel name each instruction name carries. Autodiff
+    decorates the scope (``jvp_flash_fwd_.1``; a scanned, rematerialized
+    layer prints it bare), so the name is looked for inside."""
+    return sorted(k for n in names for k in known if k in n)
+
+
+@pytest.mark.parametrize("layout", ["bthd", "bhtd"])
+def test_flash_kernels_are_named_by_what_they_are(one_chip, layout):
+    """Forward, dK/dV and dQ carry their own names whatever scope calls
+    them (they were ``attn.23/24/25`` by a flax scope and a counter)."""
+    from deepspeed_tpu.ops import flash_attention as fa
+
+    fn, shape = ((fa.flash_attention_bthd, (4, 1024, H, D))
+                 if layout == "bthd" else
+                 (fa.flash_attention, (4, H, 1024, D)))
+    q = _s(one_chip, shape)
+
+    def fwd_bwd(q, k, v):
+        with jax.named_scope("attn"):
+            return jax.grad(lambda *a: fn(*a).astype(jnp.float32).sum(),
+                            argnums=(0, 1, 2))(q, k, v)
+
+    names = _kernel_names(_compiled_text(fwd_bwd, q, q, q))
+    assert len(names) == 3 and _stems(names) == FLASH_NAMES, names
+
+
+def test_flash_kernels_keep_their_names_under_shard_map(topo):
+    """On four chips they were ``shard_map.206-208``."""
+    from deepspeed_tpu.ops.flash_attention import flash_attention_bthd_tp
+
+    mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "tp"))
+    q = _s(NamedSharding(mesh, P("data")), (16, 1024, H, D))
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(lambda *a: flash_attention_bthd_tp(
+            *a, mesh=mesh).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    names = _kernel_names(_compiled_text(fwd_bwd, q, q, q))
+    assert len(names) == 3 and _stems(names) == FLASH_NAMES, names
+
+
+def test_dense_decode_kernel_is_named(one_chip):
+    from deepspeed_tpu.ops.decode_attention import decode_attention
+
+    names = _kernel_names(_compiled_text(
+        decode_attention, _s(one_chip, (8, 1, H, D)),
+        _s(one_chip, (8, 1024, H, D)), _s(one_chip, (8, 1024, H, D)),
+        _s(one_chip, (), jnp.int32)))
+    assert _stems(names, ["decode_attn"]) == ["decode_attn"], names
+
+
+@pytest.mark.parametrize("mesh_shape", [None, (1, 4)])
+def test_paged_decode_kernel_matches_the_benchmarks_reader(
+        topo, one_chip, monkeypatch, _no_global_topology, mesh_shape):
+    """The serving decode program of a GPT-2 (125M widths, two layers).
+    Alone, its paged kernel is the instruction ``attn._paged_kv_attend.N``,
+    which is the name the accepted reader of ``paged_decode_roofline_share``
+    matches (the pattern is read from the benchmark's own file); with the
+    heads over tp=4 it keeps ``paged_kv_attend`` in its name."""
+    import json
+    import os
+    import re
+
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
+    from deepspeed_tpu.ops import attention as ops_attention
+
+    # the dispatcher asks whether a TPU is attached; here one is described
+    monkeypatch.setattr(ops_attention, "use_decode_kernel", lambda: True)
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(repo, "perfbench", "layer_metrics",
+                           "paged_decode_roofline_share.json")) as f:
+        pattern = re.compile(json.load(f)["pattern"])
+
+    slots, blocks, bs, per_seq = 8, 512, 32, 32
+    cfg = GPT2Config(vocab_size=50257, n_positions=1024, n_embd=H * D,
+                     n_layer=2, n_head=H, dtype=jnp.bfloat16)
+    module = GPT2LMHeadModel(cfg.for_paged_decode(blocks, bs))
+    if mesh_shape is None:
+        place, mesh = one_chip, None
+    else:
+        from deepspeed_tpu.parallel.topology import (MeshTopology,
+                                                     set_topology)
+
+        mt = MeshTopology(axis_sizes={"data": 1, "tp": 4},
+                          devices=topo.devices)
+        set_topology(mt)
+        mesh, place = mt.mesh, NamedSharding(mt.mesh, P())
+
+    def paging(n):
+        return {"block_tables": jnp.zeros((n, per_seq), jnp.int32),
+                "lengths": jnp.zeros((n,), jnp.int32),
+                "num_valid": jnp.ones((n,), jnp.int32), "prefill": False}
+
+    shapes = jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
+        paging=paging(1)))
+    put = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda s: _s(place, s.shape, s.dtype), tree)
+
+    def decode(params, cache, tokens, tables, lengths):
+        pg = {"block_tables": tables, "lengths": lengths,
+              "num_valid": jnp.ones_like(lengths), "prefill": False}
+        return module.apply({"params": params, "cache": cache}, tokens,
+                            mutable=["cache"], paging=pg)
+
+    text = _compiled_text(
+        decode, put(shapes["params"]), put(shapes["cache"]),
+        _s(place, (slots, 1), jnp.int32),
+        _s(place, (slots, per_seq), jnp.int32),
+        _s(place, (slots,), jnp.int32))
+    names = _kernel_names(text)
+    if mesh_shape is None:
+        assert [ln for ln in map(str.strip, text.splitlines())
+                if pattern.search(ln)], names
+    else:
+        # per shard the kernel sits in a shard_map, whose body is scoped
+        # (it printed as ``shard_map.N``)
+        assert names and all("paged_kv_attend" in n for n in names), names

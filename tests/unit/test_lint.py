@@ -162,8 +162,9 @@ class TestGL05:
 
     def test_unregistered_span_names_flagged(self):
         """Span-name registry leg: every literal span-name emit site
-        (kind-\"span\" emits, tracer.record_span/span/begin,
-        step_trace.phase) is pinned against telemetry/events.SPANS."""
+        (kind-\"span\" emits, tracer.record_span/begin, step_trace.mark,
+        the one bracket's span= keyword) is pinned against
+        telemetry/events.SPANS."""
         found = [f for f in by_code(fixture_run("gl05", "bad"), "GL05")
                  if "unregistered span name" in f.message]
         names = {f.message.split("'")[1] for f in found}

@@ -547,45 +547,6 @@ class TestManagerWiring:
         assert snap["ds_steps_per_sec"]["series"][0]["value"] > 0
         t.close()
 
-    def test_exposed_comm_single_source(self, tmp_path):
-        """Satellite contract: the per-step exposed-comm fraction (and
-        its measured|static_estimate label) is computed ONCE and lands
-        identically on the `step` event, the step-trace root span, and
-        the registry gauge — the three surfaces can never disagree."""
-        from deepspeed_tpu.telemetry import Telemetry
-
-        t = Telemetry({"enabled": True, "dir": str(tmp_path),
-                       "memory": False, "metrics_port": 0,
-                       "compile_watchdog": False,
-                       "tracing": {"enabled": True, "ici_gbps": 100.0,
-                                   "peak_tflops": 100.0}})
-        # seed the cost model the static estimate reads (the compile
-        # collector would fill this on a real engine)
-        t._latest_costs["step"] = {"flops": 1e12,
-                                   "collective_operand_bytes": int(1e9)}
-        t._compile_totals["step"] = {"compiles": 1, "trace_secs": 0.0,
-                                     "compile_secs": 0.0,
-                                     "retraces_after_warm": 0}
-        with t.step_trace.phase("fwd_bwd"):
-            pass
-        t.on_step_boundary(1)
-        t.flush()
-        events = [json.loads(line) for line in
-                  open(os.path.join(str(tmp_path), "telemetry.jsonl"))
-                  if line.strip()]
-        step_ev = next(e for e in events if e["kind"] == "step")
-        root = next(e for e in events if e["kind"] == "span"
-                    and e["name"] == "step")
-        frac = step_ev["data"]["exposed_comm_fraction"]
-        assert frac is not None
-        assert step_ev["data"]["exposed_comm_source"] == "static_estimate"
-        assert root["data"]["exposed_comm_fraction"] == frac
-        assert root["data"]["source"] == "static_estimate"
-        rows = t.metrics.snapshot()["ds_exposed_comm_fraction"]["series"]
-        assert rows == [{"labels": {"source": "static_estimate"},
-                         "value": frac}]
-        t.close()
-
     def test_compile_counters(self, tmp_path):
         from deepspeed_tpu.telemetry import Telemetry
 

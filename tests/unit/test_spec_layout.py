@@ -525,11 +525,10 @@ class TestTPKernels:
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-class TestTPExposedComm:
-    def test_tp_collectives_feed_exposed_comm(self):
+class TestTPStepCost:
+    def test_tp_collectives_feed_step_cost(self):
         """On a dp=1 / tp=2 mesh the ONLY collectives in the compiled
-        step are tp-axis ones — the step_cost accounting and the
-        exposed-comm fraction (PR 10/14 plumbing) must both see them."""
+        step are tp-axis ones — the step_cost accounting must see them."""
         topo = MeshTopology(axis_sizes={"data": 1, "tp": 2},
                             devices=jax.devices()[:2])
         engine, *_ = deepspeed_tpu.initialize(
@@ -540,8 +539,7 @@ class TestTPExposedComm:
                     "zero_optimization": {"stage": 0},
                     "telemetry": {"enabled": True, "jsonl": False,
                                   "memory": False, "hlo_cost": True,
-                                  "tracing": {"enabled": True,
-                                              "exposed_comm": True}},
+                                  "tracing": {"enabled": True}},
                     "steps_per_print": 10_000})
         ids = np.zeros((4, 16), np.int32)
         for _ in range(2):
@@ -552,10 +550,6 @@ class TestTPExposedComm:
         wire = max((e["data"].get("collective_operand_bytes") or 0
                     for e in evs if e["kind"] == "step_cost"), default=0)
         assert wire > 0, "tp collectives missing from step_cost"
-        fracs = [e["data"].get("exposed_comm_fraction")
-                 for e in evs if e["kind"] == "step"
-                 and e["data"].get("exposed_comm_fraction") is not None]
-        assert fracs and fracs[-1] > 0, fracs
         engine.destroy()
 
 

@@ -2,12 +2,12 @@
 
 Proof obligations:
 
-- the span layer's primitives (Tracer/StepTrace/histogram/interval
-  math) are correct, exception-isolated, and inert when disabled;
+- the span layer's primitives (Tracer/StepTrace/Brackets/histogram) are
+  correct, exception-isolated, and inert when disabled;
 - a traced training run emits causally-linked step traces (phase
-  children under one per-step root) carrying a LABELED exposed-comm
-  fraction, and the zero-overhead pin holds: with tracing absent the
-  compiled step program is byte-identical to a tracing-enabled engine's;
+  children under one per-step root), and the zero-overhead pin holds:
+  with tracing absent the compiled step program is byte-identical to a
+  tracing-enabled engine's;
 - a request routed through the multi-replica front door and killed
   mid-decode by chaos renders as ONE trace with two `attempt` subtrees
   and exactly-once (position-disjoint) `deliver` spans;
@@ -28,9 +28,9 @@ import pytest
 from deepspeed_tpu.serving import request as rq
 from deepspeed_tpu.telemetry.events import SPANS, load_all_events
 from deepspeed_tpu.telemetry.metrics import Histogram
-from deepspeed_tpu.telemetry.tracing import (NULL_TRACER, StepTrace, Tracer,
-                                             end_span, to_ns)
-from deepspeed_tpu.telemetry import exposed_comm as xc
+from deepspeed_tpu.telemetry.tracing import (NULL_TRACER, Brackets,
+                                             StepTrace, Tracer, end_span,
+                                             to_ns)
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
 
@@ -76,7 +76,9 @@ class TestTracer:
         h = tr.begin("request", "t1", start_ns=5, request_id="r")
         h.end(end_ns=9, state="finished")
         h.end(end_ns=99)  # idempotent: no double emit
-        with tr.span("decode", "t1", parent=h.span, tokens=2):
+        with Brackets("serve", tracer=tr)(
+                "decode", span="decode",
+                trace={"trace": "t1", "serve_id": h.span}, tokens=2):
             pass
         assert len(c.spans("request")) == 1
         (req,) = c.spans("request")
@@ -90,8 +92,10 @@ class TestTracer:
         assert NULL_TRACER.record_span("queue", "t", 0, 1) is None
         assert NULL_TRACER.begin("request", "t") is None
         end_span(None)  # tolerates the disabled-path None
-        with NULL_TRACER.span("decode", "t"):
+        with Brackets("serve")("decode", span="decode",
+                               trace={"trace": "t"}) as b:
             pass
+        assert b.t0 is None  # no sink wanted a time: no clock was read
 
     def test_emit_exceptions_are_isolated(self):
         def boom(*a, **k):
@@ -112,7 +116,7 @@ class TestTracer:
         for name in ("request", "attempt", "deliver", "serve", "queue",
                      "prefill", "prefill_chunk", "cow", "decode", "shed",
                      "step", "data", "fwd_bwd", "optimizer", "ckpt_io",
-                     "exposed_comm"):
+                     "serve_step", "schedule", "decode_step", "emit"):
             assert name in SPANS, name
 
 
@@ -121,18 +125,18 @@ class TestStepTrace:
     def test_phases_nest_under_one_step_root(self):
         tr, c = _tracer()
         st = StepTrace(tr)
-        with st.phase("data"):
+        bracket = Brackets("train", tracer=tr, step_trace=st)
+        with bracket("data", span="data"):
             pass
-        with st.phase("fwd_bwd"):
+        with bracket("fwd_bwd", span="fwd_bwd"):
             pass
-        with st.phase("optimizer"):
+        with bracket("optimizer", span="optimizer"):
             pass
-        trace = st.flush(7, exposed_comm_fraction=0.25,
-                         source="static_estimate")
+        trace = st.flush(7, busy=3)
         (root,) = c.spans("step")
         assert root["data"]["trace"] == trace
         assert root["data"]["step"] == 7
-        assert root["data"]["exposed_comm_fraction"] == 0.25
+        assert root["data"]["busy"] == 3
         for name in ("data", "fwd_bwd", "optimizer"):
             (child,) = c.spans(name)
             assert child["data"]["trace"] == trace
@@ -146,12 +150,14 @@ class TestStepTrace:
         assert st.flush(1) is None
         assert not c.events
 
-    def test_disabled_phase_is_shared_nullcontext(self):
+    def test_disabled_phase_records_nothing(self):
         st = StepTrace(NULL_TRACER)
-        cm1, cm2 = st.phase("data"), st.phase("fwd_bwd")
-        assert cm1 is cm2  # no per-call allocation on the disabled path
-        with cm1:
+        reads = []
+        bracket = Brackets("train", step_trace=st,
+                           clock=lambda: reads.append(1) or 0.0)
+        with bracket("data", span="data"):
             pass
+        assert not reads  # tracing off: the bracket read no clock
         st.mark("data", 0, 1)
         assert st.flush(1) is None
 
@@ -190,82 +196,10 @@ class TestHistogram:
 
 
 # ---------------------------------------------------------------------------
-class TestExposedComm:
-    def test_interval_math(self):
-        assert xc.merge_intervals([(5, 10), (0, 6), (20, 30)]) == \
-            [(0, 10), (20, 30)]
-        assert xc.total_ns([(0, 10), (5, 15)]) == 15
-        assert xc.overlap_ns([(0, 10)], [(5, 20)]) == 5
-        assert xc.overlap_ns([(0, 10), (20, 30)], [(5, 25)]) == 10
-
-    def test_exposed_fraction(self):
-        # comm 0-10 and 20-30; compute 5-25 covers 5-10 and 20-25:
-        # exposed comm = 10ns of 30ns busy
-        out = xc.exposed_fraction([(0, 10), (20, 30)], [(5, 25)])
-        assert out["exposed_comm_ns"] == 10
-        assert out["busy_ns"] == 30
-        assert out["exposed_comm_fraction"] == round(10 / 30, 4)
-
-    def test_static_estimate_is_labeled(self):
-        est = xc.static_estimate(
-            {"collective_operand_bytes": 9e9, "flops": 275e12},
-            ici_gbps=90.0, peak_tflops=275.0)
-        # comm 0.1s vs compute 1.0s -> ~9.1% exposed upper bound
-        assert est["source"] == "static_estimate"
-        assert abs(est["exposed_comm_fraction"] - 0.0909) < 0.001
-        assert xc.static_estimate({}, 90.0, 275.0) is None
-
-    def test_profiler_path_gates_cleanly(self, tmp_path):
-        measured, reason = xc.from_profiler_dir(str(tmp_path))
-        assert measured is None and reason
-        # this container has no XPlane parser OR no capture — either
-        # reason is a clean gate, never an exception
-
-
-# ---------------------------------------------------------------------------
 class TestPerAxisAttribution:
-    """The static estimate learns per-axis wire attribution: each
-    collective's replica groups name the mesh axis whose wire it rides,
-    and ``tracing.axis_gbps`` prices each axis at its own rate."""
-
-    COST = {
-        "collective_operand_bytes": 10_000_000,
-        "flops": 1e12,
-        "collective_bytes_per_axis": {"data": 8_000_000,
-                                      "fsdp": 1_000_000,
-                                      "data+fsdp": 1_000_000},
-    }
-
-    def test_axis_rate_joint_is_min_of_parts(self):
-        rates = {"data": 25.0, "fsdp": 100.0}
-        assert xc._axis_rate("data", rates, 90.0) == 25.0
-        assert xc._axis_rate("tp", rates, 90.0) == 90.0  # unconfigured
-        # a joint collective is bounded by its slowest link
-        assert xc._axis_rate("data+fsdp", rates, 90.0) == 25.0
-        assert xc._axis_rate("fsdp+tp", rates, 90.0) == 90.0
-
-    def test_unconfigured_is_numerically_identical(self):
-        """No axis_gbps (or an empty dict) must leave the single-rate
-        arithmetic untouched — same fraction, same comm seconds."""
-        base = xc.static_estimate(self.COST, 90.0, 275.0)
-        for axis_gbps in (None, {}):
-            est = xc.static_estimate(self.COST, 90.0, 275.0,
-                                     axis_gbps=axis_gbps)
-            assert est["exposed_comm_fraction"] == \
-                base["exposed_comm_fraction"]
-            assert est["comm_secs_est"] == base["comm_secs_est"]
-        # the attribution itself still renders (it's free information)
-        assert base["collective_bytes_per_axis"][
-            "data"] == 8_000_000
-
-    def test_per_axis_rates_reprice_the_wire(self):
-        est = xc.static_estimate(self.COST, 90.0, 275.0,
-                                 axis_gbps={"data": 10.0, "fsdp": 100.0})
-        by = est["comm_secs_by_axis"]
-        assert abs(by["data"] - 8e6 / 10e9) < 1e-9
-        assert abs(by["fsdp"] - 1e6 / 100e9) < 1e-9
-        assert abs(by["data+fsdp"] - 1e6 / 10e9) < 1e-9  # min(10, 100)
-        assert abs(est["comm_secs_est"] - sum(by.values())) < 1e-6
+    """Each compiled collective's replica groups name the mesh axis
+    whose wire it rides (``collective_bytes_per_axis`` of the step-cost
+    payload)."""
 
     def test_compiled_attribution_keys_match_mesh_axes(self):
         """End-to-end: a compiled sharded program's collectives land on
@@ -606,7 +540,7 @@ class TestConfigAndZeroOverhead:
 
         t = TelemetryConfig()
         assert t.tracing.enabled is False
-        assert t.tracing.exposed_comm is True
+        assert set(type(t.tracing).model_fields) == {"enabled"}
         assert t.rotate_bytes == 0 and t.rotate_keep == 4
 
     def test_validation(self):
@@ -614,7 +548,7 @@ class TestConfigAndZeroOverhead:
                                                   TelemetryTracingConfig)
 
         with pytest.raises(Exception):
-            TelemetryTracingConfig(ici_gbps=-1)
+            TelemetryTracingConfig(ici_gbps=90.0)  # a key that is gone
         with pytest.raises(Exception):
             TelemetryConfig(rotate_bytes=-1)
         with pytest.raises(Exception):
@@ -698,18 +632,6 @@ class TestTrainingStepTraces:
             assert {"data", "fwd_bwd", "optimizer"} <= names, names
             assert all(c["data"]["trace"] == root["data"]["trace"]
                        for c in children)
-        engine.telemetry.close()
-
-    def test_exposed_comm_estimate_labeled_on_step_root(self, tmp_path):
-        engine, spans = self._run(tmp_path)
-        root = [e for e in spans if e["name"] == "step"][-1]
-        if engine.telemetry._latest_costs:  # cost model exists here
-            assert root["data"].get("source") == "static_estimate"
-            frac = root["data"].get("exposed_comm_fraction")
-            assert frac is not None and 0.0 <= frac <= 1.0
-        est = engine.telemetry.exposed_comm_estimate()
-        if est is not None:
-            assert est["source"] == "static_estimate"
         engine.telemetry.close()
 
     def test_ckpt_io_span(self, tmp_path):

@@ -21,9 +21,10 @@ the emitting wrapper's responsibility):
 - the same two with kind ``"span"``: the *name* argument is checked
   against SPANS
 - tracer call shapes (``telemetry/tracing.py``): ``*tracer.record_span(
-  "name", ...)`` / ``*tracer.span("name", ...)`` / ``*tracer.begin(
-  "name", ...)`` and ``*step_trace.phase("name")`` /
+  "name", ...)`` / ``*tracer.begin("name", ...)`` /
   ``*step_trace.mark("name", ...)``
+- the one bracket: ``*_bracket("phase", span="name", ...)`` — the
+  ``span=`` keyword is the JSONL span name
 """
 
 import ast
@@ -35,8 +36,9 @@ from tools.lint.core import str_const
 EVENTS_MODULE = "deepspeed_tpu/telemetry/events.py"
 
 # dotted-call suffixes whose FIRST argument is a span name
-_TRACER_CALLS = ("tracer.record_span", "tracer.span", "tracer.begin",
-                 "step_trace.phase", "step_trace.mark")
+_TRACER_CALLS = ("tracer.record_span", "tracer.begin", "step_trace.mark")
+# dotted-call suffix of the one bracket: its ``span=`` keyword names the span
+_BRACKET_CALL = "_bracket"
 
 
 def _registry_tuple(ctx: LintContext,
@@ -98,7 +100,12 @@ def _tracer_name_arg(call: ast.Call) -> Optional[ast.expr]:
     """The span-name argument of a tracer call shape, or None when this
     call is not one."""
     d = dotted(call.func)
-    if d is None or not d.endswith(_TRACER_CALLS):
+    if d is None:
+        return None
+    if d.endswith(_BRACKET_CALL):
+        return next((k.value for k in call.keywords if k.arg == "span"),
+                    None)
+    if not d.endswith(_TRACER_CALLS):
         return None
     if call.args:
         return call.args[0]
@@ -121,8 +128,8 @@ class EventKindRegistry(Checker):
         for mod in ctx.modules:
             # raw-source pre-filter: no emit call shape, no parse
             if not mod.mentions(".emit(", "make_event(", ".record_span(",
-                                "tracer.span(", "tracer.begin(",
-                                "step_trace.phase(", "step_trace.mark("):
+                                "tracer.begin(", "step_trace.mark(",
+                                "_bracket("):
                 continue
             for node in mod.nodes():
                 if not isinstance(node, ast.Call):

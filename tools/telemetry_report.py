@@ -262,14 +262,12 @@ _STEP_PHASES = ("data", "fwd", "bwd", "fwd_bwd", "reduce", "optimizer",
 
 def _aggregate_spans(span_events: List[Dict]) -> Dict:
     """Span-trace aggregates: per-name duration histograms (fixed-bucket
-    — constant memory over a long run), the per-step phase table with
-    its exposed-comm column, and waterfall data for the most recent
-    request traces."""
+    — constant memory over a long run), the per-step phase table, and
+    waterfall data for the most recent request traces."""
     if not span_events:
         return {"count": 0}
     by_name: Dict[str, Histogram] = {}
     traces: Dict[str, List[Dict]] = {}
-    measured = []
     for e in span_events:
         d = e.get("data", {})
         dur = max(int(d.get("end_ns", 0)) - int(d.get("start_ns", 0)), 0)
@@ -277,10 +275,6 @@ def _aggregate_spans(span_events: List[Dict]) -> Dict:
         if h is None:  # setdefault would build a throwaway per event
             by_name[e.get("name")] = h = Histogram()
         h.observe(dur)
-        if e.get("name") == "exposed_comm":
-            measured.append({k: v for k, v in d.items()
-                             if k not in SPAN_META})
-            continue
         traces.setdefault(str(d.get("trace")), []).append(e)
     steps, requests = [], []
     for trace, evs in traces.items():
@@ -294,9 +288,7 @@ def _aggregate_spans(span_events: List[Dict]) -> Dict:
         if root["name"] == "step":
             row = {"step": d.get("step"),
                    "total_ms": round(dur_ms, 3),
-                   "phases": {}, "exposed_comm_fraction":
-                   d.get("exposed_comm_fraction"),
-                   "exposed_comm_source": d.get("source")}
+                   "phases": {}}
             for e in evs:
                 if e["name"] in _STEP_PHASES:
                     ph = e["data"]
@@ -332,7 +324,6 @@ def _aggregate_spans(span_events: List[Dict]) -> Dict:
                     for k, h in sorted(by_name.items())},
         "steps": steps[-20:],
         "requests": requests[-5:],
-        "measured_exposed_comm": measured,
     }
 
 
@@ -749,8 +740,7 @@ def _waterfall_lines(req: Dict, pad: str) -> List[str]:
 
 def _span_lines(agg: Dict, markdown: bool) -> List[str]:
     """Trace summary: per-span-name latency histograms, the per-step
-    phase table (exposed-comm column labeled by source), and per-request
-    waterfalls."""
+    phase table, and per-request waterfalls."""
     s = agg.get("spans") or {}
     if not s.get("count"):
         return []
@@ -776,8 +766,7 @@ def _span_lines(agg: Dict, markdown: bool) -> List[str]:
     steps = s.get("steps") or []
     if steps:
         phases = sorted({p for r in steps for p in r["phases"]})
-        head = (["step", "total ms"] + [f"{p} ms" for p in phases]
-                + ["exposed comm"])
+        head = ["step", "total ms"] + [f"{p} ms" for p in phases]
         out.append("")
         if markdown:
             out.append("| " + " | ".join(head) + " |")
@@ -787,22 +776,12 @@ def _span_lines(agg: Dict, markdown: bool) -> List[str]:
                        "(host-side dispatch walltime):")
             out.append(pad + "  ".join(f"{h:>12}" for h in head))
         for r in steps:
-            frac = r.get("exposed_comm_fraction")
-            src = r.get("exposed_comm_source") or ""
-            exp = (f"{frac} ({'est' if 'static' in src else src})"
-                   if frac is not None else "-")
             cells = ([str(r["step"]), f"{r['total_ms']}"]
-                     + [str(r["phases"].get(p, "-")) for p in phases]
-                     + [exp])
+                     + [str(r["phases"].get(p, "-")) for p in phases])
             if markdown:
                 out.append("| " + " | ".join(cells) + " |")
             else:
                 out.append(pad + "  ".join(f"{c:>12}" for c in cells))
-    for m in (s.get("measured_exposed_comm") or [])[-3:]:
-        out.append(f"{pad}measured exposed comm (profiled window): "
-                   f"{m.get('exposed_comm_fraction')} "
-                   f"(comm {m.get('comm_ns')} ns / busy "
-                   f"{m.get('busy_ns')} ns)")
     for req in (s.get("requests") or [])[-3:]:
         out.append("")
         head = (f"request {req.get('request_id') or req['trace']}: "

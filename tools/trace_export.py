@@ -13,8 +13,8 @@ Layout: each TRACE becomes one Perfetto "process" (named by its trace
 id and root span), and within it each span lands on the "thread" of its
 ``replica``/``rank`` attribute (so a failover renders as the attempt
 subtrees side by side on two replica lanes). Span attrs ride in
-``args`` — click any slice to see request ids, token counts, exposed
-comm fractions. Exit codes: 0 = wrote a trace, 1 = no span events found
+``args`` — click any slice to see request ids and token counts.
+Exit codes: 0 = wrote a trace, 1 = no span events found
 (enable ``telemetry.tracing``), 2 = bad input path.
 """
 

@@ -1096,7 +1096,7 @@ def _tp_decode_wire_bytes(ctx):
         psh = jax.tree_util.tree_map(
             lambda s: NamedSharding(mesh, s if s is not None else P()),
             specs, is_leaf=lambda s: s is None or isinstance(s, P))
-        csh = decode_cache_specs(cache_abs, mesh)
+        csh = decode_cache_specs(cache_abs, mesh, heads=cfg.n_head)
 
         def step(p, c, tok, tables, lengths):
             o, vars_ = dmodel.apply(

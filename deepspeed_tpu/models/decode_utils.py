@@ -103,12 +103,13 @@ def paged_positions(lengths, T: int):
     return lengths[:, None] + jnp.arange(T)[None]
 
 
-def paged_write_rows(block_tables, positions, num_valid, block_size: int):
-    """[B, T] flattened pool rows for a paged step's KV writes.
+def paged_write_slots(block_tables, positions, num_valid, block_size: int):
+    """``(block, offset)``, each ``[B, T]``: where a paged step's KV rows
+    land in the pool.
 
     Real tokens (``t < num_valid[b]``) map through the row's block table:
-    ``table[b, pos // bs] * bs + pos % bs``. The padded tail of a
-    bucketed prefill (and idle serving slots, ``num_valid == 0``) routes
+    block ``table[b, pos // bs]``, offset ``pos % bs``. The padded tail of
+    a bucketed prefill (and idle serving slots, ``num_valid == 0``) routes
     to the reserved garbage block 0 instead — pads must never overwrite
     another sequence's blocks, and clamping them onto real rows would
     corrupt this sequence's own prefix."""
@@ -116,6 +117,6 @@ def paged_write_rows(block_tables, positions, num_valid, block_size: int):
     mb = block_tables.shape[-1]
     blk = jnp.clip(positions // block_size, 0, mb - 1)
     off = positions % block_size
-    rows = jnp.take_along_axis(block_tables, blk, axis=1) * block_size + off
     valid = jnp.arange(T)[None] < num_valid[:, None]
-    return jnp.where(valid, rows, off)
+    return jnp.where(valid, jnp.take_along_axis(block_tables, blk, axis=1),
+                     0), off

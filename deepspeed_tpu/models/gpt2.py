@@ -22,7 +22,7 @@ import numpy as np
 from deepspeed_tpu.models.decode_utils import (cache_attn_mask,
                                                decode_positions,
                                                pad_lengths, paged_positions,
-                                               paged_write_rows,
+                                               paged_write_slots,
                                                row_positions)
 from deepspeed_tpu.ops.attention import attention
 from deepspeed_tpu.models.remat_utils import offload_policy, saved_block_input
@@ -152,10 +152,15 @@ class GPT2Config:
                          kv_dtype: str = ""):
         """Serving variant: decode mode whose KV cache is a shared block
         pool (block 0 reserved as the garbage sink — see
-        ``ops.decode_attention.GARBAGE_BLOCK``). Mutually exclusive with
-        ``padded``: ragged prompts are the block table's job here.
-        ``kv_dtype="int8"`` stores the pool quantized per row with a
-        scale side pool (the serving ``kv_cache_dtype`` knob)."""
+        ``ops.decode_attention.GARBAGE_BLOCK``). The pool is ONE pair of
+        ``cache`` variables owned by the block stack,
+        ``transformer/key_pool`` and ``transformer/value_pool`` of shape
+        ``[n_layer, num_blocks, block_size, n_head * head_dim]``, which
+        every layer writes and reads in place (``_paged_pool_vars``).
+        Mutually exclusive with ``padded``: ragged prompts are the block
+        table's job here. ``kv_dtype="int8"`` stores the pool quantized
+        per row with ``key_scale`` / ``value_scale`` side pools of the
+        same leading shape (the serving ``kv_cache_dtype`` knob)."""
         return dataclasses.replace(self, decode=True, dropout=0.0,
                                    padded=False, paged=True,
                                    paged_num_blocks=int(num_blocks),
@@ -290,55 +295,35 @@ class CausalSelfAttention(nn.Module):
     # attribute so each unrolled layer compiles its own mask shape
     window: int = 0
 
-    def _paged_kv_attend(self, q4, k, v, paging, B, T, head_dim):
-        """Paged decode (serving): scatter this step's KV into the shared
-        block pool, then attend — block-table gather (Pallas kernel on
-        TPU, dense gather oracle elsewhere) for decode steps; prefill
-        (``paging["prefill"]``, rows fresh at length 0) falls through to
-        the standard causal path over its own keys, the same program the
-        append-cache prefill compiles. Returns ``(q4, k4, v4, y,
-        cached_attn)``; ``y is None`` on the prefill fall-through."""
+    def _paged_kv_attend(self, q4, k, v, paging, kv, B, T, head_dim):
+        """Paged decode (serving): scatter this step's KV rows in place
+        into this layer of the stacked block pool, then attend — block-
+        table gather (Pallas kernel on TPU, dense gather oracle elsewhere)
+        for decode steps; prefill (``paging["prefill"]``, rows fresh at
+        length 0) falls through to the standard causal path over its own
+        keys, the same program the append-cache prefill compiles. ``kv``
+        is ``(pools, layer)`` as the block stack threads it (see
+        :func:`_paged_pool_vars`); the updated pools go back the same way.
+        Returns ``(q4, k4, v4, y, cached_attn, pools)``; ``y is None`` on
+        the prefill fall-through."""
         cfg = self.config
         if paging is None:
             raise ValueError(
                 "paged decode needs the `paging` call argument: "
                 '{"block_tables": [B, MB] int32, "lengths": [B] int32, '
                 '"num_valid": [B] int32, "prefill": bool}')
-        if cfg.padded:
-            raise ValueError("paged and padded decode are mutually "
-                             "exclusive: ragged prompts are the block "
-                             "table's job in paged mode")
-        nb, bs = cfg.paged_num_blocks, cfg.paged_block_size
-        if nb <= 1 or bs <= 0:
+        if kv is None:
             raise ValueError(
-                f"paged decode needs paged_num_blocks > 1 (got {nb}; "
-                f"block 0 is the reserved garbage sink) and "
-                f"paged_block_size > 0 (got {bs})")
+                "paged decode runs under the block stack (ScanBlocks / "
+                "LoopBlocks), which owns the KV pool and threads it "
+                "through the layers as `kv`")
+        pools, layer = kv
+        bs = cfg.paged_block_size
         tables = paging["block_tables"]
         lengths = paging["lengths"]
-        num_valid = paging["num_valid"]
-        if cfg.paged_kv_dtype not in ("", "int8"):
-            raise ValueError(f"paged_kv_dtype must be '' or 'int8', got "
-                             f"{cfg.paged_kv_dtype!r}")
-        quant = cfg.paged_kv_dtype == "int8"
+        quant = "key_scale" in pools
         k4 = k.reshape(B, T, cfg.n_head, head_dim)
         v4 = v.reshape(B, T, cfg.n_head, head_dim)
-        pool_shape = (nb, bs, cfg.n_head, head_dim)
-        pool_dtype = jnp.int8 if quant else cfg.dtype
-        ck = self.variable("cache", "key_pool", jnp.zeros, pool_shape,
-                           pool_dtype)
-        cv = self.variable("cache", "value_pool", jnp.zeros, pool_shape,
-                           pool_dtype)
-        if quant:
-            # per-row scale side pools (one f32 scale per token x head),
-            # scattered through the SAME flattened row indices as the
-            # int8 pools so the block table stays the single source of
-            # placement truth
-            scale_shape = (nb, bs, cfg.n_head, 1)
-            cks = self.variable("cache", "key_scale", jnp.zeros,
-                                scale_shape, jnp.float32)
-            cvs = self.variable("cache", "value_scale", jnp.zeros,
-                                scale_shape, jnp.float32)
         pos = paged_positions(lengths, T)  # [B, T] logical slots
         if cfg.position_embedding == "rotary":
             # rotate by absolute position BEFORE pooling, mirroring the
@@ -347,48 +332,44 @@ class CausalSelfAttention(nn.Module):
                               cfg.rotary_interleaved)
             k4 = apply_rotary(k4, pos, cfg.rotary_dim, cfg.rope_theta,
                               cfg.rotary_interleaved)
-        rows = paged_write_rows(tables, pos, num_valid, bs)
-        flat = (nb * bs, cfg.n_head, head_dim)
+        rows = {"key_pool": k4, "value_pool": v4}
         if quant:
             from deepspeed_tpu.ops.quantizer import quantize_rowwise
 
-            kq, ks = quantize_rowwise(k4)   # int8 [B,T,H,D], f32 [B,T,H,1]
-            vq, vs = quantize_rowwise(v4)
-            sflat = (nb * bs, cfg.n_head, 1)
-            ck.value = ck.value.reshape(flat).at[rows.reshape(-1)].set(
-                kq.reshape(B * T, cfg.n_head, head_dim)).reshape(pool_shape)
-            cv.value = cv.value.reshape(flat).at[rows.reshape(-1)].set(
-                vq.reshape(B * T, cfg.n_head, head_dim)).reshape(pool_shape)
-            cks.value = cks.value.reshape(sflat).at[rows.reshape(-1)].set(
-                ks.reshape(B * T, cfg.n_head, 1)).reshape(scale_shape)
-            cvs.value = cvs.value.reshape(sflat).at[rows.reshape(-1)].set(
-                vs.reshape(B * T, cfg.n_head, 1)).reshape(scale_shape)
-        else:
-            ck.value = ck.value.reshape(flat).at[rows.reshape(-1)].set(
-                k4.reshape(B * T, cfg.n_head, head_dim)).reshape(pool_shape)
-            cv.value = cv.value.reshape(flat).at[rows.reshape(-1)].set(
-                v4.reshape(B * T, cfg.n_head, head_dim)).reshape(pool_shape)
+            # per-row scale side pools (one f32 scale per token x head)
+            # ride the SAME (layer, block, offset) address as the int8
+            # pools, so the block table stays the single source of
+            # placement truth
+            rows["key_pool"], rows["key_scale"] = quantize_rowwise(k4)
+            rows["value_pool"], rows["value_scale"] = quantize_rowwise(v4)
+        blk, off = paged_write_slots(tables, pos, paging["num_valid"], bs)
+        # B*T rows of H*D lanes (H for the scales) written where they lie:
+        # no layer is sliced out of the pool and nothing is re-stacked
+        pools = {name: pool.at[layer, blk, off].set(_pool_rows(
+            rows[name].reshape(B, T, -1), pool.shape[-1]))
+            for name, pool in pools.items()}
         if paging.get("prefill"):
-            return q4, k4, v4, None, False
+            return q4, k4, v4, None, False, pools
         from deepspeed_tpu.ops.attention import use_decode_kernel
 
         alibi = cfg.position_embedding == "alibi"
         if use_decode_kernel() and not alibi and not self.window:
+            # heads partitioned over tp; per-shard KV pools
             if quant:
                 from deepspeed_tpu.ops.decode_attention import (
                     decode_attention_paged_int8_tp)
 
                 y4 = decode_attention_paged_int8_tp(
-                    q4, ck.value, cv.value, cks.value, cvs.value, tables,
-                    lengths, softmax_scale=cfg.attn_scale)
+                    q4, pools["key_pool"], pools["value_pool"],
+                    pools["key_scale"], pools["value_scale"], tables,
+                    lengths, layer, softmax_scale=cfg.attn_scale)
             else:
                 from deepspeed_tpu.ops.decode_attention import (
                     decode_attention_paged_tp)
 
-                # heads partitioned over tp; per-shard KV pools
-                y4 = decode_attention_paged_tp(q4, ck.value, cv.value,
-                                               tables, lengths,
-                                               softmax_scale=cfg.attn_scale)
+                y4 = decode_attention_paged_tp(
+                    q4, pools["key_pool"], pools["value_pool"], tables,
+                    lengths, layer, softmax_scale=cfg.attn_scale)
             y = y4.transpose(0, 2, 1, 3)
         else:
             from deepspeed_tpu.ops.decode_attention import (
@@ -396,28 +377,25 @@ class CausalSelfAttention(nn.Module):
 
             S = tables.shape[-1] * bs
             if quant:
-                kd = gather_paged_cache_int8(
-                    ck.value, cks.value, tables,
-                    cfg.dtype).transpose(0, 2, 1, 3)
-                vd = gather_paged_cache_int8(
-                    cv.value, cvs.value, tables,
-                    cfg.dtype).transpose(0, 2, 1, 3)
+                kd, vd = (gather_paged_cache_int8(
+                    pools[f"{n}_pool"], pools[f"{n}_scale"], tables, layer,
+                    cfg.n_head, cfg.dtype) for n in ("key", "value"))
             else:
-                kd = gather_paged_cache(ck.value,
-                                        tables).transpose(0, 2, 1, 3)
-                vd = gather_paged_cache(cv.value,
-                                        tables).transpose(0, 2, 1, 3)
+                kd, vd = (gather_paged_cache(
+                    pools[f"{n}_pool"], tables, layer, cfg.n_head)
+                    for n in ("key", "value"))
             # per-row lengths: each serving slot is at its own position
             mask = cache_attn_mask(S, lengths, T, window=self.window)
             bias = _alibi_bias(cfg, jnp.arange(S)) if alibi else None
-            y = attention(q4.transpose(0, 2, 1, 3), kd, vd, mask=mask,
-                          bias=bias, causal=False,
+            y = attention(q4.transpose(0, 2, 1, 3),
+                          kd.transpose(0, 2, 1, 3), vd.transpose(0, 2, 1, 3),
+                          mask=mask, bias=bias, causal=False,
                           softmax_scale=cfg.attn_scale, use_flash=False)
-        return q4, k4, v4, y, True
+        return q4, k4, v4, y, True, pools
 
     @nn.compact
     def __call__(self, x, deterministic=True, attention_mask=None,
-                 paging=None):
+                 paging=None, kv=None):
         cfg = self.config
         B, T, C = x.shape
         head_dim = cfg.n_embd // cfg.n_head
@@ -441,8 +419,8 @@ class CausalSelfAttention(nn.Module):
         if cfg.decode and cfg.paged:
             # serving block-pool cache; paged prefill falls through to
             # the standard causal path below (cached_attn stays False)
-            q4, k4, v4, y, cached_attn = self._paged_kv_attend(
-                q4, k, v, paging, B, T, head_dim)
+            q4, k4, v4, y, cached_attn, pools = self._paged_kv_attend(
+                q4, k, v, paging, kv, B, T, head_dim)
         elif cfg.decode:
             # KV cache: [B, n_positions, H, D] append buffer (the TPU-native
             # form of the reference's softmax_context KV workspace,
@@ -569,7 +547,7 @@ class CausalSelfAttention(nn.Module):
                      else cfg.attn_out_bias, name="c_proj")(y)
         if cfg.dropout > 0:
             y = nn.Dropout(cfg.dropout)(y, deterministic=deterministic)
-        return y
+        return y if kv is None else (y, pools)
 
 
 class MLP(nn.Module):
@@ -596,7 +574,9 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, deterministic=True, pld_theta=None, layer_frac=0.0,
-                 attention_mask=None, paging=None):
+                 attention_mask=None, paging=None, kv=None):
+        """``kv`` is the serving KV pool on its way through the layers,
+        ``(pools, layer)``; with it the block returns ``(x, pools)``."""
         cfg = self.config
         pld_on = cfg.pld and pld_theta is not None and not deterministic
         if pld_on:
@@ -625,15 +605,20 @@ class Block(nn.Module):
             attn_out = CausalSelfAttention(cfg, window=self.window,
                                            name="attn")(
                 h1, deterministic=deterministic,
-                attention_mask=attention_mask, paging=paging)
+                attention_mask=attention_mask, paging=paging, kv=kv)
+            if kv is not None:
+                attn_out, pools = attn_out
             mlp_out = MLP(cfg, name="mlp")(h2, deterministic=deterministic)
             if pld_on:
                 attn_out, mlp_out = _gate(attn_out), _gate(mlp_out)
-            return x + attn_out + mlp_out
+            x = x + attn_out + mlp_out
+            return x if kv is None else (x, pools)
         attn_out = CausalSelfAttention(cfg, window=self.window,
                                        name="attn")(
             ln_1(x), deterministic=deterministic,
-            attention_mask=attention_mask, paging=paging)
+            attention_mask=attention_mask, paging=paging, kv=kv)
+        if kv is not None:
+            attn_out, pools = attn_out
         if pld_on:
             attn_out = _gate(attn_out)
         x = x + attn_out
@@ -643,21 +628,72 @@ class Block(nn.Module):
         if pld_on:
             mlp_out = _gate(mlp_out)
         x = x + mlp_out
-        return x
+        return x if kv is None else (x, pools)
+
+
+def _pool_rows(rows, lanes: int):
+    """``[B, T, n]`` rows widened with zero lanes to a pool's row (the
+    scale rows: a lane a head, padded to whole registers)."""
+    return jnp.pad(rows, ((0, 0), (0, 0), (0, lanes - rows.shape[-1])))
+
+
+def _paged_pool_vars(module, cfg):
+    """The serving KV pool, declared ONCE by the block stack (``None``
+    unless this is a paged-decode model): ``cache`` variables of the one
+    resident shape ``[n_layer, blocks, block_size, lanes]`` —
+    ``key_pool`` / ``value_pool`` with ``lanes = n_head * head_dim`` and,
+    for ``paged_kv_dtype="int8"``, the f32 ``key_scale`` / ``value_scale``
+    side pools with ``scale_lanes(n_head)`` lanes
+    (``ops/decode_attention.py``, "THE POOL'S ONE SHAPE"). The stack
+    hands ``(pools, layer)`` down to each layer's attention and takes the
+    updated pools back — a scan CARRIES them, so the compiled program
+    writes and reads one buffer in place where a scanned ``cache``
+    collection would slice a layer out and re-stack the pool every
+    step."""
+    if not (cfg.decode and cfg.paged):
+        return None
+    if cfg.padded:
+        raise ValueError("paged and padded decode are mutually "
+                         "exclusive: ragged prompts are the block "
+                         "table's job in paged mode")
+    nb, bs = cfg.paged_num_blocks, cfg.paged_block_size
+    if nb <= 1 or bs <= 0:
+        raise ValueError(
+            f"paged decode needs paged_num_blocks > 1 (got {nb}; "
+            f"block 0 is the reserved garbage sink) and "
+            f"paged_block_size > 0 (got {bs})")
+    if cfg.paged_kv_dtype not in ("", "int8"):
+        raise ValueError(f"paged_kv_dtype must be '' or 'int8', got "
+                         f"{cfg.paged_kv_dtype!r}")
+    quant = cfg.paged_kv_dtype == "int8"
+    leaves = {name: (cfg.n_embd, jnp.int8 if quant else cfg.dtype)
+              for name in ("key_pool", "value_pool")}
+    if quant:
+        from deepspeed_tpu.ops.decode_attention import scale_lanes
+
+        leaves.update({name: (scale_lanes(cfg.n_head), jnp.float32)
+                       for name in ("key_scale", "value_scale")})
+    return {name: module.variable("cache", name, jnp.zeros,
+                                  (cfg.n_layer, nb, bs, lanes), dtype)
+            for name, (lanes, dtype) in leaves.items()}
 
 
 class _ScanBody(nn.Module):
     config: GPT2Config
 
     @nn.compact
-    def __call__(self, x, deterministic, pld_theta, layer_frac,
-                 attention_mask, paging):
+    def __call__(self, carry, deterministic, pld_theta, layer_frac,
+                 attention_mask, paging, layer):
+        """``carry`` is ``x``, or ``(x, pools)`` when ``layer`` (the
+        scanned-in layer index of a paged-decode stack) is given."""
         cfg = self.config
+        x, pools = (carry, None) if layer is None else carry
         if cfg.remat:
             x = saved_block_input(x, cfg)
-        x = _remat_block(cfg)(cfg, name="block")(
-            x, deterministic, pld_theta, layer_frac, attention_mask, paging)
-        return x, None
+        out = _remat_block(cfg)(cfg, name="block")(
+            x, deterministic, pld_theta, layer_frac, attention_mask, paging,
+            None if layer is None else (pools, layer))
+        return out, None
 
 
 class ScanBlocks(nn.Module):
@@ -671,12 +707,13 @@ class ScanBlocks(nn.Module):
     def __call__(self, x, deterministic=True, pld_theta=None,
                  attention_mask=None, paging=None):
         cfg = self.config
+        pools = _paged_pool_vars(self, cfg)
         ScannedBlock = nn.scan(
             _ScanBody,
             variable_axes={"params": 0, "cache": 0},
             split_rngs={"params": True, "dropout": True, "pld": True},
             in_axes=(nn.broadcast, nn.broadcast, 0, nn.broadcast,
-                     nn.broadcast),
+                     nn.broadcast, nn.broadcast if pools is None else 0),
             length=cfg.n_layer,
             metadata_params={nn.meta.PARTITION_NAME: "layers"},
         )
@@ -684,8 +721,22 @@ class ScanBlocks(nn.Module):
         # of L keeps with prob 1 - i/L*(1-theta), i = 1..L
         fracs = (jnp.arange(cfg.n_layer, dtype=jnp.float32) + 1.0) / max(
             1, cfg.n_layer)
-        x, _ = ScannedBlock(cfg, name="h")(x, deterministic, pld_theta, fracs,
-                                           attention_mask, paging)
+        if pools is None:
+            carry, layers = x, None
+        else:
+            # the pool rides the scan as CARRY beside x and the layer index
+            # is scanned in like ``fracs``: every layer scatters into and
+            # reads from the one stacked buffer
+            carry = (x, {n: v.value for n, v in pools.items()})
+            layers = jnp.arange(cfg.n_layer, dtype=jnp.int32)
+        carry, _ = ScannedBlock(cfg, name="h")(
+            carry, deterministic, pld_theta, fracs, attention_mask, paging,
+            layers)
+        if pools is None:
+            return carry
+        x, new = carry
+        for n, v in pools.items():
+            v.value = new[n]
         return x
 
 
@@ -698,12 +749,17 @@ class LoopBlocks(nn.Module):
         cfg = self.config
         block_cls = _remat_block(cfg)
         windows = cfg.attention_windows or (0,) * cfg.n_layer
+        pools = _paged_pool_vars(self, cfg)
+        vals = pools and {n: v.value for n, v in pools.items()}
         for i in range(cfg.n_layer):
             if cfg.remat:
                 x = saved_block_input(x, cfg)
-            x = block_cls(cfg, window=windows[i], name=f"h_{i}")(
+            out = block_cls(cfg, window=windows[i], name=f"h_{i}")(
                 x, deterministic, pld_theta, (i + 1) / max(1, cfg.n_layer),
-                attention_mask, paging)
+                attention_mask, paging, pools and (vals, i))
+            x, vals = out if pools else (out, None)
+        for n, v in (pools or {}).items():
+            v.value = vals[n]
         return x
 
 

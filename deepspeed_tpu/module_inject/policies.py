@@ -248,27 +248,33 @@ def specs_from_policy(policy: TPPolicy, params_abstract, mesh,
     return jax.tree_util.tree_unflatten(treedef, specs)
 
 
-def decode_cache_specs(cache_abstract, mesh, axis: str = AXIS_TP):
+def decode_cache_specs(cache_abstract, mesh, axis: str = AXIS_TP,
+                       heads: int = 0):
     """PartitionSpecs for a decode KV cache under tensor parallelism.
 
-    The cache is the decode working set the TP layout must keep sharded:
-    ``cached_key``/``cached_value`` leaves carry the layout
-    ``[..., positions, heads, head_dim]`` (models/gpt2.py decode cache,
-    optionally with a leading stacked-layer axis), and the serving block
-    pools (``key_pool``/``value_pool`` ``[..., blocks, block_size,
-    heads, head_dim]`` plus their int8 ``key_scale``/``value_scale``
-    side pools ``[..., heads, 1]``) carry heads at the same -2 slot —
-    the HEAD axis follows the attention heads the QKV column-split
-    distributed, so it shards over ``axis`` exactly like the reference
-    splits its inference KV workspace per TP rank
-    (``inference_context.h`` workspace carved per ``mp_size``): each tp
-    shard owns a per-shard KV pool. Scalars/per-row bookkeeping
-    (``cache_index``, ``position``, ``pad_len``) replicate, as do
-    head-indivisible caches.
+    The cache is the decode working set the TP layout must keep sharded.
+    ``cached_key``/``cached_value`` leaves (the append cache) carry the
+    layout ``[..., positions, heads, head_dim]`` and shard their head
+    axis (-2). The serving block pools keep ONE shape, ``[layers, blocks,
+    block_size, lanes]`` (``ops/decode_attention.py``): ``key_pool`` /
+    ``value_pool`` rows are ``heads * head_dim`` lanes, so sharding the
+    LANE axis over ``axis`` gives each tp shard ``heads / tp`` contiguous
+    heads of every pool row — the heads the QKV column-split distributed,
+    exactly like the reference splits its inference KV workspace per TP
+    rank (``inference_context.h`` workspace carved per ``mp_size``): each
+    tp shard owns a per-shard KV pool. A pool row does not say how many
+    heads it holds, so the caller passes ``heads`` (the model's
+    ``n_head``); pools replicate without it. The int8 ``key_scale`` /
+    ``value_scale`` side pools (a lane a head, padded to whole registers;
+    a sixteenth of the int8 rows' bytes at head size 64) replicate: the
+    kernel finds a shard's heads in the whole row.
+    Scalars/per-row bookkeeping (``cache_index``, ``position``,
+    ``pad_len``) replicate, as do head-indivisible caches.
     """
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    from deepspeed_tpu.ops.decode_attention import POOL_LANE_AXIS
     from deepspeed_tpu.parallel.topology import resolve_axis_name
     from deepspeed_tpu.utils.pytree import flatten_with_path_strings
 
@@ -278,12 +284,15 @@ def decode_cache_specs(cache_abstract, mesh, axis: str = AXIS_TP):
 
     def spec(path, leaf):
         leaf_name = path.rsplit("/", 1)[-1]
-        if leaf_name in ("cached_key", "cached_value", "key_pool",
-                         "value_pool", "key_scale", "value_scale") \
-                and tp > 1 and len(leaf.shape) >= 2 \
-                and leaf.shape[-2] % tp == 0:
+        if leaf_name in ("cached_key", "cached_value"):
+            head_axis, n = len(leaf.shape) - 2, leaf.shape[-2]
+        elif leaf_name in ("key_pool", "value_pool"):
+            head_axis, n = POOL_LANE_AXIS, heads
+        else:
+            return P()
+        if tp > 1 and n and n % tp == 0:
             parts = [None] * len(leaf.shape)
-            parts[-2] = axis  # heads
+            parts[head_axis] = axis
             return P(*parts)
         return P()
 

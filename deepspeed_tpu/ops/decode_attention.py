@@ -143,12 +143,29 @@ def decode_attention(q, k_cache, v_cache, cache_index, softmax_scale=None,
 
 
 # ---------------------------------------------------------------------------
-# Paged variant: the KV cache is a SHARED block pool ([num_blocks,
-# block_size, H, D]) and each sequence owns a block table mapping its
-# logical blocks to pool blocks — the serving layer's continuous-batching
-# cache (vLLM-style paging, TPU-native via scalar-prefetch block DMA).
-# The dense append-cache kernel above is kept untouched: it serves the
-# legacy generate() path and is the correctness oracle for this one.
+# Paged variant: the KV cache is ONE resident, layer-stacked block pool and
+# each sequence owns a block table mapping its logical blocks to pool
+# blocks — the serving layer's continuous-batching cache (vLLM-style
+# paging, TPU-native via scalar-prefetch block DMA). The dense append-cache
+# kernel above is kept untouched: it serves the legacy generate() path and
+# is the correctness oracle for this one.
+#
+# THE POOL'S ONE SHAPE. Every pool leaf is ``[layers, blocks, block_size,
+# lanes]``: ``lanes = H * D`` for the key/value pools (head ``h`` is lanes
+# ``[h*D, (h+1)*D)``); the int8 scale side pools hold head ``h``'s scale
+# in lane ``h`` of ``scale_lanes(H)`` lanes, whole 128-lane registers. A
+# row of ``H * D`` lanes tiles the TPU's (8, 128) registers with a few
+# percent of padding and a whole-register row with none, so the array's
+# default device layout is row-major — the layout the kernel's block DMA
+# reads — where ``[..., H, D]`` pads H to 8 sublanes and D to 128 lanes
+# (and ``[..., bs, H]`` of a few heads puts the BLOCK axis minor-most) and
+# makes XLA convert the whole pool around every call. The kernel is handed
+# the STACKED pool and the layer index as a scalar-prefetch operand and
+# addresses ``(layer, table[b, j])`` itself, so no program ever slices a
+# layer out: writers scatter rows in place (``pool.at[layer, block,
+# offset]``) and this kernel reads in place. POOL_BLOCK_AXIS /
+# POOL_LANE_AXIS are the one spelling of "which axis" that the model, the
+# serving programs (cow, migrate, export) and the tp sharding rule share.
 #
 # MULTI-QUERY-ROW (verify) CONTRACT: the kernel is written over T_q query
 # rows per sequence, not 1 — query row r of sequence b sits at absolute
@@ -165,35 +182,57 @@ def decode_attention(q, k_cache, v_cache, cache_index, softmax_scale=None,
 # (static shapes — the zero-retrace pin), never read back.
 # ---------------------------------------------------------------------------
 
+POOL_BLOCK_AXIS = 1
+POOL_LANE_AXIS = 3
 
-def gather_paged_cache(pool, block_tables):
-    """Assemble the dense ``[B, MB*bs, H, D]`` logical window from pool
-    blocks — the XLA fallback (CPU serving, alibi/window models) and the
-    correctness oracle the paged kernel is tested against. Gathered rows
-    land at their logical positions; table entries past a sequence's
-    allocation point at the garbage block and are masked by the caller's
-    length mask."""
+
+def scale_lanes(heads: int) -> int:
+    """Lanes of an int8 scale pool row: one lane a head, padded to whole
+    128-lane registers (a row of a few dozen lanes would make the block
+    axis the minor-most of the default device layout)."""
+    return -(-heads // 128) * 128
+
+
+def gather_paged_cache(pool, block_tables, layer, heads):
+    """Assemble the dense ``[B, MB*bs, H, D]`` logical window of one layer
+    from pool blocks — the XLA fallback (CPU serving, alibi/window models)
+    and the correctness oracle the paged kernel is tested against.
+    ``pool`` is the stacked ``[L, nb, bs, H*D]`` pool. Gathered rows land
+    at their logical positions; table entries past a sequence's allocation
+    point at the garbage block and are masked by the caller's length
+    mask."""
     b, mb = block_tables.shape
-    nb, bs, heads, d = pool.shape
-    return pool[block_tables].reshape(b, mb * bs, heads, d)
+    _, _, bs, lanes = pool.shape
+    return pool[layer, block_tables].reshape(b, mb * bs, heads,
+                                             lanes // heads)
 
 
-def gather_paged_cache_int8(pool, scales, block_tables, dtype=jnp.float32):
-    """Dense-dequantize an int8 pool through a block table: the XLA
-    fallback (CPU serving) and the correctness oracle for the int8 paged
-    kernel. ``pool`` is ``[nb, bs, H, D]`` int8, ``scales`` the
-    ``[nb, bs, H, 1]`` f32 side pool written by the same
-    ``paged_write_rows`` scatter. Returns the ``[B, MB*bs, H, D]``
-    logical window in ``dtype``."""
-    b, mb = block_tables.shape
-    nb, bs, heads, d = pool.shape
-    q = pool[block_tables].reshape(b, mb * bs, heads, d).astype(jnp.float32)
-    s = scales[block_tables].reshape(b, mb * bs, heads, 1)
-    return (q * s).astype(dtype)
+def gather_paged_cache_int8(pool, scales, block_tables, layer, heads,
+                            dtype=jnp.float32):
+    """Dense-dequantize one layer of an int8 pool through a block table:
+    the XLA fallback (CPU serving) and the correctness oracle for the int8
+    paged kernel. ``pool`` is ``[L, nb, bs, H*D]`` int8, ``scales`` the
+    ``[L, nb, bs, scale_lanes(H)]`` f32 side pool written by the same
+    scatter. Returns the ``[B, MB*bs, H, D]`` logical window in
+    ``dtype``."""
+    q = gather_paged_cache(pool, block_tables, layer, heads)
+    s = gather_paged_cache(scales[..., :heads], block_tables, layer, heads)
+    return (q.astype(jnp.float32) * s).astype(dtype)
 
 
-def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
-                  l_scr, acc_scr, *, scale, bs, tq, heads, d, num_kb):
+def _heads_of(block, heads, d):
+    """``[rows, H*d]`` lanes -> ``[H, rows, d]``: each head's lanes sliced
+    out statically (the pool row is lane-dense; no relayout of the pool
+    ever happens outside this register-level shuffle)."""
+    return jnp.stack([block[:, h * d:(h + 1) * d] for h in range(heads)])
+
+
+def _paged_kernel(tables_ref, lens_ref, at_ref, q_ref, k_ref, v_ref,
+                  *rest, scale, bs, tq, heads, d, num_kb, quant, head_shard):
+    if quant:
+        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+    else:
+        o_ref, m_scr, l_scr, acc_scr = rest
     bi = pl.program_id(0)
     ji = pl.program_id(1)
     idx = lens_ref[bi]  # this row's valid length BEFORE the step
@@ -210,8 +249,19 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
     @pl.when(ji * bs < idx + tq)
     def _body():
         q = q_ref[...].reshape(tq, heads, d).transpose(1, 0, 2)   # [H,tq,d]
-        k = k_ref[...].reshape(bs, heads, d).transpose(1, 0, 2)   # [H,bs,d]
-        v = v_ref[...].reshape(bs, heads, d).transpose(1, 0, 2)
+        k = _heads_of(k_ref[...], heads, d)                       # [H,bs,d]
+        v = _heads_of(v_ref[...], heads, d)
+        if quant:
+            # dequantize in-register: int8 rows x the side-pool scales
+            ks, vs = ks_ref[...], vs_ref[...]
+            if head_shard:
+                # a tp shard holds heads [h0, h0 + heads) of the (whole,
+                # replicated) scale row: bring lane h0 to lane 0
+                back = ks.shape[1] - at_ref[1]
+                ks, vs = pltpu.roll(ks, back, 1), pltpu.roll(vs, back, 1)
+            q = q.astype(jnp.float32)
+            k = k.astype(jnp.float32) * _heads_of(ks, heads, 1)
+            v = v.astype(jnp.float32) * _heads_of(vs, heads, 1)
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale            # [H,tq,bs]
@@ -238,9 +288,74 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
             .astype(o_ref.dtype)
 
 
+def _paged_call(q, pools, block_tables, lengths, layer, softmax_scale,
+                head0=None):
+    """The one ``pallas_call`` behind both paged entry points: ``pools`` is
+    ``(k_pool, v_pool)`` or ``(k_pool, v_pool, k_scale, v_scale)``.
+    ``head0`` (tp shards only) is the first head this call's ``q`` and K/V
+    lanes hold, for the scale rows, which stay whole."""
+    b, tq, heads, d = q.shape
+    if tq < 1:
+        raise ValueError(f"need at least one query row per sequence, "
+                         f"got T_q={tq}")
+    _, _, bs, lanes = pools[0].shape
+    if lanes != heads * d:
+        raise ValueError(f"pool rows hold {lanes} lanes, the query needs "
+                         f"heads x dim = {heads} x {d}")
+    quant = len(pools) == 4
+    if quant and (pools[2].shape[:3] != pools[0].shape[:3]
+                  or pools[2].shape[3] % 128 or pools[2].shape[3] < heads):
+        raise ValueError(
+            f"scale pool shape {pools[2].shape} is not "
+            f"{pools[0].shape[:3]} + (whole 128-lane registers holding "
+            f"{heads} heads,): one f32 scale per pool row x head")
+    mb = block_tables.shape[-1]
+    scale = softmax_scale if softmax_scale is not None else d ** -0.5
+
+    def pool_spec(width):
+        # (layer, table[b, j]) picked by the DMA itself: the stacked pool
+        # is an operand as it lies in HBM, never a slice of it
+        return pl.BlockSpec((None, None, bs, width),
+                            lambda bi, ji, tab, ln, at:
+                            (at[0], tab[bi, ji], 0, 0))
+
+    q_spec = pl.BlockSpec((1, tq, heads, d),
+                          lambda bi, ji, tab, ln, at: (bi, 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, mb),
+        in_specs=[q_spec] + [pool_spec(p.shape[POOL_LANE_AXIS])
+                             for p in pools],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((heads, tq, 128), jnp.float32),   # m
+            pltpu.VMEM((heads, tq, 128), jnp.float32),   # l
+            pltpu.VMEM((heads, tq, d), jnp.float32),     # acc
+        ],
+    )
+    kernel = functools.partial(_paged_kernel, scale=scale, bs=bs, tq=tq,
+                               heads=heads, d=d, num_kb=mb, quant=quant,
+                               head_shard=quant and head0 is not None)
+    at = jnp.stack([jnp.asarray(layer, jnp.int32).reshape(()),
+                    jnp.asarray(0 if head0 is None else head0, jnp.int32)])
+    # no ``name=`` here: a pallas_call's name is also a named scope, and
+    # the device trace already prints this kernel under the caller's scope
+    # (``attn._paged_kv_attend.N``), which the benchmark's paged-decode
+    # roofline reader matches by that name
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, tq, heads, d), q.dtype),
+        compiler_params=tpu_compiler_params(
+            dimension_semantics=("parallel", "arbitrary")),
+    )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
+      at, q, *pools)
+
+
 def decode_attention_paged(q, k_pool, v_pool, block_tables, lengths,
-                           softmax_scale=None):
-    """Attend a decode (or k-token verify) step against a paged KV cache.
+                           layer=0, softmax_scale=None):
+    """Attend a decode (or k-token verify, or prefill-chunk) step against
+    one layer of the paged KV pool.
 
     Args:
       q: ``[B, T_q, H, D]`` query step. ``T_q = 1`` is plain decode;
@@ -249,191 +364,50 @@ def decode_attention_paged(q, k_pool, v_pool, block_tables, lengths,
         row r attends causally at its own absolute position
         ``lengths[b] + r`` — bitwise the attention sequential decode
         would have computed, which is what makes greedy verify exact.
-      k_pool / v_pool: ``[num_blocks, block_size, H, D]`` shared block
-        pools; this step's keys must already be scattered at each row's
-        ``[lengths[b], lengths[b] + T_q)`` logical positions (verify
-        pads scatter into the garbage block and are never read).
+      k_pool / v_pool: the STACKED ``[layers, num_blocks, block_size,
+        H*D]`` pools (see "THE POOL'S ONE SHAPE" above); this step's keys
+        must already be scattered at each row's ``[lengths[b], lengths[b]
+        + T_q)`` logical positions of ``layer`` (verify pads scatter into
+        the garbage block and are never read).
       block_tables: ``[B, MB]`` int32 — row b's logical block j lives in
         pool block ``block_tables[b, j]``; entries past the allocation
         point at the reserved garbage block (their blocks skip compute).
       lengths: ``[B]`` int32 — valid tokens per row *before* this step.
+      layer: int32 scalar (traced inside a layer scan, or a Python int) —
+        which layer of the stacked pool to read.
 
-    The block table and lengths are *scalar-prefetch* operands: the grid
-    is static over ``(B, MB)``, each grid step DMAs exactly the pool
-    block the table names, and blocks past ``lengths[b] + T_q`` skip both
-    the fetch's compute and the online-softmax update.
+    The block table, lengths and layer are *scalar-prefetch* operands:
+    the grid is static over ``(B, MB)``, each grid step DMAs exactly the
+    ``(layer, block)`` the table names — ``block_size`` rows of ``H*D``
+    lanes, unpadded — and blocks past ``lengths[b] + T_q`` skip both the
+    fetch's compute and the online-softmax update.
 
     Returns ``[B, T_q, H, D]`` in the query's dtype.
     """
-    b, tq, heads, d = q.shape
-    if tq < 1:
-        raise ValueError(f"need at least one query row per sequence, "
-                         f"got T_q={tq}")
-    nb, bs, ph, pd = k_pool.shape
-    if (ph, pd) != (heads, d):
-        raise ValueError(f"pool heads/dim {(ph, pd)} != query {(heads, d)}")
-    mb = block_tables.shape[-1]
-    scale = softmax_scale if softmax_scale is not None else d ** -0.5
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, mb),
-        in_specs=[
-            pl.BlockSpec((1, tq, heads, d),
-                         lambda bi, ji, tab, ln: (bi, 0, 0, 0)),
-            pl.BlockSpec((1, bs, heads, d),
-                         lambda bi, ji, tab, ln: (tab[bi, ji], 0, 0, 0)),
-            pl.BlockSpec((1, bs, heads, d),
-                         lambda bi, ji, tab, ln: (tab[bi, ji], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tq, heads, d),
-                               lambda bi, ji, tab, ln: (bi, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((heads, tq, 128), jnp.float32),   # m
-            pltpu.VMEM((heads, tq, 128), jnp.float32),   # l
-            pltpu.VMEM((heads, tq, d), jnp.float32),     # acc
-        ],
-    )
-    kernel = functools.partial(_paged_kernel, scale=scale, bs=bs, tq=tq,
-                               heads=heads, d=d, num_kb=mb)
-    tables = jnp.asarray(block_tables, jnp.int32)
-    lens = jnp.asarray(lengths, jnp.int32)
-    # no ``name=`` here or on the int8 twin below: a pallas_call's name is
-    # also a named scope, and the device trace already prints this kernel
-    # under the caller's scope (``attn._paged_kv_attend.N``), which the
-    # benchmark's paged-decode roofline reader matches by that name
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, tq, heads, d), q.dtype),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
-    )(tables, lens, q, k_pool, v_pool)
-
-
-# ---------------------------------------------------------------------------
-# int8 paged variant: the pools hold per-row symmetric int8 KV
-# (ops.quantizer.quantize_rowwise — one f32 scale per token x head in a
-# side pool indexed by the SAME block table), and the kernel dequantizes
-# inside the block DMA's compute step. Attention math is unchanged and
-# stays fp32-accumulated; gather_paged_cache_int8 above is the dense
-# oracle this kernel is tested against with a pinned tolerance.
-# ---------------------------------------------------------------------------
-
-
-def _paged_int8_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref,
-                       vs_ref, o_ref, m_scr, l_scr, acc_scr, *, scale, bs,
-                       tq, heads, d, num_kb):
-    bi = pl.program_id(0)
-    ji = pl.program_id(1)
-    idx = lens_ref[bi]
-
-    @pl.when(ji == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    @pl.when(ji * bs < idx + tq)
-    def _body():
-        q = q_ref[...].reshape(tq, heads, d).transpose(1, 0, 2) \
-            .astype(jnp.float32)                                   # [H,tq,d]
-        # dequantize in-register: int8 rows x the side-pool scales
-        ks = ks_ref[...].reshape(bs, heads, 1).transpose(1, 0, 2)  # [H,bs,1]
-        vs = vs_ref[...].reshape(bs, heads, 1).transpose(1, 0, 2)
-        k = k_ref[...].reshape(bs, heads, d).transpose(1, 0, 2) \
-            .astype(jnp.float32) * ks                              # [H,bs,d]
-        v = v_ref[...].reshape(bs, heads, d).transpose(1, 0, 2) \
-            .astype(jnp.float32) * vs
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale            # [H,tq,bs]
-        rows = jax.lax.broadcasted_iota(jnp.int32, (heads, tq, bs), 1)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (heads, tq, bs), 2) \
-            + ji * bs
-        s = jnp.where(cols <= idx + rows, s, NEG_INF)
-        m_prev = m_scr[:, :, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_scr[:, :, 0:1] + jnp.sum(p, axis=2, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)                    # [H,tq,d]
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    @pl.when(ji == num_kb - 1)
-    def _finish():
-        l = l_scr[:, :, 0:1]
-        out = acc_scr[:] / jnp.where(l == 0.0, 1.0, l)             # [H,tq,d]
-        o_ref[...] = out.transpose(1, 0, 2).reshape(1, tq, heads, d) \
-            .astype(o_ref.dtype)
+    return _paged_call(q, (k_pool, v_pool), block_tables, lengths, layer,
+                       softmax_scale)
 
 
 def decode_attention_paged_int8(q, k_pool, v_pool, k_scale, v_scale,
-                                block_tables, lengths, softmax_scale=None):
-    """Attend a decode (or k-token verify) step against an
-    int8-quantized paged KV cache.
+                                block_tables, lengths, layer=0,
+                                softmax_scale=None):
+    """Attend a decode (or k-token verify) step against one layer of an
+    int8-quantized paged KV pool.
 
-    Same contract as :func:`decode_attention_paged` (including the
-    multi-query-row verify semantics), except ``k_pool`` /
-    ``v_pool`` are ``[num_blocks, block_size, H, D]`` int8 and
-    ``k_scale`` / ``v_scale`` are their ``[num_blocks, block_size, H,
-    1]`` f32 per-row scales (one scale per token x head —
+    Same contract and the same kernel as :func:`decode_attention_paged`
+    (including the multi-query-row verify semantics), except ``k_pool`` /
+    ``v_pool`` are ``[layers, num_blocks, block_size, H*D]`` int8 and
+    ``k_scale`` / ``v_scale`` are their ``[layers, num_blocks,
+    block_size, scale_lanes(H)]`` f32 per-row scales (one scale per
+    token x head, head ``h`` in lane ``h`` —
     ``ops.quantizer.quantize_rowwise``). The scale side pools ride the
-    same scalar-prefetch block table: each grid step DMAs the named pool
+    same ``(layer, block)`` address: each grid step DMAs the named pool
     block *and* its scale rows, dequantizes in-register, and runs the
-    identical fp32 online-softmax update.
+    identical fp32 online-softmax update; :func:`gather_paged_cache_int8`
+    is the dense oracle it is tested against with a pinned tolerance.
     """
-    b, tq, heads, d = q.shape
-    if tq < 1:
-        raise ValueError(f"need at least one query row per sequence, "
-                         f"got T_q={tq}")
-    nb, bs, ph, pd = k_pool.shape
-    if (ph, pd) != (heads, d):
-        raise ValueError(f"pool heads/dim {(ph, pd)} != query {(heads, d)}")
-    if k_scale.shape != (nb, bs, heads, 1):
-        raise ValueError(
-            f"scale pool shape {k_scale.shape} != {(nb, bs, heads, 1)} "
-            f"(one f32 scale per pool row x head)")
-    mb = block_tables.shape[-1]
-    scale = softmax_scale if softmax_scale is not None else d ** -0.5
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, mb),
-        in_specs=[
-            pl.BlockSpec((1, tq, heads, d),
-                         lambda bi, ji, tab, ln: (bi, 0, 0, 0)),
-            pl.BlockSpec((1, bs, heads, d),
-                         lambda bi, ji, tab, ln: (tab[bi, ji], 0, 0, 0)),
-            pl.BlockSpec((1, bs, heads, d),
-                         lambda bi, ji, tab, ln: (tab[bi, ji], 0, 0, 0)),
-            pl.BlockSpec((1, bs, heads, 1),
-                         lambda bi, ji, tab, ln: (tab[bi, ji], 0, 0, 0)),
-            pl.BlockSpec((1, bs, heads, 1),
-                         lambda bi, ji, tab, ln: (tab[bi, ji], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tq, heads, d),
-                               lambda bi, ji, tab, ln: (bi, 0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((heads, tq, 128), jnp.float32),   # m
-            pltpu.VMEM((heads, tq, 128), jnp.float32),   # l
-            pltpu.VMEM((heads, tq, d), jnp.float32),     # acc
-        ],
-    )
-    kernel = functools.partial(_paged_int8_kernel, scale=scale, bs=bs,
-                               tq=tq, heads=heads, d=d, num_kb=mb)
-    tables = jnp.asarray(block_tables, jnp.int32)
-    lens = jnp.asarray(lengths, jnp.int32)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, tq, heads, d), q.dtype),
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
-    )(tables, lens, q, k_pool, v_pool, k_scale, v_scale)
+    return _paged_call(q, (k_pool, v_pool, k_scale, v_scale), block_tables,
+                       lengths, layer, softmax_scale)
 
 
 # ---------------------------------------------------------------------------
@@ -475,58 +449,53 @@ def decode_attention_tp(q, k_cache, v_cache, cache_index,
         q, k_cache, v_cache, jnp.asarray(cache_index, jnp.int32))
 
 
-def decode_attention_paged_tp(q, k_pool, v_pool, block_tables, lengths,
-                              softmax_scale=None, mesh=None, axis=None):
-    """TP-aware :func:`decode_attention_paged`: the shared block pools
-    live tp-sharded on their head dim (per-shard KV pools — each tp
-    shard holds heads/tp of every pool block), block tables/lengths
-    follow the batch."""
+def _paged_tp(q, pools, block_tables, lengths, layer, softmax_scale,
+              mesh, axis):
     from jax.sharding import PartitionSpec as P
 
     from deepspeed_tpu.ops.kernel_mesh import kernel_mesh_plan
 
-    def kernel(qs, ks, vs, t, ln):
-        return decode_attention_paged(qs, ks, vs, t, ln,
-                                      softmax_scale=softmax_scale)
-
+    tables = jnp.asarray(block_tables, jnp.int32)
+    lens = jnp.asarray(lengths, jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32)
     plan = kernel_mesh_plan(q.shape[0], q.shape[2], mesh=mesh, axis=axis)
     if plan is None:
-        return kernel(q, k_pool, v_pool, block_tables, lengths)
-    # pools are the SHARED per-replica cache: head-sharded over tp,
-    # replicated over data; per-row operands follow the batch entry
+        return _paged_call(q, pools, tables, lens, layer, softmax_scale)
+
+    def kernel(qs, t, ln, ly, *ps):
+        head0 = (None if plan.heads is None else
+                 jax.lax.axis_index(plan.heads) * qs.shape[2])
+        return _paged_call(qs, ps, t, ln, ly, softmax_scale, head0)
+
+    # pools are the SHARED per-replica cache: the K/V lane axis split into
+    # tp groups of heads/tp contiguous heads, replicated over data; the
+    # scale rows (a lane a head, padded to whole registers) stay whole and
+    # the kernel finds its heads in them; per-row operands follow the
+    # batch entry
     qs_spec = P(plan.batch, None, plan.heads, None)
-    pool_spec = P(None, None, plan.heads, None)
+    pool_specs = (P(None, None, None, plan.heads),) * 2 + (P(),) * (
+        len(pools) - 2)
     return plan.shard_map(
-        kernel,
-        (qs_spec, pool_spec, pool_spec, P(plan.batch), P(plan.batch)),
-        qs_spec, name="paged_kv_attend")(q, k_pool, v_pool, jnp.asarray(block_tables, jnp.int32),
-                 jnp.asarray(lengths, jnp.int32))
+        kernel, (qs_spec, P(plan.batch), P(plan.batch), P()) + pool_specs,
+        qs_spec, name="paged_kv_attend")(q, tables, lens, layer, *pools)
+
+
+def decode_attention_paged_tp(q, k_pool, v_pool, block_tables, lengths,
+                              layer=0, softmax_scale=None, mesh=None,
+                              axis=None):
+    """TP-aware :func:`decode_attention_paged`: the stacked pools live
+    tp-sharded on their lane axis (per-shard KV pools — each tp shard
+    holds heads/tp contiguous heads of every pool row), block
+    tables/lengths follow the batch, the layer index is replicated."""
+    return _paged_tp(q, (k_pool, v_pool), block_tables, lengths, layer,
+                     softmax_scale, mesh, axis)
 
 
 def decode_attention_paged_int8_tp(q, k_pool, v_pool, k_scale, v_scale,
-                                   block_tables, lengths,
+                                   block_tables, lengths, layer=0,
                                    softmax_scale=None, mesh=None,
                                    axis=None):
-    """TP-aware :func:`decode_attention_paged_int8`: int8 pools AND
-    their f32 scale side pools head-sharded over ``axis``."""
-    from jax.sharding import PartitionSpec as P
-
-    from deepspeed_tpu.ops.kernel_mesh import kernel_mesh_plan
-
-    def kernel(qs, ks, vs, kss, vss, t, ln):
-        return decode_attention_paged_int8(qs, ks, vs, kss, vss, t, ln,
-                                           softmax_scale=softmax_scale)
-
-    plan = kernel_mesh_plan(q.shape[0], q.shape[2], mesh=mesh, axis=axis)
-    if plan is None:
-        return kernel(q, k_pool, v_pool, k_scale, v_scale, block_tables,
-                      lengths)
-    qs_spec = P(plan.batch, None, plan.heads, None)
-    pool_spec = P(None, None, plan.heads, None)
-    return plan.shard_map(
-        kernel,
-        (qs_spec, pool_spec, pool_spec, pool_spec, pool_spec,
-         P(plan.batch), P(plan.batch)),
-        qs_spec, name="paged_kv_attend")(q, k_pool, v_pool, k_scale, v_scale,
-                 jnp.asarray(block_tables, jnp.int32),
-                 jnp.asarray(lengths, jnp.int32))
+    """TP-aware :func:`decode_attention_paged_int8`: int8 pools
+    lane-sharded over ``axis``, their f32 scale side pools replicated."""
+    return _paged_tp(q, (k_pool, v_pool, k_scale, v_scale), block_tables,
+                     lengths, layer, softmax_scale, mesh, axis)

@@ -261,17 +261,20 @@ class ServingEngine:
 
     # ------------------------------------------------------------------
     def _init_cache(self):
-        """Zeroed per-layer KV pools, shaped by tracing the paged decode
-        module's init without running it (eval_shape: no compute, no
-        params materialized). Placed with the mesh shardings the
-        compiled programs emit — ``decode_cache_specs``: on a tp>1 mesh
-        the key/value pools (and their int8 scale side pools) live
-        HEAD-SHARDED over the tp axis, a per-shard KV pool per device
-        group, exactly the layout the TP-aware paged Pallas kernel
-        consumes — so the FIRST prefill's argument signature already
-        matches steady state (a `jnp.zeros` pool would carry
-        SingleDeviceSharding and cost that bucket one spurious
-        retrace)."""
+        """The zeroed KV pool, shaped by tracing the paged decode module's
+        init without running it (eval_shape: no compute, no params
+        materialized). Every leaf has the pool's one shape, ``[layers,
+        blocks, block_size, lanes]`` (``ops/decode_attention.py``: K/V
+        rows of ``heads * head_dim`` lanes, int8 scale rows a lane a head),
+        which every serving program writes and reads in place. Placed
+        with the mesh shardings the compiled programs emit —
+        ``decode_cache_specs``: on a tp>1 mesh the lane axis is split
+        over the tp axis into ``heads / tp`` contiguous heads a shard, a
+        per-shard KV pool per device group, exactly the layout the
+        TP-aware paged Pallas kernel consumes — so the FIRST prefill's
+        argument signature already matches steady state (a `jnp.zeros`
+        pool would carry SingleDeviceSharding and cost that bucket one
+        spurious retrace)."""
         jax, jnp = self._jax, self._jnp
         from deepspeed_tpu.module_inject.policies import decode_cache_specs
 
@@ -282,7 +285,8 @@ class ServingEngine:
             lambda: self._dmodule.init(jax.random.PRNGKey(0),
                                        jnp.zeros((1, 1), jnp.int32),
                                        paging=pg))
-        shardings = decode_cache_specs(shapes["cache"], self.engine.mesh)
+        shardings = decode_cache_specs(shapes["cache"], self.engine.mesh,
+                                       heads=self._dmodule.config.n_head)
         return jax.tree_util.tree_map(
             lambda s, sh: jax.device_put(jnp.zeros(s.shape, s.dtype), sh),
             shapes["cache"], shardings)
@@ -480,18 +484,18 @@ class ServingEngine:
     def _build_cow(self):
         """Copy one pool block's rows onto another across every cache
         leaf (key/value pools and, under int8 KV, their scale side
-        pools) — the device half of partial-tail copy-on-write. Pool
-        leaves all end in ``[num_blocks, block_size, H, *]`` (with an
-        optional leading scanned-layer axis), so the block axis is
-        always ``ndim - 4``."""
+        pools), all layers at once — the device half of partial-tail
+        copy-on-write. Every leaf is ``[layers, blocks, block_size,
+        lanes]``; ``POOL_BLOCK_AXIS`` names the block axis."""
         jax = self._jax
+        from deepspeed_tpu.ops.decode_attention import POOL_BLOCK_AXIS
 
         def fn(cache, src, dst):
             def copy(p):
-                ax = p.ndim - 4
-                row = jax.lax.dynamic_index_in_dim(p, src, axis=ax,
-                                                   keepdims=False)
-                return jax.lax.dynamic_update_index_in_dim(p, row, dst, ax)
+                row = jax.lax.dynamic_index_in_dim(
+                    p, src, axis=POOL_BLOCK_AXIS, keepdims=False)
+                return jax.lax.dynamic_update_index_in_dim(
+                    p, row, dst, POOL_BLOCK_AXIS)
 
             return jax.tree_util.tree_map(copy, cache)
 
@@ -500,24 +504,19 @@ class ServingEngine:
     def _build_migrate(self, B: int):
         """Scatter ``B`` migrated pool blocks (every cache leaf — K/V
         pools and, under int8 KV, their scale side pools ride the same
-        block indices) onto this replica's pool at the freshly allocated
-        destination blocks. The import half of live KV migration: rows
-        land on exactly the pool rows every later ``paged_write_rows``/
-        paged-gather computation addresses through the rewritten block
-        table, so the resumed decode is bit-identical to never having
-        moved. Same axis convention as the cow program: pool leaves all
-        end in ``[num_blocks, block_size, H, *]`` (optional leading
-        scanned-layer axis), so the block axis is always ``ndim - 4``."""
-        jax, jnp = self._jax, self._jnp
+        block indices; all layers of a block travel together) onto this
+        replica's pool at the freshly allocated destination blocks. The
+        import half of live KV migration: rows land on exactly the
+        ``(layer, block, offset)`` every later paged write and the paged
+        kernel address through the rewritten block table, so the resumed
+        decode is bit-identical to never having moved. ``rows`` leaves
+        are ``[layers, B, block_size, lanes]``, the pool's own shape
+        with ``B`` on ``POOL_BLOCK_AXIS``."""
+        jax = self._jax
 
         def fn(cache, rows, dst):
-            def scatter(p, r):
-                ax = p.ndim - 4
-                pm = jnp.moveaxis(p, ax, 0)
-                rm = jnp.moveaxis(r, ax, 0)
-                return jnp.moveaxis(pm.at[dst].set(rm), 0, ax)
-
-            return jax.tree_util.tree_map(scatter, cache, rows)
+            return jax.tree_util.tree_map(
+                lambda p, r: p.at[:, dst].set(r), cache, rows)
 
         return self._jit(fn, f"serving_migrate_B{B}",
                          f"serving.migrate[blocks={B}]", donate=0)
@@ -1118,9 +1117,10 @@ class ServingEngine:
         import on another replica: the request's identity and counters,
         its pending last token, and the per-block KV rows of every cache
         leaf (int8 side pools and their scales ride the same block
-        indices), gathered on the block axis and split into per-TP-shard
-        chunks along the head axis — the transfer unit PR 15's
-        head-sharded pools define. Read-only on the source (an open
+        indices), gathered on ``POOL_BLOCK_AXIS`` and split into
+        per-TP-shard chunks along ``POOL_LANE_AXIS`` (``heads / tp``
+        contiguous heads each) — the transfer unit the lane-sharded
+        pools define. Read-only on the source (an open
         speculative window is dropped first — it is uncommitted by
         definition), so a transfer that dies downstream leaves this
         replica able to keep decoding or to serve a replay. Returns None
@@ -1134,6 +1134,9 @@ class ServingEngine:
         if self.block_mgr.speculating(request_id):
             self.block_mgr.drop_speculative(request_id)
         jax, jnp = self._jax, self._jnp
+        from deepspeed_tpu.ops.decode_attention import (POOL_BLOCK_AXIS,
+                                                        POOL_LANE_AXIS)
+
         bs = self.config.block_size
         covered = self.block_mgr.owned(request_id)[
             :blocks_for_tokens(req.length, bs)]
@@ -1142,15 +1145,18 @@ class ServingEngine:
             tp = int(dict(self.engine.mesh.shape).get("tp", 1))
         except Exception:
             tp = 1
-        leaves, treedef = jax.tree_util.tree_flatten(self.cache)
+        leaves, treedef = jax.tree_util.tree_flatten_with_path(self.cache)
         idx = jnp.asarray(np.asarray(covered, np.int32))
+        heads = self._dmodule.config.n_head
         rows, wire_bytes = [], 0
-        for leaf in leaves:
-            r = np.asarray(jnp.take(leaf, idx, axis=leaf.ndim - 4))
-            h = r.ndim - 2
-            if tp > 1 and r.shape[h] % tp == 0:
+        for path, leaf in leaves:
+            r = np.asarray(jnp.take(leaf, idx, axis=POOL_BLOCK_AXIS))
+            if path[-1].key.endswith("_scale"):
+                # a scale row's padding lanes stay home
+                r = r[..., :heads]
+            if tp > 1 and heads % tp == 0:
                 chunks = [np.ascontiguousarray(c)
-                          for c in np.split(r, tp, axis=h)]
+                          for c in np.split(r, tp, axis=POOL_LANE_AXIS)]
             else:
                 chunks = [r]
             wire_bytes += sum(c.nbytes for c in chunks)
@@ -1196,7 +1202,7 @@ class ServingEngine:
                         ) -> Optional[Request]:
         """Splice an exported sequence into a free decode slot: allocate
         blocks, scatter the migrated rows onto this pool at exactly the
-        rows every later ``paged_write_rows``-indexed program addresses
+        ``(layer, block, offset)`` every later paged program addresses
         through the rewritten table, seed the request's token/sampling
         counters, and resume decoding mid-stream — NO prefill program
         dispatch. Returns None when the export cannot land here (pool
@@ -1223,6 +1229,8 @@ class ServingEngine:
         if slot is None:
             return None
         jax, jnp = self._jax, self._jnp
+        from deepspeed_tpu.ops.decode_attention import POOL_LANE_AXIS
+
         mnt = int(export["max_new_tokens"]
                   or self.config.default_max_new_tokens)
         cost = len(export["prompt"]) + mnt
@@ -1264,10 +1272,13 @@ class ServingEngine:
             B = int(export["blocks"])
             if B:
                 rows_leaves = []
-                for chunks in export["rows"]:
+                for chunks, leaf in zip(export["rows"], leaves):
                     r = (chunks[0] if len(chunks) == 1 else np.concatenate(
-                        chunks, axis=chunks[0].ndim - 2))
-                    rows_leaves.append(jnp.asarray(r))
+                        chunks, axis=POOL_LANE_AXIS))
+                    pad = [(0, 0)] * r.ndim
+                    pad[POOL_LANE_AXIS] = (0, leaf.shape[POOL_LANE_AXIS]
+                                           - r.shape[POOL_LANE_AXIS])
+                    rows_leaves.append(jnp.asarray(np.pad(r, pad)))
                 rows = jax.tree_util.tree_unflatten(treedef, rows_leaves)
                 if B not in self._migrate_fns:
                     self._migrate_fns[B] = self._build_migrate(B)

@@ -4,11 +4,11 @@ replicas instead of replaying its work.
 The paged pool makes this cheap to say and do: a sequence's KV is a set
 of pool blocks named by its block table, so migration is a block-granular
 transfer plus a table rewrite — ``ServingEngine.export_sequence`` gathers
-the covered blocks' rows (every cache leaf: int8 side pools and their
-scales ride the same indices, per-TP-shard chunks along the head axis),
-``import_sequence`` allocates blocks on the target, scatters the rows at
-exactly the pool rows every later ``paged_write_rows``-indexed program
-addresses through the rewritten table, and splices the request into a
+the covered blocks' rows (every cache leaf, all layers of a block
+together: int8 side pools and their scales ride the same indices,
+per-TP-shard chunks along the lane axis), ``import_sequence`` allocates
+blocks on the target, scatters the rows at exactly the ``(layer, block,
+offset)`` every later paged program addresses through the rewritten table, and splices the request into a
 free slot mid-stream — NO prefill dispatch, counters intact, greedy
 continuation bit-identical to never having moved.
 

@@ -51,6 +51,15 @@ def _s(sharding, shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+@pytest.fixture
+def _no_global_topology():
+    from deepspeed_tpu.parallel.topology import reset_topology
+
+    reset_topology()
+    yield
+    reset_topology()
+
+
 # GPT-2 125M: 12 heads of 64; train batch 16 x 1024; serving 8 slots over a
 # pool of 512 blocks of 32 tokens, 32 blocks a sequence
 H, D = 12, 64
@@ -78,27 +87,53 @@ def test_decode_attention(one_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("t_q", [1, 5])
-def test_decode_attention_paged(one_chip, t_q):
+# the paged kernel reads the STACKED pool [layers, blocks, bs, H*D] at a
+# layer index: 125M's 12 heads of 64, XL's 25 (a row of 1600 lanes is no
+# multiple of 128), and head size 128
+@pytest.mark.parametrize("t_q,heads,dim", [(1, H, D), (5, H, D), (1, 25, 64),
+                                           (5, 25, 64), (1, 8, 128)])
+def test_decode_attention_paged(one_chip, t_q, heads, dim):
     from deepspeed_tpu.ops.decode_attention import decode_attention_paged
 
-    pool = _s(one_chip, (512, 32, H, D))
+    pool = _s(one_chip, (2, 512, 32, heads * dim))
     text = _compiled_text(
-        decode_attention_paged, _s(one_chip, (8, t_q, H, D)), pool, pool,
-        _s(one_chip, (8, 32), jnp.int32), _s(one_chip, (8,), jnp.int32))
+        decode_attention_paged, _s(one_chip, (8, t_q, heads, dim)), pool,
+        pool, _s(one_chip, (8, 32), jnp.int32), _s(one_chip, (8,), jnp.int32),
+        _s(one_chip, (), jnp.int32))
     assert "tpu_custom_call" in text
 
 
-def test_decode_attention_paged_int8(one_chip):
+@pytest.mark.parametrize("heads,dim", [(H, D), (25, 64), (8, 128)])
+def test_decode_attention_paged_int8(one_chip, heads, dim):
     from deepspeed_tpu.ops.decode_attention import (
-        decode_attention_paged_int8)
+        decode_attention_paged_int8, scale_lanes)
 
-    pool = _s(one_chip, (512, 32, H, D), jnp.int8)
-    scale = _s(one_chip, (512, 32, H, 1), jnp.float32)
+    pool = _s(one_chip, (2, 512, 32, heads * dim), jnp.int8)
+    scale = _s(one_chip, (2, 512, 32, scale_lanes(heads)), jnp.float32)
     text = _compiled_text(
-        decode_attention_paged_int8, _s(one_chip, (8, 1, H, D)), pool, pool,
-        scale, scale, _s(one_chip, (8, 32), jnp.int32),
-        _s(one_chip, (8,), jnp.int32))
+        decode_attention_paged_int8, _s(one_chip, (8, 1, heads, dim)), pool,
+        pool, scale, scale, _s(one_chip, (8, 32), jnp.int32),
+        _s(one_chip, (8,), jnp.int32), _s(one_chip, (), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_decode_attention_paged_int8_over_tp(topo, _no_global_topology):
+    """Heads over tp=4: the K/V lanes are split, the scale rows stay whole
+    and each shard rolls its heads' lanes to the front by a traced offset —
+    the one thing in the kernel that only a mesh exercises."""
+    from deepspeed_tpu.ops.decode_attention import (
+        decode_attention_paged_int8_tp, scale_lanes)
+
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "tp"))
+    lanes = NamedSharding(mesh, P(None, None, None, "tp"))
+    rep = NamedSharding(mesh, P())
+    pool = _s(lanes, (2, 512, 32, H * D), jnp.int8)
+    scale = _s(rep, (2, 512, 32, scale_lanes(H)), jnp.float32)
+    text = _compiled_text(
+        lambda *a: decode_attention_paged_int8_tp(*a, mesh=mesh),
+        _s(NamedSharding(mesh, P(None, None, "tp")), (8, 1, H, D)), pool,
+        pool, scale, scale, _s(rep, (8, 32), jnp.int32),
+        _s(rep, (8,), jnp.int32), _s(rep, (), jnp.int32))
     assert "tpu_custom_call" in text
 
 
@@ -127,15 +162,6 @@ def test_flash_on_a_mesh_goes_through_shard_map(topo):
     text = _compiled_text(
         lambda q, k, v: flash_attention_bthd_tp(q, k, v, mesh=mesh), q, q, q)
     assert "tpu_custom_call" in text
-
-
-@pytest.fixture
-def _no_global_topology():
-    from deepspeed_tpu.parallel.topology import reset_topology
-
-    reset_topology()
-    yield
-    reset_topology()
 
 
 def test_flash_inside_the_ulysses_shard_map(topo, _no_global_topology):
@@ -314,3 +340,121 @@ def test_paged_decode_kernel_matches_the_benchmarks_reader(
         # per shard the kernel sits in a shard_map, whose body is scoped
         # (it printed as ``shard_map.N``)
         assert names and all("paged_kv_attend" in n for n in names), names
+
+
+# ---------------------------------------------------------------------------
+# the serving KV pool: one resident form, written and read in place
+def _results(text):
+    """``(name, opcode, [(dtype, dims), ...], line)`` of every instruction
+    in a compiled program's text (a tuple result lists its elements)."""
+    import re
+
+    head = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*)$")
+    shape = re.compile(r"\b([a-z]+[0-9]+)\[([0-9,]*)\]")
+    for line in text.splitlines():
+        m = head.match(line)
+        if not m:
+            continue
+        rest = m.group(2)
+        # the result type ends where the opcode starts: the first
+        # ``word(`` that is not inside the (possibly tuple) type
+        op = re.search(r"(?:^|[\s)}])([a-z][\w\-]*)\(", rest)
+        if not op:
+            continue
+        yield (m.group(1), op.group(1),
+               [(d, tuple(int(x) for x in dims.split(",") if x))
+                for d, dims in shape.findall(rest[:op.start(1)])], line)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("kv", ["", "int8"])
+def test_serving_programs_update_the_kv_pool_in_place(one_chip, monkeypatch,
+                                                      kv, program):
+    """The serving decode program and a prefill program at GPT-2 XL widths
+    (25 heads of 64; 32 slots; the default pool of 1025 blocks of 32), the
+    pool donated, bf16 and int8. The pool has ONE resident form that the
+    program writes and reads in place: in the compiled program only
+    parameters, loop plumbing and the row scatters have a result with the
+    pool's block dims (no ``copy``, ``dynamic-slice``,
+    ``dynamic-update-slice`` or ``AllocateBuffer`` over a pool or a
+    layer's slice of one), program temporaries stay below ONE layer's
+    pool, and the input-output alias covers every pool byte. On the parent
+    of PR 27 this found the layout conversions around the layer scan
+    (``copy.30-33``), the scan's second pool (``AllocateBuffer``) and the
+    copy back onto the donated argument (``copy.52/53``): two thirds of a
+    decode step.
+
+    Eight layers, so that every pool leaf is larger than the chip's 128
+    MiB of fast memory: the compiler prefetches a smaller one there whole
+    (``copy-start``), which the 48-layer program cannot do."""
+    import re
+
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
+    from deepspeed_tpu.ops import attention as ops_attention
+
+    monkeypatch.setattr(ops_attention, "use_decode_kernel", lambda: True)
+    heads, dim, layers, slots, blocks, bs, per_seq = 25, 64, 8, 32, 1025, 32, 32
+    # a small vocabulary: the embedding's own layout copy (not the pool's)
+    # would otherwise be the program's largest temporary
+    cfg = GPT2Config(vocab_size=1024, n_positions=1024, n_embd=heads * dim,
+                     n_layer=layers, n_head=heads, dtype=jnp.bfloat16)
+    module = GPT2LMHeadModel(cfg.for_paged_decode(blocks, bs, kv))
+    shapes = jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
+        paging={"block_tables": jnp.zeros((1, per_seq), jnp.int32),
+                "lengths": jnp.zeros((1,), jnp.int32),
+                "num_valid": jnp.ones((1,), jnp.int32), "prefill": False}))
+    pool = jax.tree_util.tree_leaves(shapes["cache"])
+    assert len(pool) == (4 if kv else 2)
+    pool_bytes = sum(int(np.prod(s.shape)) * s.dtype.itemsize for s in pool)
+
+    n, t = (slots, 1) if program == "decode" else (1, 256)
+
+    def fn(params, cache, ids, tables, lengths, num_valid):
+        pg = {"block_tables": tables, "lengths": lengths,
+              "num_valid": num_valid, "prefill": program == "prefill"}
+        out, vars_ = module.apply({"params": params, "cache": cache}, ids,
+                                  mutable=["cache"], paging=pg)
+        return jnp.argmax(out[:, -1], axis=-1), vars_["cache"]
+
+    put = lambda tree, dtype=None: jax.tree_util.tree_map(  # noqa: E731
+        lambda s: _s(one_chip, s.shape, dtype or s.dtype), tree)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        put(shapes["params"], jnp.bfloat16), put(shapes["cache"]),
+        _s(one_chip, (n, t), jnp.int32),
+        _s(one_chip, (n, per_seq), jnp.int32),
+        _s(one_chip, (n,), jnp.int32),
+        _s(one_chip, (n,), jnp.int32)).compile()
+    text = compiled.as_text()
+
+    # an instruction "touches the pool" if a result of it has the pool's
+    # (blocks, block_size) dims side by side: the stacked pool, a layer
+    # of it, or any re-tiling that keeps the block axis
+    touching = [(name, op, line) for name, op, res, line in _results(text)
+                if any((blocks, bs) in zip(dims, dims[1:]) for _, dims in res)]
+    assert touching
+    plumbing = {"parameter", "get-tuple-element", "tuple", "while",
+                "bitcast", "scatter", "fusion"}
+    assert {op for _, op, _ in touching} <= plumbing, sorted(
+        (name, op) for name, op, _ in touching if op not in plumbing)
+    # a fusion with a pool-sized result is the in-place scatter and
+    # nothing else: its computation's root is the scatter
+    roots = {m.group(1): m.group(2) for m in re.finditer(
+        r"^%?([\w.\-]+) [^\n]*\{\n(?:[^\n]*\n)*?\s*ROOT %[\w.\-]+ = "
+        r"[^\n]*?\s([a-z][\w\-]*)\(", text, re.M)}
+    for name, op, line in touching:
+        if op == "fusion":
+            called = re.search(r"calls=%([\w.\-]+)", line).group(1)
+            assert roots.get(called) in ("scatter", "bitcast"), (
+                name, called, roots.get(called))
+    assert sum(op in ("scatter", "fusion") and roots.get(
+        (re.search(r"calls=%([\w.\-]+)", line) or [None, None])[1],
+        op) == "scatter" for _, op, line in touching) >= len(pool)
+
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < pool_bytes // layers, (
+        mem.temp_size_in_bytes, pool_bytes // layers)
+    assert mem.alias_size_in_bytes >= pool_bytes, (
+        mem.alias_size_in_bytes, pool_bytes)
+    if program == "decode":
+        assert any("_paged_kv_attend" in k for k in _kernel_names(text))

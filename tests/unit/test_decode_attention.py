@@ -88,39 +88,62 @@ def test_dense_kernel_at_block_boundaries(idx):
 # ---------------------------------------------------------------------------
 # paged (block-table) variant
 # ---------------------------------------------------------------------------
-def _paged_setup(B, lengths, tq, bs, mb, H=2, D=64, seed=0):
-    """Random pool + per-row permuted block tables holding each row's
-    prefix at its logical positions (the serving layout)."""
+LAYERS = 3  # every pool here is stacked: [LAYERS, nb, bs, H*D]
+
+
+def _paged_setup(B, lengths, tq, bs, mb, H=2, D=64, seed=0, layer=1,
+                 dtype=np.float32):
+    """Random STACKED pool ``[LAYERS, nb, bs, H*D]`` (every layer holds
+    different numbers, so reading the wrong one cannot pass) + per-row
+    permuted block tables holding each row's prefix at its logical
+    positions (the serving layout). Returns the positional arguments of
+    ``decode_attention_paged``, ``layer`` last."""
     from deepspeed_tpu.ops.decode_attention import GARBAGE_BLOCK
 
     rng = np.random.default_rng(seed)
     nb = 1 + B * mb
-    k_pool = rng.normal(size=(nb, bs, H, D)).astype(np.float32)
-    v_pool = rng.normal(size=(nb, bs, H, D)).astype(np.float32)
+    k_pool = rng.normal(size=(LAYERS, nb, bs, H * D)).astype(np.float32)
+    v_pool = rng.normal(size=(LAYERS, nb, bs, H * D)).astype(np.float32)
     tables = np.full((B, mb), GARBAGE_BLOCK, np.int32)
     free = list(rng.permutation(np.arange(1, nb)))
     for b, ln in enumerate(lengths):
         need = max(1, -(-(ln + tq) // bs))
         tables[b, :need] = [free.pop() for _ in range(need)]
     q4 = rng.normal(size=(B, tq, H, D)).astype(np.float32)
-    return (jnp.asarray(q4), jnp.asarray(k_pool), jnp.asarray(v_pool),
-            jnp.asarray(tables), jnp.asarray(lengths, jnp.int32))
+    return (jnp.asarray(q4, dtype), jnp.asarray(k_pool, dtype),
+            jnp.asarray(v_pool, dtype), jnp.asarray(tables),
+            jnp.asarray(lengths, jnp.int32), layer)
 
 
-def _paged_dense_ref(q4, k_pool, v_pool, tables, lengths):
-    """Oracle: gather the pool into the dense logical window, mask with
-    per-row lengths (decode_utils vector-idx form)."""
+def _dense_ref(q4, kd, vd, tables, lengths, bs):
+    """Attention of ``q4`` over dense ``[B, S, H, D]`` windows, masked with
+    per-row lengths (decode_utils vector-idx form), in float32."""
     from deepspeed_tpu.models.decode_utils import cache_attn_mask
+
+    mask = cache_attn_mask(tables.shape[-1] * bs, lengths, q4.shape[1])
+    f32 = lambda a: jnp.asarray(a, jnp.float32).transpose(0, 2, 1, 3)  # noqa: E731
+    return attention_reference(f32(q4), f32(kd), f32(vd), mask=mask,
+                               causal=False).transpose(0, 2, 1, 3)
+
+
+def _paged_dense_ref(q4, k_pool, v_pool, tables, lengths, layer):
+    """Oracle: gather one layer of the pool into the dense logical
+    window."""
     from deepspeed_tpu.ops.decode_attention import gather_paged_cache
 
-    B, tq = q4.shape[:2]
-    S = tables.shape[-1] * k_pool.shape[1]
-    kd = gather_paged_cache(k_pool, tables).transpose(0, 2, 1, 3)
-    vd = gather_paged_cache(v_pool, tables).transpose(0, 2, 1, 3)
-    mask = cache_attn_mask(S, lengths, tq)
-    y = attention_reference(q4.transpose(0, 2, 1, 3), kd, vd, mask=mask,
-                            causal=False)
-    return y.transpose(0, 2, 1, 3)
+    H = q4.shape[2]
+    return _dense_ref(q4, gather_paged_cache(k_pool, tables, layer, H),
+                      gather_paged_cache(v_pool, tables, layer, H),
+                      tables, lengths, k_pool.shape[2])
+
+
+def _int8_dense_ref(q4, kq, vq, ks, vs, tables, lengths, layer):
+    from deepspeed_tpu.ops.decode_attention import gather_paged_cache_int8
+
+    H = q4.shape[2]
+    return _dense_ref(q4, gather_paged_cache_int8(kq, ks, tables, layer, H),
+                      gather_paged_cache_int8(vq, vs, tables, layer, H),
+                      tables, lengths, kq.shape[2])
 
 
 @pytest.mark.parametrize("lengths,tq", [
@@ -152,13 +175,13 @@ def test_verify_rows_equal_sequential_single_row_calls():
 
     tq = 4
     args = _paged_setup(2, [5, 37], tq, bs=32, mb=4, seed=1)
-    q4, k_pool, v_pool, tables, lens = args
+    q4, k_pool, v_pool, tables, lens, layer = args
     with tpu_interpret_mode():
         multi = np.asarray(decode_attention_paged(*args))
     for r in range(tq):
         with tpu_interpret_mode():
             single = decode_attention_paged(q4[:, r:r + 1], k_pool, v_pool,
-                                            tables, lens + r)
+                                            tables, lens + r, layer)
         np.testing.assert_allclose(multi[:, r:r + 1], np.asarray(single),
                                    rtol=2e-5, atol=2e-5)
 
@@ -172,22 +195,22 @@ def test_verify_rejected_tail_rows_isolated():
     from deepspeed_tpu.ops.decode_attention import decode_attention_paged
 
     bs, tq, length, accepted = 8, 4, 10, 1
-    q4, k_pool, v_pool, tables, lens = _paged_setup(1, [length], tq, bs=bs,
-                                                    mb=4, seed=3)
+    q4, k_pool, v_pool, tables, lens, layer = _paged_setup(
+        1, [length], tq, bs=bs, mb=4, seed=3)
     with tpu_interpret_mode():
         out1 = np.asarray(decode_attention_paged(q4, k_pool, v_pool,
-                                                 tables, lens))
+                                                 tables, lens, layer))
     kp = np.asarray(k_pool).copy()
     vp = np.asarray(v_pool).copy()
     table = np.asarray(tables)[0]
     for pos in range(length + accepted + 1, length + tq):
         blk, off = table[pos // bs], pos % bs
-        kp[blk, off] = 7777.0
-        vp[blk, off] = -7777.0
+        kp[layer, blk, off] = 7777.0
+        vp[layer, blk, off] = -7777.0
     with tpu_interpret_mode():
         out2 = np.asarray(decode_attention_paged(q4, jnp.asarray(kp),
                                                  jnp.asarray(vp),
-                                                 tables, lens))
+                                                 tables, lens, layer))
     # rows 0..accepted (the kept prefix + its correction row) untouched
     np.testing.assert_array_equal(out1[:, :accepted + 1],
                                   out2[:, :accepted + 1])
@@ -197,10 +220,11 @@ def test_paged_verify_rejects_zero_rows():
     from deepspeed_tpu.ops.decode_attention import (
         decode_attention_paged, decode_attention_paged_int8)
 
-    q4, k_pool, v_pool, tables, lens = _paged_setup(1, [5], 1, bs=8, mb=4)
+    q4, k_pool, v_pool, tables, lens, _ = _paged_setup(1, [5], 1, bs=8,
+                                                       mb=4)
     with pytest.raises(ValueError, match="query row"):
         decode_attention_paged(q4[:, :0], k_pool, v_pool, tables, lens)
-    kq, vq, ks, vs = _int8_pools(k_pool, v_pool)
+    kq, vq, ks, vs = _int8_pools(k_pool, v_pool, q4.shape[2])
     with pytest.raises(ValueError, match="query row"):
         decode_attention_paged_int8(q4[:, :0], kq, vq, ks, vs, tables, lens)
 
@@ -224,21 +248,28 @@ def test_paged_garbage_blocks_ignored():
     it (and on unowned pool blocks) must not change any output."""
     from deepspeed_tpu.ops.decode_attention import decode_attention_paged
 
-    q4, k_pool, v_pool, tables, lengths = _paged_setup(1, [5], 1, bs=8, mb=4)
+    q4, k_pool, v_pool, tables, lengths, layer = _paged_setup(
+        1, [5], 1, bs=8, mb=4)
     with tpu_interpret_mode():
-        out1 = decode_attention_paged(q4, k_pool, v_pool, tables, lengths)
+        out1 = decode_attention_paged(q4, k_pool, v_pool, tables, lengths,
+                                      layer)
     kp = np.asarray(k_pool).copy()
     vp = np.asarray(v_pool).copy()
     owned = set(int(b) for b in np.asarray(tables)[0, :1])
-    for blk in range(kp.shape[0]):
+    for blk in range(kp.shape[1]):
         if blk not in owned:
-            kp[blk] = 9999.0
-            vp[blk] = -9999.0
-    kp[list(owned)[0], 6:] = 4444.0  # beyond the valid prefix, same block
-    vp[list(owned)[0], 6:] = -4444.0
+            kp[:, blk] = 9999.0
+            vp[:, blk] = -9999.0
+    # beyond the valid prefix in the same block, and the same block of
+    # every OTHER layer
+    kp[:, list(owned)[0], 6:] = 4444.0
+    vp[:, list(owned)[0], 6:] = -4444.0
+    for other in set(range(LAYERS)) - {layer}:
+        kp[other] = 5555.0
+        vp[other] = -5555.0
     with tpu_interpret_mode():
         out2 = decode_attention_paged(q4, jnp.asarray(kp), jnp.asarray(vp),
-                                      tables, lengths)
+                                      tables, lengths, layer)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2))
 
 
@@ -250,8 +281,8 @@ def _aliased_setup(bs=8, mb=4, H=2, D=64, seed=0):
 
     rng = np.random.default_rng(seed)
     nb = 1 + 6
-    k_pool = rng.normal(size=(nb, bs, H, D)).astype(np.float32)
-    v_pool = rng.normal(size=(nb, bs, H, D)).astype(np.float32)
+    k_pool = rng.normal(size=(LAYERS, nb, bs, H * D)).astype(np.float32)
+    v_pool = rng.normal(size=(LAYERS, nb, bs, H * D)).astype(np.float32)
     # rows share physical blocks 1,2 (16 shared prefix tokens); row 0
     # owns private block 3, row 1 owns private blocks 4,5
     tables = np.asarray([[1, 2, 3, GARBAGE_BLOCK],
@@ -259,7 +290,7 @@ def _aliased_setup(bs=8, mb=4, H=2, D=64, seed=0):
     lengths = np.asarray([19, 27], np.int32)
     q4 = rng.normal(size=(2, 1, H, D)).astype(np.float32)
     return (jnp.asarray(q4), jnp.asarray(k_pool), jnp.asarray(v_pool),
-            jnp.asarray(tables), jnp.asarray(lengths))
+            jnp.asarray(tables), jnp.asarray(lengths), LAYERS - 1)
 
 
 def test_paged_aliased_tables_match_dense():
@@ -282,31 +313,41 @@ def test_paged_aliased_garbage_isolation():
     blocks only ever contribute their fully-valid rows."""
     from deepspeed_tpu.ops.decode_attention import decode_attention_paged
 
-    q4, k_pool, v_pool, tables, lengths = _aliased_setup()
+    q4, k_pool, v_pool, tables, lengths, layer = _aliased_setup()
     with tpu_interpret_mode():
-        out1 = decode_attention_paged(q4, k_pool, v_pool, tables, lengths)
+        out1 = decode_attention_paged(q4, k_pool, v_pool, tables, lengths,
+                                      layer)
     kp = np.asarray(k_pool).copy()
     vp = np.asarray(v_pool).copy()
-    kp[6] = 9999.0          # unowned block
-    vp[6] = -9999.0
-    kp[3, 4:] = 4444.0      # row 0 private tail: valid rows [0, 19-16+1)
-    vp[3, 4:] = -4444.0
-    kp[5, 4:] = 4444.0      # row 1 private tail: valid rows [0, 27-24+1)
-    vp[5, 4:] = -4444.0
+    kp[:, 6] = 9999.0       # unowned block
+    vp[:, 6] = -9999.0
+    kp[:, 3, 4:] = 4444.0   # row 0 private tail: valid rows [0, 19-16+1)
+    vp[:, 3, 4:] = -4444.0
+    kp[:, 5, 4:] = 4444.0   # row 1 private tail: valid rows [0, 27-24+1)
+    vp[:, 5, 4:] = -4444.0
     with tpu_interpret_mode():
         out2 = decode_attention_paged(q4, jnp.asarray(kp), jnp.asarray(vp),
-                                      tables, lengths)
+                                      tables, lengths, layer)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2))
 
 
 # ---------------------------------------------------------------------------
 # int8 paged variant (the serving kv_cache_dtype: "int8" codec)
 # ---------------------------------------------------------------------------
-def _int8_pools(k_pool, v_pool):
+def _int8_pools(k_pool, v_pool, H):
+    """The pools as the model's write path stores them: rows quantized per
+    token x head (``quantize_rowwise`` over ``[..., H, D]``), int8 rows
+    back to ``H*D`` lanes, the scales a lane a head in whole registers."""
+    from deepspeed_tpu.ops.decode_attention import scale_lanes
     from deepspeed_tpu.ops.quantizer import quantize_rowwise
 
-    kq, ks = quantize_rowwise(jnp.asarray(k_pool))
-    vq, vs = quantize_rowwise(jnp.asarray(v_pool))
+    def quant(pool):
+        q, s = quantize_rowwise(jnp.asarray(pool, jnp.float32).reshape(
+            pool.shape[:3] + (H, -1)))
+        return q.reshape(pool.shape), jnp.pad(
+            s[..., 0], ((0, 0),) * 3 + ((0, scale_lanes(H) - H),))
+
+    (kq, ks), (vq, vs) = quant(k_pool), quant(v_pool)
     return kq, vq, ks, vs
 
 
@@ -322,18 +363,13 @@ def test_paged_int8_kernel_matches_dequant_oracle(lengths, tq):
     from deepspeed_tpu.ops.decode_attention import (
         decode_attention_paged_int8, gather_paged_cache_int8)
 
-    q4, k_pool, v_pool, tables, lens = _paged_setup(
+    q4, k_pool, v_pool, tables, lens, layer = _paged_setup(
         len(lengths), lengths, tq, bs=32, mb=4, seed=sum(lengths) + tq)
-    kq, vq, ks, vs = _int8_pools(k_pool, v_pool)
+    kq, vq, ks, vs = _int8_pools(k_pool, v_pool, q4.shape[2])
     with tpu_interpret_mode():
-        out = decode_attention_paged_int8(q4, kq, vq, ks, vs, tables, lens)
-    B = q4.shape[0]
-    S = tables.shape[-1] * k_pool.shape[1]
-    kd = gather_paged_cache_int8(kq, ks, tables).transpose(0, 2, 1, 3)
-    vd = gather_paged_cache_int8(vq, vs, tables).transpose(0, 2, 1, 3)
-    mask = cache_attn_mask(S, lens, tq)
-    ref = attention_reference(q4.transpose(0, 2, 1, 3), kd, vd, mask=mask,
-                              causal=False).transpose(0, 2, 1, 3)
+        out = decode_attention_paged_int8(q4, kq, vq, ks, vs, tables, lens,
+                                          layer)
+    ref = _int8_dense_ref(q4, kq, vq, ks, vs, tables, lens, layer)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -343,23 +379,135 @@ def test_paged_int8_error_vs_f32_pinned():
     f32 paged path. Per-row symmetric int8 on unit-normal KV keeps the
     attention output within a few percent — regressions in the codec
     (wrong scale axis, asymmetric drift) blow straight through this."""
-    from deepspeed_tpu.ops.decode_attention import decode_attention_paged
-
     args = _paged_setup(2, [17, 40], 1, bs=32, mb=4, seed=7)
-    q4, k_pool, v_pool, tables, lens = args
+    q4, k_pool, v_pool, tables, lens, layer = args
     ref = _paged_dense_ref(*args)
-    from deepspeed_tpu.models.decode_utils import cache_attn_mask
-    from deepspeed_tpu.ops.decode_attention import gather_paged_cache_int8
-
-    kq, vq, ks, vs = _int8_pools(k_pool, v_pool)
-    S = tables.shape[-1] * k_pool.shape[1]
-    kd = gather_paged_cache_int8(kq, ks, tables).transpose(0, 2, 1, 3)
-    vd = gather_paged_cache_int8(vq, vs, tables).transpose(0, 2, 1, 3)
-    mask = cache_attn_mask(S, lens, 1)
-    out = attention_reference(q4.transpose(0, 2, 1, 3), kd, vd, mask=mask,
-                              causal=False).transpose(0, 2, 1, 3)
+    out = _int8_dense_ref(q4, *_int8_pools(k_pool, v_pool, q4.shape[2]),
+                          tables, lens, layer)
     err = np.max(np.abs(np.asarray(out) - np.asarray(ref)))
     assert err < 0.05, f"int8 KV attention error {err} past the pinned budget"
+
+
+@pytest.mark.parametrize("layer", [0, LAYERS - 1])
+@pytest.mark.parametrize("tq", [1, 4])
+@pytest.mark.parametrize("H,D", [(2, 64), (2, 128), (3, 64), (5, 32)])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_stacked_lane_dense_pool_forms(layer, tq, H, D, kv):
+    """The kernel's one pool form, ``[L, nb, bs, H*D]`` addressed by
+    ``(layer, block)``: first and last layer, decode and verify rows, head
+    sizes 64 and 128, rows that are no multiple of 128 lanes (3 x 64, 5 x
+    32), bf16 and int8 pools — each against the dense oracle over the SAME
+    stored numbers, so the tolerance is the kernel's own arithmetic (bf16
+    probabilities into the value matmul), not the storage format's."""
+    from deepspeed_tpu.ops.decode_attention import (
+        decode_attention_paged, decode_attention_paged_int8)
+
+    dtype = jnp.bfloat16 if kv == "bf16" else np.float32
+    q4, k_pool, v_pool, tables, lens, _ = _paged_setup(
+        2, [9, 40], tq, bs=16, mb=4, H=H, D=D, seed=H * D + tq,
+        dtype=dtype)
+    if kv == "bf16":
+        with tpu_interpret_mode():
+            out = decode_attention_paged(q4, k_pool, v_pool, tables, lens,
+                                         layer)
+        ref = _paged_dense_ref(q4, k_pool, v_pool, tables, lens, layer)
+        tol = 2e-2
+    else:
+        kq, vq, ks, vs = _int8_pools(k_pool, v_pool, H)
+        with tpu_interpret_mode():
+            out = decode_attention_paged_int8(q4, kq, vq, ks, vs, tables,
+                                              lens, layer)
+        ref = _int8_dense_ref(q4, kq, vq, ks, vs, tables, lens, layer)
+        tol = 2e-5
+    assert out.shape == q4.shape and out.dtype == q4.dtype
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
+                               rtol=tol, atol=tol)
+
+
+def test_paged_pool_shape_is_checked():
+    """A pool row must hold exactly the query's heads x dim lanes, and a
+    scale row one lane a head."""
+    from deepspeed_tpu.ops.decode_attention import (
+        decode_attention_paged, decode_attention_paged_int8)
+
+    q4, k_pool, v_pool, tables, lens, _ = _paged_setup(1, [5], 1, bs=8,
+                                                       mb=4)
+    with pytest.raises(ValueError, match="lanes"):
+        decode_attention_paged(q4, k_pool[..., :64], v_pool[..., :64],
+                               tables, lens)
+    kq, vq, ks, vs = _int8_pools(k_pool, v_pool, q4.shape[2])
+    with pytest.raises(ValueError, match="scale pool shape"):
+        decode_attention_paged_int8(q4, kq, vq, ks[..., :1], vs[..., :1],
+                                    tables, lens)
+
+
+@pytest.mark.heavy
+@pytest.mark.parametrize("kv", ["", "int8"])
+@pytest.mark.parametrize("scan_layers", [True, False],
+                         ids=["scanned", "unrolled"])
+def test_paged_model_steps_kernel_matches_dense(monkeypatch, scan_layers, kv):
+    """End-to-end through the model: a paged prefill, two decode steps and
+    a 3-row verify step of a scanned stack (pool carried through the layer
+    scan, the layer index scanned in) and of an unrolled one (static layer
+    index), with the kernel reading the stacked pool at ``(layer, block)``,
+    give the dense gather path's logits and leave the same pool behind."""
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
+    from deepspeed_tpu.ops import attention as attn_mod
+
+    cfg = GPT2Config.tiny(n_positions=64, dtype=jnp.float32,
+                          scan_layers=scan_layers)
+    model = GPT2LMHeadModel(cfg.for_paged_decode(9, 8, kv))
+    rng = np.random.default_rng(0)
+    tables = jnp.asarray([[3, 1, 5, 0], [2, 7, 4, 0]], jnp.int32)
+    prompt = jnp.asarray(rng.integers(0, 256, (2, 8)), jnp.int32)
+    n_prompt = jnp.asarray([6, 8], jnp.int32)
+
+    def paging(lengths, num_valid, prefill=False):
+        return {"block_tables": tables, "lengths": lengths,
+                "num_valid": num_valid, "prefill": prefill}
+
+    variables = model.init(jax.random.PRNGKey(0), prompt,
+                           paging=paging(jnp.zeros((2,), jnp.int32),
+                                         n_prompt, True))
+    params = {"params": variables["params"]}
+    cache0 = jax.tree_util.tree_map(jnp.zeros_like, variables["cache"])
+    assert {k: v.shape for k, v in cache0["transformer"].items()} == {
+        **{f"{n}_pool": (cfg.n_layer, 9, 8, cfg.n_embd)
+           for n in ("key", "value")},
+        **({f"{n}_scale": (cfg.n_layer, 9, 8, 128)
+            for n in ("key", "value")} if kv else {})}
+
+    def run(force):
+        monkeypatch.setattr(attn_mod, "_FORCE_DECODE_KERNEL", force)
+        outs, cache, lengths = [], cache0, n_prompt
+        with tpu_interpret_mode() if force else _null():
+            _, vars_ = model.apply({**params, "cache": cache}, prompt,
+                                   mutable=["cache"], paging=paging(
+                                       jnp.zeros((2,), jnp.int32), n_prompt,
+                                       True))
+            cache = jax.block_until_ready(vars_["cache"])
+            for t in (1, 1, 3):
+                tok = jnp.asarray(rng_tokens[len(outs)][:, :t])
+                logits, vars_ = model.apply(
+                    {**params, "cache": cache}, tok, mutable=["cache"],
+                    paging=paging(lengths, jnp.full((2,), t, jnp.int32)))
+                logits, cache = jax.block_until_ready(
+                    (logits, vars_["cache"]))
+                lengths = lengths + t
+                outs.append(np.asarray(logits))
+        return outs, cache
+
+    rng_tokens = rng.integers(0, 256, (3, 2, 3)).astype(np.int32)
+    dense, dense_cache = run(False)
+    kern, kern_cache = run(True)
+    for a, b in zip(dense, kern):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+    for a, b in zip(jax.tree_util.tree_leaves(dense_cache),
+                    jax.tree_util.tree_leaves(kern_cache)):
+        # garbage block 0 takes the pads' writes; everything else agrees
+        np.testing.assert_allclose(np.asarray(a, np.float32)[:, 1:],
+                                   np.asarray(b, np.float32)[:, 1:],
+                                   rtol=2e-4, atol=2e-4)
 
 
 def test_quantize_rowwise_roundtrip():

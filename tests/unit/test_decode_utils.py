@@ -13,7 +13,7 @@ import pytest
 from deepspeed_tpu.models.decode_utils import (cache_attn_mask,
                                                decode_positions, pad_lengths,
                                                paged_positions,
-                                               paged_write_rows,
+                                               paged_write_slots,
                                                row_positions,
                                                validate_left_padded_mask)
 
@@ -112,6 +112,13 @@ class TestPositionHelpers:
             validate_left_padded_mask(ids, jnp.asarray([[0, 0, 0], [1, 1, 1]]))
         assert validate_left_padded_mask(
             ids, jnp.asarray([[1, 1, 1], [1, 1, 1]])) is None  # fast path
+
+
+def paged_write_rows(tables, pos, num_valid, bs):
+    """Flattened pool rows of ``paged_write_slots``' (block, offset)."""
+    blk, off = paged_write_slots(tables, pos, num_valid, bs)
+    assert blk.shape == off.shape == pos.shape
+    return blk * bs + off
 
 
 class TestPagedHelpers:

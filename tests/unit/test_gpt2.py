@@ -42,6 +42,35 @@ class TestModel:
             params = m.init(jax.random.PRNGKey(0), ids)["params"]
             assert m.apply({"params": params}, ids).shape == (2, 16, 256)
 
+    @pytest.mark.parametrize("scan", [True, False])
+    def test_train_forward_has_no_kv_pool(self, scan):
+        """The block stack owns the serving KV pool, but only a
+        paged-decode model has one: the train forward (no ``paging``,
+        ``decode`` off) creates no ``cache`` variable at init or at apply,
+        and its scan carries ``x`` alone — in the traced program the layer
+        loop has no operand beyond the seed's (the lowered train step of
+        the benchmark's medium cell is text-identical across PR 27)."""
+        cfg = GPT2Config.tiny(dtype=jnp.float32, scan_layers=scan,
+                              remat=True, remat_policy="dots")
+        m = GPT2LMHeadModel(cfg)
+        ids = jnp.ones((2, 16), jnp.int32)
+        variables = m.init(jax.random.PRNGKey(0), ids)
+        assert set(variables) == {"params"}
+        logits, mutated = m.apply({"params": variables["params"]}, ids,
+                                  mutable=["cache"])
+        assert logits.shape == (2, 16, 256) and not mutated.get("cache")
+        if scan:
+            jaxpr = jax.make_jaxpr(lambda p: m.apply({"params": p}, ids))(
+                variables["params"])
+            scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+            # carry: x; scanned in: the stacked params and the layer
+            # fractions; no pool, no layer index
+            stacked = len(jax.tree_util.tree_leaves(
+                variables["params"]["transformer"]))
+            assert len(scans) == 1 and scans[0].params["num_carry"] == 1
+            assert len(scans[0].invars) - scans[0].params["num_consts"] \
+                == 1 + stacked + 1
+
     def test_causality(self):
         """Changing a future token must not change past logits."""
         cfg = GPT2Config.tiny(dtype=jnp.float32)

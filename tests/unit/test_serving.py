@@ -1086,6 +1086,160 @@ class TestServingFastPath:
 
 
 # ---------------------------------------------------------------------------
+# the pool's one resident form: [layers, blocks, block_size, lanes]
+# ---------------------------------------------------------------------------
+def _pool_engine(scan_layers=True, serving=None):
+    """A tiny engine whose block stack is scanned or unrolled."""
+    import jax.numpy as jnp
+
+    import deepspeed_tpu
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
+    from deepspeed_tpu.parallel.topology import reset_topology
+
+    reset_topology()
+    cfg = GPT2Config.tiny(dtype=jnp.float32, scan_layers=scan_layers)
+    kwargs = {} if serving is None else {"serving": serving}
+    return cfg, deepspeed_tpu.init_inference(
+        GPT2LMHeadModel(cfg), dtype="fp32", seed=3, **kwargs)
+
+
+@pytest.mark.heavy
+class TestOneResidentPool:
+    @pytest.mark.parametrize("sampled", [False, True],
+                             ids=["greedy", "keyed"])
+    @pytest.mark.parametrize("scan_layers", [True, False],
+                             ids=["scanned", "unrolled"])
+    def test_served_tokens_are_generates(self, scan_layers, sampled):
+        """A scanned stack (the pool rides the layer scan as carry) and an
+        unrolled one (``LoopBlocks`` threads the same stacked pool through
+        its layers at a static index) serve, staggered, exactly the tokens
+        ``generate()`` produces through the append cache — the path this
+        change does not touch — for greedy and for keyed sampling. (The
+        paged kernel under the same two stacks:
+        ``test_decode_attention.py::test_paged_model_steps_kernel_matches_dense``.)"""
+        import jax.numpy as jnp
+
+        from deepspeed_tpu.serving import ServingEngine
+
+        serving = dict(_SERVING)
+        if sampled:
+            serving["sampling"] = {"enabled": True}
+        cfg, engine = _pool_engine(scan_layers, serving)
+        srv = ServingEngine(engine)
+        pools = dict(_cache_leaves(srv.cache))
+        assert {n: l.shape for n, l in pools.items()} == {
+            n: (cfg.n_layer, srv.num_blocks, 8, cfg.n_embd)
+            for n in ("key_pool", "value_pool")}
+        rng = np.random.default_rng(5)
+        prompts = [rng.integers(1, 256, n) for n in (5, 11, 3, 9)]
+        news = [5, 3, 4, 3]
+        knobs = [dict(do_sample=True, seed=11 * (i + 1), temperature=0.9,
+                      top_p=0.9) if sampled and i % 2 == 0 else {}
+                 for i in range(len(prompts))]
+        reqs = [srv.submit(p, max_new_tokens=n, **k)
+                for p, n, k in zip(prompts[:2], news, knobs)]
+        srv.step()
+        srv.step()
+        reqs += [srv.submit(p, max_new_tokens=n, **k)
+                 for p, n, k in zip(prompts[2:], news[2:], knobs[2:])]
+        srv.drain()
+        _, ref = _pool_engine(scan_layers)
+        ref.params = engine.params
+        for req, p, n, k in zip(reqs, prompts, news, knobs):
+            assert req.state == FINISHED, (req.state, req.finish_reason)
+            out = ref.generate(jnp.asarray(p[None]), max_new_tokens=n,
+                               **(k or {"do_sample": False}))
+            assert req.tokens == [int(t) for t in out[0, len(p):]]
+        assert srv.block_mgr.num_free == srv.num_blocks - 1
+
+    @pytest.mark.parametrize("kv", ["", "int8"])
+    def test_cow_copies_one_block_of_every_layer(self, kv):
+        """The cow program copies block ``src`` onto ``dst`` on the pool's
+        block axis, in every leaf (int8: the scale rows too) and every
+        layer, and touches no other block."""
+        from deepspeed_tpu.serving import ServingEngine
+
+        _, engine = _pool_engine(serving={**_SERVING, "prefix_cache": True,
+                                          "kv_cache_dtype": kv})
+        srv = ServingEngine(engine)
+        before = _random_pool(srv)
+        srv._cow_copy(3, 5)
+        after = {n: np.asarray(l) for n, l in _cache_leaves(srv.cache)}
+        assert len(after) == (4 if kv else 2)
+        for name, old in before.items():
+            want = old.copy()
+            want[:, 5] = old[:, 3]
+            np.testing.assert_array_equal(after[name], want)
+
+    @pytest.mark.parametrize("kv", ["", "int8"])
+    def test_export_import_round_trips_blocks(self, kv):
+        """``export_sequence`` gathers a sequence's blocks on the block
+        axis (all layers of a block together; a scale row's padding lanes
+        stay home) and ``import_sequence`` scatters them onto another
+        pool's freshly allocated blocks: the same rows, in every leaf and
+        layer, and the moved request finishes with the tokens it would
+        have produced at home."""
+        from deepspeed_tpu.serving import ServingEngine
+
+        serving = {**_SERVING, "kv_cache_dtype": kv}
+        cfg, e0 = _pool_engine(serving=serving)
+        _, e1 = _pool_engine(serving=serving)
+        e1.params = e0.params
+        src, dst, home = (ServingEngine(e0), ServingEngine(e1),
+                          ServingEngine(e0))
+        prompt = np.arange(1, 14)
+        expect = home.generate_batch([prompt], max_new_tokens=6)[0]
+        dst.generate_batch([np.arange(3, 9)], max_new_tokens=2)  # dirty it
+        req = src.submit(prompt, max_new_tokens=6)
+        for _ in range(3):
+            src.step()
+        export = src.export_sequence(req.request_id)
+        assert export["blocks"] == 2 and len(export["rows"]) == (
+            4 if kv else 2)
+        lanes = {"key_pool": cfg.n_embd, "value_pool": cfg.n_embd,
+                 "key_scale": cfg.n_head, "value_scale": cfg.n_head}
+        for (name, _), chunks in zip(_cache_leaves(src.cache),
+                                     export["rows"]):
+            assert [c.shape for c in chunks] == [
+                (cfg.n_layer, 2, 8, lanes[name])], name
+        moved = dst.import_sequence(export)
+        assert moved is not None
+        src_blocks = src.block_mgr.owned(req.request_id)[:2]
+        dst_blocks = dst.block_mgr.owned(moved.request_id)[:2]
+        got = dict(_cache_leaves(dst.cache))
+        for name, leaf in _cache_leaves(src.cache):
+            np.testing.assert_array_equal(
+                np.asarray(got[name])[:, dst_blocks],
+                np.asarray(leaf)[:, src_blocks])
+        assert src.migrate_out(req.request_id)
+        dst.drain()
+        assert moved.tokens == expect
+
+
+def _cache_leaves(cache):
+    """``[(leaf name, array)]`` of a serving cache."""
+    import jax
+
+    return [(path[-1].key, leaf) for path, leaf in
+            jax.tree_util.tree_flatten_with_path(cache)[0]]
+
+
+def _random_pool(srv):
+    """Fill every leaf of ``srv.cache`` with distinct numbers; returns
+    them as numpy by leaf name."""
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(9)
+    vals = {n: rng.integers(-100, 100, l.shape).astype(l.dtype)
+            for n, l in _cache_leaves(srv.cache)}
+    srv.cache = jax.tree_util.tree_map_with_path(
+        lambda p, l: jax.device_put(jnp.asarray(vals[p[-1].key]),
+                                    l.sharding), srv.cache)
+    return vals
+
+
+# ---------------------------------------------------------------------------
 # speculative decoding: the k-token verify program (heavy)
 # ---------------------------------------------------------------------------
 _SPEC = {**_SERVING, "speculative": {"num_speculative_tokens": 3}}

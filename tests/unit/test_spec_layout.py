@@ -469,32 +469,52 @@ class TestInjectedLayers:
 
 
 class TestTPKernels:
-    def test_paged_tp_matches_dense_oracle(self):
-        """The TP-aware paged decode kernel (heads over tp, per-shard
-        pools) equals the dense gather oracle on a tp=2 mesh (interpret
-        mode on CPU)."""
+    @staticmethod
+    def _stacked(kv, L=2, B=2, H=4, D=8, nb=4, bs=8, seed=0):
+        """``(q, pools)`` in the pool's one shape ``[L, nb, bs, lanes]``:
+        K/V rows of ``H*D`` lanes and, for int8, the scale rows (a lane a
+        head, whole registers)."""
+        from deepspeed_tpu.ops.decode_attention import scale_lanes
+        from deepspeed_tpu.ops.quantizer import quantize_rowwise
+
+        rng = np.random.default_rng(seed)
+        q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
+        kv5 = [jnp.asarray(rng.normal(size=(L, nb, bs, H, D)), jnp.float32)
+               for _ in range(2)]
+        if kv != "int8":
+            return q, tuple(p.reshape(L, nb, bs, H * D) for p in kv5)
+        quant = [quantize_rowwise(p) for p in kv5]
+        pad = ((0, 0),) * 3 + ((0, scale_lanes(H) - H),)
+        return q, (tuple(qp.reshape(L, nb, bs, H * D) for qp, _ in quant)
+                   + tuple(jnp.pad(sc[..., 0], pad) for _, sc in quant))
+
+    @pytest.mark.parametrize("layer", [0, 1])
+    @pytest.mark.parametrize("kv", ["", "int8"])
+    def test_paged_tp_matches_dense_oracle(self, kv, layer):
+        """The TP-aware paged decode kernel (K/V lanes split into tp
+        groups of contiguous heads, per-shard pools; the int8 scale rows
+        whole, each shard finding its heads in them) equals the dense
+        gather oracle on a tp=2 mesh (interpret mode on CPU)."""
         from deepspeed_tpu.ops import attention as attn_mod
-        from deepspeed_tpu.ops.decode_attention import (
-            decode_attention_paged_tp, gather_paged_cache)
+        from deepspeed_tpu.ops import decode_attention as da
         from deepspeed_tpu.utils.compat import tpu_interpret_mode
 
         mesh = MeshTopology(axis_sizes={"tp": 2},
                             devices=jax.devices()[:2]).mesh
-        B, H, D, nb, bs = 2, 4, 8, 4, 8
-        rng = np.random.default_rng(0)
-        q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
-        kp = jnp.asarray(rng.normal(size=(nb, bs, H, D)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(nb, bs, H, D)), jnp.float32)
+        q, pools = self._stacked(kv)
+        H, bs = q.shape[2], pools[0].shape[2]
         tables = jnp.asarray([[1, 2], [3, 1]], jnp.int32)
         lengths = jnp.asarray([5, 9], jnp.int32)
-        # write the current-step key at each row's position so the
-        # kernel's causal row sees itself (mirrors the model's scatter)
+        fn = (da.decode_attention_paged_int8_tp if kv == "int8"
+              else da.decode_attention_paged_tp)
         with tpu_interpret_mode():
-            got = decode_attention_paged_tp(q, kp, vp, tables,
-                                            lengths, mesh=mesh)
-        # dense oracle
-        kd = gather_paged_cache(kp, tables)
-        vd = gather_paged_cache(vp, tables)
+            got = fn(q, *pools, tables, lengths, layer, mesh=mesh)
+        if kv == "int8":
+            kd, vd = (da.gather_paged_cache_int8(p, sc, tables, layer, H)
+                      for p, sc in zip(pools[:2], pools[2:]))
+        else:
+            kd, vd = (da.gather_paged_cache(p, tables, layer, H)
+                      for p in pools)
         S = tables.shape[-1] * bs
         pos = jnp.arange(S)[None, :]
         mask = (pos <= lengths[:, None])[:, None, None, :]
@@ -505,24 +525,67 @@ class TestTPKernels:
             np.asarray(got), np.asarray(ref.transpose(0, 2, 1, 3)),
             rtol=2e-5, atol=2e-5)
 
-    def test_tp_wrapper_falls_back_off_mesh(self):
+    @pytest.mark.parametrize("kv", ["", "int8"])
+    def test_tp_wrapper_falls_back_off_mesh(self, kv):
         """With no live tp axis the wrapper IS the plain kernel call —
         the zero-overhead contract at tp=1."""
-        from deepspeed_tpu.ops.decode_attention import (
-            decode_attention_paged, decode_attention_paged_tp)
+        from deepspeed_tpu.ops import decode_attention as da
         from deepspeed_tpu.utils.compat import tpu_interpret_mode
 
-        B, H, D, nb, bs = 1, 4, 8, 3, 8
-        rng = np.random.default_rng(1)
-        q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
-        kp = jnp.asarray(rng.normal(size=(nb, bs, H, D)), jnp.float32)
-        vp = jnp.asarray(rng.normal(size=(nb, bs, H, D)), jnp.float32)
+        q, pools = self._stacked(kv, B=1, nb=3, seed=1)
         tables = jnp.asarray([[1, 2]], jnp.int32)
         lengths = jnp.asarray([4], jnp.int32)
+        tp, plain = ((da.decode_attention_paged_int8_tp,
+                      da.decode_attention_paged_int8) if kv == "int8" else
+                     (da.decode_attention_paged_tp,
+                      da.decode_attention_paged))
         with tpu_interpret_mode():
-            a = decode_attention_paged_tp(q, kp, vp, tables, lengths)
-            b = decode_attention_paged(q, kp, vp, tables, lengths)
+            a = tp(q, *pools, tables, lengths, 1)
+            b = plain(q, *pools, tables, lengths, 1)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("heads,tp,sharded", [(4, 2, True),
+                                                  (3, 2, False),
+                                                  (4, 1, False)])
+    @pytest.mark.parametrize("kv", ["", "int8"])
+    def test_decode_cache_specs_shard_the_head_lanes(self, kv, heads, tp,
+                                                     sharded):
+        """K/V pools shard their LANE axis (``heads / tp`` contiguous heads
+        a shard) when the heads divide over tp and replicate when they do
+        not, or when the caller does not say how many heads a row holds;
+        the int8 scale rows always replicate."""
+        from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel
+        from deepspeed_tpu.module_inject.policies import decode_cache_specs
+        from deepspeed_tpu.ops.decode_attention import (POOL_LANE_AXIS,
+                                                        scale_lanes)
+
+        mesh = MeshTopology(axis_sizes={"tp": tp},
+                            devices=jax.devices()[:tp]).mesh
+        cfg = GPT2Config.tiny(n_head=heads, n_embd=heads * 16,
+                              dtype=jnp.float32)
+        module = GPT2LMHeadModel(cfg.for_paged_decode(5, 8, kv))
+        pg = {"block_tables": jnp.zeros((1, 2), jnp.int32),
+              "lengths": jnp.zeros((1,), jnp.int32),
+              "num_valid": jnp.ones((1,), jnp.int32), "prefill": True}
+        cache = jax.eval_shape(lambda: module.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
+            paging=pg))["cache"]
+        leaves = dict(_flat_paths(cache))
+        assert {p.rsplit("/", 1)[-1]: l.shape for p, l in leaves.items()} \
+            == {**{f"{n}_pool": (cfg.n_layer, 5, 8, heads * 16)
+                   for n in ("key", "value")},
+                **({f"{n}_scale": (cfg.n_layer, 5, 8, scale_lanes(heads))
+                    for n in ("key", "value")} if kv else {})}
+        specs = dict(_flat_paths(decode_cache_specs(cache, mesh,
+                                                    heads=heads)))
+        for path, sh in specs.items():
+            want = [None] * 4
+            if sharded and path.endswith("_pool"):
+                want[POOL_LANE_AXIS] = "tp"
+            assert tuple(sh.spec) + (None,) * (4 - len(sh.spec)) \
+                == tuple(want), (path, sh.spec)
+        for sh in jax.tree_util.tree_leaves(decode_cache_specs(cache, mesh)):
+            assert not any(sh.spec), sh.spec
 
 
 class TestTPStepCost:

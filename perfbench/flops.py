@@ -1,11 +1,9 @@
-"""Operations and bytes computed from shapes: the benchmark's own copy, so
-that a utilization cannot drift with the program.
-
-``train_flops_per_token`` is the formula of
-``deepspeed_tpu/profiling/flops_profiler.transformer_flops_per_token``
-(6N + 12 L T d per token: forward 2N + 4 L T d, backward twice that;
-recomputed operations are not counted). The kernel functions give what the
-ALGORITHM needs, not what an implementation happens to do.
+"""Published peaks, and the operations and bytes of kernels computed from
+shapes: the benchmark's own copy, so that a utilization cannot drift with
+the program. The kernel functions take shapes and no model, and give what
+the ALGORITHM needs, not what an implementation happens to do. What a
+model needs per token (parameters, training operations) is its family's
+(``perfbench/families/``).
 """
 
 import json
@@ -25,23 +23,6 @@ def peaks(device_kind: str) -> dict:
             f"perfbench/peaks.json (known: {sorted(table)}); add the kind "
             "with its source, nothing is assumed for an unknown chip")
     return table[device_kind]
-
-
-def gpt2_param_count(model: dict) -> int:
-    """Parameters of a GPT-2 of these sizes, tied head counted once."""
-    d, layers = model["n_embd"], model["n_layer"]
-    per_layer = (d * 3 * d + 3 * d      # c_attn
-                 + d * d + d            # attn c_proj
-                 + d * 4 * d + 4 * d    # c_fc
-                 + 4 * d * d + d        # mlp c_proj
-                 + 4 * d)               # ln_1, ln_2
-    return (model["vocab_size"] * d + model["n_positions"] * d
-            + layers * per_layer + 2 * d)
-
-
-def train_flops_per_token(model: dict, seq_len: int) -> float:
-    n = gpt2_param_count(model)
-    return 6.0 * n + 12.0 * model["n_layer"] * seq_len * model["n_embd"]
 
 
 def flash_train_flops(batch: int, heads: int, seq: int, head_dim: int,

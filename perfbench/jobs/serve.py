@@ -5,7 +5,11 @@ on a loopback port, driven over HTTP/SSE by the open-loop load generator
 The cell's ``serve`` block: ``serving`` (the program's ``serving`` config
 block: ``decode_slots``, ``block_size``, ...), ``gateway`` (its gateway
 block), and for the traced run ``trace_start_s`` / ``trace_seconds``: the
-profiler runs over a steady slice of a shorter window.
+profiler runs over a steady slice of a shorter window. The model comes from
+the configuration's family (``perfbench/families/``). The longest context
+the cell serves, which sizes the pool, is the longest its traffic sends:
+the mix's ``max_total``, at most the family's largest, which it is where
+the mix gives none.
 
 Every time is taken at the client. ``ttft``: from the moment the request
 was DUE to its first token event. ``tpot``: (last token arrival - first) /
@@ -30,8 +34,8 @@ import time
 
 import numpy as np
 
-from perfbench import reference_gpt2, traffic
-from perfbench.model_config import gpt2_fields
+from perfbench import traffic
+from perfbench.byname import BenchError
 
 # The served logits are bf16 arithmetic, the reference's float32, so where
 # the reference holds two candidates closer than bf16 can tell apart,
@@ -65,17 +69,22 @@ def setup(cell: dict, seed: int, device: dict) -> dict:
     import jax.numpy as jnp
 
     import deepspeed_tpu
-    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
     from deepspeed_tpu.parallel.topology import reset_topology
     from deepspeed_tpu.serving import ServingEngine
     from deepspeed_tpu.serving.gateway import ServingGateway
 
-    job = cell["serve"]
-    model = cell["config_file"]["model"]
+    job, family, config_file = (cell["serve"], cell["family"],
+                                cell["config_file"])
     dtype = getattr(jnp, job.get("dtype", "bfloat16"))
+    vocab = family.vocab_size(config_file)
+    mix = cell["traffic_file"]
+    largest = family.max_context(config_file)
+    context = int(mix.get("max_total") or largest)
+    if context > largest:
+        raise BenchError(f"traffic mix {cell['traffic']!r} sends contexts of "
+                         f"{context}: the family serves at most {largest}")
     reset_topology()
-    cfg = GPT2Config(**gpt2_fields(cell["config_file"]), dtype=dtype)
-    module = GPT2LMHeadModel(cfg)
+    module = family.serving_module(config_file, dtype)
 
     # the weights: on the device, in one jitted call from the seed, in the
     # type they are served in
@@ -90,17 +99,16 @@ def setup(cell: dict, seed: int, device: dict) -> dict:
         module, params=make(jax.random.PRNGKey(int(seed) % (2 ** 31))),
         dtype=dtype, seed=int(seed) % (2 ** 31),
         tensor_parallel={"tp_size": int(cell["chips"])},
-        max_out_tokens=model["n_positions"], serving=job["serving"]))
+        max_out_tokens=context, serving=job["serving"]))
 
     # warm exactly the prefill buckets this mix's prompt lengths can hit,
     # and decode: one short request per bucket, straight into the engine
-    mix = cell["traffic_file"]
     lo, hi = mix["prompt_len"]["min"], mix["prompt_len"]["max"]
     rng = np.random.default_rng([int(seed), 11])
     hit = [b for i, b in enumerate(srv.buckets)
            if b >= lo and (i == 0 or srv.buckets[i - 1] < hi)]
     for bucket in hit:
-        srv.submit(rng.integers(0, model["vocab_size"], min(bucket, hi)),
+        srv.submit(rng.integers(0, vocab, min(bucket, hi)),
                    max_new_tokens=2)
         srv.drain()
     srv.reset_stats()
@@ -108,7 +116,8 @@ def setup(cell: dict, seed: int, device: dict) -> dict:
     gateway = ServingGateway(srv, {"pump": True, "poll_secs": 0.002,
                                    **job.get("gateway", {})}).start()
     state = {"cell": cell, "seed": seed, "srv": srv, "gateway": gateway,
-             "mix": mix, "model": model, "child": None}
+             "mix": mix, "vocab": vocab, "max_context": context,
+             "child": None}
     return state
 
 
@@ -120,7 +129,7 @@ def _start_child(state: dict, seconds: float):
     child.stdin.write(json.dumps({
         "url": state["gateway"].url, "mix": state["mix"],
         "seed": state["seed"], "seconds": seconds,
-        "vocab_size": state["model"]["vocab_size"], "warmup": 2}) + "\n")
+        "vocab_size": state["vocab"], "warmup": 2}) + "\n")
     child.stdin.flush()
     line = child.stdout.readline().strip()
     if line != "READY":
@@ -208,8 +217,8 @@ def run(state: dict, seconds: float, tracer) -> dict:
         "started_at": started_at, "metrics": metrics,
         "facts": {"ttft_ms": ttft, "queue_ms": queue, "decode_steps": steps,
                   "decode_slots": slots, "decode_tokens": every - started,
-                  "window_s": seconds, "model": state["model"],
-                  "requests": reqs, "traced_span_s": span},
+                  "window_s": seconds, "requests": reqs,
+                  "traced_span_s": span},
         "notes": {"requests": len(reqs), "finished": len(ok),
                   "window_s": seconds, "ttft_p50_ms": _pct(ttft, 50),
                   "ttft_p95_ms": _pct(ttft, 95),
@@ -236,24 +245,23 @@ def check(state: dict, result: dict) -> dict:
     import jax
     import jax.numpy as jnp
 
-    srv, model, seed = state["srv"], state["model"], state["seed"]
+    srv, cell, seed = state["srv"], state["cell"], state["seed"]
     reqs = state["requests"]
     window = result["notes"]["window_s"]
-    prompts = traffic.requests(state["mix"], seed, window, model["vocab_size"])
+    prompts = traffic.requests(state["mix"], seed, window, state["vocab"])
     done = [i for i, r in enumerate(reqs) if r["ok"] and r["tokens"]]
     rng = np.random.default_rng([int(seed), 13])
     picked = sorted(rng.choice(done, min(CHECKED_REQUESTS, len(done)),
                                replace=False).tolist()) if done else []
-    ref = jax.jit(reference_gpt2.logits, static_argnums=2)
-    width = model["n_positions"]
+    ref = jax.jit(cell["family"].reference_logits(cell["config_file"]))
+    width = state["max_context"]
     judged = exact = 0
     worst = 0.0
     for i in picked:
         prompt, served = prompts[i]["prompt"], reqs[i]["tokens"]
         ids = np.zeros((1, width), np.int32)  # right padding: causal, unseen
         ids[0, :len(prompt) + len(served)] = prompt + served
-        logits = np.asarray(ref(srv.engine.params, jnp.asarray(ids),
-                                model["n_head"]))[0]
+        logits = np.asarray(ref(srv.engine.params, jnp.asarray(ids)))[0]
         for k, token in enumerate(served):
             row = logits[len(prompt) - 1 + k]
             gap = float(row.max() - row[token]) / float(np.abs(row).max())

@@ -5,10 +5,11 @@ The cell's ``train`` block: ``micro_batch_per_chip``, ``zero_stage``,
 ``remat_policy``, ``mesh`` (null = ``{"data": chips}``), and optionally
 ``trace_seconds`` (the traced run's window, default 3 s) and
 ``reference_rows`` (rows of the first batch the reference sees at a time).
+The model comes from the configuration's family (``perfbench/families/``).
 
 ``correct``: every loss finite, and the first step's loss, the mean over
 ALL rows of the first batch, within ``LOSS_ATOL`` of the loss that
-``perfbench/reference_gpt2.py`` computes on those rows from the engine's
+the family's plain reference computes on those rows from the engine's
 own initial parameters. (No compile inside the window is the harness's.)
 """
 
@@ -17,8 +18,7 @@ import time
 
 import numpy as np
 
-from perfbench import flops, reference_gpt2, traffic
-from perfbench.model_config import gpt2_fields
+from perfbench import traffic
 
 # The engine computes in bf16 from f32 master weights, the reference in
 # f32 at the highest matmul precision. Per token the two log-likelihoods
@@ -37,15 +37,12 @@ def setup(cell: dict, seed: int, device: dict) -> dict:
     import jax.numpy as jnp
 
     import deepspeed_tpu
-    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2ForTraining
     from deepspeed_tpu.parallel.topology import reset_topology
 
     job, chips = cell["train"], int(cell["chips"])
-    model = cell["config_file"]["model"]
+    family, config_file = cell["family"], cell["config_file"]
     dtype = getattr(jnp, job.get("dtype", "bfloat16"))
     reset_topology()
-    cfg = GPT2Config(**gpt2_fields(cell["config_file"]), dtype=dtype,
-                     remat=True, remat_policy=job["remat_policy"])
     config = {
         "train_micro_batch_size_per_gpu": int(job["micro_batch_per_chip"]),
         "gradient_accumulation_steps": 1,
@@ -68,25 +65,26 @@ def setup(cell: dict, seed: int, device: dict) -> dict:
 
         topology = MeshTopology(axis_sizes=axes,
                                 devices=jax.devices()[:chips])
-    engine, *_ = deepspeed_tpu.initialize(model=GPT2ForTraining(cfg),
-                                          config=config, mesh=topology)
+    engine, *_ = deepspeed_tpu.initialize(
+        model=family.training_model(config_file, dtype, job["remat_policy"]),
+        config=config, mesh=topology)
     rows = int(job["micro_batch_per_chip"]) * chips
     mix = cell["traffic_file"]
     state = {"engine": engine, "cell": cell, "seed": seed, "rows": rows,
-             "mix": mix, "vocab": model["vocab_size"], "chips": chips,
-             "flops_per_token": flops.train_flops_per_token(
-                 model, int(mix["seq_len"]))}
+             "mix": mix, "vocab": family.vocab_size(config_file),
+             "chips": chips,
+             "flops_per_token": family.train_flops_per_token(
+                 config_file, int(mix["seq_len"]))}
 
     # the reference's loss on the first batch, from the initial parameters
     # (eval_batch is the public call that builds them without a step)
     first = traffic.train_batch(mix, seed, 0, rows, state["vocab"])
     chunk = int(job.get("reference_rows", 2))
     engine.eval_batch({"input_ids": first[:chunk]})
-    ref = jax.jit(reference_gpt2.next_token_loss, static_argnums=2)
+    ref = jax.jit(family.reference_loss(config_file))
     total = count = 0.0
     for i in range(0, rows, chunk):
-        nll, n = ref(engine.state.params, jnp.asarray(first[i:i + chunk]),
-                     model["n_head"])
+        nll, n = ref(engine.state.params, jnp.asarray(first[i:i + chunk]))
         total, count = total + float(nll), count + int(n)
     state["reference_first_loss"] = total / count
 

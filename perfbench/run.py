@@ -12,8 +12,9 @@ per-layer metrics. There is no fallback to another device: without a TPU
 (and without ``JAX_PLATFORMS=cpu`` asked for by name, which a test does
 and which proves nothing) the run exits non-zero and prints no result.
 
-Everything that belongs to one cell, configuration, traffic mix, job kind
-or per-layer metric is a file of its own, found by name: see README.md.
+Everything that belongs to one cell, configuration, model family, traffic
+mix, job kind, per-layer metric or kernel is a file of its own, found by
+name: see README.md.
 """
 
 import time
@@ -22,18 +23,16 @@ _T0 = time.perf_counter()  # process start, as near as Python lets us read it
 
 import argparse
 import contextlib
-import importlib
 import json
 import os
 import shutil
 import sys
 import tempfile
 
+from perfbench import byname
+from perfbench.byname import BenchError
+
 HERE = os.path.dirname(os.path.abspath(__file__))
-
-
-class BenchError(Exception):
-    """A run that cannot give a result; the message names what is wrong."""
 
 
 def say(**fields):
@@ -48,9 +47,42 @@ def _load_json(path: str, what: str) -> dict:
         return json.load(f)
 
 
+def _benchmark(root: str) -> dict:
+    return _load_json(os.path.join(os.path.dirname(root), "BENCHMARK.json"),
+                      "BENCHMARK.json")
+
+
+def check_cut(config_file: dict, declared=None):
+    """Hold a configuration file to the form of a cut (README.md).
+    ``reduced`` lists the keys of ``model`` that differ from the source, and
+    is what BENCHMARK.json ``declared`` for the configuration, where it
+    lists it. A cut file says what it was cut from, ``published`` (exactly
+    those keys, with the source's values), and what it stands for,
+    ``deployment``; an uncut one has neither."""
+    reduced, model = config_file["reduced"], config_file["model"]
+    wrong = None
+    if declared is not None and reduced != declared:
+        wrong = f"reduced {reduced} is not BENCHMARK.json's {declared}"
+    elif [k for k in reduced if k not in model]:
+        wrong = f"reduced {reduced} names a key that model lacks"
+    elif not reduced:
+        if "published" in config_file or "deployment" in config_file:
+            wrong = "published or deployment without a key in reduced"
+    elif sorted(config_file.get("published", ())) != sorted(reduced):
+        wrong = f"published has to hold exactly the keys {reduced}"
+    elif [k for k in reduced if config_file["published"][k] == model[k]]:
+        wrong = "a key in reduced has its published value in model"
+    elif not str(config_file.get("deployment", "")).strip():
+        wrong = "a cut configuration states its deployment"
+    if wrong:
+        raise BenchError(f"configuration of {config_file['source']!r}: "
+                         f"{wrong}")
+
+
 def load_cell(name: str, root: str = HERE) -> dict:
-    """The cell ``name`` with its configuration and traffic mix, all found
-    by name under ``root``; an unknown name is an error that says so."""
+    """The cell ``name`` with its configuration, the configuration's family
+    (the module ``families/<family>.py``) and its traffic mix, all found by
+    name under ``root``; an unknown name is an error that says so."""
     known = sorted(f[:-5] for f in os.listdir(os.path.join(root, "workloads"))
                    if f.endswith(".json"))
     if name not in known:
@@ -61,6 +93,9 @@ def load_cell(name: str, root: str = HERE) -> dict:
     cell["config_file"] = _load_json(
         os.path.join(root, "configs", f"{cell['config']}.json"),
         f"configuration {cell['config']!r}")
+    declared = {c["name"]: c["reduced"] for c in _benchmark(root)["configs"]}
+    check_cut(cell["config_file"], declared.get(cell["config"]))
+    cell["family"] = byname.module("families", cell["config_file"]["family"])
     cell["traffic_file"] = _load_json(
         os.path.join(root, "traffic", f"{cell['traffic']}.json"),
         f"traffic mix {cell['traffic']!r}")
@@ -72,8 +107,7 @@ def declared_metrics(root: str = HERE) -> dict:
     BENCHMARK.json beside ``root``: the one place that says which cells
     report a metric (``workloads``; none means every cell), its unit, its
     layer and the end-to-end metric it should move."""
-    bench = _load_json(os.path.join(os.path.dirname(root), "BENCHMARK.json"),
-                       "BENCHMARK.json")
+    bench = _benchmark(root)
     return {kind: bench[kind] for kind in ("end_to_end", "per_layer")}
 
 
@@ -178,8 +212,7 @@ def read_layer_metrics(cell: dict, facts: dict, root: str = HERE) -> dict:
     for spec in layer_metric_specs(cell, root):
         if not on_chip and spec.get("needs_chip", True):
             continue  # a CPU rehearsal writes no number under a device metric
-        reader = importlib.import_module(f"perfbench.readers.{spec['reader']}")
-        value = reader.read(spec, facts)
+        value = byname.module("readers", spec["reader"]).read(spec, facts)
         if value is not None:
             out[spec["name"]] = {"value": value, "unit": spec["unit"]}
     return out
@@ -196,7 +229,7 @@ def run_cell(args, root: str = HERE) -> dict:
 
     cache_dir = arm_compilation_cache()
     compile_watch.install()
-    job = importlib.import_module(f"perfbench.jobs.{cell['job']}")
+    job = byname.module("jobs", cell["job"])
     tracer = Tracer(bool(args.trace), cpu_rehearsal=dev["platform"] == "cpu")
     state = job.setup(cell, args.seed, dev)
     try:
@@ -214,6 +247,11 @@ def run_cell(args, root: str = HERE) -> dict:
         persistent_cache_hits_in_setup=before["persistent_cache_hits"])
     say(phase="window", compiles_in_window=compiles, **result["notes"])
     say(phase="check", **verdict)
+    # each number compared beside its limit, where a failed run's record
+    # keeps it: the end of standard error
+    print("perfbench: check " + json.dumps(
+        {**verdict, "compiles_in_window": compiles,
+         "compiles_in_window_limit": 0}), file=sys.stderr, flush=True)
 
     device = {"platform": dev["platform"], "kind": dev["kind"],
               "count": dev["count"], "memory_peak_bytes": memory_peak_bytes()}

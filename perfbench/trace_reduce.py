@@ -23,16 +23,17 @@ OPS_LINE = "XLA Ops"
 COLLECTIVE = re.compile(
     r"all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute",
     re.IGNORECASE)
-# host annotations the benchmark itself writes
-ANNOTATION = re.compile(r"^perfbench\.")
+# host annotations the benchmark itself writes, and the program's brackets
+ANNOTATION = re.compile(r"^(perfbench|ds)\.")
 WINDOW_ANNOTATION = "perfbench.window"
 
 
 @dataclasses.dataclass
 class Trace:
     """Events of one traced run. ``device_ops[i]`` are the operations of
-    chip ``i``; ``host`` are the benchmark's own annotations, every host
-    thread together. All on the profiler's clock, in ns."""
+    chip ``i``; ``host`` are the benchmark's and the program's annotations
+    (``perfbench.*``, ``ds.*``), every host thread together. All on the
+    profiler's clock, in ns."""
     device_ops: Dict[int, List[Event]]
     host: List[Event]
 
@@ -203,17 +204,25 @@ def exposed_collective_ns(events: Sequence[Event]) -> int:
 
 def idle_gaps(events: Sequence[Event], lo: int, hi: int,
               host: Sequence[Event]) -> Dict[str, int]:
-    """Idle time of one device inside ``[lo, hi)``, by the benchmark
-    annotation that covers the middle of each gap (the innermost, that is
+    """Idle time of one device inside ``[lo, hi)``, by the annotation
+    that covers the middle of each gap (the innermost, that is
     the shortest, where several do; the window's own annotation only when
     nothing else does) and ``unattributed`` where none does."""
     gaps = subtract([(lo, hi)], union(_spans(events)))
+    # one sweep over the gaps and the annotations, both by time: a serving
+    # trace holds some 1e5 gaps under some 1e4 program brackets
+    by_start = sorted((hs, hs + d, d, n) for n, hs, d in host
+                      if n != WINDOW_ANNOTATION)
     out: Dict[str, int] = {}
+    open_now, nxt = [], 0
     for s, e in gaps:
         mid = (s + e) // 2
-        covering = [(d, n) for n, hs, d in host
-                    if hs <= mid < hs + d and n != WINDOW_ANNOTATION]
-        name = min(covering)[1] if covering else "unattributed"
+        while nxt < len(by_start) and by_start[nxt][0] <= mid:
+            open_now.append(by_start[nxt])
+            nxt += 1
+        open_now = [a for a in open_now if a[1] > mid]
+        name = (min((d, n) for _, _, d, n in open_now)[1] if open_now
+                else "unattributed")
         out[name] = out.get(name, 0) + (e - s)
     return out
 

@@ -3,14 +3,19 @@ contract's keys, cells found by name, data files consistent with
 BENCHMARK.json. A CPU run proves nothing about speed; every line it prints
 says ``"platform": "cpu"``."""
 
+import copy
 import io
 import json
 import os
+import shutil
+import sys
 from contextlib import redirect_stdout
 
 import pytest
 
+from perfbench import byname
 from perfbench import run as bench_run
+from perfbench.byname import BenchError
 
 from .conftest import REPO, TINY_CELLS
 
@@ -35,7 +40,9 @@ SERVE_END_TO_END = {"tpot_p95_ms", "served_tok_s", "setup_s"}
 # off the chip no number is written under a device metric's name, so the
 # traced CPU runs report only counts and host-side times
 SERVE_PER_LAYER_OFF_CHIP = {"queue_p95_ms", "decode_occupancy", "ttft_p50_ms",
-                            "ttft_p95_ms"}
+                            "ttft_p95_ms", "decode_step_p50_ms",
+                            "prefill_p50_ms", "tpot_prefill_blocked_share",
+                            "step_host_share"}
 
 
 @pytest.mark.parametrize("cell,metrics", [
@@ -43,6 +50,9 @@ SERVE_PER_LAYER_OFF_CHIP = {"queue_p95_ms", "decode_occupancy", "ttft_p50_ms",
     ("tiny-serve", SERVE_END_TO_END),
     ("tiny-serve-offline", SERVE_END_TO_END),    # all due at t = 0
     ("tiny-serve-burst", SERVE_END_TO_END),      # bursts of 3x the rate
+    # a second family, cut in depth, found by its name alone
+    ("tiny-alt-train", {"train_tok_s_chip", "setup_s"}),
+    ("tiny-alt-serve", SERVE_END_TO_END),
 ])
 def test_untraced_run_prints_the_end_to_end_line(bench_copy, cell, metrics):
     root, _ = bench_copy
@@ -63,6 +73,8 @@ def test_untraced_run_prints_the_end_to_end_line(bench_copy, cell, metrics):
 @pytest.mark.parametrize("cell,reported", [
     ("tiny-train", set()),
     ("tiny-serve", SERVE_PER_LAYER_OFF_CHIP),
+    ("tiny-alt-train", set()),
+    ("tiny-alt-serve", SERVE_PER_LAYER_OFF_CHIP),
 ])
 def test_traced_run_prints_per_layer_metrics_and_breakdown(bench_copy, cell,
                                                            reported):
@@ -90,6 +102,123 @@ def test_unknown_workload_fails_by_name(bench_copy, capsys):
     assert rc != 0
     err = capsys.readouterr().err
     assert "no-such-cell" in err and "tiny-train" in err
+
+
+def test_unknown_family_fails_by_name(bench_copy, tmp_path, capsys):
+    """A configuration that names a family with no file: the run gives no
+    result and says which families there are."""
+    root, _ = bench_copy
+    top = tmp_path / "perfbench"
+    for folder in ("workloads", "traffic"):
+        shutil.copytree(os.path.join(root, folder), top / folder)
+    os.mkdir(top / "configs")
+    config_file = _config_file(root, "tiny-gpt2")
+    with open(top / "configs" / "tiny-gpt2.json", "w") as f:
+        json.dump({**config_file, "family": "no-such-family"}, f)
+    shutil.copy(os.path.join(os.path.dirname(root), "BENCHMARK.json"),
+                tmp_path)
+    rc = bench_run.main(["--workload", "tiny-train"], root=str(top))
+    assert rc != 0
+    err = capsys.readouterr().err
+    assert "no-such-family" in err and "'gpt2'" in err and "tiny-alt" in err
+
+
+def test_code_found_by_name_is_a_module_like_any_other(bench_copy, tmp_path,
+                                                       monkeypatch):
+    """All four kinds come through their package: one module object a
+    name, known to ``sys.modules``, so that a file may hold what looks its
+    own module up there (a dataclass under postponed annotations does)."""
+    from perfbench import families
+    from perfbench.families import gpt2
+    from perfbench.jobs import serve
+    from perfbench.readers import percentile
+
+    (tmp_path / "with-dataclass.py").write_text(
+        "from __future__ import annotations\n"
+        "from dataclasses import dataclass\n\n\n"
+        "@dataclass\nclass Shapes:\n    heads: int\n    head_dim: int = 64\n")
+    monkeypatch.setattr(families, "__path__",
+                        [*families.__path__, str(tmp_path)])
+    mod = byname.module("families", "with-dataclass")
+    assert mod.Shapes(heads=25).head_dim == 64
+    assert byname.module("families", "with-dataclass") is mod
+    monkeypatch.delitem(sys.modules, mod.__name__)
+    root, _ = bench_copy
+    assert bench_run.load_cell("tiny-train", root)["family"] is gpt2
+    # what a test-only family imports is the harness's copy of it too
+    alt = bench_run.load_cell("tiny-alt-train", root)["family"]
+    assert alt is sys.modules["perfbench.families.tiny-alt"]
+    assert alt.gpt2 is gpt2
+    assert byname.module("jobs", "serve") is serve
+    assert byname.module("readers", "percentile") is percentile
+    for kind, has in (("jobs", "train"), ("readers", "kernel_roofline")):
+        with pytest.raises(BenchError, match=f"no-such.*{has}"):
+            byname.module(kind, "no-such")
+
+
+def test_a_cell_serves_the_longest_context_its_traffic_sends(bench_copy):
+    """The mix's ``max_total`` sizes the pool; one over the family's
+    largest gives no result."""
+    from perfbench.jobs import serve
+
+    root, _ = bench_copy
+    cell = bench_run.load_cell("tiny-alt-serve", root)
+    assert cell["traffic_file"]["max_total"] == 64 < \
+        cell["family"].max_context(cell["config_file"]) == 96
+    assert "max_context" not in cell["serve"]
+    cell["traffic_file"]["max_total"] = 97
+    with pytest.raises(BenchError, match="tiny-chat.*97.*at most 96"):
+        serve.setup(cell, 0, {})
+
+
+def _config_file(root, name):
+    with open(os.path.join(root, "configs", f"{name}.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("name,declared", [
+    ("gpt2-medium", []), ("gpt2-xl", []), ("tiny-gpt2", []),
+    ("tiny-alt", ["num_hidden_layers"]),
+    ("tiny-alt", None),     # a configuration BENCHMARK.json does not list
+])
+def test_configurations_keep_to_the_form_of_a_cut(bench_copy, name, declared):
+    root, _ = bench_copy
+    bench_run.check_cut(_config_file(root, name), declared)
+
+
+def _drop(key):
+    return lambda c: c.pop(key)
+
+
+@pytest.mark.parametrize("name,declared,alter,says", [
+    # uncut, and yet it says what it was cut from
+    ("gpt2-xl", [], lambda c: c.update(published={"n_layer": 96}),
+     "without a key in reduced"),
+    ("gpt2-xl", [], lambda c: c.update(deployment="depth only"),
+     "without a key in reduced"),
+    # cut, and it does not say what it stands for, or from what
+    ("tiny-alt", ["num_hidden_layers"], _drop("deployment"), "deployment"),
+    ("tiny-alt", ["num_hidden_layers"], lambda c: c.update(deployment=" "),
+     "deployment"),
+    ("tiny-alt", ["num_hidden_layers"], _drop("published"), "published"),
+    ("tiny-alt", ["num_hidden_layers"],
+     lambda c: c["published"].update(vocab_size=1024), "published"),
+    ("tiny-alt", ["num_hidden_layers"],
+     lambda c: c["published"].update(num_hidden_layers=2),
+     "published value in model"),
+    # a key in reduced that model lacks
+    ("tiny-alt", ["n_layer"], lambda c: c.update(reduced=["n_layer"]),
+     "model lacks"),
+    # the file and BENCHMARK.json disagree, either way
+    ("tiny-alt", [], lambda c: None, "BENCHMARK.json"),
+    ("gpt2-xl", ["n_layer"], lambda c: None, "BENCHMARK.json"),
+])
+def test_a_malformed_cut_is_refused(bench_copy, name, declared, alter, says):
+    root, _ = bench_copy
+    config_file = copy.deepcopy(_config_file(root, name))
+    alter(config_file)
+    with pytest.raises(BenchError, match=says):
+        bench_run.check_cut(config_file, declared)
 
 
 @pytest.fixture(params=["committed", "with_cells_added"])
@@ -121,8 +250,11 @@ def test_every_cell_file_names_what_exists_and_is_declared(benchmark):
             f"perfbench/configs/{cell['config']}.json"
         assert cell["config_file"]["source"] == \
             configs[cell["config"]]["source"]
-        assert cell["config_file"]["reduced"] == \
-            configs[cell["config"]]["reduced"] == []
+        # a cut configuration says what it was cut from and what it stands
+        # for; an uncut one is held to [] on both sides and says neither
+        bench_run.check_cut(cell["config_file"],
+                            configs[cell["config"]]["reduced"])
+        assert cell["family"].vocab_size(cell["config_file"]) > 0
     assert set(cells) <= {f[:-5] for f in os.listdir(folder)}
 
 
@@ -163,8 +295,9 @@ def test_declared_metrics_resolve_for_every_cell(benchmark):
 def test_a_new_cell_is_only_new_files(bench_copy):
     """The copy the other tests ran in differs from ``perfbench/`` by added
     files alone (the tiny cells, one of which takes up ``queue_p95_ms`` and
-    the other serving metrics without a word in any file of theirs), and
-    its BENCHMARK.json by added entries and added names in ``workloads``
+    the other serving metrics without a word in any file of theirs; a second
+    family with its cut configuration, its cells and a kernel's arithmetic),
+    and its BENCHMARK.json by added entries and added names in ``workloads``
     lists alone."""
     root, bench = bench_copy
     for folder, _, files in os.walk(PERFBENCH):
@@ -178,6 +311,12 @@ def test_a_new_cell_is_only_new_files(bench_copy):
     added = {f[:-5] for f in os.listdir(os.path.join(root, "workloads"))
              if f.startswith("tiny-")}
     assert added == set(TINY_CELLS)
+    for folder, new in (("families", {"tiny-alt.py"}),
+                        ("kernels", {"tiny-matmul.py"}),
+                        ("configs", {"tiny-gpt2.json", "tiny-alt.json"}),
+                        ("layer_metrics", {"tiny-matmul_roofline.json"})):
+        assert set(os.listdir(os.path.join(root, folder))) - \
+            set(os.listdir(os.path.join(PERFBENCH, folder))) == new
     committed = _bench()
     for kind in ("configs", "workloads", "end_to_end", "per_layer"):
         for old, new in zip(committed[kind], bench[kind]):

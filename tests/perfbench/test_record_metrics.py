@@ -6,7 +6,6 @@ tiny serve cell."""
 
 import json
 import os
-import shutil
 
 import pytest
 
@@ -97,30 +96,18 @@ def test_the_four_metrics_are_declared_like_queue_p95_ms():
         assert spec["reader"] in ("record_percentile", "record_share")
 
 
-@pytest.fixture(scope="module")
-def rehearsal_copy(bench_copy, tmp_path_factory):
-    """A copy of the session's benchmark copy in which the four metrics'
-    files say ``"needs_chip": false``. The committed files say true, so
-    that the accepted rehearsal test of ``test_harness.py`` (which pins the
-    exact set of metrics a CPU run prints, and may not be edited) holds;
-    they are host-side times all the same, and this copy lets the CPU
-    rehearsal read them through the whole harness."""
-    root, _ = bench_copy
-    top = os.path.join(tmp_path_factory.mktemp("bench-records"), "b")
-    shutil.copytree(os.path.dirname(root), top)
+def test_the_four_metrics_are_host_side_times_read_off_the_chip_too():
+    """Their files say ``"needs_chip": false``, so the CPU rehearsal of
+    every serving cell runs their readers through the whole harness."""
     for name in NEW_METRICS:
-        path = os.path.join(top, "perfbench", "layer_metrics",
-                            f"{name}.json")
-        with open(path) as f:
-            spec = json.load(f)
-        assert spec["needs_chip"] is True
-        with open(path, "w") as f:
-            json.dump({**spec, "needs_chip": False}, f)
-    return os.path.join(top, "perfbench")
+        spec = bench_run._load_json(os.path.join(
+            REPO, "perfbench", "layer_metrics", f"{name}.json"), name)
+        assert spec["needs_chip"] is False
 
 
-def test_tiny_serve_trace_line_carries_the_four_metrics(rehearsal_copy):
-    rc, lines = _run(rehearsal_copy, "--workload", "tiny-serve", "--seed",
+def test_tiny_serve_trace_line_carries_the_four_metrics(bench_copy):
+    root, _ = bench_copy
+    rc, lines = _run(root, "--workload", "tiny-serve", "--seed",
                      "3000000007", "--seconds", "2", "--trace", "1")
     assert rc == 0
     last = json.loads(lines[-1])

@@ -116,3 +116,54 @@ def test_collective_bytes_per_step_from_executed_events(trace):
     trace.device_ops[0] = [e for e in trace.device_ops[0] if e[0] != GATHER]
     assert collective_bytes_step.read(
         {}, {"trace_events": trace, "steps": 2}) is None
+
+
+def test_the_programs_own_brackets_name_a_gap(trace):
+    """``ds.*`` annotations are read beside ``perfbench.*``, and the
+    innermost covering one names a gap: [0,100) lies under ds.serve.step
+    and, inside it, ds.serve.decode.sync; [700,900) has its middle, 800,
+    under ds.serve.step alone (another thread's ds.gateway.sse_write ended
+    at 790). The benchmark's wait covers everything and names nothing."""
+    trace.host[:] = [
+        ("perfbench.window", 0, 1000),
+        ("perfbench.serve.wait_for_client", 0, 1000),
+        ("ds.serve.step", 0, 950), ("ds.serve.decode", 0, 500),
+        ("ds.serve.decode.sync", 40, 80), ("ds.gateway.sse_write", 690, 100),
+        ("some.other.annotation", 0, 10)]
+    assert tr.summarize(trace)["idle_gap_seconds"] == pytest.approx({
+        "ds.serve.decode.sync": 100e-9, "ds.serve.step": 200e-9})
+    assert tr.ANNOTATION.match("ds.serve.step")
+    assert tr.ANNOTATION.match("perfbench.train.step")
+    assert not tr.ANNOTATION.match("some.other.annotation")
+    assert not tr.ANNOTATION.match("dsx.serve")
+
+
+def _idle_gaps_one_by_one(events, lo, hi, host):
+    """``idle_gaps`` as it was before it became one sweep: every gap asks
+    every annotation. Kept here as the reference."""
+    out = {}
+    for s, e in tr.subtract([(lo, hi)], tr.union(tr._spans(events))):
+        mid = (s + e) // 2
+        covering = [(d, n) for n, hs, d in host
+                    if hs <= mid < hs + d and n != tr.WINDOW_ANNOTATION]
+        name = min(covering)[1] if covering else "unattributed"
+        out[name] = out.get(name, 0) + (e - s)
+    return out
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_the_sweep_names_gaps_as_asking_every_annotation_did(seed):
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    starts = np.sort(rng.integers(0, 100_000, 400))
+    events = [("%op", int(s), int(d))
+              for s, d in zip(starts, rng.integers(1, 300, 400))]
+    host = [("perfbench.window", 0, 100_000)] + [
+        (f"ds.layer.{i % 7}", int(s), int(d))
+        for i, (s, d) in enumerate(zip(rng.integers(0, 100_000, 300),
+                                       rng.integers(1, 20_000, 300)))]
+    got = tr.idle_gaps(events, 0, 100_000, host)
+    assert got == _idle_gaps_one_by_one(events, 0, 100_000, host)
+    assert len(got) > 3 and sum(got.values()) == \
+        100_000 - tr.busy_ns(tr.clip(events, 0, 100_000))

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from perfbench import flops, reference_gpt2, traffic
+from perfbench.families import gpt2 as gpt2_family
 
 from .conftest import REPO
 
@@ -108,12 +109,33 @@ def test_train_batches_are_fresh_each_step_and_seeded():
         traffic.requests(mix, 0, 1.0, 100)
 
 
+@pytest.mark.parametrize("config,parameters,layers,width", [
+    ("gpt2-medium", 354_823_168, 24, 1024),
+    ("gpt2-xl", 1_557_611_200, 48, 1600),
+])
+def test_the_family_counts_what_the_files_say(config, parameters, layers,
+                                              width):
+    """N and 6N + 12LTd, which ``mfu.train`` stands on, to the digit."""
+    with open(os.path.join(REPO, "perfbench", "configs",
+                           f"{config}.json")) as f:
+        config_file = json.load(f)
+    assert gpt2_family.param_count(config_file) == parameters == \
+        config_file["parameters"]
+    assert gpt2_family.train_flops_per_token(config_file, 1024) == \
+        6 * parameters + 12 * layers * 1024 * width
+    assert gpt2_family.attention_shapes(config_file) == {
+        "heads": config_file["model"]["n_head"],
+        "kv_heads": config_file["model"]["n_head"], "head_dim": 64,
+        "paged_layers": layers}
+    assert gpt2_family.vocab_size(config_file) == 50257
+    assert gpt2_family.max_context(config_file) == 1024
+
+
 def test_flops_and_peaks():
     with open(os.path.join(REPO, "perfbench", "configs", "gpt2-xl.json")) as f:
         xl = json.load(f)
-    assert flops.gpt2_param_count(xl["model"]) == xl["parameters"] == \
-        1_557_611_200
-    assert flops.train_flops_per_token(xl["model"], 1024) == \
+    assert gpt2_family.param_count(xl) == xl["parameters"] == 1_557_611_200
+    assert gpt2_family.train_flops_per_token(xl, 1024) == \
         6 * 1_557_611_200 + 12 * 48 * 1024 * 1600
     assert flops.peaks("TPU v5 lite")["bf16_flops_per_s"] == 197e12
     with pytest.raises(KeyError, match="TPU v9"):

@@ -264,28 +264,39 @@ def apply_rotary(x, positions, rotary_dim: int, theta: float,
     return jnp.concatenate([out, rest], axis=-1) if rd < D else out
 
 
-def _remat_block(cfg):
-    """Block wrapped per the config's activation-checkpointing policy."""
+def _remat_block(cfg, block=None):
+    """``block`` (default :class:`Block`) wrapped per the config's
+    activation-checkpointing policy."""
+    block = block or Block
     if not cfg.remat:
-        return Block
+        return block
     if cfg.cpu_checkpointing:
         # the OUTER stack-level checkpoint (see GPT2LMHeadModel) owns both
         # the recompute and the host offload; an inner wrap would save the
         # block inputs on-device, defeating the offload
-        return Block
+        return block
     policy = None
     if cfg.remat_policy == "dots":
         # save matmul outputs AND the flash-attention residuals (named in
         # ops/flash_attention.py) — backward recomputes only the cheap
         # elementwise chains (LN / gelu / residual adds)
+        names = ("flash_q", "flash_k", "flash_v", "flash_o", "flash_lse")
+        if block is not Block:
+            # a ZeRO-3 use site (_block_at_use): memory is what stage 3 is
+            # for, and q, k, v are transposed slices of the c_attn output
+            # that checkpoint_dots keeps anyway. Saved beside it in the
+            # kernel's layout (head size 64 padded to 128 lanes) they cost
+            # 3.5 GB a chip at GPT-2 XL, the room the compiler needs to run
+            # a layer's collectives beside its compute (PERF.md, PR 32);
+            # backward redoes the three transposes instead
+            names = names[3:]
         policy = jax.checkpoint_policies.save_from_both_policies(
             jax.checkpoint_policies.checkpoint_dots,
-            jax.checkpoint_policies.save_only_these_names(
-                "flash_q", "flash_k", "flash_v", "flash_o", "flash_lse"))
+            jax.checkpoint_policies.save_only_these_names(*names))
     # deterministic (arg index 2; 0 is self) is branched on in Python —
     # it must stay static under jax.checkpoint, and therefore must be
     # passed POSITIONALLY at every call site of the wrapped block
-    return nn.remat(Block, prevent_cse=False, policy=policy,
+    return nn.remat(block, prevent_cse=False, policy=policy,
                     static_argnums=(2,))
 
 
@@ -690,16 +701,49 @@ class _ScanBody(nn.Module):
         x, pools = (carry, None) if layer is None else carry
         if cfg.remat:
             x = saved_block_input(x, cfg)
-        out = _remat_block(cfg)(cfg, name="block")(
+        out = _remat_block(cfg, _block_at_use(cfg, self.path + ("block",)))(
+            cfg, name="block")(
             x, deterministic, pld_theta, layer_frac, attention_mask, paging,
             None if layer is None else (pools, layer))
         return out, None
 
 
+def _block_at_use(cfg, path):
+    """The scanned stack's ZeRO-3 use site: :class:`Block` whose parameters,
+    this layer's slice of the stacked leaves at ``path``, pass through
+    ``runtime/zero/partition.gather_at_use`` on their way in: cast to the
+    compute dtype on the shard, all-gathered, their gradients
+    reduce-scattered in float32. The wrap sits INSIDE the remat wrap, so
+    backward gathers again and no gathered weight is a residual of the
+    scan; with ``remat`` off it brings a remat of its own whose policy
+    saves every residual but the gathered weights. ``None`` (the plain
+    :class:`Block`) wherever no engine is tracing a stage-3 step: serving,
+    ``init``, every lower stage, one device."""
+    from deepspeed_tpu.runtime.zero import partition as zero
+
+    if not zero.gathering():
+        return None
+
+    def whole(variables):
+        return {c: zero.gather_at_use(v, path, dtype=cfg.dtype, stacked=1)
+                if c == "params" else v for c, v in variables.items()}
+
+    block = nn.map_variables(Block, "params", trans_in_fn=whole)
+    if cfg.remat:
+        return block
+    return nn.remat(block, prevent_cse=False, static_argnums=(2,),
+                    policy=zero.save_all_but_gathered)
+
+
 class ScanBlocks(nn.Module):
     """All transformer blocks as one scanned body: params get a leading
-    ``n_layer`` axis, XLA compiles a single block, ZeRO-3 gathers one layer's
-    params per scan step instead of the whole stack."""
+    ``n_layer`` axis and XLA compiles a single block. Under the engine's
+    ZeRO-3 step the body is the stack's use site (:func:`_block_at_use`):
+    each scan step all-gathers ONE layer's weights in the compute dtype,
+    inside the rematerialised region, and reduce-scatters their float32
+    gradients; activations stay on the batch. Small leaves (a layer's
+    biases and norms, under ``param_persistence_threshold`` elements a
+    LAYER) are persistent: whole on every chip, never gathered."""
 
     config: GPT2Config
 
@@ -977,6 +1021,21 @@ class GPT2ForTraining:
     def apply(self, variables, batch, rngs=None):
         return self.model.apply(variables, self._input_ids(batch), rngs=rngs)
 
+    def zero3_use_sites(self):
+        """Engine hook: where this model asks
+        ``runtime/zero/partition.gather_at_use`` for its weights, as
+        param-path prefix -> leading scanned dims. The scanned stack
+        gathers a layer a scan step; the tables are gathered once in the
+        loss. None for the unrolled stack, nor where a stack-level
+        checkpoint or a model-axis constraint on the saved activations
+        (``cpu_checkpointing``, ``partition_activations``) owns the
+        block's boundary: those keep the engine's GSPMD program."""
+        cfg = self.config
+        if (not cfg.scan_layers or cfg.cpu_checkpointing
+                or cfg.partition_activations):
+            return {}
+        return {"transformer/h/block": 1, **{k: 0 for k in _TABLES}}
+
     def with_activation_checkpointing(self, enabled: bool, policy: str = "full",
                                       cpu_checkpointing: bool = False,
                                       partition_activations: bool = False):
@@ -1061,6 +1120,26 @@ def gpt2_pipe(config: GPT2Config):
                           use_rngs=config.dropout > 0)
 
 
+# the model-level leaves that are ZeRO-3 use sites beside the scanned stack
+_TABLES = ("wte", "wpe", "lm_head")
+
+
+def _tables_at_use(params, cfg):
+    """The embedding tables and an untied head through the ZeRO-3 seam,
+    once for all their uses (``wte`` is looked up AND is the tied head):
+    the compute dtype on the wire, the values back in float32, because
+    the lookup's scatter-add and the sum of the two uses' cotangents are
+    taken in the table's dtype. The identity outside a stage-3 step."""
+    from deepspeed_tpu.runtime.zero import partition as zero
+
+    if not zero.gathering():
+        return params
+    return {**params, **{
+        k: zero.gather_at_use(params[k], (k,), dtype=cfg.dtype,
+                              keep_dtype=True)
+        for k in _TABLES if k in params}}
+
+
 def gpt2_loss_fn(model: GPT2LMHeadModel):
     """Engine-facing loss: ``fn(params, batch, rngs=None) -> loss``.
 
@@ -1075,6 +1154,7 @@ def gpt2_loss_fn(model: GPT2LMHeadModel):
             input_ids, labels = batch
         if labels is None:
             labels = input_ids
+        params = _tables_at_use(params, model.config)
         hidden, wte = model.apply({"params": params}, input_ids,
                                   deterministic=rngs is None, rngs=rngs,
                                   return_hidden=True, pld_theta=pld_theta)

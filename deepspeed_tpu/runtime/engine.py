@@ -550,6 +550,7 @@ class DeepSpeedEngine:
         self._jit_micro = None
         self._jit_apply = None
         self._param_treedef = None
+        self._zero3_program = None  # set as a stage-3 step is traced
         if model_parameters is not None:
             from deepspeed_tpu.utils.pytree import unwrap_variables_dict
 
@@ -685,6 +686,18 @@ class DeepSpeedEngine:
                              if tp > 1 else None)
         return jax.tree_util.tree_unflatten(treedef, specs)
 
+    @property
+    def _zero3_sites(self):
+        """The model's ZeRO-3 use sites (``zero3_use_sites()``: param-path
+        prefix -> leading scanned dims), ``{}`` below stage 3 and for a
+        model that declares none. They decide the unit a stacked leaf is
+        partitioned by and, in :meth:`_zero3_plan`, the program."""
+        from deepspeed_tpu.runtime.zero.partition import use_sites_of
+
+        if self.zero_optimization_stage() < 3:
+            return {}
+        return use_sites_of(self.module)
+
     def _shardings_for(self, params_abstract):
         layout = self.spec_layout
         return build_zero_shardings(
@@ -692,7 +705,8 @@ class DeepSpeedEngine:
             stage=self.zero_optimization_stage(),
             param_specs=self._tp_base_specs(params_abstract),
             persistence_threshold=layout.persistence_threshold,
-            hierarchical=layout.hierarchical_active)
+            hierarchical=layout.hierarchical_active,
+            sites=self._zero3_sites)
 
     def _build_state(self, params):
         params = jax.tree_util.tree_map(jnp.asarray, params)
@@ -728,7 +742,8 @@ class DeepSpeedEngine:
         else:
             opt_abstract = jax.eval_shape(self.optimizer.init, abstract)
             opt_state_shardings = build_opt_state_shardings(
-                opt_abstract, abstract, self.mesh, stage=stage, param_specs=base_specs)
+                opt_abstract, abstract, self.mesh, stage=stage,
+                param_specs=base_specs, sites=self._zero3_sites)
             with self.mesh:
                 opt_state = jax.jit(self.optimizer.init,
                                     out_shardings=opt_state_shardings)(params)
@@ -736,22 +751,23 @@ class DeepSpeedEngine:
             # grads live reduce-scattered over the data axes (ZeRO-2), on top
             # of any TP sharding
             _, grad_shardings = build_zero_shardings(
-                abstract, self.mesh, stage=stage, param_specs=base_specs)
+                abstract, self.mesh, stage=stage, param_specs=base_specs,
+                sites=self._zero3_sites)
         else:
             # host offload fetches full grads D2H each boundary, so keep them
             # in the param layout (stage-2 scatter would make device_get span
             # non-addressable devices on multi-host)
             grad_shardings = param_shardings
-        accum_dtype = self._grad_accum_dtype()
-        with self.mesh:
-            grad_acc = jax.jit(
-                lambda p: jax.tree_util.tree_map(
-                    lambda x: jnp.zeros(x.shape, accum_dtype), p),
-                out_shardings=grad_shardings)(params)
+        # the accumulation buffer exists only where a program accumulates:
+        # _compile_steps makes it for the micro-step path and not for the
+        # fused step, whose gradients go from backward to the update inside
+        # one program (a parameter-sized float32 buffer a chip otherwise
+        # held, zero, through every step)
+        self._grad_shardings = grad_shardings
         self.state = TrainState(
             params=params,
             opt_state=opt_state,
-            grad_acc=grad_acc,
+            grad_acc={},
             loss_scale=jax.device_put(self._initial_loss_scaler, jax.tree_util.tree_map(
                 lambda _: rep, self._initial_loss_scaler)),
             global_step=jax.device_put(jnp.zeros((), jnp.int32), rep),
@@ -761,13 +777,32 @@ class DeepSpeedEngine:
         self._state_shardings = TrainState(
             params=param_shardings,
             opt_state=opt_state_shardings,
-            grad_acc=grad_shardings,
+            grad_acc={},
             loss_scale=jax.tree_util.tree_map(lambda _: rep, self._initial_loss_scaler),
             global_step=rep,
             skipped_steps=rep,
             rng=rep,
         )
         self._compile_steps()
+
+    def _set_grad_acc(self, wanted: bool):
+        """Make (zeros, sharded as the gradients are) or drop the
+        accumulation buffer of the live state."""
+        have = bool(jax.tree_util.tree_leaves(self.state.grad_acc))
+        if have == wanted:
+            return
+        grad_acc, shardings = {}, {}
+        if wanted:
+            accum_dtype, shardings = self._grad_accum_dtype(), \
+                self._grad_shardings
+            with self.mesh:
+                grad_acc = jax.jit(
+                    lambda p: jax.tree_util.tree_map(
+                        lambda x: jnp.zeros(x.shape, accum_dtype), p),
+                    out_shardings=shardings)(self.state.params)
+        self.state = self.state._replace(grad_acc=grad_acc)
+        self._state_shardings = self._state_shardings._replace(
+            grad_acc=shardings)
 
     # ------------------------------------------------------------------
     # 1-bit optimizer path: fused shard_map step, collective inside
@@ -804,6 +839,7 @@ class DeepSpeedEngine:
                 lambda _: rep, self._initial_loss_scaler),
             global_step=rep, skipped_steps=rep, rng=rep,
         )
+        self._grad_shardings = {}
         self._jit_onebit = {}
         self._jit_micro = None
         self._jit_apply = None
@@ -978,14 +1014,120 @@ class DeepSpeedEngine:
             check_vma=False)
 
     # ------------------------------------------------------------------
+    # ZeRO-3: gather the weight at its use, scatter its gradient
+    def _zero3_plan(self):
+        """The :class:`GatherPlan` of this engine's stage-3 step, or None
+        where the step stays GSPMD's: below stage 3, ZeRO axes that
+        multiply to one, a model that declares no use site, and the
+        regimes that own the loss's program themselves (a live expert,
+        seq, pipe or tp axis, compression transforms on whole parameters,
+        host offload). ``tp`` because a ``lax.all_gather`` under a
+        ``shard_map`` that leaves ``tp`` to the partitioner is given its
+        operand whole over ``tp``: the compiled step all-gathers over
+        ``tp`` first and then ``tp`` times the bytes over the ZeRO axes
+        (PERF.md, PR 32). Decided by what the engine observes; no option."""
+        from deepspeed_tpu.parallel.topology import (AXIS_EXPERT, AXIS_PIPE,
+                                                     AXIS_SEQ)
+        from deepspeed_tpu.runtime.zero.partition import GatherPlan
+
+        sites = self._zero3_sites
+        if not sites or self._host_offload or self._compressor is not None:
+            return None
+        if self.spec_layout.tp_size > 1 or any(
+                self.topology.axis_size(a) > 1
+                for a in (AXIS_EXPERT, AXIS_SEQ, AXIS_PIPE)):
+            return None
+        plan = GatherPlan(self.mesh, self._state_shardings.params,
+                          self.state.params, sites,
+                          zero_axes=self.spec_layout.zero_axes,
+                          batch_axes=self.spec_layout.batch_axes)
+        return plan if plan.world > 1 else None
+
+    def _zero3_loss_fn(self, plan, train: bool):
+        """The loss (``train``: and its gradients) under ``shard_map`` over
+        the whole mesh (every axis but the plan's is of size one, so a
+        Pallas kernel inside is called plainly): every chip runs the model
+        on its own rows of the batch, the model's use sites gather the weights
+        (``partition.gather_at_use``) and whatever sharded leaf lies under
+        no site is gathered here, first. The loss is the mean over chips
+        of each chip's own mean, as the reference's is; gradients leave
+        reduce-scattered in float32, laid out as the parameters are."""
+        from jax.sharding import PartitionSpec as P
+
+        from deepspeed_tpu.utils.compat import shard_map
+
+        loss_fn = self._loss_fn
+        pld = self.progressive_layer_drop
+        use_pld = pld is not None and self._loss_accepts_pld
+        param_specs = plan.param_in_specs()
+
+        def local(params, batch, scale, global_step, key):
+            rngs = None
+            if train:
+                sub, sub2, sub3 = jax.random.split(
+                    jax.random.fold_in(key, plan.replica_index()), 3)
+                rngs = {"dropout": sub, "gating": sub2, "pld": sub3}
+
+            def scaled_loss(p):
+                with plan:
+                    loss = loss_fn(
+                        plan.gather_rest(p), batch, rngs=rngs,
+                        **({"pld_theta": pld.theta_at(global_step)}
+                           if train and use_pld else {}))
+                return loss * (scale / plan.world)
+
+            if not train:
+                return jax.lax.psum(scaled_loss(params), plan.axes)
+            loss, grads = jax.value_and_grad(scaled_loss)(params)
+            return jax.lax.psum(loss, plan.axes), plan.reduce_rest(grads)
+
+        def call(params, batch, scale, global_step, key):
+            batch_specs = jax.tree_util.tree_map(plan.batch_in_spec, batch)
+            out = shard_map(
+                local, mesh=self.mesh,
+                in_specs=(param_specs, batch_specs, P(), P(), P()),
+                out_specs=(P(), param_specs) if train else P(),
+                check_vma=False)(params, batch, scale, global_step, key)
+            self._note_zero3_program(plan, train)
+            return out
+
+        return call
+
+    def _note_zero3_program(self, plan, train: bool):
+        """Once, as the step is traced: which ZeRO-3 program this engine
+        compiles, and the plan's counts (also in the topology manifest)."""
+        if not train or self._zero3_program is not None:
+            return
+        if plan is None:
+            self._zero3_program = {"program": "gspmd"}
+            why = ("the model declares no use site"
+                   if not self._zero3_sites else
+                   "another regime owns the loss's program")
+            log_dist("ZeRO-3 step: GSPMD program (parameters sharded, the "
+                     f"partitioner places the collectives): {why}",
+                     ranks=[0])
+        else:
+            d = self._zero3_program = plan.describe()
+            log_dist(
+                "ZeRO-3 step: gather-at-use program over "
+                f"{'x'.join(d['axes'])}: {d['leaves_gathered_in_scan']} "
+                f"leaves gathered a layer in the scan, "
+                f"{d['leaves_gathered_once']} gathered once, "
+                f"{d['leaves_persistent']} persistent; a step and chip "
+                f"all-gathers {d['gather_operand_bytes_step']} operand "
+                f"bytes ({', '.join(d['wire_dtypes'])}) and reduce-scatters "
+                f"{d['scatter_operand_bytes_step']} (float32)", ranks=[0])
+
+    # ------------------------------------------------------------------
     # jitted hot paths
     def _compile_steps(self):
         if self._onebit:
             return  # fused step compiled lazily per stage flag
+        self._set_grad_acc(not self._fused_step)
         gas = self.gradient_accumulation_steps()
         loss_fn = self._loss_fn
         fp16 = self.fp16_enabled_
-        grad_shardings = self._state_shardings.grad_acc
+        grad_shardings = self._grad_shardings
 
         # PLD: theta(t) computed in-graph from the step counter (no host
         # round-trip, no retrace) and passed into the model forward —
@@ -1009,6 +1151,17 @@ class DeepSpeedEngine:
         # wire-compressed reduction: one shard_map'd grad program serves
         # the micro and fused paths (fused implies gas == 1)
         cq_grad = self._comm_quant_grad_fn(gas) if self._comm_quant else None
+        # ZeRO-3: the explicit gather-at-use program, where it applies
+        z3_plan = None if cq_grad is not None else self._zero3_plan()
+        z3_grad = (self._zero3_loss_fn(z3_plan, train=True)
+                   if z3_plan is not None else None)
+        stage3 = self.zero_optimization_stage() >= 3
+
+        def zero3_grads(state, batch, sub):
+            scale = state.loss_scale.loss_scale if fp16 \
+                else jnp.ones((), jnp.float32)
+            return z3_grad(state.params, batch, scale / gas,
+                           state.global_step, sub)
 
         if self._fused_step:
             apply_math = self._apply_math
@@ -1030,7 +1183,11 @@ class DeepSpeedEngine:
                     loss_scaled, grads = cq_grad(
                         state.params, batch, state.loss_scale.loss_scale,
                         state.global_step, sub)
+                elif z3_grad is not None:
+                    loss_scaled, grads = zero3_grads(state, batch, sub)
                 else:
+                    if stage3:
+                        self._note_zero3_program(None, True)
                     loss_scaled, grads = jax.value_and_grad(scaled_loss)(
                         state.params)
                 grads = jax.tree_util.tree_map(
@@ -1069,7 +1226,11 @@ class DeepSpeedEngine:
                 loss_scaled, grads = cq_grad(
                     state.params, batch, state.loss_scale.loss_scale,
                     state.global_step, sub)
+            elif z3_grad is not None:
+                loss_scaled, grads = zero3_grads(state, batch, sub)
             else:
+                if stage3:
+                    self._note_zero3_program(None, True)
                 loss_scaled, grads = jax.value_and_grad(scaled_loss)(
                     state.params)
             grads = jax.lax.with_sharding_constraint(grads, grad_shardings)
@@ -1479,8 +1640,15 @@ class DeepSpeedEngine:
         self._ensure_state(batch)
         if not hasattr(self, "_jit_eval"):
             loss_fn = self._loss_fn
+            plan = self._zero3_plan()
+            z3_loss = (self._zero3_loss_fn(plan, train=False)
+                       if plan is not None else None)
 
             def eval_loss(params, b):
+                if z3_loss is not None:  # the step's forward, gathers and all
+                    return z3_loss(params, b, jnp.ones((), jnp.float32),
+                                   jnp.zeros((), jnp.int32),
+                                   jnp.zeros((2,), jnp.uint32))
                 return loss_fn(params, b, rngs=None)
 
             self._jit_eval = self.telemetry.watch_jit(
@@ -2140,6 +2308,9 @@ class DeepSpeedEngine:
                 "process_count": int(jax.process_count()),
             },
             "zero_stage": int(self.zero_optimization_stage()),
+            # which stage-3 program the step compiled to, with the plan's
+            # counts (None until a step has been traced)
+            "zero3_program": self._zero3_program,
             "batch": {
                 "train_batch_size": int(self.train_batch_size()),
                 "micro_batch_per_gpu":

@@ -392,7 +392,8 @@ class PipelineEngine(DeepSpeedEngine):
         pipe_loss = pipeline_loss_fn(self._pipe_module, mesh, n_micro,
                                      virtual_stages=self.virtual_stages)
         fp16 = self.fp16_enabled_
-        grad_shardings = self._state_shardings.grad_acc
+        self._set_grad_acc(True)  # the pipeline accumulates micro-batches
+        grad_shardings = self._grad_shardings
         mb_rows = self._micro_batch_rows()
 
         def to_micro(a):

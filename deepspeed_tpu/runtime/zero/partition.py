@@ -15,13 +15,40 @@ gather/release hooks (``runtime/zero/partition_parameters.py:1042``,
 - **stage 2**: same program — gradients never materialize replicated because
   the only consumer (the sharded update) needs 1/N of them; XLA's scheduler
   plays the role of the IPG overlap stream.
-- **stage 3**: params themselves sharded; every use triggers a (scan-scoped)
-  all-gather, every grad a reduce-scatter — the fetch/release coordinator
-  becomes dataflow.
+- **stage 3**: params themselves sharded. Two programs, chosen by what
+  the engine observes (``engine._zero3_plan``), never by an option:
+
+  * a model written against the seam (:func:`gather_at_use`; it declares
+    its use sites with ``zero3_use_sites()``, as the scanned GPT-2 does)
+    gets the EXPLICIT program: loss and gradients run under ``shard_map``
+    over the live ZeRO axes (``data``, ``fsdp``). Inside the layer scan,
+    inside the rematerialised region, one layer's slice of every sharded
+    leaf is cast to the compute dtype ON THE SHARD and all-gathered (bf16
+    on the wire; backward gathers again, so no gathered weight is ever a
+    residual); its gradient is cast up to float32 and reduce-scattered, so
+    the sum across chips is float32 and each chip ends holding its own
+    shard. The tables outside the scan (``wte``, ``wpe``, an untied head)
+    are gathered once for all their uses, bf16 on the wire, float32
+    values. Activations are each chip's own rows from embedding to loss:
+    no collective ever moves one. The loss is the mean over chips of each
+    chip's batch mean, as the reference's is.
+  * any other model (a user's module, the unrolled ``LoopBlocks``,
+    ``models/llama.py``), and the regimes that own the loss's program
+    (tp / expert / seq / pipe axes, compression, host offload,
+    comm-quantization), keep the GSPMD program: the sharded parameters go
+    into ``value_and_grad`` and the partitioner places whatever
+    collectives it derives, which for a feature-sharded weight and a
+    batch-sharded activation are all-to-alls of the ACTIVATIONS (PERF.md,
+    PR 25).
 
 Sharding rule: shard the largest dimension divisible by the axis size; params
 smaller than ``param_persistence_threshold`` stay replicated (mirrors
-``stage3_param_persistence_threshold``).
+``stage3_param_persistence_threshold``). Under a use site the unit is the
+one that is gathered: persistence is judged on ONE LAYER's slice of a
+stacked leaf (a layer's biases and norms are persistent, whole on every
+chip), the scanned dim is never split, and the slice's FIRST divisible
+dim is (a gather along it is a concatenation, its transpose one
+reduce-scatter). Optimizer state and gradients take the same dims.
 
 Since the 3-axis mesh (``data x fsdp x tp``, GSPMD arXiv:2105.04663) the
 one authority over *which axis shards what* is :class:`SpecLayout`:
@@ -38,10 +65,13 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu.parallel.topology import (AXIS_DATA, AXIS_EXPERT,
-                                             AXIS_FSDP, AXIS_SEQ, AXIS_TP)
+                                             AXIS_FSDP, AXIS_SEQ, AXIS_TP,
+                                             axis_spec_entry)
+from deepspeed_tpu.utils.pytree import key_entry_str
 
 # ZeRO partitions optimizer state / ZeRO-3 params over these axes (the
 # flattened product is the reference's "partition count"); the batch only
@@ -63,11 +93,17 @@ def hierarchical_param_axes(zero_axes: Sequence[str] = ZERO_AXES
 
 
 def _shardable_dim(shape: Tuple[int, ...], axis_size: int,
-                   taken: Sequence[Optional[str]]) -> Optional[int]:
-    """Largest dim divisible by axis_size and not already sharded."""
+                   taken: Sequence[Optional[str]],
+                   stacked: int = 0, leading: bool = False) -> Optional[int]:
+    """Largest dim divisible by axis_size and not already sharded, or
+    with ``leading`` the FIRST such dim; the ``stacked`` leading (scanned)
+    dims are never a candidate."""
     best, best_size = None, 0
     for i, d in enumerate(shape):
-        if taken[i] is None and d % axis_size == 0 and d >= axis_size and d > best_size:
+        if i >= stacked and taken[i] is None and d % axis_size == 0 \
+                and d >= axis_size and d > best_size:
+            if leading:
+                return i
             best, best_size = i, d
     return best
 
@@ -76,12 +112,19 @@ def zero_partition_spec(shape: Tuple[int, ...],
                         mesh: Mesh,
                         data_axes: Optional[Sequence[str]] = None,
                         base_spec: Optional[P] = None,
-                        persistence_threshold: int = 0) -> P:
+                        persistence_threshold: int = 0,
+                        stacked: int = 0, leading: bool = False) -> P:
     """PartitionSpec sharding ``shape`` over the (flattened) data axes,
     layered on top of ``base_spec`` (TP/expert specs from the model).
 
     Returns ``base_spec`` unchanged if the array is too small (persistence
-    threshold) or no dim divides evenly.
+    threshold) or no dim divides evenly. ``stacked`` leading dims are a
+    layer scan's: the unit that is gathered at a use is ``shape[stacked:]``,
+    so persistence is judged on that and the scanned dims stay whole.
+    ``leading`` shards the unit's FIRST divisible dim, not its largest: an
+    all-gather along it is a concatenation and its reduce-scatter is one
+    collective, where the chip's compiler turns a scatter along a minor
+    dim into an all-reduce of the whole gradient and a slice.
     """
     if data_axes is None:
         data_axes = ZERO_AXES
@@ -94,9 +137,9 @@ def zero_partition_spec(shape: Tuple[int, ...],
     if not data_axes:
         return base_spec if base_spec is not None else P()
     axis_size = int(np.prod([mesh.shape[a] for a in data_axes]))
-    if int(np.prod(shape)) < max(persistence_threshold, axis_size):
+    if int(np.prod(shape[stacked:])) < max(persistence_threshold, axis_size):
         return P(*entries) if base_spec is not None else P()
-    dim = _shardable_dim(shape, axis_size, entries)
+    dim = _shardable_dim(shape, axis_size, entries, stacked, leading)
     if dim is None:
         return P(*entries) if base_spec is not None else P()
     group = tuple(data_axes) if len(data_axes) > 1 else data_axes[0]
@@ -109,7 +152,8 @@ def build_zero_shardings(params_shapes,
                          stage: int,
                          param_specs=None,
                          persistence_threshold: int = 0,
-                         hierarchical: bool = False):
+                         hierarchical: bool = False,
+                         sites: Optional[Dict[str, int]] = None):
     """Shardings for (params, optimizer state) given a ZeRO stage.
 
     ``params_shapes``: pytree of ``jax.ShapeDtypeStruct`` (or arrays).
@@ -117,6 +161,9 @@ def build_zero_shardings(params_shapes,
     ``hierarchical``: hpZ — stage-3 *params* shard over
     :func:`hierarchical_param_axes` only (inside a data replica);
     optimizer state keeps the full :data:`ZERO_AXES` partition.
+    ``sites``: the model's use sites (:func:`use_sites_of`), path prefix ->
+    leading scanned dims; a leaf under one is partitioned by its per-layer
+    slice (:func:`zero_partition_spec`, ``stacked``).
     Returns ``(param_shardings, opt_shardings)`` pytrees of NamedSharding.
     """
 
@@ -128,36 +175,42 @@ def build_zero_shardings(params_shapes,
 
     param_axes = hierarchical_param_axes() if hierarchical else ZERO_AXES
 
-    def param_sharding(leaf, spec):
+    def param_sharding(path, leaf, spec):
         base = base_spec_of(spec)
         if stage >= 3:
+            site, stacked = site_of(sites, path)
             s = zero_partition_spec(leaf.shape, mesh,
                                     data_axes=param_axes,
                                     base_spec=base,
-                                    persistence_threshold=persistence_threshold)
+                                    persistence_threshold=persistence_threshold,
+                                    stacked=stacked, leading=site is not None)
         else:
             s = base if base is not None else P()
         return NamedSharding(mesh, s)
 
-    def opt_sharding(leaf, spec):
+    def opt_sharding(path, leaf, spec):
         base = base_spec_of(spec)
         if stage >= 1:
-            s = zero_partition_spec(leaf.shape, mesh, base_spec=base)
+            # the same dims as the parameter's, or every update would
+            # move its leaf between two layouts
+            site, stacked = site_of(sites, path)
+            s = zero_partition_spec(leaf.shape, mesh, base_spec=base,
+                                    stacked=stacked, leading=site is not None)
         else:
             s = base if base is not None else P()
         return NamedSharding(mesh, s)
 
-    param_shardings = jax.tree_util.tree_map(
+    param_shardings = jax.tree_util.tree_map_with_path(
         param_sharding, params_shapes, param_specs,
         is_leaf=lambda x: hasattr(x, "shape"))
-    opt_shardings = jax.tree_util.tree_map(
+    opt_shardings = jax.tree_util.tree_map_with_path(
         opt_sharding, params_shapes, param_specs,
         is_leaf=lambda x: hasattr(x, "shape"))
     return param_shardings, opt_shardings
 
 
 def build_opt_state_shardings(opt_abstract, params_abstract, mesh: Mesh,
-                              stage: int, param_specs=None):
+                              stage: int, param_specs=None, sites=None):
     """Shardings for an arbitrary optimizer-state pytree.
 
     Optimizer states are built of (a) subtrees that mirror the params tree
@@ -167,7 +220,7 @@ def build_opt_state_shardings(opt_abstract, params_abstract, mesh: Mesh,
     """
     params_leaves, params_def = jax.tree_util.tree_flatten(params_abstract)
     _, mirrored = build_zero_shardings(params_abstract, mesh, stage=stage,
-                                       param_specs=param_specs)
+                                       param_specs=param_specs, sites=sites)
     rep = replicated(mesh)
 
     def _mirrors_params(sub) -> bool:
@@ -196,6 +249,285 @@ def build_opt_state_shardings(opt_abstract, params_abstract, mesh: Mesh,
 
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
+
+
+# ----------------------------------------------------------------------
+# ZeRO-3 at the use site: gather the WEIGHT, scatter its gradient
+#
+# A model written against this seam declares its use sites
+# (``zero3_use_sites()`` on the object the engine trains: param-path
+# prefix -> leading scanned dims) and asks :func:`gather_at_use` for the
+# weights of each site where it uses them. While the engine traces a
+# stage-3 step it runs the loss under ``shard_map`` with a
+# :class:`GatherPlan` of the live ZeRO axes active; everywhere else no plan is
+# active and the function is the identity.
+GATHERED = "zero3_gathered"  # checkpoint_name of every gathered weight
+
+_PLANS: list = []
+
+
+def use_sites_of(model) -> Dict[str, int]:
+    """The use sites a model declares, ``{}`` for one that declares none."""
+    sites = getattr(model, "zero3_use_sites", None)
+    return dict(sites()) if callable(sites) else {}
+
+
+def site_of(sites: Optional[Dict[str, int]], path) -> Tuple[Optional[str], int]:
+    """``(site, stacked dims)`` of the site a param path lies under
+    (``path``: a "/"-joined string or a tree key path); ``(None, 0)``."""
+    if not sites:
+        return None, 0
+    if not isinstance(path, str):
+        path = _path_str(path)
+    for site, stacked in sites.items():
+        if path == site or path.startswith(site + "/"):
+            return site, int(stacked)
+    return None, 0
+
+
+def _path_str(key_path) -> str:
+    return "/".join(key_entry_str(k) for k in key_path)
+
+
+def _names(entry) -> Tuple[str, ...]:
+    if entry is None:
+        return ()
+    return tuple(entry) if isinstance(entry, (tuple, list)) else (entry,)
+
+
+def _entry(names):
+    return None if not names else names[0] if len(names) == 1 else tuple(names)
+
+
+def manual_spec(spec, axes: Sequence[str], ndim: Optional[int] = None) -> P:
+    """The part of ``spec`` that names ``axes``: what a ``shard_map`` manual
+    over exactly those axes takes for an array laid out as ``spec``."""
+    entries = list(spec) if spec is not None else []
+    if ndim is not None:
+        entries += [None] * (ndim - len(entries))
+    return P(*(_entry([a for a in _names(e) if a in axes]) for e in entries))
+
+
+class GatherPlan:
+    """What the engine makes known while it traces a stage-3 step: the
+    mesh, every parameter's shape and PartitionSpec, the model's use
+    sites and the live ZeRO axes (the ``shard_map``'s manual axes). Also
+    the record of what was gathered (:meth:`describe`), filled as the
+    model is traced."""
+
+    def __init__(self, mesh: Mesh, shardings, shapes,
+                 sites: Dict[str, int],
+                 zero_axes: Sequence[str] = ZERO_AXES,
+                 batch_axes: Sequence[str] = BATCH_AXES):
+        self.mesh = mesh
+        self.axes = tuple(a for a in zero_axes if mesh.shape.get(a, 1) > 1)
+        self.batch_axes = tuple(a for a in batch_axes if a in self.axes)
+        self.world = int(np.prod([mesh.shape[a] for a in self.axes] or [1]))
+        self.sites = dict(sites)
+        self.specs = jax.tree_util.tree_map(
+            lambda sh: getattr(sh, "spec", sh), shardings,
+            is_leaf=lambda x: isinstance(x, (NamedSharding, P)))
+        self.shapes = jax.tree_util.tree_map(
+            lambda x: tuple(x.shape), shapes,
+            is_leaf=lambda x: hasattr(x, "shape"))
+        self.served: Dict[str, Dict] = {}
+
+    def __enter__(self):
+        _PLANS.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        _PLANS.pop()
+
+    # -- what the shard_map takes -----------------------------------------
+    def param_in_specs(self):
+        return jax.tree_util.tree_map(
+            lambda spec, shape: manual_spec(spec, self.axes, len(shape)),
+            self.specs, self.shapes, is_leaf=lambda x: isinstance(x, P))
+
+    def batch_in_spec(self, x) -> P:
+        entry = axis_spec_entry(self.mesh, self.batch_axes,
+                                x.shape[0] if x.ndim else None)
+        return P(entry) if x.ndim else P()
+
+    def replica_index(self):
+        """This shard's index over the batch axes (0 where the batch is
+        not split): what a per-shard rng folds in."""
+        idx = 0
+        for a in self.batch_axes:
+            idx = idx * self.mesh.shape[a] + jax.lax.axis_index(a)
+        return idx
+
+    # -- the gather -------------------------------------------------------
+    def _leaf(self, w, spec, shape, path: str, stacked: int, dtype,
+              keep_dtype: bool):
+        entries = list(spec) + [None] * (len(shape) - len(spec))
+        dims = [(i - stacked, tuple(a for a in _names(e) if a in self.axes))
+                for i, e in enumerate(entries)]
+        dims = [(i, axes) for i, axes in dims if axes]
+        if not dims:
+            return w
+        if any(i < 0 for i, _ in dims):
+            raise ValueError(
+                f"{path}: a scanned dim of a ZeRO-3 use site is sharded "
+                f"({spec}); declare the site's scanned dims")
+        floating = jax.numpy.issubdtype(w.dtype, jax.numpy.floating)
+        wire = jax.numpy.dtype(dtype if dtype is not None and floating
+                               else w.dtype)
+        out = w.dtype if keep_dtype else wire
+        trips = int(np.prod(shape[:stacked] or (1,)))
+        full = int(np.prod(shape[stacked:]))
+        used = [a for _, axes in dims for a in axes]
+        group = int(np.prod([self.mesh.shape[a] for a in used]))
+        self.served[path] = {
+            "in_scan": stacked > 0, "trips": trips,
+            "gather_operand_bytes": full // group * wire.itemsize,
+            "scatter_operand_bytes": full * 4,
+            "wire_dtype": wire.name}
+        return _gather_leaf(
+            w, dims, tuple(a for a in self.axes if a not in used), wire, out)
+
+    def gather(self, tree, path: Sequence[str], dtype=None, stacked: int = 0,
+               keep_dtype: bool = False):
+        specs, shapes = self.specs, self.shapes
+        for k in path:
+            specs, shapes = specs[k], shapes[k]
+        prefix = "/".join(path)
+        return jax.tree_util.tree_map_with_path(
+            lambda kp, w, spec, shape: self._leaf(
+                w, spec, shape,
+                "/".join(filter(None, (prefix, _path_str(kp)))),
+                stacked, dtype, keep_dtype),
+            tree, specs, shapes)
+
+    def gather_rest(self, params):
+        """Every sharded leaf that lies under NO use site, gathered here,
+        once, in its own dtype: whatever the model does not ask for is
+        whole before the model sees it."""
+        def leaf(kp, w, spec, shape):
+            path = _path_str(kp)
+            if site_of(self.sites, path)[0] is not None:
+                return w
+            return self._leaf(w, spec, shape, path, 0, None, False)
+
+        return jax.tree_util.tree_map_with_path(
+            leaf, params, self.specs, self.shapes)
+
+    def reduce_rest(self, grads):
+        """The gradient of every leaf the plan did not gather (a
+        persistent one, whole on every chip) summed over the manual axes
+        in float32; a gathered leaf's was reduce-scattered in its
+        backward."""
+        def leaf(kp, g):
+            if _path_str(kp) in self.served or not self.axes:
+                return g
+            return jax.lax.psum(g.astype(np.float32), self.axes)
+
+        return jax.tree_util.tree_map_with_path(leaf, grads)
+
+    # -- the counter ------------------------------------------------------
+    def describe(self) -> Dict:
+        """JSON-safe plan of one training step on one chip: which leaves
+        are gathered where, and the operand bytes of the all-gathers and
+        reduce-scatters (what each chip feeds them; a scanned leaf is
+        gathered again in the rematerialised backward)."""
+        scan = {p: r for p, r in self.served.items() if r["in_scan"]}
+        once = {p: r for p, r in self.served.items() if not r["in_scan"]}
+        n_leaves = len(jax.tree_util.tree_leaves(
+            self.shapes, is_leaf=lambda x: isinstance(x, tuple)))
+        in_scan = sum(2 * r["trips"] * r["gather_operand_bytes"]
+                      for r in scan.values())
+        gathered = in_scan + sum(r["gather_operand_bytes"]
+                                 for r in once.values())
+        scattered = sum(r["trips"] * r["scatter_operand_bytes"]
+                        for r in self.served.values())
+        return {"program": "gather_at_use", "axes": list(self.axes),
+                "leaves_gathered_in_scan": len(scan),
+                "leaves_gathered_once": len(once),
+                "leaves_persistent": n_leaves - len(self.served),
+                "gather_operand_bytes_step": int(gathered),
+                "gather_operand_bytes_in_scan": int(in_scan),
+                "scatter_operand_bytes_step": int(scattered),
+                "wire_dtypes": sorted({r["wire_dtype"]
+                                       for r in self.served.values()})}
+
+
+def _gather_leaf(w, dims, other_axes, wire_dtype, out_dtype):
+    """``w`` (this chip's shard) -> the whole weight: cast to the wire
+    dtype ON THE SHARD, all-gathered over the ZeRO axes of each sharded
+    dim. Its transpose is written out: the cotangent is cast UP to
+    float32 and reduce-scattered, so every chip ends holding its own
+    shard of the float32 sum (and the sum over ``other_axes``, the manual
+    axes this leaf is whole on: hpZ's ``data``)."""
+    in_dtype = w.dtype
+
+    @jax.custom_vjp
+    def zero3_gather(w):  # the name is what save_all_but_gathered reads
+        g = w.astype(wire_dtype)
+        for dim, axes in dims:
+            g = jax.lax.all_gather(g, axes, axis=dim, tiled=True)
+        return g.astype(out_dtype)
+
+    def fwd(w):
+        return zero3_gather(w), None
+
+    def bwd(_, ct):
+        ct = ct.astype(np.float32)
+        for dim, axes in reversed(dims):
+            ct = jax.lax.psum_scatter(ct, axes, scatter_dimension=dim,
+                                      tiled=True)
+        if other_axes:
+            ct = jax.lax.psum(ct, other_axes)
+        return (ct.astype(in_dtype),)
+
+    zero3_gather.defvjp(fwd, bwd)
+    # named OUTSIDE the custom_vjp, where a remat policy can see it: no
+    # policy saves a gathered weight, backward gathers again
+    return checkpoint_name(zero3_gather(w), GATHERED)
+
+
+def save_all_but_gathered(prim, *_, **params) -> bool:
+    """A remat policy for a block that is NOT rematerialised: every
+    residual is saved as without remat, but a gathered weight, which
+    backward gathers again (the ``custom_vjp`` call that made it, by its
+    function's name, and the named value itself)."""
+    if prim.name == "custom_vjp_call":
+        info = getattr(getattr(params.get("call_jaxpr"), "jaxpr", None),
+                       "debug_info", None)
+        return not str(getattr(info, "func_src_info", "")).startswith(
+            "zero3_gather ")
+    if prim.name == "name":
+        return params.get("name") != GATHERED
+    return True
+
+
+def gather_at_use(tree, path: Sequence[str], dtype=None, stacked: int = 0,
+                  keep_dtype: bool = False):
+    """THE SEAM. ``tree``: the parameters at ``path`` of the model's
+    parameter tree (a leaf or a subtree), as the model is about to use
+    them; ``stacked``: how many leading scanned dims a ``lax.scan`` has
+    already sliced off them. Returns them whole: each leaf that ZeRO-3
+    shards is cast to ``dtype`` (floating leaves; ``None`` keeps the
+    leaf's own) on the shard, all-gathered over its ZeRO axes, and its
+    gradient is reduce-scattered in float32 (:func:`_gather_leaf`).
+    ``keep_dtype`` casts the gathered values back up to the leaf's dtype:
+    for a table whose cotangents are accumulated in it (an embedding
+    looked up and tied to the head), so that only the wire is narrow.
+
+    The identity unless a :class:`GatherPlan` is active, which the engine
+    enters only around a stage-3 step on a mesh whose ZeRO axes multiply
+    to more than one, and even then for every leaf whose spec names no
+    such axis."""
+    plan = _PLANS[-1] if _PLANS else None
+    if plan is None:
+        return tree
+    return plan.gather(tree, tuple(path), dtype, stacked, keep_dtype)
+
+
+def gathering() -> bool:
+    """Whether a plan is active: a block stack wraps its block for the
+    seam only then, so every other trace is the unwrapped module's."""
+    return bool(_PLANS)
 
 
 # ----------------------------------------------------------------------

@@ -249,3 +249,63 @@ def collective_operand_dtypes(hlo_text: str, min_bytes: int = 0):
         if c["operand_bytes"] >= min_bytes:
             dtypes.update(d for d, _ in c["operands"])
     return dtypes
+
+
+# ----------------------------------------------------------------------
+# per-step counts: a collective inside a `while` body runs once a trip
+_COMP_RE = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
+_CALLEE_RE = re.compile(
+    r"\b(body|condition|to_apply|calls)=%?([\w.\-]+)")
+_CALLEE_LIST_RE = re.compile(
+    r"\b(?:branch_computations|called_computations)=\{([^}]*)\}")
+_TRIPS_RE = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
+
+
+def _computations(hlo_text: str):
+    """``(entry name, {name: [lines]})`` of a printed HLO module."""
+    comps: Dict[str, List[str]] = {}
+    entry, name = None, None
+    for line in hlo_text.splitlines():
+        m = _COMP_RE.match(line)
+        if m:
+            name = m.group(2)
+            comps[name] = []
+            if m.group(1):
+                entry = name
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return entry, comps
+
+
+def collectives_per_step(hlo_text: str) -> List[Dict]:
+    """:func:`parse_collectives`, each entry with ``trips``: how many times
+    one execution of the module runs it, the product of the known trip
+    counts of the ``while`` loops around it (1 outside every loop), and
+    ``computation``, the one it is printed in. A loop whose trip count
+    the compiler did not print counts as one trip."""
+    entry, comps = _computations(hlo_text)
+    trips: Dict[str, int] = {}
+
+    def visit(name: str, mult: int):
+        if name not in comps:
+            return
+        trips[name] = trips.get(name, 0) + mult
+        for line in comps[name]:
+            n = _TRIPS_RE.search(line)
+            for kind, callee in _CALLEE_RE.findall(line):
+                loop = kind in ("body", "condition") and n
+                visit(callee, mult * (int(n.group(1)) if loop else 1))
+            for group in _CALLEE_LIST_RE.findall(line):
+                for callee in _NAME_RE.findall(group):
+                    visit(callee, mult)
+
+    if entry is not None:
+        visit(entry, 1)
+    out = []
+    for name, lines in comps.items():
+        for c in parse_collectives("\n".join(lines)):
+            out.append({**c, "computation": name,
+                        "trips": trips.get(name, 1)})
+    return out

@@ -458,3 +458,87 @@ def test_serving_programs_update_the_kv_pool_in_place(one_chip, monkeypatch,
         mem.alias_size_in_bytes, pool_bytes)
     if program == "decode":
         assert any("_paged_kv_attend" in k for k in _kernel_names(text))
+
+
+# ---------------------------------------------------------------------------
+# ZeRO-3's explicit program as the chip's compiler makes it
+def test_zero3_step_gathers_bf16_weights_and_scatters_f32_gradients(
+        topo, _no_global_topology):
+    """The engine's stage-3 fused step for a scanned GPT-2 at XL's width
+    (two layers) on ``data=4``, compiled for the described chips from
+    abstract state: inside the layer loops the weights cross the wire as
+    bf16 all-gathers, the gradient sums are float32 (``reduce-scatter``,
+    or the all-reduce-scatter the backend lowers one to), and nothing is
+    an ``all-to-all``. (The CPU backend widens a bf16 collective to
+    float32, so only this test reads the wire's dtype off a compiled
+    program; tests/unit/test_zero3_gather_at_use.py has the rest.)"""
+    import deepspeed_tpu
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2ForTraining
+    from deepspeed_tpu.parallel.topology import MeshTopology
+    from deepspeed_tpu.runtime.engine import TrainState
+    from deepspeed_tpu.runtime.zero.partition import (
+        batch_sharding, build_opt_state_shardings, build_zero_shardings,
+        replicated)
+    from deepspeed_tpu.utils.hlo_inspect import collectives_per_step
+
+    layers, width, seq = 2, 1600, 256
+    model = GPT2ForTraining(GPT2Config(
+        vocab_size=1024, n_positions=seq, n_embd=width, n_layer=layers,
+        n_head=25, dtype=jnp.bfloat16, scan_layers=True, remat=True,
+        remat_policy="dots"))
+    engine, *_ = deepspeed_tpu.initialize(
+        model=model, mesh=MeshTopology(axis_sizes={"data": 4},
+                                       devices=topo.devices[:4]),
+        config={"train_micro_batch_size_per_gpu": 1,
+                "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+                "bf16": {"enabled": True}, "fused_step": True,
+                "zero_optimization": {"stage": 3},
+                "steps_per_print": 10 ** 9})
+    mesh, rep = engine.mesh, replicated(engine.mesh)
+    params = jax.eval_shape(lambda r: model.init(
+        r, {"input_ids": jnp.zeros((1, 8), jnp.int32)})["params"],
+        jax.random.PRNGKey(0))
+    sites = engine._zero3_sites
+    param_sh, _ = engine._shardings_for(params)
+    opt = jax.eval_shape(engine.optimizer.init, params)
+    opt_sh = build_opt_state_shardings(opt, params, mesh, stage=3,
+                                       sites=sites)
+    _, engine._grad_shardings = build_zero_shardings(
+        params, mesh, stage=3, sites=sites)
+
+    def shaped(tree, shardings):
+        return jax.tree_util.tree_map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree, shardings)
+
+    scalar = lambda dt: jax.ShapeDtypeStruct((), dt, sharding=rep)  # noqa: E731
+    scaler = engine._initial_loss_scaler
+    scaler_sh = jax.tree_util.tree_map(lambda _: rep, scaler)
+    engine._state_shardings = TrainState(
+        params=param_sh, opt_state=opt_sh, grad_acc={}, loss_scale=scaler_sh,
+        global_step=rep, skipped_steps=rep, rng=rep)
+    engine.state = TrainState(
+        params=shaped(params, param_sh), opt_state=shaped(opt, opt_sh),
+        grad_acc={}, loss_scale=shaped(jax.tree_util.tree_map(
+            jnp.asarray, scaler), scaler_sh),
+        global_step=scalar(jnp.int32), skipped_steps=scalar(jnp.int32),
+        rng=jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep))
+    engine._compile_steps()
+    batch = {"input_ids": jax.ShapeDtypeStruct(
+        (4, seq), jnp.int32,
+        sharding=batch_sharding(mesh, ndim=2, shape=(4, seq)))}
+    text = engine._jit_fused.lower(
+        engine.state, batch, scalar(jnp.float32)).compile().as_text()
+    assert engine._zero3_program["program"] == "gather_at_use"
+
+    # two layers: the compiler unrolls both loops, so every weight-sized
+    # collective of the step is read (the optimizer's own are smaller)
+    big = [c for c in collectives_per_step(text)
+           if c["operand_bytes"] >= width * width // 4]
+    assert not [c for c in big if c["op"] == "all-to-all"]
+    gathers = [c for c in big if c["op"] == "all-gather"]
+    assert len(gathers) >= 2 * 4 * layers  # forward and backward, a layer
+    assert all({d for d, _ in c["operands"]} == {"bf16"} for c in gathers)
+    sums = [c for c in big if c["op"] in ("reduce-scatter", "all-reduce")]
+    assert len(sums) >= layers
+    assert all({d for d, _ in c["operands"]} == {"f32"} for c in sums)

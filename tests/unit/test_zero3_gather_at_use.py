@@ -1,0 +1,404 @@
+"""ZeRO-3 gathers the weights, not the activations: the program, pinned.
+
+Under the engine's stage-3 step a model written against the seam
+(``runtime/zero/partition.gather_at_use``; the scanned GPT-2) runs under
+``shard_map`` over the live ZeRO axes: inside the layer loop one layer's
+weights are all-gathered in the compute dtype and their float32 gradients
+reduce-scattered; activations never leave the batch layout, so no
+``all-to-all`` and no ``collective-permute`` crosses a ZeRO axis. What the
+compiled step gathers and scatters is what the plan the engine logs says.
+Everywhere else (lower stages, one device, serving, a model that declares
+no use site) the seam is the identity and the program is the parent's.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu
+from deepspeed_tpu.models.gpt2 import (GPT2Config, GPT2ForTraining,
+                                       GPT2LMHeadModel)
+from deepspeed_tpu.parallel.topology import MeshTopology, reset_topology
+from deepspeed_tpu.runtime.zero import partition as zero
+from deepspeed_tpu.utils.hlo_inspect import collectives_per_step
+
+LAYERS, WIDTH, HEADS, VOCAB, SEQ = 3, 64, 4, 256, 32
+# a layer's kernels (4,096 to 16,384 elements) and the tables are sharded;
+# its biases and norms (64 to 256) are persistent
+THRESHOLD = 1000
+
+
+@pytest.fixture(autouse=True)
+def _fresh_topology():
+    reset_topology()
+    yield
+    reset_topology()
+
+
+def _engine(stage, axes, dtype=jnp.float32, hierarchical=False, scan=True,
+            policy="dots", micro=2):
+    reset_topology()
+    n = int(np.prod(list(axes.values())))
+    model = GPT2ForTraining(GPT2Config(
+        vocab_size=VOCAB, n_positions=SEQ, n_embd=WIDTH, n_layer=LAYERS,
+        n_head=HEADS, dtype=dtype, scan_layers=scan,
+        remat=policy is not None, remat_policy=policy or "full"))
+    config = {
+        "train_micro_batch_size_per_gpu": micro,
+        "gradient_accumulation_steps": 1,
+        "optimizer": {"type": "AdamW",
+                      "params": {"lr": 1e-3, "weight_decay": 0.1}},
+        "gradient_clipping": 1.0,
+        "bf16": {"enabled": dtype == jnp.bfloat16},
+        "fused_step": True,
+        "zero_optimization": {"stage": stage,
+                              "param_persistence_threshold": THRESHOLD,
+                              "hierarchical_gather": hierarchical},
+        "steps_per_print": 10 ** 9, "seed": 7}
+    engine, *_ = deepspeed_tpu.initialize(
+        model=model, config=config,
+        mesh=MeshTopology(axis_sizes=axes, devices=jax.devices()[:n]))
+    return engine
+
+
+def _batch(engine, axes, micro=2, step=0):
+    rows = micro * axes.get("data", 1)
+    ids = np.random.default_rng(step).integers(
+        0, VOCAB, (rows, SEQ), dtype=np.int32)
+    return {"input_ids": ids}
+
+
+def _train(engine, axes, steps=2):
+    losses = []
+    for i in range(steps):
+        loss = engine(_batch(engine, axes, step=i))
+        engine.backward(loss)
+        engine.step()
+        losses.append(float(loss))
+    return losses
+
+
+def _step_text(engine, axes):
+    """The compiled fused step (the engine's own jitted program)."""
+    engine(_batch(engine, axes))  # builds the state and the program
+    return engine._jit_fused.lower(
+        engine.state, engine._shard_batch(_batch(engine, axes)),
+        jnp.float32(0)).compile().as_text()
+
+
+def _lowered_text(engine, axes):
+    """The step as JAX hands it to the compiler (StableHLO)."""
+    return engine._jit_fused.lower(
+        engine.state, engine._shard_batch(_batch(engine, axes)),
+        jnp.float32(0)).as_text()
+
+
+MESHES = [
+    ({"data": 4}, False), ({"fsdp": 4}, False),
+    ({"data": 2, "fsdp": 2}, False), ({"data": 2, "fsdp": 2}, True),
+    ({"data": 8}, False),
+    ({"data": 4, "fsdp": 2}, True),
+]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("axes,hierarchical", MESHES, ids=[
+    "+".join(f"{a}{n}" for a, n in axes.items()) + ("-hpz" if h else "")
+    for axes, h in MESHES])
+def test_the_compiled_step_gathers_weights_and_scatters_gradients(
+        axes, hierarchical, dtype):
+    engine = _engine(3, axes, dtype, hierarchical)
+    text = _step_text(engine, axes)
+    plan = engine._zero3_program
+    assert plan["program"] == "gather_at_use"
+    zero_axes = [a for a in ("data", "fsdp") if axes.get(a, 1) > 1]
+    assert plan["axes"] == zero_axes
+    # a layer's four kernels in the scan, wte and wpe once, the rest whole
+    assert plan["leaves_gathered_in_scan"] == 4
+    assert plan["leaves_gathered_once"] == 2
+    assert plan["wire_dtypes"] == [jnp.dtype(dtype).name]
+
+    colls = [c for c in collectives_per_step(text)
+             if c["operand_bytes"] >= 1024]
+    in_loop = [c for c in colls if c["trips"] == LAYERS]
+    assert {"all-gather", "reduce-scatter"} <= {c["op"] for c in in_loop}
+    # nothing moves an activation between layouts: no all-to-all and no
+    # collective-permute inside the layer loop, nor anywhere else in the
+    # step, hpZ excepted: it moves the gradient SHARDS from the
+    # parameters' layout to the optimizer's, once, outside the loop
+    moved = [c for c in collectives_per_step(text)
+             if c["op"] in ("all-to-all", "collective-permute")]
+    assert not [c for c in moved if c["trips"] == LAYERS or not hierarchical]
+
+    # elements a step and chip: the plan the engine logs. (The CPU backend
+    # widens a bf16 collective to float32, so the compiled text is counted
+    # in elements and the wire's dtype is read from the lowered program;
+    # tests/unit/test_chip_compile.py reads the chip's own.)
+    wire = jnp.dtype(dtype).itemsize
+    widths = {"bf16": 2, "f32": 4}
+
+    def elements(cs):
+        return sum(c["trips"] * b // widths[d]
+                   for c in cs for d, b in c["operands"])
+
+    gathers = [c for c in colls if c["op"] == "all-gather"]
+    scatters = [c for c in colls if c["op"] == "reduce-scatter"]
+    assert elements(c for c in gathers if c["trips"] == LAYERS) \
+        == plan["gather_operand_bytes_in_scan"] // wire
+    assert elements(scatters) == plan["scatter_operand_bytes_step"] // 4
+    assert all({d for d, _ in c["operands"]} == {"f32"} for c in scatters)
+    lowered = _lowered_text(engine, axes)
+    gathered = re.findall(r"all_gather.*?->\s*tensor<[0-9x]*x(\w+)>", lowered)
+    assert gathered and set(gathered) == {
+        "bf16" if dtype == jnp.bfloat16 else "f32"}
+    # the sum across chips is float32 whatever the compute dtype
+    scattered = re.findall(r"reduce_scatter.*?->\s*tensor<[0-9x]*x(\w+)>",
+                           lowered, re.S)
+    assert scattered and set(scattered) == {"f32"}
+    # the replica groups are the spec's: hpZ gathers inside a data replica
+    group = int(np.prod([axes[a] for a in zero_axes
+                         if not (hierarchical and a == "data")]))
+    assert {c["group_size"] for c in gathers
+            if c["trips"] == LAYERS} == {group}
+
+
+@pytest.mark.parametrize("policy", ["dots", "full", None])
+def test_the_scan_saves_no_gathered_weight(policy):
+    """Backward gathers again: nowhere in the program is there an array of
+    a whole layer's weight times ``n_layer`` (the residual a gather outside
+    the rematerialised region would leave), in any dtype; with remat off
+    (``None``) the block's own policy keeps every residual but those.
+    ``[n_layer, width, width]`` is left out: it is also the shape of the stacked
+    SHARDS of the MLP's output projection."""
+    axes = {"data": 4}
+    engine = _engine(3, axes, jnp.bfloat16, policy=policy)
+    text = _step_text(engine, axes)
+    assert engine._zero3_program["program"] == "gather_at_use"
+    for rows, cols in [(WIDTH, 3 * WIDTH), (WIDTH, 4 * WIDTH),
+                       (4 * WIDTH, WIDTH)]:
+        assert not re.search(rf"\[{LAYERS},{rows},{cols}\]", text), (rows,
+                                                                     cols)
+    # the shards are there, stacked
+    assert re.search(rf"f32\[{LAYERS},{WIDTH // 4},{3 * WIDTH}\]", text)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+def test_stage_3_agrees_with_stage_0(dtype, tol):
+    """Two steps on data=4 from the same seed: losses, the evaluation
+    loss and the updated parameters agree with the stage-0 program's."""
+    axes = {"data": 4}
+    e0 = _engine(0, axes, dtype)
+    l0 = _train(e0, axes)
+    ev0 = float(e0.eval_batch(_batch(e0, axes, step=9)))
+    p0 = jax.tree_util.tree_map(np.asarray, e0.state.params)
+    n0 = float(e0.get_global_grad_norm() or 0.0)
+    e3 = _engine(3, axes, dtype)
+    l3 = _train(e3, axes)
+    ev3 = float(e3.eval_batch(_batch(e3, axes, step=9)))
+    n3 = float(e3.get_global_grad_norm() or 0.0)
+    np.testing.assert_allclose(l3, l0, rtol=tol, atol=tol)
+    np.testing.assert_allclose(ev3, ev0, rtol=tol, atol=tol)
+    np.testing.assert_allclose(n3, n0, rtol=10 * tol, atol=tol)
+    for a, b in zip(jax.tree_util.tree_leaves(p0),
+                    jax.tree_util.tree_leaves(e3.state.params)):
+        # AdamW's first steps move a weight by lr (1e-3) whichever way
+        # the gradient's sign falls: bf16 rounding may flip a near-zero
+        # one, in both steps
+        np.testing.assert_allclose(np.asarray(b), a, rtol=0,
+                                   atol=2e-6 if dtype == jnp.float32
+                                   else 4.5e-3)
+
+
+def test_persistence_is_judged_on_the_layer():
+    """``[n_layer, 256]`` is 768 elements stacked and under the threshold
+    either way; ``[n_layer, 64, 64]`` is sharded although... and a leaf of
+    ``[n_layer, 400]`` (1,200 stacked, 400 a layer) stays whole because
+    the unit that is gathered is the layer's slice."""
+    mesh = MeshTopology(axis_sizes={"data": 4},
+                        devices=jax.devices()[:4]).mesh
+    shapes = {"transformer": {"h": {"block": {
+        "bias": jax.ShapeDtypeStruct((LAYERS, 400), jnp.float32),
+        "kernel": jax.ShapeDtypeStruct((LAYERS, 64, 192), jnp.float32)}}}}
+    sites = {"transformer/h/block": 1}
+    stacked, _ = zero.build_zero_shardings(
+        shapes, mesh, stage=3, persistence_threshold=THRESHOLD)
+    by_unit, opt = zero.build_zero_shardings(
+        shapes, mesh, stage=3, persistence_threshold=THRESHOLD, sites=sites)
+    blk = lambda t: t["transformer"]["h"]["block"]  # noqa: E731
+    assert "data" in str(blk(stacked)["bias"].spec)      # the old rule
+    assert "data" not in str(blk(by_unit)["bias"].spec)  # persistent
+    # a site's leaf is split on the unit's LEADING dim (a gather along it
+    # is a concatenation), never on the scanned one; its optimizer state
+    # follows
+    assert tuple(blk(by_unit)["kernel"].spec) == (None, "data", None)
+    assert tuple(blk(opt)["kernel"].spec) == (None, "data", None)
+    assert tuple(blk(stacked)["kernel"].spec) == (None, None, "data")
+
+
+def test_the_seam_is_the_identity_without_a_plan():
+    tree = {"w": jnp.ones((4, 4))}
+    assert not zero.gathering()
+    assert zero.gather_at_use(tree, ("anything",), dtype=jnp.bfloat16,
+                              stacked=1) is tree
+
+
+_COLLECTIVE = re.compile(
+    r"all[-_]gather|reduce[-_]scatter|all[-_]to[-_]all|all[-_]reduce|"
+    r"collective[-_]permute|manual_computation|shard_map|psum")
+
+
+@pytest.mark.parametrize("stage", [0, 3])
+def test_one_device_lowers_to_no_collective(stage):
+    """On one device (any stage) the ZeRO axes multiply to one: no plan,
+    no ``shard_map``, no collective in the lowered step."""
+    axes = {"data": 1}
+    engine = _engine(stage, axes, jnp.bfloat16)
+    engine(_batch(engine, axes))
+    lowered = engine._jit_fused.lower(
+        engine.state, engine._shard_batch(_batch(engine, axes)),
+        jnp.float32(0)).as_text()
+    assert not _COLLECTIVE.search(lowered)
+    assert (engine._zero3_program or {}).get("program") != "gather_at_use"
+
+
+def test_stage_0_on_a_mesh_keeps_the_gspmd_program():
+    axes = {"data": 4}
+    engine = _engine(0, axes, jnp.bfloat16)
+    engine(_batch(engine, axes))
+    lowered = engine._jit_fused.lower(
+        engine.state, engine._shard_batch(_batch(engine, axes)),
+        jnp.float32(0)).as_text()
+    assert not re.search(r"manual_computation|shard_map|all_gather|"
+                         r"reduce_scatter", lowered)
+    assert engine._zero3_program is None
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_serving_programs_hold_no_collective(program):
+    """The serving engine's decode and prefill programs run the same
+    ``ScanBlocks`` with no engine and no plan: the seam is the identity."""
+    cfg = GPT2Config(vocab_size=VOCAB, n_positions=SEQ, n_embd=WIDTH,
+                     n_layer=LAYERS, n_head=HEADS, dtype=jnp.bfloat16)
+    module = GPT2LMHeadModel(cfg.for_paged_decode(9, 8, ""))
+    n, t = (3, 1) if program == "decode" else (1, 16)
+    pg = {"block_tables": jnp.zeros((n, 4), jnp.int32),
+          "lengths": jnp.zeros((n,), jnp.int32),
+          "num_valid": jnp.ones((n,), jnp.int32),
+          "prefill": program == "prefill"}
+    variables = jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros((n, t), jnp.int32), paging=pg))
+
+    def fn(variables, ids):
+        out, vars_ = module.apply(variables, ids, mutable=["cache"],
+                                  paging=pg)
+        return out, vars_["cache"]
+
+    lowered = jax.jit(fn).lower(
+        variables, jax.ShapeDtypeStruct((n, t), jnp.int32)).as_text()
+    assert not _COLLECTIVE.search(lowered)
+    assert "custom_vjp" not in lowered and "optimization_barrier" not in lowered
+
+
+def test_a_model_without_the_seam_trains_on_the_gspmd_program():
+    """``scan_layers=False`` declares no use site: stage 3 still trains,
+    on the partitioner's program, and the engine's log says which."""
+    import logging
+
+    from deepspeed_tpu.utils.logging import logger as ds_logger
+
+    axes = {"data": 4}
+    records = []
+    handler = logging.Handler()
+    handler.emit = lambda r: records.append(r.getMessage())
+    ds_logger.addHandler(handler)
+    try:
+        engine = _engine(3, axes, jnp.float32, scan=False)
+        losses = _train(engine, axes)
+    finally:
+        ds_logger.removeHandler(handler)
+    assert all(np.isfinite(losses))
+    assert engine._zero3_program == {"program": "gspmd"}
+    assert engine.describe_topology(include_tensors=False)[
+        "zero3_program"] == {"program": "gspmd"}
+    assert sum("ZeRO-3 step: GSPMD program" in m for m in records) == 1
+    e0 = _engine(0, axes, jnp.float32, scan=False)
+    np.testing.assert_allclose(losses, _train(e0, axes), rtol=1e-5)
+
+
+def test_a_tp_mesh_keeps_the_gspmd_program():
+    """``lax.all_gather`` under a ``shard_map`` that leaves ``tp`` to the
+    partitioner gets its operand whole over ``tp`` (the compiled step
+    gathered over ``tp`` first, then ``tp`` times the bytes over ``data``),
+    so a mesh with a live ``tp`` axis keeps the partitioner's program,
+    where the ``tp`` entry of every spec survives; it trains as stage 0
+    on the same mesh does."""
+    axes = {"data": 2, "tp": 2}
+    engine = _engine(3, axes, jnp.float32)
+    losses = _train(engine, axes)
+    assert engine._zero3_program == {"program": "gspmd"}
+    spec = engine._state_shardings.params["transformer"]["h"]["block"][
+        "attn"]["c_attn"]["kernel"].spec
+    assert "tp" in str(spec) and "data" in str(spec)
+    np.testing.assert_allclose(losses, _train(_engine(0, axes), axes),
+                               rtol=1e-5)
+
+
+def test_the_engine_logs_the_plan_once():
+    import logging
+
+    from deepspeed_tpu.utils.logging import logger as ds_logger
+
+    axes = {"data": 4}
+    records = []
+    handler = logging.Handler()
+    handler.emit = lambda r: records.append(r.getMessage())
+    ds_logger.addHandler(handler)
+    try:
+        engine = _engine(3, axes, jnp.bfloat16)
+        _train(engine, axes, steps=2)
+        engine.eval_batch(_batch(engine, axes))
+    finally:
+        ds_logger.removeHandler(handler)
+    logged = [m for m in records if "ZeRO-3 step:" in m]
+    assert len(logged) == 1 and "gather-at-use program over data" in logged[0]
+    plan = engine.describe_topology(include_tensors=False)["zero3_program"]
+    assert str(plan["gather_operand_bytes_step"]) in logged[0]
+    assert str(plan["scatter_operand_bytes_step"]) in logged[0]
+    # a layer's four kernels: shard (bf16) gathered forward and again in
+    # the rematerialised backward, whole (f32) scattered once
+    kernels = WIDTH * 3 * WIDTH + WIDTH * WIDTH + 2 * WIDTH * 4 * WIDTH
+    assert plan["gather_operand_bytes_in_scan"] == \
+        2 * LAYERS * kernels // 4 * 2
+    tables = VOCAB * WIDTH + SEQ * WIDTH
+    assert plan["scatter_operand_bytes_step"] == \
+        (LAYERS * kernels + tables) * 4
+
+
+def test_the_fused_step_holds_no_accumulation_buffer():
+    """Gradients go from backward to the update inside the fused program:
+    the state carries no parameter-sized zero buffer through every step.
+    Re-gating to accumulation (``set_train_batch_size``) makes one, sharded
+    as the gradients are, and the micro-step path trains on."""
+    axes = {"data": 4}
+    engine = _engine(3, axes, jnp.float32)
+    losses = _train(engine, axes)
+    assert engine._fused_step and engine.state.grad_acc == {}
+    engine.set_train_batch_size(2 * engine.train_batch_size())
+    assert not engine._fused_step
+    acc = engine.state.grad_acc["transformer"]["h"]["block"]["mlp"]["c_fc"][
+        "kernel"]
+    assert acc.shape == (LAYERS, WIDTH, 4 * WIDTH)
+    assert acc.sharding.spec == engine._grad_shardings["transformer"]["h"][
+        "block"]["mlp"]["c_fc"]["kernel"].spec
+    for step in (2, 3):  # two micro-steps, one boundary
+        loss = engine(_batch(engine, axes, step=step))
+        engine.backward(loss)
+        engine.step()
+    assert np.isfinite(float(loss)) and float(loss) < losses[0] + 1.0
+    assert engine.global_steps == 3

@@ -4,8 +4,7 @@ algbw/busbw accounting, warmup/trials).
 
 Timing is in-program chained (``lax.scan`` of dependent collective calls)
 with marginal cost (T(N)-T(1))/(N-1): per-dispatch latency and host↔device
-transfer are excluded, and min-over-repeats rides out chip sharing — the
-same methodology as tools/perf_sparse.py (PERF.md).
+transfer are excluded, and min-over-repeats rides out chip sharing.
 """
 
 import time

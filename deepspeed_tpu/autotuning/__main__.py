@@ -1,9 +1,10 @@
-"""CLI: tune the bench model and write the config bench.py consumes.
+"""CLI: tune GPT-2 125M at seq 1024 and write the best config found.
 
 ``python -m deepspeed_tpu.autotuning`` ≈ the reference's
 ``deepspeed --autotuning run`` entry (launcher/runner.py:351 routes into
-autotuning). The best config lands in ``<results-dir>/best_config.json``;
-``bench.py`` picks it up automatically when present.
+autotuning). The best config lands in ``<results-dir>/best_config.json``,
+a result the operator reads (it carries the model, seq and chip count it
+was tuned for); nothing in the repo loads it.
 """
 
 import argparse
@@ -62,7 +63,7 @@ def main(argv=None):
                         "offline launch-config search: walk the axis "
                         "registry (Pallas tiles, reduction bucket bytes, "
                         "collective tier, serving prefill shape) on the "
-                        "in-process bench harness and write "
+                        "package's own series (autotuning/series.py) and write "
                         "<results-dir>/tuned.json (consumed by the "
                         "`tuning` config block)")
     p.add_argument("--axes", default=None,

@@ -174,8 +174,8 @@ class Autotuner:
         return {
             "candidate": dataclasses.asdict(cand),
             "ds_config": self._trial_spec(cand)["ds_config"],
-            # identity: consumers (bench.py) must check the tuned config was
-            # produced for THEIR model/seq before honoring it
+            # identity: whoever applies the tuned config must check it was
+            # produced for THEIR model/seq/chip count before honoring it
             "model_spec": self.model_spec,
             "seq_len": self.seq_len,
             "dp": self.dp,

@@ -14,10 +14,9 @@ declares:
 - a **validity predicate** — a candidate the current runtime cannot
   measure (dp=1 for a reduction axis, no serving layer) is recorded as
   skipped with the reason, never silently dropped;
-- a **measurement hook** — the bench series (``bench.run_series`` /
-  ``bench_decode.run_series``) that measures it for real, reading the
-  PR 2 telemetry stream (step cost, wire bytes, retraces, TTFT) as the
-  objective rather than wall clock alone;
+- a **measurement hook** — the series (``series.run``) that measures
+  it for real, reading the PR 2 telemetry stream (step cost, wire
+  bytes, retraces, TTFT) as the objective rather than wall clock alone;
 - a **target** — the config path (``comm_quantization.bucket_bytes``,
   ``serving.prefill_chunk_tokens``) or kernel-registry key
   (``ops.decode_attention.block_k``) the chosen value is applied to.
@@ -39,12 +38,12 @@ class LiveAxis:
     name: str                 # artifact key, e.g. "zero.reduce_bucket_bytes"
     target: str               # config path or ops-registry key it tunes
     grid: Tuple               # candidate values (JSON-able)
-    bench: str                # "train" -> bench.run_series,
-    #                           "decode" -> bench_decode.run_series
-    series: str               # run_series name the measurement drives
+    bench: str                # runner family, "train" or "decode": the
+    #                           key of LiveTuner(runners={family: fn})
+    series: str               # series.run name the measurement drives
     objective: str            # measurement key that ranks candidates
     minimize: bool = False
-    # config overrides handed to run_series for one candidate value
+    # config overrides handed to series.run for one candidate value
     overrides: Callable[[object], Dict] = None
     # (ok, reason) — reason recorded in evidence when skipped
     validity: Optional[Callable[[object], Tuple[bool, str]]] = None
@@ -111,7 +110,7 @@ def _tile_on_backend(value) -> Tuple[bool, str]:
 def _mesh_shape_valid(value):
     """(data, fsdp, tp) candidate: data = -1 (fill), fsdp*tp must divide
     the device count with at least one device left for data. tp=2 also
-    needs the bench model's head count divisible — the smoke GPT-2 has
+    needs the series model's head count divisible — the smoke GPT-2 has
     4+ heads, so any tp <= 4 power of two is head-legal."""
     import jax
 

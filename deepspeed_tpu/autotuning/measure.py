@@ -1,11 +1,10 @@
 """The measured live-tuner: walk the axis registry, measure every valid
-candidate on the real bench harness, write the tuned-config artifact.
+candidate on the package's own series, write the tuned-config artifact.
 
 Unlike the offline :class:`~deepspeed_tpu.autotuning.autotuner.Autotuner`
 (subprocess trials over launch-time choices, cost-model ordered), the
-live tuner runs *in-process* against the importable bench series
-(``bench.run_series`` / ``bench_decode.run_series``): each trial builds
-the same engines the bench builds, and the measurement dict carries the
+live tuner runs *in-process* against ``series.run``: each trial builds
+a real engine, and the measurement dict carries the
 telemetry-stream objectives (steps/s, compile seconds, retraces in the
 timed window, collective wire bytes, TTFT percentiles) — not wall clock
 alone. The output is a versioned, deterministic, fingerprint-pinned
@@ -47,37 +46,21 @@ def _deep_merge(base: Dict, extra: Dict) -> Dict:
 
 
 def _default_runner(bench: str) -> Callable[[str, Dict], Dict]:
-    """Import the bench harness entry point for one axis family. The
-    repo-root bench scripts are plain modules next to the
-    ``deepspeed_tpu`` package; the tuner calls their ``run_series``
-    instead of shelling out (ISSUE 8 satellite). Resolved ONCE per axis
-    (before any candidate runs) so a missing harness is a loud failure,
-    never N trials of ImportError \"evidence\" and an empty artifact."""
-    import importlib
-    import sys
-
+    """The measurement entry point of one axis family. Both families
+    resolve to ``series.run``; the family is the key by which
+    ``LiveTuner(runners=...)`` substitutes another."""
     if bench not in ("train", "decode"):
         raise ValueError(f"unknown bench family {bench!r}")
-    name = "bench" if bench == "train" else "bench_decode"
-    repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    if repo_root not in sys.path:
-        sys.path.insert(0, repo_root)
-    try:
-        return importlib.import_module(name).run_series
-    except ImportError as e:
-        raise ImportError(
-            f"live tuning needs the bench harness module {name!r} "
-            f"(looked beside the deepspeed_tpu package at {repo_root!r}); "
-            "run from a repo checkout, or inject runners= into LiveTuner"
-        ) from e
+    from deepspeed_tpu.autotuning import series
+
+    return series.run
 
 
 class LiveTuner:
     """Measured search over live tunable axes (module docstring).
 
-    ``runners`` overrides the bench dispatch per family (tests inject
-    fakes; production uses the real bench modules). ``telemetry`` is an
+    ``runners`` overrides the measurement per family (tests inject
+    fakes; production uses ``series.run``). ``telemetry`` is an
     optional :class:`~deepspeed_tpu.telemetry.Telemetry` — each trial
     lands in its event stream as a ``tuning`` event, so
     ``tools/telemetry_report.py`` can render the search next to the
@@ -103,7 +86,7 @@ class LiveTuner:
             self._telemetry.emit("tuning", axis.name, data=data)
 
     def measure(self, axis: LiveAxis, value) -> Dict:
-        """One trial: run the axis's bench series with the candidate
+        """One trial: run the axis's series with the candidate
         applied; returns the measurement dict (must carry the axis
         objective key)."""
         config = _deep_merge(self.base_config, axis.series_config(value))
@@ -122,9 +105,9 @@ class LiveTuner:
         included)."""
         trials: List[Dict] = []
         best_value, best_score = None, None
-        # resolve the harness BEFORE the candidate loop: an unimportable
-        # bench module must fail the tune loudly, not become per-trial
-        # "evidence" in a silently empty artifact
+        # resolve the runner BEFORE the candidate loop: an unknown family
+        # must fail the tune loudly, not become per-trial "evidence" in
+        # a silently empty artifact
         self._runner(axis.bench)
         for value in axis.grid:
             ok, reason = axis.valid(value)

@@ -30,7 +30,7 @@ class ModelProfile:
     @property
     def flops_per_token(self) -> int:
         # 6N matmul FLOPs (fwd+bwd) + causal attention (PaLM appendix B,
-        # halved for causality) — same accounting as bench.py.
+        # halved for causality).
         return 6 * self.n_params + 6 * self.n_layer * self.seq_len * self.n_embd
 
 
@@ -71,9 +71,8 @@ class Candidate:
 # "none": every intermediate alive for backward (qkv, attention out, 4C mlp
 # hidden, gelu, projections, LNs, residuals). "dots": matmul outputs + flash
 # residuals only (elementwise chains recomputed). "full": just the block
-# boundary. Calibrated against xprof memory profiles of the bench model
-# (PERF.md); deliberately round numbers — this ranks candidates, it does not
-# bill them.
+# boundary. Calibrated against xprof memory profiles of GPT-2 125M;
+# deliberately round numbers — this ranks candidates, it does not bill them.
 _ACT_UNITS = {"none": 30.0, "dots": 12.0, "full": 2.0}
 
 

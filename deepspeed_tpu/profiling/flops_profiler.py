@@ -192,7 +192,7 @@ def transformer_flops_per_token(n_params: int, n_layer: int, n_embd: int,
     """Analytic transformer cost model (PaLM appendix / scaling-book form):
     fwd ≈ 2N + 2·L·T·d per token, train ≈ 3x fwd. The reference derives its
     per-module tree from hooks; on TPU the analytic form is what MFU math
-    uses (bench.py)."""
+    uses."""
     fwd = 2.0 * n_params + 2.0 * 2.0 * n_layer * seq_len * n_embd
     return {"fwd_flops_per_token": fwd,
             "train_flops_per_token": 3.0 * fwd}

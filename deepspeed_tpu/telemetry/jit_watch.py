@@ -8,8 +8,8 @@ implicit dispatch never exposes: the **compiled executable**, whose
 ``cost_analysis()`` (FLOPs, bytes accessed), ``memory_analysis()``
 (argument/output/temp bytes — peak HBM picture on TPU), and optimized HLO
 text (per-collective wire bytes via ``utils/hlo_inspect`` — the same
-parser the comm-quantization regression tests and ``tools/
-perf_comm_wire.py`` trust) become telemetry events. Subsequent calls
+parser the comm-quantization regression tests trust) become telemetry
+events. Subsequent calls
 dispatch the cached executable, so the program XLA runs is the SAME one
 the raw jit would run — the zero-overhead guard test proves the optimized
 HLO is byte-identical with telemetry on, off, and absent.

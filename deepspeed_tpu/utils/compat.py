@@ -58,8 +58,8 @@ def arm_compilation_cache() -> str:
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it, and no
     other directory is set in code; where it is not, the cache lives in one
-    fixed directory inside the checkout. Used by ``chip_smoke.py``, the
-    bench scripts and ``tests/conftest.py``."""
+    fixed directory inside the checkout. Used by ``chip_smoke.py``,
+    ``perfbench/run.py`` and ``tests/conftest.py``."""
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not cache_dir:
         cache_dir = _CHECKOUT_CACHE_DIR

@@ -12,7 +12,6 @@ child process: the caller's process is the one that holds the chip.
 """
 
 import dataclasses
-import json
 from typing import Dict
 
 
@@ -30,9 +29,10 @@ class DevicePeaks:
 
 # Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``
 # (Google Cloud TPU documentation, the "TPU v4" / "TPU v5e" / "TPU v5p" /
-# "TPU v6e" system-architecture pages). The one table in the repo: the
-# bench MFU denominator, the autotuner's roofline and chip_smoke.py all
-# read it. A kind that is not here is an error, not a default.
+# "TPU v6e" system-architecture pages). The package's one table: the
+# autotuner's roofline and chip_smoke.py read it (the benchmark keeps its
+# own, perfbench/peaks.json). A kind that is not here is an error, not a
+# default.
 PEAKS: Dict[str, DevicePeaks] = {
     "TPU v4": DevicePeaks(275e12, 1228e9, 32e9),
     "TPU v5 lite": DevicePeaks(197e12, 819e9, 16e9),
@@ -84,9 +84,3 @@ def require_device(platform: str = "tpu") -> Dict:
         f"this program needs a {platform!r} device but JAX started on "
         f"{dev['platform']!r} ({dev['kind']}); it does not carry on on "
         "another device unasked. For a CPU smoke run set JAX_PLATFORMS=cpu")
-
-
-def emit_result(out: Dict):
-    """Print one result as a JSON line that names the device it was
-    measured on (:func:`describe`), as every bench line must."""
-    print(json.dumps({**out, "device": describe()}), flush=True)

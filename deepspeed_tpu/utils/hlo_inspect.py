@@ -6,8 +6,8 @@ The wire-truth of the compressed collectives (``runtime/comm/compressed.py``
 the optimized HLO and reading its operand type, not by trusting the Python
 that requested it. This module is that reader — shared by the HLO
 regression tests (``tests/unit/test_comm_quantization.py``) and the
-PERF.md wire-bytes extractor (``tools/perf_comm_wire.py``), so the test
-and the published table can never disagree on parsing.
+telemetry step-cost collector (``telemetry/jit_watch.py``), so the test
+and the reported wire bytes can never disagree on parsing.
 """
 
 import re

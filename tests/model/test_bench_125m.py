@@ -1,9 +1,9 @@
-"""The ACTUAL bench program shape, exercised off-chip (VERDICT r4 next #3).
+"""The ACTUAL 125M smoke program shape, exercised off-chip (VERDICT r4 next #3).
 
-``bench.py``'s TPU branch trains GPT-2 125M (seq 1024, bf16, dots-remat,
-fused step, dense→chunked LM-head auto-switch). The correctness suite
-otherwise runs at toy dims, so the exact program the bench compiles was
-never exercised without the chip. Here, on the CPU mesh:
+``chip_smoke.py``'s train phase trains GPT-2 125M (seq 1024, bf16,
+dots-remat, fused step, dense→chunked LM-head auto-switch). The correctness
+suite otherwise runs at toy dims, so the exact program the smoke compiles
+was never exercised without the chip. Here, on the CPU mesh:
 
 - the REAL bench-shape program (batch 16 x 1024) is lowered + compiled
   and its ``memory_analysis()`` numbers pinned — the chunked-head switch
@@ -29,7 +29,7 @@ VOCAB = 50257
 
 
 def _bench_engine(batch):
-    """Mirrors bench.py's TPU branch exactly (single-chip mesh)."""
+    """Mirrors chip_smoke.py's 125M train engine (single-chip mesh)."""
     reset_topology()
     topo = MeshTopology(axis_sizes={"data": 1}, devices=jax.devices()[:1])
     cfg = GPT2Config(vocab_size=VOCAB, n_positions=SEQ, n_embd=768,
@@ -131,7 +131,7 @@ def test_bench_config_loss_trajectory():
     """RUN the bench config (batch 2 for CPU runtime; everything else
     identical) and pin the loss trajectory."""
     cfg, engine = _bench_engine(2)
-    ids = _ids(2)  # ONE fixed batch every step, exactly like bench.py
+    ids = _ids(2)  # ONE fixed batch every step, as chip_smoke.py trains
     losses = []
     for _ in range(3):
         loss = engine({"input_ids": ids})

@@ -267,20 +267,3 @@ class TestCompilationCacheDir:
                 pass
             assert not jax.config.jax_enable_compilation_cache
         assert jax.config.jax_enable_compilation_cache
-
-    def test_bench_startup_series_with_the_cache_armed(self):
-        """``JAX_PLATFORMS=cpu python bench.py`` died here: with the
-        persistent cache armed (conftest arms it, as the script does), the
-        second run's engine was handed its program by the cache, and the
-        CPU backend put that program into the AOT bundle without its
-        kernels. The series keeps the cache out, so twice is as good as
-        once, and the resumed engine compiles nothing."""
-        import bench
-        from deepspeed_tpu.telemetry import compile_watch
-
-        compile_watch.install()
-        for _ in range(2):
-            out = bench.run_series("startup", {"batch": 1, "seq": 32})
-            assert out["metric"].endswith("cpu_smoke_tokens_per_sec_startup")
-            assert out["warm_backend_compiles"] == 0
-            assert out["aot_save_events"] == ["captured"]

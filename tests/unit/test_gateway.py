@@ -1227,6 +1227,42 @@ class TestGatewayOverRealEngines:
         assert set(report["tenants"]) == {"t1", "t2"}
         assert all(streams.values())
 
+    def test_gold_burst_finishes_whole_while_best_effort_sheds(self):
+        """Quota isolation on the real engine: in one concurrent
+        two-tenant burst through a pumping gateway every gold request
+        finishes, while the rate-capped best_effort tenant is shed at
+        the door with 429s."""
+        from concurrent.futures import ThreadPoolExecutor
+
+        gw = _real_gateway(serving={
+            "block_size": 8, "decode_slots": 2, "max_queue_depth": 16,
+            "gateway": {"pump": True, "poll_secs": 0.002, "tenants": [
+                {"name": "gold", "api_key": "gold-key",
+                 "slo_class": "gold", "requests_per_sec": 10000.0},
+                {"name": "be", "api_key": "be-key",
+                 "slo_class": "best_effort", "requests_per_sec": 1.0,
+                 "burst_requests": 1}]}})
+
+        def one(i):
+            key = "gold-key" if i % 2 == 0 else "be-key"
+            body = {"prompt": [3 + i, 4, 5, 6][:2 + i % 3],
+                    "max_new_tokens": 4, "stream": False}
+            try:
+                with _post(gw.url, body, key=key, timeout=120) as resp:
+                    return key, json.loads(resp.read())["state"]
+            except urllib.error.HTTPError as e:
+                with e:
+                    return key, e.code
+
+        try:
+            with ThreadPoolExecutor(max_workers=6) as pool:
+                results = list(pool.map(one, range(6)))
+        finally:
+            gw.destroy()
+        assert [s for k, s in results if k == "gold-key"] \
+            == [rq.FINISHED] * 3
+        assert [s for k, s in results if k == "be-key"].count(429) >= 1
+
     def test_gateway_block_leaves_decode_hlo_byte_identical(self):
         """Zero-overhead pin (the PR 2-12 convention): the gateway is
         pure host-side policy — a serving config WITH a gateway+tenants
@@ -1256,22 +1292,3 @@ class TestGatewayOverRealEngines:
             texts.append(lowered.compile().as_text())
             srv.destroy()
         assert texts[0] == texts[1]
-
-
-# ---------------------------------------------------------------------------
-@pytest.mark.heavy
-def test_bench_gateway_series_contract():
-    """The bench satellite: ``run_series('gateway')`` measures direct vs
-    through-gateway on the real engine and proves quota isolation — the
-    gold tenant's burst comes through clean while the rate-capped
-    best_effort tenant sheds with 429s."""
-    from bench_decode import run_series
-
-    out = run_series("gateway")
-    assert out["metric"].endswith("_gateway")
-    assert "error" not in out, out
-    assert out["direct_tokens_per_sec"] and out["gateway_tokens_per_sec"]
-    assert out["gateway_ttft_ms_p95"] is not None
-    # quota isolation: every gold request finished; best_effort shed
-    assert out["burst_gold_ok"] == out["burst_gold_requests"]
-    assert out["burst_best_effort_429"] >= 1

@@ -71,7 +71,7 @@ class TestInferenceEngine:
 
     @pytest.mark.parametrize("dtype", ["fp32", "int8"])
     def test_forward_last_matches_full_forward(self, dtype):
-        # the serving prefill (bench_decode TTFT): last-position logits
+        # the serving prefill (TTFT): last-position logits
         # sliced INSIDE the jit must equal the full forward's last column
         # — including through the int8 dequant path
         cfg = _tiny()

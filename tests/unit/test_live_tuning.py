@@ -521,19 +521,51 @@ class TestTelemetryReportTuning:
 
 # ----------------------------------------------------------------------
 class TestBenchRunSeries:
-    def test_unknown_series_rejected(self):
-        import bench
-        import bench_decode
+    @pytest.mark.parametrize("name", ["startup", "gateway"])
+    def test_unknown_series_rejected(self, name):
+        """Only the four series the axis registry names came into the
+        package: a train-side and a decode-side name of the bench
+        scripts that went are unknown."""
+        from deepspeed_tpu.autotuning import series
 
-        with pytest.raises(KeyError, match="unknown bench series"):
-            bench.run_series("nope")
-        with pytest.raises(KeyError, match="unknown decode series"):
-            bench_decode.run_series("nope")
+        with pytest.raises(KeyError, match="unknown series"):
+            series.run(name)
+
+    def test_default_runners_resolve_from_the_package_alone(self, tmp_path):
+        """A library layer imports nothing above itself: with only the
+        package importable (no checkout root on ``sys.path``, the
+        working directory elsewhere) a default ``LiveTuner`` resolves
+        both families' runners."""
+        import subprocess
+        import sys
+
+        import deepspeed_tpu
+
+        site = tmp_path / "site"
+        site.mkdir()
+        os.symlink(os.path.dirname(deepspeed_tpu.__file__),
+                   site / "deepspeed_tpu")
+        script = (
+            "import os, sys\n"
+            "import deepspeed_tpu.autotuning.measure as measure\n"
+            "from deepspeed_tpu.autotuning import series\n"
+            "tuner = measure.LiveTuner()\n"
+            "assert tuner._runner('train') is series.run\n"
+            "assert tuner._runner('decode') is series.run\n"
+            "assert not any(os.path.exists(os.path.join(p, 'chip_smoke.py'))\n"
+            "               for p in sys.path), sys.path\n"
+            "print('RUNNERS_OK')\n")
+        res = subprocess.run(
+            [sys.executable, "-c", script], capture_output=True, text=True,
+            cwd=str(tmp_path), timeout=120,
+            env={**os.environ, "PYTHONPATH": str(site)})
+        assert res.returncode == 0, res.stderr
+        assert "RUNNERS_OK" in res.stdout
 
     @pytest.mark.heavy
     def test_acceptance_three_axes_on_real_bench(self, tmp_path):
         """ISSUE 8 acceptance: the live autotuner over the three named
-        axes on the REAL bench harness writes a tuned.json whose
+        axes on the package's own series writes a tuned.json whose
         choices are backed by recorded measurement evidence and
         consumed by a rebuilt engine."""
         import deepspeed_tpu

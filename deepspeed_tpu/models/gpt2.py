@@ -315,6 +315,8 @@ class CausalSelfAttention(nn.Module):
         keys, the same program the append-cache prefill compiles. ``kv``
         is ``(pools, layer)`` as the block stack threads it (see
         :func:`_paged_pool_vars`); the updated pools go back the same way.
+        ``paging["work"]``, where the model put it, is the kernel's work
+        list of this step's lengths, made once for every layer.
         Returns ``(q4, k4, v4, y, cached_attn, pools)``; ``y is None`` on
         the prefill fall-through."""
         cfg = self.config
@@ -373,14 +375,16 @@ class CausalSelfAttention(nn.Module):
                 y4 = decode_attention_paged_int8_tp(
                     q4, pools["key_pool"], pools["value_pool"],
                     pools["key_scale"], pools["value_scale"], tables,
-                    lengths, layer, softmax_scale=cfg.attn_scale)
+                    lengths, layer, softmax_scale=cfg.attn_scale,
+                    work=paging.get("work"))
             else:
                 from deepspeed_tpu.ops.decode_attention import (
                     decode_attention_paged_tp)
 
                 y4 = decode_attention_paged_tp(
                     q4, pools["key_pool"], pools["value_pool"], tables,
-                    lengths, layer, softmax_scale=cfg.attn_scale)
+                    lengths, layer, softmax_scale=cfg.attn_scale,
+                    work=paging.get("work"))
             y = y4.transpose(0, 2, 1, 3)
         else:
             from deepspeed_tpu.ops.decode_attention import (
@@ -882,6 +886,17 @@ class GPT2LMHeadModel(nn.Module):
                 "scan_layers=False: the window is a static per-layer "
                 "property, but a scanned stack compiles ONE body")
         blocks = ScanBlocks if cfg.scan_layers else LoopBlocks
+        if cfg.decode and cfg.paged and paging and not paging.get("prefill"):
+            from deepspeed_tpu.ops.attention import use_decode_kernel
+            from deepspeed_tpu.ops.decode_attention import paged_work_list
+
+            if use_decode_kernel():
+                # the paged kernel's grid follows this step's lengths and
+                # nothing a layer changes: listed once here, not once a
+                # layer inside the stack
+                paging = {**paging, "work": paged_work_list(
+                    paging["lengths"], T, cfg.paged_block_size,
+                    paging["block_tables"].shape[-1])}
         if cfg.remat and cfg.cpu_checkpointing:
             # cpu_checkpointing: ONE checkpoint over the whole stack whose
             # policy host-offloads the per-layer "block_in" residuals (the

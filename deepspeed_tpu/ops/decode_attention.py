@@ -11,12 +11,16 @@ TPU-native design points:
   strided block DMA — no per-token transpose of the whole cache (the dense
   XLA fallback pays two ``[B, S, H, D] -> [B, H, S, D]`` copies per decoded
   token).
-- ``cache_index`` is a *scalar-prefetch* operand: the grid is static over
-  the full window, but blocks past the valid prefix skip both compute and
-  the online-softmax update (``pl.when``), and the boundary block is
-  iota-masked. fp32 accumulation throughout.
-- All heads are processed per grid step (grid = batch x kv-blocks): decode
-  tiles are tiny, so per-step grid overhead, not FLOPs, dominates.
+- ``cache_index`` is a *scalar-prefetch* operand: the append-cache
+  kernel's grid is static over the full window, but blocks past the valid
+  prefix skip both compute and the online-softmax update (``pl.when``),
+  and the boundary block is iota-masked. fp32 accumulation throughout.
+- All heads are processed per grid step: decode tiles are tiny, so what a
+  grid step costs whether it is live or dead (the pipeline's bookkeeping
+  for its operands), not FLOPs or bytes, dominates. The paged kernel,
+  which serving runs with most of its tables empty, therefore has NO dead
+  steps: its grid is one traced axis over the live blocks of all rows (see
+  ``_paged_call``).
 """
 
 import functools
@@ -146,9 +150,12 @@ def decode_attention(q, k_cache, v_cache, cache_index, softmax_scale=None,
 # Paged variant: the KV cache is ONE resident, layer-stacked block pool and
 # each sequence owns a block table mapping its logical blocks to pool
 # blocks — the serving layer's continuous-batching cache (vLLM-style
-# paging, TPU-native via scalar-prefetch block DMA). The dense append-cache
-# kernel above is kept untouched: it serves the legacy generate() path and
-# is the correctness oracle for this one.
+# paging, TPU-native via scalar-prefetch block DMA). The kernel walks each
+# row's OWN blocks: its grid is the live blocks of all rows, row after row,
+# read from ``lengths``, and nothing past a row's live prefix is fetched or
+# stepped over (``_paged_call``). The dense append-cache kernel above is
+# kept untouched: it serves the legacy generate() path and is the
+# correctness oracle for this one.
 #
 # THE POOL'S ONE SHAPE. Every pool leaf is ``[layers, blocks, block_size,
 # lanes]``: ``lanes = H * D`` for the key/value pools (head ``h`` is lanes
@@ -227,27 +234,37 @@ def _heads_of(block, heads, d):
     return jnp.stack([block[:, h * d:(h + 1) * d] for h in range(heads)])
 
 
-def _paged_kernel(tables_ref, lens_ref, at_ref, q_ref, k_ref, v_ref,
-                  *rest, scale, bs, tq, heads, d, num_kb, quant, head_shard):
+def _paged_kernel(row_ref, first_ref, tables_ref, lens_ref, at_ref, q_ref,
+                  k_ref, v_ref, *rest, scale, bs, tq, heads, d, quant,
+                  head_shard):
     if quant:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
         o_ref, m_scr, l_scr, acc_scr = rest
-    bi = pl.program_id(0)
-    ji = pl.program_id(1)
+    # this grid step is block ji of row bi's live prefix (see
+    # paged_work_list)
+    step = pl.program_id(0)
+    bi = row_ref[step]
+    ji = step - first_ref[bi]
     idx = lens_ref[bi]  # this row's valid length BEFORE the step
+    # an idle serving slot (length 0 AND a table that starts at the garbage
+    # block) owns no block: its one step does no arithmetic and writes
+    # zeros, which the caller discards. Any other row is attended over
+    # whatever its table names, the garbage block included
+    owns = (idx > 0) | (tables_ref[bi, 0] != GARBAGE_BLOCK)
 
-    @pl.when(ji == 0)
+    @pl.when(jnp.logical_not(owns))
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(owns & (ji == 0))
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # logical block ji covers key positions [ji*bs, (ji+1)*bs); anything
-    # at or past idx + tq is invalid (unallocated tables point at the
-    # garbage block — skipped here before its DMA'd bytes ever matter)
-    @pl.when(ji * bs < idx + tq)
-    def _body():
+    @pl.when(owns)
+    def _block():
         q = q_ref[...].reshape(tq, heads, d).transpose(1, 0, 2)   # [H,tq,d]
         k = _heads_of(k_ref[...], heads, d)                       # [H,bs,d]
         v = _heads_of(v_ref[...], heads, d)
@@ -265,6 +282,8 @@ def _paged_kernel(tables_ref, lens_ref, at_ref, q_ref, k_ref, v_ref,
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale            # [H,tq,bs]
+        # query row r sits at absolute position idx + r and sees keys <=
+        # that: the boundary block's rows past the prefix are masked here
         rows = jax.lax.broadcasted_iota(jnp.int32, (heads, tq, bs), 1)
         cols = jax.lax.broadcasted_iota(jnp.int32, (heads, tq, bs), 2) \
             + ji * bs
@@ -280,7 +299,8 @@ def _paged_kernel(tables_ref, lens_ref, at_ref, q_ref, k_ref, v_ref,
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(ji == num_kb - 1)
+    # the row's last step is the one before the next row's first
+    @pl.when(owns & (step + 1 == first_ref[bi + 1]))
     def _finish():
         l = l_scr[:, :, 0:1]
         out = acc_scr[:] / jnp.where(l == 0.0, 1.0, l)             # [H,tq,d]
@@ -288,12 +308,65 @@ def _paged_kernel(tables_ref, lens_ref, at_ref, q_ref, k_ref, v_ref,
             .astype(o_ref.dtype)
 
 
+def paged_work_list(lengths, tq, block_size, max_blocks):
+    """The paged kernel's grid, made from ``lengths`` alone: ``(row_of,
+    first)``. Row ``r`` of the batch owns grid steps ``[first[r], first[r +
+    1])``, one a live block, ``n_r = min(cdiv(lengths[r] + tq, block_size),
+    max_blocks)`` of them, and ``first[B]`` is the grid's length; ``row_of[s]``
+    is the row step ``s`` belongs to (``B * max_blocks + 1`` entries: one
+    more than the longest grid, for the pipeline's look at the step after
+    the last, which stays the last row's). ``tq >= 1``, so every row has a
+    step: an idle slot (length 0, table all garbage) one on the garbage
+    block.
+
+    It depends on nothing a layer changes, so a program that calls the
+    kernel once a layer makes it ONCE, before the layer loop, and hands it
+    to every call as ``work=`` (``models/gpt2.py`` does); a call given none
+    makes its own. It costs a ``(B + 1) x (B * max_blocks + 1)`` compare
+    and sum, and ``row_of`` takes about the SMEM the block tables do."""
+    lens = jnp.asarray(lengths, jnp.int32)
+    b = lens.shape[0]
+    live = jnp.minimum((lens + (tq + block_size - 1)) // block_size,
+                       max_blocks)
+    first = jnp.sum(jnp.tril(jnp.broadcast_to(live, (b + 1, b)), -1),
+                    axis=1, dtype=jnp.int32)
+    steps = jnp.arange(b * max_blocks + 1, dtype=jnp.int32)
+    # the rows that have started by s, less one
+    row_of = jnp.minimum(jnp.sum(first[:, None] <= steps[None, :], axis=0,
+                                 dtype=jnp.int32), b) - 1
+    return row_of, first
+
+
 def _paged_call(q, pools, block_tables, lengths, layer, softmax_scale,
-                head0=None):
+                head0=None, work=None):
     """The one ``pallas_call`` behind both paged entry points: ``pools`` is
     ``(k_pool, v_pool)`` or ``(k_pool, v_pool, k_scale, v_scale)``.
     ``head0`` (tp shards only) is the first head this call's ``q`` and K/V
-    lanes hold, for the scale rows, which stay whole."""
+    lanes hold, for the scale rows, which stay whole. ``work`` is
+    :func:`paged_work_list` of these ``lengths``, made here if not given.
+
+    THE GRID FOLLOWS ``lengths``, NOT ``block_tables.shape``: it is one
+    axis whose (traced) length is the number of live blocks of all rows,
+    ``sum_b n_b`` with ``n_b = min(cdiv(lengths[b] + T_q, bs), MB)``, and
+    step ``s`` is the next block of the last row that has started by
+    ``s``: row 0's blocks in ascending order, then row 1's, and so on
+    (``row_of`` and ``first``, the work list, are two more scalar-prefetch
+    operands). A fixed ``(B, MB)`` grid pays the pipeline's bookkeeping
+    for every step of every table, dead or live, and that bookkeeping, not
+    the bytes, was the kernel's time while few slots were busy; this one
+    runs no step, and fetches no block, past a row's live prefix. A live
+    step is the fixed grid's live step: the same fp32 online-softmax update
+    on the one block that arrived, so the sums are taken in the same order
+    and the outputs of rows that hold a sequence are the fixed grid's to
+    the bit. (A grid over rows with a manual ``make_async_copy`` loop
+    inside would do the same, but Mosaic refuses a DMA slice of a pool row
+    whose lanes are no multiple of 128, GPT-2 XL's 1600 for one; the
+    pipeline's own block DMA moves any row.)
+
+    The one axis is ``arbitrary``: the softmax state is carried from step
+    to step in scratch, and rows are of unequal length, so a chip with two
+    TensorCores (v4, v5p) cannot split rows across them as the fixed
+    grid's ``parallel`` row axis let it. The v5e has one."""
     b, tq, heads, d = q.shape
     if tq < 1:
         raise ValueError(f"need at least one query row per sequence, "
@@ -311,19 +384,30 @@ def _paged_call(q, pools, block_tables, lengths, layer, softmax_scale,
             f"{heads} heads,): one f32 scale per pool row x head")
     mb = block_tables.shape[-1]
     scale = softmax_scale if softmax_scale is not None else d ** -0.5
+    tables = jnp.asarray(block_tables, jnp.int32)
+    lens = jnp.asarray(lengths, jnp.int32)
+    row_of, first = (paged_work_list(lens, tq, bs, mb) if work is None
+                     else work)
+    if row_of.shape != (b * mb + 1,) or first.shape != (b + 1,):
+        raise ValueError(
+            f"work list of shapes {row_of.shape}, {first.shape} is not "
+            f"paged_work_list's for {b} rows of {mb} blocks")
 
     def pool_spec(width):
-        # (layer, table[b, j]) picked by the DMA itself: the stacked pool
-        # is an operand as it lies in HBM, never a slice of it
-        return pl.BlockSpec((None, None, bs, width),
-                            lambda bi, ji, tab, ln, at:
-                            (at[0], tab[bi, ji], 0, 0))
+        # (layer, table[row, block]) picked by the DMA itself: the stacked
+        # pool is an operand as it lies in HBM, never a slice of it
+        def index(s, row_of, first, tab, ln, at):
+            row = row_of[s]
+            return (at[0], tab[row, jnp.minimum(s - first[row], mb - 1)],
+                    0, 0)
+        return pl.BlockSpec((None, None, bs, width), index)
 
     q_spec = pl.BlockSpec((1, tq, heads, d),
-                          lambda bi, ji, tab, ln, at: (bi, 0, 0, 0))
+                          lambda s, row_of, first, tab, ln, at:
+                          (row_of[s], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, mb),
+        num_scalar_prefetch=5,
+        grid=(first[b],),
         in_specs=[q_spec] + [pool_spec(p.shape[POOL_LANE_AXIS])
                              for p in pools],
         out_specs=q_spec,
@@ -334,7 +418,7 @@ def _paged_call(q, pools, block_tables, lengths, layer, softmax_scale,
         ],
     )
     kernel = functools.partial(_paged_kernel, scale=scale, bs=bs, tq=tq,
-                               heads=heads, d=d, num_kb=mb, quant=quant,
+                               heads=heads, d=d, quant=quant,
                                head_shard=quant and head0 is not None)
     at = jnp.stack([jnp.asarray(layer, jnp.int32).reshape(()),
                     jnp.asarray(0 if head0 is None else head0, jnp.int32)])
@@ -347,13 +431,12 @@ def _paged_call(q, pools, block_tables, lengths, layer, softmax_scale,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, tq, heads, d), q.dtype),
         compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
-    )(jnp.asarray(block_tables, jnp.int32), jnp.asarray(lengths, jnp.int32),
-      at, q, *pools)
+            dimension_semantics=("arbitrary",)),
+    )(row_of, first, tables, lens, at, q, *pools)
 
 
 def decode_attention_paged(q, k_pool, v_pool, block_tables, lengths,
-                           layer=0, softmax_scale=None):
+                           layer=0, softmax_scale=None, work=None):
     """Attend a decode (or k-token verify, or prefill-chunk) step against
     one layer of the paged KV pool.
 
@@ -375,22 +458,31 @@ def decode_attention_paged(q, k_pool, v_pool, block_tables, lengths,
       lengths: ``[B]`` int32 — valid tokens per row *before* this step.
       layer: int32 scalar (traced inside a layer scan, or a Python int) —
         which layer of the stacked pool to read.
+      work: :func:`paged_work_list` of these ``lengths``, for a caller
+        that runs many layers on one step's lengths and makes it once;
+        made here if None.
 
     The block table, lengths and layer are *scalar-prefetch* operands:
-    the grid is static over ``(B, MB)``, each grid step DMAs exactly the
-    ``(layer, block)`` the table names — ``block_size`` rows of ``H*D``
-    lanes, unpadded — and blocks past ``lengths[b] + T_q`` skip both the
-    fetch's compute and the online-softmax update.
+    the grid is one axis over the live blocks of all rows, ``sum_b
+    min(cdiv(lengths[b] + T_q, block_size), MB)`` steps, a traced number;
+    each step DMAs exactly the ``(layer, block)`` the table names —
+    ``block_size`` rows of ``H*D`` lanes, unpadded — and no step exists
+    for a block past ``lengths[b] + T_q``, so such a block is never
+    fetched. The fp32 online-softmax update runs once a block, blocks in
+    ascending order. An idle serving slot (length 0 AND a table that starts
+    at the garbage block) takes one step, does no arithmetic and gets
+    zeros; a row of any length above 0 is attended over what its table
+    names, the garbage block included.
 
     Returns ``[B, T_q, H, D]`` in the query's dtype.
     """
     return _paged_call(q, (k_pool, v_pool), block_tables, lengths, layer,
-                       softmax_scale)
+                       softmax_scale, work=work)
 
 
 def decode_attention_paged_int8(q, k_pool, v_pool, k_scale, v_scale,
                                 block_tables, lengths, layer=0,
-                                softmax_scale=None):
+                                softmax_scale=None, work=None):
     """Attend a decode (or k-token verify) step against one layer of an
     int8-quantized paged KV pool.
 
@@ -407,7 +499,7 @@ def decode_attention_paged_int8(q, k_pool, v_pool, k_scale, v_scale,
     is the dense oracle it is tested against with a pinned tolerance.
     """
     return _paged_call(q, (k_pool, v_pool, k_scale, v_scale), block_tables,
-                       lengths, layer, softmax_scale)
+                       lengths, layer, softmax_scale, work=work)
 
 
 # ---------------------------------------------------------------------------
@@ -450,7 +542,7 @@ def decode_attention_tp(q, k_cache, v_cache, cache_index,
 
 
 def _paged_tp(q, pools, block_tables, lengths, layer, softmax_scale,
-              mesh, axis):
+              mesh, axis, work=None):
     from jax.sharding import PartitionSpec as P
 
     from deepspeed_tpu.ops.kernel_mesh import kernel_mesh_plan
@@ -460,12 +552,19 @@ def _paged_tp(q, pools, block_tables, lengths, layer, softmax_scale,
     layer = jnp.asarray(layer, jnp.int32)
     plan = kernel_mesh_plan(q.shape[0], q.shape[2], mesh=mesh, axis=axis)
     if plan is None:
-        return _paged_call(q, pools, tables, lens, layer, softmax_scale)
+        return _paged_call(q, pools, tables, lens, layer, softmax_scale,
+                           work=work)
+    # a work list made for the whole batch serves every shard only while
+    # the batch is whole inside; where data axes split it, each shard lists
+    # its own rows
+    work = () if work is None or plan.batch is not None else tuple(work)
 
-    def kernel(qs, t, ln, ly, *ps):
+    def kernel(qs, t, ln, ly, *rest):
+        ps, wk = rest[:len(pools)], rest[len(pools):]
         head0 = (None if plan.heads is None else
                  jax.lax.axis_index(plan.heads) * qs.shape[2])
-        return _paged_call(qs, ps, t, ln, ly, softmax_scale, head0)
+        return _paged_call(qs, ps, t, ln, ly, softmax_scale, head0,
+                           work=wk or None)
 
     # pools are the SHARED per-replica cache: the K/V lane axis split into
     # tp groups of heads/tp contiguous heads, replicated over data; the
@@ -474,28 +573,30 @@ def _paged_tp(q, pools, block_tables, lengths, layer, softmax_scale,
     # batch entry
     qs_spec = P(plan.batch, None, plan.heads, None)
     pool_specs = (P(None, None, None, plan.heads),) * 2 + (P(),) * (
-        len(pools) - 2)
+        len(pools) - 2 + len(work))
     return plan.shard_map(
         kernel, (qs_spec, P(plan.batch), P(plan.batch), P()) + pool_specs,
-        qs_spec, name="paged_kv_attend")(q, tables, lens, layer, *pools)
+        qs_spec, name="paged_kv_attend")(q, tables, lens, layer, *pools,
+                                         *work)
 
 
 def decode_attention_paged_tp(q, k_pool, v_pool, block_tables, lengths,
                               layer=0, softmax_scale=None, mesh=None,
-                              axis=None):
+                              axis=None, work=None):
     """TP-aware :func:`decode_attention_paged`: the stacked pools live
     tp-sharded on their lane axis (per-shard KV pools — each tp shard
     holds heads/tp contiguous heads of every pool row), block
-    tables/lengths follow the batch, the layer index is replicated."""
+    tables/lengths follow the batch, the layer index and the work list
+    are replicated."""
     return _paged_tp(q, (k_pool, v_pool), block_tables, lengths, layer,
-                     softmax_scale, mesh, axis)
+                     softmax_scale, mesh, axis, work)
 
 
 def decode_attention_paged_int8_tp(q, k_pool, v_pool, k_scale, v_scale,
                                    block_tables, lengths, layer=0,
                                    softmax_scale=None, mesh=None,
-                                   axis=None):
+                                   axis=None, work=None):
     """TP-aware :func:`decode_attention_paged_int8`: int8 pools
     lane-sharded over ``axis``, their f32 scale side pools replicated."""
     return _paged_tp(q, (k_pool, v_pool, k_scale, v_scale), block_tables,
-                     lengths, layer, softmax_scale, mesh, axis)
+                     lengths, layer, softmax_scale, mesh, axis, work)

@@ -89,31 +89,35 @@ def test_decode_attention(one_chip):
 
 # the paged kernel reads the STACKED pool [layers, blocks, bs, H*D] at a
 # layer index: 125M's 12 heads of 64, XL's 25 (a row of 1600 lanes is no
-# multiple of 128), and head size 128
-@pytest.mark.parametrize("t_q,heads,dim", [(1, H, D), (5, H, D), (1, 25, 64),
-                                           (5, 25, 64), (1, 8, 128)])
-def test_decode_attention_paged(one_chip, t_q, heads, dim):
+# multiple of 128), head size 128, and the benchmark's serving cell as it is
+# sized (GPT-2 XL, 32 slots x 32 blocks of 32 over a pool of 513)
+@pytest.mark.parametrize("slots,t_q,heads,dim", [
+    (8, 1, H, D), (8, 5, H, D), (8, 1, 25, 64), (8, 5, 25, 64),
+    (8, 1, 8, 128), (32, 1, 25, 64), (32, 5, 25, 64)])
+def test_decode_attention_paged(one_chip, slots, t_q, heads, dim):
     from deepspeed_tpu.ops.decode_attention import decode_attention_paged
 
-    pool = _s(one_chip, (2, 512, 32, heads * dim))
+    pool = _s(one_chip, (2, 513, 32, heads * dim))
     text = _compiled_text(
-        decode_attention_paged, _s(one_chip, (8, t_q, heads, dim)), pool,
-        pool, _s(one_chip, (8, 32), jnp.int32), _s(one_chip, (8,), jnp.int32),
-        _s(one_chip, (), jnp.int32))
+        decode_attention_paged, _s(one_chip, (slots, t_q, heads, dim)), pool,
+        pool, _s(one_chip, (slots, 32), jnp.int32),
+        _s(one_chip, (slots,), jnp.int32), _s(one_chip, (), jnp.int32))
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("heads,dim", [(H, D), (25, 64), (8, 128)])
-def test_decode_attention_paged_int8(one_chip, heads, dim):
+@pytest.mark.parametrize("slots,t_q,heads,dim", [
+    (8, 1, H, D), (8, 1, 25, 64), (8, 1, 8, 128), (32, 1, 25, 64),
+    (32, 5, 25, 64)])
+def test_decode_attention_paged_int8(one_chip, slots, t_q, heads, dim):
     from deepspeed_tpu.ops.decode_attention import (
         decode_attention_paged_int8, scale_lanes)
 
-    pool = _s(one_chip, (2, 512, 32, heads * dim), jnp.int8)
-    scale = _s(one_chip, (2, 512, 32, scale_lanes(heads)), jnp.float32)
+    pool = _s(one_chip, (2, 513, 32, heads * dim), jnp.int8)
+    scale = _s(one_chip, (2, 513, 32, scale_lanes(heads)), jnp.float32)
     text = _compiled_text(
-        decode_attention_paged_int8, _s(one_chip, (8, 1, heads, dim)), pool,
-        pool, scale, scale, _s(one_chip, (8, 32), jnp.int32),
-        _s(one_chip, (8,), jnp.int32), _s(one_chip, (), jnp.int32))
+        decode_attention_paged_int8, _s(one_chip, (slots, t_q, heads, dim)),
+        pool, pool, scale, scale, _s(one_chip, (slots, 32), jnp.int32),
+        _s(one_chip, (slots,), jnp.int32), _s(one_chip, (), jnp.int32))
     assert "tpu_custom_call" in text
 
 

@@ -89,6 +89,9 @@ def test_dense_kernel_at_block_boundaries(idx):
 # paged (block-table) variant
 # ---------------------------------------------------------------------------
 LAYERS = 3  # every pool here is stacked: [LAYERS, nb, bs, H*D]
+# a length of _paged_setup: the serving engine's idle slot (length 0, the
+# table all garbage block)
+IDLE = None
 
 
 def _paged_setup(B, lengths, tq, bs, mb, H=2, D=64, seed=0, layer=1,
@@ -96,8 +99,9 @@ def _paged_setup(B, lengths, tq, bs, mb, H=2, D=64, seed=0, layer=1,
     """Random STACKED pool ``[LAYERS, nb, bs, H*D]`` (every layer holds
     different numbers, so reading the wrong one cannot pass) + per-row
     permuted block tables holding each row's prefix at its logical
-    positions (the serving layout). Returns the positional arguments of
-    ``decode_attention_paged``, ``layer`` last."""
+    positions (the serving layout); a row of length ``IDLE`` owns no block.
+    Returns the positional arguments of ``decode_attention_paged``,
+    ``layer`` last."""
     from deepspeed_tpu.ops.decode_attention import GARBAGE_BLOCK
 
     rng = np.random.default_rng(seed)
@@ -107,12 +111,28 @@ def _paged_setup(B, lengths, tq, bs, mb, H=2, D=64, seed=0, layer=1,
     tables = np.full((B, mb), GARBAGE_BLOCK, np.int32)
     free = list(rng.permutation(np.arange(1, nb)))
     for b, ln in enumerate(lengths):
+        if ln is IDLE:
+            continue
         need = max(1, -(-(ln + tq) // bs))
         tables[b, :need] = [free.pop() for _ in range(need)]
     q4 = rng.normal(size=(B, tq, H, D)).astype(np.float32)
     return (jnp.asarray(q4, dtype), jnp.asarray(k_pool, dtype),
             jnp.asarray(v_pool, dtype), jnp.asarray(tables),
-            jnp.asarray(lengths, jnp.int32), layer)
+            jnp.asarray([0 if ln is IDLE else ln for ln in lengths],
+                        jnp.int32), layer)
+
+
+def _live(lengths):
+    """Rows of a ``_paged_setup`` batch that hold a sequence: an idle
+    slot's output is whatever the kernel leaves there, and is discarded."""
+    return [b for b, ln in enumerate(lengths) if ln is not IDLE]
+
+
+def _mixed(tq, bs=32, mb=4):
+    """One batch of every length of live prefix: an idle slot (no block),
+    exactly one block, ``len + tq`` exactly on a block boundary, and all
+    ``mb`` blocks of the table."""
+    return [IDLE, bs - tq - 3, 2 * bs - tq, mb * bs - tq]
 
 
 def _dense_ref(q4, kd, vd, tables, lengths, bs):
@@ -152,16 +172,19 @@ def _int8_dense_ref(q4, kq, vq, ks, vs, tables, lengths, layer):
     ([0, 12], 4), ([60, 30], 4),                    # multi-query steps
     ([0, 31, 64], 5),                               # verify shapes (k+1
     ([3, 17, 40], 8),                               # rows, mixed depths)
+    (_mixed(1), 1), (_mixed(4), 4),                 # idle .. the whole table
+    ([IDLE, 70, IDLE, IDLE, 5, IDLE], 1),           # live among idle slots
 ])
 def test_paged_matches_dense_gather(lengths, tq):
     from deepspeed_tpu.ops.decode_attention import decode_attention_paged
 
     args = _paged_setup(len(lengths), lengths, tq, bs=32, mb=4,
-                        seed=sum(lengths) + tq)
+                        seed=sum(filter(None, lengths)) + tq)
     with tpu_interpret_mode():
         out = decode_attention_paged(*args)
     ref = _paged_dense_ref(*args)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+    live = _live(lengths)
+    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(ref)[live],
                                rtol=2e-5, atol=2e-5)
 
 
@@ -353,7 +376,8 @@ def _int8_pools(k_pool, v_pool, H):
 
 @pytest.mark.parametrize("lengths,tq", [([0, 5], 1), ([7, 63], 1),
                                         ([60, 30], 4),
-                                        ([0, 23, 57], 5)])  # verify shapes
+                                        ([0, 23, 57], 5),   # verify shapes
+                                        (_mixed(1), 1), (_mixed(4), 4)])
 def test_paged_int8_kernel_matches_dequant_oracle(lengths, tq):
     """The int8 kernel dequantizes inside the block DMA; the dense
     gather-dequantize oracle must agree to fp32 round-off — both read
@@ -364,13 +388,15 @@ def test_paged_int8_kernel_matches_dequant_oracle(lengths, tq):
         decode_attention_paged_int8, gather_paged_cache_int8)
 
     q4, k_pool, v_pool, tables, lens, layer = _paged_setup(
-        len(lengths), lengths, tq, bs=32, mb=4, seed=sum(lengths) + tq)
+        len(lengths), lengths, tq, bs=32, mb=4,
+        seed=sum(filter(None, lengths)) + tq)
     kq, vq, ks, vs = _int8_pools(k_pool, v_pool, q4.shape[2])
     with tpu_interpret_mode():
         out = decode_attention_paged_int8(q4, kq, vq, ks, vs, tables, lens,
                                           layer)
     ref = _int8_dense_ref(q4, kq, vq, ks, vs, tables, lens, layer)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+    live = _live(lengths)
+    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(ref)[live],
                                rtol=2e-5, atol=2e-5)
 
 
@@ -388,40 +414,256 @@ def test_paged_int8_error_vs_f32_pinned():
     assert err < 0.05, f"int8 KV attention error {err} past the pinned budget"
 
 
-@pytest.mark.parametrize("layer", [0, LAYERS - 1])
+@pytest.mark.parametrize("layer", [0, LAYERS - 1, "traced"])
 @pytest.mark.parametrize("tq", [1, 4])
 @pytest.mark.parametrize("H,D", [(2, 64), (2, 128), (3, 64), (5, 32)])
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 def test_stacked_lane_dense_pool_forms(layer, tq, H, D, kv):
     """The kernel's one pool form, ``[L, nb, bs, H*D]`` addressed by
-    ``(layer, block)``: first and last layer, decode and verify rows, head
-    sizes 64 and 128, rows that are no multiple of 128 lanes (3 x 64, 5 x
-    32), bf16 and int8 pools — each against the dense oracle over the SAME
+    ``(layer, block)``: first and last layer as Python ints (an unrolled
+    stack) and a traced layer index (a scanned one), decode and verify
+    rows, head sizes 64 and 128, rows that are no multiple of 128 lanes (3
+    x 64, 5 x 32), bf16 and int8 pools, over one batch of every length of
+    live prefix (``_mixed``) — each against the dense oracle over the SAME
     stored numbers, so the tolerance is the kernel's own arithmetic (bf16
     probabilities into the value matmul), not the storage format's."""
     from deepspeed_tpu.ops.decode_attention import (
         decode_attention_paged, decode_attention_paged_int8)
 
     dtype = jnp.bfloat16 if kv == "bf16" else np.float32
+    lengths = _mixed(tq, bs=16)
     q4, k_pool, v_pool, tables, lens, _ = _paged_setup(
-        2, [9, 40], tq, bs=16, mb=4, H=H, D=D, seed=H * D + tq,
+        len(lengths), lengths, tq, bs=16, mb=4, H=H, D=D, seed=H * D + tq,
         dtype=dtype)
     if kv == "bf16":
-        with tpu_interpret_mode():
-            out = decode_attention_paged(q4, k_pool, v_pool, tables, lens,
-                                         layer)
-        ref = _paged_dense_ref(q4, k_pool, v_pool, tables, lens, layer)
-        tol = 2e-2
+        kernel, pools, tol = decode_attention_paged, (k_pool, v_pool), 2e-2
+        oracle = _paged_dense_ref
     else:
-        kq, vq, ks, vs = _int8_pools(k_pool, v_pool, H)
-        with tpu_interpret_mode():
-            out = decode_attention_paged_int8(q4, kq, vq, ks, vs, tables,
-                                              lens, layer)
-        ref = _int8_dense_ref(q4, kq, vq, ks, vs, tables, lens, layer)
-        tol = 2e-5
+        kernel, pools, tol = (decode_attention_paged_int8,
+                              _int8_pools(k_pool, v_pool, H), 2e-5)
+        oracle = _int8_dense_ref
+    if layer == "traced":
+        layer = 1
+        kernel = jax.jit(kernel)
+        at = jnp.asarray(layer, jnp.int32)
+    else:
+        at = layer
+    with tpu_interpret_mode():
+        out = jax.block_until_ready(kernel(q4, *pools, tables, lens, at))
+    ref = oracle(q4, *pools, tables, lens, layer)
     assert out.shape == q4.shape and out.dtype == q4.dtype
-    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref),
-                               rtol=tol, atol=tol)
+    live = _live(lengths)
+    np.testing.assert_allclose(np.asarray(out, np.float32)[live],
+                               np.asarray(ref)[live], rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("tq", [1, 4])
+@pytest.mark.parametrize("kv", ["f32", "int8"])
+def test_paged_kernel_fetches_nothing_past_a_rows_live_prefix(kv, tq):
+    """The bound: the kernel walks ``min(cdiv(length + tq, bs), mb)``
+    blocks of a row and no more. Every pool block that no row's live
+    prefix names, the garbage block among them, is filled with NaN (the
+    int8 pools' scales, and their rows with 127): one fetch past a live
+    prefix, whatever mask follows it, makes an output NaN (0 x NaN), and
+    the outputs have to stay finite and equal to the dense oracle's over
+    the clean pool. No slot is idle here: an idle slot's one block IS the
+    garbage block."""
+    from deepspeed_tpu.ops.decode_attention import (
+        GARBAGE_BLOCK, decode_attention_paged, decode_attention_paged_int8)
+
+    bs, mb = 32, 4
+    lengths = _mixed(tq, bs, mb)[1:] + [0]
+    q4, k_pool, v_pool, tables, lens, layer = _paged_setup(
+        len(lengths), lengths, tq, bs=bs, mb=mb, seed=11 + tq)
+    named = {int(tables[b, j]) for b, ln in enumerate(lengths)
+             for j in range(-(-(ln + tq) // bs))}
+    assert GARBAGE_BLOCK not in named
+    dead = [blk for blk in range(k_pool.shape[1]) if blk not in named]
+    assert len(dead) >= len(lengths)  # each short row leaves some behind
+
+    def poison(pool, value):
+        return jnp.asarray(pool).at[:, jnp.asarray(dead)].set(value)
+
+    if kv == "f32":
+        pools, oracle = (k_pool, v_pool), _paged_dense_ref
+        bad = tuple(poison(p, np.nan) for p in pools)
+        kernel = decode_attention_paged
+    else:
+        pools, oracle = _int8_pools(k_pool, v_pool, q4.shape[2]), \
+            _int8_dense_ref
+        bad = tuple(poison(p, 127 if p.dtype == jnp.int8 else np.nan)
+                    for p in pools)
+        kernel = decode_attention_paged_int8
+    with tpu_interpret_mode():
+        out = np.asarray(kernel(q4, *bad, tables, lens, layer))
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(
+        out, np.asarray(oracle(q4, *pools, tables, lens, layer)),
+        rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("tq", [1, 4])
+@pytest.mark.parametrize("kv", ["f32", "int8"])
+def test_paged_rows_of_many_blocks(kv, tq):
+    """Long rows in one batch with short ones, so the softmax state is
+    carried over many steps and begun anew at every row: rows that end on
+    a block boundary, one key into the next block, in the middle of the
+    ninth and at the table's end, beside an idle slot and a one-block
+    row."""
+    from deepspeed_tpu.ops.decode_attention import (
+        decode_attention_paged, decode_attention_paged_int8)
+
+    bs, mb = 32, 10
+    lengths = [4 * bs - tq, IDLE, 4 * bs - tq + 1, 5, 9 * bs - tq - 5,
+               mb * bs - tq]
+    q4, k_pool, v_pool, tables, lens, layer = _paged_setup(
+        len(lengths), lengths, tq, bs=bs, mb=mb, seed=5 + tq)
+    if kv == "f32":
+        kernel, pools, oracle = decode_attention_paged, (k_pool, v_pool), \
+            _paged_dense_ref
+    else:
+        kernel, pools, oracle = decode_attention_paged_int8, _int8_pools(
+            k_pool, v_pool, q4.shape[2]), _int8_dense_ref
+    with tpu_interpret_mode():
+        out = np.asarray(kernel(q4, *pools, tables, lens, layer))
+    live = _live(lengths)
+    np.testing.assert_allclose(
+        out[live], np.asarray(oracle(q4, *pools, tables, lens, layer))[live],
+        rtol=2e-5, atol=2e-5)
+
+
+def test_paged_grid_is_the_live_blocks_of_all_rows(monkeypatch):
+    """The kernel's iteration space follows ``lengths``, not
+    ``block_tables.shape``: the ``pallas_call`` has ONE grid axis, its
+    length is traced, and it comes to one step a live block of every row
+    (one for an idle slot), where the fixed grid had ``B x MB``."""
+    from deepspeed_tpu.ops import decode_attention as da
+
+    bs, mb, tq = 32, 4, 1
+    lengths = _mixed(tq, bs, mb) + [IDLE, 40]
+    args = _paged_setup(len(lengths), lengths, tq, bs=bs, mb=mb, seed=2)
+    jaxpr = jax.make_jaxpr(da.decode_attention_paged)(*args)
+    (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name ==
+               "pallas_call"]
+    mapping = call.params["grid_mapping"]
+    assert len(mapping.grid) == 1 and mapping.num_dynamic_grid_bounds == 1
+    assert not isinstance(mapping.grid[0], int)
+
+    seen = []
+    real = da.pl.pallas_call
+
+    def spy(kernel, *a, grid_spec, **kw):
+        seen.append(grid_spec.grid)
+        return real(kernel, *a, grid_spec=grid_spec, **kw)
+
+    monkeypatch.setattr(da.pl, "pallas_call", spy)
+    with tpu_interpret_mode():
+        jax.block_until_ready(da.decode_attention_paged(*args))
+    (grid,) = seen
+    # idle 1; one block 1; to a boundary 2; the whole table 4; idle 1; 41
+    # keys 2
+    assert [int(g) for g in grid] == [1 + 1 + 2 + mb + 1 + 2]
+    assert int(grid[0]) < len(lengths) * mb
+
+
+def test_paged_work_list_made_once_serves_every_call():
+    """``paged_work_list`` is the grid: each row's first step, and each
+    step's row. A call handed the list made outside it (as the model makes
+    it, once before its layer loop) gives what a call that makes its own
+    gives; one of another batch's shape is refused."""
+    from deepspeed_tpu.ops.decode_attention import (
+        decode_attention_paged, paged_work_list)
+
+    bs, mb, tq = 32, 4, 1
+    lengths = _mixed(tq, bs, mb) + [IDLE, 40]
+    args = _paged_setup(len(lengths), lengths, tq, bs=bs, mb=mb, seed=2)
+    row_of, first = paged_work_list(args[4], tq, bs, mb)
+    # idle 1; one block 1; to a boundary 2; the whole table 4; idle 1; 41
+    # keys 2
+    assert [int(f) for f in first] == [0, 1, 2, 4, 8, 9, 11]
+    assert [int(r) for r in row_of[:12]] == [0, 1, 2, 2, 3, 3, 3, 3, 4, 5,
+                                             5, 5]
+    assert row_of.shape == (len(lengths) * mb + 1,)
+    assert int(row_of[-1]) == len(lengths) - 1
+    with tpu_interpret_mode():
+        own = jax.block_until_ready(decode_attention_paged(*args))
+        given = jax.block_until_ready(decode_attention_paged(
+            *args, work=(row_of, first)))
+    live = _live(lengths)
+    np.testing.assert_array_equal(np.asarray(own)[live],
+                                  np.asarray(given)[live])
+    with pytest.raises(ValueError, match="work list"):
+        decode_attention_paged(*args, work=(row_of[:-1], first))
+
+
+@pytest.mark.parametrize("scan_layers", [True, False],
+                         ids=["scanned", "unrolled"])
+def test_model_lists_the_paged_kernels_work_once_a_step(monkeypatch,
+                                                         scan_layers):
+    """The work list depends on the step's lengths alone, so the model
+    makes it before its layer stack and every layer's kernel call takes
+    that one: one ``paged_work_list`` a traced decode step, whatever the
+    number of layers; none in a prefill step, which runs no paged
+    kernel."""
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
+    from deepspeed_tpu.ops import attention as attn_mod
+    from deepspeed_tpu.ops import decode_attention as da
+
+    cfg = GPT2Config.tiny(n_positions=64, dtype=jnp.float32,
+                          scan_layers=scan_layers)
+    assert cfg.n_layer > 1
+    model = GPT2LMHeadModel(cfg.for_paged_decode(9, 8))
+    tables = jnp.asarray([[3, 1, 5, 0], [2, 7, 4, 0]], jnp.int32)
+
+    def paging(lengths, n, prefill):
+        return {"block_tables": tables,
+                "lengths": jnp.asarray(lengths, jnp.int32),
+                "num_valid": jnp.full((2,), n, jnp.int32),
+                "prefill": prefill}
+
+    prompt = jnp.zeros((2, 8), jnp.int32)
+    variables = model.init(jax.random.PRNGKey(0), prompt,
+                           paging=paging([0, 0], 8, True))
+    made = []
+    real = da.paged_work_list
+
+    def spy(*a):
+        made.append(a[1:])
+        return real(*a)
+
+    monkeypatch.setattr(da, "paged_work_list", spy)
+    monkeypatch.setattr(attn_mod, "_FORCE_DECODE_KERNEL", True)
+
+    def step(ids, pg):
+        return model.apply(variables, ids, mutable=["cache"], paging=pg)
+
+    jaxpr = jax.make_jaxpr(lambda ids, ln: step(ids, paging(ln, 1, False)))(
+        prompt[:, :1], jnp.asarray([6, 8], jnp.int32))
+    assert made == [(1, 8, 4)]
+    assert "pallas_call" in str(jaxpr)
+    jax.make_jaxpr(lambda ids: step(ids, paging([0, 0], 8, True)))(prompt)
+    assert len(made) == 1
+
+
+def test_paged_live_row_on_the_garbage_block_is_attended():
+    """Only a row of length 0 whose table starts at the garbage block is
+    idle. A row that holds tokens is attended over whatever its table
+    names, the garbage block too, as the fixed grid did; a fresh row
+    (length 0) on a block of its own is attended over its ``tq`` keys."""
+    from deepspeed_tpu.ops.decode_attention import (
+        GARBAGE_BLOCK, decode_attention_paged)
+
+    lengths, tq = [5, 0, IDLE], 4
+    q4, k_pool, v_pool, tables, lens, layer = _paged_setup(
+        3, lengths, tq, bs=32, mb=4, seed=9)
+    tables = tables.at[0, 0].set(GARBAGE_BLOCK)
+    with tpu_interpret_mode():
+        out = np.asarray(decode_attention_paged(q4, k_pool, v_pool, tables,
+                                                lens, layer))
+    ref = np.asarray(_paged_dense_ref(q4, k_pool, v_pool, tables, lens,
+                                      layer))
+    np.testing.assert_allclose(out[:2], ref[:2], rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(out[2], 0.0)
 
 
 def test_paged_pool_shape_is_checked():

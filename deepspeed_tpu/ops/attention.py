@@ -135,12 +135,14 @@ def attention(q, k, v, mask=None, causal=True, softmax_scale=None,
 
         # decided from the shapes, before the call: an error raised by the
         # kernel itself is a fault and propagates
-        reason = flash_ineligible(q.shape, k.shape, layout="bhtd")
+        reason = flash_ineligible(q.shape, k.shape, layout="bhtd",
+                                  dtype=q.dtype)
         if reason is None:
             record_dispatch("flash")
+            _note_flash_plan(q.shape, k.shape, causal, q.dtype)
             return flash_attention_sharded(q, k, v, causal=causal,
                                            softmax_scale=softmax_scale)
-        # e.g. seq not divisible by the kernel block size — take the XLA
+        # e.g. no block of whole tiles divides the sequence — take the XLA
         # path, but SAY so: losing the kernel is a perf cliff the user
         # should see (once per offending shape) and a counter can assert on
         _warn_fallback(q.shape, k.shape, reason)
@@ -150,6 +152,26 @@ def attention(q, k, v, mask=None, causal=True, softmax_scale=None,
     return attention_reference(q, k, v, mask=mask, causal=causal,
                                softmax_scale=softmax_scale,
                                dropout_rate=dropout_rate, dropout_rng=dropout_rng)
+
+
+_noted_plans = set()
+
+
+def _note_flash_plan(q_shape, k_shape, causal, dtype):
+    """Log the kernel's schedule (``flash_plan``: what a grid step fetches,
+    the compute chunk, how many chunks and tiles run unmasked / masked /
+    are skipped) once per shape, while tracing: the static counter of the
+    tile schedule, as ``GatherPlan.describe`` is ZeRO-3's."""
+    key = (tuple(q_shape), tuple(k_shape), bool(causal), str(dtype))
+    if key in _noted_plans:
+        return
+    _noted_plans.add(key)
+    from deepspeed_tpu.ops.flash_attention import flash_plan
+    from deepspeed_tpu.utils.logging import logger
+
+    plan = flash_plan(q_shape, k_shape, causal, dtype)
+    logger.info(f"flash_attention q{tuple(q_shape)} k{tuple(k_shape)} "
+                f"causal={bool(causal)}: {plan.describe()}")
 
 
 _warned_shapes = set()
@@ -165,4 +187,6 @@ def _warn_fallback(q_shape, k_shape, reason: str):
     logger.warning(
         f"flash_attention unavailable for q{tuple(q_shape)} k{tuple(k_shape)} "
         f"({reason}); taking the dense XLA attention path — pad the sequence "
-        f"to a multiple of the kernel block (512) to regain the fused kernel")
+        f"to a multiple of the kernel's 128-row tile (or keep it at 512 or "
+        f"under, where one compute chunk takes it whole) to regain the fused "
+        f"kernel")

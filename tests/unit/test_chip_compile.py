@@ -250,6 +250,31 @@ def test_flash_kernels_are_named_by_what_they_are(one_chip, layout):
     assert len(names) == 3 and _stems(names) == FLASH_NAMES, names
 
 
+# the folded layout at the benchmark's shapes: the training cells' rows
+# (medium 8 x 16 heads, XL 4 x 25 a chip: the panel is the sequence, two
+# rows a grid step) forward + backward, and the serving cell's prefill, one
+# request of 25 heads a call at each prompt bucket, forward only
+@pytest.mark.parametrize("shape,grad", [
+    ((8, 16, 1024, 64), True), ((4, 25, 1024, 64), True),
+    ((1, 25, 16, 64), False), ((1, 25, 128, 64), False),
+    ((1, 25, 512, 64), False), ((1, 25, 1024, 64), False),
+    ((2, 8, 768, 128), True), ((1, 4, 2048, 64), True)])
+def test_flash_cells_shapes(one_chip, shape, grad):
+    from deepspeed_tpu.ops.flash_attention import flash_attention
+
+    q = _s(one_chip, shape)
+
+    def run(q, k, v):
+        if not grad:
+            return flash_attention(q, k, v)
+        return jax.grad(lambda *a: flash_attention(*a).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    names = _kernel_names(_compiled_text(run, q, q, q))
+    assert _stems(names) == (FLASH_NAMES if grad else ["flash_fwd"]), names
+    assert len(names) == (3 if grad else 1)
+
+
 def test_flash_kernels_keep_their_names_under_shard_map(topo):
     """On four chips they were ``shard_map.206-208``."""
     from deepspeed_tpu.ops.flash_attention import flash_attention_bthd_tp

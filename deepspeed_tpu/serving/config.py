@@ -642,6 +642,13 @@ class ServingConfig(DeepSpeedConfigModel):
     # side pool indexed by the same block table) for 2-4x more concurrent
     # sequences per HBM byte
     kv_cache_dtype: str = ""
+    # a sparse model's routing, handed back: keep, for the last N finished
+    # requests, the experts every processed token chose in every sparse
+    # layer (``ServingEngine.routed_experts``), what a trainer replays a
+    # rollout's routing from and what a float32 reference needs to tell
+    # the program's arithmetic from a routed set that flipped at a near
+    # tie. 0 = the programs return none
+    routed_experts_kept: int = 0
     # satellite: pad legacy generate() prompts up to the bucket set before
     # keying its compile cache (identical tokens via the left-padded mask
     # path; one compiled program per bucket instead of per prompt length)

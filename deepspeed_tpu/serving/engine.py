@@ -145,13 +145,34 @@ class ServingEngine:
             1 + self.config.decode_slots * self.blocks_per_seq)
         self.buckets = resolve_buckets(self.config.prompt_buckets,
                                        self.max_len, floor=bs)
+        # a model with sliding-window layers keeps their rows in a ring of
+        # this many blocks a decode slot (models/mimo_v2.py): the table a
+        # slot hands its programs is the sequence's blocks, then its ring
+        ring_for = getattr(mcfg, "paged_ring_blocks_for", None)
+        self.ring_blocks = int(ring_for(bs)) if ring_for else 0
+        # keywords omitted on purpose: a model family predating a knob
+        # keeps serving exactly as before
+        knobs = {}
+        if self.ring_blocks:
+            self._refuse_beside_rings()
+            knobs["ring_slots"] = self.config.decode_slots
         if self.config.kv_cache_dtype:
-            dcfg = mcfg.for_paged_decode(self.num_blocks, bs,
-                                         kv_dtype=self.config.kv_cache_dtype)
-        else:
-            # keyword omitted on purpose: a model family predating the
-            # kv_dtype knob keeps serving exactly as before
-            dcfg = mcfg.for_paged_decode(self.num_blocks, bs)
+            knobs["kv_dtype"] = self.config.kv_cache_dtype
+        # request id -> int32 [tokens processed, sparse layers x k], the
+        # oldest dropped past ``routed_experts_kept``
+        self._routed_kept: Dict[str, np.ndarray] = {}
+        if self.config.routed_experts_kept:
+            if not getattr(type(self.engine.module), "serve_routed", False):
+                from deepspeed_tpu.runtime.config import DeepSpeedConfigError
+
+                raise DeepSpeedConfigError(
+                    "serving.routed_experts_kept: "
+                    f"{type(self.engine.module).__name__}'s serving "
+                    "programs return no routed experts")
+            knobs["return_routed"] = True
+        dcfg = mcfg.for_paged_decode(self.num_blocks, bs, **knobs)
+        self._routed_width = (dcfg.routed_width
+                              if "return_routed" in knobs else 0)
         self._dmodule = type(self.engine.module)(dcfg)
         self.block_mgr = BlockManager(self.num_blocks, bs,
                                       self.blocks_per_seq)
@@ -171,6 +192,20 @@ class ServingEngine:
         # since the last publish.
         self._ledger = {**dict.fromkeys(_PHASES, 0.0), "prefill_calls": 0,
                         "busy_slot_steps": 0}
+        # what the model itself counts in a call (a sparse model: experts
+        # touched, pairs routed here and in all), handed back behind the
+        # sampled tokens and summed here by the kind of program; and the
+        # bytes of keys and values live at each decode step, by kind of
+        # layer, where the model has more than one
+        self._counter_names = tuple(getattr(type(self.engine.module),
+                                            "serve_counters", ()))
+        for phase in ("prefill", "decode"):
+            for name in self._counter_names:
+                self._ledger[f"{phase}.{name}"] = 0
+        kv_bytes = getattr(dcfg, "kv_bytes_per_token", None)
+        self._kv_bytes = kv_bytes() if kv_bytes else None
+        for kind in self._kv_bytes or ():
+            self._ledger[f"kv_live_bytes.{kind}"] = 0
         self._ledger_base = dict(self._ledger)
         self._ledger_published = dict(self._ledger)
         self._busy = 0  # active slots of the latest decode step
@@ -189,7 +224,8 @@ class ServingEngine:
 
         self.cache = self._init_cache()
         self._tables = np.full(
-            (self.config.decode_slots, self.blocks_per_seq), 0, np.int32)
+            (self.config.decode_slots,
+             self.blocks_per_seq + self.ring_blocks), 0, np.int32)
         self._last_tokens = np.zeros((self.config.decode_slots,), np.int32)
         self._lengths = np.zeros((self.config.decode_slots,), np.int32)
         self._prefill_fns: Dict[int, object] = {}
@@ -278,7 +314,8 @@ class ServingEngine:
         jax, jnp = self._jax, self._jnp
         from deepspeed_tpu.module_inject.policies import decode_cache_specs
 
-        pg = {"block_tables": jnp.zeros((1, self.blocks_per_seq), jnp.int32),
+        pg = {"block_tables": jnp.zeros(
+                  (1, self.blocks_per_seq + self.ring_blocks), jnp.int32),
               "lengths": jnp.zeros((1,), jnp.int32),
               "num_valid": jnp.zeros((1,), jnp.int32), "prefill": True}
         shapes = jax.eval_shape(
@@ -290,6 +327,73 @@ class ServingEngine:
         return jax.tree_util.tree_map(
             lambda s, sh: jax.device_put(jnp.zeros(s.shape, s.dtype), sh),
             shapes["cache"], shardings)
+
+    def _refuse_beside_rings(self):
+        """A model whose window layers live in per-slot rings has two
+        kinds of KV row, and these mechanisms know one: each refuses the
+        model here, by name, until it is taught the second."""
+        from deepspeed_tpu.runtime.config import DeepSpeedConfigError
+
+        model = type(self.engine.module).__name__
+        asked = {
+            "serving.prefix_cache": self.config.prefix_cache,
+            "serving.speculative": self.config.speculative is not None
+            and self.config.speculative.enabled,
+            "serving.kv_cache_dtype": bool(self.config.kv_cache_dtype),
+            "tensor_parallel.tp_size > 1": self.engine.mp_world_size > 1,
+        }
+        for mechanism, on in asked.items():
+            if on:
+                raise DeepSpeedConfigError(
+                    f"{mechanism} cannot serve {model}: its sliding-window "
+                    "layers keep their keys and values in a ring a decode "
+                    "slot, beside the block tables of its global layers, "
+                    f"and {mechanism} handles one kind of KV row")
+
+    def _slot_table(self, slot: int, table: np.ndarray) -> np.ndarray:
+        """The table a slot's programs get: the sequence's blocks and,
+        for a model with window layers, the slot's own ring (ring ``s``
+        is blocks ``1 + s * ring .. `` of the window pool; block 0 is its
+        garbage block)."""
+        if not self.ring_blocks:
+            return table
+        ring = 1 + slot * self.ring_blocks + np.arange(
+            self.ring_blocks, dtype=np.int32)
+        return np.concatenate([table.astype(np.int32), ring])
+
+    def _with_counters(self, tok, out):
+        """The sampled tokens and, behind them in the same array, what the
+        model counted in this call (``(logits, {"counters": int32[n]})``):
+        the host's one fetch a step brings both."""
+        jnp = self._jnp
+        aux = out[1] if isinstance(out, tuple) and len(out) > 1 else None
+        if isinstance(aux, dict) and "counters" in aux:
+            # (and behind those, where the model was asked for them, the
+            # experts each row's tokens chose)
+            return jnp.concatenate(
+                [tok.astype(jnp.int32), aux["counters"].astype(jnp.int32)]
+                + ([aux["routed"].astype(jnp.int32).reshape(-1)]
+                   if "routed" in aux else []))
+        return tok
+
+    def _count(self, phase: str, counted):
+        for name, n in zip(self._counter_names, counted):
+            self._ledger[f"{phase}.{name}"] += int(n)
+
+    def _routed(self, fetched, rows: int) -> Optional[np.ndarray]:
+        """What lies behind the tokens and the counters of a fetched
+        array: ``[rows, tokens a row, layers x k]``, or None."""
+        tail = fetched[rows + len(self._counter_names):]
+        return tail.reshape(rows, -1, self._routed_width) if tail.size \
+            else None
+
+    def routed_experts(self, request_id: str) -> Optional[np.ndarray]:
+        """``int32 [tokens, sparse layers x k]``: the experts each token
+        the programs processed for a finished request chose (the prompt,
+        then every served token but the last, which was never fed back),
+        layer by layer; None for a request not among the last
+        ``serving.routed_experts_kept`` to finish."""
+        return self._routed_kept.get(request_id)
 
     def _donate(self, argnum: int = 1):
         # the old pool is dead after every call — donate it so steady-state
@@ -335,7 +439,7 @@ class ServingEngine:
                 # prompt length — num_valid itself
                 tok = keyed_sample(last, seeds, num_valid, flags, temps,
                                    top_ks, top_ps)
-                return tok, vars_["cache"]
+                return self._with_counters(tok, out), vars_["cache"]
 
             return self._jit(kfn, f"serving_prefill_T{T}",
                              f"serving.prefill[T={T}]")
@@ -352,7 +456,8 @@ class ServingEngine:
             # (right padding: index num_valid-1)
             last = jnp.take_along_axis(
                 logits, (num_valid - 1)[:, None, None], axis=1)[:, 0]
-            return self._sample(last, rng), vars_["cache"]
+            return (self._with_counters(self._sample(last, rng), out),
+                    vars_["cache"])
 
         return self._jit(fn, f"serving_prefill_T{T}",
                          f"serving.prefill[T={T}]")
@@ -379,7 +484,7 @@ class ServingEngine:
                 # last token sits at position lengths)
                 tok = keyed_sample(logits, seeds, lengths + 1, flags,
                                    temps, top_ks, top_ps)
-                return tok, vars_["cache"]
+                return self._with_counters(tok, out), vars_["cache"]
 
             return self._jit(
                 kfn, "serving_decode",
@@ -394,7 +499,8 @@ class ServingEngine:
                                        tokens, mutable=["cache"],
                                        paging=paging)
             logits = logits_of(out)[:, -1]
-            return self._sample(logits, rng), vars_["cache"]
+            return (self._with_counters(self._sample(logits, rng), out),
+                    vars_["cache"])
 
         return self._jit(
             fn, "serving_decode",
@@ -430,7 +536,7 @@ class ServingEngine:
                 # chunked and unchunked admission sample the same token
                 tok = keyed_sample(last, seeds, lengths + num_valid,
                                    flags, temps, top_ks, top_ps)
-                return tok, vars_["cache"]
+                return self._with_counters(tok, out), vars_["cache"]
 
             return self._jit(kfn, f"serving_chunk_T{T}",
                              f"serving.chunk[T={T}]")
@@ -444,7 +550,8 @@ class ServingEngine:
             logits = logits_of(out)
             last = jnp.take_along_axis(
                 logits, (num_valid - 1)[:, None, None], axis=1)[:, 0]
-            return self._sample(last, rng), vars_["cache"]
+            return (self._with_counters(self._sample(last, rng), out),
+                    vars_["cache"])
 
         return self._jit(fn, f"serving_chunk_T{T}",
                          f"serving.chunk[T={T}]")
@@ -587,7 +694,7 @@ class ServingEngine:
                 for req in shed:
                     self._record(req, shed=True, began=True)
             for slot, req, table in admitted:
-                self._begin(slot, req, table, done)
+                self._begin(slot, req, self._slot_table(slot, table), done)
             self._prefill_chunks(done)
             # one decode step for the whole slot batch (mid-prefill slots
             # are idle decode rows: garbage table, outputs ignored); with
@@ -648,10 +755,18 @@ class ServingEngine:
                     jnp.asarray(table[None]),
                     jnp.asarray([req.prompt_len], jnp.int32), *tail)
             with self._bracket("prefill.sync"):
-                tok = int(np.asarray(tok)[0])
+                tok = np.asarray(tok)
+        self._count("prefill", tok[1:])
+        self._keep_routed(req, self._routed(tok, 1), req.prompt_len)
+        tok = int(tok[0])
         self._prefill_done(req, ph)
         req.prefill_chunks = 1
         self._slot_live(slot, req, table, tok, done)
+
+    @staticmethod
+    def _keep_routed(req: Request, routed, tokens: int):
+        if routed is not None:
+            req.routed.append(routed[0, :tokens])
 
     def _prefill_done(self, req: Request, ph):
         """One closed prefill bracket: the request's own share of the
@@ -716,9 +831,11 @@ class ServingEngine:
                     jnp.asarray(table[None]), jnp.asarray([pos], jnp.int32),
                     jnp.asarray([step_len], jnp.int32), *tail)
             with self._bracket("prefill.sync"):
-                tok = int(np.asarray(tok)[0])
+                tok = np.asarray(tok)
+        self._count("prefill", tok[1:])
+        self._keep_routed(req, self._routed(tok, 1), step_len)
         self._prefill_done(req, ph)
-        return tok
+        return int(tok[0])
 
     def _slot_live(self, slot: int, req: Request, table: np.ndarray,
                    tok: int, done: List[Request]):
@@ -804,9 +921,13 @@ class ServingEngine:
                 # drive finish logic
                 toks = np.asarray(toks)  # graft-lint: disable=GL04
         now = ph.t1
+        self._count("decode", toks[len(self._lengths):])
+        routed = self._routed(toks, len(self._lengths))
         self._step_boundary(len(active))
         with self._bracket("emit", span="emit", ledger="emit"):
             for slot, req in active:
+                if routed is not None:
+                    req.routed.append(routed[slot])
                 tok = int(toks[slot])
                 req.length += 1
                 self._lengths[slot] = req.length
@@ -828,6 +949,15 @@ class ServingEngine:
         self._step_count += 1
         self._busy = active
         self._ledger["busy_slot_steps"] += active
+        if self._kv_bytes:
+            # what the step just run had to read: a global layer every
+            # token of a sequence, a window layer what its ring holds
+            live = self._lengths[self._lengths > 0].astype(np.int64)
+            held = self.ring_blocks * self.config.block_size
+            self._ledger["kv_live_bytes.global"] += int(
+                live.sum()) * self._kv_bytes["global"]
+            self._ledger["kv_live_bytes.window"] += int(
+                np.minimum(live, held).sum()) * self._kv_bytes["window"]
         self.telemetry.on_step_boundary(self._step_count, samples=active)
         # per-step load gauges on the event stream: the router's health
         # signals come from here, not from private scheduler state
@@ -995,6 +1125,12 @@ class ServingEngine:
         self._record(req, shed=False, began=True)
         done.append(req)
         self.finished.append(req)
+        if req.routed:
+            kept = self._routed_kept
+            kept[req.request_id] = np.concatenate(req.routed)
+            req.routed = []
+            while len(kept) > self.config.routed_experts_kept:
+                del kept[next(iter(kept))]
 
     def _record(self, req: Request, shed: bool, began: bool):
         rec = req.record()
@@ -1127,6 +1263,7 @@ class ServingEngine:
         when the request is not migratable (unknown, queued, or still
         mid-prefill — those replay/resubmit cheaply)."""
         raise_if("serving.migration.export", detail=request_id)
+        self._no_migration_beside_rings("export_sequence")
         req = next((r for _, r in self.sched.running()
                     if r.request_id == request_id), None)
         if req is None or req.slot in self._prefilling or req.length <= 0:
@@ -1213,6 +1350,7 @@ class ServingEngine:
         this call allocated and leaves the scheduler untouched."""
         if export is None:
             return None
+        self._no_migration_beside_rings("import_sequence")
         rid = request_id or export["request_id"]
         samp = export.get("sampling")
         if (export["block_size"] != self.config.block_size
@@ -1304,6 +1442,14 @@ class ServingEngine:
                             wire_bytes=int(export["wire_bytes"]),
                             length=req.length)
         return req
+
+    def _no_migration_beside_rings(self, call: str):
+        if self.ring_blocks:
+            raise NotImplementedError(
+                f"live KV migration ({call}) cannot move a sequence of "
+                f"{type(self.engine.module).__name__}: its window layers' "
+                "rows live in the slot's ring, which the export does not "
+                "carry")
 
     def migrate_out(self, request_id: str) -> bool:
         """Detach a migrated-away request from this replica: free its
@@ -1420,6 +1566,8 @@ class ServingEngine:
                 "accepted_tokens_per_step": round(acc / self._spec_steps, 4)
                 if self._spec_steps else None,
             }
+        from deepspeed_tpu.ops.attention import dispatch_counts
+
         s = self.sched.stats
         total = max(1, s["submitted"])
         led, base = self._ledger, self._ledger_base
@@ -1431,6 +1579,19 @@ class ServingEngine:
             "prefill_calls": led["prefill_calls"] - base["prefill_calls"],
             "busy_slot_steps": (led["busy_slot_steps"]
                                 - base["busy_slot_steps"]),
+            # the model's own counters by kind of program, the live KV
+            # bytes by kind of layer summed over decode steps (both empty
+            # for a model that has neither), and which attention path
+            # each call site took when its program was traced
+            "model_counters": {
+                phase: {name: led[f"{phase}.{name}"] - base[f"{phase}.{name}"]
+                        for name in self._counter_names}
+                for phase in ("prefill", "decode") if self._counter_names},
+            "kv_live_bytes": {
+                kind: led[f"kv_live_bytes.{kind}"]
+                - base[f"kv_live_bytes.{kind}"]
+                for kind in self._kv_bytes or ()},
+            "attention_paths": dispatch_counts(),
             "prefix_cache": prefix_stats,
             "speculative": spec_stats,
             "finished": s["finished"], "shed": s["shed"],

@@ -78,6 +78,9 @@ class Request:
     # cumulative; prefill_secs is the time inside its OWN prefill calls.
     # record() turns the pair into where its decode life went. ----
     prefill_secs: float = 0.0
+    # under serving.routed_experts_kept: the chosen experts of each call
+    # that processed tokens of this request, [tokens, layers x k] a call
+    routed: list = dataclasses.field(default_factory=list)
     live_mark: Optional[tuple] = None
     finish_mark: Optional[tuple] = None
     # ---- span-tracing context (telemetry/tracing.py) ----

@@ -571,3 +571,81 @@ def test_zero3_step_gathers_bf16_weights_and_scatters_f32_gradients(
     sums = [c for c in big if c["op"] in ("reduce-scatter", "all-reduce")]
     assert len(sums) >= layers
     assert all({d for d, _ in c["operands"]} == {"f32"} for c in sums)
+
+
+def _reader_pattern(metric):
+    """The instruction a benchmark reader matches in the device trace."""
+    import json
+    import os
+    import re
+
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(repo, "perfbench", "layer_metrics",
+                           f"{metric}.json")) as f:
+        return re.compile(json.load(f)["pattern"])
+
+
+def _custom_calls(text):
+    return [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
+            if "tpu_custom_call" in ln]
+
+
+# MiMo-V2.5 at its published widths: 64 query heads over 4 (global) and 8
+# (window) KV heads, keys 192 wide and values 128, a window of 128 in rings
+# of 5 blocks of 32, 16 held experts of 3 x 4096 x 2048; the benchmark cell's
+# 64 decode slots
+@pytest.mark.parametrize("kind", ["global", "window"])
+def test_hybrid_decode_kernel_at_the_published_widths(one_chip, kind):
+    """The GQA paged kernel compiles for the v5e at both row shapes (768 /
+    512 lanes and 1536 / 1024: whole registers), the window's with its ring
+    and its sink, and is the instruction the benchmark's reader matches."""
+    from deepspeed_tpu.ops.hybrid_decode_attention import (
+        decode_attention_hybrid)
+
+    pattern = _reader_pattern("hybrid_decode_roofline_share")
+    slots, bs = 64, 32
+    window = kind == "window"
+    kv, layers, blocks, per_row = ((8, 5, 1 + slots * 5, 5) if window
+                                   else (4, 2, 8193, 128))
+
+    def step(q, k, v, tables, lengths, sink):
+        with jax.named_scope("attn._hybrid_kv_attend"):
+            return decode_attention_hybrid(
+                q, k, v, tables, lengths, layers - 1, kv_heads=kv,
+                window=128 if window else 0, ring=window,
+                sink=sink if window else None)
+
+    text = _compiled_text(
+        step, _s(one_chip, (slots, 1, 64, 192)),
+        _s(one_chip, (layers, blocks, bs, kv * 192)),
+        _s(one_chip, (layers, blocks, bs, kv * 128)),
+        _s(one_chip, (slots, per_row), jnp.int32),
+        _s(one_chip, (slots,), jnp.int32), _s(one_chip, (64,), jnp.float32))
+    calls = _custom_calls(text)
+    assert calls and all(pattern.search(ln) for ln in calls), calls
+
+
+@pytest.mark.parametrize("tokens", [64, 3072], ids=["decode", "prefill"])
+def test_grouped_expert_kernel_at_the_published_widths(one_chip, tokens):
+    """The dropless grouped matmul over 16 held experts of 3 x 4096 x 2048
+    compiles for the v5e at a decode step's 64 rows and a long prompt's
+    3,072 (its weight blocks take more fast memory than the default
+    allows: the limit it asks for has to be granted), under the name the
+    benchmark's reader matches."""
+    from deepspeed_tpu.moe.dropless import expert_ffn
+
+    pattern = _reader_pattern("expert_matmul_roofline_share")
+
+    def layer(x, experts, weights, gate, up, down):
+        return expert_ffn(x, experts, weights, gate, up, down,
+                          first_expert=0, n_routed=256, use_kernel=True)
+
+    text = _compiled_text(
+        layer, _s(one_chip, (tokens, 4096)),
+        _s(one_chip, (tokens, 8), jnp.int32),
+        _s(one_chip, (tokens, 8), jnp.float32),
+        _s(one_chip, (16, 4096, 2048)), _s(one_chip, (16, 4096, 2048)),
+        _s(one_chip, (16, 2048, 4096)))
+    calls = _custom_calls(text)
+    assert len(calls) == 1 and pattern.search(calls[0]), calls

@@ -446,9 +446,12 @@ class TestRunner:
 def repo_report():
     baseline = load_baseline(os.path.join(REPO, "tools",
                                           "lint_baseline.json"))
-    t0 = time.monotonic()
+    # the pass's own CPU time, not the wall clock: under several test
+    # workers on a loaded machine the same pass waits for a core, and a
+    # wall-clock limit then fails on the machine, not on the lint
+    t0 = time.process_time()
     report = run(root=REPO, baseline=baseline)
-    report.elapsed = time.monotonic() - t0
+    report.elapsed = time.process_time() - t0
     return report
 
 
@@ -470,8 +473,8 @@ class TestRepoGate:
 
     def test_runs_inside_the_tier1_budget(self, repo_report):
         assert repo_report.elapsed < 2.0, (
-            f"lint pass took {repo_report.elapsed:.2f}s — it must stay "
-            f"cheap enough to gate every tier-1 run")
+            f"lint pass took {repo_report.elapsed:.2f}s of CPU time — it "
+            f"must stay cheap enough to gate every tier-1 run")
 
     def test_lint_package_itself_is_jax_free(self):
         """AST pin, same convention as GL01: nothing under tools/lint

@@ -12,6 +12,17 @@ programs:
 - ``serving.decode[slots=N]`` — ONE program for the fixed slot batch:
   every active sequence advances one token against its own block table
   and length; idle slots compute into the garbage block and are ignored;
+- ``serving.decode_feed`` — the decode program's token input, made on
+  the device: the newest decode output's sampled tokens with the host's
+  token laid over the rows that went live since. The decode loop runs
+  ONE STEP AHEAD (``_decode_step``): step N+1 is on the device's queue
+  before step N's tokens are fetched, so the host's whole round (fetch,
+  emit, the stream callbacks, the next schedule pass and dispatch) runs
+  while the device computes. A fetched row is delivered only to the
+  request it was dispatched for; an ending known by the count of tokens
+  is left out of the step ahead, so only ``eos``, a cancel or a deadline
+  ever costs a wasted row. No knob: off only where a proposer is
+  configured (the verify step needs the tokens on the host);
 - ``serving.chunk[T=c]`` — the serving fast path's third program
   (compiled only when ``prefill_chunk_tokens`` or ``prefix_cache`` is
   on): writes ``c`` prompt tokens at the sequence's current length and
@@ -86,6 +97,15 @@ from deepspeed_tpu.utils.logging import log_dist
 
 # the bracketed phases that tile a scheduler iteration: the ledger's keys
 _PHASES = ("schedule", "prefill", "decode", "emit")
+
+# an idle slot's row of the keyed sampler's five arrays (seed, flag,
+# temperature, top-k, top-p)
+_IDLE_SAMP = (0, 0, 1.0, 0, 0.0)
+
+# one decode step on the device's queue, its tokens not fetched yet: the
+# program's output (still on the device), the (slot, request) pairs it was
+# dispatched for, and the lengths it was dispatched with
+_Flight = collections.namedtuple("_Flight", "toks pairs lengths")
 
 
 def _model_window(model_config) -> Optional[int]:
@@ -191,7 +211,10 @@ class ServingEngine:
         # base reset_stats() takes, the registry gets what it gained
         # since the last publish.
         self._ledger = {**dict.fromkeys(_PHASES, 0.0), "prefill_calls": 0,
-                        "busy_slot_steps": 0}
+                        "busy_slot_steps": 0,
+                        # decode steps dispatched while another was still
+                        # in flight, and rows fetched and not delivered
+                        "decode_ahead_steps": 0, "decode_dropped_rows": 0}
         # what the model itself counts in a call (a sparse model: experts
         # touched, pairs routed here and in all), handed back behind the
         # sampled tokens and summed here by the kind of program; and the
@@ -230,6 +253,14 @@ class ServingEngine:
         self._lengths = np.zeros((self.config.decode_slots,), np.int32)
         self._prefill_fns: Dict[int, object] = {}
         self._decode_fn = None
+        # the decode loop runs one step ahead (``_decode_step``): the step
+        # in flight, the newest decode output (the next step's tokens, on
+        # the device), the program that lays the host's tokens over it,
+        # and what a flush outside ``step()`` finished
+        self._flight: Optional[_Flight] = None
+        self._feed_fn = None
+        self._prev_toks = None
+        self._late: List[Request] = []
         # chunked / prefix-continued prefill state: a slot mid-prefill is
         # NOT in the decode batch (its row of self._tables stays pointed
         # at the garbage block) until its whole prompt is written
@@ -506,6 +537,36 @@ class ServingEngine:
             fn, "serving_decode",
             f"serving.decode[slots={self.config.decode_slots}]")
 
+    def _build_feed(self):
+        """The decode program's token input, made on the device: the
+        newest decode output's sampled tokens, with the host's token laid
+        over every row that ``fresh`` gives one (``>= 0``: a slot that
+        went live since that step was dispatched, or zero for an idle
+        row). So a sampled token never waits for the host to come back as
+        the next step's input. Both sides carry the mesh's replicated
+        sharding by declaration: the decode program then sees one
+        argument signature whatever made ``prev`` (the zeros before the
+        first step, returned beside the program, or the decode program's
+        own output), and stays ONE program."""
+        jax, jnp = self._jax, self._jnp
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        n = self.config.decode_slots
+        everywhere = NamedSharding(self.engine.mesh, PartitionSpec())
+
+        def serving_decode_feed(prev, fresh):
+            return jnp.where(fresh < 0, prev[:n].astype(jnp.int32),
+                             fresh)[:, None]
+
+        # what ``_with_counters`` returns for the slot batch: the tokens,
+        # then the counters, then each row's routed experts
+        zeros = jax.device_put(np.zeros(
+            (n + len(self._counter_names) + n * self._routed_width,),
+            np.int32), everywhere)
+        return self.engine.telemetry.watch_jit(
+            jax.jit(serving_decode_feed, in_shardings=everywhere,
+                    out_shardings=everywhere), "serving.decode_feed"), zeros
+
     def _build_chunk(self, T: int):
         """One prefill chunk: write ``num_valid`` prompt tokens at the
         sequence's current pool length and attend them against everything
@@ -646,13 +707,15 @@ class ServingEngine:
                 jnp.asarray([req.top_k or 0], jnp.int32),
                 jnp.asarray([req.top_p or 0.0], jnp.float32))
 
-    def _slot_samp_args(self):
-        """The keyed decode program's per-slot sampling arrays (idle and
-        greedy slots carry flag 0)."""
+    def _slot_samp_args(self, live):
+        """The keyed decode program's per-slot sampling arrays (greedy
+        slots carry flag 0, and rows outside ``live`` an idle slot's
+        row)."""
         jnp = self._jnp
-        return (jnp.asarray(self._seeds), jnp.asarray(self._samp_on),
-                jnp.asarray(self._temps), jnp.asarray(self._top_ks),
-                jnp.asarray(self._top_ps))
+        rows = (self._seeds, self._samp_on, self._temps, self._top_ks,
+                self._top_ps)
+        return tuple(jnp.asarray(np.where(live, a, a.dtype.type(idle)))
+                     for a, idle in zip(rows, _IDLE_SAMP))
 
     # ------------------------------------------------------------------
     def submit(self, prompt, max_new_tokens: int = 0, **kwargs) -> Request:
@@ -678,7 +741,8 @@ class ServingEngine:
         requests into free slots, advance mid-prefill prompts one budgeted
         chunk, then advance every decode-ready sequence one token. Returns
         requests finished this step."""
-        done: List[Request] = []
+        # (what a flush between two calls finished is reported here too)
+        done, self._late = self._late, []
         with self._bracket("step", step=self._step_count + 1,
                            busy=self._busy, queue_depth=len(self.sched.queue)):
             with self._bracket("schedule", span="schedule",
@@ -699,12 +763,17 @@ class ServingEngine:
             # one decode step for the whole slot batch (mid-prefill slots
             # are idle decode rows: garbage table, outputs ignored); with
             # speculation on, the verify program IS the decode step
-            if any(slot not in self._prefilling
-                   for slot, _ in self.sched.running()):
+            if self._decode_ready():
                 if self._proposer is not None:
                     self._spec_step(done)
                 else:
                     self._decode_step(done)
+            if self._flight is not None and not any(
+                    self.sched.slots[slot] is req
+                    for slot, req in self._flight.pairs):
+                # the step in flight has no taker left (eos, a cancel,
+                # the deadline sweep): fetched now, its rows dropped
+                self._decode_step(done, ahead=False)
         if self._step_trace.enabled:
             g = self.sched.gauges()
             self._step_trace.flush(self._step_count,
@@ -870,9 +939,14 @@ class ServingEngine:
 
     def _mark_live(self, req: Request, now: float):
         """The request joins the decode batch at ``now``: its first
-        token's timestamp and the ledger as it stood then."""
+        token's timestamp and the ledger as it stood then. A step in
+        flight is counted at its fetch, inside this request's decode
+        life, and is none of its steps: the mark counts it already."""
         req.first_token_ts = now
-        req.live_mark = self._ledger_mark()
+        prefill, decode, steps, busy = self._ledger_mark()
+        if self._flight is not None:
+            steps, busy = steps + 1, busy + len(self._flight.pairs)
+        req.live_mark = (prefill, decode, steps, busy)
 
     def _set_samp_slot(self, slot: int, req: Request):
         """Load one slot's sampling row from the request's (resolved)
@@ -886,11 +960,8 @@ class ServingEngine:
         self._top_ps[slot] = req.top_p or 0.0
 
     def _clear_samp_slot(self, slot: int):
-        self._seeds[slot] = 0
-        self._samp_on[slot] = 0
-        self._temps[slot] = 1.0
-        self._top_ks[slot] = 0
-        self._top_ps[slot] = 0.0
+        (self._seeds[slot], self._samp_on[slot], self._temps[slot],
+         self._top_ks[slot], self._top_ps[slot]) = _IDLE_SAMP
 
     def _cow_copy(self, src: int, dst: int):
         jnp = self._jnp
@@ -899,33 +970,58 @@ class ServingEngine:
         self.cache = self._cow_fn(self.cache, jnp.asarray(src, jnp.int32),
                                   jnp.asarray(dst, jnp.int32))
 
-    def _decode_step(self, done: List[Request]):
-        jnp = self._jnp
+    def _decode_step(self, done: List[Request], ahead: bool = True):
+        """One decode step fetched and emitted; and, with ``ahead``, the
+        NEXT one dispatched first, so that the device computes step N+1
+        while the host fetches step N, emits it, and goes round its loop
+        (the handlers' writes, the next schedule pass and dispatch). What
+        step N+1 needs of step N is on the device already (the sampled
+        tokens) or known without looking (the lengths, + 1); its tables
+        and sampling rows never change while a request decodes. At most
+        one step is in flight on return (``step()`` then fetches one
+        that no running sequence needs any more). ``ahead=False`` only
+        fetches the step in flight: the flush (``_flush``)."""
         if self._decode_fn is None:
             self._decode_fn = self._build_decode()
-        active = [(s, r) for s, r in self.sched.running()
-                  if s not in self._prefilling]
+        if self._feed_fn is None:
+            self._feed_fn, self._prev_toks = self._build_feed()
+        ready = self._decode_ready()
         with self._bracket("decode", span="decode_step", ledger="decode",
-                           active=len(active)) as ph:
-            with self._bracket("decode.dispatch"):
-                tokens = jnp.asarray(self._last_tokens[:, None])
-                tail = (self._slot_samp_args() if self._keyed
-                        else (self._next_rng(),))
-                toks, self.cache = self._decode_fn(
-                    self.engine.params, self.cache, tokens,
-                    jnp.asarray(self._tables), jnp.asarray(self._lengths),
-                    *tail)
+                           active=len(ready)) as ph:
+            if ahead:
+                with self._bracket("decode.dispatch"):
+                    # (the call that finds nothing in flight dispatches
+                    # the step it will fetch, then the one ahead of it)
+                    flight = (self._flight if self._flight is not None
+                              else self._dispatch(None, ready))
+                    self._flight = self._dispatch(flight, ready)
+            else:
+                flight, self._flight = self._flight, None
             with self._bracket("decode.sync"):
                 # the ONE designed host sync per decode step: sampled
                 # tokens must reach the host to stream to callers and
                 # drive finish logic
-                toks = np.asarray(toks)  # graft-lint: disable=GL04
+                toks = np.asarray(flight.toks)  # graft-lint: disable=GL04
         now = ph.t1
+        # counted here, at the fetch, from the fetched step's own view
         self._count("decode", toks[len(self._lengths):])
         routed = self._routed(toks, len(self._lengths))
-        self._step_boundary(len(active))
+        self._step_boundary(len(flight.pairs), flight.lengths)
         with self._bracket("emit", span="emit", ledger="emit"):
-            for slot, req in active:
+            for slot, req in flight.pairs:
+                if self.sched.slots[slot] is not req:
+                    # the row's request left its slot after the step was
+                    # dispatched (eos at the step before, a cancel, a
+                    # blown deadline; the slot may have a new tenant):
+                    # nothing of the row is delivered. It wrote one KV
+                    # row past the request's last token, into a block
+                    # (or the slot's ring) the request had been given:
+                    # harmless, because every program that could reuse
+                    # the block is dispatched after this one on the same
+                    # device queue, and the prefix cache indexes only
+                    # whole prompt tokens (test_serving_lookahead.py).
+                    self._ledger["decode_dropped_rows"] += 1
+                    continue
                 if routed is not None:
                     req.routed.append(routed[slot])
                 tok = int(toks[slot])
@@ -942,8 +1038,64 @@ class ServingEngine:
                               >= req.max_new_tokens else "window")
                     self._finish(req, reason, now, done)
 
-    def _step_boundary(self, active: int):
-        """One decode (or verify) step is over: the ledger's counts, the
+    def _decode_ready(self):
+        return [(s, r) for s, r in self.sched.running()
+                if s not in self._prefilling]
+
+    def _dispatch(self, behind: Optional[_Flight],
+                  ready) -> Optional[_Flight]:
+        """Put one decode step on the device's queue for the decode-ready
+        ``ready``, behind the step in flight (``behind``, or None): the
+        *dispatched* view, one step
+        ahead of the fetched one (``_lengths``, ``_last_tokens``). A row
+        of ``behind`` goes on with its token left on the device and its
+        length + 1, unless ``behind`` is its request's last step by the
+        count of tokens or by the context limit (known without the
+        fetch: then it is an idle row here, as a finished slot's is).
+        Every other decode-ready slot went live since ``behind`` was
+        dispatched and brings the host's row. None, and nothing
+        dispatched, where no row is left."""
+        jnp = self._jnp
+        onward = np.zeros(len(self._lengths), bool)
+        live = np.zeros(len(self._lengths), bool)
+        was = dict(behind.pairs) if behind is not None else {}
+        pairs = []
+        for slot, req in ready:
+            if was.get(slot) is req:
+                if (len(req.tokens) + 1 >= req.max_new_tokens
+                        or req.length + 2 > self.max_len):
+                    continue
+                onward[slot] = True
+            live[slot] = True
+            pairs.append((slot, req))
+        if not pairs:
+            return None
+        lengths = np.where(live, self._lengths + onward, 0).astype(np.int32)
+        fresh = np.where(onward, -1, np.where(live, self._last_tokens, 0))
+        tail = (self._slot_samp_args(live) if self._keyed
+                else (self._next_rng(),))
+        toks, self.cache = self._decode_fn(
+            self.engine.params, self.cache,
+            self._feed_fn(self._prev_toks, fresh.astype(np.int32)),
+            jnp.asarray(np.where(live[:, None], self._tables, 0)),
+            jnp.asarray(lengths), *tail)
+        self._prev_toks = toks
+        if behind is not None:
+            self._ledger["decode_ahead_steps"] += 1
+        return _Flight(toks, pairs, lengths)
+
+    def _flush(self) -> List[Request]:
+        """Fetch and deliver the step in flight, if there is one: what
+        reads or moves a sequence's state from outside the step loop
+        does this first. Returns the requests it finished."""
+        done: List[Request] = []
+        if self._flight is not None:
+            self._decode_step(done, ahead=False)
+        return done
+
+    def _step_boundary(self, active: int, lengths: np.ndarray):
+        """One decode (or verify) step is over, dispatched with
+        ``lengths`` for ``active`` live rows: the ledger's counts, the
         telemetry step boundary, and, under telemetry only, the load
         gauges (the slot scan is not paid with telemetry off)."""
         self._step_count += 1
@@ -952,7 +1104,7 @@ class ServingEngine:
         if self._kv_bytes:
             # what the step just run had to read: a global layer every
             # token of a sequence, a window layer what its ring holds
-            live = self._lengths[self._lengths > 0].astype(np.int64)
+            live = lengths[lengths > 0].astype(np.int64)
             held = self.ring_blocks * self.config.block_size
             self._ledger["kv_live_bytes.global"] += int(
                 live.sum()) * self._kv_bytes["global"]
@@ -979,11 +1131,13 @@ class ServingEngine:
         dispatch cost of one decode step; proposals right-pad to ``k``
         against the garbage block so the program shape never changes."""
         jnp = self._jnp
+        # synchronous: the proposer needs the tokens on the host. The
+        # plain path never runs beside it, so nothing is ever in flight
+        assert self._flight is None
         if self._verify_fn is None:
             self._verify_fn = self._build_verify()
         k = self.spec_k
-        active = [(s, r) for s, r in self.sched.running()
-                  if s not in self._prefilling]
+        active = self._decode_ready()
         tokens = np.zeros((self.config.decode_slots, k + 1), np.int32)
         tokens[:, 0] = self._last_tokens
         num_valid = np.ones((self.config.decode_slots,), np.int32)
@@ -1032,7 +1186,7 @@ class ServingEngine:
         # the router's exactly-once splice sees no speculative token
         raise_if("serving.spec_commit")
         self._spec_steps += 1
-        self._step_boundary(len(active))
+        self._step_boundary(len(active), self._lengths)
         with self._bracket("emit", span="emit", ledger="emit"):
             self._spec_emit(active, proposals, toks, t0, now, done)
 
@@ -1221,6 +1375,10 @@ class ServingEngine:
             phase.labels(phase=name).inc(now[name] - was[name])
         m.counter("ds_serving_busy_slot_steps_total").inc(
             now["busy_slot_steps"] - was["busy_slot_steps"])
+        m.counter("ds_serving_decode_ahead_steps_total").inc(
+            now["decode_ahead_steps"] - was["decode_ahead_steps"])
+        m.counter("ds_serving_decode_dropped_rows_total").inc(
+            now["decode_dropped_rows"] - was["decode_dropped_rows"])
 
     # ------------------------------------------------------------------
     def cancel(self, request_id: str, reason: str = "cancelled") -> bool:
@@ -1264,6 +1422,7 @@ class ServingEngine:
         mid-prefill — those replay/resubmit cheaply)."""
         raise_if("serving.migration.export", detail=request_id)
         self._no_migration_beside_rings("export_sequence")
+        self._late.extend(self._flush())
         req = next((r for _, r in self.sched.running()
                     if r.request_id == request_id), None)
         if req is None or req.slot in self._prefilling or req.length <= 0:
@@ -1351,6 +1510,7 @@ class ServingEngine:
         if export is None:
             return None
         self._no_migration_beside_rings("import_sequence")
+        self._late.extend(self._flush())
         rid = request_id or export["request_id"]
         samp = export.get("sampling")
         if (export["block_size"] != self.config.block_size
@@ -1456,6 +1616,7 @@ class ServingEngine:
         slot, blocks and token budget WITHOUT a shed record — the
         request is still live, on another replica, in the same client
         trace. Call only after the target committed its import."""
+        self._late.extend(self._flush())
         now = self.clock()
         req = self.sched.migrate_out(request_id, now)
         if req is None:
@@ -1506,6 +1667,8 @@ class ServingEngine:
         while self.pending and (max_steps is None or steps < max_steps):
             out.extend(self.step())
             steps += 1
+        # (nothing stays in flight behind a drain)
+        out.extend(self._flush())
         return out
 
     def generate_batch(self, prompts, max_new_tokens: int = 0, **kwargs):
@@ -1579,6 +1742,13 @@ class ServingEngine:
             "prefill_calls": led["prefill_calls"] - base["prefill_calls"],
             "busy_slot_steps": (led["busy_slot_steps"]
                                 - base["busy_slot_steps"]),
+            # how often the decode loop ran ahead: steps dispatched while
+            # another was in flight, and rows fetched and not delivered
+            "decode_ahead": {
+                "steps": (led["decode_ahead_steps"]
+                          - base["decode_ahead_steps"]),
+                "dropped_rows": (led["decode_dropped_rows"]
+                                 - base["decode_dropped_rows"])},
             # the model's own counters by kind of program, the live KV
             # bytes by kind of layer summed over decode steps (both empty
             # for a model that has neither), and which attention path
@@ -1612,9 +1782,10 @@ class ServingEngine:
     def destroy(self):
         """Drop compiled programs and the cache pool; destroys the wrapped
         engine only when this ServingEngine constructed it."""
+        self._flush()
         self._prefill_fns.clear()
         self._chunk_fns.clear()
-        self._decode_fn = None
+        self._decode_fn = self._feed_fn = self._prev_toks = None
         self._cow_fn = None
         self._migrate_fns.clear()
         self._verify_fn = None

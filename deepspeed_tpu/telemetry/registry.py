@@ -90,6 +90,14 @@ NAMES = {
     "ds_serving_busy_slot_steps_total": (
         "counter", "sum over decode steps of the slots that decoded: "
                    "over ds_steps_total, the mean decode batch"),
+    "ds_serving_decode_ahead_steps_total": (
+        "counter", "decode steps dispatched while the step before was "
+                   "still in flight: over ds_steps_total, how often the "
+                   "decode loop ran one step ahead"),
+    "ds_serving_decode_dropped_rows_total": (
+        "counter", "rows of a fetched decode step not delivered: their "
+                   "request had left its slot (eos, cancel, deadline) "
+                   "after the step was dispatched"),
     "ds_serving_queue_depth": (
         "gauge", "admission queue depth at the last decode step"),
     "ds_serving_slots_busy": (

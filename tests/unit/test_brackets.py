@@ -51,8 +51,10 @@ PREFILL_MS, DECODE_MS, STREAM_MS, ADMIT_MS = 50, 10, 1, 2
 def two_requests():
     """A (4 new tokens) decodes alone, B (2 new tokens) arrives after A's
     second token: B's prefill lands inside A's decode life. A prefill
-    program takes 50 ms, a decode program 10, a stream callback 1, an
-    admission pass 2; nothing else takes time."""
+    program takes 50 ms, a decode program 10 (at its dispatch: the fake
+    clock has no device beside it), a stream callback 1, an admission pass
+    2; nothing else takes time. The decode loop runs one step ahead: a
+    ``step()`` dispatches the step after the one it fetches."""
     from deepspeed_tpu.serving import ServingEngine
 
     clock = MsClock()
@@ -68,10 +70,10 @@ def two_requests():
 
     t_start = clock()
     a = srv.submit([5, 6, 7, 8], max_new_tokens=4, stream=stream)
-    srv.step()                      # A: prefill, token 1; decode, token 2
+    srv.step()          # A: prefill, token 1; steps 1, 2 out; 1 in: A 2
     b = srv.submit([9, 10, 11, 12, 13], max_new_tokens=2, stream=stream)
-    srv.step()                      # B: prefill, token 1; decode: A 3, B 2
-    srv.step()                      # decode: A 4
+    srv.step()          # B: prefill, token 1; step 3 out (A, B); 2 in: A 3
+    srv.step()          # both end at step 3: none out; 3 in: A 4, B 2
     assert a.done and b.done and not srv.pending
     out = {"a": a.record(), "b": b.record(), "reqs": (a, b),
            "wall": clock() - t_start, "stats": srv.stats()}
@@ -86,14 +88,16 @@ def two_requests():
 
 # (a) -----------------------------------------------------------------------
 @pytest.mark.parametrize("who,expected", [
-    # A: live at 52 (admit 2, prefill 50), finished at 141: 89 ms of life =
+    # A: live at 52 (admit 2, prefill 50), finished at 140: 88 ms of life =
     # 3 decode steps (30) + B's prefill (50) + 2 admission passes that ran
-    # inside it (4) + 5 stream callbacks (5)
+    # inside it (4) + 4 stream callbacks (4)
     ("a", {"prefill_ms": 50.0, "decode_steps": 3, "decode_ms": 30.0,
-           "blocked_ms": 50.0, "host_ms": 9.0, "batch_mean": 1.333}),
-    # B: live at 116, finished at 127 after one step beside A
+           "blocked_ms": 50.0, "host_ms": 8.0, "batch_mean": 1.333}),
+    # B: live at 126, with step 2 in flight, which is none of its steps;
+    # finished at 140 after one step beside A (10), 2 stream callbacks
+    # and an admission pass (4)
     ("b", {"prefill_ms": 50.0, "decode_steps": 1, "decode_ms": 10.0,
-           "blocked_ms": 0.0, "host_ms": 1.0, "batch_mean": 2.0}),
+           "blocked_ms": 0.0, "host_ms": 4.0, "batch_mean": 2.0}),
 ])
 def test_record_says_where_the_decode_life_went(two_requests, who, expected):
     rec = two_requests[who]

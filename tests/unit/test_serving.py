@@ -1191,9 +1191,12 @@ class TestOneResidentPool:
         expect = home.generate_batch([prompt], max_new_tokens=6)[0]
         dst.generate_batch([np.arange(3, 9)], max_new_tokens=2)  # dirty it
         req = src.submit(prompt, max_new_tokens=6)
-        for _ in range(3):
+        for _ in range(2):
             src.step()
+        # (the export first fetches the step in flight: a third decode
+        # token, 16 tokens pooled)
         export = src.export_sequence(req.request_id)
+        assert export["length"] == 16 and src._flight is None
         assert export["blocks"] == 2 and len(export["rows"]) == (
             4 if kv else 2)
         lanes = {"key_pool": cfg.n_embd, "value_pool": cfg.n_embd,

@@ -22,6 +22,16 @@ stream callback (step thread) never blocks — a slow reader overflows its
 own queue and sheds that request only, via the backend ``cancel()``
 seam, drained at the next :meth:`ServingGateway.step`.
 
+The front door measures itself, always on (no config key): four stamps
+a request on the gateway's one clock (``accept`` in the handler's
+``setup()``, ``submitted`` when ``backend.submit`` returned,
+``first_emit`` on the step thread, ``first_flush`` when the first token
+event's flush returned) and a time on every token handed over. They
+complete the record the ``done`` event carries (``ingress_ms``,
+``first_egress_ms``, ``ttft_wire_ms``, ``egress_mean_ms``,
+``egress_max_ms``, ``write_ms``), are what ``ds_gateway_ttft_ms`` and a
+tenant's SLO outcome observe, and add up in ``stats()["front_door"]``.
+
 Pure host code: never imports jax (GL01) and reads only the injected
 clock (GL07) — the trace-replay harness runs the whole front door on
 simulated time, bit-deterministically.
@@ -87,17 +97,70 @@ class _NullTelemetry:
         pass
 
 
+def _ms(start: Optional[float], end: Optional[float]) -> Optional[float]:
+    if start is None or end is None:
+        return None
+    return round(1e3 * (end - start), 3)
+
+
 class _Stream:
     """Per-request delivery state shared between the step thread (the
-    stream callback producing) and the handler thread (consuming)."""
+    stream callback producing) and the handler thread (consuming), and
+    the request's times at the front door, all on the gateway's clock.
+    Each has ONE writer: ``first_ts`` the step thread, every other the
+    request's own handler thread (``accept_ts`` / ``submitted_ts``
+    before the step thread can emit; the rest in :meth:`delivered`)."""
 
-    def __init__(self, request_id: str, maxsize: int):
+    def __init__(self, request_id: str, maxsize: int, accept_ts: float):
         self.request_id = request_id
         self.q: "queue.Queue" = queue.Queue(maxsize=maxsize)
-        self.first_ts: Optional[float] = None
+        self.accept_ts = accept_ts        # handler's setup(): thread runs
+        self.submitted_ts: Optional[float] = None   # backend.submit returned
+        self.first_ts: Optional[float] = None       # first token emitted
+        self.first_flush_ts: Optional[float] = None
+        self.last_flush_ts: Optional[float] = None
+        self.flushed = 0            # token events written and flushed
+        self.egress_secs = 0.0      # sum of flushed_at - emitted_at
+        self.egress_max = 0.0
+        self.write_secs = 0.0       # sum of the time inside sse_write
         self.tokens = 0
         self.overflow = False
         self.closed = False
+
+    def delivered(self, flushed: int, first_flush: Optional[float],
+                  last_flush: Optional[float], egress_secs: float,
+                  egress_max: float, write_secs: float):
+        """What the handler's write loop measured, kept in its locals
+        while it ran: stored before the record or ``_finish`` read it."""
+        self.flushed = flushed
+        self.first_flush_ts, self.last_flush_ts = first_flush, last_flush
+        self.egress_secs, self.egress_max = egress_secs, egress_max
+        self.write_secs = write_secs
+
+    def since_submit_ms(self) -> Optional[float]:
+        """``first_emit - submitted``: TTFT as the engine's side of the
+        door sees it, read on the step thread (deterministic under the
+        replay harness' simulated time)."""
+        if self.first_ts is None or self.submitted_ts is None:
+            return None
+        return 1e3 * max(self.first_ts - self.submitted_ts, 0.0)
+
+    def record_fields(self) -> dict:
+        """The gateway's fields of the request's record, as far as the
+        request got. ``ttft_wire_ms = ingress_ms + (first_emit -
+        submitted) + first_egress_ms``. A reply that streamed no token
+        event (a JSON reply; a request shed before its first flush) has
+        ``None`` in the egress fields and in ``ttft_wire_ms``."""
+        n = self.flushed
+        return {
+            "ingress_ms": _ms(self.accept_ts, self.submitted_ts),
+            "first_egress_ms": _ms(self.first_ts, self.first_flush_ts),
+            "ttft_wire_ms": _ms(self.accept_ts, self.first_flush_ts),
+            "egress_mean_ms": round(1e3 * self.egress_secs / n, 3)
+            if n else None,
+            "egress_max_ms": round(1e3 * self.egress_max, 3) if n else None,
+            "write_ms": round(1e3 * self.write_secs, 3) if n else None,
+        }
 
 
 class ServingGateway:
@@ -142,6 +205,11 @@ class ServingGateway:
         self._running = False
         # per-tenant counters for stats()/bench (metrics may be off)
         self._counts: Dict[str, Dict[str, int]] = {}
+        # the front door's own sums: each stream's added once, in
+        # _finish, under the lock (never += from a handler's write loop)
+        self._front_door = {"requests": 0, "tokens_written": 0,
+                            "ingress_secs": 0.0, "write_secs": 0.0,
+                            "egress_wait_secs": 0.0}
 
     # ------------------------------------------------------------------
     # lifecycle
@@ -263,11 +331,23 @@ class ServingGateway:
                    status=status)
         if trace is not None:
             tid, root = trace
-            now_ns = to_ns(self.clock())
+            now = self.clock()
+            now_ns = to_ns(now)
+            self._ingress_span(trace, now, tenant_name, reason)
             self._tracer.record_span("shed", tid, now_ns, now_ns,
                                      parent=span_id(root), reason=reason,
                                      tenant=tenant_name)
             end_span(root, end_ns=now_ns, status=status)
+
+    def _ingress_span(self, trace, end_ts: float, tenant_name: str,
+                      outcome: str):
+        """accept (the root's start) -> the engine has the request, or
+        the door refused it."""
+        if trace is not None:
+            tid, root = trace
+            self._tracer.record_span("ingress", tid, root.start_ns,
+                                     to_ns(end_ts), parent=span_id(root),
+                                     tenant=tenant_name, outcome=outcome)
 
     def _finish(self, tenant: Tenant, stream: _Stream, outcome: str,
                 reason: str = "", ttft_ms: Optional[float] = None,
@@ -276,8 +356,15 @@ class ServingGateway:
         if stream.closed:
             return
         stream.closed = True
+        fields = stream.record_fields()
         with self._lock:
             self._streams.pop(stream.request_id, None)
+            door = self._front_door
+            door["requests"] += 1
+            door["tokens_written"] += stream.flushed
+            door["ingress_secs"] += stream.submitted_ts - stream.accept_ts
+            door["write_secs"] += stream.write_secs
+            door["egress_wait_secs"] += stream.egress_secs
         tenant.release()
         shed = outcome != "ok"
         tenant.record_outcome(shed, ttft_ms)
@@ -299,9 +386,18 @@ class ServingGateway:
         self._emit("request.finished", tenant=tenant.name, outcome=outcome,
                    reason=reason, request_id=stream.request_id,
                    tokens=stream.tokens, ttft_ms=ttft_ms,
-                   budget_remaining=round(tenant.budget_remaining(), 6))
+                   budget_remaining=round(tenant.budget_remaining(), 6),
+                   **fields)
         if trace is not None:
             tid, root = trace
+            if stream.flushed:
+                # one span a request, never one a token
+                self._tracer.record_span(
+                    "deliver", tid, to_ns(stream.first_flush_ts),
+                    to_ns(stream.last_flush_ts), parent=span_id(root),
+                    tokens=stream.flushed,
+                    egress_mean_ms=fields["egress_mean_ms"],
+                    egress_max_ms=fields["egress_max_ms"])
             end_span(root, end_ns=to_ns(self.clock()), status=status,
                      outcome=outcome, tokens=stream.tokens)
 
@@ -317,7 +413,15 @@ class ServingGateway:
         independent of the metrics plane being armed)."""
         with self._lock:
             counts = {t: dict(row) for t, row in self._counts.items()}
-        out = {"tenants": {}}
+            door = dict(self._front_door)
+        # every admitted request that reached its end, and what its
+        # handler spent: write_secs is wall time inside sse_write (the
+        # work and the wait to get the interpreter lock back), so
+        # write_secs / tokens_written x the tokens written a second is
+        # the handler threads inside the bracket at any moment
+        out = {"tenants": {},
+               "front_door": {k: round(v, 6) if isinstance(v, float) else v
+                              for k, v in door.items()}}
         for tenant in self.tenants.tenants:
             row = counts.get(tenant.name, {})
             row["inflight"] = tenant.inflight
@@ -344,21 +448,20 @@ class ServingGateway:
             return None, "forbidden"
         return tenant, ""
 
-    def admit(self, tenant: Tenant, body: dict):
-        """Quota + backend admission for a parsed, authenticated request.
-        Returns ``(handle, stream, trace, retry_after, reason)`` —
-        ``handle`` is None when rejected."""
+    def admit(self, tenant: Tenant, body: dict, accept_ts: float):
+        """Quota + backend admission for a parsed, authenticated request
+        whose handler began at ``accept_ts``. Returns ``(handle, stream,
+        trace, retry_after, reason)`` — ``handle`` is None when
+        rejected."""
         t0 = self.clock()
         trace = None
         if self._tracer.enabled and tenant.sample_trace():
             tid = self._tracer.new_trace(hint=tenant.name)
-            root = self._tracer.begin("gateway", tid, start_ns=to_ns(t0),
+            root = self._tracer.begin("gateway", tid,
+                                      start_ns=to_ns(accept_ts),
                                       tenant=tenant.name,
                                       route=GENERATE_ROUTE)
             trace = (tid, root)
-            self._tracer.record_span("auth", tid, to_ns(t0), to_ns(t0),
-                                     parent=span_id(root),
-                                     tenant=tenant.name)
         max_new = int(body.get("max_new_tokens", 0) or 0)
         overload = getattr(self.backend, "overload", None)
         threshold = self.config.overload_reject_threshold
@@ -379,7 +482,8 @@ class ServingGateway:
             return None, None, None, \
                 max(wait, self.config.retry_after_secs), reason
         request_id = str(body.get("request_id") or self._next_id())
-        stream = _Stream(request_id, self.config.send_queue_tokens)
+        stream = _Stream(request_id, self.config.send_queue_tokens,
+                         accept_ts)
         kwargs: Dict[str, Any] = {
             "max_new_tokens": max_new,
             "request_id": request_id,
@@ -412,7 +516,11 @@ class ServingGateway:
         with self._lock:
             self._streams[request_id] = stream
         handle = self.backend.submit(body["prompt"], **kwargs)
-        if getattr(handle, "state", "") == "shed":
+        stream.submitted_ts = self.clock()
+        shed = getattr(handle, "state", "") == "shed"
+        self._ingress_span(trace, stream.submitted_ts, tenant.name,
+                           "backend_shed" if shed else "ok")
+        if shed:
             # backend admission control said no (queue full / duplicate
             # id / inflight-token cap): surface it as 503, not a hang
             self._finish(tenant, stream, "shed",
@@ -430,15 +538,19 @@ class ServingGateway:
         return handle, stream, trace, 0.0, ""
 
     def _make_stream_cb(self, tenant: Tenant, stream: _Stream):
+        clock = self.clock
+
         def on_token(req, token: int, done: bool):
             if stream.closed or stream.overflow:
                 return
+            # step-thread clock read: deterministic under the replay
+            # harness' simulated time. Every token carries the moment it
+            # was handed over; the handler measures its wait from it.
+            now = clock()
             if stream.first_ts is None:
-                # step-thread clock read: deterministic under the
-                # replay harness' simulated time
-                stream.first_ts = self.clock()
+                stream.first_ts = now
             try:
-                stream.q.put_nowait(("token", int(token)))
+                stream.q.put_nowait(("token", int(token), now))
                 stream.tokens += 1
                 if done:
                     stream.q.put_nowait(("done",))
@@ -450,15 +562,47 @@ class ServingGateway:
         return on_token
 
     def observe_ttft(self, tenant: Tenant, stream: _Stream,
-                     submit_ts: float) -> Optional[float]:
-        if stream.first_ts is None:
-            return None
-        return 1e3 * max(stream.first_ts - submit_ts, 0.0)
+                     streamed: bool = True) -> Optional[float]:
+        """The TTFT ``ds_gateway_ttft_ms`` and the tenant's SLO outcome
+        observe: accept -> first flush where the reply streams (None if
+        no token event reached the socket), ``first_emit - submitted``
+        for a JSON reply, which has no flush a token."""
+        ttft_ms = (_ms(stream.accept_ts, stream.first_flush_ts) if streamed
+                   else stream.since_submit_ms())
+        if ttft_ms is not None:
+            self._metrics.histogram("ds_gateway_ttft_ms",
+                                    labels=("tenant",)) \
+                .labels(tenant=tenant.name).observe(ttft_ms)
+        return ttft_ms
+
+    def complete_record(self, record: dict, stream: _Stream) -> dict:
+        """The backend's record (engine or router) with the gateway's own
+        fields: what the ``done`` event and the JSON reply carry."""
+        if record.get("ttft_ms") is None:
+            # backends that don't stamp timestamps (or use a different
+            # timebase) still report the gateway-observed TTFT, read on
+            # the step thread
+            since = stream.since_submit_ms()
+            if since is not None:
+                record["ttft_ms"] = round(since, 3)
+        record.update(stream.record_fields())
+        return record
 
 
 def _sse(event: str, data: dict) -> bytes:
     return (f"event: {event}\ndata: {json.dumps(data, sort_keys=True)}"
             f"\n\n").encode("utf-8")
+
+
+def _token_frame(request_id: str) -> bytes:
+    """The bytes of one request's token events with the two numbers left
+    open (``frame % (index, token)``): what ``_sse("token", {"index": i,
+    "request_id": id, "token": t})`` builds, without a ``json.dumps`` a
+    token on a handler thread (it pays for the stamps a token several
+    times over)."""
+    rid = json.dumps(request_id).replace("%", "%%")
+    return (f'event: token\ndata: {{"index": %d, "request_id": {rid}, '
+            f'"token": %d}}\n\n').encode("utf-8")
 
 
 class _Handler(BaseHTTPRequestHandler):
@@ -471,6 +615,12 @@ class _Handler(BaseHTTPRequestHandler):
     @property
     def gw(self) -> ServingGateway:
         return self.server.gateway
+
+    def setup(self):
+        # the request's first stamp: the connection is accepted and its
+        # thread runs; request line, headers and body are still unread
+        self.accept_ts = self.server.gateway.clock()
+        super().setup()
 
     def _json(self, status: int, payload: dict, headers=()):
         body = json.dumps(payload, sort_keys=True).encode("utf-8")
@@ -526,6 +676,23 @@ class _Handler(BaseHTTPRequestHandler):
                                        "reason": "not_found"}})
             return
         gw = self.gw
+        # a handler thread: everything up to the engine having the
+        # request contends with the pump for the interpreter lock (a
+        # refusal's reply is written inside the bracket too)
+        with gw._bracket("ingress"):
+            admitted = self._ingress(gw)
+        if admitted is None:
+            return
+        tenant, body, handle, stream, trace = admitted
+        if body.get("stream", True):
+            self._stream_sse(gw, tenant, handle, stream, trace)
+        else:
+            self._respond_json(gw, tenant, handle, stream, trace)
+
+    def _ingress(self, gw: ServingGateway):
+        """Authenticate, read, parse, validate and admit one request:
+        ``(tenant, body, handle, stream, trace)``, or None after the
+        refusal was answered."""
         api_key = self._api_key()
         tenant, err = gw.authenticate(api_key)
         label = tenant.name if tenant is not None else "unknown"
@@ -536,38 +703,34 @@ class _Handler(BaseHTTPRequestHandler):
         if length <= 0:
             gw._reject(label, "bad_request", 400)
             self._error(400, "bad_request", label)
-            return
+            return None
         if length > gw.config.max_body_bytes:
             gw._reject(label, "too_large", 413)
             self._error(413, "too_large", label)
-            return
+            return None
         if tenant is None:
             status = _REASON_STATUS[err]
             gw._reject(label, err, status)
             self._error(status, err, label)
-            return
+            return None
         raw = self.rfile.read(length)
         body = self._parse(raw)
         if body is None:
             gw._reject(tenant.name, "bad_request", 400)
             self._error(400, "bad_request", tenant.name)
-            return
+            return None
         samp_err = _validate_sampling(body)
         if samp_err is not None:
             gw._reject(tenant.name, samp_err, 400)
             self._error(400, samp_err, tenant.name)
-            return
-        handle, stream, trace, retry_after, reason = gw.admit(tenant, body)
+            return None
+        handle, stream, trace, retry_after, reason = gw.admit(
+            tenant, body, self.accept_ts)
         if handle is None:
             self._error(_REASON_STATUS.get(reason, 429), reason,
                         tenant.name, retry_after)
-            return
-        submit_ts = gw.clock()
-        if body.get("stream", True):
-            self._stream_sse(gw, tenant, handle, stream, trace, submit_ts)
-        else:
-            self._respond_json(gw, tenant, handle, stream, trace,
-                               submit_ts)
+            return None
+        return tenant, body, handle, stream, trace
 
     # ------------------------------------------------------------------
     def _api_key(self) -> Optional[str]:
@@ -634,67 +797,65 @@ class _Handler(BaseHTTPRequestHandler):
             pause.wait(0.005)
         return rec()
 
-    def _observe_ttft(self, gw, tenant, stream, submit_ts):
-        ttft_ms = gw.observe_ttft(tenant, stream, submit_ts)
-        if ttft_ms is not None:
-            gw._metrics.histogram("ds_gateway_ttft_ms",
-                                  labels=("tenant",)) \
-                .labels(tenant=tenant.name).observe(ttft_ms)
-        return ttft_ms
-
-    def _stream_sse(self, gw, tenant, handle, stream, trace, submit_ts):
+    def _stream_sse(self, gw, tenant, handle, stream, trace):
         self.send_response(200)
         self.send_header("Content-Type", "text/event-stream; charset=utf-8")
         self.send_header("Cache-Control", "no-store")
         self.send_header("X-Request-Id", stream.request_id)
         self.send_header("Connection", "close")
         self.end_headers()
+        clock, bracket = gw.clock, gw._bracket
+        write, flush = self.wfile.write, self.wfile.flush
+        frame = _token_frame(stream.request_id)
+        # what this loop measures stays in its locals (two clock reads
+        # and three adds a token) until delivered() stores it
         index = 0
+        first_flush = t1 = None
+        egress = egress_max = wrote = 0.0
+        outcome, reason = "ok", ""
         try:
             for item in self._pull(gw, handle, stream):
                 if item[0] == "token":
                     # a handler thread: these contend with the pump for
                     # the interpreter lock
-                    with gw._bracket("sse_write"):
-                        self.wfile.write(_sse("token", {
-                            "token": item[1], "index": index,
-                            "request_id": stream.request_id}))
-                        self.wfile.flush()
+                    with bracket("sse_write"):
+                        t0 = clock()
+                        write(frame % (index, item[1]))
+                        flush()
+                        t1 = clock()
+                    # the token's wait between the step thread and the
+                    # flushed socket, and the handler's time writing it
+                    wait = t1 - item[2]
+                    egress += wait
+                    if wait > egress_max:
+                        egress_max = wait
+                    wrote += t1 - t0
+                    if not index:
+                        first_flush = t1
                     index += 1
-                elif item[0] == "done":
-                    ttft = self._observe_ttft(gw, tenant, stream,
-                                              submit_ts)
-                    record = self._record_of(handle)
-                    if record.get("ttft_ms") is None and ttft is not None:
-                        # backends that don't stamp timestamps (or use a
-                        # different timebase) still report the gateway-
-                        # observed TTFT, read on the step thread
-                        record["ttft_ms"] = round(ttft, 3)
-                    self.wfile.write(_sse("done", record))
-                    self.wfile.flush()
-                    gw._finish(tenant, stream, "ok", ttft_ms=ttft,
-                               trace=trace)
-                    return
+                    continue
+                stream.delivered(index, first_flush, t1, egress,
+                                 egress_max, wrote)
+                if item[0] == "done":
+                    write(_sse("done", gw.complete_record(
+                        self._record_of(handle), stream)))
                 else:  # ("error", reason)
-                    reason = item[1]
-                    self.wfile.write(_sse("error", {
+                    outcome, reason = "shed", item[1]
+                    write(_sse("error", {
                         "reason": reason,
                         "request_id": stream.request_id}))
-                    self.wfile.flush()
-                    ttft = self._observe_ttft(gw, tenant, stream,
-                                              submit_ts)
-                    gw._finish(tenant, stream, "shed", reason=reason,
-                               ttft_ms=ttft, trace=trace)
-                    return
+                flush()
+                break
         except (BrokenPipeError, ConnectionError, OSError):
             # client went away mid-stream: cancel through the backend
             # seam so the slot and its KV blocks are released
             gw._request_cancel(stream.request_id, "disconnect")
-            ttft = gw.observe_ttft(tenant, stream, submit_ts)
-            gw._finish(tenant, stream, "shed", reason="disconnect",
-                       ttft_ms=ttft, trace=trace)
+            outcome, reason = "shed", "disconnect"
+        stream.delivered(index, first_flush, t1, egress, egress_max, wrote)
+        gw._finish(tenant, stream, outcome, reason=reason,
+                   ttft_ms=gw.observe_ttft(tenant, stream), trace=trace)
 
-    def _respond_json(self, gw, tenant, handle, stream, trace, submit_ts):
+    def _respond_json(self, gw, tenant, handle, stream, trace):
         tokens: List[int] = []
         outcome, reason = "ok", ""
         for item in self._pull(gw, handle, stream):
@@ -705,10 +866,8 @@ class _Handler(BaseHTTPRequestHandler):
             else:
                 outcome, reason = "shed", item[1]
                 break
-        ttft = self._observe_ttft(gw, tenant, stream, submit_ts)
-        record = self._record_of(handle)
-        if record.get("ttft_ms") is None and ttft is not None:
-            record["ttft_ms"] = round(ttft, 3)
+        ttft = gw.observe_ttft(tenant, stream, streamed=False)
+        record = gw.complete_record(self._record_of(handle), stream)
         payload = {"request_id": stream.request_id,
                    "state": "finished" if outcome == "ok" else "shed",
                    "reason": reason, "tokens": tokens, "record": record}

@@ -73,13 +73,17 @@ SPANS = (
     # client/router level: one trace per request
     "request",        # root — submit to finish/shed, across failovers
     "attempt",        # one dispatch to one replica (attrs: attempt, replica)
-    "deliver",        # tokens streamed to the client by one attempt
+    "deliver",        # tokens streamed to the client: by one attempt
+    #                   (router), or first -> last flushed token event of
+    #                   one HTTP reply (gateway; attrs: tokens,
+    #                   egress_mean_ms, egress_max_ms)
     # gateway (HTTP front door) level: one trace per sampled HTTP request
-    "gateway",        # root — request received -> response flushed
-    #                   (attrs: tenant, route, status, streamed)
-    "auth",           # API-key resolution -> tenant identity (or 401/403)
-    "quota",          # token-bucket/inflight admission decision
-    #                   (attrs: tenant, outcome, retry_after_ms)
+    "gateway",        # root — handler accepted -> response flushed
+    #                   (attrs: tenant, route, status, outcome, tokens)
+    "ingress",        # accept -> backend.submit returned, or the door
+    #                   refused (attrs: tenant, outcome)
+    "quota",          # token-bucket/inflight admission decision, inside
+    #                   ingress (attrs: tenant, outcome)
     # replica/serving-engine level
     "serve",          # one replica serving one attempt (engine-side root)
     "queue",          # submit/dispatch -> decode-slot admission
@@ -106,10 +110,7 @@ SPANS = (
     # training step level: one trace per optimizer step
     "step",           # root — first observed phase -> step boundary
     "data",           # host-side batch fetch/assembly
-    "fwd",            # forward (engines that split fwd/bwd)
-    "bwd",            # backward (engines that split fwd/bwd)
     "fwd_bwd",        # fused forward+backward(+in-graph reduce) dispatch
-    "reduce",         # gradient reduction, where host-observable
     "optimizer",      # optimizer apply dispatch
     "ckpt_io",        # checkpoint save/load IO (own trace, between steps)
 )

@@ -174,8 +174,8 @@ NAMES = {
         "gauge", "requests currently admitted through the gateway and "
                  "not yet finished, by tenant"),
     "ds_gateway_ttft_ms": (
-        "histogram", "submit -> first SSE token flushed to the client, "
-                     "by tenant (gateway-observed TTFT)"),
+        "histogram", "handler accepted -> first SSE token event flushed "
+                     "(first emit - submit for a JSON reply), by tenant"),
     "ds_gateway_tokens_total": (
         "counter", "generated tokens delivered to clients, by tenant"),
     "ds_gateway_stream_sheds_total": (

@@ -30,6 +30,6 @@ class ServingEngine:
 
     def gateway_step(self):
         # gateway near-misses: the registered kind is `gateway`, the
-        # registered span names are gateway / auth / quota
+        # registered span names are gateway / ingress / quota
         self.telemetry.emit("gatway", "request.finished", step=1)  # typo
-        self._tracer.record_span("authz", "t1", 0, 1)            # near-miss
+        self._tracer.record_span("ingres", "t1", 0, 1)           # near-miss

@@ -9,4 +9,4 @@ def make_event(kind, name, step, rank, data):
 
 
 SPANS = ("request", "queue", "decode", "draft", "verify",
-         "spec_commit", "migrate", "gateway", "auth", "quota")
+         "spec_commit", "migrate", "gateway", "ingress", "quota")

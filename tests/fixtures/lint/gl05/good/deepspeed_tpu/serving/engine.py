@@ -37,5 +37,5 @@ class ServingEngine:
         # the HTTP front door's registered kind + span names
         self.telemetry.emit("gateway", "request.finished", step=1)
         self._tracer.begin("gateway", "t1")
-        self._tracer.record_span("auth", "t1", 0, 1)
+        self._tracer.record_span("ingress", "t1", 0, 1)
         self._tracer.record_span("quota", "t1", 0, 1)

@@ -151,6 +151,12 @@ def test_shed_request_carries_none_in_the_new_fields(two_requests):
 def _annotations(run):
     """``[(name, start_ns, end_ns)]`` of the ``ds.*`` host annotations a
     real profiler session saw while ``run()`` ran."""
+    return [event[1:] for event in _thread_annotations(run)]
+
+
+def _thread_annotations(run):
+    """``[(thread, name, start_ns, end_ns)]`` of the same, ``thread`` the
+    profiler's line the annotation lies on."""
     import glob
     import os
     import tempfile
@@ -170,9 +176,10 @@ def _annotations(run):
         (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile",
                                          "*", "*.xplane.pb"))
         data = ProfileData.from_file(path)
-        return [(e.name, int(e.start_ns), int(e.start_ns + e.duration_ns))
+        return [((plane.name, i), e.name, int(e.start_ns),
+                 int(e.start_ns + e.duration_ns))
                 for plane in data.planes if plane.name.startswith("/host:")
-                for line in plane.lines for e in line.events
+                for i, line in enumerate(plane.lines) for e in line.events
                 if e.name.startswith("ds.")]
 
 

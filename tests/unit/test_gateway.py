@@ -585,11 +585,18 @@ class TestQuotaEnforcement:
             assert 'ds_gateway_requests_total{outcome="ok",' \
                    'tenant="acme"} 1' in expo
             # spam samples every request: the reject closed its root
-            # with a shed child; admitted requests carry auth+quota
+            # with a shed child; every sampled request carries ingress
+            # (accept -> the engine has it, or the refusal) + quota
             span_names = [e["name"] for e in telemetry.events
                           if e["kind"] == "span"]
             assert "gateway" in span_names and "shed" in span_names
-            assert "auth" in span_names and "quota" in span_names
+            assert "ingress" in span_names and "quota" in span_names
+            assert "auth" not in span_names
+            ingress = [e["data"] for e in telemetry.events
+                       if e["kind"] == "span" and e["name"] == "ingress"]
+            assert sorted(s["outcome"] for s in ingress) \
+                == ["ok", "ok", "rate"]
+            assert all(s["tenant"] == "spam" for s in ingress)
             shed = [e for e in telemetry.events if e["kind"] == "span"
                     and e["name"] == "shed"]
             assert shed and all(s["data"].get("tenant") == "spam"

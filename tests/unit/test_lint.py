@@ -169,7 +169,7 @@ class TestGL05:
                  if "unregistered span name" in f.message]
         names = {f.message.split("'")[1] for f in found}
         assert names == {"prefil", "dequeue", "warmup", "fwdbwd",
-                         "drafts", "commit", "migrat", "authz"}
+                         "drafts", "commit", "migrat", "ingres"}
         assert all("request, queue, decode, draft, verify, spec_commit"
                    in f.message for f in found)
 

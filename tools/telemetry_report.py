@@ -256,8 +256,7 @@ def aggregate(events: List[Dict]) -> Dict:
     }
 
 
-_STEP_PHASES = ("data", "fwd", "bwd", "fwd_bwd", "reduce", "optimizer",
-                "ckpt_io")
+_STEP_PHASES = ("data", "fwd_bwd", "optimizer", "ckpt_io")
 
 
 def _aggregate_spans(span_events: List[Dict]) -> Dict:

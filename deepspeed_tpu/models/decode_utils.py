@@ -9,7 +9,10 @@ attention). Model files must use these — a private re-implementation
 desynchronizing any one of them produces wrong positions with no error.
 """
 
+import jax
 import jax.numpy as jnp
+
+from deepspeed_tpu.ops.attention import record_dispatch
 
 
 def validate_left_padded_mask(input_ids, attention_mask):
@@ -120,3 +123,91 @@ def paged_write_slots(block_tables, positions, num_valid, block_size: int):
     valid = jnp.arange(T)[None] < num_valid[:, None]
     return jnp.where(valid, jnp.take_along_axis(block_tables, blk, axis=1),
                      0), off
+
+
+# ---------------------------------------------------------------------------
+# token lookup: one algorithm (select a row of the table a token), two
+# access patterns, chosen from how the table really lies on its device
+
+# The most tokens a call looks up by columns. Measured on a v5e on GPT-2
+# XL's table (bf16[50257, 1600], which the backend lays with the vocabulary
+# minor because 1600 is 12.5 registers; PERF.md section 6, PR 42): by rows
+# the program first copies the whole table into row-major order, 346-360 us
+# whatever the count (450 us inside the decode program); by columns a token
+# costs a chunk's read: for the decode program's 32 tokens under 40 us in
+# the kernel and 73 us in the XLA loop, which beside the tied head alone
+# read 191 us for 64 tokens, 361 us for 96 and 3.6-4.6 us a token from
+# there. Loop and copy meet near 92 tokens: 64 is the largest power of two
+# under it, and past it the one copy is the cheaper. (The kernel meets the
+# copy later, not measured past 128 tokens; a prefill is too rare in the
+# benchmark's cells to show the difference: ROADMAP S1b.)
+LOOKUP_COLUMNS_MAX_TOKENS = 64
+
+
+def vocab_is_minor(table) -> bool:
+    """Whether a ``[vocab, width]`` table, as it really lies, has the
+    vocabulary as its minor (lane) dimension: read off the array's own
+    format, on one device. Anything else answers False: a row-major
+    array, one sharded or replicated over several devices, a quantised
+    leaf (a dict the program rebuilds the table from), a tracer."""
+    layout = getattr(getattr(table, "format", None), "layout", None)
+    sharding = getattr(table, "sharding", None)
+    return (layout is not None and sharding is not None
+            and len(sharding.device_set) == 1
+            and tuple(layout.major_to_minor) == (1, 0))
+
+
+def lookup_form(table, tokens: int) -> str:
+    """``"columns"`` or ``"rows"``: how a program that looks ``tokens``
+    token ids up in ``table`` should read it (:func:`embed_lookup`)."""
+    return ("columns" if tokens <= LOOKUP_COLUMNS_MAX_TOKENS
+            and vocab_is_minor(table) else "rows")
+
+
+def lookup_columns(table, ids):
+    """``table[ids]``, bit for bit, read through the table's transposed
+    view: a token takes the lane-aligned chunk ``[width, 128]`` that holds
+    its column (the last chunk clamped to the table's edge) and selects
+    its lane. For a table that lies with the vocabulary minor the
+    transpose is a bitcast and the chunk a run of whole registers, so the
+    program holds no row-major copy of the table. The selection works on
+    the bits (a ``where`` and an integer max over zeros): an ``inf``, a
+    ``nan`` or a ``-0.0`` comes back as it went in (what a backend's own
+    slice does to a value, it does here as in ``table[ids]``: the chip
+    flushes a bfloat16 denormal in both, the CPU quiets a signalling nan
+    of a bfloat16 chunk). On the chip the Pallas
+    kernel reads the same chunks with the next one in flight
+    (``ops/embed_lookup.py``); this loop is its oracle and every other
+    backend's form."""
+    from deepspeed_tpu.ops import attention, embed_lookup as kernel
+
+    vocab, width = table.shape
+    flat = jnp.clip(ids.reshape(-1).astype(jnp.int32), 0, vocab - 1)
+    if attention.use_decode_kernel() and kernel.kernel_serves(table):
+        rows = kernel.lookup_columns_kernel(table, flat)
+        return rows.reshape(ids.shape + (width,))
+    lanes = min(kernel.LANES, vocab)
+    columns = table.T
+    bits = jnp.dtype(f"uint{8 * jnp.dtype(table.dtype).itemsize}")
+    lane = jax.lax.broadcasted_iota(jnp.int32, (width, lanes), 1)
+
+    def one(token):
+        # (the chunk is sliced before it is read as integers: the other way
+        # round the chip's compiler writes the whole table out as integers)
+        start = jnp.minimum(token // lanes * lanes, vocab - lanes)
+        chunk = jax.lax.dynamic_slice(columns, (0, start), (width, lanes))
+        chunk = jax.lax.bitcast_convert_type(chunk, bits)
+        picked = jnp.where(lane == token - start, chunk, jnp.zeros((), bits))
+        return jax.lax.bitcast_convert_type(jnp.max(picked, axis=1),
+                                            table.dtype)
+
+    return jax.lax.map(one, flat).reshape(ids.shape + (width,))
+
+
+def embed_lookup(table, ids, form: str = "rows"):
+    """The rows of ``table`` at ``ids``, by the access pattern ``form``
+    names (:func:`lookup_form`; both give the same bits). Counted at trace
+    time beside the attention paths, so ``stats()["attention_paths"]``
+    says which form each compiled program took."""
+    record_dispatch(f"embed_lookup_{form}")
+    return lookup_columns(table, ids) if form == "columns" else table[ids]

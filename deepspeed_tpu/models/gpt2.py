@@ -21,7 +21,8 @@ import numpy as np
 
 from deepspeed_tpu.models.decode_utils import (cache_attn_mask,
                                                decode_positions,
-                                               pad_lengths, paged_positions,
+                                               embed_lookup, pad_lengths,
+                                               paged_positions,
                                                paged_write_slots,
                                                row_positions)
 from deepspeed_tpu.ops.attention import attention
@@ -819,6 +820,9 @@ class GPT2LMHeadModel(nn.Module):
     """
 
     config: GPT2Config
+    # the parameter ``__call__`` looks its tokens up in: the serving engine
+    # reads this leaf's layout to choose ``paging["lookup"]``
+    lookup_table = "wte"
 
     @nn.compact
     def __call__(self, input_ids, deterministic=True, return_hidden=False,
@@ -826,7 +830,11 @@ class GPT2LMHeadModel(nn.Module):
         cfg = self.config
         B, T = input_ids.shape
         wte = self.param("wte", _dense_init(), (cfg.vocab_size, cfg.n_embd), jnp.float32)
-        x = wte[input_ids].astype(cfg.dtype)
+        # a paged serving program names the access pattern its builder
+        # chose from the table's real layout (decode_utils.lookup_form);
+        # every other caller reads rows, ``wte[input_ids]``
+        x = embed_lookup(wte, input_ids,
+                         (paging or {}).get("lookup", "rows")).astype(cfg.dtype)
         if cfg.position_embedding == "learned":
             # table carries position_offset pad rows (OPT stores 2)
             wpe = self.param("wpe", _dense_init(0.01),

@@ -194,6 +194,9 @@ class ServingEngine:
         self._routed_width = (dcfg.routed_width
                               if "return_routed" in knobs else 0)
         self._dmodule = type(self.engine.module)(dcfg)
+        # the parameter the model looks its tokens up in, where it names one
+        self._lookup_table = getattr(type(self.engine.module),
+                                     "lookup_table", None)
         self.block_mgr = BlockManager(self.num_blocks, bs,
                                       self.blocks_per_seq)
         self.prefix = (PrefixCache(self.block_mgr)
@@ -440,6 +443,23 @@ class ServingEngine:
         return self.engine.telemetry.watch_jit(
             self._jax.jit(fn, donate_argnums=self._donate(donate)), watch)
 
+    def _paging(self, ids, tables, lengths, num_valid, prefill=False):
+        """The ``paging`` argument of a model call, for every program: the
+        traced tables and lengths, the kind of call, and, where the model
+        names the table it looks its tokens up in, the access pattern
+        chosen from how that table lies on its device and from this
+        program's static token count."""
+        from deepspeed_tpu.models.decode_utils import lookup_form
+
+        paging = {"block_tables": tables, "lengths": lengths,
+                  "num_valid": num_valid, "prefill": prefill}
+        if self._lookup_table is not None:
+            # the engine's own leaf: the device array as it lies (a
+            # quantised leaf is a dict, which reads as no array)
+            paging["lookup"] = lookup_form(
+                self.engine.params.get(self._lookup_table), ids.size)
+        return paging
+
     def _sample(self, logits, rng):
         from deepspeed_tpu.inference.engine import sample_logits
 
@@ -457,9 +477,9 @@ class ServingEngine:
             def kfn(qparams, cache, ids, tables, num_valid, seeds, flags,
                     temps, top_ks, top_ps):
                 params = dequant(qparams)
-                paging = {"block_tables": tables,
-                          "lengths": jnp.zeros((ids.shape[0],), jnp.int32),
-                          "num_valid": num_valid, "prefill": True}
+                paging = self._paging(
+                    ids, tables, jnp.zeros((ids.shape[0],), jnp.int32),
+                    num_valid, prefill=True)
                 out, vars_ = dmodule.apply(
                     {"params": params, "cache": cache}, ids,
                     mutable=["cache"], paging=paging)
@@ -477,9 +497,9 @@ class ServingEngine:
 
         def fn(qparams, cache, ids, tables, num_valid, rng):
             params = dequant(qparams)
-            paging = {"block_tables": tables,
-                      "lengths": jnp.zeros((ids.shape[0],), jnp.int32),
-                      "num_valid": num_valid, "prefill": True}
+            paging = self._paging(
+                ids, tables, jnp.zeros((ids.shape[0],), jnp.int32),
+                num_valid, prefill=True)
             out, vars_ = dmodule.apply({"params": params, "cache": cache},
                                        ids, mutable=["cache"], paging=paging)
             logits = logits_of(out)
@@ -503,9 +523,8 @@ class ServingEngine:
             def kfn(qparams, cache, tokens, tables, lengths, seeds, flags,
                     temps, top_ks, top_ps):
                 params = dequant(qparams)
-                paging = {"block_tables": tables, "lengths": lengths,
-                          "num_valid": jnp.ones_like(lengths),
-                          "prefill": False}
+                paging = self._paging(tokens, tables, lengths,
+                                      jnp.ones_like(lengths))
                 out, vars_ = dmodule.apply(
                     {"params": params, "cache": cache}, tokens,
                     mutable=["cache"], paging=paging)
@@ -523,9 +542,8 @@ class ServingEngine:
 
         def fn(qparams, cache, tokens, tables, lengths, rng):
             params = dequant(qparams)
-            paging = {"block_tables": tables, "lengths": lengths,
-                      "num_valid": jnp.ones_like(lengths),
-                      "prefill": False}
+            paging = self._paging(tokens, tables, lengths,
+                                  jnp.ones_like(lengths))
             out, vars_ = dmodule.apply({"params": params, "cache": cache},
                                        tokens, mutable=["cache"],
                                        paging=paging)
@@ -583,8 +601,7 @@ class ServingEngine:
             def kfn(qparams, cache, ids, tables, lengths, num_valid,
                     seeds, flags, temps, top_ks, top_ps):
                 params = dequant(qparams)
-                paging = {"block_tables": tables, "lengths": lengths,
-                          "num_valid": num_valid, "prefill": False}
+                paging = self._paging(ids, tables, lengths, num_valid)
                 out, vars_ = dmodule.apply(
                     {"params": params, "cache": cache}, ids,
                     mutable=["cache"], paging=paging)
@@ -604,8 +621,7 @@ class ServingEngine:
 
         def fn(qparams, cache, ids, tables, lengths, num_valid, rng):
             params = dequant(qparams)
-            paging = {"block_tables": tables, "lengths": lengths,
-                      "num_valid": num_valid, "prefill": False}
+            paging = self._paging(ids, tables, lengths, num_valid)
             out, vars_ = dmodule.apply({"params": params, "cache": cache},
                                        ids, mutable=["cache"], paging=paging)
             logits = logits_of(out)
@@ -634,8 +650,7 @@ class ServingEngine:
 
         def fn(qparams, cache, tokens, tables, lengths, num_valid, rng):
             params = dequant(qparams)
-            paging = {"block_tables": tables, "lengths": lengths,
-                      "num_valid": num_valid, "prefill": False}
+            paging = self._paging(tokens, tables, lengths, num_valid)
             out, vars_ = dmodule.apply({"params": params, "cache": cache},
                                        tokens, mutable=["cache"],
                                        paging=paging)

@@ -395,6 +395,16 @@ def _results(text):
                 for d, dims in shape.findall(rest[:op.start(1)])], line)
 
 
+def _roots(text):
+    """computation name -> the opcode of its ROOT, for every computation
+    of a compiled program's text (what a ``fusion`` really is)."""
+    import re
+
+    return {m.group(1): m.group(2) for m in re.finditer(
+        r"^%?([\w.\-]+) [^\n]*\{\n(?:[^\n]*\n)*?\s*ROOT %[\w.\-]+ = "
+        r"[^\n]*?\s([a-z][\w\-]*)\(", text, re.M)}
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 @pytest.mark.parametrize("kv", ["", "int8"])
 def test_serving_programs_update_the_kv_pool_in_place(one_chip, monkeypatch,
@@ -468,9 +478,7 @@ def test_serving_programs_update_the_kv_pool_in_place(one_chip, monkeypatch,
         (name, op) for name, op, _ in touching if op not in plumbing)
     # a fusion with a pool-sized result is the in-place scatter and
     # nothing else: its computation's root is the scatter
-    roots = {m.group(1): m.group(2) for m in re.finditer(
-        r"^%?([\w.\-]+) [^\n]*\{\n(?:[^\n]*\n)*?\s*ROOT %[\w.\-]+ = "
-        r"[^\n]*?\s([a-z][\w\-]*)\(", text, re.M)}
+    roots = _roots(text)
     for name, op, line in touching:
         if op == "fusion":
             called = re.search(r"calls=%([\w.\-]+)", line).group(1)
@@ -487,6 +495,104 @@ def test_serving_programs_update_the_kv_pool_in_place(one_chip, monkeypatch,
         mem.alias_size_in_bytes, pool_bytes)
     if program == "decode":
         assert any("_paged_kv_attend" in k for k in _kernel_names(text))
+
+
+# ---------------------------------------------------------------------------
+# the token lookup reads the table as it lies
+@pytest.mark.parametrize("width,heads,program,form", [
+    (1600, 25, "decode", "columns"),
+    (1024, 16, "decode", "rows"),
+    (1600, 25, "prefill", "rows"),
+], ids=["xl-decode", "medium-decode", "xl-prefill-768"])
+def test_serving_lookup_reads_the_table_as_it_lies(one_chip, monkeypatch,
+                                                   width, heads, program,
+                                                   form):
+    """GPT-2's 50,257-row bf16 table in a serving program (two layers, 32
+    slots, pool donated). The backend lays ``[50257, 1600]`` with the
+    VOCABULARY minor (1600 is 12.5 registers of 128 lanes; the tied head
+    takes it so), and ``wte[ids]`` then costs a row-major copy of all 161
+    MB in every program. ``lookup_form`` reads the layout (here: the format
+    this compiler gives the parameter, as the serving engine reads its
+    device array's) and the token count; the decode program at XL's width
+    takes columns (the ``embed_lookup_columns`` kernel) and holds NO
+    instruction with the table's dims but the parameter and its bitcasts
+    (nor, with the XLA loop in the kernel's place, but those and the loop
+    that carries the table); at 1024 wide the
+    table lies row-major, the program takes rows and holds none either; a
+    768-token prefill at XL's width is over the crossover, takes rows and
+    pays the one copy."""
+    import re
+
+    from deepspeed_tpu.models.decode_utils import lookup_form
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
+    from deepspeed_tpu.ops import attention as ops_attention
+
+    monkeypatch.setattr(ops_attention, "use_decode_kernel", lambda: True)
+    vocab, layers, slots, blocks, bs, per_seq = 50257, 2, 32, 129, 32, 32
+    cfg = GPT2Config(vocab_size=vocab, n_positions=1024, n_embd=width,
+                     n_layer=layers, n_head=heads, dtype=jnp.bfloat16)
+    module = GPT2LMHeadModel(cfg.for_paged_decode(blocks, bs))
+    shapes = jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
+        paging={"block_tables": jnp.zeros((1, per_seq), jnp.int32),
+                "lengths": jnp.zeros((1,), jnp.int32),
+                "num_valid": jnp.ones((1,), jnp.int32), "prefill": False}))
+    n, t = (slots, 1) if program == "decode" else (1, 768)
+
+    # the layout the chip gives a parameter of the table's shape
+    table = _s(one_chip, (vocab, width))
+    lies = jax.jit(lambda w: w).lower(table).compile().input_formats[0][0]
+    vocab_minor = tuple(lies.layout.major_to_minor) == (1, 0)
+    assert vocab_minor == (width == 1600), lies
+    chosen = lookup_form(jax.ShapeDtypeStruct(
+        table.shape, table.dtype, sharding=lies), n * t)
+    assert chosen == form
+
+    def fn(params, cache, ids, tables, lengths, num_valid):
+        pg = {"block_tables": tables, "lengths": lengths,
+              "num_valid": num_valid, "prefill": program == "prefill",
+              "lookup": chosen}
+        out, vars_ = module.apply({"params": params, "cache": cache}, ids,
+                                  mutable=["cache"], paging=pg)
+        return jnp.argmax(out[:, -1], axis=-1), vars_["cache"]
+
+    put = lambda tree, dtype=None: jax.tree_util.tree_map(  # noqa: E731
+        lambda s: _s(one_chip, s.shape, dtype or s.dtype), tree)
+    before = ops_attention.dispatch_counts().get(f"embed_lookup_{form}", 0)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        put(shapes["params"], jnp.bfloat16), put(shapes["cache"]),
+        _s(one_chip, (n, t), jnp.int32),
+        _s(one_chip, (n, per_seq), jnp.int32),
+        _s(one_chip, (n,), jnp.int32),
+        _s(one_chip, (n,), jnp.int32)).compile()
+    assert ops_attention.dispatch_counts()[f"embed_lookup_{form}"] == \
+        before + 1
+    # the program takes the table in the layout the choice assumed
+    assert compiled.input_formats[0][0]["wte"].layout == lies.layout
+
+    text = compiled.as_text()
+    table_dims = {(vocab, width), (width, vocab)}
+    touching = [(name, op, line) for name, op, res, line in _results(text)
+                if any(dims in table_dims for _, dims in res)]
+    assert any(op == "parameter" for _, op, _ in touching)
+    roots = _roots(text)
+    moved = []
+    for name, op, line in touching:
+        if op == "fusion":
+            op = roots.get(re.search(r"calls=%([\w.\-]+)", line).group(1))
+        # (``copy-start`` / ``copy-done``: the prefetch of a table under the
+        # chip's 128 MiB of fast memory, whole and in the layout it has; the
+        # 103 MB table of the 1024-wide model gets one)
+        if op not in ("parameter", "bitcast", "get-tuple-element", "tuple",
+                      "while", "copy-start", "copy-done"):
+            moved.append((name, op))
+    if program == "decode":
+        assert not moved, moved
+    else:
+        assert [op for _, op in moved] == ["copy"], moved
+    # columns on the chip is the Pallas kernel (ops/embed_lookup.py)
+    assert any("embed_lookup_columns" in k for k in _kernel_names(text)) \
+        == (form == "columns")
 
 
 # ---------------------------------------------------------------------------

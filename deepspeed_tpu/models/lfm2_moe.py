@@ -1,0 +1,477 @@
+"""The LFM2-MoE family: gated short convolutions beside grouped-query
+attention, over a dense or a sparse FFN.
+
+Layer ``i``, pre-norm, RMSNorm with a learned weight, no bias anywhere:
+``h = x + Op_i(norm x)``, ``out = h + FFN_i(norm h)``.
+
+- ``layer_types[i] == "conv"``: ``[B | C | X] = u W_in``; ``z = B * X``;
+  ``c_t = sum_j w[:, j] z_{t-(L-1)+j}`` (depthwise, causal, ``L =
+  conv_L_cache`` taps, zeros before the sequence's start); ``Op(u) = (C *
+  c) W_out``. All a sequence keeps of its past in such a layer is the last
+  ``L - 1`` values of ``z``: a state of FIXED SIZE, whatever its length.
+- ``"full_attention"``: grouped-query heads of ``hidden / heads``, RMSNorm
+  over every query and key head BEFORE RoPE (all dims, half-rotation).
+- FFN: SwiGLU for ``i < num_dense_layers``, else dropless sigmoid top-k
+  routing (``moe/dropless.py``, every expert held; ``models/mimo_v2.py``'s
+  ``SparseExperts`` with this family's ``+ 1e-6`` and scaling factor).
+- a final norm; the head is the embedding, tied.
+
+SERVING. ``for_paged_decode`` gives the module the attention layers' KV
+pools, addressed through the sequence's block table as every model's are,
+and ONE MORE pool, ``conv_state_pool [conv layers, 1 + slots, L - 1,
+hidden]``: row ``1 + s`` is decode slot ``s``'s state in every
+convolution layer, row 0 what idle rows write. The engine hands each row
+of a program its slot's state row as the table's last entry, through the
+seam that hands MiMo-V2 a slot's ring (``paged_slot_state_for``,
+``serving/engine.py``): state of fixed size a slot, written in place every
+step. One function, :meth:`ShortConv.__call__`, serves the whole-prompt
+prefill, a prefill chunk and a decode step: a sequence at length 0 starts
+from zeros whatever its slot held, and the new state is taken at each
+row's ``num_valid``, never at a bucket's end.
+"""
+
+import dataclasses
+from typing import Any, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.models.decode_utils import (embed_lookup, paged_positions,
+                                               paged_write_slots)
+from deepspeed_tpu.models.llama import (LlamaMLP, RMSNorm, apply_rope,
+                                        rope_frequencies)
+from deepspeed_tpu.models.mimo_v2 import (SparseExperts, causal_gqa,
+                                          masked_gqa)
+from deepspeed_tpu.moe import dropless
+
+
+@dataclasses.dataclass(frozen=True)
+class Lfm2MoeConfig:
+    vocab_size: int = 65536
+    hidden_size: int = 2048
+    num_hidden_layers: int = 24
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 8
+    layer_types: Tuple[str, ...] = ()     # "conv" | "full_attention"
+    num_dense_layers: int = 2
+    intermediate_size: int = 7168
+    moe_intermediate_size: int = 1792
+    num_experts: int = 32
+    num_experts_per_tok: int = 4
+    routed_scaling_factor: float = 1.0
+    # the top-k normalisation's ``+ eps`` in the denominator
+    route_norm_eps: float = 1e-6
+    conv_L_cache: int = 3
+    norm_eps: float = 1e-5
+    rope_theta: float = 1e6
+    max_position_embeddings: int = 128000
+    # initialisation scales. Training moves all of them; a caller that
+    # wants every operator visible to a comparison on random weights
+    # (perfbench: a convolution's term is a product of THREE projections,
+    # and vanishes at 0.02) sets them
+    conv_in_std: float = 0.02
+    conv_tap_std: float = 0.02
+    conv_out_std: float = 0.02
+    expert_bias_std: float = 0.0
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    # serving (for_paged_decode)
+    decode: bool = False
+    paged: bool = False
+    paged_num_blocks: int = 0
+    paged_block_size: int = 0
+    paged_state_slots: int = 0
+    paged_return_routed: bool = False
+
+    def __post_init__(self):
+        n = self.num_hidden_layers
+        kinds = set(self.layer_types) - {"conv", "full_attention"}
+        if len(self.layer_types) != n or kinds:
+            raise ValueError(
+                f"layer_types needs one of 'conv' / 'full_attention' a "
+                f"layer ({n}), got {self.layer_types}")
+        if self.hidden_size % self.num_attention_heads or (
+                self.num_attention_heads % self.num_key_value_heads):
+            raise ValueError(
+                f"{self.num_attention_heads} heads over hidden "
+                f"{self.hidden_size} and {self.num_key_value_heads} KV heads")
+
+    # what the generic serving code asks of a model's config
+    @property
+    def n_head(self) -> int:
+        return self.num_attention_heads
+
+    @property
+    def head_dim(self) -> int:
+        return self.hidden_size // self.num_attention_heads
+
+    # what ``mimo_v2.SparseExperts`` asks of one: every expert held
+    @property
+    def n_routed_experts(self) -> int:
+        return self.num_experts
+
+    ep_rank, ep_size = 0, 1
+
+    @property
+    def selection_bias_std(self) -> float:
+        return self.expert_bias_std
+
+    @property
+    def sparse_layers(self) -> int:
+        return self.num_hidden_layers - min(self.num_dense_layers,
+                                            self.num_hidden_layers)
+
+    @property
+    def routed_width(self) -> int:
+        """Experts a token chooses over all its sparse layers: the width
+        of a row of what ``paged_return_routed`` returns."""
+        return self.sparse_layers * self.num_experts_per_tok
+
+    def layers_of(self, kind: str):
+        """Indices of the layers of one kind, in order: a layer's place in
+        its kind's pool is its place here."""
+        return [i for i, k in enumerate(self.layer_types) if k == kind]
+
+    def kv_bytes_per_token(self) -> dict:
+        """Bytes of keys and values one token keeps (attention layers)."""
+        item = jnp.dtype(self.dtype).itemsize
+        return {"global": len(self.layers_of("full_attention"))
+                * self.num_key_value_heads * 2 * self.head_dim * item}
+
+    def state_bytes_per_slot(self) -> int:
+        """Bytes a decode slot's convolution state takes, all layers."""
+        return (len(self.layers_of("conv")) * (self.conv_L_cache - 1)
+                * self.hidden_size * jnp.dtype(self.dtype).itemsize)
+
+    def paged_slot_state_for(self, block_size: int):
+        """What a decode slot keeps beside its block table (the engine's
+        per-slot seam): one entry of the table, the slot's row of the
+        state pool. None without convolution layers."""
+        if not self.layers_of("conv"):
+            return None
+        return {"entries": 1, "knob": "state_slots",
+                "what": "short-convolution layers keep a state of fixed "
+                        "size a decode slot, written in place every step"}
+
+    def kv_live_bytes(self, live) -> dict:
+        """Bytes of per-sequence state a decode step reads, by kind, for
+        busy rows of the lengths ``live``: the attention layers' keys and
+        values of every token, the convolutions' state a busy slot."""
+        return {"global": int(live.sum())
+                * self.kv_bytes_per_token()["global"],
+                "state": len(live) * self.state_bytes_per_slot()}
+
+    def for_paged_decode(self, num_blocks: int, block_size: int,
+                         kv_dtype: str = "", state_slots: int = 0,
+                         return_routed: bool = False):
+        """Serving variant (see the module's docstring). ``num_blocks``
+        sizes the attention layers' pool (block 0 the garbage block);
+        ``state_slots`` decode slots get a row each in the state pool;
+        with ``return_routed`` a call also returns every token's chosen
+        experts."""
+        if kv_dtype:
+            raise ValueError(
+                f"kv_cache_dtype {kv_dtype!r}: this model's convolution "
+                "state has no quantized pool")
+        if self.layers_of("conv") and state_slots < 1:
+            raise ValueError("convolution layers keep a state a decode "
+                             "slot: for_paged_decode needs state_slots")
+        return dataclasses.replace(
+            self, decode=True, paged=True, paged_num_blocks=int(num_blocks),
+            paged_block_size=int(block_size),
+            paged_state_slots=int(state_slots),
+            paged_return_routed=bool(return_routed))
+
+    @staticmethod
+    def tiny(**kw):
+        """The CPU tests' size: every mechanism, no published width. The
+        convolutions' scales make them carry the residual stream, as they
+        do at the published widths (where the embedding is 2% of it):
+        at 0.02 a tied head would repeat its input whatever the state."""
+        base = dict(vocab_size=128, hidden_size=64, num_hidden_layers=6,
+                    num_attention_heads=8, num_key_value_heads=2,
+                    layer_types=("conv", "conv", "full_attention", "conv",
+                                 "conv", "full_attention"),
+                    num_dense_layers=2, intermediate_size=128,
+                    moe_intermediate_size=32, num_experts=32,
+                    num_experts_per_tok=4, max_position_embeddings=256,
+                    conv_in_std=0.125, conv_tap_std=0.33, conv_out_std=0.125,
+                    expert_bias_std=0.01)
+        base.update(kw)
+        return Lfm2MoeConfig(**base)
+
+
+def _init(scale=0.02):
+    return nn.initializers.normal(stddev=scale)
+
+
+def gated_inputs(u, w_in):
+    """``[B | C | X] = u W_in``: the convolution's input gate, its output
+    gate and what the two gate, each ``[B, T, C]``."""
+    return jnp.split(jnp.dot(u, w_in), 3, axis=-1)
+
+
+def short_conv(z, taps, state, num_valid):
+    """The depthwise causal convolution and the state it leaves.
+
+    ``z [B, T, C]``: this call's positions; ``taps [C, L]``, the last of
+    which meets the current position; ``state [B, L - 1, C]``: ``z`` at the
+    ``L - 1`` positions before this call's first; ``num_valid [B]``: the
+    real positions of each row (a bucket's padding lies behind them).
+    -> ``(c [B, T, C] float32, new state [B, L - 1, C])``: the state after
+    the row's LAST REAL position (the old one where it has none)."""
+    t, keep = z.shape[1], taps.shape[1] - 1
+    line = jnp.concatenate([state.astype(z.dtype), z], axis=1)
+    w = taps.astype(jnp.float32)
+    c = sum(w[None, None, :, j] * line[:, j:j + t].astype(jnp.float32)
+            for j in range(keep + 1))
+    at = num_valid[:, None] + jnp.arange(keep, dtype=jnp.int32)[None]
+    return c, jnp.take_along_axis(line, at[..., None], axis=1)
+
+
+class ShortConv(nn.Module):
+    """``(u, state, num_valid) -> (y, new state)``: the gated short
+    convolution of a whole prompt (state zero), of a prefill chunk (state
+    as stored) and of a decode step (``T = 1``) alike. Plain call: the
+    whole sequence from zeros."""
+
+    config: Lfm2MoeConfig
+
+    @nn.compact
+    def __call__(self, u, state=None, num_valid=None):
+        cfg = self.config
+        b, t, d = u.shape
+        keep = cfg.conv_L_cache - 1
+        w_in = self.param("in_proj", _init(cfg.conv_in_std), (d, 3 * d),
+                          cfg.param_dtype)
+        taps = self.param("conv", _init(cfg.conv_tap_std),
+                          (d, cfg.conv_L_cache), cfg.param_dtype)
+        gate_b, gate_c, x = gated_inputs(u, w_in.astype(cfg.dtype))
+        z = gate_b * x
+        if state is None:
+            state = jnp.zeros((b, keep, d), z.dtype)
+        if num_valid is None:
+            num_valid = jnp.full((b,), t, jnp.int32)
+        c, state = short_conv(z, taps, state, num_valid)
+        y = (gate_c.astype(jnp.float32) * c).astype(cfg.dtype)
+        out = nn.Dense(d, use_bias=False, dtype=cfg.dtype,
+                       param_dtype=cfg.param_dtype,
+                       kernel_init=_init(cfg.conv_out_std),
+                       name="out_proj")(y)
+        return out, state
+
+
+def conv_state_in(pool, index, rows, lengths):
+    """The state a paged call's rows start from, of convolution layer
+    ``index``: what their slots hold (``rows [B]`` into the pool), and
+    zeros for a sequence at length 0, whatever its slot's last tenant
+    left."""
+    held = pool[index, rows]
+    return jnp.where((lengths == 0)[:, None, None], jnp.zeros_like(held),
+                     held)
+
+
+def _paged_conv(layer, u, paging, pools, index):
+    """One convolution layer of a serving program: the slot's state in,
+    the new state written in place."""
+    from deepspeed_tpu.ops.attention import record_dispatch
+
+    t = u.shape[1]
+    form = ("prefill" if paging.get("prefill") else "decode" if t == 1
+            else "chunk")
+    record_dispatch(f"lfm2_conv_{form}")
+    pool = pools["conv_state_pool"]
+    rows = paging["block_tables"][:, -1]
+    with jax.named_scope(f"lfm2._conv_{form}"):
+        out, state = layer(
+            u, conv_state_in(pool, index, rows, paging["lengths"]),
+            paging["num_valid"])
+        pool = pool.at[index, rows].set(state.astype(pool.dtype))
+    return out, {**pools, "conv_state_pool": pool}
+
+
+class Lfm2Attention(nn.Module):
+    config: Lfm2MoeConfig
+
+    @nn.compact
+    def __call__(self, x, paging=None, pools=None, index=0, work=None):
+        cfg = self.config
+        b, t, _ = x.shape
+        heads, kv, dh = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                         cfg.head_dim)
+
+        def proj(name, width):
+            return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
+                            param_dtype=cfg.param_dtype, kernel_init=_init(),
+                            name=name)
+
+        q = proj("q_proj", heads * dh)(x).reshape(b, t, heads, dh)
+        k = proj("k_proj", kv * dh)(x).reshape(b, t, kv, dh)
+        v = proj("v_proj", kv * dh)(x).reshape(b, t, kv, dh)
+        # the norm of every query and key head, a weight a projection,
+        # BEFORE the rotation
+        q = RMSNorm(cfg.norm_eps, cfg.dtype, name="q_layernorm")(q)
+        k = RMSNorm(cfg.norm_eps, cfg.dtype, name="k_layernorm")(k)
+        paged = cfg.decode and cfg.paged
+        if paged and paging is None:
+            raise ValueError(
+                "paged decode needs the `paging` call argument: "
+                '{"block_tables", "lengths", "num_valid", "prefill"}')
+        pos = (paged_positions(paging["lengths"], t) if paged
+               else jnp.arange(t, dtype=jnp.int32)[None])
+        cos, sin = rope_frequencies(dh, pos, cfg.rope_theta)
+        if cos.shape[0] == 1:
+            cos, sin = cos[0], sin[0]
+        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+        if not paged:
+            y = causal_gqa(q, k, v)
+        else:
+            y, pools = self._paged(q, k, v, pos, paging, pools, index, work)
+        out = proj("o_proj", cfg.hidden_size)(y.reshape(b, t, heads * dh))
+        return out, pools
+
+    def _paged(self, q, k, v, pos, paging, pools, index, work):
+        """Write this step's keys and values through the block table and
+        attend: a whole prompt over its own keys, a decode step on a TPU
+        through the paged kernel (its global kind), a prompt's later chunk
+        and every step where no TPU is over the gathered blocks."""
+        from deepspeed_tpu.ops.attention import (record_dispatch,
+                                                 use_decode_kernel)
+        from deepspeed_tpu.ops.hybrid_decode_attention import (
+            decode_attention_hybrid)
+
+        cfg = self.config
+        b, t = q.shape[:2]
+        kv, bs = cfg.num_key_value_heads, cfg.paged_block_size
+        table = paging["block_tables"][:, :-1]      # the last: the state row
+        blk, off = paged_write_slots(table, pos, paging["num_valid"], bs)
+        k_pool = pools["global_key_pool"].at[index, blk, off].set(
+            k.reshape(b, t, -1))
+        v_pool = pools["global_value_pool"].at[index, blk, off].set(
+            v.reshape(b, t, -1))
+        if paging.get("prefill"):
+            record_dispatch("lfm2_attn_prefill_xla")
+            y = causal_gqa(q, k, v)
+        elif t == 1 and use_decode_kernel():
+            record_dispatch("lfm2_attn_decode_kernel")
+            with jax.named_scope("attn._hybrid_kv_attend"):
+                y = decode_attention_hybrid(
+                    q, k_pool, v_pool, table, paging["lengths"], index,
+                    kv_heads=kv, work=work)
+        else:
+            record_dispatch("lfm2_attn_cached_xla")
+            rows = table.shape[-1] * bs
+            key_pos = jnp.broadcast_to(
+                jnp.arange(rows, dtype=jnp.int32)[None], (b, rows))
+            y = masked_gqa(
+                q, k_pool[index, table].reshape(b, rows, kv, cfg.head_dim),
+                v_pool[index, table].reshape(b, rows, kv, cfg.head_dim),
+                pos, key_pos)
+        return y, {**pools, "global_key_pool": k_pool,
+                   "global_value_pool": v_pool}
+
+
+def _paged_pools(module, cfg: Lfm2MoeConfig):
+    """The serving pools, declared once by the model: a key and a value
+    pool of the attention layers (``[layers, blocks, block_size, kv_heads
+    * head_dim]``, the engine's ``num_blocks``), and the convolution
+    layers' state pool (row 0 for idle rows, then a row a slot)."""
+    nb, bs = cfg.paged_num_blocks, cfg.paged_block_size
+    if nb <= 1 or bs <= 0:
+        raise ValueError(f"paged decode needs paged_num_blocks > 1 (got "
+                         f"{nb}) and paged_block_size > 0 (got {bs})")
+    shapes = {}
+    attn, conv = (len(cfg.layers_of(k)) for k in ("full_attention", "conv"))
+    if attn:
+        row = (attn, nb, bs, cfg.num_key_value_heads * cfg.head_dim)
+        shapes["global_key_pool"] = shapes["global_value_pool"] = row
+    if conv:
+        shapes["conv_state_pool"] = (conv, 1 + cfg.paged_state_slots,
+                                     cfg.conv_L_cache - 1, cfg.hidden_size)
+    return {name: module.variable("cache", name, jnp.zeros, shape, cfg.dtype)
+            for name, shape in shapes.items()}
+
+
+class Lfm2MoeForCausalLM(nn.Module):
+    """Embedding -> the layers -> final RMSNorm -> the embedding as head.
+    Plain call: ``[B, T, vocab]`` float32 logits. Paged (serving) call:
+    ``(logits, {"counters": int32[4]})`` as ``MiMoV2ForCausalLM``'s, with
+    ``"routed"`` under ``paged_return_routed``."""
+
+    config: Lfm2MoeConfig
+    serve_counters = dropless.COUNTERS
+    serve_routed = True
+    # the engine reads this leaf's layout to choose ``paging["lookup"]``
+    lookup_table = "embed_tokens"
+
+    @nn.compact
+    def __call__(self, input_ids, deterministic=True, paging=None):
+        cfg = self.config
+        paged = cfg.decode and cfg.paged
+        embed = self.param("embed_tokens", _init(),
+                           (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
+        x = embed_lookup(embed, input_ids, (paging or {}).get(
+            "lookup", "rows")).astype(cfg.dtype)
+        t = input_ids.shape[1]
+        pools = valid = work = None
+        if paged:
+            variables = _paged_pools(self, cfg)
+            pools = {name: var.value for name, var in variables.items()}
+            tables, lengths = paging["block_tables"], paging["lengths"]
+            # a bucket's padding and an idle slot's row are no tokens: they
+            # route nowhere
+            valid = ((jnp.arange(t)[None] < paging["num_valid"][:, None])
+                     & (tables[:, :1] != 0))
+            if t == 1 and not paging.get("prefill"):
+                from deepspeed_tpu.ops.attention import use_decode_kernel
+                from deepspeed_tpu.ops.hybrid_decode_attention import (
+                    hybrid_work_list)
+
+                if use_decode_kernel():
+                    # the kernel's grid follows this step's lengths, the
+                    # same for every attention layer: made once
+                    work = hybrid_work_list(lengths, cfg.paged_block_size,
+                                            tables.shape[-1] - 1)
+        place = {i: n for kind in ("conv", "full_attention")
+                 for n, i in enumerate(cfg.layers_of(kind))}
+        counters = jnp.zeros((len(dropless.COUNTERS),), jnp.int32)
+        routed = []
+        # the residual stream and every norm are float32, as MiMo-V2's are
+        # (models/mimo_v2.py says why); what a matmul reads is cfg.dtype
+        x = x.astype(jnp.float32)
+        norm = lambda name: RMSNorm(cfg.norm_eps, jnp.float32, name=name)
+        for i, kind in enumerate(cfg.layer_types):
+            scope = f"layers_{i}"
+            u = norm(f"{scope}_operator_norm")(x).astype(cfg.dtype)
+            if kind == "conv":
+                layer = ShortConv(cfg, name=f"{scope}_conv")
+                if paged:
+                    a, pools = _paged_conv(layer, u, paging, pools, place[i])
+                else:
+                    a, _ = layer(u)
+            else:
+                a, pools = Lfm2Attention(cfg, name=f"{scope}_attn")(
+                    u, paging, pools, place[i], work)
+            x = x + a.astype(jnp.float32)
+            h = norm(f"{scope}_ffn_norm")(x)
+            if i >= cfg.num_dense_layers:
+                y, c, chosen = SparseExperts(cfg, name=f"{scope}_mlp")(
+                    h, valid)
+                counters = counters + c
+                routed.append(chosen)
+            else:
+                y = LlamaMLP(cfg, name=f"{scope}_mlp")(h.astype(cfg.dtype))
+            x = x + y.astype(jnp.float32)
+        if paged:
+            for name, var in variables.items():
+                var.value = pools[name]
+        x = norm("norm")(x).astype(cfg.dtype)
+        logits = jnp.einsum("btc,vc->btv", x, embed.astype(cfg.dtype),
+                            preferred_element_type=jnp.float32)
+        if not paged:
+            return logits
+        aux = {"counters": counters}
+        if cfg.paged_return_routed and routed:
+            aux["routed"] = jnp.concatenate(routed, axis=-1)
+        return logits, aux

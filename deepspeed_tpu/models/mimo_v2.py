@@ -36,6 +36,7 @@ from typing import Any, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from deepspeed_tpu.models.decode_utils import (paged_positions,
                                                paged_write_slots)
@@ -134,6 +135,28 @@ class MiMoV2Config:
         if not self.layers_of(True):
             return 0
         return -(-self.sliding_window // block_size) + 1
+
+    def paged_slot_state_for(self, block_size: int):
+        """What a decode slot keeps beside its block table (the engine's
+        per-slot seam, ``serving/engine.py``): its ring, ``entries``
+        blocks of the window pool. None without window layers."""
+        ring = self.paged_ring_blocks_for(block_size)
+        if not ring:
+            return None
+        return {"entries": ring, "knob": "ring_slots",
+                "what": "sliding-window layers keep their keys and values "
+                        "in a ring a decode slot"}
+
+    def kv_live_bytes(self, live) -> dict:
+        """Bytes of keys and values a decode step reads, by kind of layer,
+        for busy rows of the lengths ``live``: a global layer every token
+        of a sequence, a window layer what the slot's ring holds."""
+        per_token = self.kv_bytes_per_token()
+        held = (self.paged_ring_blocks_for(self.paged_block_size)
+                * self.paged_block_size)
+        return {"global": int(live.sum()) * per_token["global"],
+                "window": int(np.minimum(live, held).sum())
+                * per_token["window"]}
 
     def kv_bytes_per_token(self) -> dict:
         """Bytes of keys and values one token keeps, by kind of layer."""
@@ -417,8 +440,12 @@ class SparseExperts(nn.Module):
         down = self.param("down", _init(), (count, f, d), cfg.param_dtype)
         rows = x.reshape(b * t, d)
         # the gate reads the float32 norm itself, the experts its cfg.dtype
-        experts, weights = dropless.route(rows, router, bias,
-                                          cfg.num_experts_per_tok)
+        # (the two constants of the normalisation are another family's:
+        # models/lfm2_moe.py; absent, they add nothing to the program)
+        experts, weights = dropless.route(
+            rows, router, bias, cfg.num_experts_per_tok,
+            norm_eps=getattr(cfg, "route_norm_eps", 0.0),
+            scale=float(getattr(cfg, "routed_scaling_factor", 1.0)))
         rows = rows.astype(cfg.dtype)
         y, counters = dropless.expert_ffn(
             rows, experts, weights, gate.astype(cfg.dtype),

@@ -315,5 +315,32 @@ def shard_params_with_policy(params, policy, mesh, axis: str = AXIS_TP):
     shardings = jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, s if s is not None else P()),
         specs, is_leaf=lambda s: s is None or isinstance(s, P))
-    params = jax.jit(lambda p: p, out_shardings=shardings)(params)
-    return params, shardings
+    # a leaf that already lies where its sharding wants it (a tree made on
+    # the device, one chip) is taken as it lies: the same buffers under the
+    # mesh's sharding, so that the programs see one argument signature. Only
+    # the others are placed (a copy): weights of more than half a chip's
+    # memory would not fit beside a second copy of themselves
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    wanted = treedef.flatten_up_to(shardings)
+    placed = [_as_it_lies(x, s) for x, s in zip(leaves, wanted)]
+    rest = [i for i, x in enumerate(placed) if x is None]
+    if rest:
+        moved = jax.jit(lambda p: p, out_shardings=[wanted[i] for i in rest])(
+            [leaves[i] for i in rest])
+        for i, x in zip(rest, moved):
+            placed[i] = x
+    return jax.tree_util.tree_unflatten(treedef, placed), shardings
+
+
+def _as_it_lies(x, sharding):
+    """``x`` under ``sharding`` without a copy, where its buffers already
+    lie as ``sharding`` asks (the same devices, the same shards); else
+    None."""
+    import jax
+
+    if not (isinstance(x, jax.Array) and x.is_fully_addressable
+            and not x.is_deleted()
+            and x.sharding.is_equivalent_to(sharding, x.ndim)):
+        return None
+    return jax.make_array_from_single_device_arrays(
+        x.shape, sharding, [shard.data for shard in x.addressable_shards])

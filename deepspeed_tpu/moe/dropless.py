@@ -37,18 +37,27 @@ from deepspeed_tpu.utils.compat import tpu_compiler_params
 COUNTERS = ("experts_touched", "experts_held", "pairs_here", "pairs_all")
 
 
-def route(x, router_kernel, selection_bias, top_k: int):
+def route(x, router_kernel, selection_bias, top_k: int, *,
+          norm_eps: float = 0.0, scale: float = 1.0):
     """``x [T, D]`` -> ``(experts [T, k] int32, weights [T, k] float32)``:
     ``s = sigmoid(x W_r)`` over every published expert, the top ``k`` of
-    ``s + bias`` chosen, ``w = s[chosen] / sum s[chosen]``. All float32."""
+    ``s + bias`` chosen, ``w = scale * s[chosen] / (sum s[chosen] +
+    norm_eps)``. All float32. The two constants are a family's own
+    (LFM2-MoE: 1e-6 and its ``routed_scaling_factor``); at their defaults
+    they add no operation to the program."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router_kernel.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     _, experts = jax.lax.top_k(
         scores + selection_bias.astype(jnp.float32)[None], top_k)
     chosen = jnp.take_along_axis(scores, experts, axis=1)
-    return (experts.astype(jnp.int32),
-            chosen / jnp.sum(chosen, axis=1, keepdims=True))
+    total = jnp.sum(chosen, axis=1, keepdims=True)
+    if norm_eps:
+        total = total + norm_eps
+    weights = chosen / total
+    if scale != 1.0:
+        weights = weights * scale
+    return experts.astype(jnp.int32), weights
 
 
 def _counters(local, held, n_held: int, valid):
@@ -99,6 +108,19 @@ def _gmm_kernel(expert_ref, x_ref, g_ref, u_ref, d_ref, o_ref):
                           preferred_element_type=jnp.float32)
 
 
+def width_tile(f: int, tile_f: int = 512) -> int:
+    """Columns of an expert's width a grid step takes: ``tile_f`` where it
+    divides the width (or the whole of a narrower one); else the widest
+    run of whole 128-lane registers up to twice that which does (1792 =
+    2 x 896: two steps a tile of rows, each matrix's block no larger than
+    a 4096 x 512 one)."""
+    tile_f = min(tile_f, f)
+    if f % tile_f:
+        fits = [w for w in range(128, 2 * tile_f + 1, 128) if f % w == 0]
+        tile_f = max(fits, default=tile_f)
+    return tile_f
+
+
 def grouped_ffn(rows, tile_expert, live_tiles, gate, up, down, *,
                 tile_rows: int, tile_f: int = 512):
     """``rows [R, D]`` (each tile of ``tile_rows`` rows belongs to expert
@@ -108,7 +130,7 @@ def grouped_ffn(rows, tile_expert, live_tiles, gate, up, down, *,
     ``live_tiles`` tiles; rows of later tiles are not written."""
     n_rows, d = rows.shape
     n_held, _, f = gate.shape
-    tile_f = min(tile_f, f)
+    tile_f = width_tile(f, tile_f)
     if n_rows % tile_rows or f % tile_f:
         raise ValueError(f"{n_rows} rows in tiles of {tile_rows}, width "
                          f"{f} in tiles of {tile_f}")

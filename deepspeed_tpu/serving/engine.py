@@ -165,17 +165,27 @@ class ServingEngine:
             1 + self.config.decode_slots * self.blocks_per_seq)
         self.buckets = resolve_buckets(self.config.prompt_buckets,
                                        self.max_len, floor=bs)
-        # a model with sliding-window layers keeps their rows in a ring of
-        # this many blocks a decode slot (models/mimo_v2.py): the table a
-        # slot hands its programs is the sequence's blocks, then its ring
+        # state of FIXED SIZE that a decode slot keeps beside its block
+        # table, whatever the context: a ring of blocks for sliding-window
+        # layers' rows (models/mimo_v2.py), a row of the state pool for
+        # short-convolution layers (models/lfm2_moe.py). The model says
+        # what it is (``paged_slot_state_for``): how many ``entries`` of a
+        # slot's table address it (the table a slot hands its programs is
+        # the sequence's blocks, then these), the keyword of
+        # ``for_paged_decode`` that takes the slots, and ``what`` it is,
+        # for the mechanisms that refuse it
+        state_for = getattr(mcfg, "paged_slot_state_for", None)
+        self.slot_state = (state_for(bs) if state_for else None) or None
+        self.slot_entries = (int(self.slot_state["entries"])
+                             if self.slot_state else 0)
         ring_for = getattr(mcfg, "paged_ring_blocks_for", None)
         self.ring_blocks = int(ring_for(bs)) if ring_for else 0
         # keywords omitted on purpose: a model family predating a knob
         # keeps serving exactly as before
         knobs = {}
-        if self.ring_blocks:
-            self._refuse_beside_rings()
-            knobs["ring_slots"] = self.config.decode_slots
+        if self.slot_state:
+            self._refuse_beside_slot_state()
+            knobs[self.slot_state["knob"]] = self.config.decode_slots
         if self.config.kv_cache_dtype:
             knobs["kv_dtype"] = self.config.kv_cache_dtype
         # request id -> int32 [tokens processed, sparse layers x k], the
@@ -221,8 +231,9 @@ class ServingEngine:
         # what the model itself counts in a call (a sparse model: experts
         # touched, pairs routed here and in all), handed back behind the
         # sampled tokens and summed here by the kind of program; and the
-        # bytes of keys and values live at each decode step, by kind of
-        # layer, where the model has more than one
+        # bytes of per-sequence state live at each decode step, by kind
+        # (rows a block table addresses, a ring's, a convolution's state),
+        # where the model says what its busy rows keep (``kv_live_bytes``)
         self._counter_names = tuple(getattr(type(self.engine.module),
                                             "serve_counters", ()))
         for phase in ("prefill", "decode"):
@@ -230,7 +241,10 @@ class ServingEngine:
                 self._ledger[f"{phase}.{name}"] = 0
         kv_bytes = getattr(dcfg, "kv_bytes_per_token", None)
         self._kv_bytes = kv_bytes() if kv_bytes else None
-        for kind in self._kv_bytes or ():
+        self._live_bytes = getattr(dcfg, "kv_live_bytes", None)
+        self._kv_kinds = (tuple(self._live_bytes(np.zeros((0,), np.int64)))
+                          if self._live_bytes else ())
+        for kind in self._kv_kinds:
             self._ledger[f"kv_live_bytes.{kind}"] = 0
         self._ledger_base = dict(self._ledger)
         self._ledger_published = dict(self._ledger)
@@ -251,7 +265,7 @@ class ServingEngine:
         self.cache = self._init_cache()
         self._tables = np.full(
             (self.config.decode_slots,
-             self.blocks_per_seq + self.ring_blocks), 0, np.int32)
+             self.blocks_per_seq + self.slot_entries), 0, np.int32)
         self._last_tokens = np.zeros((self.config.decode_slots,), np.int32)
         self._lengths = np.zeros((self.config.decode_slots,), np.int32)
         self._prefill_fns: Dict[int, object] = {}
@@ -349,7 +363,7 @@ class ServingEngine:
         from deepspeed_tpu.module_inject.policies import decode_cache_specs
 
         pg = {"block_tables": jnp.zeros(
-                  (1, self.blocks_per_seq + self.ring_blocks), jnp.int32),
+                  (1, self.blocks_per_seq + self.slot_entries), jnp.int32),
               "lengths": jnp.zeros((1,), jnp.int32),
               "num_valid": jnp.zeros((1,), jnp.int32), "prefill": True}
         shapes = jax.eval_shape(
@@ -362,13 +376,25 @@ class ServingEngine:
             lambda s, sh: jax.device_put(jnp.zeros(s.shape, s.dtype), sh),
             shapes["cache"], shardings)
 
-    def _refuse_beside_rings(self):
-        """A model whose window layers live in per-slot rings has two
-        kinds of KV row, and these mechanisms know one: each refuses the
-        model here, by name, until it is taught the second."""
+    def _beside_slot_state(self, mechanism: str) -> str:
+        """Why ``mechanism`` cannot serve this model: it moves, shares or
+        rewrites rows that a block table addresses, and knows nothing of
+        the state a slot keeps beside the table (a ring, a convolution's
+        state: the model's own words)."""
+        return (f"{mechanism} cannot serve "
+                f"{type(self.engine.module).__name__}: its "
+                f"{self.slot_state['what']}, beside the block table, and "
+                f"{mechanism} handles only the rows a block table "
+                "addresses")
+
+    def _refuse_beside_slot_state(self):
+        """A model that keeps state a decode slot beside the block table
+        (``self.slot_state``) has a second kind of per-sequence state, and
+        these mechanisms know one: each refuses the model here, by name,
+        until it is taught the second. (Live migration refuses at its
+        calls: ``export_sequence`` / ``import_sequence``.)"""
         from deepspeed_tpu.runtime.config import DeepSpeedConfigError
 
-        model = type(self.engine.module).__name__
         asked = {
             "serving.prefix_cache": self.config.prefix_cache,
             "serving.speculative": self.config.speculative is not None
@@ -378,22 +404,20 @@ class ServingEngine:
         }
         for mechanism, on in asked.items():
             if on:
-                raise DeepSpeedConfigError(
-                    f"{mechanism} cannot serve {model}: its sliding-window "
-                    "layers keep their keys and values in a ring a decode "
-                    "slot, beside the block tables of its global layers, "
-                    f"and {mechanism} handles one kind of KV row")
+                raise DeepSpeedConfigError(self._beside_slot_state(mechanism))
 
     def _slot_table(self, slot: int, table: np.ndarray) -> np.ndarray:
         """The table a slot's programs get: the sequence's blocks and,
-        for a model with window layers, the slot's own ring (ring ``s``
-        is blocks ``1 + s * ring .. `` of the window pool; block 0 is its
-        garbage block)."""
-        if not self.ring_blocks:
+        for a model that keeps state a slot, the slot's own entries of
+        that state's pool: slot ``s`` has ``1 + s * entries ..`` (a ring's
+        blocks of the window pool; the one row of the convolutions' state
+        pool), 0 being the pool's garbage block or row, which an idle
+        row's zeroed table names."""
+        if not self.slot_entries:
             return table
-        ring = 1 + slot * self.ring_blocks + np.arange(
-            self.ring_blocks, dtype=np.int32)
-        return np.concatenate([table.astype(np.int32), ring])
+        own = 1 + slot * self.slot_entries + np.arange(
+            self.slot_entries, dtype=np.int32)
+        return np.concatenate([table.astype(np.int32), own])
 
     def _with_counters(self, tok, out):
         """The sampled tokens and, behind them in the same array, what the
@@ -1116,15 +1140,14 @@ class ServingEngine:
         self._step_count += 1
         self._busy = active
         self._ledger["busy_slot_steps"] += active
-        if self._kv_bytes:
-            # what the step just run had to read: a global layer every
-            # token of a sequence, a window layer what its ring holds
-            live = lengths[lengths > 0].astype(np.int64)
-            held = self.ring_blocks * self.config.block_size
-            self._ledger["kv_live_bytes.global"] += int(
-                live.sum()) * self._kv_bytes["global"]
-            self._ledger["kv_live_bytes.window"] += int(
-                np.minimum(live, held).sum()) * self._kv_bytes["window"]
+        if self._kv_kinds:
+            # what the step just run had to read, by the model's own
+            # arithmetic on its busy rows' lengths: a layer that pages its
+            # rows every token of a sequence, a window layer what its ring
+            # holds, a convolution's state its fixed size a busy slot
+            live = self._live_bytes(lengths[lengths > 0].astype(np.int64))
+            for kind, nbytes in live.items():
+                self._ledger[f"kv_live_bytes.{kind}"] += int(nbytes)
         self.telemetry.on_step_boundary(self._step_count, samples=active)
         # per-step load gauges on the event stream: the router's health
         # signals come from here, not from private scheduler state
@@ -1436,7 +1459,7 @@ class ServingEngine:
         when the request is not migratable (unknown, queued, or still
         mid-prefill — those replay/resubmit cheaply)."""
         raise_if("serving.migration.export", detail=request_id)
-        self._no_migration_beside_rings("export_sequence")
+        self._no_migration_beside_slot_state("export_sequence")
         self._late.extend(self._flush())
         req = next((r for _, r in self.sched.running()
                     if r.request_id == request_id), None)
@@ -1524,7 +1547,7 @@ class ServingEngine:
         this call allocated and leaves the scheduler untouched."""
         if export is None:
             return None
-        self._no_migration_beside_rings("import_sequence")
+        self._no_migration_beside_slot_state("import_sequence")
         self._late.extend(self._flush())
         rid = request_id or export["request_id"]
         samp = export.get("sampling")
@@ -1618,13 +1641,10 @@ class ServingEngine:
                             length=req.length)
         return req
 
-    def _no_migration_beside_rings(self, call: str):
-        if self.ring_blocks:
-            raise NotImplementedError(
-                f"live KV migration ({call}) cannot move a sequence of "
-                f"{type(self.engine.module).__name__}: its window layers' "
-                "rows live in the slot's ring, which the export does not "
-                "carry")
+    def _no_migration_beside_slot_state(self, call: str):
+        if self.slot_state:
+            raise NotImplementedError(self._beside_slot_state(
+                f"live KV migration ({call})"))
 
     def migrate_out(self, request_id: str) -> bool:
         """Detach a migrated-away request from this replica: free its
@@ -1775,7 +1795,7 @@ class ServingEngine:
             "kv_live_bytes": {
                 kind: led[f"kv_live_bytes.{kind}"]
                 - base[f"kv_live_bytes.{kind}"]
-                for kind in self._kv_bytes or ()},
+                for kind in self._kv_kinds},
             "attention_paths": dispatch_counts(),
             "prefix_cache": prefix_stats,
             "speculative": spec_stats,

@@ -755,3 +755,55 @@ def test_grouped_expert_kernel_at_the_published_widths(one_chip, tokens):
         _s(one_chip, (16, 2048, 4096)))
     calls = _custom_calls(text)
     assert len(calls) == 1 and pattern.search(calls[0]), calls
+
+
+# LFM2-8B-A1B at its published widths: 32 query heads over 8 KV heads of 64
+# (keys and values alike: a 512-lane pool row), no window layer; 32 held
+# experts of 3 x 2048 x 1792; the benchmark cell's 64 decode slots of 80
+# blocks of 32
+def test_hybrid_decode_kernel_at_lfm2s_widths(one_chip):
+    """The GQA paged kernel's global kind compiles for the v5e with four
+    query heads a KV head and rows of 512 lanes, under the name the
+    benchmark's reader matches."""
+    from deepspeed_tpu.ops.hybrid_decode_attention import (
+        decode_attention_hybrid)
+
+    pattern = _reader_pattern("hybrid_decode_roofline_share")
+    slots, bs, blocks = 64, 32, 1 + 64 * 80
+
+    def step(q, k, v, tables, lengths):
+        with jax.named_scope("attn._hybrid_kv_attend"):
+            return decode_attention_hybrid(q, k, v, tables, lengths, 2,
+                                           kv_heads=8)
+
+    text = _compiled_text(
+        step, _s(one_chip, (slots, 1, 32, 64)),
+        _s(one_chip, (3, blocks, bs, 512)), _s(one_chip, (3, blocks, bs, 512)),
+        _s(one_chip, (slots, 80), jnp.int32),
+        _s(one_chip, (slots,), jnp.int32))
+    calls = _custom_calls(text)
+    assert calls and all(pattern.search(ln) for ln in calls), calls
+
+
+@pytest.mark.parametrize("tokens", [64, 2048], ids=["decode", "prefill"])
+def test_grouped_expert_kernel_at_lfm2s_widths(one_chip, tokens):
+    """The dropless grouped matmul over 32 held experts of width 1792,
+    which the 512-column tile does not divide (``dropless.width_tile``:
+    two tiles of 896), at a decode step's 64 rows and the longest prompt
+    bucket's 2,048."""
+    from deepspeed_tpu.moe.dropless import expert_ffn
+
+    pattern = _reader_pattern("expert_matmul_roofline_share")
+
+    def layer(x, experts, weights, gate, up, down):
+        return expert_ffn(x, experts, weights, gate, up, down,
+                          first_expert=0, n_routed=32, use_kernel=True)
+
+    text = _compiled_text(
+        layer, _s(one_chip, (tokens, 2048)),
+        _s(one_chip, (tokens, 4), jnp.int32),
+        _s(one_chip, (tokens, 4), jnp.float32),
+        _s(one_chip, (32, 2048, 1792)), _s(one_chip, (32, 2048, 1792)),
+        _s(one_chip, (32, 1792, 2048)))
+    calls = _custom_calls(text)
+    assert len(calls) == 1 and pattern.search(calls[0]), calls

@@ -97,9 +97,9 @@ def lower_precision(part: str):
     elif part == "gate":
         plain_route = dropless.route
 
-        def route(x, router_kernel, selection_bias, top_k):
+        def route(x, router_kernel, selection_bias, top_k, **kw):
             return plain_route(x.astype(jnp.bfloat16), router_kernel,
-                               selection_bias, top_k)
+                               selection_bias, top_k, **kw)
 
         dropless.route = route
     else:

@@ -12,8 +12,9 @@ calls, with GPT-2 125M at its published size and weights made from a seed:
 3. **serve**   ``init_inference`` -> ``ServingEngine`` behind a
    ``ServingGateway`` on a loopback port; staggered greedy requests of
    mixed prompt lengths. Every request completes, tokens equal
-   ``engine.generate()``, the paged decode kernel is in the decode program,
-   nothing compiles after warm-up.
+   ``engine.generate()`` or part from it only at near ties (judged as the
+   tp phase judges a parting), the paged decode kernel is in the decode
+   program, nothing compiles after warm-up.
 
 ``--chips 4`` runs ONLY the multi-chip phases: ZeRO-3 over ``fsdp=4``
 against stage 0 over ``data=4``, then greedy paged decode at ``tp_size=4``
@@ -75,7 +76,9 @@ ZERO3_VS_STAGE0_ATOL = 0.05
 # must agree to a few bf16 steps of the largest |logit| (seen: 1.5 steps,
 # 0.0262 at 2.21, as the largest difference over the 50257 entries). A
 # stream that parts is then followed on from the tp=1 prefix; parting more
-# often than this in one request is no rounding matter.
+# often than this in one request is no rounding matter. The serve phase
+# holds a served stream to ``generate()``'s by the same three: the paged
+# decode path sums its softmax in another order than the append cache's.
 TP_NEAR_TIE_RTOL = 0.01
 TP_LOGITS_RTOL = 4 * 2.0 ** -7
 TP_MAX_PARTINGS = 3
@@ -335,28 +338,50 @@ def serve_phase(size: Size, seed: int, kernels: bool):
               f"decode kernel is not in it ({sorted(texts)})")
 
     # the batch-invariance contract: what continuous batching served is
-    # what generate() gives each prompt alone
-    mismatches = []
+    # what generate() gives each prompt alone, but for near ties: where a
+    # served stream parts, it is served on from generate()'s prefix and
+    # the position judged by the logits of both paths
+    max_steps = 4 * (size.new_tokens + 1)
+
+    def serve_on(prefix, n):
+        req = srv.submit(prefix, max_new_tokens=n)
+        srv.drain(max_steps)
+        check(len(req.tokens) == n, f"{len(req.tokens)} of {n} tokens "
+              f"served in {max_steps} steps")
+        return [int(t) for t in req.tokens]
+
+    partings = []
     for i, (p, o) in enumerate(zip(prompts, outs)):
         alone = srv.engine.generate(jnp.asarray(p[None]),
                                     max_new_tokens=size.new_tokens,
                                     do_sample=False)
         alone = [int(t) for t in np.asarray(alone)[0, len(p):]]
-        if alone != [int(t) for t in o["tokens"]]:
-            first = next(j for j, (a, b) in
-                         enumerate(zip(alone, o["tokens"])) if a != b)
-            mismatches.append((i, first))
+        def after(n, p=p, alone=alone):
+            return np.concatenate([p, np.asarray(alone[:n], np.int32)])
+
+        for pos, served in follow_stream(
+                alone, [int(t) for t in o["tokens"]],
+                lambda n: serve_on(after(n), size.new_tokens - n),
+                names=("generate()", "served")):
+            partings.append({
+                "request": i, "position": pos,
+                "generate_token": alone[pos], "served_token": served,
+                **judge_parting(
+                    np.asarray(srv.engine.forward_last(
+                        jnp.asarray(after(pos)[None])), np.float32)[0],
+                    paged_last_logits(srv.engine, cfg, after(pos),
+                                      size.serving["block_size"]),
+                    alone[pos], served, names=("generate()", "served"))})
     srv.destroy()
-    check(not mismatches,
-          "served tokens differ from generate() (request, first differing "
-          f"token): {mismatches}")
 
     ttft = [o["record"]["ttft_ms"] for o in outs]
     rate = [o["record"]["tokens_per_sec"] for o in outs]
     say_numbers("serve", requests=len(outs),
         prompt_lens=[len(p) for p in prompts], new_tokens=size.new_tokens,
         decode_slots=size.serving["decode_slots"],
-        tokens_equal_generate=True, paged_kernel_in_program=bool(kernels),
+        tokens_equal_generate=not partings, partings=partings,
+        near_tie_rtol=TP_NEAR_TIE_RTOL, logits_rtol=TP_LOGITS_RTOL,
+        paged_kernel_in_program=bool(kernels),
         compiles_after_warmup=compiles,
         warmup_secs_compile_included=round(warm_secs, 2),
         window_secs=round(window_secs, 3),
@@ -434,12 +459,51 @@ def multichip_phase(size: Size, seed: int, chips: int):
         stage3_step_ms=round(1e3 * float(np.median(s3[1:])), 2))
 
 
-def follow_stream(want, got, serve_from):
-    """Hold the tp stream ``got`` to the tp=1 stream ``want`` over its
-    whole length. Where they part, note the position and carry on from the
-    tp=1 prefix (``serve_from(n)``: the tp engine's tokens after
-    ``want[:n]``), so that a fault after the first parting still shows.
-    Returns the positions where they parted."""
+def paged_last_logits(engine, cfg, prefix, block_size: int):
+    """The last position's logits as the serving programs compute them:
+    all but the last token of ``prefix`` prefilled into a block pool of its
+    own, then the last token one decode step through the block table (on
+    the chip, the paged kernel)."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel
+
+    n = len(prefix)
+    blocks = -(-n // block_size)
+    model = GPT2LMHeadModel(cfg.for_paged_decode(blocks + 1, block_size))
+    tables = jnp.arange(1, blocks + 1, dtype=jnp.int32)[None]
+
+    def paging(length, num_valid, prefill):
+        return {"block_tables": tables,
+                "lengths": jnp.asarray([length], jnp.int32),
+                "num_valid": jnp.asarray([num_valid], jnp.int32),
+                "prefill": prefill}
+
+    ids = jnp.asarray(prefix[None], jnp.int32)
+    params = engine._dequantize(engine.params)
+    cache = jax.tree_util.tree_map(
+        lambda leaf: jnp.zeros(leaf.shape, leaf.dtype),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), ids[:, :1],
+            paging=paging(0, 1, True)))["cache"])
+    _, filled = model.apply({"params": params, "cache": cache}, ids[:, :-1],
+                            mutable=["cache"],
+                            paging=paging(0, n - 1, True))
+    out, _ = model.apply({"params": params, "cache": filled["cache"]},
+                         ids[:, -1:], mutable=["cache"],
+                         paging=paging(n - 1, 1, False))
+    return np.asarray(engine._logits_of(out), np.float32)[0, -1]
+
+
+def follow_stream(want, got, serve_from, names=("tp=1", "tp")):
+    """Hold the stream ``got`` (the tp engine's; the served one) to the
+    reference stream ``want`` (tp=1's; ``generate()``'s) over its whole
+    length. Where they part, note the position and carry on from the
+    reference's prefix (``serve_from(n)``: the tokens of the engine under
+    test after ``want[:n]``), so that a fault after the first parting
+    still shows. Returns the positions where they parted."""
+    ref, ours = names
     parted, done = [], 0
     while True:
         j = next((k for k, (a, b) in enumerate(zip(want[done:], got))
@@ -450,7 +514,8 @@ def follow_stream(want, got, serve_from):
             return parted
         parted.append((done + j, got[j]))
         check(len(parted) <= TP_MAX_PARTINGS,
-              f"the tp stream parts from tp=1 at {[p for p, _ in parted]}: "
+              f"the {ours} stream parts from {ref} at "
+              f"{[p for p, _ in parted]}: "
               f"more than {TP_MAX_PARTINGS} times in one request")
         done += j + 1
         if done == len(want):
@@ -458,19 +523,21 @@ def follow_stream(want, got, serve_from):
         got = serve_from(done)
 
 
-def judge_parting(logits_tp1, logits_tp, tp1_token: int, tp_token: int):
-    """One position where the streams part: a near tie by the tp=1 model's
-    own logits, and the same logits from both engines to a few bf16 steps."""
-    scale = float(np.abs(logits_tp1).max())
-    gap = abs(float(logits_tp1[tp1_token]) - float(logits_tp1[tp_token]))
-    dev = float(np.abs(logits_tp1 - logits_tp).max())
+def judge_parting(logits_ref, logits_ours, ref_token: int, our_token: int,
+                  names=("tp=1", "tp")):
+    """One position where the streams part: a near tie by the reference's
+    own logits, and the same logits from both to a few bf16 steps."""
+    ref, ours = names
+    scale = float(np.abs(logits_ref).max())
+    gap = abs(float(logits_ref[ref_token]) - float(logits_ref[our_token]))
+    dev = float(np.abs(logits_ref - logits_ours).max())
     check(gap <= TP_NEAR_TIE_RTOL * scale,
-          f"tp picked token {tp_token} where tp=1 picked {tp1_token} and "
-          f"holds them {gap:.5f} apart: no near tie (> {TP_NEAR_TIE_RTOL} "
-          f"of max |logit| {scale:.5f})")
+          f"{ours} picked token {our_token} where {ref} picked {ref_token} "
+          f"and holds them {gap:.5f} apart: no near tie (> "
+          f"{TP_NEAR_TIE_RTOL} of max |logit| {scale:.5f})")
     check(dev <= TP_LOGITS_RTOL * scale,
-          f"tp logits differ from tp=1 by {dev:.5f} (> {TP_LOGITS_RTOL} of "
-          f"max |logit| {scale:.5f})")
+          f"{ours} logits differ from {ref} by {dev:.5f} (> "
+          f"{TP_LOGITS_RTOL} of max |logit| {scale:.5f})")
     return {"logit_gap": round(gap, 6), "max_logit_diff": round(dev, 6),
             "max_abs_logit": round(scale, 5)}
 

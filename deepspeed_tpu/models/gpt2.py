@@ -895,16 +895,20 @@ class GPT2LMHeadModel(nn.Module):
                 "property, but a scanned stack compiles ONE body")
         blocks = ScanBlocks if cfg.scan_layers else LoopBlocks
         if cfg.decode and cfg.paged and paging and not paging.get("prefill"):
-            from deepspeed_tpu.ops.attention import use_decode_kernel
-            from deepspeed_tpu.ops.decode_attention import paged_work_list
+            from deepspeed_tpu.ops.attention import (record_dispatch,
+                                                     use_decode_kernel)
+            from deepspeed_tpu.ops.decode_attention import (paged_plan,
+                                                            paged_step_work)
 
             if use_decode_kernel():
                 # the paged kernel's grid follows this step's lengths and
-                # nothing a layer changes: listed once here, not once a
-                # layer inside the stack
-                paging = {**paging, "work": paged_work_list(
-                    paging["lengths"], T, cfg.paged_block_size,
-                    paging["block_tables"].shape[-1])}
+                # nothing a layer changes: listed once here, as the layers'
+                # calls take it, not once a layer inside the stack
+                record_dispatch("paged_decode_tile%d" % paged_plan(
+                    cfg.paged_block_size).tile_keys)
+                paging = {**paging, "work": paged_step_work(
+                    paging["lengths"], paging["block_tables"], T,
+                    cfg.paged_block_size)}
         if cfg.remat and cfg.cpu_checkpointing:
             # cpu_checkpointing: ONE checkpoint over the whole stack whose
             # policy host-offloads the per-layer "block_in" residuals (the

@@ -19,11 +19,12 @@ TPU-native design points:
   grid step costs whether it is live or dead (the pipeline's bookkeeping
   for its operands), not FLOPs or bytes, dominates. The paged kernel,
   which serving runs with most of its tables empty, therefore has NO dead
-  steps: its grid is one traced axis over the live blocks of all rows (see
-  ``_paged_call``).
+  steps: its grid is one traced axis over the live blocks of all rows, a
+  tile of them a step (see ``_paged_call`` and ``paged_plan``).
 """
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -152,8 +153,9 @@ def decode_attention(q, k_cache, v_cache, cache_index, softmax_scale=None,
 # blocks — the serving layer's continuous-batching cache (vLLM-style
 # paging, TPU-native via scalar-prefetch block DMA). The kernel walks each
 # row's OWN blocks: its grid is the live blocks of all rows, row after row,
-# read from ``lengths``, and nothing past a row's live prefix is fetched or
-# stepped over (``_paged_call``). The dense append-cache kernel above is
+# a tile of consecutive blocks a step (``paged_plan``), read from
+# ``lengths``, and no step exists past a row's live prefix
+# (``_paged_call``). The dense append-cache kernel above is
 # kept untouched: it serves the legacy generate() path and is the
 # correctness oracle for this one.
 #
@@ -234,24 +236,72 @@ def _heads_of(block, heads, d):
     return jnp.stack([block[:, h * d:(h + 1) * d] for h in range(heads)])
 
 
+class PagedPlan(NamedTuple):
+    """What one grid step of the paged kernel attends; static in the
+    shapes (:func:`paged_plan`)."""
+    tile_blocks: int   # consecutive blocks of a row a grid step attends
+    block_size: int    # keys a pool block
+
+    @property
+    def tile_keys(self) -> int:
+        return self.tile_blocks * self.block_size
+
+    def describe(self) -> str:
+        return (f"one softmax update a tile of {self.tile_blocks} x "
+                f"{self.block_size} = {self.tile_keys} keys")
+
+
+# keys a tile aims at: one 128-lane register of float32 scores a head and
+# query row
+PAGED_TILE_KEYS = 128
+
+
+def paged_plan(block_size):
+    """The tile of the paged kernel: as many consecutive blocks of a row as
+    hold ``PAGED_TILE_KEYS`` keys (4 blocks of 32, 8 of 16; one block where
+    a block is 128 keys or more). The query rows do not move it: what a
+    step holds in VMEM is its ``[heads, tq, 128-lane]`` scores, query and
+    accumulator, which a block of 32 keys pads to as a tile of 128 fills
+    them, so the chip's compiler takes a tile wherever it takes a block (at
+    25 heads of 64 both to 176 query rows, neither from 192); and a ``k +
+    1``-row verify step has to take the decode step's tile, or its row
+    ``r`` would not be the ``tq = 1`` call at ``lengths + r`` to the bit."""
+    return PagedPlan(max(1, PAGED_TILE_KEYS // block_size), block_size)
+
+
+_noted_plans = set()
+
+
+def _note_paged_plan(plan, q_shape, pool_shape, quant):
+    """Log the tile once a shape, while tracing (as ``flash_plan`` is)."""
+    key = (plan, tuple(q_shape), tuple(pool_shape), quant)
+    if key in _noted_plans:
+        return
+    _noted_plans.add(key)
+    from deepspeed_tpu.utils.logging import logger
+
+    logger.info(f"decode_attention_paged q{tuple(q_shape)} pool"
+                f"{tuple(pool_shape)}{' int8' if quant else ''}: "
+                f"{plan.describe()}")
+
+
 def _paged_kernel(row_ref, first_ref, tables_ref, lens_ref, at_ref, q_ref,
-                  k_ref, v_ref, *rest, scale, bs, tq, heads, d, quant,
-                  head_shard):
-    if quant:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        o_ref, m_scr, l_scr, acc_scr = rest
-    # this grid step is block ji of row bi's live prefix (see
-    # paged_work_list)
+                  *rest, scale, bs, tq, heads, d, quant, head_shard, tile,
+                  batch):
+    # ``tile`` refs a pool, one a block of this step's tile, in order; then
+    # the zeros the output starts as (never read here)
+    pools = [rest[i * tile:(i + 1) * tile] for i in range(4 if quant else 2)]
+    _, o_ref, m_scr, l_scr, acc_scr = rest[len(pools) * tile:]
+    keys = tile * bs
+    # this grid step is tile ji of row bi's live prefix (see
+    # paged_work_list); an idle serving slot has no step, and its rows of
+    # the output stay the zeros they started as
     step = pl.program_id(0)
     bi = row_ref[step]
     ji = step - first_ref[bi]
     idx = lens_ref[bi]  # this row's valid length BEFORE the step
-    # an idle serving slot (length 0 AND a table that starts at the garbage
-    # block) owns no block: its one step does no arithmetic and writes
-    # zeros, which the caller discards. Any other row is attended over
-    # whatever its table names, the garbage block included
-    owns = (idx > 0) | (tables_ref[bi, 0] != GARBAGE_BLOCK)
+    # a batch of idle slots only still runs the grid's one step, on no row
+    owns = step < first_ref[batch]
 
     @pl.when(jnp.logical_not(owns))
     def _idle():
@@ -263,14 +313,18 @@ def _paged_kernel(row_ref, first_ref, tables_ref, lens_ref, at_ref, q_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
+    def rows_of(refs):
+        # the tile's blocks one under the other: [keys, lanes]
+        return jnp.concatenate([r[...] for r in refs], axis=0)
+
     @pl.when(owns)
-    def _block():
+    def _tile():
         q = q_ref[...].reshape(tq, heads, d).transpose(1, 0, 2)   # [H,tq,d]
-        k = _heads_of(k_ref[...], heads, d)                       # [H,bs,d]
-        v = _heads_of(v_ref[...], heads, d)
+        k = _heads_of(rows_of(pools[0]), heads, d)                # [H,keys,d]
+        v = _heads_of(rows_of(pools[1]), heads, d)
         if quant:
             # dequantize in-register: int8 rows x the side-pool scales
-            ks, vs = ks_ref[...], vs_ref[...]
+            ks, vs = rows_of(pools[2]), rows_of(pools[3])
             if head_shard:
                 # a tp shard holds heads [h0, h0 + heads) of the (whole,
                 # replicated) scale row: bring lane h0 to lane 0
@@ -281,12 +335,14 @@ def _paged_kernel(row_ref, first_ref, tables_ref, lens_ref, at_ref, q_ref,
             v = v.astype(jnp.float32) * _heads_of(vs, heads, 1)
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale            # [H,tq,bs]
+            preferred_element_type=jnp.float32) * scale            # [H,tq,keys]
         # query row r sits at absolute position idx + r and sees keys <=
-        # that: the boundary block's rows past the prefix are masked here
-        rows = jax.lax.broadcasted_iota(jnp.int32, (heads, tq, bs), 1)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (heads, tq, bs), 2) \
-            + ji * bs
+        # that: the boundary block's rows past the prefix, and the blocks
+        # of a tile past the row's live prefix (which hold a live block's
+        # rows again: see pool_spec), are masked here by their POSITION
+        rows = jax.lax.broadcasted_iota(jnp.int32, (heads, tq, keys), 1)
+        cols = jax.lax.broadcasted_iota(jnp.int32, (heads, tq, keys), 2) \
+            + ji * keys
         s = jnp.where(cols <= idx + rows, s, NEG_INF)
         m_prev = m_scr[:, :, 0:1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
@@ -308,33 +364,69 @@ def _paged_kernel(row_ref, first_ref, tables_ref, lens_ref, at_ref, q_ref,
             .astype(o_ref.dtype)
 
 
-def paged_work_list(lengths, tq, block_size, max_blocks):
-    """The paged kernel's grid, made from ``lengths`` alone: ``(row_of,
+def paged_step_lengths(lengths, block_tables, tq):
+    """``lengths`` as :func:`paged_work_list` takes them for the paged
+    kernel: an idle serving slot (length 0 AND a table that starts at the
+    garbage block) is handed ``-tq``, which is what it holds with this
+    step's ``tq`` rows counted: no key, so no live block and NO grid step
+    (its output rows are zeros, which the caller discards). Any other row
+    keeps its length and is attended over whatever its table names, the
+    garbage block included: a fresh row (length 0) on a block of its own
+    over its ``tq`` keys."""
+    lens = jnp.asarray(lengths, jnp.int32)
+    tables = jnp.asarray(block_tables, jnp.int32)
+    return jnp.where((lens == 0) & (tables[:, 0] == GARBAGE_BLOCK), -tq, lens)
+
+
+def paged_work_list(lengths, tq, block_size, max_blocks, *, tile_blocks=1):
+    """A paged kernel's grid, made from ``lengths`` alone: ``(row_of,
     first)``. Row ``r`` of the batch owns grid steps ``[first[r], first[r +
-    1])``, one a live block, ``n_r = min(cdiv(lengths[r] + tq, block_size),
-    max_blocks)`` of them, and ``first[B]`` is the grid's length; ``row_of[s]``
-    is the row step ``s`` belongs to (``B * max_blocks + 1`` entries: one
-    more than the longest grid, for the pipeline's look at the step after
-    the last, which stays the last row's). ``tq >= 1``, so every row has a
-    step: an idle slot (length 0, table all garbage) one on the garbage
-    block.
+    1])``, one a tile of ``tile_blocks`` live blocks, ``cdiv(n_r,
+    tile_blocks)`` of them with ``n_r = min(cdiv(lengths[r] + tq,
+    block_size), max_blocks)`` the row's live blocks, and ``first[B]`` is
+    the grid's length; ``row_of[s]`` is the last row that has started by
+    step ``s`` (``B * cdiv(max_blocks, tile_blocks) + 1`` entries: one more
+    than the longest grid, for the pipeline's look at the step after the
+    last, which stays the last row's). A row of length 0 or more has a
+    step (``tq >= 1``): an idle slot, listed at its length 0, one on the
+    garbage block, which is how the hybrid kernel takes it; one handed
+    ``-tq`` (:func:`paged_step_lengths`) has no key and no step, and
+    ``row_of`` passes over it. ``tile_blocks`` is :func:`paged_plan`'s for
+    the call the list is made for; at its default a step is a block (the
+    hybrid kernel's grid).
 
     It depends on nothing a layer changes, so a program that calls the
     kernel once a layer makes it ONCE, before the layer loop, and hands it
     to every call as ``work=`` (``models/gpt2.py`` does); a call given none
-    makes its own. It costs a ``(B + 1) x (B * max_blocks + 1)`` compare
-    and sum, and ``row_of`` takes about the SMEM the block tables do."""
+    makes its own. It costs a ``(B + 1) x (B * cdiv(max_blocks,
+    tile_blocks) + 1)`` compare and sum, and ``row_of`` takes about the
+    SMEM the block tables do."""
     lens = jnp.asarray(lengths, jnp.int32)
     b = lens.shape[0]
     live = jnp.minimum((lens + (tq + block_size - 1)) // block_size,
                        max_blocks)
-    first = jnp.sum(jnp.tril(jnp.broadcast_to(live, (b + 1, b)), -1),
+    # (at the default the list is traced as it always was, operation for
+    # operation: the hybrid kernel's programs are their parent's text)
+    tiles = (live if tile_blocks == 1
+             else (live + (tile_blocks - 1)) // tile_blocks)
+    first = jnp.sum(jnp.tril(jnp.broadcast_to(tiles, (b + 1, b)), -1),
                     axis=1, dtype=jnp.int32)
-    steps = jnp.arange(b * max_blocks + 1, dtype=jnp.int32)
+    steps = jnp.arange(b * -(-max_blocks // tile_blocks) + 1, dtype=jnp.int32)
     # the rows that have started by s, less one
     row_of = jnp.minimum(jnp.sum(first[:, None] <= steps[None, :], axis=0,
                                  dtype=jnp.int32), b) - 1
     return row_of, first
+
+
+def paged_step_work(lengths, block_tables, tq, block_size):
+    """:func:`paged_work_list` as a call of the paged kernel takes it: in
+    :func:`paged_plan`'s tiles, idle slots without a step
+    (:func:`paged_step_lengths`). What a program makes once a step, before
+    its layers, and hands to every layer's call as ``work=``."""
+    return paged_work_list(
+        paged_step_lengths(lengths, block_tables, tq), tq, block_size,
+        block_tables.shape[-1],
+        tile_blocks=paged_plan(block_size).tile_blocks)
 
 
 def _paged_call(q, pools, block_tables, lengths, layer, softmax_scale,
@@ -343,25 +435,49 @@ def _paged_call(q, pools, block_tables, lengths, layer, softmax_scale,
     ``(k_pool, v_pool)`` or ``(k_pool, v_pool, k_scale, v_scale)``.
     ``head0`` (tp shards only) is the first head this call's ``q`` and K/V
     lanes hold, for the scale rows, which stay whole. ``work`` is
-    :func:`paged_work_list` of these ``lengths``, made here if not given.
+    :func:`paged_step_work` of these ``lengths`` and tables, made here if
+    not given.
 
     THE GRID FOLLOWS ``lengths``, NOT ``block_tables.shape``: it is one
-    axis whose (traced) length is the number of live blocks of all rows,
-    ``sum_b n_b`` with ``n_b = min(cdiv(lengths[b] + T_q, bs), MB)``, and
-    step ``s`` is the next block of the last row that has started by
-    ``s``: row 0's blocks in ascending order, then row 1's, and so on
-    (``row_of`` and ``first``, the work list, are two more scalar-prefetch
-    operands). A fixed ``(B, MB)`` grid pays the pipeline's bookkeeping
-    for every step of every table, dead or live, and that bookkeeping, not
-    the bytes, was the kernel's time while few slots were busy; this one
-    runs no step, and fetches no block, past a row's live prefix. A live
-    step is the fixed grid's live step: the same fp32 online-softmax update
-    on the one block that arrived, so the sums are taken in the same order
-    and the outputs of rows that hold a sequence are the fixed grid's to
-    the bit. (A grid over rows with a manual ``make_async_copy`` loop
-    inside would do the same, but Mosaic refuses a DMA slice of a pool row
-    whose lanes are no multiple of 128, GPT-2 XL's 1600 for one; the
-    pipeline's own block DMA moves any row.)
+    axis whose (traced) length is the number of live TILES of all rows,
+    ``sum_b cdiv(n_b, tile_blocks)`` with ``n_b = min(cdiv(lengths[b] +
+    T_q, bs), MB)`` the row's live blocks, and step ``s`` is the next tile
+    of the last row that has started by ``s``: row 0's tiles in ascending
+    order, then row 1's, and so on (``row_of`` and ``first``, the work
+    list, are two more scalar-prefetch operands). A fixed ``(B, MB)`` grid
+    pays the pipeline's bookkeeping for every step of every table, dead or
+    live, and that bookkeeping, not the bytes, was the kernel's time while
+    few slots were busy; this one runs no step past a row's live prefix,
+    and none at all for an idle serving slot (:func:`paged_step_lengths`):
+    a step's bookkeeping grows with its operands (about 0.2 us a step of
+    two pool operands, 0.7 of eight: PERF.md section 6), and a call with 3
+    of 32 slots busy would spend more on its 29 idle steps than on its
+    live ones. The output starts as zeros (an operand aliased to it), so
+    the rows no step writes are zeros.
+
+    A STEP IS A TILE, NOT A BLOCK. Tile ``j`` of a row is its blocks ``[j *
+    tile_blocks, (j + 1) * tile_blocks)``, each its own operand (so
+    ``tile_blocks`` ``BlockSpec``s a pool, moved by the pipeline's own
+    block DMA, which moves a row of any width), laid one under the other
+    in the body: ONE maximum, ONE ``exp``, ONE rescale of the accumulator
+    and one pair of batched matmuls over ``tile_blocks * bs`` keys in
+    ascending order, in float32 but for the probabilities, which are
+    rounded to the values' dtype for the second matmul. What a live step
+    costs is by the step, not by the key (about 1 us for a block of 32
+    keys whose bytes take 0.25), so a row of 10 blocks takes 3 steps where
+    it took 10. Tiles are ABSOLUTE: tile ``j`` holds the same key positions
+    whatever the row's length or ``T_q``, and a tile whose keys a query row
+    cannot see leaves that row's state as it was to the bit (``exp(-1e30 -
+    m) = 0``, ``alpha = 1``), so row ``r`` of a ``T_q = k + 1`` call is the
+    ``T_q = 1`` call at ``lengths + r`` to the bit. A block of a tile past
+    the row's live prefix is never named: that operand names the block it
+    held a step ago (the same index, so the pipeline moves nothing) or, in
+    the row's first tile, the row's block 0; its scores are masked by
+    position, and its value rows are a live block's, so nothing a dead
+    block holds (NaN included) reaches the matmul. (A grid over rows with a
+    manual ``make_async_copy`` loop inside would do the same, but Mosaic
+    refuses a DMA slice of a pool row whose lanes are no multiple of 128,
+    GPT-2 XL's 1600 for one.)
 
     The one axis is ``arbitrary``: the softmax state is carried from step
     to step in scratch, and rows are of unequal length, so a chip with two
@@ -386,19 +502,28 @@ def _paged_call(q, pools, block_tables, lengths, layer, softmax_scale,
     scale = softmax_scale if softmax_scale is not None else d ** -0.5
     tables = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32)
-    row_of, first = (paged_work_list(lens, tq, bs, mb) if work is None
+    plan = paged_plan(bs)
+    _note_paged_plan(plan, q.shape, pools[0].shape, quant)
+    tile = plan.tile_blocks
+    row_of, first = (paged_step_work(lens, tables, tq, bs) if work is None
                      else work)
-    if row_of.shape != (b * mb + 1,) or first.shape != (b + 1,):
+    if row_of.shape != (b * -(-mb // tile) + 1,) or first.shape != (b + 1,):
         raise ValueError(
             f"work list of shapes {row_of.shape}, {first.shape} is not "
-            f"paged_work_list's for {b} rows of {mb} blocks")
+            f"paged_work_list's for {b} rows of {mb} blocks in tiles of "
+            f"{tile}")
 
-    def pool_spec(width):
+    def pool_spec(width, i):
         # (layer, table[row, block]) picked by the DMA itself: the stacked
-        # pool is an operand as it lies in HBM, never a slice of it
+        # pool is an operand as it lies in HBM, never a slice of it. Block
+        # i of the step's tile, or, where the row's live prefix ends before
+        # it, a live block that costs no fetch (see the docstring)
         def index(s, row_of, first, tab, ln, at):
             row = row_of[s]
-            return (at[0], tab[row, jnp.minimum(s - first[row], mb - 1)],
+            j = (s - first[row]) * tile + i
+            live = jnp.minimum((ln[row] + (tq + bs - 1)) // bs, mb)
+            return (at[0], tab[row, jnp.where(j < live, j,
+                                              jnp.maximum(j - tile, 0))],
                     0, 0)
         return pl.BlockSpec((None, None, bs, width), index)
 
@@ -407,9 +532,11 @@ def _paged_call(q, pools, block_tables, lengths, layer, softmax_scale,
                           (row_of[s], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=(first[b],),
-        in_specs=[q_spec] + [pool_spec(p.shape[POOL_LANE_AXIS])
-                             for p in pools],
+        # a batch of idle slots only has no step: one, on no row, runs
+        grid=(jnp.maximum(first[b], 1),),
+        in_specs=[q_spec] + [pool_spec(p.shape[POOL_LANE_AXIS], i)
+                             for p in pools for i in range(tile)]
+        + [pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((heads, tq, 128), jnp.float32),   # m
@@ -419,7 +546,8 @@ def _paged_call(q, pools, block_tables, lengths, layer, softmax_scale,
     )
     kernel = functools.partial(_paged_kernel, scale=scale, bs=bs, tq=tq,
                                heads=heads, d=d, quant=quant,
-                               head_shard=quant and head0 is not None)
+                               head_shard=quant and head0 is not None,
+                               tile=tile, batch=b)
     at = jnp.stack([jnp.asarray(layer, jnp.int32).reshape(()),
                     jnp.asarray(0 if head0 is None else head0, jnp.int32)])
     # no ``name=`` here: a pallas_call's name is also a named scope, and
@@ -430,9 +558,13 @@ def _paged_call(q, pools, block_tables, lengths, layer, softmax_scale,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, tq, heads, d), q.dtype),
+        # the operand after the scalars, q and the pools is the output's
+        # own buffer, zeros: a row no step visits is never written
+        input_output_aliases={6 + len(pools) * tile: 0},
         compiler_params=tpu_compiler_params(
             dimension_semantics=("arbitrary",)),
-    )(row_of, first, tables, lens, at, q, *pools)
+    )(row_of, first, tables, lens, at, q,
+      *(p for p in pools for _ in range(tile)), jnp.zeros_like(q))
 
 
 def decode_attention_paged(q, k_pool, v_pool, block_tables, lengths,
@@ -458,21 +590,21 @@ def decode_attention_paged(q, k_pool, v_pool, block_tables, lengths,
       lengths: ``[B]`` int32 — valid tokens per row *before* this step.
       layer: int32 scalar (traced inside a layer scan, or a Python int) —
         which layer of the stacked pool to read.
-      work: :func:`paged_work_list` of these ``lengths``, for a caller
-        that runs many layers on one step's lengths and makes it once;
-        made here if None.
+      work: :func:`paged_step_work` of these ``lengths`` and tables, for a
+        caller that runs many layers on one step's lengths and makes it
+        once; made here if None.
 
     The block table, lengths and layer are *scalar-prefetch* operands:
-    the grid is one axis over the live blocks of all rows, ``sum_b
-    min(cdiv(lengths[b] + T_q, block_size), MB)`` steps, a traced number;
-    each step DMAs exactly the ``(layer, block)`` the table names —
-    ``block_size`` rows of ``H*D`` lanes, unpadded — and no step exists
-    for a block past ``lengths[b] + T_q``, so such a block is never
-    fetched. The fp32 online-softmax update runs once a block, blocks in
-    ascending order. An idle serving slot (length 0 AND a table that starts
-    at the garbage block) takes one step, does no arithmetic and gets
-    zeros; a row of any length above 0 is attended over what its table
-    names, the garbage block included.
+    the grid is one axis over the live blocks of all rows, a tile of
+    :func:`paged_plan`'s ``tile_blocks`` a step, ``sum_b cdiv(min(cdiv(
+    lengths[b] + T_q, block_size), MB), tile_blocks)`` steps, a traced
+    number; each step DMAs exactly the ``(layer, block)``s the table names
+    for its tile — ``block_size`` rows of ``H*D`` lanes each, unpadded —
+    and no block past ``lengths[b] + T_q`` is ever named. The fp32
+    online-softmax update runs once a tile, tiles in ascending order. An
+    idle serving slot (length 0 AND a table that starts at the garbage
+    block) has no step and gets zeros; any other row is attended over what
+    its table names, the garbage block included.
 
     Returns ``[B, T_q, H, D]`` in the query's dtype.
     """
@@ -493,8 +625,8 @@ def decode_attention_paged_int8(q, k_pool, v_pool, k_scale, v_scale,
     block_size, scale_lanes(H)]`` f32 per-row scales (one scale per
     token x head, head ``h`` in lane ``h`` —
     ``ops.quantizer.quantize_rowwise``). The scale side pools ride the
-    same ``(layer, block)`` address: each grid step DMAs the named pool
-    block *and* its scale rows, dequantizes in-register, and runs the
+    same ``(layer, block)`` address: each grid step DMAs its tile's pool
+    blocks *and* their scale rows, dequantizes in-register, and runs the
     identical fp32 online-softmax update; :func:`gather_paged_cache_int8`
     is the dense oracle it is tested against with a pinned tolerance.
     """

@@ -91,12 +91,19 @@ def test_decode_attention(one_chip):
 # layer index: 125M's 12 heads of 64, XL's 25 (a row of 1600 lanes is no
 # multiple of 128), head size 128, and the benchmark's serving cell as it is
 # sized (GPT-2 XL, 32 slots x 32 blocks of 32 over a pool of 513)
+# since PR 44 a grid step is a tile of 4 such blocks, each its own operand:
+# the same shapes, and a prefill chunk's 128 query rows (the chip's
+# compiler takes a tile wherever it takes a block: at 25 heads of 64 to 176
+# rows, neither from 192)
 @pytest.mark.parametrize("slots,t_q,heads,dim", [
     (8, 1, H, D), (8, 5, H, D), (8, 1, 25, 64), (8, 5, 25, 64),
-    (8, 1, 8, 128), (32, 1, 25, 64), (32, 5, 25, 64)])
+    (8, 1, 8, 128), (32, 1, 25, 64), (32, 5, 25, 64), (1, 128, 25, 64),
+    (4, 128, H, D)])
 def test_decode_attention_paged(one_chip, slots, t_q, heads, dim):
-    from deepspeed_tpu.ops.decode_attention import decode_attention_paged
+    from deepspeed_tpu.ops.decode_attention import (decode_attention_paged,
+                                                    paged_plan)
 
+    assert paged_plan(32).tile_blocks == 4
     pool = _s(one_chip, (2, 513, 32, heads * dim))
     text = _compiled_text(
         decode_attention_paged, _s(one_chip, (slots, t_q, heads, dim)), pool,
@@ -107,7 +114,7 @@ def test_decode_attention_paged(one_chip, slots, t_q, heads, dim):
 
 @pytest.mark.parametrize("slots,t_q,heads,dim", [
     (8, 1, H, D), (8, 1, 25, 64), (8, 1, 8, 128), (32, 1, 25, 64),
-    (32, 5, 25, 64)])
+    (32, 5, 25, 64), (1, 128, 25, 64)])
 def test_decode_attention_paged_int8(one_chip, slots, t_q, heads, dim):
     from deepspeed_tpu.ops.decode_attention import (
         decode_attention_paged_int8, scale_lanes)

@@ -61,6 +61,7 @@ class TestChipSmoke:
             chip_smoke.FLASH_VS_REFERENCE_ATOL
         assert serve["requests"] == TINY.requests
         assert serve["tokens_equal_generate"] is True
+        assert serve["partings"] == []
         assert serve["compiles_after_warmup"] == 0
         # numbers from this run are labelled as what they are
         assert train["smoke_numbers"] and train["device_kind"] == "cpu"
@@ -208,6 +209,102 @@ class TestTpStreamsAreHeldOverTheirWholeLength:
         assert parting["position"] == 2
         assert parting["max_logit_diff"] <= 1e-4  # f32 here
         assert tp["requests_with_equal_tokens"] == TINY.requests - 1
+
+
+class TestServedStreamsAreJudgedAsTpStreamsAre:
+    """Phase 3's guard since PR 44: a served stream that parts from
+    ``generate()`` is served on from ``generate()``'s prefix, the position
+    held to the tp phase's near-tie and logits tolerances, at most
+    ``TP_MAX_PARTINGS`` a request."""
+
+    @staticmethod
+    def _spoil(monkeypatch, times):
+        """The first served stream's token 2 made wrong, and the first
+        token of what is served on after it, ``times`` in all."""
+        real = chip_smoke.follow_stream
+        left = [times]
+
+        def wrong(tokens, want):
+            left[0] -= 1
+            return [(want + 1) % TINY.model["vocab_size"]] + tokens[1:]
+
+        def spoiled(want, got, serve_from, names):
+            if not left[0]:
+                return real(want, got, serve_from, names)
+            got = got[:2] + wrong(got[2:], want[2])
+
+            def from_(n):
+                rest = serve_from(n)
+                return wrong(rest, want[n]) if left[0] else rest
+
+            return real(want, got, from_, names)
+
+        monkeypatch.setattr(chip_smoke, "follow_stream", spoiled)
+
+    @staticmethod
+    def _serve_line(capsys):
+        (serve,) = (json.loads(l) for l in
+                    capsys.readouterr().out.strip().splitlines()
+                    if l.startswith('{"phase": "serve"'))
+        return serve
+
+    def test_the_paged_paths_logits_are_the_whole_forwards(self, one_device):
+        """``paged_last_logits`` (prefill into a pool, one decode step
+        through the block table) against ``forward_last`` at f32."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        import deepspeed_tpu
+        from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel
+
+        cfg = chip_smoke._model_config(TINY)
+        engine = deepspeed_tpu.init_inference(
+            GPT2LMHeadModel(cfg), dtype=cfg.dtype, seed=0,
+            tensor_parallel={"tp_size": 1}, max_out_tokens=cfg.n_positions)
+        for n in (3, 8, 13):
+            prefix = np.arange(n, dtype=np.int32) % cfg.vocab_size
+            np.testing.assert_allclose(
+                chip_smoke.paged_last_logits(engine, cfg, prefix, 8),
+                np.asarray(engine.forward_last(jnp.asarray(prefix[None])),
+                           np.float32)[0], atol=1e-4)
+
+    def test_a_parting_that_is_no_near_tie_fails(self, one_device,
+                                                 monkeypatch):
+        self._spoil(monkeypatch, 1)
+        with pytest.raises(AssertionError, match="served picked token .* "
+                           "where generate\\(\\) picked .* no near tie"):
+            chip_smoke.serve_phase(TINY, 0, kernels=False)
+
+    def test_a_near_tie_passes_and_is_counted(self, one_device, monkeypatch,
+                                              capsys):
+        self._spoil(monkeypatch, 1)
+        monkeypatch.setattr(chip_smoke, "TP_NEAR_TIE_RTOL", 10.0)
+        chip_smoke.serve_phase(TINY, 0, kernels=False)
+        serve = self._serve_line(capsys)
+        assert serve["tokens_equal_generate"] is False
+        (parting,) = serve["partings"]
+        assert (parting["request"], parting["position"]) == (0, 2)
+        assert parting["served_token"] != parting["generate_token"]
+        assert parting["max_logit_diff"] <= 1e-4  # f32 here
+        assert parting["logit_gap"] > 0
+
+    def test_a_fourth_parting_in_one_request_fails(self, one_device,
+                                                   monkeypatch):
+        self._spoil(monkeypatch, 4)
+        monkeypatch.setattr(chip_smoke, "TP_NEAR_TIE_RTOL", 10.0)
+        with pytest.raises(AssertionError, match="the served stream parts "
+                           "from generate\\(\\) at \\[2, 3, 4, 5\\]: "
+                           "more than 3 times"):
+            chip_smoke.serve_phase(TINY, 0, kernels=False)
+
+    def test_three_partings_in_one_request_pass(self, one_device,
+                                                monkeypatch, capsys):
+        self._spoil(monkeypatch, 3)
+        monkeypatch.setattr(chip_smoke, "TP_NEAR_TIE_RTOL", 10.0)
+        chip_smoke.serve_phase(TINY, 0, kernels=False)
+        serve = self._serve_line(capsys)
+        assert [f["position"] for f in serve["partings"]] == [2, 3, 4]
+        assert {f["request"] for f in serve["partings"]} == {0}
 
 
 class TestDevicePolicy:

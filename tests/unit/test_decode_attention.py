@@ -188,25 +188,36 @@ def test_paged_matches_dense_gather(lengths, tq):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_verify_rows_equal_sequential_single_row_calls():
+@pytest.mark.parametrize("dtype", ["bf16", "f32"])
+def test_verify_rows_equal_sequential_single_row_calls(dtype):
     """The accept-oracle property at kernel level: row r of one
-    multi-query verify call computes the SAME attention a plain decode
-    call would at length + r — the prefix each draft token would have
-    seen decoded sequentially. This is what makes greedy k-token verify
-    an exact oracle rather than an approximation."""
+    multi-query verify call computes the SAME attention, to the bit, that a
+    plain decode call would at length + r — the prefix each draft token
+    would have seen decoded sequentially. This is what makes greedy k-token
+    verify an exact oracle rather than an approximation. Tiles are
+    absolute, so it holds where the rows straddle a tile's boundary too
+    (126 + 4 rows: two see one tile, two see two; the ``tq = 1`` calls at
+    126 and 127 have one step, those at 128 and 129 two). In bf16, what
+    serving stores, the ``tq = 1`` call itself; in float32 the CPU's matmul
+    of four rows is not its matmul of one row to the last bit (nor was it
+    under the parent's kernel), so there the single row goes through a
+    four-row call, at row 0."""
     from deepspeed_tpu.ops.decode_attention import decode_attention_paged
 
     tq = 4
-    args = _paged_setup(2, [5, 37], tq, bs=32, mb=4, seed=1)
+    args = _paged_setup(3, [5, 37, 126], tq, bs=32, mb=8, seed=1,
+                        dtype=jnp.bfloat16 if dtype == "bf16"
+                        else np.float32)
     q4, k_pool, v_pool, tables, lens, layer = args
     with tpu_interpret_mode():
-        multi = np.asarray(decode_attention_paged(*args))
+        multi = np.asarray(decode_attention_paged(*args), np.float32)
     for r in range(tq):
+        rows = q4[:, r:r + 1] if dtype == "bf16" else jnp.roll(q4, -r, 1)
         with tpu_interpret_mode():
-            single = decode_attention_paged(q4[:, r:r + 1], k_pool, v_pool,
-                                            tables, lens + r, layer)
-        np.testing.assert_allclose(multi[:, r:r + 1], np.asarray(single),
-                                   rtol=2e-5, atol=2e-5)
+            single = decode_attention_paged(rows, k_pool, v_pool, tables,
+                                            lens + r, layer)
+        np.testing.assert_array_equal(
+            multi[:, r], np.asarray(single, np.float32)[:, 0])
 
 
 def test_verify_rejected_tail_rows_isolated():
@@ -532,15 +543,129 @@ def test_paged_rows_of_many_blocks(kv, tq):
         rtol=2e-5, atol=2e-5)
 
 
-def test_paged_grid_is_the_live_blocks_of_all_rows(monkeypatch):
-    """The kernel's iteration space follows ``lengths``, not
-    ``block_tables.shape``: the ``pallas_call`` has ONE grid axis, its
-    length is traced, and it comes to one step a live block of every row
-    (one for an idle slot), where the fixed grid had ``B x MB``."""
+def _oracle_error(kv, tq, batch):
+    """``(largest, rms)`` error of the paged kernel over the live rows of
+    one batch against the float32 dense-gather oracle over the SAME stored
+    numbers: bf16 pools and queries (the probabilities round to bf16 for
+    the value matmul), or int8 pools with float32 queries (everything in
+    float32: the order of the sums alone). ``mixed`` is every length of
+    live prefix in a table of 4 blocks, ``many`` rows of up to 10 blocks
+    (three tiles of four) beside short ones."""
+    from deepspeed_tpu.ops.decode_attention import (
+        decode_attention_paged, decode_attention_paged_int8)
+
+    bs = 32
+    if batch == "mixed":
+        mb, lengths = 4, _mixed(tq, bs, 4) + [40, 0]
+    else:
+        mb = 10
+        lengths = [4 * bs - tq, IDLE, 4 * bs - tq + 1, 5, 9 * bs - tq - 5,
+                   mb * bs - tq, 6 * bs + 3]
+    q4, k_pool, v_pool, tables, lens, layer = _paged_setup(
+        len(lengths), lengths, tq, bs=bs, mb=mb, H=5, D=64, seed=17 + tq,
+        dtype=jnp.bfloat16 if kv == "bf16" else np.float32)
+    if kv == "bf16":
+        kernel, pools, oracle = decode_attention_paged, (k_pool, v_pool), \
+            _paged_dense_ref
+    else:
+        kernel, pools, oracle = decode_attention_paged_int8, _int8_pools(
+            k_pool, v_pool, q4.shape[2]), _int8_dense_ref
+    with tpu_interpret_mode():
+        out = jax.block_until_ready(kernel(q4, *pools, tables, lens, layer))
+    live = _live(lengths)
+    err = (np.asarray(out, np.float64)[live]
+           - np.asarray(oracle(q4, *pools, tables, lens, layer),
+                        np.float64)[live])
+    return float(np.abs(err).max()), float(np.sqrt(np.mean(err ** 2)))
+
+
+# the block-a-step form's error against that oracle, read on PR 44's parent
+# (94a7eb4, interpret mode): (largest, rms) of each case
+BLOCK_A_STEP_ERROR = {
+    ("bf16", 1, "mixed"): (0.004032, 0.000468),
+    ("bf16", 1, "many"): (0.004248, 0.0004971),
+    ("bf16", 4, "mixed"): (0.007522, 0.0006416),
+    ("bf16", 4, "many"): (0.00513, 0.0004665),
+    ("bf16", 5, "mixed"): (0.007159, 0.0006585),
+    ("bf16", 5, "many"): (0.004643, 0.0004133),
+    ("int8", 1, "mixed"): (7.153e-07, 4.819e-08),
+    ("int8", 1, "many"): (2.086e-07, 3.392e-08),
+    ("int8", 4, "mixed"): (5.588e-07, 6.639e-08),
+    ("int8", 4, "many"): (5.96e-07, 5.717e-08),
+    ("int8", 5, "mixed"): (7.153e-07, 7.305e-08),
+    ("int8", 5, "many"): (8.345e-07, 5.894e-08),
+}
+
+
+@pytest.mark.parametrize("kv,tq,batch", sorted(BLOCK_A_STEP_ERROR))
+def test_paged_error_against_the_float32_oracle(kv, tq, batch):
+    """What the tile form is held to: over the same batches its largest
+    and its rms error against the float32 oracle are no more than 1.25 x
+    what the block-a-step form read (it does the same arithmetic in the
+    same precisions; only the maximum its probabilities are rounded
+    against is taken over a tile of 128 keys)."""
+    largest, rms = _oracle_error(kv, tq, batch)
+    was_largest, was_rms = BLOCK_A_STEP_ERROR[kv, tq, batch]
+    assert largest <= 1.25 * was_largest, (largest, was_largest)
+    assert rms <= 1.25 * was_rms, (rms, was_rms)
+
+
+@pytest.mark.parametrize("block_size,tq,tile_blocks", [
+    (32, 1, 4), (32, 5, 4), (32, 256, 4), (16, 1, 8), (128, 1, 1),
+    (256, 1, 1), (8, 4, 16)])
+def test_paged_plan_reads_the_tile_from_the_shapes(monkeypatch, block_size,
+                                                   tq, tile_blocks):
+    """128 keys a tile, whole blocks, at least one; the query rows do not
+    move it (so verify takes decode's tile): a traced call of that
+    ``tq`` has ``tile_blocks`` operands a pool and lists its work in those
+    tiles."""
     from deepspeed_tpu.ops import decode_attention as da
 
-    bs, mb, tq = 32, 4, 1
-    lengths = _mixed(tq, bs, mb) + [IDLE, 40]
+    plan = da.paged_plan(block_size)
+    assert plan.tile_blocks == tile_blocks
+    assert plan.tile_keys == max(128, block_size)
+    assert f"{plan.tile_keys} keys" in plan.describe()
+    seen = []
+    real = da.pl.pallas_call
+
+    def spy(kernel, *a, grid_spec, **kw):
+        seen.append(len(grid_spec.in_specs))
+        return real(kernel, *a, grid_spec=grid_spec, **kw)
+
+    monkeypatch.setattr(da.pl, "pallas_call", spy)
+    mb = 2 * tile_blocks
+    args = _paged_setup(2, [3, block_size], tq, bs=block_size,
+                        mb=max(mb, -(-(block_size + tq) // block_size)))
+    jax.make_jaxpr(da.decode_attention_paged)(*args)
+    assert seen == [1 + 2 * tile_blocks + 1]  # q, K and V tiles, the zeros
+    row_of, _ = da.paged_step_work(args[4], args[3], tq, block_size)
+    assert row_of.shape == (2 * -(-args[3].shape[1] // tile_blocks) + 1,)
+
+
+def _tile_batch(tq, bs=32, mb=8, tile=4):
+    """Idle; one block; to a tile's boundary; one block past it; a whole
+    table of two tiles; idle; one key into a second block."""
+    return [IDLE, bs - tq - 3, tile * bs - tq, tile * bs - tq + 1,
+            mb * bs - tq, IDLE, bs]
+
+
+@pytest.mark.parametrize("bs,mb,steps", [
+    # tiles of 4 x 32: 0 + 1 + 1 + 2 + 2 + 0 + 1
+    (32, 8, 7),
+    # a block of 128 keys is a tile, so a step is a live block: 0 + 1 + 4
+    # + 5 + 8 + 0 + 2 (tile=4 only places the lengths)
+    (128, 8, 20)])
+def test_paged_grid_is_the_live_blocks_of_all_rows(monkeypatch, bs, mb,
+                                                   steps):
+    """The kernel's iteration space follows ``lengths``, not
+    ``block_tables.shape``: the ``pallas_call`` has ONE grid axis, its
+    length is traced, and it comes to ``cdiv(live blocks, tile_blocks)``
+    steps a row that holds a sequence and none for an idle slot, where the
+    fixed grid had ``B x MB``."""
+    from deepspeed_tpu.ops import decode_attention as da
+
+    tq = 1
+    lengths = _tile_batch(tq, bs, mb)
     args = _paged_setup(len(lengths), lengths, tq, bs=bs, mb=mb, seed=2)
     jaxpr = jax.make_jaxpr(da.decode_attention_paged)(*args)
     (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name ==
@@ -558,32 +683,100 @@ def test_paged_grid_is_the_live_blocks_of_all_rows(monkeypatch):
 
     monkeypatch.setattr(da.pl, "pallas_call", spy)
     with tpu_interpret_mode():
-        jax.block_until_ready(da.decode_attention_paged(*args))
+        out = jax.block_until_ready(da.decode_attention_paged(*args))
     (grid,) = seen
-    # idle 1; one block 1; to a boundary 2; the whole table 4; idle 1; 41
-    # keys 2
-    assert [int(g) for g in grid] == [1 + 1 + 2 + mb + 1 + 2]
+    assert [int(g) for g in grid] == [steps]
     assert int(grid[0]) < len(lengths) * mb
+    live = _live(lengths)
+    np.testing.assert_allclose(
+        np.asarray(out)[live], np.asarray(_paged_dense_ref(*args))[live],
+        rtol=2e-5, atol=2e-5)
+    idle = [b for b in range(len(lengths)) if b not in live]
+    np.testing.assert_array_equal(np.asarray(out)[idle], 0.0)
 
 
-def test_paged_work_list_made_once_serves_every_call():
+@pytest.mark.parametrize("kv", ["f32", "int8"])
+def test_paged_batch_of_idle_slots_runs_one_step_on_no_row(monkeypatch, kv):
+    """No slot holds a sequence (a warm-up call): no row has a step, the
+    grid's one step touches none, and every output row is zero, whatever
+    the garbage block holds."""
+    from deepspeed_tpu.ops import decode_attention as da
+
+    lengths = [IDLE] * 3
+    q4, k_pool, v_pool, tables, lens, layer = _paged_setup(
+        3, lengths, 1, bs=32, mb=8, seed=4)
+    pools = (k_pool, v_pool)
+    kernel = da.decode_attention_paged
+    if kv == "int8":
+        pools, kernel = _int8_pools(k_pool, v_pool, q4.shape[2]), \
+            da.decode_attention_paged_int8
+    pools = tuple(p.at[:, da.GARBAGE_BLOCK].set(
+        127 if p.dtype == jnp.int8 else np.nan) for p in pools)
+    row_of, first = da.paged_work_list(
+        da.paged_step_lengths(lens, tables, 1), 1, 32, 8, tile_blocks=4)
+    assert [int(f) for f in first] == [0, 0, 0, 0]
+    seen = []
+    real = da.pl.pallas_call
+
+    def spy(kernel, *a, grid_spec, **kw):
+        seen.append(grid_spec.grid)
+        return real(kernel, *a, grid_spec=grid_spec, **kw)
+
+    monkeypatch.setattr(da.pl, "pallas_call", spy)
+    with tpu_interpret_mode():
+        out = jax.block_until_ready(kernel(q4, *pools, tables, lens, layer))
+    assert [int(g) for g in seen[0]] == [1]
+    np.testing.assert_array_equal(np.asarray(out), 0.0)
+
+
+@pytest.mark.parametrize("tile", ["default", 4])
+def test_paged_work_list_made_once_serves_every_call(tile):
     """``paged_work_list`` is the grid: each row's first step, and each
-    step's row. A call handed the list made outside it (as the model makes
-    it, once before its layer loop) gives what a call that makes its own
-    gives; one of another batch's shape is refused."""
+    step's row. At its default tile (a block a step: what the hybrid
+    kernel lists) it is the parent's list to the value; at the paged
+    kernel's tile a call handed the list made outside it (as the model
+    makes it, once before its layer loop) gives what a call that makes its
+    own gives; one of another batch's shape, or of another tile, is
+    refused."""
     from deepspeed_tpu.ops.decode_attention import (
-        decode_attention_paged, paged_work_list)
+        decode_attention_paged, paged_plan, paged_step_lengths,
+        paged_work_list)
 
     bs, mb, tq = 32, 4, 1
     lengths = _mixed(tq, bs, mb) + [IDLE, 40]
     args = _paged_setup(len(lengths), lengths, tq, bs=bs, mb=mb, seed=2)
-    row_of, first = paged_work_list(args[4], tq, bs, mb)
-    # idle 1; one block 1; to a boundary 2; the whole table 4; idle 1; 41
-    # keys 2
-    assert [int(f) for f in first] == [0, 1, 2, 4, 8, 9, 11]
-    assert [int(r) for r in row_of[:12]] == [0, 1, 2, 2, 3, 3, 3, 3, 4, 5,
-                                             5, 5]
-    assert row_of.shape == (len(lengths) * mb + 1,)
+    if tile == "default":
+        row_of, first = paged_work_list(args[4], tq, bs, mb)
+        # idle 1; one block 1; to a boundary 2; the whole table 4; idle 1;
+        # 41 keys 2
+        assert [int(f) for f in first] == [0, 1, 2, 4, 8, 9, 11]
+        assert [int(r) for r in row_of[:12]] == [0, 1, 2, 2, 3, 3, 3, 3, 4,
+                                                 5, 5, 5]
+        assert row_of.shape == (len(lengths) * mb + 1,)
+        assert int(row_of[-1]) == len(lengths) - 1
+        # the kernel takes tiles of four blocks here, and says so
+        with pytest.raises(ValueError, match="work list"):
+            decode_attention_paged(*args, work=(row_of, first))
+        return
+    assert paged_plan(bs).tile_blocks == tile
+    mb = 8
+    lengths = _tile_batch(tq, bs, mb, tile)
+    args = _paged_setup(len(lengths), lengths, tq, bs=bs, mb=mb, seed=2)
+    row_of, first = paged_work_list(args[4], tq, bs, mb, tile_blocks=tile)
+    # listed at their length 0 (as the hybrid kernel lists them), idle
+    # slots have a step: idle 1; one block 1; to the tile's boundary 1; a
+    # block past it 2; the whole table 2; idle 1; 33 keys 1
+    assert [int(f) for f in first] == [0, 1, 2, 3, 5, 7, 8, 9]
+    assert [int(r) for r in row_of[:10]] == [0, 1, 2, 3, 3, 4, 4, 5, 6, 6]
+    # ... and as the paged kernel's callers list them, none: ``row_of``
+    # passes over them
+    marked = paged_step_lengths(args[4], args[3], tq)
+    assert [int(n) for n in marked] == [-tq] + [int(n) for n in
+                                               args[4][1:5]] + [-tq, bs]
+    row_of, first = paged_work_list(marked, tq, bs, mb, tile_blocks=tile)
+    assert [int(f) for f in first] == [0, 0, 1, 2, 4, 6, 6, 7]
+    assert [int(r) for r in row_of[:8]] == [1, 2, 3, 3, 4, 4, 6, 6]
+    assert row_of.shape == (len(lengths) * (mb // tile) + 1,)
     assert int(row_of[-1]) == len(lengths) - 1
     with tpu_interpret_mode():
         own = jax.block_until_ready(decode_attention_paged(*args))
@@ -594,6 +787,25 @@ def test_paged_work_list_made_once_serves_every_call():
                                   np.asarray(given)[live])
     with pytest.raises(ValueError, match="work list"):
         decode_attention_paged(*args, work=(row_of[:-1], first))
+
+
+@pytest.mark.parametrize("max_blocks,first,row_of", [
+    (5, [0, 1, 2, 3, 5, 10, 11, 16],
+     [0, 1, 2, 3, 3, 4, 4, 4, 4, 4, 5] + [6] * 25),
+    (8, [0, 1, 2, 3, 5, 12, 13, 21],
+     [0, 1, 2, 3, 3, 4, 4, 4, 4, 4, 4, 4, 5] + [6] * 44)])
+def test_hybrid_work_list_is_the_parents_to_the_value(max_blocks, first,
+                                                      row_of):
+    """The hybrid kernel (``ops/hybrid_decode_attention.py``, PR 44 leaves
+    it byte for byte) lists its work at the default tile: a step a live
+    block, one for an idle slot, over a ring of 5 blocks and a table of 8.
+    The values are PR 44's parent's (94a7eb4)."""
+    from deepspeed_tpu.ops.hybrid_decode_attention import hybrid_work_list
+
+    got_row_of, got_first = hybrid_work_list(
+        jnp.asarray([0, 5, 31, 32, 200, 0, 255]), 32, max_blocks)
+    assert [int(f) for f in got_first] == first
+    assert [int(r) for r in got_row_of] == row_of
 
 
 @pytest.mark.parametrize("scan_layers", [True, False],
@@ -627,9 +839,9 @@ def test_model_lists_the_paged_kernels_work_once_a_step(monkeypatch,
     made = []
     real = da.paged_work_list
 
-    def spy(*a):
-        made.append(a[1:])
-        return real(*a)
+    def spy(*a, **kw):
+        made.append(a[1:] + (kw,))
+        return real(*a, **kw)
 
     monkeypatch.setattr(da, "paged_work_list", spy)
     monkeypatch.setattr(attn_mod, "_FORCE_DECODE_KERNEL", True)
@@ -637,12 +849,18 @@ def test_model_lists_the_paged_kernels_work_once_a_step(monkeypatch,
     def step(ids, pg):
         return model.apply(variables, ids, mutable=["cache"], paging=pg)
 
+    counted = attn_mod.dispatch_counts().get("paged_decode_tile128", 0)
     jaxpr = jax.make_jaxpr(lambda ids, ln: step(ids, paging(ln, 1, False)))(
         prompt[:, :1], jnp.asarray([6, 8], jnp.int32))
-    assert made == [(1, 8, 4)]
+    # blocks of 8 keys: the kernel's tile, 16 of them
+    assert made == [(1, 8, 4, {"tile_blocks": 16})]
     assert "pallas_call" in str(jaxpr)
+    # ... and the form is counted once a traced program, not once a layer
+    assert attn_mod.dispatch_counts().get("paged_decode_tile128", 0) \
+        == counted + 1
     jax.make_jaxpr(lambda ids: step(ids, paging([0, 0], 8, True)))(prompt)
     assert len(made) == 1
+    assert attn_mod.dispatch_counts()["paged_decode_tile128"] == counted + 1
 
 
 def test_paged_live_row_on_the_garbage_block_is_attended():

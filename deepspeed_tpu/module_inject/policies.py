@@ -269,7 +269,10 @@ def decode_cache_specs(cache_abstract, mesh, axis: str = AXIS_TP,
     a sixteenth of the int8 rows' bytes at head size 64) replicate: the
     kernel finds a shard's heads in the whole row.
     Scalars/per-row bookkeeping (``cache_index``, ``position``,
-    ``pad_len``) replicate, as do head-indivisible caches.
+    ``pad_len``) replicate, as do head-indivisible caches, and so does a
+    ``latent_pool`` (``models/deepseek_v2.py``): its row is ONE latent a
+    token shared by every head, with no lanes a head to split (the
+    engine refuses that model at ``tp_size`` > 1 until a test has met it).
     """
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P

@@ -38,23 +38,37 @@ COUNTERS = ("experts_touched", "experts_held", "pairs_here", "pairs_all")
 
 
 def route(x, router_kernel, selection_bias, top_k: int, *,
-          norm_eps: float = 0.0, scale: float = 1.0):
+          norm_eps: float = 0.0, scale: float = 1.0,
+          scoring: str = "sigmoid", renormalize: bool = True):
     """``x [T, D]`` -> ``(experts [T, k] int32, weights [T, k] float32)``:
-    ``s = sigmoid(x W_r)`` over every published expert, the top ``k`` of
+    ``s = scoring(x W_r)`` over every published expert, the top ``k`` of
     ``s + bias`` chosen, ``w = scale * s[chosen] / (sum s[chosen] +
-    norm_eps)``. All float32. The two constants are a family's own
-    (LFM2-MoE: 1e-6 and its ``routed_scaling_factor``); at their defaults
-    they add no operation to the program."""
-    scores = jax.nn.sigmoid(jnp.dot(
+    norm_eps)``. All float32. ``scoring`` is the family's own function of
+    the gate's logits: ``"sigmoid"``, a score an expert by itself (MiMo-V2,
+    LFM2-MoE), or ``"softmax"`` over all the experts (DeepSeek-V2, whose
+    chosen scores are the weights as they stand: ``renormalize=False``,
+    and which has no selection bias: ``None``). The two constants are a
+    family's own too (LFM2-MoE: 1e-6 and its ``routed_scaling_factor``).
+    At its defaults each argument adds no operation to the program."""
+    logits = jnp.dot(
         x.astype(jnp.float32), router_kernel.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    _, experts = jax.lax.top_k(
-        scores + selection_bias.astype(jnp.float32)[None], top_k)
-    chosen = jnp.take_along_axis(scores, experts, axis=1)
-    total = jnp.sum(chosen, axis=1, keepdims=True)
-    if norm_eps:
-        total = total + norm_eps
-    weights = chosen / total
+        precision=jax.lax.Precision.HIGHEST)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"scoring {scoring!r}: 'sigmoid' or 'softmax'")
+    select = scores
+    if selection_bias is not None:
+        select = scores + selection_bias.astype(jnp.float32)[None]
+    _, experts = jax.lax.top_k(select, top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=1)
+    if renormalize:
+        total = jnp.sum(weights, axis=1, keepdims=True)
+        if norm_eps:
+            total = total + norm_eps
+        weights = weights / total
     if scale != 1.0:
         weights = weights * scale
     return experts.astype(jnp.int32), weights
@@ -113,7 +127,15 @@ def width_tile(f: int, tile_f: int = 512) -> int:
     divides the width (or the whole of a narrower one); else the widest
     run of whole 128-lane registers up to twice that which does (1792 =
     2 x 896: two steps a tile of rows, each matrix's block no larger than
-    a 4096 x 512 one)."""
+    a 4096 x 512 one). A width of a PRIME number of registers has no such
+    run but one register: 1408 = 11 x 128 (DeepSeek-V2-Lite's experts)
+    takes 128, eleven steps a tile of rows, each moving three blocks of
+    2048 x 128 (0.5 MB a matrix). That costs nothing a run can see: the
+    whole width in ONE step (three blocks of 5.8 MB) read 1.543 ms a layer
+    against 1.532 at a decode step's 48 rows and 2.152 against 2.129 at a
+    chunk's 512, on the chip over 64 experts (PERF.md, PR 45): the
+    kernel's time is the weights' bytes, whatever the step that moves
+    them, so the rule stands as it stood."""
     tile_f = min(tile_f, f)
     if f % tile_f:
         fits = [w for w in range(128, 2 * tile_f + 1, 128) if f % w == 0]

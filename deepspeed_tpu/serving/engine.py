@@ -180,11 +180,20 @@ class ServingEngine:
                              if self.slot_state else 0)
         ring_for = getattr(mcfg, "paged_ring_blocks_for", None)
         self.ring_blocks = int(ring_for(bs)) if ring_for else 0
+        # a block pool whose rows are NOT keys and values by heads (a
+        # latent row a token shared by all heads: models/deepseek_v2.py).
+        # The model says ``what`` its rows are (``paged_row_kind``); the
+        # scheduler, the block manager and the prefill / chunk / decode
+        # programs take them as they take any row a block table addresses,
+        # and the mechanisms that read a row BY HEADS refuse the model
+        row_kind = getattr(mcfg, "paged_row_kind", None)
+        self.row_kind = (row_kind() if row_kind else None) or None
         # keywords omitted on purpose: a model family predating a knob
         # keeps serving exactly as before
         knobs = {}
-        if self.slot_state:
+        if self.slot_state or self.row_kind:
             self._refuse_beside_slot_state()
+        if self.slot_state:
             knobs[self.slot_state["knob"]] = self.config.decode_slots
         if self.config.kv_cache_dtype:
             knobs["kv_dtype"] = self.config.kv_cache_dtype
@@ -378,21 +387,28 @@ class ServingEngine:
 
     def _beside_slot_state(self, mechanism: str) -> str:
         """Why ``mechanism`` cannot serve this model: it moves, shares or
-        rewrites rows that a block table addresses, and knows nothing of
-        the state a slot keeps beside the table (a ring, a convolution's
-        state: the model's own words)."""
-        return (f"{mechanism} cannot serve "
-                f"{type(self.engine.module).__name__}: its "
-                f"{self.slot_state['what']}, beside the block table, and "
-                f"{mechanism} handles only the rows a block table "
-                "addresses")
+        rewrites rows of keys and values by heads that a block table
+        addresses, and knows nothing of the state a slot keeps beside the
+        table (a ring, a convolution's state) or of a row that is no keys
+        and values by heads (a latent row): the model's own words."""
+        name = type(self.engine.module).__name__
+        if self.slot_state:
+            return (f"{mechanism} cannot serve {name}: its "
+                    f"{self.slot_state['what']}, beside the block table, "
+                    f"and {mechanism} handles only the rows a block table "
+                    "addresses")
+        return (f"{mechanism} cannot serve {name}: its "
+                f"{self.row_kind['what']}, and {mechanism} handles only "
+                "rows of keys and values by heads")
 
     def _refuse_beside_slot_state(self):
         """A model that keeps state a decode slot beside the block table
         (``self.slot_state``) has a second kind of per-sequence state, and
         these mechanisms know one: each refuses the model here, by name,
-        until it is taught the second. (Live migration refuses at its
-        calls: ``export_sequence`` / ``import_sequence``.)"""
+        until it is taught the second; and so each does a model whose rows
+        are no keys and values by heads (``self.row_kind``), which none of
+        them has met. (Live migration refuses at its calls:
+        ``export_sequence`` / ``import_sequence``.)"""
         from deepspeed_tpu.runtime.config import DeepSpeedConfigError
 
         asked = {
@@ -1642,7 +1658,7 @@ class ServingEngine:
         return req
 
     def _no_migration_beside_slot_state(self, call: str):
-        if self.slot_state:
+        if self.slot_state or self.row_kind:
             raise NotImplementedError(self._beside_slot_state(
                 f"live KV migration ({call})"))
 

@@ -1,0 +1,671 @@
+"""The DeepSeek-V2 family at a small size on the CPU: the program in float32
+against the plain reference (``perfbench/reference_deepseek_v2.py``) on
+LOGITS: the plain call and each operator alone; prefill then decode
+through the paged latent pool (a whole prompt in a bucket it does not fill;
+chunks that divide the prompt, chunks that do not, a chunk boundary inside
+a block); the absorbed decode against the decompressed form and the Pallas
+kernel against both; idle rows and a pool full of NaN; the softmax router
+against its equations and over expert-parallel shares; YaRN's frequencies
+against the published formulas; the latent bytes in the engine's ledger;
+the controls that the comparisons are not blind to; and the mechanisms
+that refuse the model by name."""
+
+import dataclasses
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu
+from deepspeed_tpu.models import deepseek_v2
+from deepspeed_tpu.models.deepseek_v2 import (DeepseekV2Config,
+                                              DeepseekV2ForCausalLM,
+                                              LatentAttention, SparseExperts,
+                                              YarnScaling)
+from deepspeed_tpu.moe import dropless
+from deepspeed_tpu.ops import latent_decode_attention
+from deepspeed_tpu.parallel.topology import reset_topology
+from deepspeed_tpu.serving import ServingEngine
+from perfbench import reference_deepseek_v2 as reference
+
+# float32 program against the float32 reference, on logits of order 1: what
+# another order of summation leaves (the two agree to 3e-7 here)
+TOL = 1e-4
+BLOCK = 4
+
+
+def shape_of(cfg: DeepseekV2Config, first_expert: int = 0) -> dict:
+    """The reference's view of a program config (the family builds the
+    same from a configuration file)."""
+    yarn = cfg.rope_scaling
+    return dict(layers=cfg.num_hidden_layers, heads=cfg.num_attention_heads,
+                nope=cfg.qk_nope_head_dim, rope=cfg.qk_rope_head_dim,
+                v_dim=cfg.v_head_dim, rank=cfg.kv_lora_rank,
+                eps=cfg.rms_norm_eps, rope_theta=cfg.rope_theta,
+                yarn=None if yarn is None else dataclasses.asdict(yarn),
+                top_k=cfg.num_experts_per_tok,
+                route_scale=cfg.routed_scaling_factor,
+                dense=cfg.first_k_dense_replace, first_expert=first_expert)
+
+
+def make(dtype=jnp.float32, seed=0, **kw):
+    cfg = DeepseekV2Config.tiny(dtype=dtype, **kw)
+    module = DeepseekV2ForCausalLM(cfg)
+    params = module.init(jax.random.PRNGKey(seed),
+                         jnp.zeros((1, 8), jnp.int32))["params"]
+    return cfg, module, params
+
+
+def reference_logits(cfg, params, ids):
+    return np.asarray(reference.logits(params, jnp.asarray(ids),
+                                       shape_of(cfg)))
+
+
+def _prompts(cfg, lengths, seed=5):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, cfg.vocab_size, n).tolist() for n in lengths]
+
+
+@pytest.fixture
+def highest():
+    # the CPU multiplies float32 exactly; the setting is the chip's, kept so
+    # that the test says what it compares
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+# ---------------------------------------------------------------------------
+# the plain call, and each operator alone
+# ---------------------------------------------------------------------------
+def test_full_forward_matches_the_reference(highest):
+    cfg, module, params = make()
+    ids = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 40))
+    got = np.asarray(module.apply({"params": params}, jnp.asarray(ids)))
+    assert np.abs(got - reference_logits(cfg, params, ids)).max() <= TOL
+    # an untied head; layer 0 dense, the others sparse with shared experts
+    assert "lm_head" in params and "router" not in params["layers_0_mlp"]
+    assert set(params["layers_1_mlp"]) == {"router", "gate", "up", "down",
+                                           "shared_experts"}
+    assert set(params["layers_0_attn"]) == {
+        "q_proj", "kv_a_proj_with_mqa", "kv_a_layernorm", "kv_b_proj",
+        "o_proj"}
+
+
+@pytest.mark.parametrize("operator", ["attention", "sparse"])
+def test_an_operator_alone_matches_the_reference(highest, operator):
+    cfg, _, params = make()
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 21, cfg.hidden_size))
+    shape = shape_of(cfg)
+    if operator == "attention":
+        p = params["layers_2_attn"]
+        got, _ = LatentAttention(cfg).apply({"params": p}, x)
+        want = reference.attention(x, p, shape)
+    else:
+        p = params["layers_3_mlp"]
+        y, shared, _, chosen = SparseExperts(cfg).apply({"params": p}, x)
+        got = y + shared
+        want, picked, _ = reference.sparse(x, p, shape)
+        assert (np.sort(chosen, -1) == np.sort(picked, -1)).all()
+    scale = float(np.abs(np.asarray(want)).max())
+    assert np.abs(np.asarray(got - want)).max() <= TOL * max(scale, 1.0)
+    assert scale > 0
+
+
+def test_every_operator_moves_the_logits(highest):
+    """The comparison above is not blind to any of them: zeroing one
+    layer's ``W_kvb``, its rope key, one sparse layer's experts or its
+    shared experts moves the logits by several times the tolerance."""
+    cfg, module, params = make()
+    ids = jnp.asarray(np.random.default_rng(1).integers(
+        0, cfg.vocab_size, (1, 24)))
+    base = np.asarray(module.apply({"params": params}, ids))
+
+    def zeroed(layer, leaf, keep=None):
+        moved = dict(params)
+        was = params[layer][leaf]
+        new = jax.tree_util.tree_map(jnp.zeros_like, was)
+        if keep is not None:       # zero only the columns past ``keep``
+            new = {"kernel": was["kernel"].at[:, keep:].set(0.0)}
+        moved[layer] = {**params[layer], leaf: new}
+        return np.asarray(module.apply({"params": moved}, ids))
+
+    for layer, leaf, keep in (
+            ("layers_1_attn", "kv_b_proj", None),
+            ("layers_1_attn", "kv_a_proj_with_mqa", cfg.kv_lora_rank),
+            ("layers_2_mlp", "down", None),
+            ("layers_2_mlp", "shared_experts", None)):
+        assert np.abs(zeroed(layer, leaf, keep) - base).max() > 5 * TOL, (
+            layer, leaf)
+
+
+def test_bf16_fails_the_float32_tolerance():
+    """The lower-precision control: the same comparison with the program
+    in bfloat16 is outside the tolerance, so the tolerance tells them
+    apart."""
+    cfg, _, params = make()
+    ids = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 40))
+    low = DeepseekV2ForCausalLM(dataclasses.replace(cfg, dtype=jnp.bfloat16))
+    got = np.asarray(low.apply({"params": params}, jnp.asarray(ids)))
+    assert np.abs(got - reference_logits(cfg, params, ids)).max() > 10 * TOL
+
+
+# ---------------------------------------------------------------------------
+# the rotation and its frequencies
+# ---------------------------------------------------------------------------
+def test_yarn_frequencies_follow_the_published_formulas():
+    """The source's ``DeepseekV2YarnRotaryEmbedding`` in numpy, at the
+    published sizes: correction dims 10 and 23 of 32, the blend between,
+    cos and sin times mscale / mscale_all_dim = 1; the softmax scale
+    0.1147."""
+    dim, theta, s = 64, 10000.0, YarnScaling()
+    inv, factor = deepseek_v2.yarn_frequencies(dim, theta, s)
+
+    def where(rot):
+        return dim * math.log(s.original_max_position_embeddings
+                              / (rot * 2 * math.pi)) / (2 * math.log(theta))
+
+    low, high = math.floor(where(s.beta_fast)), math.ceil(where(s.beta_slow))
+    assert (low, high) == (10, 23)
+    extra = 1.0 / theta ** (np.arange(0, dim, 2) / dim)
+    mask = 1.0 - np.clip((np.arange(dim // 2) - low) / (high - low), 0, 1)
+    want = extra / s.factor * (1 - mask) + extra * mask
+    assert np.allclose(np.asarray(inv), want, rtol=1e-6)
+    assert np.allclose(want[:10], extra[:10]) and np.allclose(
+        want[24:], extra[24:] / 40)
+    assert factor == 1.0
+    assert DeepseekV2Config().softmax_scale == pytest.approx(
+        192 ** -0.5 * (0.1 * 0.707 * math.log(40) + 1) ** 2)
+    ref_inv, ref_factor = reference.yarn(dim, theta, dataclasses.asdict(s))
+    assert np.allclose(np.asarray(ref_inv), want, rtol=1e-6)
+    assert ref_factor == 1.0
+
+
+def test_the_rotation_deinterleaves_its_pairs():
+    """``(x_0, x_1)`` is the first pair: it lands on lanes 0 and rope / 2,
+    rotated by the first frequency."""
+    cfg = DeepseekV2Config.tiny(dtype=jnp.float32, rope_scaling=None)
+    x = np.zeros((1, 1, cfg.qk_rope_head_dim), np.float32)
+    x[0, 0, 0], x[0, 0, 1] = 1.0, 2.0
+    got = np.asarray(deepseek_v2.rotate_pairs(
+        jnp.asarray(x), jnp.asarray([[3]]), cfg))[0, 0]
+    half = cfg.qk_rope_head_dim // 2
+    c, s = math.cos(3.0), math.sin(3.0)
+    assert got[0] == pytest.approx(c - 2 * s) and got[half] == pytest.approx(
+        2 * c + s)
+    assert np.abs(np.delete(got, [0, half])).max() == 0.0
+
+
+# ---------------------------------------------------------------------------
+# the router
+# ---------------------------------------------------------------------------
+def test_the_router_follows_its_equations():
+    """Softmax over all the experts in float32, the top k chosen, the
+    weights the probabilities AS THEY STAND times the scaling factor: not
+    renormalised, no bias."""
+    rng = np.random.default_rng(7)
+    x = rng.normal(size=(9, 16)).astype(np.float32)
+    w = rng.normal(size=(16, 32)).astype(np.float32)
+    experts, weights = dropless.route(
+        jnp.asarray(x), jnp.asarray(w), None, 6, scale=2.5,
+        scoring="softmax", renormalize=False)
+    logits = x.astype(np.float64) @ w
+    probs = np.exp(logits - logits.max(1, keepdims=True))
+    probs /= probs.sum(1, keepdims=True)
+    want = np.argsort(-probs, axis=1)[:, :6]
+    assert (np.sort(np.asarray(experts), 1) == np.sort(want, 1)).all()
+    picked = np.take_along_axis(probs, np.asarray(experts), 1)
+    assert np.allclose(np.asarray(weights), 2.5 * picked, rtol=1e-5)
+    assert (np.asarray(weights).sum(1) < 2.5).all()      # not renormalised
+    with pytest.raises(ValueError, match="scoring"):
+        dropless.route(jnp.asarray(x), jnp.asarray(w), None, 6,
+                       scoring="tanh")
+
+
+def test_the_other_families_router_is_the_program_it_was():
+    """``route`` at its defaults, and with the new arguments spelled out
+    at their defaults, traces the operations it traced before they came
+    (MiMo-V2's and LFM2's programs are the parent's text)."""
+    x, w, b = jnp.ones((5, 8)), jnp.ones((8, 16)), jnp.zeros((16,))
+
+    def was(x, router_kernel, selection_bias, top_k=4):
+        scores = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), router_kernel.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, experts = jax.lax.top_k(
+            scores + selection_bias.astype(jnp.float32)[None], top_k)
+        chosen = jnp.take_along_axis(scores, experts, axis=1)
+        return (experts.astype(jnp.int32),
+                chosen / jnp.sum(chosen, axis=1, keepdims=True))
+
+    text = str(jax.make_jaxpr(was)(x, w, b))
+    now = lambda x, w, b: dropless.route(x, w, b, 4)
+    given = lambda x, w, b: dropless.route(x, w, b, 4, scoring="sigmoid",
+                                           renormalize=True)
+    soft = lambda x, w, b: dropless.route(x, w, None, 4, scoring="softmax",
+                                          renormalize=False)
+    assert str(jax.make_jaxpr(now)(x, w, b)) == text
+    assert str(jax.make_jaxpr(given)(x, w, b)) == text
+    assert str(jax.make_jaxpr(soft)(x, w, b)) != text
+
+
+def test_the_shares_of_the_experts_add_up_to_the_whole_layer(highest):
+    """Softmax routing over ALL the experts on every rank, each rank the
+    terms of the experts it holds (``held_range``): the shares' sum, with
+    the shared experts (which every rank computes alike) counted ONCE, is
+    the uncut reference's layer."""
+    cfg, _, params = make()
+    p = params["layers_2_mlp"]
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 17, cfg.hidden_size))
+    want, picked, _ = reference.sparse(x, p, shape_of(cfg))
+    total, shared_terms = 0.0, []
+    for rank in range(4):
+        part = dataclasses.replace(cfg, ep_rank=rank, ep_size=4)
+        first, count = dropless.held_range(cfg.n_routed_experts, rank, 4)
+        held = {**p, **{k: p[k][first:first + count]
+                        for k in ("gate", "up", "down")}}
+        y, shared, counters, chosen = SparseExperts(part).apply(
+            {"params": held}, x)
+        assert (np.sort(chosen, -1) == np.sort(picked, -1)).all()
+        assert int(counters[1]) == count           # experts held here
+        # the reference's share of the same experts, without the shared
+        ref_part = reference.expert_terms(
+            x.reshape(-1, cfg.hidden_size), held, first,
+            *reference.routed(x.reshape(-1, cfg.hidden_size), p,
+                              shape_of(cfg))[:2]).reshape(x.shape)
+        assert np.abs(np.asarray(y - ref_part)).max() <= TOL
+        total = total + y
+        shared_terms.append(shared)
+    for other in shared_terms[1:]:
+        assert np.abs(np.asarray(other - shared_terms[0])).max() == 0.0
+    assert np.abs(np.asarray(total + shared_terms[0] - want)).max() <= TOL
+
+
+@pytest.mark.parametrize("width, tile", [(2048, 512), (1792, 896),
+                                         (1408, 128), (32, 32)])
+def test_a_width_of_eleven_registers_is_tiled_by_one(width, tile):
+    """1408 = 11 x 128, a prime number of registers: no run of whole
+    registers up to 1,024 divides it but one (``width_tile``'s docstring
+    and PERF.md say what that costs and what was read on the chip); 2048
+    and 1792 keep the tiles they had."""
+    assert dropless.width_tile(width) == tile and width % tile == 0
+
+
+# ---------------------------------------------------------------------------
+# through the paged latent pool
+# ---------------------------------------------------------------------------
+def serving_engine(params, cfg, **serving):
+    reset_topology()
+    block = {"decode_slots": 3, "block_size": BLOCK, "max_model_len": 64,
+             **serving}
+    return ServingEngine(deepspeed_tpu.init_inference(
+        DeepseekV2ForCausalLM(cfg), params=params, dtype=cfg.dtype,
+        serving=block))
+
+
+def _paged_logits(srv, prompt, steps, slot=1, chunk=0):
+    """Drive the engine's own paged module with its own pool and tables,
+    as its programs do, and keep the LOGITS: every prompt position (the
+    whole prompt right-padded into a bucket it does NOT fill, or chunks of
+    ``chunk``), then ``steps`` greedy decode steps in the decode program's
+    batch shape, the other slots idle. -> (logits [positions, vocab],
+    ids)."""
+    dm, params = srv._dmodule, srv.engine.params
+
+    def call(prefill):
+        def fn(cache, ids, tables, lengths, num_valid):
+            out, v = dm.apply(
+                {"params": params, "cache": cache}, ids, mutable=["cache"],
+                paging={"block_tables": tables, "lengths": lengths,
+                        "num_valid": num_valid, "prefill": prefill})
+            return out[0], v["cache"]
+        return jax.jit(fn)
+
+    whole, cached = call(True), call(False)
+    rid = f"direct-{slot}-{len(prompt)}"
+    table = srv._slot_table(slot, srv.block_mgr.allocate(
+        rid, len(prompt) + steps))
+    i32 = lambda x: jnp.asarray(x, jnp.int32)
+    rows, n = [], len(prompt)
+    for at in range(0, n, chunk or n):
+        m = min(chunk or n, n - at)
+        width = chunk or (-(-n // 8) * 8 + 8)     # never filled
+        ids = np.zeros((1, width), np.int32)
+        ids[0, :m] = prompt[at:at + m]
+        lg, srv.cache = (cached if chunk else whole)(
+            srv.cache, i32(ids), i32(table[None]), i32([at]), i32([m]))
+        rows.append(np.asarray(lg[0, :m]))
+    slots = srv.config.decode_slots
+    tables = np.zeros((slots, len(table)), np.int32)
+    tables[slot] = table
+    tokens = list(prompt)
+    for _ in range(steps):
+        tokens.append(int(rows[-1][-1].argmax()))
+        lengths, last = np.zeros(slots, np.int32), np.zeros((slots, 1),
+                                                            np.int32)
+        lengths[slot], last[slot] = len(tokens) - 1, tokens[-1]
+        lg, srv.cache = cached(srv.cache, i32(last), i32(tables),
+                               i32(lengths), jnp.ones(slots, jnp.int32))
+        rows.append(np.asarray(lg[slot]))
+    srv.block_mgr.release(rid)
+    return np.concatenate(rows), tokens
+
+
+@pytest.mark.parametrize("chunk", [0, 8, 7, 6], ids=[
+    "whole-prompt", "chunks-of-8", "chunks-that-do-not-divide",
+    "a-boundary-inside-a-block"])
+def test_paged_logits_match_the_reference(highest, chunk):
+    """Prefill then decode through the latent pool against the reference's
+    full forward pass, on LOGITS at every position: a prompt of 27 in a
+    bucket of 40; in chunks of 8 (the last holds 3 real positions); of 7
+    (27 = 3 x 7 + 6); of 6 (every other chunk starts inside a block of 4).
+    The prefill is decompressed, the decode steps absorbed."""
+    cfg, _, params = make()
+    srv = serving_engine(params, cfg)
+    try:
+        got, tokens = _paged_logits(srv, _prompts(cfg, [27])[0], 14,
+                                    chunk=chunk)
+        want = reference_logits(cfg, params, np.asarray([tokens]))[0]
+        assert np.abs(got - want[:len(got)]).max() <= TOL
+        paths = srv.stats()["attention_paths"]
+        assert paths.get("mla_decode_absorbed_xla")
+        assert paths.get("mla_chunk_decompressed_xla" if chunk
+                         else "mla_prefill_decompressed_xla")
+    finally:
+        srv.destroy()
+
+
+def test_a_slots_next_request_reads_none_of_its_last_ones_rows(highest):
+    """Two requests one after the other over the SAME blocks (the pool has
+    room for one), the second shorter: each is the reference's."""
+    cfg, _, params = make()
+    srv = serving_engine(params, cfg, decode_slots=1, num_blocks=1 + 10)
+    try:
+        for prompt, chunk in zip(_prompts(cfg, [30, 7, 11]), (0, 0, 8)):
+            got, tokens = _paged_logits(srv, prompt, 5, slot=0, chunk=chunk)
+            want = reference_logits(cfg, params, np.asarray([tokens]))[0]
+            assert np.abs(got - want[:len(got)]).max() <= TOL, len(prompt)
+    finally:
+        srv.destroy()
+
+
+def test_a_pool_full_of_nan_outside_the_live_prefixes_stays_outside(highest):
+    """Idle rows and everything past a sequence's live prefix weigh 0 and
+    are never read into a sum: with the pool filled with NaN first, every
+    logit of every row (the idle slots' too) is finite, and the busy
+    slot's are the reference's."""
+    cfg, _, params = make()
+    srv = serving_engine(params, cfg)
+    try:
+        srv.cache = jax.tree_util.tree_map(
+            lambda x: jnp.full_like(x, jnp.nan), srv.cache)
+        for chunk in (0, 6):
+            got, tokens = _paged_logits(srv, _prompts(cfg, [13])[0], 6,
+                                        chunk=chunk)
+            assert np.isfinite(got).all()
+            want = reference_logits(cfg, params, np.asarray([tokens]))[0]
+            assert np.abs(got - want[:len(got)]).max() <= TOL
+        # the decode program's whole batch: the idle rows' logits too
+        dm = srv._dmodule
+        out, _ = dm.apply(
+            {"params": srv.engine.params, "cache": srv.cache},
+            jnp.zeros((3, 1), jnp.int32), mutable=["cache"],
+            paging={"block_tables": jnp.zeros((3, 16), jnp.int32),
+                    "lengths": jnp.zeros((3,), jnp.int32),
+                    "num_valid": jnp.ones((3,), jnp.int32),
+                    "prefill": False})
+        assert np.isfinite(np.asarray(out[0])).all()
+    finally:
+        srv.destroy()
+
+
+def _pooled_step(cfg, params, n=21, seed=3):
+    """One layer's pool holding ``n`` rows of a sequence through a table in
+    scrambled block order, and a decode step's queries at position ``n``:
+    ``(module, bound call, its arguments)``."""
+    rng = np.random.default_rng(seed)
+    dcfg = cfg.for_paged_decode(12, BLOCK)
+    attn = LatentAttention(dcfg)
+    p = params["layers_1_attn"]
+    blocks = rng.permutation(np.arange(1, 12))[:8]
+    table = np.zeros((2, 8), np.int32)
+    table[1] = blocks
+    pool = jnp.zeros((dcfg.num_hidden_layers, 12, BLOCK, dcfg.latent_lanes))
+    x = jax.random.normal(jax.random.PRNGKey(seed), (2, n + 1,
+                                                     cfg.hidden_size))
+    paging = {"block_tables": jnp.asarray(table),
+              "lengths": jnp.zeros((2,), jnp.int32),
+              "num_valid": jnp.asarray([0, n], jnp.int32), "prefill": True}
+    _, pool = attn.apply({"params": p}, x[:, :n], paging, pool, 1)
+    step = {"block_tables": jnp.asarray(table),
+            "lengths": jnp.asarray([0, n], jnp.int32),
+            "num_valid": jnp.ones((2,), jnp.int32), "prefill": False}
+    return attn, p, x, pool, step, n
+
+
+def test_absorbed_decode_is_the_decompressed_form(highest, monkeypatch):
+    """One decode step over the same pool rows both ways: ``W_kvb`` folded
+    into the query and the output (what the step runs), and the rows
+    decompressed into keys and values by heads (what a chunk of one token
+    would run): the same function."""
+    cfg, _, params = make()
+    attn, p, x, pool, step, n = _pooled_step(cfg, params)
+    absorbed, _ = attn.apply({"params": p}, x[:, n:], step, pool, 1)
+    # a "chunk" of one token: the decompressed path over the gathered rows
+    forced = dict(step)
+    monkeypatch.setattr(
+        LatentAttention, "_absorbed_xla",
+        lambda self, *a: (_ for _ in ()).throw(AssertionError("absorbed")))
+    two = {**forced, "num_valid": jnp.asarray([1, 1], jnp.int32)}
+    padded = jnp.concatenate([x[:, n:], jnp.zeros_like(x[:, n:])], axis=1)
+    decompressed, _ = attn.apply({"params": p}, padded, two, pool, 1)
+    assert np.abs(np.asarray(absorbed[1, 0] - decompressed[1, 0])).max() \
+        <= 1e-5
+    # and both are the reference's attention at that position
+    want = reference.attention(x[1:], p, shape_of(cfg))[0, n]
+    assert np.abs(np.asarray(absorbed[1, 0] - want)).max() <= 1e-5
+
+
+def test_the_latent_kernel_is_the_absorbed_xla_path(monkeypatch):
+    """The Pallas kernel (interpret mode) over a table in scrambled block
+    order, beside an idle slot, against the same step's XLA tiles; its
+    idle row is zeros."""
+    from deepspeed_tpu.ops import attention as ops_attention
+    from deepspeed_tpu.utils.compat import tpu_interpret_mode
+
+    cfg, _, params = make()
+    attn, p, x, pool, step, n = _pooled_step(cfg, params, n=29)
+    want, _ = attn.apply({"params": p}, x[:, n:], step, pool, 1)
+    monkeypatch.setattr(ops_attention, "use_decode_kernel", lambda: True)
+    # 4 blocks of 4 keys a grid step: the row's 30 keys are two tiles, the
+    # second with two blocks past the live prefix (at the published 512
+    # keys a step the interpreter would move 128 operands a step)
+    monkeypatch.setattr(latent_decode_attention, "LATENT_TILE_KEYS", 16)
+    with tpu_interpret_mode():
+        got, _ = attn.apply({"params": p}, x[:, n:], step, pool, 1)
+        got = jax.block_until_ready(got)
+    assert np.abs(np.asarray(got[1] - want[1])).max() <= 1e-5
+    assert np.abs(np.asarray(want[1])).max() > 1e-3
+
+
+def test_decode_through_both_kernels_matches_the_xla_paths(monkeypatch):
+    """The decode program with the Pallas kernels in it (interpret mode):
+    the latent multi-query kernel and the grouped expert matmul, against
+    the same steps on the XLA paths."""
+    from deepspeed_tpu.ops import attention as ops_attention
+    from deepspeed_tpu.utils.compat import tpu_interpret_mode
+
+    cfg, _, params = make()
+    prompt = _prompts(cfg, [19])[0]
+    plain = serving_engine(params, cfg)
+    want, _ = _paged_logits(plain, prompt, 3)
+    plain.destroy()
+    monkeypatch.setattr(ops_attention, "use_decode_kernel", lambda: True)
+    monkeypatch.setattr(latent_decode_attention, "LATENT_TILE_KEYS", 16)
+    ffn = dropless.expert_ffn
+    monkeypatch.setattr(dropless, "expert_ffn", lambda *a, **k: ffn(
+        *a, **{**k, "use_kernel": True}))
+    srv = serving_engine(params, cfg)
+    try:
+        one = jax.devices()[0]
+        srv.engine.params, srv.cache = jax.device_put(
+            (srv.engine.params, srv.cache), one)
+        with tpu_interpret_mode():
+            got, _ = _paged_logits(srv, prompt, 3)
+        paths = srv.stats()["attention_paths"]
+        assert paths.get("mla_decode_absorbed_kernel") and paths.get(
+            "moe_experts_grouped_kernel")
+        assert np.abs(got - want).max() <= TOL
+    finally:
+        srv.destroy()
+
+
+@pytest.mark.parametrize("control", ["latent", "kvb"])
+def test_a_lower_precision_moves_the_logits(highest, monkeypatch, control):
+    """The controls the chip's check has to fail: the latent row through
+    float8 on its way into the pool; ``W_kvb``'s absorbed halves through
+    float8 (the decode steps alone feel those). Either moves the logits by
+    hundreds of times the tolerance."""
+    low = lambda w: w.astype(jnp.float8_e4m3fn).astype(w.dtype)
+    if control == "latent":
+        row = deepseek_v2.pool_row
+        monkeypatch.setattr(deepseek_v2, "pool_row", lambda c, k_pe, lanes:
+                            low(row(c, k_pe, lanes)))
+    else:
+        halves = deepseek_v2.absorbed_halves
+        monkeypatch.setattr(
+            deepseek_v2, "absorbed_halves",
+            lambda w, nope: tuple(low(h) for h in halves(w, nope)))
+    cfg, _, params = make()
+    srv = serving_engine(params, cfg)
+    try:
+        prompt = _prompts(cfg, [27])[0]
+        got, tokens = _paged_logits(srv, prompt, 10, chunk=8)
+        want = reference_logits(cfg, params, np.asarray([tokens]))[0]
+        miss = np.abs(got - want[:len(got)]).max(-1)
+        assert miss[len(prompt):].max() > 20 * TOL
+        if control == "kvb":       # the prefill never reads those halves
+            assert miss[:len(prompt)].max() <= TOL
+    finally:
+        srv.destroy()
+
+
+# ---------------------------------------------------------------------------
+# through the engine
+# ---------------------------------------------------------------------------
+def test_the_engine_serves_chunked_prefill_and_counts_latent_bytes(highest):
+    """``init_inference`` -> ``ServingEngine`` with ``prefill_chunk_tokens``
+    set: the served tokens are the reference's greedy tokens, the routed
+    sets the reference's own, and the ledger counts 576 values a live
+    token a layer at each step boundary."""
+    cfg, _, params = make()
+    prompts = _prompts(cfg, [21, 6, 33])
+    srv = serving_engine(params, cfg, prefill_chunk_tokens=8,
+                         routed_experts_kept=4)
+    try:
+        reqs = [srv.submit(p, max_new_tokens=n)
+                for p, n in zip(prompts, [9, 12, 5])]
+        srv.drain()
+        sparse, k = cfg.sparse_layers, cfg.num_experts_per_tok
+        for req, prompt in zip(reqs, prompts):
+            ids = np.asarray([list(prompt) + req.tokens[:-1]])
+            want = reference_logits(cfg, params, ids)[0]
+            assert req.tokens == want[len(prompt) - 1:].argmax(-1).tolist()
+            got = srv.routed_experts(req.request_id)
+            assert got.shape == (ids.shape[1], sparse * k)
+            sets = np.asarray(reference.routed_sets(
+                params, jnp.asarray(ids), shape_of(cfg)))[:, 0]
+            got = got.reshape(-1, sparse, k).transpose(1, 0, 2)
+            assert (np.sort(got, -1) == np.sort(sets, -1)).all()
+        stats = srv.stats()
+        assert set(stats["kv_live_bytes"]) == {"latent"}
+        row = cfg.num_hidden_layers * cfg.latent_row * 4       # float32
+        assert stats["kv_live_bytes"]["latent"] > 0
+        assert stats["kv_live_bytes"]["latent"] % row == 0
+        assert srv._dmodule.config.kv_bytes_per_token() == {"latent": row}
+        # (the paths are counted as programs are traced, process-wide)
+        assert stats["attention_paths"].get("mla_chunk_decompressed_xla")
+        assert set(srv._chunk_fns) == {8} and not srv._prefill_fns
+        assert stats["model_counters"]["decode"]["experts_held"] > 0
+        assert (stats["model_counters"]["prefill"]["pairs_here"]
+                == stats["model_counters"]["prefill"]["pairs_all"])
+        # ONE pool, a latent row a token, through the block table; no
+        # state a slot
+        assert {k: v.shape for k, v in srv.cache.items()} == {
+            "latent_pool": (cfg.num_hidden_layers, srv.num_blocks, BLOCK,
+                            cfg.latent_lanes)}
+        assert srv.slot_state is None and srv.slot_entries == 0
+    finally:
+        srv.destroy()
+
+
+def test_the_published_row_is_576_values_in_640_lanes():
+    cfg = DeepseekV2Config(num_hidden_layers=6)
+    assert (cfg.latent_row, cfg.latent_lanes) == (576, 640)
+    assert cfg.kv_bytes_per_token() == {"latent": 6_912}
+    assert cfg.kv_live_bytes(np.asarray([100, 28])) == {
+        "latent": 128 * 6_912}
+    # a ninth of what its heads' keys and values would keep
+    by_heads = 16 * (192 + 128) * 2
+    assert by_heads == 10_240 and 1_152 * 9 > by_heads > 1_152 * 8
+    assert cfg.routed_width == 5 * 6 and cfg.sparse_layers == 5
+
+
+# ---------------------------------------------------------------------------
+# refusals, by name
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("serving, mechanism", [
+    ({"prefix_cache": True}, "serving.prefix_cache"),
+    ({"speculative": {"num_speculative_tokens": 2}}, "serving.speculative"),
+    ({"kv_cache_dtype": "int8"}, "serving.kv_cache_dtype"),
+], ids=["prefix-cache", "speculation", "int8-kv"])
+def test_mechanisms_that_read_rows_by_heads_refuse_the_model(serving,
+                                                             mechanism):
+    cfg, _, params = make()
+    with pytest.raises(Exception, match=mechanism.replace(".", r"\.")) as e:
+        serving_engine(params, cfg, **serving)
+    assert "DeepseekV2ForCausalLM" in str(e.value)
+    assert "one latent row a token" in str(e.value)
+    assert "keys and values by heads" in str(e.value)
+
+
+def test_tensor_parallel_refuses_the_model():
+    cfg, _, params = make()
+    reset_topology()
+    with pytest.raises(Exception, match="tp_size > 1") as e:
+        ServingEngine(deepspeed_tpu.init_inference(
+            DeepseekV2ForCausalLM(cfg), params=params, dtype=cfg.dtype,
+            tensor_parallel={"tp_size": 2},
+            serving={"decode_slots": 2, "block_size": BLOCK,
+                     "max_model_len": 32}))
+    assert "DeepseekV2ForCausalLM" in str(e.value)
+    assert "latent row" in str(e.value)
+    reset_topology()
+
+
+def test_migration_refuses_the_model():
+    cfg, _, params = make()
+    srv = serving_engine(params, cfg)
+    try:
+        req = srv.submit([1, 2, 3, 4, 5], max_new_tokens=8)
+        srv.step()
+        for call in (lambda: srv.export_sequence(req.request_id),
+                     lambda: srv.import_sequence({"request_id": "x"})):
+            with pytest.raises(NotImplementedError, match="migration") as e:
+                call()
+            assert "latent row" in str(e.value)
+    finally:
+        srv.destroy()
+
+
+def test_for_paged_decode_refuses_what_it_cannot_size():
+    cfg = DeepseekV2Config.tiny()
+    with pytest.raises(ValueError, match="kv_cache_dtype"):
+        cfg.for_paged_decode(9, 4, kv_dtype="int8")
+    with pytest.raises(ValueError, match="experts over"):
+        DeepseekV2Config.tiny(ep_size=5)
+    with pytest.raises(ValueError, match="rotates pairs"):
+        DeepseekV2Config.tiny(qk_rope_head_dim=7)
+    assert cfg.paged_row_kind()["kind"] == "latent"
+    assert not hasattr(cfg, "paged_slot_state_for")

@@ -1116,7 +1116,10 @@ class DeepSpeedEngine:
                 f"{d['leaves_persistent']} persistent; a step and chip "
                 f"all-gathers {d['gather_operand_bytes_step']} operand "
                 f"bytes ({', '.join(d['wire_dtypes'])}) and reduce-scatters "
-                f"{d['scatter_operand_bytes_step']} (float32)", ranks=[0])
+                f"{d['scatter_operand_bytes_step']} (float32), "
+                f"{d['leaves_scattered_by_ring']} leaves by a ring of "
+                f"{d['ring_permutes_step']} float32 permutes "
+                f"({d['ring_operand_bytes_step']} operand bytes)", ranks=[0])
 
     # ------------------------------------------------------------------
     # jitted hot paths

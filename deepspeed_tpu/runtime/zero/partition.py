@@ -25,11 +25,16 @@ gather/release hooks (``runtime/zero/partition_parameters.py:1042``,
     inside the rematerialised region, one layer's slice of every sharded
     leaf is cast to the compute dtype ON THE SHARD and all-gathered (bf16
     on the wire; backward gathers again, so no gathered weight is ever a
-    residual); its gradient is cast up to float32 and reduce-scattered, so
-    the sum across chips is float32 and each chip ends holding its own
-    shard. The tables outside the scan (``wte``, ``wpe``, an untied head)
-    are gathered once for all their uses, bf16 on the wire, float32
-    values. Activations are each chip's own rows from embedding to loss:
+    residual); its gradient is cast up to float32, summed and scattered,
+    so the sum across chips is float32 and each chip ends holding its own
+    shard: by a ring of float32 ``ppermute``s and adds
+    (:func:`ring_reduce_scatter`; asynchronous on the chip, so the rest of
+    the layer's backward runs beside them) where the scatter group is 2 to
+    8 chips, by the backend's reduce-scatter otherwise
+    (:func:`scatter_form`). The tables outside the scan (``wte``, ``wpe``,
+    an untied head) are gathered once for all their uses, bf16 on the
+    wire, float32 values; their gradients are summed the same way.
+    Activations are each chip's own rows from embedding to loss:
     no collective ever moves one. The loss is the mean over chips of each
     chip's batch mean, as the reference's is.
   * any other model (a user's module, the unrolled ``LoopBlocks``,
@@ -378,14 +383,31 @@ class GatherPlan:
         trips = int(np.prod(shape[:stacked] or (1,)))
         full = int(np.prod(shape[stacked:]))
         used = [a for _, axes in dims for a in axes]
-        group = int(np.prod([self.mesh.shape[a] for a in used]))
+        sizes = [int(np.prod([self.mesh.shape[a] for a in axes]))
+                 for _, axes in dims]
+        group = int(np.prod(sizes))
+        form = scatter_form(sizes)
+        # a ring's permutes and the bytes a chip feeds them: the dims are
+        # scattered last to first, each over what the ones before it left
+        left, steps, permutes, ring_bytes = full * 4, 0, 0, 0
+        if form == "ring":
+            for (dim, _), n in zip(reversed(dims), reversed(sizes)):
+                left //= n
+                steps += n - 1
+                permutes += (n - 1) * len(
+                    _ring_parts(n, shape[stacked + dim] // n))
+                ring_bytes += (n - 1) * left
         self.served[path] = {
             "in_scan": stacked > 0, "trips": trips,
             "gather_operand_bytes": full // group * wire.itemsize,
             "scatter_operand_bytes": full * 4,
+            "scatter_form": form, "ring_steps": steps,
+            "ring_permutes": permutes, "ring_operand_bytes": ring_bytes,
             "wire_dtype": wire.name}
         return _gather_leaf(
-            w, dims, tuple(a for a in self.axes if a not in used), wire, out)
+            w, [(dim, axes, n if form == "ring" else 0)
+                for (dim, axes), n in zip(dims, sizes)],
+            tuple(a for a in self.axes if a not in used), wire, out)
 
     def gather(self, tree, path: Sequence[str], dtype=None, stacked: int = 0,
                keep_dtype: bool = False):
@@ -430,7 +452,10 @@ class GatherPlan:
         """JSON-safe plan of one training step on one chip: which leaves
         are gathered where, and the operand bytes of the all-gathers and
         reduce-scatters (what each chip feeds them; a scanned leaf is
-        gathered again in the rematerialised backward)."""
+        gathered again in the rematerialised backward). Of the scattered
+        gradients, which leaves the ring took (:func:`scatter_form`), in
+        how many permutes (trips x steps x ways) and operand bytes: a
+        ring over ``n`` chips sends ``(n - 1) / n`` of what it scatters."""
         scan = {p: r for p, r in self.served.items() if r["in_scan"]}
         once = {p: r for p, r in self.served.items() if not r["in_scan"]}
         n_leaves = len(jax.tree_util.tree_leaves(
@@ -441,6 +466,7 @@ class GatherPlan:
                                  for r in once.values())
         scattered = sum(r["trips"] * r["scatter_operand_bytes"]
                         for r in self.served.values())
+        ring = [r for r in self.served.values() if r["ring_steps"]]
         return {"program": "gather_at_use", "axes": list(self.axes),
                 "leaves_gathered_in_scan": len(scan),
                 "leaves_gathered_once": len(once),
@@ -448,23 +474,86 @@ class GatherPlan:
                 "gather_operand_bytes_step": int(gathered),
                 "gather_operand_bytes_in_scan": int(in_scan),
                 "scatter_operand_bytes_step": int(scattered),
+                "leaves_scattered_by_ring": len(ring),
+                "ring_permutes_step": int(sum(
+                    r["trips"] * r["ring_permutes"] for r in ring)),
+                "ring_operand_bytes_step": int(sum(
+                    r["trips"] * r["ring_operand_bytes"] for r in ring)),
                 "wire_dtypes": sorted({r["wire_dtype"]
                                        for r in self.served.values()})}
+
+
+# the scatter groups an unrolled ring serves: n - 1 steps each way
+RING_GROUPS = range(2, 9)
+
+
+def scatter_form(groups: Sequence[int]) -> str:
+    """How the float32 gradient of a gathered leaf is summed and scattered,
+    from what the plan observes of the mesh: ``groups``, the chips each
+    sharded dim is split over. ``"ring"`` (:func:`ring_reduce_scatter`)
+    where every group is 2 to 8 chips: the ring's permutes are
+    asynchronous on the chip, so the rest of the layer's backward runs
+    beside a kernel's, and the optimizer's first updates beside a
+    table's. ``"reduce_scatter"``, the backend's own (synchronous on the
+    chip), for a dim split over more chips than an unrolled ring should
+    have steps."""
+    return "ring" if groups and all(
+        n in RING_GROUPS for n in groups) else "reduce_scatter"
+
+
+def _ring_parts(n: int, piece: int):
+    """How a ring over ``n`` chips cuts a piece of ``piece`` rows:
+    ``(first row, rows, hop)`` of each part. Two halves that travel
+    opposite ways, so both directions of every link carry half the bytes,
+    where the ring has two ways and the piece two rows."""
+    half = piece // 2 if n > 2 else 0
+    if not half:
+        return [(0, piece, 1)]
+    return [(0, half, 1), (half, piece - half, -1)]
+
+
+def ring_reduce_scatter(ct, axes, dim: int, n: int):
+    """``psum_scatter(ct, axes, scatter_dimension=dim, tiled=True)`` over a
+    group of ``n`` chips written out as a ring in the axes' index order:
+    ``ct`` is cut in ``n`` pieces along ``dim``; in each of ``n - 1``
+    steps a chip sends one partial sum to its neighbour, receives one and
+    adds its own contribution, in ``ct``'s dtype on the wire and in every
+    add; after the last step chip ``i`` holds the sum of piece ``i``."""
+    piece = ct.shape[dim] // n
+    me = jax.lax.axis_index(axes)
+    parts = []
+    for first, rows, hop in _ring_parts(n, piece):
+        perm = [(i, (i + hop) % n) for i in range(n)]
+
+        def own(j, first=first, rows=rows):
+            return jax.lax.dynamic_slice_in_dim(
+                ct, (j % n) * piece + first, rows, axis=dim)
+
+        # the partial sum that arrives at step s is piece ``me - s * hop``'s
+        part = own(me - hop)
+        for s in range(2, n + 1):
+            part = jax.lax.ppermute(part, axes, perm) + own(me - s * hop)
+        parts.append(part)
+    return parts[0] if len(parts) == 1 else jax.numpy.concatenate(
+        parts, axis=dim)
 
 
 def _gather_leaf(w, dims, other_axes, wire_dtype, out_dtype):
     """``w`` (this chip's shard) -> the whole weight: cast to the wire
     dtype ON THE SHARD, all-gathered over the ZeRO axes of each sharded
-    dim. Its transpose is written out: the cotangent is cast UP to
-    float32 and reduce-scattered, so every chip ends holding its own
-    shard of the float32 sum (and the sum over ``other_axes``, the manual
-    axes this leaf is whole on: hpZ's ``data``)."""
+    dim (``dims``: ``(dim, axes, ring)``). Its transpose is written out:
+    the cotangent is cast UP to float32 and reduce-scattered, by a ring of
+    ``ring`` chips (:func:`ring_reduce_scatter`) or, ``ring`` 0, by the
+    backend's ``psum_scatter`` (:func:`scatter_form`), so every chip ends
+    holding its own shard of the float32 sum (and the sum over
+    ``other_axes``, the manual axes this leaf is whole on: hpZ's
+    ``data``)."""
     in_dtype = w.dtype
 
     @jax.custom_vjp
     def zero3_gather(w):  # the name is what save_all_but_gathered reads
         g = w.astype(wire_dtype)
-        for dim, axes in dims:
+        for dim, axes, _ in dims:
             g = jax.lax.all_gather(g, axes, axis=dim, tiled=True)
         return g.astype(out_dtype)
 
@@ -473,9 +562,10 @@ def _gather_leaf(w, dims, other_axes, wire_dtype, out_dtype):
 
     def bwd(_, ct):
         ct = ct.astype(np.float32)
-        for dim, axes in reversed(dims):
-            ct = jax.lax.psum_scatter(ct, axes, scatter_dimension=dim,
-                                      tiled=True)
+        for dim, axes, ring in reversed(dims):
+            ct = ring_reduce_scatter(ct, axes, dim, ring) if ring else \
+                jax.lax.psum_scatter(ct, axes, scatter_dimension=dim,
+                                     tiled=True)
         if other_axes:
             ct = jax.lax.psum(ct, other_axes)
         return (ct.astype(in_dtype),)

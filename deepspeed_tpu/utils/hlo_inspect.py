@@ -38,6 +38,20 @@ _GROUPS_IOTA_RE = re.compile(
     r"(?:T\((\d+(?:,\d+)*)\))?")
 
 
+_PAIRS_RE = re.compile(
+    r"source_target_pairs=\{(\{\d+,\d+\}(?:,\{\d+,\d+\})*)\}")
+
+
+def parse_source_target_pairs(line: str) -> Optional[List[Tuple[int, int]]]:
+    """The ``(source, target)`` pairs of one ``collective-permute`` line,
+    ``None`` for a line that carries none."""
+    m = _PAIRS_RE.search(line)
+    if not m:
+        return None
+    return [tuple(int(x) for x in pair.split(","))
+            for pair in m.group(1)[1:-1].split("},{")]
+
+
 def parse_replica_groups(line: str) -> Optional[List[List[int]]]:
     """The replica groups of one HLO collective line as a list of member
     lists, or ``None`` when the line carries no ``replica_groups=``.
@@ -138,7 +152,9 @@ def _result_type(rest: str) -> str:
 
 def parse_collectives(hlo_text: str) -> List[Dict]:
     """Collective ops of a compiled-HLO module as
-    ``{op, operands: [(dtype, bytes)], operand_bytes}`` dicts.
+    ``{op, operands: [(dtype, bytes)], operand_dims, operand_bytes, groups,
+    group_size, pairs}`` dicts (``operand_dims``: each operand's dims as
+    a tuple; ``pairs``: a ``collective-permute``'s source-target pairs).
 
     ``operand_bytes`` is the per-member contribution each device feeds the
     collective — the honest wire-size proxy (an all-gather *result* is
@@ -168,9 +184,12 @@ def parse_collectives(hlo_text: str) -> List[Dict]:
         out.append({
             "op": m.group(1),
             "operands": operands,
+            "operand_dims": [tuple(int(x) for x in dims.split(",") if x)
+                             for _, dims in shapes],
             "operand_bytes": sum(b for _, b in operands),
             "groups": groups,
             "group_size": len(groups[0]) if groups else None,
+            "pairs": parse_source_target_pairs(line),
         })
     return out
 

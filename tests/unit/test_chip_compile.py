@@ -609,11 +609,17 @@ def test_zero3_step_gathers_bf16_weights_and_scatters_f32_gradients(
     """The engine's stage-3 fused step for a scanned GPT-2 at XL's width
     (two layers) on ``data=4``, compiled for the described chips from
     abstract state: inside the layer loops the weights cross the wire as
-    bf16 all-gathers, the gradient sums are float32 (``reduce-scatter``,
-    or the all-reduce-scatter the backend lowers one to), and nothing is
-    an ``all-to-all``. (The CPU backend widens a bf16 collective to
-    float32, so only this test reads the wire's dtype off a compiled
-    program; tests/unit/test_zero3_gather_at_use.py has the rest.)"""
+    bf16 all-gathers; a kernel's gradient, and a table's outside them, is
+    summed by the ring, float32 ``collective-permute``s (asynchronous
+    pairs on the chip) each feeding a float32 add; what the backend still
+    sums itself (the persistent leaves' gradients) is float32 too;
+    nothing bf16 is summed across chips and nothing is an ``all-to-all``
+    or a ``reduce-scatter``. (The CPU
+    backend widens a bf16 collective to float32, so only this test reads
+    the wire's dtype off a compiled program;
+    tests/unit/test_zero3_gather_at_use.py has the rest.)"""
+    import re
+
     import deepspeed_tpu
     from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2ForTraining
     from deepspeed_tpu.parallel.topology import MeshTopology
@@ -673,17 +679,46 @@ def test_zero3_step_gathers_bf16_weights_and_scatters_f32_gradients(
         engine.state, batch, scalar(jnp.float32)).compile().as_text()
     assert engine._zero3_program["program"] == "gather_at_use"
 
-    # two layers: the compiler unrolls both loops, so every weight-sized
-    # collective of the step is read (the optimizer's own are smaller)
-    big = [c for c in collectives_per_step(text)
-           if c["operand_bytes"] >= width * width // 4]
+    # every weight-sized collective of the step (the optimizer's own are
+    # smaller)
+    every = collectives_per_step(text)
+    big = [c for c in every if c["operand_bytes"] >= width * width // 4]
     assert not [c for c in big if c["op"] == "all-to-all"]
     gathers = [c for c in big if c["op"] == "all-gather"]
     assert len(gathers) >= 2 * 4 * layers  # forward and backward, a layer
     assert all({d for d, _ in c["operands"]} == {"bf16"} for c in gathers)
-    sums = [c for c in big if c["op"] in ("reduce-scatter", "all-reduce")]
-    assert len(sums) >= layers
-    assert all({d for d, _ in c["operands"]} == {"f32"} for c in sums)
+    # the ring: as many permutes as the plan counts, each a float32 piece
+    # of a kernel's gradient, consumed by float32 arithmetic only
+    plan = engine._zero3_program
+    permutes = [c for c in every if c["op"] == "collective-permute"]
+    assert plan["leaves_scattered_by_ring"] == 6
+    # (a kernel's in the backward loop's body, whose trip count the chip's
+    # compiler does not print: once a layer; a table's in the entry)
+    by_body = {}
+    for c in permutes:
+        by_body.setdefault(c["computation"], []).append(c["operand_bytes"])
+    tables, kernels = sorted(by_body.values(), key=len)
+    assert (len(tables), len(kernels)) == (2 * 3 * 2, 4 * 3 * 2)
+    assert len(tables) + len(kernels) * layers == plan["ring_permutes_step"]
+    assert sum(tables) + sum(kernels) * layers \
+        == plan["ring_operand_bytes_step"]
+    assert all({d for d, _ in c["operands"]} == {"f32"} for c in permutes)
+    assert {dims for c in permutes for dims in c["operand_dims"]} == {
+        (rows // 8, cols) for rows, cols in [
+            (width, 3 * width), (width, width), (width, 4 * width),
+            (4 * width, width), (1024, width), (seq, width)]}
+    assert "collective-permute-start" in text   # asynchronous on the chip
+    fed = [line for line in text.splitlines()
+           if re.search(r"\(.*%collective-permute-done", line)
+           and " fusion(" in line]
+    assert fed and all(
+        re.match(r"\s*(ROOT )?%[\w.\-]+ = \(?f32\[", line) for line in fed)
+    # what the backend still sums (a layer's stacked biases and norms)
+    assert not [c for c in every if c["op"] == "reduce-scatter"]
+    sums = [c for c in every if c["op"] == "all-reduce"
+            and c["operand_bytes"] >= layers * width * 4]
+    assert sums
+    assert all({d for d, _ in c["operands"]} <= {"f32"} for c in sums)
 
 
 def _reader_pattern(metric):

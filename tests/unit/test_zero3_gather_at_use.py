@@ -4,9 +4,11 @@ Under the engine's stage-3 step a model written against the seam
 (``runtime/zero/partition.gather_at_use``; the scanned GPT-2) runs under
 ``shard_map`` over the live ZeRO axes: inside the layer loop one layer's
 weights are all-gathered in the compute dtype and their float32 gradients
-reduce-scattered; activations never leave the batch layout, so no
-``all-to-all`` and no ``collective-permute`` crosses a ZeRO axis. What the
-compiled step gathers and scatters is what the plan the engine logs says.
+summed and scattered by a ring of float32 ``collective-permute``s, as are
+those of the tables gathered once; activations never leave the batch
+layout, so there is no ``all-to-all`` and every permute's operand is a
+piece of a WEIGHT's gradient. What the compiled step gathers and permutes
+is what the plan the engine logs says.
 Everywhere else (lower stages, one device, serving, a model that declares
 no use site) the seam is the identity and the program is the parent's.
 """
@@ -104,11 +106,25 @@ MESHES = [
 ]
 
 
+MESH_IDS = ["+".join(f"{a}{n}" for a, n in axes.items())
+            + ("-hpz" if h else "") for axes, h in MESHES]
+# a layer's four kernels and the two tables, each split on its leading dim
+KERNELS = [(WIDTH, 3 * WIDTH), (WIDTH, WIDTH), (WIDTH, 4 * WIDTH),
+           (4 * WIDTH, WIDTH)]
+TABLES = [(VOCAB, WIDTH), (SEQ, WIDTH)]
+KERNEL_ELEMENTS = sum(r * c for r, c in KERNELS)
+TABLE_ELEMENTS = sum(r * c for r, c in TABLES)
+
+
+def _scatter_axes(axes, hierarchical):
+    """The axes a parameter is split over: hpZ keeps ``data`` out."""
+    return tuple(a for a in ("data", "fsdp") if axes.get(a, 1) > 1
+                 and not (hierarchical and a == "data"))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-@pytest.mark.parametrize("axes,hierarchical", MESHES, ids=[
-    "+".join(f"{a}{n}" for a, n in axes.items()) + ("-hpz" if h else "")
-    for axes, h in MESHES])
+@pytest.mark.parametrize("axes,hierarchical", MESHES, ids=MESH_IDS)
 def test_the_compiled_step_gathers_weights_and_scatters_gradients(
         axes, hierarchical, dtype):
     engine = _engine(3, axes, dtype, hierarchical)
@@ -121,18 +137,43 @@ def test_the_compiled_step_gathers_weights_and_scatters_gradients(
     assert plan["leaves_gathered_in_scan"] == 4
     assert plan["leaves_gathered_once"] == 2
     assert plan["wire_dtypes"] == [jnp.dtype(dtype).name]
+    # every mesh here scatters over 2 to 8 chips: the kernels and the
+    # tables take the ring, in n - 1 steps, two ways where the ring has two
+    group = int(np.prod([axes[a] for a in _scatter_axes(axes, hierarchical)]))
+    ways = 2 if group > 2 else 1
+    assert plan["leaves_scattered_by_ring"] == 6
+    assert plan["ring_permutes_step"] == \
+        (LAYERS * 4 + 2) * (group - 1) * ways
+    assert plan["scatter_operand_bytes_step"] == \
+        (LAYERS * KERNEL_ELEMENTS + TABLE_ELEMENTS) * 4
+    assert plan["ring_operand_bytes_step"] == \
+        plan["scatter_operand_bytes_step"] * (group - 1) // group
 
-    colls = [c for c in collectives_per_step(text)
-             if c["operand_bytes"] >= 1024]
+    every = collectives_per_step(text)
+    colls = [c for c in every if c["operand_bytes"] >= 1024]
     in_loop = [c for c in colls if c["trips"] == LAYERS]
-    assert {"all-gather", "reduce-scatter"} <= {c["op"] for c in in_loop}
-    # nothing moves an activation between layouts: no all-to-all and no
-    # collective-permute inside the layer loop, nor anywhere else in the
-    # step, hpZ excepted: it moves the gradient SHARDS from the
-    # parameters' layout to the optimizer's, once, outside the loop
-    moved = [c for c in collectives_per_step(text)
-             if c["op"] in ("all-to-all", "collective-permute")]
-    assert not [c for c in moved if c["trips"] == LAYERS or not hierarchical]
+    assert {"all-gather", "collective-permute"} <= {c["op"] for c in in_loop}
+    # the backend's reduce-scatter is nowhere, and nothing moves an
+    # activation between layouts: no all-to-all, and every permute is the
+    # ring's, inside the gather's replica groups (hpZ gathers inside a
+    # data replica): a kernel's in the layer loop, a table's outside it.
+    # (hpZ also moves the gradient SHARDS from the parameters' layout to
+    # the optimizer's, ACROSS those groups, once, outside the loop.)
+    assert not [c for c in every
+                if c["op"] in ("all-to-all", "reduce-scatter")]
+    gathers = [c for c in colls if c["op"] == "all-gather"]
+    in_loop_gathers = [c for c in gathers if c["trips"] == LAYERS]
+    assert {c["group_size"] for c in in_loop_gathers} == {group}
+    together = {frozenset(g) for c in in_loop_gathers for g in c["groups"]}
+    permutes = [c for c in every if c["op"] == "collective-permute"
+                and all(any({src, dst} <= g for g in together)
+                        for src, dst in c["pairs"])]
+    moved = [c for c in every if c["op"] == "collective-permute"
+             and c not in permutes]
+    assert not [c for c in moved if c["trips"] > 1 or not hierarchical]
+    tables = [c for c in permutes if c["trips"] == 1]
+    assert len(permutes) - len(tables) == 4 * (group - 1) * ways
+    assert all(c["trips"] == LAYERS for c in permutes if c not in tables)
 
     # elements a step and chip: the plan the engine logs. (The CPU backend
     # widens a bf16 collective to float32, so the compiled text is counted
@@ -145,25 +186,87 @@ def test_the_compiled_step_gathers_weights_and_scatters_gradients(
         return sum(c["trips"] * b // widths[d]
                    for c in cs for d, b in c["operands"])
 
-    gathers = [c for c in colls if c["op"] == "all-gather"]
-    scatters = [c for c in colls if c["op"] == "reduce-scatter"]
-    assert elements(c for c in gathers if c["trips"] == LAYERS) \
+    assert elements(in_loop_gathers) \
         == plan["gather_operand_bytes_in_scan"] // wire
-    assert elements(scatters) == plan["scatter_operand_bytes_step"] // 4
-    assert all({d for d, _ in c["operands"]} == {"f32"} for c in scatters)
+    assert sum(c["trips"] for c in permutes) == plan["ring_permutes_step"]
+    assert elements(permutes) == plan["ring_operand_bytes_step"] // 4
+    assert elements(tables) == TABLE_ELEMENTS * (group - 1) // group
+    assert all({d for d, _ in c["operands"]} == {"f32"} for c in permutes)
+    # every permute's operand is a float32 piece of a weight's gradient
+    pieces = {(rows // group // ways, cols) for rows, cols in KERNELS + TABLES}
+    assert {dims for c in permutes for dims in c["operand_dims"]} == pieces
     lowered = _lowered_text(engine, axes)
     gathered = re.findall(r"all_gather.*?->\s*tensor<[0-9x]*x(\w+)>", lowered)
     assert gathered and set(gathered) == {
         "bf16" if dtype == jnp.bfloat16 else "f32"}
     # the sum across chips is float32 whatever the compute dtype
-    scattered = re.findall(r"reduce_scatter.*?->\s*tensor<[0-9x]*x(\w+)>",
-                           lowered, re.S)
-    assert scattered and set(scattered) == {"f32"}
-    # the replica groups are the spec's: hpZ gathers inside a data replica
-    group = int(np.prod([axes[a] for a in zero_axes
-                         if not (hierarchical and a == "data")]))
-    assert {c["group_size"] for c in gathers
-            if c["trips"] == LAYERS} == {group}
+    summed = re.findall(r"collective_permute.*?->\s*tensor<[0-9x]*x(\w+)>",
+                        lowered, re.S)
+    assert summed and set(summed) == {"f32"}
+    assert "reduce_scatter" not in lowered
+    # hpZ: the ring's sum is then summed over ``data``
+    if hierarchical:
+        psums = [c for c in colls if c["op"] == "all-reduce"
+                 and c["group_size"] == axes["data"]]
+        assert elements(psums) == \
+            (LAYERS * KERNEL_ELEMENTS + TABLE_ELEMENTS) // group
+
+
+@pytest.mark.parametrize("axes,hierarchical", MESHES, ids=MESH_IDS)
+def test_the_ring_is_the_reduce_scatter(axes, hierarchical):
+    """On random float32 cotangents the ring leaves what
+    ``psum_scatter(tiled=True)`` leaves, to float32 rounding (the sum's
+    order differs): piece ``i`` of the sum on index ``i`` of the axes, for
+    a scatter along either dim, two ways (pieces of 6 and 5) and one (a
+    piece of a single row)."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from deepspeed_tpu.utils.compat import shard_map
+
+    names = _scatter_axes(axes, hierarchical)
+    n = int(np.prod([axes[a] for a in names]))
+    mesh = Mesh(np.array(jax.devices()[:n]).reshape(
+        [axes[a] for a in names]), names)
+    for dim, shape in [(0, (n * 6, 10)), (1, (3, n * 5)), (0, (n, 7))]:
+        ct = jax.random.normal(jax.random.PRNGKey(dim), (n,) + shape,
+                               jnp.float32)
+
+        def both(ct, dim=dim):
+            return (zero.ring_reduce_scatter(ct[0], names, dim, n)[None],
+                    jax.lax.psum_scatter(ct[0], names, scatter_dimension=dim,
+                                         tiled=True)[None])
+
+        ring, backend = jax.jit(shard_map(
+            both, mesh=mesh, in_specs=P(names),
+            out_specs=(P(names), P(names)), check_vma=False))(ct)
+        whole = np.asarray(ct, np.float64).sum(0)
+        want = np.stack(np.split(whole, n, axis=dim))
+        scale = np.abs(want).max()
+        assert ring.dtype == jnp.float32
+        np.testing.assert_allclose(ring, want, rtol=0, atol=1e-6 * scale)
+        np.testing.assert_allclose(ring, backend, rtol=0, atol=1e-6 * scale)
+
+
+@pytest.mark.parametrize("groups,form", [
+    ([2], "ring"), ([4], "ring"), ([8], "ring"), ([2, 4], "ring"),
+    ([1], "reduce_scatter"), ([9], "reduce_scatter"),
+    ([256], "reduce_scatter"), ([4, 16], "reduce_scatter"),
+    ([], "reduce_scatter"),
+])
+def test_which_leaves_take_the_ring(groups, form):
+    """A leaf whose every scatter group is 2 to 8 chips; a group of one
+    and a group over 8 keep the backend's ``psum_scatter``."""
+    assert zero.scatter_form(groups) == form
+
+
+def test_a_ring_goes_both_ways_where_it_has_two():
+    """A piece is cut in two halves that travel opposite ways; two chips
+    have one way round, and a piece of one row does not split."""
+    assert [hop for n in (3, 8) for _, _, hop in zero._ring_parts(n, 6)] \
+        == [1, -1, 1, -1]
+    assert zero._ring_parts(2, 6) == [(0, 6, 1)]
+    assert zero._ring_parts(4, 1) == [(0, 1, 1)]
+    assert zero._ring_parts(4, 5) == [(0, 2, 1), (2, 3, -1)]
 
 
 @pytest.mark.parametrize("policy", ["dots", "full", None])
@@ -370,6 +473,11 @@ def test_the_engine_logs_the_plan_once():
     plan = engine.describe_topology(include_tensors=False)["zero3_program"]
     assert str(plan["gather_operand_bytes_step"]) in logged[0]
     assert str(plan["scatter_operand_bytes_step"]) in logged[0]
+    # which leaves the ring scattered, in how many permutes and bytes
+    assert plan["leaves_scattered_by_ring"] == 6
+    assert (f"{plan['leaves_scattered_by_ring']} leaves by a ring of "
+            f"{plan['ring_permutes_step']} float32 permutes "
+            f"({plan['ring_operand_bytes_step']} operand bytes)") in logged[0]
     # a layer's four kernels: shard (bf16) gathered forward and again in
     # the rematerialised backward, whole (f32) scattered once
     kernels = WIDTH * 3 * WIDTH + WIDTH * WIDTH + 2 * WIDTH * 4 * WIDTH

@@ -283,7 +283,7 @@ def _remat_block(cfg, block=None):
         # elementwise chains (LN / gelu / residual adds)
         names = ("flash_q", "flash_k", "flash_v", "flash_o", "flash_lse")
         if block is not Block:
-            # a ZeRO-3 use site (_block_at_use): memory is what stage 3 is
+            # a ZeRO-3 use site (_AtUseBlock): memory is what stage 3 is
             # for, and q, k, v are transposed slices of the c_attn output
             # that checkpoint_dots keeps anyway. Saved beside it in the
             # kernel's layout (head size 64 padded to 128 lanes) they cost
@@ -706,49 +706,46 @@ class _ScanBody(nn.Module):
         x, pools = (carry, None) if layer is None else carry
         if cfg.remat:
             x = saved_block_input(x, cfg)
-        out = _remat_block(cfg, _block_at_use(cfg, self.path + ("block",)))(
+        out = _remat_block(cfg)(
             cfg, name="block")(
             x, deterministic, pld_theta, layer_frac, attention_mask, paging,
             None if layer is None else (pools, layer))
         return out, None
 
 
-def _block_at_use(cfg, path):
-    """The scanned stack's ZeRO-3 use site: :class:`Block` whose parameters,
-    this layer's slice of the stacked leaves at ``path``, pass through
-    ``runtime/zero/partition.gather_at_use`` on their way in: cast to the
-    compute dtype on the shard, all-gathered, their gradients
-    reduce-scattered in float32. The wrap sits INSIDE the remat wrap, so
-    backward gathers again and no gathered weight is a residual of the
-    scan; with ``remat`` off it brings a remat of its own whose policy
-    saves every residual but the gathered weights. ``None`` (the plain
-    :class:`Block`) wherever no engine is tracing a stage-3 step: serving,
-    ``init``, every lower stage, one device."""
+class _AtUseBlock(Block):
+    """:class:`Block` as the scanned stack's ZeRO-3 use site
+    (:func:`_stack_gathered_ahead`, at the end of this file): a class of
+    its own so that :func:`_remat_block` gives it the use site's policy
+    (``block is not Block``: q, k, v are not saved beside the ``c_attn``
+    output). Its weights arrive gathered; it gathers nothing itself."""
+
+
+def _no_cotangent(tree):
+    """Zero cotangents for the arguments nothing is differentiated by:
+    floating zeros, ``float0`` for integer leaves (rng keys)."""
+    return jax.tree_util.tree_map(
+        lambda a: jnp.zeros_like(a)
+        if jnp.issubdtype(a.dtype, jnp.inexact)
+        else np.zeros(a.shape, jax.dtypes.float0), tree)
+
+
+def _gathering() -> bool:
+    """Whether the engine is tracing a ZeRO-3 step (an active
+    ``partition.GatherPlan``): the scanned stack is then the use site of
+    its own weights. Never while serving, in ``init``, at a lower stage
+    or on one device."""
     from deepspeed_tpu.runtime.zero import partition as zero
 
-    if not zero.gathering():
-        return None
-
-    def whole(variables):
-        return {c: zero.gather_at_use(v, path, dtype=cfg.dtype, stacked=1)
-                if c == "params" else v for c, v in variables.items()}
-
-    block = nn.map_variables(Block, "params", trans_in_fn=whole)
-    if cfg.remat:
-        return block
-    return nn.remat(block, prevent_cse=False, static_argnums=(2,),
-                    policy=zero.save_all_but_gathered)
+    return zero.gathering()
 
 
 class ScanBlocks(nn.Module):
     """All transformer blocks as one scanned body: params get a leading
     ``n_layer`` axis and XLA compiles a single block. Under the engine's
-    ZeRO-3 step the body is the stack's use site (:func:`_block_at_use`):
-    each scan step all-gathers ONE layer's weights in the compute dtype,
-    inside the rematerialised region, and reduce-scatters their float32
-    gradients; activations stay on the batch. Small leaves (a layer's
-    biases and norms, under ``param_persistence_threshold`` elements a
-    LAYER) are persistent: whole on every chip, never gathered."""
+    ZeRO-3 step the stack is the use site of its own weights and runs
+    :func:`_stack_gathered_ahead` instead; a layer's small leaves (biases
+    and norms) are persistent: whole on every chip, never gathered."""
 
     config: GPT2Config
 
@@ -757,6 +754,9 @@ class ScanBlocks(nn.Module):
                  attention_mask=None, paging=None):
         cfg = self.config
         pools = _paged_pool_vars(self, cfg)
+        if pools is None and _gathering():
+            return _stack_gathered_ahead(self, x, deterministic, pld_theta,
+                                         attention_mask)
         ScannedBlock = nn.scan(
             _ScanBody,
             variable_axes={"params": 0, "cache": 0},
@@ -1195,3 +1195,97 @@ def gpt2_loss_fn(model: GPT2LMHeadModel):
             else 1_000_000_000)
 
     return loss_fn
+
+
+# ---------------------------------------------------------------------------
+# The scanned stack under ZeRO-3: a layer's weights gathered one layer ahead.
+# (Here, behind everything else, so that no other program's source lines
+# move: a Pallas kernel's compiled text carries its callers' line numbers.)
+def _stack_gathered_ahead(stack, x, deterministic, pld_theta, attention_mask):
+    """:class:`ScanBlocks` (``stack``) under the ZeRO-3 plan: each layer's
+    weights are gathered ONE LAYER AHEAD in the forward pass (the
+    reference's parameter prefetch, ``partitioned_param_coordinator.py``).
+    A compiled loop body overlaps a collective only with work of the same
+    iteration, and a layer's first weights have nothing of their own layer
+    before them: so scan step ``i`` runs layer ``i`` on the weights that
+    step ``i - 1`` gathered, carried in, and gathers layer ``i + 1``'s
+    beside its own matmuls (one more layer of compute-dtype weights live),
+    the leaves that can in one collective (``partition._gather_together``).
+
+    No gathered weight is saved for it. A layer is a ``custom_vjp``: its
+    forward rule differentiates the block (under the block's own remat
+    policy, so the residuals are what they were) and DROPS the carried
+    weights from what it keeps; its backward rule gathers the layer's
+    weights again inside its own scan step, as the rematerialised backward
+    always has, hands them to the block's pullback, and scatters the
+    float32 gradients (``partition.gather_at_use``'s transpose: the ring).
+    The backward does not gather ahead: on the chip its gathers then share
+    the wire with the ring's permutes and lose what they gain (PERF.md,
+    PR 47). Dropout and layer drop draw from one key a layer."""
+    from deepspeed_tpu.runtime.zero import partition as zero
+
+    cfg, n = stack.config, stack.config.n_layer
+    shards = nn.meta.unbox(stack.variables["params"]["h"]["block"])
+    site = stack.path + ("h", "block")
+    whole = zero.gatherer(site, dtype=cfg.dtype, stacked=1)
+    ahead_of = zero.gatherer(site, dtype=cfg.dtype, stacked=1, ahead=n)
+    block = _remat_block(cfg, _AtUseBlock)(cfg)
+    # 1-indexed depth fractions, as ScanBlocks scans them in
+    fracs = (jnp.arange(n, dtype=jnp.float32) + 1.0) / max(1, n)
+    keys = {name: jax.random.split(stack.make_rng(name), n)
+            for name in ("dropout", "pld") if stack.has_rng(name)}
+
+    def run(weights, x, frac, keys):
+        return block.apply({"params": weights}, x, deterministic, pld_theta,
+                           frac, attention_mask, None, None,
+                           rngs=keys or None)
+
+    kept = {}  # the pullback's structure: static, so not a residual
+
+    @jax.custom_vjp
+    def layer(weights, mine, x, frac, keys):
+        return run(weights, x, frac, keys)
+
+    def layer_fwd(weights, mine, x, frac, keys):
+        out, pullback = jax.vjp(lambda w, x: run(w, x, frac, keys),
+                                weights, x)
+        leaves, kept["tree"] = jax.tree_util.tree_flatten(pullback)
+        carried = jax.tree_util.tree_leaves(weights)
+        # which of the pullback's residuals ARE the carried weights
+        kept["weights"] = [next((j for j, w in enumerate(carried)
+                                 if leaf is w), None) for leaf in leaves]
+        return out, ([leaf for leaf, j in zip(leaves, kept["weights"])
+                      if j is None], mine, (frac, keys))
+
+    def layer_bwd(residuals, ct):
+        saved, mine, rest = residuals
+        weights, scatter = jax.vjp(whole, mine)
+        again, saved = jax.tree_util.tree_leaves(weights), iter(saved)
+        pullback = jax.tree_util.tree_unflatten(kept["tree"], [
+            next(saved) if j is None else again[j] for j in kept["weights"]])
+        ct_weights, ct_x = pullback(ct)
+        return (_no_cotangent(weights), scatter(ct_weights)[0], ct_x,
+                *_no_cotangent(rest))
+
+    layer.defvjp(layer_fwd, layer_bwd)
+
+    def at(i):
+        return jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
+            shards)
+
+    def step(carry, xs):
+        x, weights = carry
+        i, mine, frac, keys = xs
+        # the next layer's weights: nothing here depends on this layer
+        # (the last step gathers its own again: a forty-eighth more)
+        ahead = jax.lax.stop_gradient(
+            ahead_of(at(jnp.minimum(i + 1, n - 1))))
+        if cfg.remat:
+            x = saved_block_input(x, cfg)
+        return (layer(weights, mine, x, frac, keys), ahead), None
+
+    (x, _), _ = jax.lax.scan(
+        step, (x, jax.lax.stop_gradient(ahead_of(at(0)))),
+        (jnp.arange(n, dtype=jnp.int32), shards, fracs, keys))
+    return x

@@ -1111,9 +1111,9 @@ class DeepSpeedEngine:
             log_dist(
                 "ZeRO-3 step: gather-at-use program over "
                 f"{'x'.join(d['axes'])}: {d['leaves_gathered_in_scan']} "
-                f"leaves gathered a layer in the scan, "
-                f"{d['leaves_gathered_once']} gathered once, "
-                f"{d['leaves_persistent']} persistent; a step and chip "
+                f"leaves a layer in the scan ({d['gathers_ahead_step']} "
+                f"gathers a step one layer ahead), {d['leaves_gathered_once']}"
+                f" once, {d['leaves_persistent']} persistent; a step and chip "
                 f"all-gathers {d['gather_operand_bytes_step']} operand "
                 f"bytes ({', '.join(d['wire_dtypes'])}) and reduce-scatters "
                 f"{d['scatter_operand_bytes_step']} (float32), "

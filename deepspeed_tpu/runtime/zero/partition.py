@@ -21,11 +21,11 @@ gather/release hooks (``runtime/zero/partition_parameters.py:1042``,
   * a model written against the seam (:func:`gather_at_use`; it declares
     its use sites with ``zero3_use_sites()``, as the scanned GPT-2 does)
     gets the EXPLICIT program: loss and gradients run under ``shard_map``
-    over the live ZeRO axes (``data``, ``fsdp``). Inside the layer scan,
-    inside the rematerialised region, one layer's slice of every sharded
-    leaf is cast to the compute dtype ON THE SHARD and all-gathered (bf16
-    on the wire; backward gathers again, so no gathered weight is ever a
-    residual); its gradient is cast up to float32, summed and scattered,
+    over the live ZeRO axes (``data``, ``fsdp``). Inside the layer scan
+    one layer's slice of every sharded leaf is cast to the compute dtype
+    ON THE SHARD and all-gathered (bf16 on the wire; in the forward one
+    layer ahead of its use; backward gathers again, so no gathered weight
+    is ever a residual); its gradient is cast up to float32, summed and scattered,
     so the sum across chips is float32 and each chip ends holding its own
     shard: by a ring of float32 ``ppermute``s and adds
     (:func:`ring_reduce_scatter`; asynchronous on the chip, so the rest of
@@ -336,6 +336,7 @@ class GatherPlan:
             lambda x: tuple(x.shape), shapes,
             is_leaf=lambda x: hasattr(x, "shape"))
         self.served: Dict[str, Dict] = {}
+        self.ahead: Dict[str, int] = {}  # site -> layers gathered ahead
 
     def __enter__(self):
         _PLANS.append(self)
@@ -366,12 +367,14 @@ class GatherPlan:
     # -- the gather -------------------------------------------------------
     def _leaf(self, w, spec, shape, path: str, stacked: int, dtype,
               keep_dtype: bool):
+        """How leaf ``w`` is gathered (:class:`_How`; no dims: it is whole
+        already), recorded for :meth:`describe`."""
         entries = list(spec) + [None] * (len(shape) - len(spec))
         dims = [(i - stacked, tuple(a for a in _names(e) if a in self.axes))
                 for i, e in enumerate(entries)]
         dims = [(i, axes) for i, axes in dims if axes]
         if not dims:
-            return w
+            return _How((), (), w.dtype, w.dtype)
         if any(i < 0 for i, _ in dims):
             raise ValueError(
                 f"{path}: a scanned dim of a ZeRO-3 use site is sharded "
@@ -404,23 +407,32 @@ class GatherPlan:
             "scatter_form": form, "ring_steps": steps,
             "ring_permutes": permutes, "ring_operand_bytes": ring_bytes,
             "wire_dtype": wire.name}
-        return _gather_leaf(
-            w, [(dim, axes, n if form == "ring" else 0)
-                for (dim, axes), n in zip(dims, sizes)],
+        return _How(
+            tuple((dim, axes, n if form == "ring" else 0)
+                  for (dim, axes), n in zip(dims, sizes)),
             tuple(a for a in self.axes if a not in used), wire, out)
 
     def gather(self, tree, path: Sequence[str], dtype=None, stacked: int = 0,
-               keep_dtype: bool = False):
+               keep_dtype: bool = False, together: bool = False):
+        """``tree`` (the parameters at ``path``) whole. ``together``: the
+        leaves one collective can carry go in one (:func:`_gather_together`:
+        a layer's kernels whose shards have the same rows), where fewer,
+        larger gathers are what the chip runs beside a layer's matmuls."""
         specs, shapes = self.specs, self.shapes
         for k in path:
             specs, shapes = specs[k], shapes[k]
         prefix = "/".join(path)
-        return jax.tree_util.tree_map_with_path(
+        hows = jax.tree_util.tree_map_with_path(
             lambda kp, w, spec, shape: self._leaf(
                 w, spec, shape,
                 "/".join(filter(None, (prefix, _path_str(kp)))),
                 stacked, dtype, keep_dtype),
             tree, specs, shapes)
+        if not together:
+            return jax.tree_util.tree_map(_gather_leaf, tree, hows)
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        return treedef.unflatten(
+            _gather_together(leaves, treedef.flatten_up_to(hows)))
 
     def gather_rest(self, params):
         """Every sharded leaf that lies under NO use site, gathered here,
@@ -430,7 +442,8 @@ class GatherPlan:
             path = _path_str(kp)
             if site_of(self.sites, path)[0] is not None:
                 return w
-            return self._leaf(w, spec, shape, path, 0, None, False)
+            return _gather_leaf(
+                w, self._leaf(w, spec, shape, path, 0, None, False))
 
         return jax.tree_util.tree_map_with_path(
             leaf, params, self.specs, self.shapes)
@@ -452,16 +465,25 @@ class GatherPlan:
         """JSON-safe plan of one training step on one chip: which leaves
         are gathered where, and the operand bytes of the all-gathers and
         reduce-scatters (what each chip feeds them; a scanned leaf is
-        gathered again in the rematerialised backward). Of the scattered
+        gathered again in the rematerialised backward, and once more where
+        the site gathers ahead). Of the scattered
         gradients, which leaves the ring took (:func:`scatter_form`), in
         how many permutes (trips x steps x ways) and operand bytes: a
-        ring over ``n`` chips sends ``(n - 1) / n`` of what it scatters."""
+        ring over ``n`` chips sends ``(n - 1) / n`` of what it scatters.
+        ``gathers_ahead_step``: the leaf gathers a step that run one layer
+        ahead of their use, beside the layer before (:func:`gatherer`: the
+        forward's, all but the first layer's)."""
         scan = {p: r for p, r in self.served.items() if r["in_scan"]}
         once = {p: r for p, r in self.served.items() if not r["in_scan"]}
         n_leaves = len(jax.tree_util.tree_leaves(
             self.shapes, is_leaf=lambda x: isinstance(x, tuple)))
-        in_scan = sum(2 * r["trips"] * r["gather_operand_bytes"]
-                      for r in scan.values())
+        ahead = {p: max((n for site, n in self.ahead.items()
+                         if p.startswith(site + "/")), default=0)
+                 for p in scan}
+        # forward and rematerialised backward; a site that gathers ahead
+        # gathers once more (its last scan step gathers its own layer again)
+        in_scan = sum((2 * r["trips"] + (ahead[p] > 0))
+                      * r["gather_operand_bytes"] for p, r in scan.items())
         gathered = in_scan + sum(r["gather_operand_bytes"]
                                  for r in once.values())
         scattered = sum(r["trips"] * r["scatter_operand_bytes"]
@@ -473,6 +495,8 @@ class GatherPlan:
                 "leaves_persistent": n_leaves - len(self.served),
                 "gather_operand_bytes_step": int(gathered),
                 "gather_operand_bytes_in_scan": int(in_scan),
+                "gathers_ahead_step": int(sum(
+                    max(n - 1, 0) for n in ahead.values())),
                 "scatter_operand_bytes_step": int(scattered),
                 "leaves_scattered_by_ring": len(ring),
                 "ring_permutes_step": int(sum(
@@ -538,57 +562,102 @@ def ring_reduce_scatter(ct, axes, dim: int, n: int):
         parts, axis=dim)
 
 
-def _gather_leaf(w, dims, other_axes, wire_dtype, out_dtype):
-    """``w`` (this chip's shard) -> the whole weight: cast to the wire
-    dtype ON THE SHARD, all-gathered over the ZeRO axes of each sharded
-    dim (``dims``: ``(dim, axes, ring)``). Its transpose is written out:
-    the cotangent is cast UP to float32 and reduce-scattered, by a ring of
-    ``ring`` chips (:func:`ring_reduce_scatter`) or, ``ring`` 0, by the
-    backend's ``psum_scatter`` (:func:`scatter_form`), so every chip ends
-    holding its own shard of the float32 sum (and the sum over
-    ``other_axes``, the manual axes this leaf is whole on: hpZ's
-    ``data``)."""
+class _How:
+    """How one leaf is gathered: ``dims``, ``(dim, axes, ring)`` of each
+    sharded dim (none: the leaf is whole on every chip); ``other_axes``,
+    the manual axes it is whole on (hpZ's ``data``); the ``wire`` dtype
+    and the dtype it comes ``out`` in."""
+
+    __slots__ = ("dims", "other_axes", "wire", "out")
+
+    def __init__(self, dims, other_axes, wire, out):
+        self.dims, self.other_axes = dims, other_axes
+        self.wire, self.out = wire, out
+
+
+def _whole(w, how):
+    """``w`` (this chip's shard) cast to the wire dtype ON THE SHARD and
+    all-gathered over the ZeRO axes of each sharded dim."""
+    g = w.astype(how.wire)
+    for dim, axes, _ in how.dims:
+        g = jax.lax.all_gather(g, axes, axis=dim, tiled=True)
+    return g.astype(how.out)
+
+
+def _scatter(ct, how, dtype):
+    """The gather's transpose, written out: the cotangent is cast UP to
+    float32 and reduce-scattered, by a ring of ``ring`` chips
+    (:func:`ring_reduce_scatter`) or, ``ring`` 0, by the backend's
+    ``psum_scatter`` (:func:`scatter_form`), so every chip ends holding
+    its own shard of the float32 sum (and the sum over ``other_axes``)."""
+    ct = ct.astype(np.float32)
+    for dim, axes, ring in reversed(how.dims):
+        ct = ring_reduce_scatter(ct, axes, dim, ring) if ring else \
+            jax.lax.psum_scatter(ct, axes, scatter_dimension=dim, tiled=True)
+    if how.other_axes:
+        ct = jax.lax.psum(ct, how.other_axes)
+    return ct.astype(dtype)
+
+
+def _gather_leaf(w, how):
+    """``w`` -> the whole weight (:func:`_whole`), its gradient float32
+    and scattered (:func:`_scatter`); ``w`` itself where it is whole."""
+    if not how.dims:
+        return w
     in_dtype = w.dtype
 
     @jax.custom_vjp
-    def zero3_gather(w):  # the name is what save_all_but_gathered reads
-        g = w.astype(wire_dtype)
-        for dim, axes, _ in dims:
-            g = jax.lax.all_gather(g, axes, axis=dim, tiled=True)
-        return g.astype(out_dtype)
+    def zero3_gather(w):
+        return _whole(w, how)
 
-    def fwd(w):
-        return zero3_gather(w), None
-
-    def bwd(_, ct):
-        ct = ct.astype(np.float32)
-        for dim, axes, ring in reversed(dims):
-            ct = ring_reduce_scatter(ct, axes, dim, ring) if ring else \
-                jax.lax.psum_scatter(ct, axes, scatter_dimension=dim,
-                                     tiled=True)
-        if other_axes:
-            ct = jax.lax.psum(ct, other_axes)
-        return (ct.astype(in_dtype),)
-
-    zero3_gather.defvjp(fwd, bwd)
+    zero3_gather.defvjp(lambda w: (zero3_gather(w), None),
+                        lambda _, ct: (_scatter(ct, how, in_dtype),))
     # named OUTSIDE the custom_vjp, where a remat policy can see it: no
-    # policy saves a gathered weight, backward gathers again
+    # policy of this repo saves a gathered weight, backward gathers again
     return checkpoint_name(zero3_gather(w), GATHERED)
 
 
-def save_all_but_gathered(prim, *_, **params) -> bool:
-    """A remat policy for a block that is NOT rematerialised: every
-    residual is saved as without remat, but a gathered weight, which
-    backward gathers again (the ``custom_vjp`` call that made it, by its
-    function's name, and the named value itself)."""
-    if prim.name == "custom_vjp_call":
-        info = getattr(getattr(params.get("call_jaxpr"), "jaxpr", None),
-                       "debug_info", None)
-        return not str(getattr(info, "func_src_info", "")).startswith(
-            "zero3_gather ")
-    if prim.name == "name":
-        return params.get("name") != GATHERED
-    return True
+def _gather_together(leaves, hows):
+    """:func:`_gather_leaf` of every leaf, with the leaves that ONE
+    collective can carry sent in one: two-dim leaves split on their
+    leading dim alone, over the same axes, with the same rows a shard and
+    the same dtype on the wire (a GPT-2 layer's ``c_attn``, attention
+    ``c_proj`` and ``c_fc`` kernels) are concatenated along their other
+    dim, all-gathered once and cut apart again. The chip runs about one
+    gather at a time beside a layer's matmuls and the rest alone (PERF.md,
+    PR 47): fewer and larger is what it hides. The transpose stays each
+    leaf's own, so a kernel's ring starts as its gradient arrives."""
+    carried = {}
+    for i, (w, how) in enumerate(zip(leaves, hows)):
+        if (len(how.dims) == 1 and how.dims[0][0] == 0 and w.ndim == 2
+                and how.out == how.wire):
+            carried.setdefault(
+                (how.dims[0][1], w.shape[0], how.wire), []).append(i)
+    carried = [idx for idx in carried.values() if len(idx) > 1]
+    dtypes = [w.dtype for w in leaves]
+
+    @jax.custom_vjp
+    def zero3_gather_together(*ws):
+        out = list(ws)
+        for idx in carried:
+            how = hows[idx[0]]
+            g = _whole(jax.numpy.concatenate(
+                [ws[i].astype(how.wire) for i in idx], axis=1), how)
+            cuts = np.cumsum([0] + [ws[i].shape[1] for i in idx])
+            for i, lo, hi in zip(idx, cuts[:-1], cuts[1:]):
+                out[i] = g[:, lo:hi]
+        alone = set(range(len(ws))) - {i for idx in carried for i in idx}
+        for i in alone:
+            if hows[i].dims:
+                out[i] = _whole(ws[i], hows[i])
+        return tuple(out)
+
+    zero3_gather_together.defvjp(
+        lambda *ws: (zero3_gather_together(*ws), None),
+        lambda _, cts: tuple(
+            _scatter(ct, how, dtype) if how.dims else ct
+            for ct, how, dtype in zip(cts, hows, dtypes)))
+    return list(zero3_gather_together(*leaves))
 
 
 def gather_at_use(tree, path: Sequence[str], dtype=None, stacked: int = 0,
@@ -614,9 +683,27 @@ def gather_at_use(tree, path: Sequence[str], dtype=None, stacked: int = 0,
     return plan.gather(tree, tuple(path), dtype, stacked, keep_dtype)
 
 
+def gatherer(path: Sequence[str], dtype=None, stacked: int = 0,
+             ahead: int = 0):
+    """:func:`gather_at_use` at ``path`` as a function of the tree, bound
+    to the plan that is active NOW: for a use site that gathers outside
+    the trace the plan was entered for (a ``custom_vjp``'s backward rule
+    is traced after the loss has returned). ``ahead``: the function is
+    the site's PREFETCH, which gathers each of that many layers' weights
+    one layer before its use; the plan counts it (``gathers_ahead_step``)
+    and sends the leaves it can in one collective
+    (:func:`_gather_together`), because what runs ahead has to hide
+    beside the layer before."""
+    plan, path = _PLANS[-1], tuple(path)
+    if ahead:
+        plan.ahead["/".join(path)] = int(ahead)
+    return lambda tree: plan.gather(tree, path, dtype, stacked,
+                                    together=bool(ahead))
+
+
 def gathering() -> bool:
-    """Whether a plan is active: a block stack wraps its block for the
-    seam only then, so every other trace is the unwrapped module's."""
+    """Whether a plan is active: a block stack is the use site of its
+    own weights only then, so every other trace is the plain module's."""
     return bool(_PLANS)
 
 

@@ -627,6 +627,7 @@ def test_zero3_step_gathers_bf16_weights_and_scatters_f32_gradients(
     from deepspeed_tpu.runtime.zero.partition import (
         batch_sharding, build_opt_state_shardings, build_zero_shardings,
         replicated)
+    from deepspeed_tpu.utils import hlo_inspect
     from deepspeed_tpu.utils.hlo_inspect import collectives_per_step
 
     layers, width, seq = 2, 1600, 256
@@ -708,6 +709,40 @@ def test_zero3_step_gathers_bf16_weights_and_scatters_f32_gradients(
             (width, 3 * width), (width, width), (width, 4 * width),
             (4 * width, width), (1024, width), (seq, width)]}
     assert "collective-permute-start" in text   # asynchronous on the chip
+    # one layer ahead (PR 47): the forward loop's body uses the weights the
+    # step before gathered and gathers the next layer's beside its own
+    # matmuls, in TWO collectives (c_attn, attention c_proj and c_fc
+    # together; the MLP's c_proj alone). At this size (a quarter of the
+    # cell's rows a layer) the chip's compiler runs one of the two beside
+    # a matmul and one alone, where the parent's body, which had to have
+    # its own layer's four first, ran 3 alone; at the cell's size both
+    # run beside matmuls where the parent ran 2 alone and waited on a
+    # third (compiled here, PERF.md PR 47). The backward loop gathers
+    # inside its own step, leaf by leaf as it did: 1 alone of 4.
+    _, comps = hlo_inspect._computations(text)
+    loops = [b for b in re.findall(r"body=%?([\w.\-]+)", text)
+             if any(" all-gather(" in line or "async-collective-start" in line
+                    for line in comps[b])]
+    assert len(loops) == 2
+    backward = next(b for b in loops if b in by_body)
+    (forward,) = [b for b in loops if b != backward]
+    plain = {b: sum(" all-gather(" in line for line in comps[b])
+             for b in loops}
+    fused = {b: sum(bool(re.match(r"\s*%async-collective-start[.\d]* = ",
+                                  line)) for line in comps[b])
+             for b in loops}
+    assert (plain[forward], fused[forward]) == (1, 1)
+    assert (plain[backward], fused[backward]) == (1, 3)
+    # the gathered weights ride the forward loop's carry, in bf16, and
+    # are no residual: nothing holds a layer's whole kernel a layer
+    carry = next(line for line in text.splitlines()
+                 if line.startswith(f"%{forward} ("))
+    for rows, cols in [(width, 3 * width), (width, 4 * width),
+                       (4 * width, width)]:
+        assert f"bf16[{rows},{cols}]" in carry
+        assert not re.search(rf"\[{layers},{rows},{cols}\]", text)
+    assert f"bf16[{width // 4},{8 * width}]" in "\n".join(comps[forward])
+    assert plan["gathers_ahead_step"] == 4 * (layers - 1)
     fed = [line for line in text.splitlines()
            if re.search(r"\(.*%collective-permute-done", line)
            and " fusion(" in line]

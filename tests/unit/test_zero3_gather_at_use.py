@@ -5,7 +5,9 @@ Under the engine's stage-3 step a model written against the seam
 ``shard_map`` over the live ZeRO axes: inside the layer loop one layer's
 weights are all-gathered in the compute dtype and their float32 gradients
 summed and scattered by a ring of float32 ``collective-permute``s, as are
-those of the tables gathered once; activations never leave the batch
+those of the tables gathered once; in the forward pass a layer's weights
+are gathered one layer ahead of their use, carried into the scan step that
+uses them and saved by none; activations never leave the batch
 layout, so there is no ``all-to-all`` and every permute's operand is a
 piece of a WEIGHT's gradient. What the compiled step gathers and permutes
 is what the plan the engine logs says.
@@ -41,13 +43,14 @@ def _fresh_topology():
 
 
 def _engine(stage, axes, dtype=jnp.float32, hierarchical=False, scan=True,
-            policy="dots", micro=2):
+            policy="dots", micro=2, layers=LAYERS, **model_kw):
     reset_topology()
     n = int(np.prod(list(axes.values())))
     model = GPT2ForTraining(GPT2Config(
-        vocab_size=VOCAB, n_positions=SEQ, n_embd=WIDTH, n_layer=LAYERS,
+        vocab_size=VOCAB, n_positions=SEQ, n_embd=WIDTH, n_layer=layers,
         n_head=HEADS, dtype=dtype, scan_layers=scan,
-        remat=policy is not None, remat_policy=policy or "full"))
+        remat=policy is not None, remat_policy=policy or "full",
+        **model_kw))
     config = {
         "train_micro_batch_size_per_gpu": micro,
         "gradient_accumulation_steps": 1,
@@ -186,8 +189,12 @@ def test_the_compiled_step_gathers_weights_and_scatters_gradients(
         return sum(c["trips"] * b // widths[d]
                    for c in cs for d, b in c["operands"])
 
-    assert elements(in_loop_gathers) \
-        == plan["gather_operand_bytes_in_scan"] // wire
+    # (forward and backward loop; the plan also counts the first layer's
+    # gather before the forward loop, whose last step gathers its own
+    # layer again)
+    assert elements(in_loop_gathers) == 2 * LAYERS * KERNEL_ELEMENTS // group
+    assert plan["gather_operand_bytes_in_scan"] // wire \
+        == (2 * LAYERS + 1) * KERNEL_ELEMENTS // group
     assert sum(c["trips"] for c in permutes) == plan["ring_permutes_step"]
     assert elements(permutes) == plan["ring_operand_bytes_step"] // 4
     assert elements(tables) == TABLE_ELEMENTS * (group - 1) // group
@@ -316,6 +323,211 @@ def test_stage_3_agrees_with_stage_0(dtype, tol):
         np.testing.assert_allclose(np.asarray(b), a, rtol=0,
                                    atol=2e-6 if dtype == jnp.float32
                                    else 4.5e-3)
+
+
+# ---------------------------------------------------------------------------
+# one layer ahead: WHEN a scanned layer's weights are gathered
+def _scans(jaxpr):
+    """``(length, reverse, carry avals, ys avals)`` of every ``scan`` in
+    a jaxpr, however deeply nested."""
+    found = []
+
+    def walk(j):
+        for eqn in getattr(j, "jaxpr", j).eqns:
+            if eqn.primitive.name == "scan":
+                p = eqn.params
+                outs = [v.aval for v in eqn.outvars]
+                found.append((p["length"], p["reverse"],
+                              outs[:p["num_carry"]], outs[p["num_carry"]:]))
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
+                        walk(sub)
+
+    walk(jaxpr)
+    return found
+
+
+def _step_jaxpr(engine, axes):
+    engine(_batch(engine, axes))
+    return engine._jit_fused.trace(
+        engine.state, engine._shard_batch(_batch(engine, axes)),
+        jnp.float32(0)).jaxpr
+
+
+def _shapes(avals, dtype=None):
+    return [tuple(a.shape) for a in avals
+            if dtype is None or a.dtype == dtype]
+
+
+@pytest.mark.parametrize("policy", ["dots", "full", None])
+@pytest.mark.parametrize("layers", [1, 2, 5])
+def test_the_forward_scan_carries_the_next_layers_weights(layers, policy):
+    """The forward scan's carry holds one layer's four kernels WHOLE, in
+    the compute dtype (gathered by the step before, the first before the
+    loop), beside the activations; what the scan saves a layer (its
+    ``ys``) holds none of them, and the backward scan carries only the
+    activations' cotangent: it gathers inside its own step."""
+    axes = {"data": 4}
+    engine = _engine(3, axes, jnp.bfloat16, policy=policy, layers=layers)
+    forward, backward = _scans(_step_jaxpr(engine, axes))
+    assert (forward[:2], backward[:2]) == ((layers, False), (layers, True))
+    for kernel in KERNELS:
+        assert kernel in _shapes(forward[2], jnp.bfloat16)
+        assert kernel not in _shapes(forward[3])
+        assert (layers,) + kernel not in _shapes(forward[3])
+    assert _shapes(backward[2]) == [(2, SEQ, WIDTH)]
+    plan = engine._zero3_program
+    assert plan["gathers_ahead_step"] == 4 * (layers - 1)
+    # eval_batch's forward takes the same road
+    engine.eval_batch(_batch(engine, axes))
+    (evaluation,) = _scans(engine._jit_eval.trace(
+        engine.state.params,
+        engine._shard_batch(_batch(engine, axes))).jaxpr)
+    assert all(k in _shapes(evaluation[2], jnp.bfloat16) for k in KERNELS)
+
+
+def test_the_compiled_forward_gathers_a_layer_in_two_collectives():
+    """The prefetch sends a layer's three kernels whose shards have the
+    same rows (``c_attn``, attention ``c_proj``, ``c_fc``: 16 of 64) in
+    ONE all-gather, concatenated along their columns, and the MLP's
+    ``c_proj`` (64 rows of 256) in its own: ``LAYERS`` times in the
+    forward loop (the next layer's; the last step's is its own again)
+    and once before it (the first layer's). The backward loop gathers
+    inside its own step, leaf by leaf as it did. The plan counts all of
+    them, leaf by leaf."""
+    axes = {"data": 4}
+    engine = _engine(3, axes, jnp.bfloat16)
+    text = _step_text(engine, axes)
+    plan = engine._zero3_program
+    gathers = [c for c in collectives_per_step(text)
+               if c["op"] == "all-gather" and c["operand_bytes"] >= 1024]
+    widths = {"bf16": 2, "f32": 4}
+
+    def elements(cs):
+        return sorted(b // widths[d] for c in cs for d, b in c["operands"])
+
+    apart = [rows // 4 * cols for rows, cols in KERNELS]
+    together = [sum(apart[:3]), apart[3]]
+    tables = [rows // 4 * cols for rows, cols in TABLES]
+    assert elements(c for c in gathers if c["trips"] == 1) \
+        == sorted(together + tables)
+    assert elements(c for c in gathers if c["trips"] == LAYERS) \
+        == sorted(together + apart)
+    assert (WIDTH // 4, 3 * WIDTH + WIDTH + 4 * WIDTH) in {
+        dims for c in gathers if c["trips"] == LAYERS
+        for dims in c["operand_dims"]}
+    assert plan["leaves_gathered_in_scan"] == 4
+    assert plan["gather_operand_bytes_in_scan"] == \
+        (2 * LAYERS + 1) * KERNEL_ELEMENTS // 4 * 2
+
+
+def test_gathered_together_is_gathered_apart():
+    """``GatherPlan.gather(together=True)`` returns what the leaf-by-leaf
+    gather returns, to the bit, and its transpose is each leaf's own: the
+    same float32 shards of the same sums."""
+    from jax.sharding import PartitionSpec as P
+
+    from deepspeed_tpu.utils.compat import shard_map
+
+    mesh = MeshTopology(axis_sizes={"data": 4},
+                        devices=jax.devices()[:4]).mesh
+    shapes = {"a": (8, 6), "b": (8, 10), "c": (16, 6), "small": (6,)}
+    specs = {k: P("data") if len(v) == 2 else P() for k, v in shapes.items()}
+    tree = {k: jax.random.normal(jax.random.PRNGKey(i), v, jnp.float32)
+            for i, (k, v) in enumerate(shapes.items())}
+    plan = zero.GatherPlan(
+        mesh, specs, {k: jax.ShapeDtypeStruct(v, jnp.float32)
+                      for k, v in shapes.items()}, {"": 0})
+
+    def run(together):
+        def local(tree):
+            def loss(tree):
+                whole = plan.gather(tree, (), dtype=jnp.bfloat16,
+                                    together=together)
+                return sum((w.astype(jnp.float32) ** 2).sum() * (i + 1)
+                           for i, w in enumerate(
+                               jax.tree_util.tree_leaves(whole))), whole
+            (_, whole), grads = jax.value_and_grad(loss, has_aux=True)(tree)
+            return whole, grads
+        return jax.jit(shard_map(
+            local, mesh=mesh, in_specs=(specs,),
+            out_specs=({k: P() for k in shapes}, specs),
+            check_vma=False))(tree)
+
+    (whole_a, grads_a), (whole_b, grads_b) = run(False), run(True)
+    for k, shape in shapes.items():
+        assert whole_b[k].shape == shape
+        np.testing.assert_array_equal(whole_a[k], whole_b[k])
+        np.testing.assert_allclose(grads_a[k], grads_b[k], rtol=1e-6)
+    assert whole_b["a"].dtype == jnp.bfloat16
+    assert whole_b["small"].dtype == jnp.float32  # whole already: untouched
+
+
+@pytest.mark.parametrize("case", ["stage0-on-a-mesh", "stage3-one-device",
+                                  "stage3-tp-mesh", "serving-decode"])
+def test_without_a_plan_the_scan_carries_activations_alone(case):
+    """No plan, no prefetch: every other program's scan is ``nn.scan``'s,
+    whose carry is the activations (and a serving program's pools)."""
+    if case == "serving-decode":
+        cfg = GPT2Config(vocab_size=VOCAB, n_positions=SEQ, n_embd=WIDTH,
+                         n_layer=4, n_head=HEADS, dtype=jnp.bfloat16)
+        module = GPT2LMHeadModel(cfg.for_paged_decode(9, 8, ""))
+        pg = {"block_tables": jnp.zeros((3, 4), jnp.int32),
+              "lengths": jnp.zeros((3,), jnp.int32),
+              "num_valid": jnp.ones((3,), jnp.int32), "prefill": False}
+        variables = jax.eval_shape(lambda: module.init(
+            jax.random.PRNGKey(0), jnp.zeros((3, 1), jnp.int32), paging=pg))
+        jaxpr = jax.make_jaxpr(lambda v, ids: module.apply(
+            v, ids, mutable=["cache"], paging=pg))(
+            variables, jax.ShapeDtypeStruct((3, 1), jnp.int32))
+    else:
+        stage, axes = {"stage0-on-a-mesh": (0, {"data": 4}),
+                       "stage3-one-device": (3, {"data": 1}),
+                       "stage3-tp-mesh": (3, {"data": 2, "tp": 2})}[case]
+        engine = _engine(stage, axes, jnp.bfloat16, layers=4)
+        jaxpr = _step_jaxpr(engine, axes)
+        assert (engine._zero3_program or {}).get("program") \
+            != "gather_at_use"
+    scans = _scans(jaxpr)
+    assert scans and all(length == 4 for length, *_ in scans)
+    for _, _, carry, _ in scans:
+        assert not [k for k in KERNELS if k in _shapes(carry)]
+    if case == "serving-decode":
+        assert "custom_vjp" not in str(jaxpr)
+
+
+@pytest.mark.parametrize("layers", [1, 2, 5])
+def test_stage_3_agrees_with_stage_0_at_every_depth(layers):
+    """One layer (nothing to gather ahead), two and five: two float32
+    steps agree with stage 0's."""
+    axes = {"data": 4}
+    e0 = _engine(0, axes, layers=layers)
+    l0 = _train(e0, axes)
+    p0 = jax.tree_util.tree_map(np.asarray, e0.state.params)
+    e3 = _engine(3, axes, layers=layers)
+    l3 = _train(e3, axes)
+    np.testing.assert_allclose(l3, l0, rtol=2e-6, atol=2e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(p0),
+                    jax.tree_util.tree_leaves(e3.state.params)):
+        # (one weight in 80,000 lands 2.4e-6 off at 5 layers on the
+        # parent's program too: the order of float32 sums)
+        np.testing.assert_allclose(np.asarray(b), a, rtol=0, atol=5e-6)
+
+
+@pytest.mark.parametrize("feature", ["dropout", "pld"])
+def test_a_layers_randomness_has_a_key_of_its_own(feature):
+    """Dropout and progressive layer drop draw from one key a layer
+    (split off the step's), on every chip its own: the step trains, two
+    steps differ, and ``eval_batch`` draws nothing."""
+    axes = {"data": 4}
+    kw = {"dropout": 0.1} if feature == "dropout" else {"pld": True}
+    engine = _engine(3, axes, jnp.float32, layers=2, **kw)
+    losses = _train(engine, axes, steps=3)
+    assert all(np.isfinite(losses))
+    a = float(engine.eval_batch(_batch(engine, axes, step=9)))
+    b = float(engine.eval_batch(_batch(engine, axes, step=9)))
+    assert a == b
 
 
 def test_persistence_is_judged_on_the_layer():
@@ -478,11 +690,17 @@ def test_the_engine_logs_the_plan_once():
     assert (f"{plan['leaves_scattered_by_ring']} leaves by a ring of "
             f"{plan['ring_permutes_step']} float32 permutes "
             f"({plan['ring_operand_bytes_step']} operand bytes)") in logged[0]
-    # a layer's four kernels: shard (bf16) gathered forward and again in
-    # the rematerialised backward, whole (f32) scattered once
+    # how many of a step's leaf gathers run a layer ahead of their use:
+    # the forward's, all but the first layer's
+    assert plan["gathers_ahead_step"] == 4 * (LAYERS - 1)
+    assert (f"({plan['gathers_ahead_step']} gathers a step one layer "
+            "ahead)") in logged[0]
+    # a layer's four kernels: shard (bf16) gathered forward (every layer's
+    # and, in the last step, the last layer's once more) and again in the
+    # rematerialised backward, whole (f32) scattered once
     kernels = WIDTH * 3 * WIDTH + WIDTH * WIDTH + 2 * WIDTH * 4 * WIDTH
     assert plan["gather_operand_bytes_in_scan"] == \
-        2 * LAYERS * kernels // 4 * 2
+        (2 * LAYERS + 1) * kernels // 4 * 2
     tables = VOCAB * WIDTH + SEQ * WIDTH
     assert plan["scatter_operand_bytes_step"] == \
         (LAYERS * kernels + tables) * 4

@@ -1,0 +1,505 @@
+"""The decoder blocks the served families are composed of, each written
+once: the leaf blocks, the attention arithmetic, the sparse FFN, and the
+paged contract, what a served family owes ``serving/engine.py``
+(:class:`ServedConfig`, :class:`PagedDecoder`). A family file holds its
+config, its token mixer, the rows it keeps in the pool and its per-layer
+pattern. Arrows point one way: this file imports no family and names none,
+and no family imports another.
+"""
+
+import dataclasses
+from typing import Any, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.models.decode_utils import (paged_positions,
+                                               paged_write_slots)
+from deepspeed_tpu.moe import dropless
+
+_NEG = -1e30
+# queries a chunk of the masked XLA attention of a whole prompt: 64 heads x
+# 512 queries x 4096 keys of float32 scores are 0.5 GB
+_QUERY_CHUNK = 512
+
+
+# ---------------------------------------------------------------------------
+# leaf blocks
+
+def init(scale=0.02):
+    return nn.initializers.normal(stddev=scale)
+
+
+def dense(of, name, width, std=0.02):
+    """The bias-free projection every block is made of, in the ``dtype`` and
+    ``param_dtype`` that ``of`` (a config, a block) says."""
+    return nn.Dense(width, use_bias=False, dtype=of.dtype,
+                    param_dtype=of.param_dtype, kernel_init=init(std),
+                    name=name)
+
+
+class RMSNorm(nn.Module):
+    """Root-mean-square layernorm (no mean subtraction, no bias)."""
+
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        x32 = x.astype(jnp.float32)
+        x32 = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1,
+                                           keepdims=True) + self.eps)
+        return (x32 * scale).astype(self.dtype)
+
+
+def rope_frequencies(head_dim: int, positions, theta: float):
+    """cos/sin tables for the given absolute positions: [..., head_dim//2]."""
+    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, jnp.float32)
+                           / head_dim))
+    ang = positions.astype(jnp.float32)[..., None] * inv  # [..., hd/2]
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def apply_rope(x, cos, sin):
+    """x: [B, T, H, D]; cos/sin: [T, D/2] shared or [B, T, D/2] per-row
+    (left-padded batches). Rotates pairs (x_even, x_odd) — the interleaved
+    convention HF Llama uses after its half-split equivalence."""
+    x1, x2 = jnp.split(x, 2, axis=-1)  # HF half-split convention
+    if cos.ndim == 2:
+        cos, sin = cos[None], sin[None]
+    c = cos[:, :, None, :]
+    s = sin[:, :, None, :]
+    return jnp.concatenate(
+        [x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(x.dtype)
+
+
+class SwiGLU(nn.Module):
+    """``down(silu(gate x) * up x)`` of one width, back to ``hidden``."""
+
+    width: int
+    hidden: int
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        g = dense(self, "gate_proj", self.width)(x)
+        u = dense(self, "up_proj", self.width)(x)
+        return dense(self, "down_proj", self.hidden)(nn.silu(g) * u)
+
+
+# ---------------------------------------------------------------------------
+# attention arithmetic
+
+def call_positions(cfg, paging, t: int):
+    """Absolute positions of a call's ``t`` tokens: ``[B, T]`` from each
+    row's length under a serving config, ``[1, T]`` from 0 otherwise."""
+    if not cfg.serving:
+        return jnp.arange(t, dtype=jnp.int32)[None]
+    if paging is None:
+        raise ValueError(
+            "paged decode needs the `paging` call argument: "
+            '{"block_tables", "lengths", "num_valid", "prefill"}')
+    return paged_positions(paging["lengths"], t)
+
+
+def masked_gqa(q, k, v, q_pos, k_pos, k_valid=None, window: int = 0,
+               sink=None):
+    """Grouped-query attention in XLA, float32 softmax: ``q [B, T, H, dk]``
+    over ``k [B, S, KV, dk]`` / ``v [B, S, KV, dv]``; query at ``q_pos [B,
+    T]`` sees key at ``k_pos [B, S]`` where ``k_pos <= q_pos``, inside the
+    window if there is one, and ``k_valid``. ``sink [H]``: one more term
+    ``exp(sink)`` in the denominator, with no value. -> ``[B, T, H, dv]``."""
+    b, t, heads, dk = q.shape
+    kv = k.shape[2]
+    group = heads // kv
+    s = jnp.einsum("btkgd,bskd->bkgts", q.reshape(b, t, kv, group, dk), k,
+                   preferred_element_type=jnp.float32) * dk ** -0.5
+    seen = k_pos[:, None, :] <= q_pos[:, :, None]
+    if window:
+        seen = seen & (k_pos[:, None, :] > q_pos[:, :, None] - window)
+    if k_valid is not None:
+        seen = seen & k_valid[:, None, :]
+    seen = seen[:, None, None]                                   # [B,1,1,T,S]
+    s = jnp.where(seen, s, _NEG)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    if sink is not None:
+        sk = sink.astype(jnp.float32).reshape(1, kv, group, 1, 1)
+        m = jnp.maximum(m, sk)
+    p = jnp.where(seen, jnp.exp(s - m), 0.0)
+    denom = jnp.sum(p, axis=-1, keepdims=True)
+    if sink is not None:
+        denom = denom + jnp.exp(sk - m)
+    out = jnp.einsum("bkgts,bskd->btkgd", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    denom = jnp.where(denom == 0.0, 1.0, denom)
+    out = out / denom.transpose(0, 3, 1, 2, 4)
+    return out.reshape(b, t, heads, v.shape[-1]).astype(q.dtype)
+
+
+def causal_gqa(q, k, v, window: int = 0, sink=None):
+    """A whole sequence from position 0 over its own keys (training-style
+    forward, a prompt's prefill): the masked XLA path, in pieces that fit.
+    Window layers of a long sequence attend block by block against the
+    block before and their own (a band of ``2 x window`` keys a query
+    block); global layers in chunks of queries."""
+    b, t = q.shape[:2]
+    pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None], (b, t))
+    if window and t % window == 0 and t > 2 * window:
+        n = t // window
+
+        def blocks(x):
+            return x.reshape(b * n, window, *x.shape[2:])
+
+        def with_previous(x):
+            x = x.reshape(b, n, window, *x.shape[2:])
+            prev = jnp.concatenate([jnp.zeros_like(x[:, :1]), x[:, :-1]], 1)
+            return jnp.concatenate([prev, x], 2).reshape(
+                b * n, 2 * window, *x.shape[3:])
+
+        # positions of the band: the block before (negative before the
+        # first block: masked) and the block itself
+        base = (jnp.arange(n, dtype=jnp.int32) * window)[None, :, None]
+        band = jnp.arange(-window, window, dtype=jnp.int32)[None, None]
+        k_pos = jnp.broadcast_to(base + band, (b, n, 2 * window)).reshape(
+            b * n, 2 * window)
+        out = masked_gqa(blocks(q), with_previous(k), with_previous(v),
+                         blocks(pos), k_pos, k_pos >= 0, window, sink)
+        return out.reshape(b, t, *out.shape[2:])
+    if t > 2 * _QUERY_CHUNK and t % _QUERY_CHUNK == 0:
+        n = t // _QUERY_CHUNK
+
+        def chunk(args):
+            qc, pc = args
+            return masked_gqa(qc, k, v, pc, pos, None, window, sink)
+
+        qs = q.reshape(b, n, _QUERY_CHUNK, *q.shape[2:]).swapaxes(0, 1)
+        ps = pos.reshape(b, n, _QUERY_CHUNK).swapaxes(0, 1)
+        out = jax.lax.map(chunk, (qs, ps))
+        return out.swapaxes(0, 1).reshape(b, t, *out.shape[3:])
+    return masked_gqa(q, k, v, pos, pos, None, window, sink)
+
+
+def paged_gqa(q, k, v, pos, paging, table, k_pool, v_pool, index, label,
+              sink=None, work=None):
+    """One layer's grouped-query step of a serving program over rows a
+    block table addresses: write this call's keys and values into layer
+    ``index`` of the pools (``[layers, blocks, block_size, kv_heads *
+    width]``) through ``table [B, blocks a sequence]``, and attend. A whole
+    prompt (``paging["prefill"]``) attends over its own keys; a decode step
+    on a TPU runs the paged kernel over the work list ``work``; a prompt's
+    later chunk, and every step where no TPU is, gathers the sequence's
+    blocks and takes the masked XLA path. ``label`` prefixes what
+    ``record_dispatch`` counts (the caller's: ``stats()`` reads it).
+    -> ``(y [B, T, H, dv], k_pool, v_pool)``."""
+    from deepspeed_tpu.ops.attention import record_dispatch, use_decode_kernel
+    from deepspeed_tpu.ops.hybrid_decode_attention import (
+        decode_attention_hybrid)
+
+    b, t = q.shape[:2]
+    kv, bs = k.shape[2], k_pool.shape[2]
+    blk, off = paged_write_slots(table, pos, paging["num_valid"], bs)
+    k_pool = k_pool.at[index, blk, off].set(k.reshape(b, t, -1))
+    v_pool = v_pool.at[index, blk, off].set(v.reshape(b, t, -1))
+    if paging.get("prefill"):
+        record_dispatch(f"{label}_prefill_xla")
+        y = causal_gqa(q, k, v, 0, sink)
+    elif t == 1 and use_decode_kernel():
+        record_dispatch(f"{label}_decode_kernel")
+        with jax.named_scope("attn._hybrid_kv_attend"):
+            y = decode_attention_hybrid(
+                q, k_pool, v_pool, table, paging["lengths"], index,
+                kv_heads=kv, sink=sink, work=work)
+    else:
+        record_dispatch(f"{label}_cached_xla")
+        rows = table.shape[-1] * bs
+        key_pos = jnp.broadcast_to(
+            jnp.arange(rows, dtype=jnp.int32)[None], (b, rows))
+        y = masked_gqa(
+            q, k_pool[index, table].reshape(b, rows, kv, k.shape[-1]),
+            v_pool[index, table].reshape(b, rows, kv, v.shape[-1]),
+            pos, key_pos, None, 0, sink)
+    return y, k_pool, v_pool
+
+
+# ---------------------------------------------------------------------------
+# the sparse FFN
+
+class SparseFFN(nn.Module):
+    """The sparse FFN: the router over ALL ``experts`` published, the expert
+    weights of the share held here (rank ``ep_rank`` of ``ep_size``:
+    ``moe/dropless.py``), and the shared experts where ``shared_width``
+    says there are any, one SwiGLU of their summed width that every share
+    computes alike. ``scoring``, ``renormalize``, ``norm_eps`` and ``scale``
+    are ``dropless.route``'s; ``bias_std`` None is no selection bias (else
+    the std it is drawn with: a balancing term that training moves from
+    zero).
+
+    Takes the float32 norm and returns the float32 sum of the held experts'
+    terms, the layer's counters and the experts each token chose ``[B, T,
+    k]``: ``(y, counters, chosen)``, or ``(y, shared, counters, chosen)``
+    with shared experts, the two terms apart so that shares can be summed
+    with the shared term counted once."""
+
+    experts: int
+    top_k: int
+    width: int
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    scoring: str = "sigmoid"
+    renormalize: bool = True
+    norm_eps: float = 0.0
+    scale: float = 1.0
+    bias_std: Optional[float] = None
+    shared_width: int = 0
+    ep_rank: int = 0
+    ep_size: int = 1
+
+    @nn.compact
+    def __call__(self, x, valid=None):
+        b, t, d = x.shape
+        first, count = dropless.held_range(self.experts, self.ep_rank,
+                                           self.ep_size)
+        f, dtype = self.width, self.dtype
+        # (created in this order: a scope draws its parameters from a
+        # counter, so the order decides the values a seed gives)
+        router = self.param("router", init(), (d, self.experts),
+                            self.param_dtype)
+        bias = None if self.bias_std is None else self.param(
+            "router_bias", init(self.bias_std), (self.experts,),
+            self.param_dtype)
+        gate = self.param("gate", init(), (count, d, f), self.param_dtype)
+        up = self.param("up", init(), (count, d, f), self.param_dtype)
+        down = self.param("down", init(), (count, f, d), self.param_dtype)
+        rows = x.reshape(b * t, d)
+        # the gate reads the float32 norm itself, the experts ``dtype``
+        # (both calls go through the module: controls replace them there)
+        experts, weights = dropless.route(
+            rows, router, bias, self.top_k, norm_eps=self.norm_eps,
+            scale=float(self.scale), scoring=self.scoring,
+            renormalize=self.renormalize)
+        rows = rows.astype(dtype)
+        y, counters = dropless.expert_ffn(
+            rows, experts, weights, gate.astype(dtype), up.astype(dtype),
+            down.astype(dtype), first_expert=first, n_routed=self.experts,
+            valid=None if valid is None else valid.reshape(b * t))
+        shared = ()
+        if self.shared_width:
+            shared = (SwiGLU(self.shared_width, d, dtype, self.param_dtype,
+                             name="shared_experts")(x.astype(dtype)),)
+        return (y.reshape(b, t, d), *(s.astype(jnp.float32) for s in shared),
+                counters, experts.reshape(b, t, -1))
+
+
+# ---------------------------------------------------------------------------
+# the paged contract: what ``ServingEngine`` asks of a served model
+
+class ServedConfig:
+    """The config's half of the contract, mixed into a family's frozen
+    dataclass. ``ServingEngine`` (``serving/engine.py``) reads of a config:
+
+    - ``for_paged_decode(num_blocks, block_size, **knobs)`` -> the serving
+      variant (here). ``knobs``: ``kv_dtype`` where the engine's config
+      names one, ``return_routed`` where it keeps routed sets, and
+      ``<slot_knob>=decode slots`` where a slot keeps state;
+    - ``routed_width`` (here), ``n_head`` (here), ``max_position_embeddings``;
+    - the family's own, each optional: ``paged_slot_state_for(block_size)``
+      -> None or ``{"entries", "knob", "what"}``, state of fixed size a
+      decode slot keeps beside its block table (``entries`` of the table a
+      slot hands its programs address it, after the sequence's blocks);
+      ``paged_row_kind()`` -> ``{"kind", "what"}`` where a pool row is no
+      keys and values by heads; ``kv_bytes_per_token()`` and
+      ``kv_live_bytes(live)`` -> bytes by kind of row.
+
+    The family's dataclass declares ``vocab_size``, ``hidden_size``,
+    ``num_hidden_layers``, ``num_attention_heads``, ``intermediate_size``,
+    ``num_experts_per_tok``, ``dtype``, ``param_dtype`` and the serving
+    fields ``decode``, ``paged``, ``paged_num_blocks``, ``paged_block_size``,
+    ``paged_return_routed``, ``paged_<slot_knob>``; it says ``sparse(i)``,
+    whether layer ``i``'s FFN is sparse, and ``sparse_ffn()``, its own
+    routing as :class:`SparseFFN`'s arguments."""
+
+    # the keyword of for_paged_decode that takes the decode slots (the
+    # ``knob`` of ``paged_slot_state_for``), in a family whose slots keep
+    # state
+    slot_knob = None
+    # why ``kv_dtype`` is refused: "this model's <unquantized>"
+    unquantized = "rows have no quantized pool"
+
+    @property
+    def n_head(self) -> int:
+        return self.num_attention_heads
+
+    @property
+    def serving(self) -> bool:
+        return self.decode and self.paged
+
+    @property
+    def sparse_layers(self) -> int:
+        return sum(bool(self.sparse(i))
+                   for i in range(self.num_hidden_layers))
+
+    @property
+    def routed_width(self) -> int:
+        """Experts a token chooses over all its sparse layers: the width
+        of a row of what ``paged_return_routed`` returns."""
+        return self.sparse_layers * self.num_experts_per_tok
+
+    def pool_dims(self):
+        """``(paged_num_blocks, paged_block_size)``, checked (block 0 is
+        the garbage block)."""
+        nb, bs = self.paged_num_blocks, self.paged_block_size
+        if nb <= 1 or bs <= 0:
+            raise ValueError(f"paged decode needs paged_num_blocks > 1 (got "
+                             f"{nb}) and paged_block_size > 0 (got {bs})")
+        return nb, bs
+
+    def for_paged_decode(self, num_blocks: int, block_size: int,
+                         kv_dtype: str = "", return_routed: bool = False,
+                         **slots):
+        """Serving variant. ``num_blocks`` sizes the pool the block tables
+        address (block 0 the garbage block); ``<slot_knob>=n`` gives ``n``
+        decode slots their state; with ``return_routed`` a call also
+        returns every token's chosen experts (:class:`PagedDecoder`)."""
+        if set(slots) - {self.slot_knob}:
+            raise TypeError(f"for_paged_decode got {sorted(slots)}")
+        if kv_dtype:
+            raise ValueError(f"kv_cache_dtype {kv_dtype!r}: this model's "
+                             f"{self.unquantized}")
+        n = int(slots.get(self.slot_knob, 0))
+        state = self.slot_knob and self.paged_slot_state_for(block_size)
+        if state and n < 1:
+            raise ValueError(f"{state['what']}: for_paged_decode needs "
+                             f"{self.slot_knob}")
+        kept = {f"paged_{self.slot_knob}": n} if self.slot_knob else {}
+        return dataclasses.replace(
+            self, decode=True, paged=True, paged_num_blocks=int(num_blocks),
+            paged_block_size=int(block_size),
+            paged_return_routed=bool(return_routed), **kept)
+
+
+def paged_valid(paging, t: int):
+    """``[B, T]``: the tokens of a paged call that are tokens. A bucket's
+    padding and an idle slot's row are none: they route nowhere."""
+    return ((jnp.arange(t)[None] < paging["num_valid"][:, None])
+            & (paging["block_tables"][:, :1] != 0))
+
+
+class PagedDecoder(nn.Module):
+    """The module's half of the contract, the decoder shell: embedding ->
+    per layer the family's mixer and a dense or a sparse FFN, pre-norm, on a
+    float32 residual stream -> final RMSNorm -> tied or untied head.
+
+    Plain call: ``[B, T, vocab]`` float32 logits. Paged (serving) call,
+    ``paging = {"block_tables", "lengths", "num_valid", "prefill"}`` with
+    the ``cache`` collection mutable: ``(logits, {"counters": int32[4]})``,
+    the sparse layers' counters of this call summed
+    (``moe/dropless.COUNTERS``), which the serving programs hand back with
+    the tokens; under ``paged_return_routed`` also ``"routed": int32[B, T,
+    sparse layers x k]``, the experts every token chose, layer by layer (a
+    padded row's are meaningless). ``ServingEngine`` reads of the class
+    ``serve_counters``, ``serve_routed``, and ``lookup_table`` where the
+    family's :meth:`lookup` takes ``paging["lookup"]``. A family says the
+    three methods and the attributes below."""
+
+    config: Any
+    # what the serving engine's ledger names the counters by
+    serve_counters = dropless.COUNTERS
+    # for_paged_decode takes ``return_routed``
+    serve_routed = True
+    # the two norms of a layer (``layers_<i>_<name>``) and their epsilon's
+    # field in the config
+    norms = ("input_layernorm", "post_attention_layernorm")
+    eps_field = "rms_norm_eps"
+    # the head is the embedding
+    tied = False
+    # the dense FFN's parameter type (None: the config's ``param_dtype``)
+    dense_param_dtype = None
+
+    def pool_shapes(self, num_blocks: int, block_size: int) -> dict:
+        """``{name: shape}`` of the serving pools (``cache`` variables of
+        ``config.dtype``), declared once by the model."""
+        raise NotImplementedError
+
+    def step_work(self, paging):
+        """What every layer's decode kernel shares of one step (the work
+        lists: the grids follow this step's lengths), made once."""
+        raise NotImplementedError
+
+    def mixer(self, i: int, u, paging, pools, work):
+        """Layer ``i``'s token mixer on the normed stream ``u`` ->
+        ``(its term, pools)``; ``pools`` the pools' values by name (None in
+        a plain call), ``work`` :meth:`step_work`'s or None."""
+        raise NotImplementedError
+
+    def lookup(self, table, ids, paging):
+        return table[ids]
+
+    @nn.compact
+    def __call__(self, input_ids, deterministic=True, paging=None):
+        cfg = self.config
+        paged = cfg.serving
+        # (``embed_tokens`` first and ``lm_head`` last: the order decides
+        # the values a seed gives)
+        embed = self.param("embed_tokens", init(),
+                           (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
+        x = self.lookup(embed, input_ids, paging).astype(cfg.dtype)
+        t = input_ids.shape[1]
+        pools = valid = work = None
+        if paged:
+            variables = {
+                name: self.variable("cache", name, jnp.zeros, shape,
+                                    cfg.dtype)
+                for name, shape in self.pool_shapes(*cfg.pool_dims()).items()}
+            pools = {name: var.value for name, var in variables.items()}
+            valid = paged_valid(paging, t)
+            if t == 1 and not paging.get("prefill"):
+                from deepspeed_tpu.ops.attention import use_decode_kernel
+
+                if use_decode_kernel():
+                    work = self.step_work(paging)
+        counters = jnp.zeros((len(dropless.COUNTERS),), jnp.int32)
+        routed = []
+        # the residual stream and every norm are float32 (bfloat16 would
+        # round the stream once a layer, and a norm's rounding moves the
+        # router's near ties); what a matmul reads is cfg.dtype
+        x = x.astype(jnp.float32)
+        norm = lambda name: RMSNorm(getattr(cfg, self.eps_field),
+                                    jnp.float32, name=name)
+        for i in range(cfg.num_hidden_layers):
+            scope = f"layers_{i}"
+            a, pools = self.mixer(
+                i, norm(f"{scope}_{self.norms[0]}")(x).astype(cfg.dtype),
+                paging, pools, work)
+            x = x + a.astype(jnp.float32)
+            h = norm(f"{scope}_{self.norms[1]}")(x)
+            if cfg.sparse(i):
+                y, *shared, c, chosen = SparseFFN(
+                    **cfg.sparse_ffn(), name=f"{scope}_mlp")(h, valid)
+                for term in shared:
+                    y = y + term
+                counters = counters + c
+                routed.append(chosen)
+            else:
+                y = SwiGLU(cfg.intermediate_size, cfg.hidden_size, cfg.dtype,
+                           self.dense_param_dtype or cfg.param_dtype,
+                           name=f"{scope}_mlp")(h.astype(cfg.dtype))
+            x = x + y.astype(jnp.float32)
+        if paged:
+            for name, var in variables.items():
+                var.value = pools[name]
+        x = norm("norm")(x).astype(cfg.dtype)
+        head = embed if self.tied else self.param(
+            "lm_head", init(), (cfg.vocab_size, cfg.hidden_size),
+            cfg.param_dtype)
+        logits = jnp.einsum("btc,vc->btv", x, head.astype(cfg.dtype),
+                            preferred_element_type=jnp.float32)
+        if not paged:
+            return logits
+        aux = {"counters": counters}
+        if cfg.paged_return_routed and routed:
+            aux["routed"] = jnp.concatenate(routed, axis=-1)
+        return logits, aux

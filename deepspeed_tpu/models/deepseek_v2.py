@@ -22,6 +22,10 @@ Layer ``i``, pre-norm, RMSNorm with a learned weight, no bias anywhere:
   summed width, which every share of the experts computes alike.
 - a final norm; an untied head.
 
+The norms, SwiGLU, the sparse FFN and the decoder shell are
+``models/blocks.py``'s; this file holds the config, YaRN, the latent
+attention in its two forms and the latent pool.
+
 WHAT A TOKEN KEEPS. One row a layer, ``[c | k_pe]`` after the norm and the
 rotation: ``kv_lora_rank + qk_rope_head_dim`` values (576: 1,152 B in
 bfloat16) shared by every head, where keys and values by heads would keep
@@ -50,6 +54,7 @@ paths over the same rows, the same function:
 """
 
 import dataclasses
+import functools
 import math
 from typing import Any, Optional
 
@@ -57,9 +62,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.decode_utils import (paged_positions,
-                                               paged_write_slots)
-from deepspeed_tpu.models.llama import RMSNorm
+from deepspeed_tpu.models import blocks
+from deepspeed_tpu.models.decode_utils import paged_write_slots
 from deepspeed_tpu.moe import dropless
 
 _NEG = -1e30
@@ -80,7 +84,7 @@ class YarnScaling:
 
 
 @dataclasses.dataclass(frozen=True)
-class DeepseekV2Config:
+class DeepseekV2Config(blocks.ServedConfig):
     vocab_size: int = 102400
     hidden_size: int = 2048
     num_hidden_layers: int = 27
@@ -119,10 +123,22 @@ class DeepseekV2Config:
             raise ValueError(f"qk_rope_head_dim {self.qk_rope_head_dim} "
                              "rotates pairs")
 
-    # what the generic serving code asks of a model's config
-    @property
-    def n_head(self) -> int:
-        return self.num_attention_heads
+    # the contract's (blocks.ServedConfig): why kv_dtype is refused (no
+    # state a decode slot), the layers that are sparse and their routing
+    unquantized = "latent rows have no quantized pool"
+
+    def sparse(self, i: int) -> bool:
+        return i >= self.first_k_dense_replace
+
+    def sparse_ffn(self) -> dict:
+        return dict(experts=self.n_routed_experts,
+                    top_k=self.num_experts_per_tok,
+                    width=self.moe_intermediate_size, scoring="softmax",
+                    renormalize=False, scale=self.routed_scaling_factor,
+                    shared_width=(self.n_shared_experts
+                                  * self.moe_intermediate_size),
+                    ep_rank=self.ep_rank, ep_size=self.ep_size,
+                    dtype=self.dtype, param_dtype=self.param_dtype)
 
     @property
     def qk_head_dim(self) -> int:
@@ -147,17 +163,6 @@ class DeepseekV2Config:
             scale = scale * m * m
         return scale
 
-    @property
-    def sparse_layers(self) -> int:
-        return self.num_hidden_layers - min(self.first_k_dense_replace,
-                                            self.num_hidden_layers)
-
-    @property
-    def routed_width(self) -> int:
-        """Experts a token chooses over all its sparse layers: the width
-        of a row of what ``paged_return_routed`` returns."""
-        return self.sparse_layers * self.num_experts_per_tok
-
     def kv_bytes_per_token(self) -> dict:
         """Bytes one token keeps, all layers: the latent rows as they are
         COUNTED (576 values a layer), whatever lanes the pool pads to."""
@@ -180,21 +185,6 @@ class DeepseekV2Config:
                         f"{self.num_attention_heads} heads, no keys and "
                         "values by heads)"}
 
-    def for_paged_decode(self, num_blocks: int, block_size: int,
-                         kv_dtype: str = "", return_routed: bool = False):
-        """Serving variant (see the module's docstring). ``num_blocks``
-        sizes the latent pool (block 0 the garbage block); with
-        ``return_routed`` a call also returns every token's chosen
-        experts."""
-        if kv_dtype:
-            raise ValueError(
-                f"kv_cache_dtype {kv_dtype!r}: this model's latent rows "
-                "have no quantized pool")
-        return dataclasses.replace(
-            self, decode=True, paged=True, paged_num_blocks=int(num_blocks),
-            paged_block_size=int(block_size),
-            paged_return_routed=bool(return_routed))
-
     @staticmethod
     def tiny(**kw):
         """The CPU tests' size: every mechanism, no published width."""
@@ -209,10 +199,6 @@ class DeepseekV2Config:
                         factor=8.0, original_max_position_embeddings=64))
         base.update(kw)
         return DeepseekV2Config(**base)
-
-
-def _init(scale=0.02):
-    return nn.initializers.normal(stddev=scale)
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -309,26 +295,6 @@ def absorbed_halves(w_kvb, nope: int):
     return w_kvb[..., :nope], w_kvb[..., nope:]
 
 
-class SwiGLU(nn.Module):
-    """``down(silu(gate x) * up x)`` of one width."""
-
-    config: DeepseekV2Config
-    width: int
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.config
-
-        def proj(name, width):
-            return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
-                            param_dtype=cfg.param_dtype, kernel_init=_init(),
-                            name=name)
-
-        return proj("down_proj", cfg.hidden_size)(
-            nn.silu(proj("gate_proj", self.width)(x))
-            * proj("up_proj", self.width)(x))
-
-
 class LatentAttention(nn.Module):
     config: DeepseekV2Config
 
@@ -339,30 +305,21 @@ class LatentAttention(nn.Module):
         heads, rank = cfg.num_attention_heads, cfg.kv_lora_rank
         nope, rope, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                           cfg.v_head_dim)
-
-        def proj(name, width):
-            return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
-                            param_dtype=cfg.param_dtype, kernel_init=_init(),
-                            name=name)
-
+        proj = functools.partial(blocks.dense, cfg)
         q = proj("q_proj", heads * cfg.qk_head_dim)(x).reshape(
             b, t, heads, cfg.qk_head_dim)
         kva = proj("kv_a_proj_with_mqa", rank + rope)(x)
-        c = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="kv_a_layernorm")(
-            kva[..., :rank])
+        c = blocks.RMSNorm(cfg.rms_norm_eps, cfg.dtype,
+                           name="kv_a_layernorm")(kva[..., :rank])
         # [rank, heads, nope + dv]: a head's key half, then its value half
-        w_kvb = self.param("kv_b_proj", _init(),
+        w_kvb = self.param("kv_b_proj", blocks.init(),
                            (rank, heads * (nope + dv)),
                            cfg.param_dtype).astype(cfg.dtype).reshape(
                                rank, heads, nope + dv)
-        paged = cfg.decode and cfg.paged
-        if paged and paging is None:
-            raise ValueError(
-                "paged decode needs the `paging` call argument: "
-                '{"block_tables", "lengths", "num_valid", "prefill"}')
-        pos = (paged_positions(paging["lengths"], t) if paged
-               else jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None],
-                                     (b, t)))
+        paged = cfg.serving
+        pos = blocks.call_positions(cfg, paging, t)
+        if not paged:
+            pos = jnp.broadcast_to(pos, (b, t))
         q_nope = q[..., :nope]
         q_pe = rotate_pairs(q[..., nope:], pos, cfg)
         k_pe = rotate_pairs(kva[..., rank:], pos, cfg)
@@ -512,125 +469,35 @@ class LatentAttention(nn.Module):
         return y, pool
 
 
-class SparseExperts(nn.Module):
-    """The sparse FFN: the softmax gate over ALL published experts, the
-    expert weights of the share held here (``moe/dropless.py``), and the
-    shared experts, which every share computes alike. Takes the float32
-    norm and returns ``(the held experts' terms, the shared experts' term,
-    the layer's counters, the experts each token chose [B, T, k])``, the
-    terms float32 and apart so that shares can be summed with the shared
-    term counted once."""
+def SparseExperts(config, **kw):
+    """The sparse FFN of a config: ``blocks.SparseFFN`` with this family's
+    routing (``DeepseekV2Config.sparse_ffn``), by the name the benchmark's
+    family builds it under. -> ``(the held experts' terms, the shared
+    experts' term, counters, chosen)``."""
+    return blocks.SparseFFN(**config.sparse_ffn(), **kw)
+
+
+class DeepseekV2ForCausalLM(blocks.PagedDecoder):
+    """``blocks.PagedDecoder`` over latent attention, an untied head."""
 
     config: DeepseekV2Config
 
-    @nn.compact
-    def __call__(self, x, valid=None):
+    def pool_shapes(self, num_blocks, block_size):
+        """The one serving pool: a latent row a token a layer, through the
+        block table."""
         cfg = self.config
-        b, t, d = x.shape
-        first, count = dropless.held_range(cfg.n_routed_experts, cfg.ep_rank,
-                                           cfg.ep_size)
-        f = cfg.moe_intermediate_size
-        router = self.param("router", _init(), (d, cfg.n_routed_experts),
-                            cfg.param_dtype)
-        gate = self.param("gate", _init(), (count, d, f), cfg.param_dtype)
-        up = self.param("up", _init(), (count, d, f), cfg.param_dtype)
-        down = self.param("down", _init(), (count, f, d), cfg.param_dtype)
-        rows = x.reshape(b * t, d)
-        # the gate reads the float32 norm itself, the experts its cfg.dtype
-        experts, weights = dropless.route(
-            rows, router, None, cfg.num_experts_per_tok,
-            scale=float(cfg.routed_scaling_factor), scoring="softmax",
-            renormalize=False)
-        rows = rows.astype(cfg.dtype)
-        y, counters = dropless.expert_ffn(
-            rows, experts, weights, gate.astype(cfg.dtype),
-            up.astype(cfg.dtype), down.astype(cfg.dtype),
-            first_expert=first, n_routed=cfg.n_routed_experts,
-            valid=None if valid is None else valid.reshape(b * t))
-        shared = SwiGLU(cfg, cfg.n_shared_experts * f,
-                        name="shared_experts")(x.astype(cfg.dtype))
-        return (y.reshape(b, t, d), shared.astype(jnp.float32), counters,
-                experts.reshape(b, t, -1))
+        return {"latent_pool": (cfg.num_hidden_layers, num_blocks,
+                                block_size, cfg.latent_lanes)}
 
+    def step_work(self, paging):
+        """The kernel's grid follows this step's lengths, the same for
+        every layer."""
+        from deepspeed_tpu.ops.latent_decode_attention import latent_step_work
 
-class DeepseekV2ForCausalLM(nn.Module):
-    """Embedding -> the layers -> final RMSNorm -> untied head. Plain call:
-    ``[B, T, vocab]`` float32 logits. Paged (serving) call: ``(logits,
-    {"counters": int32[4]})`` as ``MiMoV2ForCausalLM``'s, with ``"routed"``
-    under ``paged_return_routed``."""
+        return latent_step_work(paging["lengths"], paging["block_tables"],
+                                self.config.paged_block_size)
 
-    config: DeepseekV2Config
-    serve_counters = dropless.COUNTERS
-    serve_routed = True
-
-    @nn.compact
-    def __call__(self, input_ids, deterministic=True, paging=None):
-        cfg = self.config
-        paged = cfg.decode and cfg.paged
-        embed = self.param("embed_tokens", _init(),
-                           (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
-        x = embed[input_ids].astype(cfg.dtype)
-        t = input_ids.shape[1]
-        pool = valid = work = None
-        if paged:
-            nb, bs = cfg.paged_num_blocks, cfg.paged_block_size
-            if nb <= 1 or bs <= 0:
-                raise ValueError(
-                    f"paged decode needs paged_num_blocks > 1 (got {nb}) "
-                    f"and paged_block_size > 0 (got {bs})")
-            # the one serving pool, declared by the model: a latent row a
-            # token a layer, through the block table
-            variable = self.variable(
-                "cache", "latent_pool", jnp.zeros,
-                (cfg.num_hidden_layers, nb, bs, cfg.latent_lanes), cfg.dtype)
-            pool = variable.value
-            tables, lengths = paging["block_tables"], paging["lengths"]
-            # a bucket's padding and an idle slot's row are no tokens: they
-            # route nowhere
-            valid = ((jnp.arange(t)[None] < paging["num_valid"][:, None])
-                     & (tables[:, :1] != 0))
-            if t == 1 and not paging.get("prefill"):
-                from deepspeed_tpu.ops.attention import use_decode_kernel
-                from deepspeed_tpu.ops.latent_decode_attention import (
-                    latent_step_work)
-
-                if use_decode_kernel():
-                    # the kernel's grid follows this step's lengths, the
-                    # same for every layer: made once
-                    work = latent_step_work(lengths, tables, bs)
-        counters = jnp.zeros((len(dropless.COUNTERS),), jnp.int32)
-        routed = []
-        # the residual stream and every norm are float32, as MiMo-V2's are
-        # (models/mimo_v2.py says why); what a matmul reads is cfg.dtype
-        x = x.astype(jnp.float32)
-        norm = lambda name: RMSNorm(cfg.rms_norm_eps, jnp.float32, name=name)
-        for i in range(cfg.num_hidden_layers):
-            scope = f"layers_{i}"
-            a, pool = LatentAttention(cfg, name=f"{scope}_attn")(
-                norm(f"{scope}_input_layernorm")(x).astype(cfg.dtype),
-                paging, pool, i, work)
-            x = x + a.astype(jnp.float32)
-            h = norm(f"{scope}_post_attention_layernorm")(x)
-            if i >= cfg.first_k_dense_replace:
-                y, shared, c, chosen = SparseExperts(
-                    cfg, name=f"{scope}_mlp")(h, valid)
-                y = y + shared
-                counters = counters + c
-                routed.append(chosen)
-            else:
-                y = SwiGLU(cfg, cfg.intermediate_size, name=f"{scope}_mlp")(
-                    h.astype(cfg.dtype))
-            x = x + y.astype(jnp.float32)
-        if paged:
-            variable.value = pool
-        x = norm("norm")(x).astype(cfg.dtype)
-        head = self.param("lm_head", _init(),
-                          (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
-        logits = jnp.einsum("btc,vc->btv", x, head.astype(cfg.dtype),
-                            preferred_element_type=jnp.float32)
-        if not paged:
-            return logits
-        aux = {"counters": counters}
-        if cfg.paged_return_routed and routed:
-            aux["routed"] = jnp.concatenate(routed, axis=-1)
-        return logits, aux
+    def mixer(self, i, u, paging, pools, work):
+        a, pool = LatentAttention(self.config, name=f"layers_{i}_attn")(
+            u, paging, pools and pools["latent_pool"], i, work)
+        return a, pools and {"latent_pool": pool}

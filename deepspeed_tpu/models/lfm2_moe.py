@@ -12,9 +12,14 @@ Layer ``i``, pre-norm, RMSNorm with a learned weight, no bias anywhere:
 - ``"full_attention"``: grouped-query heads of ``hidden / heads``, RMSNorm
   over every query and key head BEFORE RoPE (all dims, half-rotation).
 - FFN: SwiGLU for ``i < num_dense_layers``, else dropless sigmoid top-k
-  routing (``moe/dropless.py``, every expert held; ``models/mimo_v2.py``'s
-  ``SparseExperts`` with this family's ``+ 1e-6`` and scaling factor).
+  routing (``moe/dropless.py``, every expert held, with this family's
+  ``+ 1e-6`` and scaling factor).
 - a final norm; the head is the embedding, tied.
+
+The norms, RoPE, SwiGLU, the attention arithmetic (the paged step through a
+block table with it), the sparse FFN and the decoder shell are
+``models/blocks.py``'s; this file holds the config, the short convolution
+and its state, the QK-normed attention and the pools.
 
 SERVING. ``for_paged_decode`` gives the module the attention layers' KV
 pools, addressed through the sequence's block table as every model's are,
@@ -22,7 +27,7 @@ and ONE MORE pool, ``conv_state_pool [conv layers, 1 + slots, L - 1,
 hidden]``: row ``1 + s`` is decode slot ``s``'s state in every
 convolution layer, row 0 what idle rows write. The engine hands each row
 of a program its slot's state row as the table's last entry, through the
-seam that hands MiMo-V2 a slot's ring (``paged_slot_state_for``,
+engine's per-slot seam (``paged_slot_state_for``,
 ``serving/engine.py``): state of fixed size a slot, written in place every
 step. One function, :meth:`ShortConv.__call__`, serves the whole-prompt
 prefill, a prefill chunk and a decode step: a sequence at length 0 starts
@@ -31,23 +36,19 @@ row's ``num_valid``, never at a bucket's end.
 """
 
 import dataclasses
+import functools
 from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.decode_utils import (embed_lookup, paged_positions,
-                                               paged_write_slots)
-from deepspeed_tpu.models.llama import (LlamaMLP, RMSNorm, apply_rope,
-                                        rope_frequencies)
-from deepspeed_tpu.models.mimo_v2 import (SparseExperts, causal_gqa,
-                                          masked_gqa)
-from deepspeed_tpu.moe import dropless
+from deepspeed_tpu.models import blocks
+from deepspeed_tpu.models.decode_utils import embed_lookup
 
 
 @dataclasses.dataclass(frozen=True)
-class Lfm2MoeConfig:
+class Lfm2MoeConfig(blocks.ServedConfig):
     vocab_size: int = 65536
     hidden_size: int = 2048
     num_hidden_layers: int = 24
@@ -97,36 +98,26 @@ class Lfm2MoeConfig:
                 f"{self.num_attention_heads} heads over hidden "
                 f"{self.hidden_size} and {self.num_key_value_heads} KV heads")
 
-    # what the generic serving code asks of a model's config
-    @property
-    def n_head(self) -> int:
-        return self.num_attention_heads
+    # the contract's (blocks.ServedConfig): the slots' keyword, why
+    # kv_dtype is refused, the layers that are sparse and their routing
+    # (every expert held)
+    slot_knob = "state_slots"
+    unquantized = "convolution state has no quantized pool"
+
+    def sparse(self, i: int) -> bool:
+        return i >= self.num_dense_layers
+
+    def sparse_ffn(self) -> dict:
+        return dict(experts=self.num_experts, top_k=self.num_experts_per_tok,
+                    width=self.moe_intermediate_size,
+                    norm_eps=self.route_norm_eps,
+                    scale=self.routed_scaling_factor,
+                    bias_std=self.expert_bias_std, dtype=self.dtype,
+                    param_dtype=self.param_dtype)
 
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_attention_heads
-
-    # what ``mimo_v2.SparseExperts`` asks of one: every expert held
-    @property
-    def n_routed_experts(self) -> int:
-        return self.num_experts
-
-    ep_rank, ep_size = 0, 1
-
-    @property
-    def selection_bias_std(self) -> float:
-        return self.expert_bias_std
-
-    @property
-    def sparse_layers(self) -> int:
-        return self.num_hidden_layers - min(self.num_dense_layers,
-                                            self.num_hidden_layers)
-
-    @property
-    def routed_width(self) -> int:
-        """Experts a token chooses over all its sparse layers: the width
-        of a row of what ``paged_return_routed`` returns."""
-        return self.sparse_layers * self.num_experts_per_tok
 
     def layers_of(self, kind: str):
         """Indices of the layers of one kind, in order: a layer's place in
@@ -150,7 +141,7 @@ class Lfm2MoeConfig:
         state pool. None without convolution layers."""
         if not self.layers_of("conv"):
             return None
-        return {"entries": 1, "knob": "state_slots",
+        return {"entries": 1, "knob": self.slot_knob,
                 "what": "short-convolution layers keep a state of fixed "
                         "size a decode slot, written in place every step"}
 
@@ -161,27 +152,6 @@ class Lfm2MoeConfig:
         return {"global": int(live.sum())
                 * self.kv_bytes_per_token()["global"],
                 "state": len(live) * self.state_bytes_per_slot()}
-
-    def for_paged_decode(self, num_blocks: int, block_size: int,
-                         kv_dtype: str = "", state_slots: int = 0,
-                         return_routed: bool = False):
-        """Serving variant (see the module's docstring). ``num_blocks``
-        sizes the attention layers' pool (block 0 the garbage block);
-        ``state_slots`` decode slots get a row each in the state pool;
-        with ``return_routed`` a call also returns every token's chosen
-        experts."""
-        if kv_dtype:
-            raise ValueError(
-                f"kv_cache_dtype {kv_dtype!r}: this model's convolution "
-                "state has no quantized pool")
-        if self.layers_of("conv") and state_slots < 1:
-            raise ValueError("convolution layers keep a state a decode "
-                             "slot: for_paged_decode needs state_slots")
-        return dataclasses.replace(
-            self, decode=True, paged=True, paged_num_blocks=int(num_blocks),
-            paged_block_size=int(block_size),
-            paged_state_slots=int(state_slots),
-            paged_return_routed=bool(return_routed))
 
     @staticmethod
     def tiny(**kw):
@@ -200,10 +170,6 @@ class Lfm2MoeConfig:
                     expert_bias_std=0.01)
         base.update(kw)
         return Lfm2MoeConfig(**base)
-
-
-def _init(scale=0.02):
-    return nn.initializers.normal(stddev=scale)
 
 
 def gated_inputs(u, w_in):
@@ -243,9 +209,9 @@ class ShortConv(nn.Module):
         cfg = self.config
         b, t, d = u.shape
         keep = cfg.conv_L_cache - 1
-        w_in = self.param("in_proj", _init(cfg.conv_in_std), (d, 3 * d),
-                          cfg.param_dtype)
-        taps = self.param("conv", _init(cfg.conv_tap_std),
+        w_in = self.param("in_proj", blocks.init(cfg.conv_in_std),
+                          (d, 3 * d), cfg.param_dtype)
+        taps = self.param("conv", blocks.init(cfg.conv_tap_std),
                           (d, cfg.conv_L_cache), cfg.param_dtype)
         gate_b, gate_c, x = gated_inputs(u, w_in.astype(cfg.dtype))
         z = gate_b * x
@@ -255,11 +221,7 @@ class ShortConv(nn.Module):
             num_valid = jnp.full((b,), t, jnp.int32)
         c, state = short_conv(z, taps, state, num_valid)
         y = (gate_c.astype(jnp.float32) * c).astype(cfg.dtype)
-        out = nn.Dense(d, use_bias=False, dtype=cfg.dtype,
-                       param_dtype=cfg.param_dtype,
-                       kernel_init=_init(cfg.conv_out_std),
-                       name="out_proj")(y)
-        return out, state
+        return blocks.dense(cfg, "out_proj", d, cfg.conv_out_std)(y), state
 
 
 def conv_state_in(pool, index, rows, lengths):
@@ -300,178 +262,86 @@ class Lfm2Attention(nn.Module):
         b, t, _ = x.shape
         heads, kv, dh = (cfg.num_attention_heads, cfg.num_key_value_heads,
                          cfg.head_dim)
-
-        def proj(name, width):
-            return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
-                            param_dtype=cfg.param_dtype, kernel_init=_init(),
-                            name=name)
-
+        proj = functools.partial(blocks.dense, cfg)
         q = proj("q_proj", heads * dh)(x).reshape(b, t, heads, dh)
         k = proj("k_proj", kv * dh)(x).reshape(b, t, kv, dh)
         v = proj("v_proj", kv * dh)(x).reshape(b, t, kv, dh)
         # the norm of every query and key head, a weight a projection,
         # BEFORE the rotation
-        q = RMSNorm(cfg.norm_eps, cfg.dtype, name="q_layernorm")(q)
-        k = RMSNorm(cfg.norm_eps, cfg.dtype, name="k_layernorm")(k)
-        paged = cfg.decode and cfg.paged
-        if paged and paging is None:
-            raise ValueError(
-                "paged decode needs the `paging` call argument: "
-                '{"block_tables", "lengths", "num_valid", "prefill"}')
-        pos = (paged_positions(paging["lengths"], t) if paged
-               else jnp.arange(t, dtype=jnp.int32)[None])
-        cos, sin = rope_frequencies(dh, pos, cfg.rope_theta)
+        q = blocks.RMSNorm(cfg.norm_eps, cfg.dtype, name="q_layernorm")(q)
+        k = blocks.RMSNorm(cfg.norm_eps, cfg.dtype, name="k_layernorm")(k)
+        pos = blocks.call_positions(cfg, paging, t)
+        cos, sin = blocks.rope_frequencies(dh, pos, cfg.rope_theta)
         if cos.shape[0] == 1:
             cos, sin = cos[0], sin[0]
-        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-        if not paged:
-            y = causal_gqa(q, k, v)
+        q, k = blocks.apply_rope(q, cos, sin), blocks.apply_rope(k, cos, sin)
+        if not cfg.serving:
+            y = blocks.causal_gqa(q, k, v)
         else:
-            y, pools = self._paged(q, k, v, pos, paging, pools, index, work)
+            # through the block table (its last entry is the state row)
+            y, k_pool, v_pool = blocks.paged_gqa(
+                q, k, v, pos, paging, paging["block_tables"][:, :-1],
+                pools["global_key_pool"], pools["global_value_pool"], index,
+                "lfm2_attn", work=work)
+            pools = {**pools, "global_key_pool": k_pool,
+                     "global_value_pool": v_pool}
         out = proj("o_proj", cfg.hidden_size)(y.reshape(b, t, heads * dh))
         return out, pools
 
-    def _paged(self, q, k, v, pos, paging, pools, index, work):
-        """Write this step's keys and values through the block table and
-        attend: a whole prompt over its own keys, a decode step on a TPU
-        through the paged kernel (its global kind), a prompt's later chunk
-        and every step where no TPU is over the gathered blocks."""
-        from deepspeed_tpu.ops.attention import (record_dispatch,
-                                                 use_decode_kernel)
-        from deepspeed_tpu.ops.hybrid_decode_attention import (
-            decode_attention_hybrid)
 
-        cfg = self.config
-        b, t = q.shape[:2]
-        kv, bs = cfg.num_key_value_heads, cfg.paged_block_size
-        table = paging["block_tables"][:, :-1]      # the last: the state row
-        blk, off = paged_write_slots(table, pos, paging["num_valid"], bs)
-        k_pool = pools["global_key_pool"].at[index, blk, off].set(
-            k.reshape(b, t, -1))
-        v_pool = pools["global_value_pool"].at[index, blk, off].set(
-            v.reshape(b, t, -1))
-        if paging.get("prefill"):
-            record_dispatch("lfm2_attn_prefill_xla")
-            y = causal_gqa(q, k, v)
-        elif t == 1 and use_decode_kernel():
-            record_dispatch("lfm2_attn_decode_kernel")
-            with jax.named_scope("attn._hybrid_kv_attend"):
-                y = decode_attention_hybrid(
-                    q, k_pool, v_pool, table, paging["lengths"], index,
-                    kv_heads=kv, work=work)
-        else:
-            record_dispatch("lfm2_attn_cached_xla")
-            rows = table.shape[-1] * bs
-            key_pos = jnp.broadcast_to(
-                jnp.arange(rows, dtype=jnp.int32)[None], (b, rows))
-            y = masked_gqa(
-                q, k_pool[index, table].reshape(b, rows, kv, cfg.head_dim),
-                v_pool[index, table].reshape(b, rows, kv, cfg.head_dim),
-                pos, key_pos)
-        return y, {**pools, "global_key_pool": k_pool,
-                   "global_value_pool": v_pool}
-
-
-def _paged_pools(module, cfg: Lfm2MoeConfig):
-    """The serving pools, declared once by the model: a key and a value
-    pool of the attention layers (``[layers, blocks, block_size, kv_heads
-    * head_dim]``, the engine's ``num_blocks``), and the convolution
-    layers' state pool (row 0 for idle rows, then a row a slot)."""
-    nb, bs = cfg.paged_num_blocks, cfg.paged_block_size
-    if nb <= 1 or bs <= 0:
-        raise ValueError(f"paged decode needs paged_num_blocks > 1 (got "
-                         f"{nb}) and paged_block_size > 0 (got {bs})")
-    shapes = {}
-    attn, conv = (len(cfg.layers_of(k)) for k in ("full_attention", "conv"))
-    if attn:
-        row = (attn, nb, bs, cfg.num_key_value_heads * cfg.head_dim)
-        shapes["global_key_pool"] = shapes["global_value_pool"] = row
-    if conv:
-        shapes["conv_state_pool"] = (conv, 1 + cfg.paged_state_slots,
-                                     cfg.conv_L_cache - 1, cfg.hidden_size)
-    return {name: module.variable("cache", name, jnp.zeros, shape, cfg.dtype)
-            for name, shape in shapes.items()}
-
-
-class Lfm2MoeForCausalLM(nn.Module):
-    """Embedding -> the layers -> final RMSNorm -> the embedding as head.
-    Plain call: ``[B, T, vocab]`` float32 logits. Paged (serving) call:
-    ``(logits, {"counters": int32[4]})`` as ``MiMoV2ForCausalLM``'s, with
-    ``"routed"`` under ``paged_return_routed``."""
+class Lfm2MoeForCausalLM(blocks.PagedDecoder):
+    """``blocks.PagedDecoder`` over the layer types; the head is the
+    embedding."""
 
     config: Lfm2MoeConfig
-    serve_counters = dropless.COUNTERS
-    serve_routed = True
+    norms = ("operator_norm", "ffn_norm")
+    eps_field = "norm_eps"
+    tied = True
+    # the dense FFN's weights are float32 whatever ``param_dtype`` says, as
+    # they were when the block was the Llama family's
+    dense_param_dtype = jnp.float32
     # the engine reads this leaf's layout to choose ``paging["lookup"]``
     lookup_table = "embed_tokens"
 
-    @nn.compact
-    def __call__(self, input_ids, deterministic=True, paging=None):
-        cfg = self.config
-        paged = cfg.decode and cfg.paged
-        embed = self.param("embed_tokens", _init(),
-                           (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
-        x = embed_lookup(embed, input_ids, (paging or {}).get(
-            "lookup", "rows")).astype(cfg.dtype)
-        t = input_ids.shape[1]
-        pools = valid = work = None
-        if paged:
-            variables = _paged_pools(self, cfg)
-            pools = {name: var.value for name, var in variables.items()}
-            tables, lengths = paging["block_tables"], paging["lengths"]
-            # a bucket's padding and an idle slot's row are no tokens: they
-            # route nowhere
-            valid = ((jnp.arange(t)[None] < paging["num_valid"][:, None])
-                     & (tables[:, :1] != 0))
-            if t == 1 and not paging.get("prefill"):
-                from deepspeed_tpu.ops.attention import use_decode_kernel
-                from deepspeed_tpu.ops.hybrid_decode_attention import (
-                    hybrid_work_list)
+    def lookup(self, table, ids, paging):
+        return embed_lookup(table, ids, (paging or {}).get("lookup", "rows"))
 
-                if use_decode_kernel():
-                    # the kernel's grid follows this step's lengths, the
-                    # same for every attention layer: made once
-                    work = hybrid_work_list(lengths, cfg.paged_block_size,
-                                            tables.shape[-1] - 1)
-        place = {i: n for kind in ("conv", "full_attention")
-                 for n, i in enumerate(cfg.layers_of(kind))}
-        counters = jnp.zeros((len(dropless.COUNTERS),), jnp.int32)
-        routed = []
-        # the residual stream and every norm are float32, as MiMo-V2's are
-        # (models/mimo_v2.py says why); what a matmul reads is cfg.dtype
-        x = x.astype(jnp.float32)
-        norm = lambda name: RMSNorm(cfg.norm_eps, jnp.float32, name=name)
-        for i, kind in enumerate(cfg.layer_types):
-            scope = f"layers_{i}"
-            u = norm(f"{scope}_operator_norm")(x).astype(cfg.dtype)
-            if kind == "conv":
-                layer = ShortConv(cfg, name=f"{scope}_conv")
-                if paged:
-                    a, pools = _paged_conv(layer, u, paging, pools, place[i])
-                else:
-                    a, _ = layer(u)
-            else:
-                a, pools = Lfm2Attention(cfg, name=f"{scope}_attn")(
-                    u, paging, pools, place[i], work)
-            x = x + a.astype(jnp.float32)
-            h = norm(f"{scope}_ffn_norm")(x)
-            if i >= cfg.num_dense_layers:
-                y, c, chosen = SparseExperts(cfg, name=f"{scope}_mlp")(
-                    h, valid)
-                counters = counters + c
-                routed.append(chosen)
-            else:
-                y = LlamaMLP(cfg, name=f"{scope}_mlp")(h.astype(cfg.dtype))
-            x = x + y.astype(jnp.float32)
-        if paged:
-            for name, var in variables.items():
-                var.value = pools[name]
-        x = norm("norm")(x).astype(cfg.dtype)
-        logits = jnp.einsum("btc,vc->btv", x, embed.astype(cfg.dtype),
-                            preferred_element_type=jnp.float32)
-        if not paged:
-            return logits
-        aux = {"counters": counters}
-        if cfg.paged_return_routed and routed:
-            aux["routed"] = jnp.concatenate(routed, axis=-1)
-        return logits, aux
+    def pool_shapes(self, num_blocks, block_size):
+        """A key and a value pool of the attention layers (``[layers,
+        blocks, block_size, kv_heads * head_dim]``, the engine's
+        ``num_blocks``), and the convolution layers' state pool (row 0 for
+        idle rows, then a row a slot)."""
+        cfg = self.config
+        shapes = {}
+        attn, conv = (len(cfg.layers_of(k))
+                      for k in ("full_attention", "conv"))
+        if attn:
+            row = (attn, num_blocks, block_size,
+                   cfg.num_key_value_heads * cfg.head_dim)
+            shapes["global_key_pool"] = shapes["global_value_pool"] = row
+        if conv:
+            shapes["conv_state_pool"] = (conv, 1 + cfg.paged_state_slots,
+                                         cfg.conv_L_cache - 1,
+                                         cfg.hidden_size)
+        return shapes
+
+    def step_work(self, paging):
+        """The kernel's grid follows this step's lengths, the same for
+        every attention layer."""
+        from deepspeed_tpu.ops.hybrid_decode_attention import hybrid_work_list
+
+        return hybrid_work_list(paging["lengths"],
+                                self.config.paged_block_size,
+                                paging["block_tables"].shape[-1] - 1)
+
+    def mixer(self, i, u, paging, pools, work):
+        cfg = self.config
+        kind = cfg.layer_types[i]
+        place = cfg.layers_of(kind).index(i)
+        if kind != "conv":
+            return Lfm2Attention(cfg, name=f"layers_{i}_attn")(
+                u, paging, pools, place, work)
+        layer = ShortConv(cfg, name=f"layers_{i}_conv")
+        if cfg.serving:
+            return _paged_conv(layer, u, paging, pools, place)
+        return layer(u)[0], pools

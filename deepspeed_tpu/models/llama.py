@@ -15,8 +15,9 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
 
+from deepspeed_tpu.models.blocks import (RMSNorm, SwiGLU, apply_rope,
+                                         init as _init, rope_frequencies)
 from deepspeed_tpu.models.gpt2 import lm_head_loss, shift_labels
 from deepspeed_tpu.models.remat_utils import offload_policy, saved_block_input
 from deepspeed_tpu.ops.attention import attention
@@ -74,47 +75,6 @@ class LlamaConfig:
         kw.setdefault("num_hidden_layers", 2)
         kw.setdefault("num_attention_heads", 4)
         return LlamaConfig(**kw)
-
-
-def _init(scale=0.02):
-    return nn.initializers.normal(stddev=scale)
-
-
-class RMSNorm(nn.Module):
-    """Root-mean-square layernorm (no mean subtraction, no bias)."""
-
-    eps: float = 1e-5
-    dtype: Any = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
-        x32 = x.astype(jnp.float32)
-        x32 = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1,
-                                           keepdims=True) + self.eps)
-        return (x32 * scale).astype(self.dtype)
-
-
-def rope_frequencies(head_dim: int, positions, theta: float):
-    """cos/sin tables for the given absolute positions: [..., head_dim//2]."""
-    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, jnp.float32)
-                           / head_dim))
-    ang = positions.astype(jnp.float32)[..., None] * inv  # [..., hd/2]
-    return jnp.cos(ang), jnp.sin(ang)
-
-
-def apply_rope(x, cos, sin):
-    """x: [B, T, H, D]; cos/sin: [T, D/2] shared or [B, T, D/2] per-row
-    (left-padded batches). Rotates pairs (x_even, x_odd) — the interleaved
-    convention HF Llama uses after its half-split equivalence."""
-    x1, x2 = jnp.split(x, 2, axis=-1)  # HF half-split convention
-    if cos.ndim == 2:
-        cos, sin = cos[None], sin[None]
-    c = cos[:, :, None, :]
-    s = sin[:, :, None, :]
-    return jnp.concatenate(
-        [x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(x.dtype)
 
 
 class LlamaAttention(nn.Module):
@@ -221,23 +181,6 @@ class LlamaAttention(nn.Module):
                         kernel_init=_init(), name="o_proj")(y)
 
 
-class LlamaMLP(nn.Module):
-    """SwiGLU: down(silu(gate(x)) * up(x))."""
-
-    config: LlamaConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.config
-        g = nn.Dense(cfg.intermediate_size, use_bias=False, dtype=cfg.dtype,
-                     kernel_init=_init(), name="gate_proj")(x)
-        u = nn.Dense(cfg.intermediate_size, use_bias=False, dtype=cfg.dtype,
-                     kernel_init=_init(), name="up_proj")(x)
-        return nn.Dense(cfg.hidden_size, use_bias=False, dtype=cfg.dtype,
-                        kernel_init=_init(), name="down_proj")(
-            nn.silu(g) * u)
-
-
 class LlamaBlock(nn.Module):
     config: LlamaConfig
 
@@ -247,7 +190,8 @@ class LlamaBlock(nn.Module):
         x = x + LlamaAttention(cfg, name="self_attn")(
             RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="input_layernorm")(x),
             deterministic=deterministic, attention_mask=attention_mask)
-        x = x + LlamaMLP(cfg, name="mlp")(
+        x = x + SwiGLU(cfg.intermediate_size, cfg.hidden_size, cfg.dtype,
+                       name="mlp")(
             RMSNorm(cfg.rms_norm_eps, cfg.dtype,
                     name="post_attention_layernorm")(x))
         return x

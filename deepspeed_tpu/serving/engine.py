@@ -165,27 +165,17 @@ class ServingEngine:
             1 + self.config.decode_slots * self.blocks_per_seq)
         self.buckets = resolve_buckets(self.config.prompt_buckets,
                                        self.max_len, floor=bs)
-        # state of FIXED SIZE that a decode slot keeps beside its block
-        # table, whatever the context: a ring of blocks for sliding-window
-        # layers' rows (models/mimo_v2.py), a row of the state pool for
-        # short-convolution layers (models/lfm2_moe.py). The model says
-        # what it is (``paged_slot_state_for``): how many ``entries`` of a
-        # slot's table address it (the table a slot hands its programs is
-        # the sequence's blocks, then these), the keyword of
-        # ``for_paged_decode`` that takes the slots, and ``what`` it is,
-        # for the mechanisms that refuse it
+        # what a served model's config and module say of themselves, and
+        # what each probe below means, is written once: the docstrings of
+        # ``ServedConfig`` and ``PagedDecoder`` in models/blocks.py. Here:
+        # state of FIXED SIZE a decode slot keeps beside its block table
+        # (a ring of blocks, a row of a state pool), and a block pool whose
+        # rows are NOT keys and values by heads (a latent row a token); the
+        # mechanisms that cannot carry either refuse the model by ``what``
         state_for = getattr(mcfg, "paged_slot_state_for", None)
         self.slot_state = (state_for(bs) if state_for else None) or None
         self.slot_entries = (int(self.slot_state["entries"])
                              if self.slot_state else 0)
-        ring_for = getattr(mcfg, "paged_ring_blocks_for", None)
-        self.ring_blocks = int(ring_for(bs)) if ring_for else 0
-        # a block pool whose rows are NOT keys and values by heads (a
-        # latent row a token shared by all heads: models/deepseek_v2.py).
-        # The model says ``what`` its rows are (``paged_row_kind``); the
-        # scheduler, the block manager and the prefill / chunk / decode
-        # programs take them as they take any row a block table addresses,
-        # and the mechanisms that read a row BY HEADS refuse the model
         row_kind = getattr(mcfg, "paged_row_kind", None)
         self.row_kind = (row_kind() if row_kind else None) or None
         # keywords omitted on purpose: a model family predating a knob

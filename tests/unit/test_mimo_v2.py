@@ -206,7 +206,7 @@ def test_the_window_pool_does_not_grow_with_the_context(highest):
     for longest in (32, 64):
         srv = serving_engine(params, cfg, max_model_len=longest)
         sizes[longest] = {k: v.shape for k, v in srv.cache.items()}
-        ring = srv.ring_blocks
+        ring = srv.slot_entries
         srv.destroy()
     assert ring == WINDOW // BLOCK + 1
     for name in ("window_key_pool", "window_value_pool"):
@@ -474,7 +474,7 @@ def _interpreted(fn, *args):
 def test_hybrid_decode_kernel_matches_the_masked_path(kind):
     """GQA (4 queries a KV head), keys 24 wide and values 16, a window's
     ring that has wrapped, unequal lengths, an idle row."""
-    from deepspeed_tpu.models.mimo_v2 import masked_gqa
+    from deepspeed_tpu.models.blocks import masked_gqa
     from deepspeed_tpu.ops.hybrid_decode_attention import (
         decode_attention_hybrid, ring_positions)
 

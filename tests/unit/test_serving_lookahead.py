@@ -447,7 +447,7 @@ def test_mimo_counters_are_the_serial_sums_on_the_same_schedule():
         n, width = srv.config.decode_slots, srv._routed_width
         sums = {ph: dict.fromkeys(names, 0) for ph in ("prefill", "decode")}
         kv = {"global": 0, "window": 0}
-        held = srv.ring_blocks * srv.config.block_size
+        held = srv.slot_entries * srv.config.block_size
         last = np.zeros((n,), np.int32)      # the host's tokens, by slot
         routed = {}                          # slot -> rows of its tenant
         finished = []
@@ -482,7 +482,7 @@ def test_mimo_counters_are_the_serial_sums_on_the_same_schedule():
                     jax.random.PRNGKey(0))
                 out = np.asarray(out)
                 # the slot is the one whose ring the table ends in
-                slot = (int(table[0, -1]) - 1) // srv.ring_blocks
+                slot = (int(table[0, -1]) - 1) // srv.slot_entries
                 if slot in routed:
                     finished.append(np.concatenate(routed[slot]))
                 last[slot] = out[0]

@@ -184,16 +184,16 @@ def causal_gqa(q, k, v, window: int = 0, sink=None):
 
 
 def paged_gqa(q, k, v, pos, paging, table, k_pool, v_pool, index, label,
-              sink=None, work=None):
+              sink=None, work=None, key_tile: int = 0):
     """One layer's grouped-query step of a serving program over rows a
     block table addresses: write this call's keys and values into layer
     ``index`` of the pools (``[layers, blocks, block_size, kv_heads *
     width]``) through ``table [B, blocks a sequence]``, and attend. A whole
     prompt (``paging["prefill"]``) attends over its own keys; a decode step
     on a TPU runs the paged kernel over the work list ``work``; a prompt's
-    later chunk, and every step where no TPU is, gathers the sequence's
-    blocks and takes the masked XLA path. ``label`` prefixes what
-    ``record_dispatch`` counts (the caller's: ``stats()`` reads it).
+    later chunk, and every step where no TPU is, takes :func:`cached_gqa`
+    (``key_tile`` is its). ``label`` prefixes what ``record_dispatch``
+    counts (the caller's: ``stats()`` reads it).
     -> ``(y [B, T, H, dv], k_pool, v_pool)``."""
     from deepspeed_tpu.ops.attention import record_dispatch, use_decode_kernel
     from deepspeed_tpu.ops.hybrid_decode_attention import (
@@ -214,14 +214,14 @@ def paged_gqa(q, k, v, pos, paging, table, k_pool, v_pool, index, label,
                 q, k_pool, v_pool, table, paging["lengths"], index,
                 kv_heads=kv, sink=sink, work=work)
     else:
-        record_dispatch(f"{label}_cached_xla")
-        rows = table.shape[-1] * bs
-        key_pos = jnp.broadcast_to(
-            jnp.arange(rows, dtype=jnp.int32)[None], (b, rows))
-        y = masked_gqa(
-            q, k_pool[index, table].reshape(b, rows, kv, k.shape[-1]),
-            v_pool[index, table].reshape(b, rows, kv, v.shape[-1]),
-            pos, key_pos, None, 0, sink)
+        # (the masked XLA path over the sequence's blocks, and its form
+        # in tiles of keys, are at the file's end, below the call sites
+        # whose line numbers a kernel's lowered text carries)
+        y = cached_gqa(
+            q, pos, paging, table,
+            (k_pool, v_pool, index),
+            (kv, k.shape[-1], v.shape[-1]),
+            label, sink, key_tile)
     return y, k_pool, v_pool
 
 
@@ -318,16 +318,16 @@ class ServedConfig:
     ``num_hidden_layers``, ``num_attention_heads``, ``intermediate_size``,
     ``num_experts_per_tok``, ``dtype``, ``param_dtype`` and the serving
     fields ``decode``, ``paged``, ``paged_num_blocks``, ``paged_block_size``,
-    ``paged_return_routed``, ``paged_<slot_knob>``; it says ``sparse(i)``,
-    whether layer ``i``'s FFN is sparse, and ``sparse_ffn()``, its own
-    routing as :class:`SparseFFN`'s arguments."""
+    ``paged_return_routed``, ``paged_<slot_knob>``; it says ``sparse(i)``
+    (is layer ``i``'s FFN sparse) and ``sparse_ffn()`` (:class:`SparseFFN`'s
+    arguments), and may set the shell's scalars and ``embedding_std``."""
 
-    # the keyword of for_paged_decode that takes the decode slots (the
-    # ``knob`` of ``paged_slot_state_for``), in a family whose slots keep
-    # state
+    # for_paged_decode's keyword for the decode slots (the seam's ``knob``)
     slot_knob = None
-    # why ``kv_dtype`` is refused: "this model's <unquantized>"
-    unquantized = "rows have no quantized pool"
+    unquantized = "rows have no quantized pool"  # kv_dtype: "this model's"
+    # the shell's (:class:`PagedDecoder`): scalars (at 1 nothing is traced)
+    embedding_multiplier = residual_multiplier = logits_scaling = 1.0
+    embedding_std = 0.02  # and what its embedding is drawn with, N(0, std)
 
     @property
     def n_head(self) -> int:
@@ -389,21 +389,21 @@ def paged_valid(paging, t: int):
 
 
 class PagedDecoder(nn.Module):
-    """The module's half of the contract, the decoder shell: embedding ->
-    per layer the family's mixer and a dense or a sparse FFN, pre-norm, on a
-    float32 residual stream -> final RMSNorm -> tied or untied head.
+    """The module's half of the contract, the decoder shell: embedding (x
+    ``embedding_multiplier``) -> per layer the family's mixer and a dense or
+    sparse FFN, pre-norm, each term x ``residual_multiplier`` onto a float32
+    stream -> final RMSNorm -> tied or untied head (/ ``logits_scaling``).
 
     Plain call: ``[B, T, vocab]`` float32 logits. Paged (serving) call,
     ``paging = {"block_tables", "lengths", "num_valid", "prefill"}`` with
     the ``cache`` collection mutable: ``(logits, {"counters": int32[4]})``,
-    the sparse layers' counters of this call summed
-    (``moe/dropless.COUNTERS``), which the serving programs hand back with
-    the tokens; under ``paged_return_routed`` also ``"routed": int32[B, T,
-    sparse layers x k]``, the experts every token chose, layer by layer (a
-    padded row's are meaningless). ``ServingEngine`` reads of the class
-    ``serve_counters``, ``serve_routed``, and ``lookup_table`` where the
-    family's :meth:`lookup` takes ``paging["lookup"]``. A family says the
-    three methods and the attributes below."""
+    the sparse layers' counters of this call summed (``dropless.COUNTERS``;
+    the serving programs hand them back with the tokens); under
+    ``paged_return_routed`` also ``"routed": int32[B, T, sparse layers x
+    k]``, each token's chosen experts layer by layer (a padded row's are
+    meaningless). ``ServingEngine`` reads of the class ``serve_counters``,
+    ``serve_routed``, and ``lookup_table`` where :meth:`lookup` takes
+    ``paging["lookup"]``. A family says the three methods and the rest."""
 
     config: Any
     # what the serving engine's ledger names the counters by
@@ -444,7 +444,7 @@ class PagedDecoder(nn.Module):
         paged = cfg.serving
         # (``embed_tokens`` first and ``lm_head`` last: the order decides
         # the values a seed gives)
-        embed = self.param("embed_tokens", init(),
+        embed = self.param("embed_tokens", init(cfg.embedding_std),
                            (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
         x = self.lookup(embed, input_ids, paging).astype(cfg.dtype)
         t = input_ids.shape[1]
@@ -466,7 +466,7 @@ class PagedDecoder(nn.Module):
         # the residual stream and every norm are float32 (bfloat16 would
         # round the stream once a layer, and a norm's rounding moves the
         # router's near ties); what a matmul reads is cfg.dtype
-        x = x.astype(jnp.float32)
+        x = _scaled(x.astype(jnp.float32), cfg.embedding_multiplier)
         norm = lambda name: RMSNorm(getattr(cfg, self.eps_field),
                                     jnp.float32, name=name)
         for i in range(cfg.num_hidden_layers):
@@ -474,7 +474,7 @@ class PagedDecoder(nn.Module):
             a, pools = self.mixer(
                 i, norm(f"{scope}_{self.norms[0]}")(x).astype(cfg.dtype),
                 paging, pools, work)
-            x = x + a.astype(jnp.float32)
+            x = x + _scaled(a.astype(jnp.float32), cfg.residual_multiplier)
             h = norm(f"{scope}_{self.norms[1]}")(x)
             if cfg.sparse(i):
                 y, *shared, c, chosen = SparseFFN(
@@ -487,7 +487,7 @@ class PagedDecoder(nn.Module):
                 y = SwiGLU(cfg.intermediate_size, cfg.hidden_size, cfg.dtype,
                            self.dense_param_dtype or cfg.param_dtype,
                            name=f"{scope}_mlp")(h.astype(cfg.dtype))
-            x = x + y.astype(jnp.float32)
+            x = x + _scaled(y.astype(jnp.float32), cfg.residual_multiplier)
         if paged:
             for name, var in variables.items():
                 var.value = pools[name]
@@ -497,9 +497,106 @@ class PagedDecoder(nn.Module):
             cfg.param_dtype)
         logits = jnp.einsum("btc,vc->btv", x, head.astype(cfg.dtype),
                             preferred_element_type=jnp.float32)
+        logits = _scaled(logits, 1.0 / cfg.logits_scaling)
         if not paged:
             return logits
         aux = {"counters": counters}
         if cfg.paged_return_routed and routed:
             aux["routed"] = jnp.concatenate(routed, axis=-1)
         return logits, aux
+
+
+# ---------------------------------------------------------------------------
+# (below the call sites above, whose line numbers a kernel's lowered text
+# carries: new code goes here)
+
+def _scaled(x, scale):
+    """``x * scale``; at 1 ``x`` itself, so a config without the multiplier
+    traces the operations it always did."""
+    return x if scale == 1 else x * scale
+
+
+def causal_conv(z, taps, state, num_valid):
+    """The depthwise causal convolution and the state it leaves.
+
+    ``z [B, T, C]``: this call's positions; ``taps [C, L]``, the last of
+    which meets the current position; ``state [B, L - 1, C]``: ``z`` at the
+    ``L - 1`` positions before this call's first; ``num_valid [B]``: the
+    real positions of each row (a bucket's padding lies behind them).
+    -> ``(c [B, T, C] float32, new state [B, L - 1, C])``: the state after
+    the row's LAST REAL position (the old one where it has none)."""
+    t, keep = z.shape[1], taps.shape[1] - 1
+    line = jnp.concatenate([state.astype(z.dtype), z], axis=1)
+    w = taps.astype(jnp.float32)
+    c = sum(w[None, None, :, j] * line[:, j:j + t].astype(jnp.float32)
+            for j in range(keep + 1))
+    at = num_valid[:, None] + jnp.arange(keep, dtype=jnp.int32)[None]
+    return c, jnp.take_along_axis(line, at[..., None], axis=1)
+
+
+def cached_gqa(q, pos, paging, table, pools, widths, label, sink=None,
+               key_tile: int = 0):
+    """Queries ``q [B, T, H, dk]`` at positions ``pos [B, T]`` over the
+    keys and values their sequences keep in layer ``index`` of ``pools =
+    (k_pool, v_pool, index)`` through ``table``; ``widths = (kv_heads, dk,
+    dv)``. -> ``[B, T, H, dv]``.
+
+    The plain form gathers EVERY block the table has room for and masks
+    (``<label>_cached_xla``): a 512-token chunk of an 8,192-token table
+    scores 8,192 keys whatever the sequence's length. With ``key_tile``
+    (keys, whole blocks, dividing the table; no sink) the keys are taken a
+    tile at a time under an online softmax, as many tiles as the call's
+    longest row has keys (``lengths + num_valid``: a traced count), so a
+    chunk at position 1,024 does not pay for the table
+    (``<label>_cached_tiled_xla``)."""
+    from deepspeed_tpu.ops.attention import record_dispatch
+
+    k_pool, v_pool, index = pools
+    kv, dk, dv = widths
+    b, t, heads, _ = q.shape
+    bs = k_pool.shape[2]
+    per = key_tile // bs
+    if not (key_tile and sink is None and t > 1 and per
+            and key_tile % bs == 0 and table.shape[-1] % per == 0):
+        record_dispatch(f"{label}_cached_xla")
+        rows = table.shape[-1] * bs
+        key_pos = jnp.broadcast_to(
+            jnp.arange(rows, dtype=jnp.int32)[None], (b, rows))
+        return masked_gqa(
+            q, k_pool[index, table].reshape(b, rows, kv, dk),
+            v_pool[index, table].reshape(b, rows, kv, dv),
+            pos, key_pos, None, 0, sink)
+    record_dispatch(f"{label}_cached_tiled_xla")
+    group = heads // kv
+    qg = q.reshape(b, t, kv, group, dk)
+    ends = paging["lengths"] + paging["num_valid"]
+    tiles = (jnp.max(ends) + key_tile - 1) // key_tile
+    offsets = jnp.arange(key_tile, dtype=jnp.int32)
+
+    def one_tile(j, carry):
+        m, l, acc = carry
+        blocks = jax.lax.dynamic_slice_in_dim(table, j * per, per, axis=1)
+        keys = k_pool[index, blocks].reshape(b, key_tile, kv, dk)
+        values = v_pool[index, blocks].reshape(b, key_tile, kv, dv)
+        s = jnp.einsum("btkgd,bskd->bkgts", qg, keys,
+                       preferred_element_type=jnp.float32) * dk ** -0.5
+        seen = ((j * key_tile + offsets)[None, None, :]
+                <= pos[:, :, None])[:, None, None]           # [B,1,1,T,S]
+        s = jnp.where(seen, s, _NEG)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        acc = acc * alpha + jnp.einsum(
+            "bkgts,bskd->bkgtd", p.astype(values.dtype), values,
+            preferred_element_type=jnp.float32)
+        return m_new, alpha * l + jnp.sum(p, axis=-1, keepdims=True), acc
+
+    lead = (b, kv, group, t)
+    m, l, acc = jax.lax.fori_loop(
+        0, tiles, one_tile,
+        (jnp.full((*lead, 1), _NEG, jnp.float32),
+         jnp.zeros((*lead, 1), jnp.float32),
+         jnp.zeros((*lead, dv), jnp.float32)))
+    out = acc / jnp.where(l == 0.0, 1.0, l)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, t, heads, dv).astype(
+        q.dtype)

@@ -178,22 +178,8 @@ def gated_inputs(u, w_in):
     return jnp.split(jnp.dot(u, w_in), 3, axis=-1)
 
 
-def short_conv(z, taps, state, num_valid):
-    """The depthwise causal convolution and the state it leaves.
-
-    ``z [B, T, C]``: this call's positions; ``taps [C, L]``, the last of
-    which meets the current position; ``state [B, L - 1, C]``: ``z`` at the
-    ``L - 1`` positions before this call's first; ``num_valid [B]``: the
-    real positions of each row (a bucket's padding lies behind them).
-    -> ``(c [B, T, C] float32, new state [B, L - 1, C])``: the state after
-    the row's LAST REAL position (the old one where it has none)."""
-    t, keep = z.shape[1], taps.shape[1] - 1
-    line = jnp.concatenate([state.astype(z.dtype), z], axis=1)
-    w = taps.astype(jnp.float32)
-    c = sum(w[None, None, :, j] * line[:, j:j + t].astype(jnp.float32)
-            for j in range(keep + 1))
-    at = num_valid[:, None] + jnp.arange(keep, dtype=jnp.int32)[None]
-    return c, jnp.take_along_axis(line, at[..., None], axis=1)
+# (a module attribute of this file: controls replace it here)
+short_conv = blocks.causal_conv
 
 
 class ShortConv(nn.Module):

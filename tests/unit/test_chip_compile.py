@@ -944,3 +944,55 @@ def test_grouped_expert_kernel_at_deepseek_v2_lites_widths(one_chip, tokens):
         _s(one_chip, (64, 1408, 2048)))
     calls = _custom_calls(text)
     assert len(calls) == 1 and pattern.search(calls[0]), calls
+
+
+# granite-4.0-h-micro at its published widths: 36 Mamba-2 layers of 64 heads
+# x 64 with a state of 128 in a pool of 1 + 64 slots; the benchmark cell's 64
+# decode slots and its 512-token chunks in scan chunks of 256
+def test_ssm_state_update_kernel_is_in_place_and_named(one_chip):
+    """The decode step's state update: the pool aliased to the kernel's
+    output (no second 2.45 GB), under the name the parked
+    ``ssm_decode_roofline_share`` reads."""
+    from deepspeed_tpu.ops.ssm_state_update import state_update_kernel
+
+    pattern = _reader_pattern("ssm_decode_roofline_share")
+    pool = (36, 65, 64, 64, 128)
+
+    def step(pool, slots, a, dx, b, c):
+        with jax.named_scope("ssm._state_update"):
+            return state_update_kernel(pool, 7, slots, a, dx, b, c)
+
+    compiled = jax.jit(step, donate_argnums=0).lower(
+        _s(one_chip, pool), _s(one_chip, (64,), jnp.int32),
+        _s(one_chip, (64, 64), jnp.float32),
+        _s(one_chip, (64, 64, 64), jnp.float32),
+        _s(one_chip, (64, 128), jnp.float32),
+        _s(one_chip, (64, 128), jnp.float32)).compile()
+    calls = _custom_calls(compiled.as_text())
+    assert len(calls) == 1 and pattern.search(calls[0]), calls
+    memory = compiled.memory_analysis()
+    held = int(np.prod(pool)) * 2
+    assert memory.alias_size_in_bytes >= held
+    assert memory.temp_size_in_bytes < held // 100
+
+
+def test_ssd_chunk_scan_kernel_at_a_prefill_chunks_shape(one_chip):
+    """The chunked scan of one layer of one 512-token call: one kernel,
+    under the name the parked ``ssd_scan_roofline_share`` reads."""
+    from deepspeed_tpu.ops.ssd_chunk_scan import kernel_serves, ssd_chunk_scan
+
+    pattern = _reader_pattern("ssd_scan_roofline_share")
+    assert kernel_serves(256, 64, 64, 128) and not kernel_serves(4, 8, 16, 32)
+
+    def scan(x, delta, rate, b, c, state):
+        return ssd_chunk_scan(x, delta, rate, b, c, state, 256, jnp.bfloat16,
+                              use_kernel=True)
+
+    f32 = jnp.float32
+    text = _compiled_text(
+        scan, _s(one_chip, (1, 512, 64, 64), f32),
+        _s(one_chip, (1, 512, 64), f32), _s(one_chip, (64,), f32),
+        _s(one_chip, (1, 512, 128), f32), _s(one_chip, (1, 512, 128), f32),
+        _s(one_chip, (1, 64, 64, 128), f32))
+    calls = _custom_calls(text)
+    assert len(calls) == 1 and pattern.search(calls[0]), calls

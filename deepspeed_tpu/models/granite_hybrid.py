@@ -416,12 +416,15 @@ class GraniteHybridForCausalLM(blocks.PagedDecoder):
         """The grids of this step's kernels, each the same for every layer
         of its kind: the attention's follows the lengths, the state
         update's the busy rows."""
-        from deepspeed_tpu.ops.hybrid_decode_attention import hybrid_work_list
+        from deepspeed_tpu.ops.hybrid_decode_attention import (
+            hybrid_plan, hybrid_work_list)
 
+        cfg = self.config
         tables = paging["block_tables"]
-        return (hybrid_work_list(paging["lengths"],
-                                 self.config.paged_block_size,
-                                 tables.shape[-1] - 1),
+        lanes = cfg.num_key_value_heads * cfg.head_dim
+        plan = hybrid_plan(cfg.paged_block_size, lanes, lanes,
+                           tables.shape[-1] - 1)
+        return (hybrid_work_list(paging["lengths"], tables, plan),
                 ssm_state_update.busy_rows(tables[:, -1]))
 
     def mixer(self, i, u, paging, pools, work):

@@ -314,11 +314,15 @@ class Lfm2MoeForCausalLM(blocks.PagedDecoder):
     def step_work(self, paging):
         """The kernel's grid follows this step's lengths, the same for
         every attention layer."""
-        from deepspeed_tpu.ops.hybrid_decode_attention import hybrid_work_list
+        from deepspeed_tpu.ops.hybrid_decode_attention import (
+            hybrid_plan, hybrid_work_list)
 
-        return hybrid_work_list(paging["lengths"],
-                                self.config.paged_block_size,
-                                paging["block_tables"].shape[-1] - 1)
+        cfg = self.config
+        tables = paging["block_tables"]
+        lanes = cfg.num_key_value_heads * cfg.head_dim
+        plan = hybrid_plan(cfg.paged_block_size, lanes, lanes,
+                           tables.shape[-1] - 1)
+        return hybrid_work_list(paging["lengths"], tables, plan)
 
     def mixer(self, i, u, paging, pools, work):
         cfg = self.config

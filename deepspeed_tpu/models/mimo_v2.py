@@ -344,14 +344,25 @@ class MiMoV2ForCausalLM(blocks.PagedDecoder):
     def step_work(self, paging):
         """The kernels' grids follow this step's lengths, the same for
         every layer of a kind: ``{window: work list}``."""
-        from deepspeed_tpu.ops.hybrid_decode_attention import hybrid_work_list
+        from deepspeed_tpu.ops.hybrid_decode_attention import (
+            hybrid_plan, hybrid_work_list)
 
         cfg = self.config
         bs = cfg.paged_block_size
         ring = cfg.paged_ring_blocks_for(bs)
         tables, lengths = paging["block_tables"], paging["lengths"]
-        return {False: hybrid_work_list(lengths, bs, tables.shape[-1] - ring),
-                True: ring and hybrid_work_list(lengths, bs, ring)}
+
+        def work(window, blocks):
+            # a slot is idle where its SEQUENCE's table says so (the
+            # leading, global part of ``tables``): its ring is its own
+            # whether it is busy or not
+            kv = cfg.kv_heads(window)
+            plan = hybrid_plan(bs, kv * cfg.head_dim, kv * cfg.v_head_dim,
+                               blocks)
+            return hybrid_work_list(lengths, tables, plan)
+
+        return {False: work(False, tables.shape[-1] - ring),
+                True: ring and work(True, ring)}
 
     def mixer(self, i, u, paging, pools, work):
         cfg = self.config

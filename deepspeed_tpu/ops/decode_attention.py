@@ -388,12 +388,12 @@ def paged_work_list(lengths, tq, block_size, max_blocks, *, tile_blocks=1):
     step ``s`` (``B * cdiv(max_blocks, tile_blocks) + 1`` entries: one more
     than the longest grid, for the pipeline's look at the step after the
     last, which stays the last row's). A row of length 0 or more has a
-    step (``tq >= 1``): an idle slot, listed at its length 0, one on the
-    garbage block, which is how the hybrid kernel takes it; one handed
-    ``-tq`` (:func:`paged_step_lengths`) has no key and no step, and
-    ``row_of`` passes over it. ``tile_blocks`` is :func:`paged_plan`'s for
-    the call the list is made for; at its default a step is a block (the
-    hybrid kernel's grid).
+    step (``tq >= 1``): an idle slot listed at its length 0 would have one,
+    on the garbage block, so every kernel's caller hands it
+    ``-tq`` (:func:`paged_step_lengths`): it has no key and no step, and
+    ``row_of`` passes over it. ``tile_blocks`` is the plan's of the call
+    the list is made for (:func:`paged_plan` here, ``hybrid_plan`` for the
+    hybrid kernel); at its default a step is a block.
 
     It depends on nothing a layer changes, so a program that calls the
     kernel once a layer makes it ONCE, before the layer loop, and hands it
@@ -406,7 +406,7 @@ def paged_work_list(lengths, tq, block_size, max_blocks, *, tile_blocks=1):
     live = jnp.minimum((lens + (tq + block_size - 1)) // block_size,
                        max_blocks)
     # (at the default the list is traced as it always was, operation for
-    # operation: the hybrid kernel's programs are their parent's text)
+    # operation)
     tiles = (live if tile_blocks == 1
              else (live + (tile_blocks - 1)) // tile_blocks)
     first = jnp.sum(jnp.tril(jnp.broadcast_to(tiles, (b + 1, b)), -1),

@@ -786,16 +786,21 @@ def _custom_calls(text):
 @pytest.mark.parametrize("kind", ["global", "window"])
 def test_hybrid_decode_kernel_at_the_published_widths(one_chip, kind):
     """The GQA paged kernel compiles for the v5e at both row shapes (768 /
-    512 lanes and 1536 / 1024: whole registers), the window's with its ring
-    and its sink, and is the instruction the benchmark's reader matches."""
+    512 lanes and 1536 / 1024: whole registers, which the kernel's own
+    copies need), the window's with its ring and its sink, at the plan's
+    tile (16 blocks of a table, the whole ring of 5: two such tiles of both
+    pools are what it asks of scoped VMEM), and is the ONE instruction the
+    benchmark's reader matches: no pool read outside it."""
     from deepspeed_tpu.ops.hybrid_decode_attention import (
-        decode_attention_hybrid)
+        decode_attention_hybrid, hybrid_plan)
 
     pattern = _reader_pattern("hybrid_decode_roofline_share")
     slots, bs = 64, 32
     window = kind == "window"
     kv, layers, blocks, per_row = ((8, 5, 1 + slots * 5, 5) if window
                                    else (4, 2, 8193, 128))
+    assert hybrid_plan(bs, kv * 192, kv * 128, per_row).tile_blocks == (
+        5 if window else 16)
 
     def step(q, k, v, tables, lengths, sink):
         with jax.named_scope("attn._hybrid_kv_attend"):
@@ -811,7 +816,7 @@ def test_hybrid_decode_kernel_at_the_published_widths(one_chip, kind):
         _s(one_chip, (slots, per_row), jnp.int32),
         _s(one_chip, (slots,), jnp.int32), _s(one_chip, (64,), jnp.float32))
     calls = _custom_calls(text)
-    assert calls and all(pattern.search(ln) for ln in calls), calls
+    assert len(calls) == 1 and pattern.search(calls[0]), calls
 
 
 @pytest.mark.parametrize("tokens", [64, 3072], ids=["decode", "prefill"])
@@ -842,29 +847,35 @@ def test_grouped_expert_kernel_at_the_published_widths(one_chip, tokens):
 # LFM2-8B-A1B at its published widths: 32 query heads over 8 KV heads of 64
 # (keys and values alike: a 512-lane pool row), no window layer; 32 held
 # experts of 3 x 2048 x 1792; the benchmark cell's 64 decode slots of 80
-# blocks of 32
-def test_hybrid_decode_kernel_at_lfm2s_widths(one_chip):
+# blocks of 32. granite-4.0-h-micro's four attention layers have the same
+# rows, over tables of 256 blocks and a pool of 8,193
+@pytest.mark.parametrize("layers,blocks,per_row", [
+    (3, 1 + 64 * 80, 80), (4, 8193, 256)], ids=["lfm2", "granite"])
+def test_hybrid_decode_kernel_at_lfm2s_widths(one_chip, layers, blocks,
+                                              per_row):
     """The GQA paged kernel's global kind compiles for the v5e with four
-    query heads a KV head and rows of 512 lanes, under the name the
-    benchmark's reader matches."""
+    query heads a KV head and rows of 512 lanes at the plan's tile of 16
+    blocks, under the name the benchmark's reader matches."""
     from deepspeed_tpu.ops.hybrid_decode_attention import (
-        decode_attention_hybrid)
+        decode_attention_hybrid, hybrid_plan)
 
     pattern = _reader_pattern("hybrid_decode_roofline_share")
-    slots, bs, blocks = 64, 32, 1 + 64 * 80
+    slots, bs = 64, 32
+    assert hybrid_plan(bs, 512, 512, per_row).tile_blocks == 16
 
     def step(q, k, v, tables, lengths):
         with jax.named_scope("attn._hybrid_kv_attend"):
-            return decode_attention_hybrid(q, k, v, tables, lengths, 2,
-                                           kv_heads=8)
+            return decode_attention_hybrid(q, k, v, tables, lengths,
+                                           layers - 1, kv_heads=8)
 
     text = _compiled_text(
         step, _s(one_chip, (slots, 1, 32, 64)),
-        _s(one_chip, (3, blocks, bs, 512)), _s(one_chip, (3, blocks, bs, 512)),
-        _s(one_chip, (slots, 80), jnp.int32),
+        _s(one_chip, (layers, blocks, bs, 512)),
+        _s(one_chip, (layers, blocks, bs, 512)),
+        _s(one_chip, (slots, per_row), jnp.int32),
         _s(one_chip, (slots,), jnp.int32))
     calls = _custom_calls(text)
-    assert calls and all(pattern.search(ln) for ln in calls), calls
+    assert len(calls) == 1 and pattern.search(calls[0]), calls
 
 
 @pytest.mark.parametrize("tokens", [64, 2048], ids=["decode", "prefill"])
@@ -996,3 +1007,96 @@ def test_ssd_chunk_scan_kernel_at_a_prefill_chunks_shape(one_chip):
         _s(one_chip, (1, 64, 64, 128), f32))
     calls = _custom_calls(text)
     assert len(calls) == 1 and pattern.search(calls[0]), calls
+
+
+# ---------------------------------------------------------------------------
+# The split between ``ops/decode_attention.py`` (GPT-2's kernel) and
+# ``ops/hybrid_decode_attention.py`` stays a split: the lowered serving
+# programs of the two cells that run neither the hybrid kernel nor
+# ``models/blocks.py:paged_gqa`` are their parent's TEXT. sha256[:16] of
+# ``lower(...).as_text()`` at the cells' own sizes, the decode kernels
+# forced on, a kernel's Mosaic body without its debug info (it carries its
+# callers' line numbers): recorded at commit 46ccfdd (the parent of PR 50)
+# by the function below, and equal there and here.
+_PARENT_PROGRAMS = {
+    ("gpt2-xl", "decode"): "2387c053ad0ea1c7",
+    ("gpt2-xl", "prefill"): "5cde9a5d74d5fc46",
+    ("deepseek-v2-lite-l6", "decode"): "0454bbe358502ca6",
+    ("deepseek-v2-lite-l6", "chunk"): "b47959bec6b2899b",
+}
+# configuration -> slots, pool blocks, a sequence's blocks, the tokens of
+# the second program (GPT-2's a whole-prompt bucket, DeepSeek's a chunk of
+# a longer prompt), for_paged_decode's other arguments
+_CELL_SIZES = {
+    "gpt2-xl": (32, 513, 32, 128, {}),
+    "deepseek-v2-lite-l6": (48, 16385, 512, 512, {"return_routed": True}),
+}
+
+
+def _lowered_program_hash(monkeypatch, one_chip, config, program):
+    import hashlib
+    import json
+    import pathlib
+
+    from jax._src import tpu_custom_call
+    from jaxlib.mlir.passmanager import PassManager
+
+    from deepspeed_tpu.ops import attention as attn_mod
+    from perfbench import byname
+
+    lower_mosaic = tpu_custom_call._lower_mosaic_module_to_asm
+
+    def without_debug_info(module, **kw):
+        with module.context:
+            PassManager.parse("builtin.module(strip-debuginfo)").run(
+                module.operation)
+        return lower_mosaic(module, **kw)
+
+    monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm",
+                        without_debug_info)
+    monkeypatch.setattr(attn_mod, "_FORCE_DECODE_KERNEL", True)
+    root = pathlib.Path(__file__).resolve().parents[2]
+    config_file = json.loads(
+        (root / "perfbench" / "configs" / f"{config}.json").read_text())
+    slots, blocks, per_seq, tokens, knobs = _CELL_SIZES[config]
+    served = byname.module("families", config_file["family"]).serving_module(
+        config_file, jnp.bfloat16)
+    model = type(served)(served.config.for_paged_decode(blocks, 32, **knobs))
+    b, t = (slots, 1) if program == "decode" else (1, tokens)
+
+    def paging(tables, lengths, num_valid):
+        return {"block_tables": tables, "lengths": lengths,
+                "num_valid": num_valid, "prefill": program == "prefill"}
+
+    ints = lambda *shape: _s(one_chip, shape, jnp.int32)
+    variables = jax.tree_util.tree_map(
+        lambda x: _s(one_chip, x.shape, x.dtype),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32),
+            paging={**paging(jnp.zeros((1, per_seq), jnp.int32),
+                             jnp.zeros((1,), jnp.int32),
+                             jnp.full((1,), 8, jnp.int32)),
+                    "prefill": True})))
+
+    def step(variables, ids, *rest):
+        return model.apply(variables, ids, mutable=["cache"],
+                           paging=paging(*rest))
+
+    text = jax.jit(step).lower(variables, ints(b, t), ints(b, per_seq),
+                               ints(b), ints(b)).as_text()
+    assert ("tpu_custom_call" in text) == (program == "decode")
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("config,program", list(_PARENT_PROGRAMS))
+def test_other_cells_serving_programs_are_the_parents_text(
+        monkeypatch, one_chip, config, program):
+    """``serve-xl-chat``'s and ``serve-dsv2lite-mla-longdoc``'s decode
+    program and prefill (chunk) program lower to the text they had before
+    the hybrid kernel took tiles (PR 50): neither model imports the hybrid
+    file, and ``paged_work_list`` at their arguments is traced as it was.
+    An edit that means to change one of these programs records its new
+    hash here (the same function on the new parent)."""
+    assert _lowered_program_hash(monkeypatch, one_chip, config,
+                                 program) == _PARENT_PROGRAMS[(config,
+                                                               program)]

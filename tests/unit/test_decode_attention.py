@@ -789,23 +789,54 @@ def test_paged_work_list_made_once_serves_every_call(tile):
         decode_attention_paged(*args, work=(row_of[:-1], first))
 
 
-@pytest.mark.parametrize("max_blocks,first,row_of", [
-    (5, [0, 1, 2, 3, 5, 10, 11, 16],
-     [0, 1, 2, 3, 3, 4, 4, 4, 4, 4, 5] + [6] * 25),
-    (8, [0, 1, 2, 3, 5, 12, 13, 21],
-     [0, 1, 2, 3, 3, 4, 4, 4, 4, 4, 4, 4, 5] + [6] * 44)])
-def test_hybrid_work_list_is_the_parents_to_the_value(max_blocks, first,
-                                                      row_of):
-    """The hybrid kernel (``ops/hybrid_decode_attention.py``, PR 44 leaves
-    it byte for byte) lists its work at the default tile: a step a live
-    block, one for an idle slot, over a ring of 5 blocks and a table of 8.
-    The values are PR 44's parent's (94a7eb4)."""
-    from deepspeed_tpu.ops.hybrid_decode_attention import hybrid_work_list
+@pytest.mark.parametrize("max_blocks,tile,first,row_of", [
+    # a ring of 5 blocks is one tile: a step a busy row
+    (5, 5, [0, 0, 1, 2, 3, 4, 4, 5, 6], [1, 2, 3, 4, 6, 7] + [7] * 3),
+    # a table of 40 blocks of 32 in tiles of 16: one block 1 step; 16
+    # blocks 1; a key past the tile 2; 38 blocks 3; 8 blocks 1; a fresh row
+    # (length 0 on a block of its own) 1
+    (40, 16, [0, 0, 1, 2, 4, 7, 7, 8, 9],
+     [1, 2, 3, 3, 4, 4, 4, 6, 7] + [7] * 16)])
+def test_hybrid_work_list_is_in_the_plans_tiles(max_blocks, tile, first,
+                                                row_of):
+    """The hybrid kernel (``ops/hybrid_decode_attention.py``) lists its
+    work in :func:`hybrid_plan`'s tiles since PR 50, and an idle slot
+    (length 0 AND a table that starts at the garbage block: rows 0 and 5)
+    owns no step; a fresh row on a block of its own keeps one."""
+    from deepspeed_tpu.ops.attention import dispatch_counts
+    from deepspeed_tpu.ops.hybrid_decode_attention import (hybrid_plan,
+                                                           hybrid_work_list)
 
-    got_row_of, got_first = hybrid_work_list(
-        jnp.asarray([0, 5, 31, 32, 200, 0, 255]), 32, max_blocks)
+    plan = hybrid_plan(32, 512, 512, max_blocks)
+    assert plan.tile_blocks == tile and plan.tile_keys == 32 * tile
+    assert f"a tile of {tile} x 32 = {32 * tile} keys" in plan.describe()
+    lengths = jnp.asarray([0, 5, 511, 512, 1200, 0, 255, 0])
+    tables = jnp.asarray([[0], [3], [4], [9], [2], [0], [7], [8]])
+    name = f"hybrid_decode_tile{32 * tile}"
+    counted = dispatch_counts().get(name, 0)
+    got_row_of, got_first = hybrid_work_list(lengths, tables, plan)
+    assert dispatch_counts()[name] == counted + 1
     assert [int(f) for f in got_first] == first
     assert [int(r) for r in got_row_of] == row_of
+    assert got_row_of.shape == (8 * -(-max_blocks // tile) + 1,)
+
+
+def test_hybrid_plan_reads_its_tile_from_the_shapes():
+    """As many blocks as hold 512 keys, no more than a row or a ring has,
+    and no more than two tiles of both pools fit their share of VMEM in:
+    the cells' shapes (LFM2 and granite: 512-lane rows over tables of 80
+    and 256; mimo: 768 / 512 lanes over 128 blocks, a ring of 5 of 1536 /
+    1024 lanes), larger blocks, and rows too wide for a whole tile."""
+    from deepspeed_tpu.ops.hybrid_decode_attention import hybrid_plan
+
+    assert [hybrid_plan(32, 512, 512, mb).tile_blocks
+            for mb in (80, 256, 8, 1)] == [16, 16, 8, 1]
+    assert hybrid_plan(32, 768, 512, 128).tile_blocks == 16
+    assert hybrid_plan(32, 1536, 1024, 5).tile_blocks == 5
+    assert hybrid_plan(128, 512, 512, 64).tile_blocks == 4
+    assert hybrid_plan(1024, 512, 512, 64).tile_blocks == 1
+    assert hybrid_plan(32, 4096, 4096, 128).tile_blocks == 8
+    assert hybrid_plan(32, 8192, 8192, 128).tile_blocks == 4
 
 
 @pytest.mark.parametrize("scan_layers", [True, False],
@@ -861,6 +892,90 @@ def test_model_lists_the_paged_kernels_work_once_a_step(monkeypatch,
     jax.make_jaxpr(lambda ids: step(ids, paging([0, 0], 8, True)))(prompt)
     assert len(made) == 1
     assert attn_mod.dispatch_counts()["paged_decode_tile128"] == counted + 1
+
+
+@pytest.mark.parametrize("family,config,module,knob,tiles", [
+    ("mimo_v2", "MiMoV2Config", "MiMoV2ForCausalLM", "ring_slots",
+     # the global kind's table of 4 blocks, the window kind's ring
+     lambda cfg: sorted({4, cfg.paged_ring_blocks_for(4)})),
+    ("lfm2_moe", "Lfm2MoeConfig", "Lfm2MoeForCausalLM", "state_slots",
+     lambda cfg: [4]),
+    ("granite_hybrid", "GraniteHybridConfig", "GraniteHybridForCausalLM",
+     "state_slots", lambda cfg: [4])])
+def test_hybrid_families_list_the_kernels_work_once_a_step(
+        monkeypatch, caplog, family, config, module, knob, tiles):
+    """A served hybrid family lists the hybrid kernel's work once a KIND of
+    layer a traced decode step, whatever its layers, in the plan's tiles;
+    counts the form it took where ``stats()["attention_paths"]`` reads it;
+    and the plan is logged once a shape, not once a layer or a trace."""
+    import importlib
+    import logging
+
+    from deepspeed_tpu.ops import attention as attn_mod
+    from deepspeed_tpu.ops import hybrid_decode_attention as hda
+
+    mod = importlib.import_module(f"deepspeed_tpu.models.{family}")
+    bs, slots = 4, 3
+    cfg = getattr(mod, config).tiny().for_paged_decode(13, bs,
+                                                       **{knob: slots})
+    tiles = tiles(cfg)
+    model = getattr(mod, module)(cfg)
+    entries = cfg.paged_slot_state_for(bs)["entries"]
+    tables = np.zeros((slots, 4 + entries), np.int32)
+    tables[0, :2], tables[2, :1] = [3, 5], [7]       # slot 1 is idle
+    tables[:, 4:] = 1 + np.arange(slots * entries).reshape(slots, entries)
+    tables[1] = 0
+
+    def paging(lengths, n, prefill):
+        return {"block_tables": jnp.asarray(tables),
+                "lengths": jnp.asarray(lengths, jnp.int32),
+                "num_valid": jnp.full((slots,), n, jnp.int32),
+                "prefill": prefill}
+
+    prompt = jnp.zeros((slots, 4), jnp.int32)
+    variables = model.init(jax.random.PRNGKey(0), prompt,
+                           paging=paging([0] * slots, 4, True))
+    made = []
+    real = hda.hybrid_work_list
+
+    def spy(lengths, block_tables, plan):
+        made.append(plan)
+        return real(lengths, block_tables, plan)
+
+    monkeypatch.setattr(hda, "hybrid_work_list", spy)
+    monkeypatch.setattr(attn_mod, "_FORCE_DECODE_KERNEL", True)
+    monkeypatch.setattr(hda, "_noted_plans", set())
+    names = [f"hybrid_decode_tile{t * bs}" for t in tiles]
+    counted = [attn_mod.dispatch_counts().get(n, 0) for n in names]
+
+    def step(ids, lengths, n, prefill):
+        return model.apply(variables, ids, mutable=["cache"],
+                           paging=paging(lengths, n, prefill))
+
+    from deepspeed_tpu.utils.logging import logger
+
+    logger.addHandler(caplog.handler)
+    try:
+        with caplog.at_level(logging.INFO, logger=logger.name):
+            for _ in range(2):     # traced twice: listed and counted twice
+                jaxpr = jax.make_jaxpr(
+                    lambda ids, ln: step(ids, ln, 1, False))(
+                        prompt[:, :1], jnp.asarray([6, 0, 2], jnp.int32))
+    finally:
+        logger.removeHandler(caplog.handler)
+    assert sorted(p.tile_blocks for p in made) == sorted(tiles * 2)
+    assert {p.block_size for p in made} == {bs}
+    assert str(jaxpr).count("pallas_call") >= len(tiles)
+    assert [attn_mod.dispatch_counts()[n] for n in names] == [
+        c + 2 for c in counted]
+    logged = [r.getMessage() for r in caplog.records
+              if "decode_attention_hybrid q" in r.getMessage()]
+    assert len(logged) == len(tiles), logged
+    for plan in set(made):
+        assert sum(plan.describe() in line for line in logged) == 1
+    # ... and a prefill step runs no paged kernel and lists nothing
+    jax.make_jaxpr(lambda ids: step(ids, [0] * slots, 4, True))(prompt)
+    assert len(made) == 2 * len(tiles)
 
 
 def test_paged_live_row_on_the_garbage_block_is_attended():

@@ -892,6 +892,7 @@ class TestRealEngineMetrics:
         from tests.unit.test_telemetry import _engine
 
         from deepspeed_tpu.parallel.topology import reset_topology
+        from deepspeed_tpu.utils.compat import compilation_cache_off
 
         x, y = random_dataset(64, 8)
         batch = (x[:32], y[:32])
@@ -900,8 +901,13 @@ class TestRealEngineMetrics:
             raw = engine._jit_micro
             raw = getattr(raw, "_fn", raw)
             engine((batch[0], batch[1]))
-            return raw.lower(engine.state,
-                             engine._shard_batch(batch)).compile().as_text()
+            # not from the persistent cache: an entry another test file's
+            # worker wrote between the two compiles carries THAT file's
+            # source lines in its text (seen once under xdist, PR 50)
+            with compilation_cache_off():
+                return raw.lower(
+                    engine.state,
+                    engine._shard_batch(batch)).compile().as_text()
 
         reset_topology()
         plain = _engine()

@@ -470,45 +470,111 @@ def _interpreted(fn, *args):
         return jax.block_until_ready(jax.jit(fn)(*args))
 
 
-@pytest.mark.parametrize("kind", ["global", "window-sink", "window"])
-def test_hybrid_decode_kernel_matches_the_masked_path(kind):
-    """GQA (4 queries a KV head), keys 24 wide and values 16, a window's
-    ring that has wrapped, unequal lengths, an idle row."""
-    from deepspeed_tpu.models.blocks import masked_gqa
-    from deepspeed_tpu.ops.hybrid_decode_attention import (
-        decode_attention_hybrid, ring_positions)
+# kind, keys a tile aims at (None: the plan's own), lengths a row (None: an
+# idle slot, a table of garbage blocks), blocks a row or ring, unnamed blocks
+# poisoned. Blocks of 4 keys; a window of 8.
+_HYBRID_CASES = {
+    # unequal lengths, an idle row and a fresh one, the plan's tile (the
+    # whole table, the whole ring: one step a row)
+    "global": ("global", None, [None, 3, 9, 30, 0], 9, False),
+    "window-sink": ("window-sink", None, [None, 3, 9, 30, 0], 3, False),
+    "window": ("window", None, [None, 3, 9, 30, 0], 3, False),
+    # tiles of 2 blocks: live prefixes that end in a tile's first block (9
+    # keys = 3 blocks, 32 = 9), at its boundary (8 = 2 blocks), in a
+    # sequence's only block (1, 3), over the whole table (35)
+    "ends-inside-a-tile": ("global", 8, [8, None, 7, 31, 0, 2, 34, 15], 9,
+                           False),
+    "rows-of-one-block": ("global", 8, [0, 2, None, 3, 1], 9, False),
+    # a ring of 3 blocks in tiles of 2, wrapped: the newest block is the
+    # tile's second (length 5), its first (9), the lone block of the second
+    # step (11); the seam between laps falls inside a tile and between two
+    "ring-seam-inside-a-tile": ("window", 8, [5, 9, 11, 14, 30, None, 2], 3,
+                                False),
+    "ring-seam-sink": ("window-sink", 8, [21, None, 12, 16, 17, 0], 3,
+                       False),
+    "idle-slots-only": ("global", 8, [None, None, None], 9, False),
+    "idle-rings-only": ("window-sink", None, [None, None], 3, False),
+    # whatever no table names is NaN: a tile's dead blocks are never read
+    "nan-in-unnamed-blocks": ("global", 8, [8, None, 7, 31, 0, 2, 34], 9,
+                              True),
+    "nan-beside-a-ring": ("window", 8, [5, None, 11, 30], 3, True),
+}
 
+
+@pytest.mark.parametrize("case", _HYBRID_CASES)
+def test_hybrid_decode_kernel_matches_the_masked_path(monkeypatch, case):
+    """GQA (4 queries a KV head), keys 24 wide and values 16, against the
+    masked XLA path over the gathered rows: the three kinds at the plan's
+    tile, and what a tile brings (``_HYBRID_CASES``)."""
+    from deepspeed_tpu.models.blocks import masked_gqa
+    from deepspeed_tpu.ops import hybrid_decode_attention as hda
+
+    kind, tile_keys, lengths, mb, poison = _HYBRID_CASES[case]
+    if tile_keys:
+        monkeypatch.setattr(hda, "HYBRID_TILE_KEYS", tile_keys)
     window = 0 if kind == "global" else WINDOW
     heads, kv, dk, dv, bs = 8, 2, 24, 16, BLOCK
-    lengths = np.asarray([0, 3, 9, 30, 0], np.int32)   # rows 0, 4: idle/new
-    b, mb = len(lengths), 3 if window else 9
+    idle = np.asarray([n is None for n in lengths])
+    lengths = np.asarray([n or 0 for n in lengths], np.int32)
+    b = len(lengths)
+    assert hda.hybrid_plan(bs, kv * dk, kv * dv, mb).tile_blocks == (
+        tile_keys // bs if tile_keys else mb)
     rng = np.random.default_rng(7)
     k_pool = rng.standard_normal((2, 1 + b * mb, bs, kv * dk), np.float32)
     v_pool = rng.standard_normal((2, 1 + b * mb, bs, kv * dv), np.float32)
     tables = 1 + np.arange(b * mb, dtype=np.int32).reshape(b, mb)
-    tables[0] = 0                                       # an idle slot
+    # what says a slot is idle is its sequence's table; its ring stays its
+    # own (the engine zeroes a dead row's whole table: either is served)
+    said_by = tables.copy()
+    said_by[idle] = 0
+    if not window:
+        tables = said_by.copy()
+        # a table names a sequence's live blocks and pads with the garbage
+        # block
+        live = -(-(lengths + 1) // bs)
+        tables[np.arange(mb)[None] >= live[:, None]] = 0
     q = rng.standard_normal((b, 1, heads, dk), np.float32)
     sink = (rng.standard_normal(heads).astype(np.float32)
             if kind == "window-sink" else None)
-    got = _interpreted(
-        lambda *a: decode_attention_hybrid(
-            *a, 1, kv_heads=kv, window=window, ring=bool(window),
-            sink=None if sink is None else jnp.asarray(sink)),
-        jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
-        jnp.asarray(tables), jnp.asarray(lengths))
+
+    def run(k_pool, v_pool):
+        plan = hda.hybrid_plan(bs, kv * dk, kv * dv, mb)
+        return np.asarray(_interpreted(
+            lambda q, k, v, t, said, n: hda.decode_attention_hybrid(
+                q, k, v, t, n, 1, kv_heads=kv, window=window,
+                ring=bool(window),
+                sink=None if sink is None else jnp.asarray(sink),
+                work=hda.hybrid_work_list(n, said, plan)),
+            *map(jnp.asarray, (q, k_pool, v_pool, tables, said_by,
+                               lengths))))
+
+    got = run(k_pool, v_pool)
     rows = mb * bs
     keys = k_pool[1][tables].reshape(b, rows, kv, dk)
     vals = v_pool[1][tables].reshape(b, rows, kv, dv)
     # the pool already holds the step's own key at position L
-    held = (np.asarray(ring_positions(lengths + 1, rows)) if window
+    held = (np.asarray(hda.ring_positions(lengths + 1, rows)) if window
             else np.broadcast_to(np.arange(rows), (b, rows)))
-    want = masked_gqa(jnp.asarray(q), jnp.asarray(keys), jnp.asarray(vals),
-                      jnp.asarray(lengths)[:, None], jnp.asarray(held),
-                      jnp.asarray(held >= 0), window,
-                      None if sink is None else jnp.asarray(sink))
-    got, want = np.asarray(got), np.asarray(want)
-    assert np.abs(got[1:] - want[1:]).max() <= 1e-5
-    assert not got[0].any()          # the idle slot: zeros, no arithmetic
+    want = np.asarray(masked_gqa(
+        jnp.asarray(q), jnp.asarray(keys), jnp.asarray(vals),
+        jnp.asarray(lengths)[:, None], jnp.asarray(held),
+        jnp.asarray(held >= 0), window,
+        None if sink is None else jnp.asarray(sink)))
+    if (~idle).any():
+        assert np.abs(got[~idle] - want[~idle]).max() <= 1e-5
+    assert not got[idle].any()       # an idle slot: zeros, no arithmetic
+    if poison:
+        # named: the live blocks of a busy row (a ring not yet full has
+        # dead blocks too) and, by a table's padding, the garbage block
+        named = np.zeros(k_pool.shape[1], bool)
+        named[0] = not window
+        live = np.minimum(-(-(lengths + 1) // bs), mb)
+        for r in np.flatnonzero(~idle):
+            named[tables[r, :live[r]]] = True
+        assert (~named).sum() >= b
+        k_pool[:, ~named] = np.nan
+        v_pool[:, ~named] = np.nan
+        np.testing.assert_array_equal(run(k_pool, v_pool), got)
 
 
 @pytest.mark.parametrize("tokens, only", [(24, None), (24, 5), (3, None)],

@@ -33,10 +33,15 @@ pools, addressed through the block table, and TWO pools of per-slot state:
 ``ssm_state_pool [mamba layers, 1 + slots, H, P, N]`` and ``ssm_conv_pool
 [mamba layers, 1 + slots, (K - 1) (H P + 2 N)]``, row ``1 + s`` decode slot
 ``s``'s, row 0 what idle rows write, both ``dtype`` (the state's arithmetic
-is float32 and the pool rounds once a stored step; the convolution's ``K -
-1`` rows lie side by side in ONE pool row: as ``[.., K - 1, channels]`` the
-chip's compiler tiles the 3 rows to 16 and copies the whole pool a layer
-to change its layout, PERF.md PR 49). A program's row finds
+is float32 and the pool rounds once a stored step; a row's state LIES in
+its ``H P N`` values as ``[H P / L, N, L]``, lane groups of ``L = 128``
+``(head, width)`` columns with the state size down the sublanes, the layout
+in which a decode step crosses no lane: ``scan_state_in`` and
+``scan_state_out`` hand the scan such rows, and its kernel turns them in
+VMEM; the convolution's ``K - 1`` rows lie side by side in ONE pool row: as
+``[.., K - 1, channels]`` the chip's compiler tiles the 3 rows to 16 and
+copies the whole pool a layer to change its layout, PERF.md PR 49). A
+program's row finds
 its slot's row as the block table's last entry (the engine's per-slot seam,
 ``paged_slot_state_for``). ONE mixer, :class:`Mamba2Mixer`, serves a whole
 sequence, a prefill chunk and a decode step: a sequence at length 0 starts
@@ -244,6 +249,23 @@ def state_in(pool, index, rows, fresh):
                      jnp.zeros_like(held), held)
 
 
+def scan_state_in(pool, index, rows, fresh):
+    """:func:`state_in` of ``ssm_state_pool`` as its values LIE: the pool is
+    ALLOCATED ``[layers, rows, H, P, N]`` and a row's values lie as ``[H P /
+    L, N, L]``, the state size down the sublanes (``ops/ssm_state_update``:
+    the layout in which a decode step crosses no lane). -> ``[B, H P / L,
+    N, L]``, what ``ssd_chunk_scan`` takes and returns."""
+    return state_in(ssm_state_update.lane_view(pool), index, rows, fresh)
+
+
+def scan_state_out(pool, index, rows, state):
+    """The pool with ``state [B, H P / L, N, L]`` in ``rows`` of layer
+    ``index`` (:func:`scan_state_in`'s layout)."""
+    view = ssm_state_update.lane_view(pool)
+    return view.at[index, rows].set(state.astype(pool.dtype)).reshape(
+        pool.shape)
+
+
 class Mamba2Mixer(nn.Module):
     """``u [B, T, d] -> (its term [B, T, d], pools)``: the Mamba-2 mixer of
     a whole sequence from zeros (plain call), of a whole prompt or a
@@ -301,7 +323,8 @@ class Mamba2Mixer(nn.Module):
                           < num_valid[:, None, None], delta, 0.0)
         if serving and t == 1 and not paging.get("prefill"):
             # a decode step: the pool in place
-            kernel = use_decode_kernel()
+            kernel = use_decode_kernel() and ssm_state_update.kernel_serves(
+                heads, cfg.mamba_d_head, cfg.mamba_d_state)
             record_dispatch("granite_ssm_decode_"
                             + ("kernel" if kernel else "xla"))
             decay = jnp.where(fresh[:, None], 0.0, jnp.exp(delta[:, 0] * rate))
@@ -316,16 +339,17 @@ class Mamba2Mixer(nn.Module):
         else:
             if serving:
                 record_dispatch("granite_ssm_prefill_chunk")
-                state = state_in(pools["ssm_state_pool"], index, rows, fresh)
+                state = scan_state_in(pools["ssm_state_pool"], index, rows,
+                                      fresh)
             else:
-                state = jnp.zeros((b, heads, cfg.mamba_d_head,
-                                   cfg.mamba_d_state), f32)
+                state = ssm_state_update.to_lanes(jnp.zeros(
+                    (b, heads, cfg.mamba_d_head, cfg.mamba_d_state), f32))
             y, state = ssd_chunk_scan.ssd_chunk_scan(
                 x, delta, rate, bm, cm, state, cfg.mamba_chunk_size,
                 cfg.dtype)
             if serving:
-                pool = pools["ssm_state_pool"]
-                state_pool = pool.at[index, rows].set(state.astype(pool.dtype))
+                state_pool = scan_state_out(pools["ssm_state_pool"], index,
+                                            rows, state)
         if serving:
             pool = pools["ssm_conv_pool"]
             pools = {**pools, "ssm_state_pool": state_pool,

@@ -50,12 +50,19 @@ def ssd_chunk_scan(x, delta, a_log_rate, b, c, state, chunk: int,
                    dtype=jnp.float32, use_kernel=None):
     """``x [B, T, H, P]``, ``delta [B, T, H]`` (0 at a padded position),
     ``a_log_rate [H]`` (``A``, negative), ``b`` / ``c [B, T, N]``, ``state
-    [B, H, P, N]`` float32: the state before the call's first position.
-    -> ``(y [B, T, H, P] float32, state after the last position)``. ``T``
-    is padded up to whole chunks of ``chunk`` with ``delta = 0``.
+    [B, H P / L, N, L]`` float32: the state before the call's first
+    position, as a row of the decode state pool lies
+    (``ops/ssm_state_update.py``: ``to_lanes`` of ``[B, H, P, N]``). ->
+    ``(y [B, T, H, P] float32, state after the last position)``, the state
+    in the same layout: the kernel reads and writes such blocks as they lie
+    and turns them in VMEM at a row's first and last chunk, so a served
+    chunk's state is never transposed through HBM (in XLA, around this call,
+    the chip's compiler re-laid the whole pool: PERF.md, PR 51). ``T`` is
+    padded up to whole chunks of ``chunk`` with ``delta = 0``.
     ``use_kernel``: None is the kernel where a TPU is and the sizes are
     whole registers."""
     from deepspeed_tpu.ops.attention import use_decode_kernel
+    from deepspeed_tpu.ops.ssm_state_update import from_lanes, to_lanes
 
     rows, t, heads, width = x.shape
     pad = -t % chunk
@@ -66,10 +73,17 @@ def ssd_chunk_scan(x, delta, a_log_rate, b, c, state, chunk: int,
     if use_kernel is None:
         use_kernel = use_decode_kernel() and kernel_serves(
             chunk, heads, width, b.shape[-1])
+    state = state.astype(jnp.float32)
+    terms = (x, delta.astype(jnp.float32), a_log_rate.astype(jnp.float32), b,
+             c)
     with jax.named_scope("ssm._chunk_scan"):
-        y, state = (_scan_kernel if use_kernel else _scan_einsums)(
-            x, delta.astype(jnp.float32), a_log_rate.astype(jnp.float32), b,
-            c, state.astype(jnp.float32), chunk, dtype)
+        if use_kernel:
+            y, state = _scan_kernel(*terms, state, chunk, dtype)
+        else:
+            # the einsums take a head's state as a [P, N] matrix
+            y, state = _scan_einsums(
+                *terms, from_lanes(state, heads, width), chunk, dtype)
+            state = to_lanes(state)
     return y[:, :t], state
 
 
@@ -120,10 +134,13 @@ def _scan_einsums(x, delta, a_log_rate, b, c, state, chunk, dtype):
 # the Pallas form
 
 def kernel_serves(chunk: int, heads: int, width: int, n: int) -> bool:
-    """Whether the kernel's blocks are whole registers at these sizes."""
+    """Whether the kernel's blocks are whole registers at these sizes (a
+    tile of heads whole lane groups of the state's layout, a head whole
+    sublane tiles)."""
     tile = min(HEAD_TILE, heads)
     return (chunk % 128 == 0 and n % 128 == 0 and heads % tile == 0
-            and (tile * width) % 128 == 0 and tile % 8 == 0)
+            and (tile * width) % 128 == 0 and tile % 8 == 0
+            and width % 8 == 0)
 
 
 def _kernel(dx_ref, cum_ref, cum_t_ref, b_ref, c_ref, s_in_ref, y_ref,
@@ -131,9 +148,15 @@ def _kernel(dx_ref, cum_ref, cum_t_ref, b_ref, c_ref, s_in_ref, y_ref,
     f32 = jnp.float32
     k = pl.program_id(2)
 
+    # the tile's states [tile x P, N] between the chunks; in HBM they lie
+    # as lane groups [N, 128 columns]: turned here, at a row's first and
+    # last chunk
+    groups, _, lanes = s_in_ref.shape
+
     @pl.when(k == 0)
     def _first():
-        state[...] = s_in_ref[...]
+        for g in range(groups):
+            state[g * lanes:(g + 1) * lanes, :] = s_in_ref[g].T
 
     bv, cv = b_ref[...], c_ref[...]                          # [Q, N]
     nt = (((1,), (1,)), ((), ()))
@@ -148,28 +171,32 @@ def _kernel(dx_ref, cum_ref, cum_t_ref, b_ref, c_ref, s_in_ref, y_ref,
         decay = jnp.exp(jnp.where(below, col - row, -jnp.inf))
         mixed = (decay * scores).astype(dtype)
         dx = dx_ref[:, h * width:(h + 1) * width]            # [Q, P]
-        s_in = state[h]                                      # [P, N]
+        s_in = state[h * width:(h + 1) * width, :]           # [P, N]
         y = jnp.dot(mixed, dx, preferred_element_type=f32)
         y = y + jnp.exp(col) * jax.lax.dot_general(
             cv, s_in.astype(dtype), nt, preferred_element_type=f32)
         last = cum_t_ref[h:h + 1, chunk - 1:chunk]           # [1, 1]
         carried = (jnp.exp(last - col) * dx.astype(f32)).astype(dtype)
-        state[h] = jnp.exp(last) * s_in + jax.lax.dot_general(
+        state[h * width:(h + 1) * width, :] = jnp.exp(
+            last) * s_in + jax.lax.dot_general(
             carried, bv, (((0,), (0,)), ((), ())),
             preferred_element_type=f32)
         y_ref[:, h * width:(h + 1) * width] = y
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _last():
-        s_out_ref[...] = state[...]
+        for g in range(groups):
+            s_out_ref[g] = state[g * lanes:(g + 1) * lanes, :].T
 
 
 def _scan_kernel(x, delta, a_log_rate, b, c, state, chunk, dtype):
-    """Whole chunks of ``chunk`` positions, as the Pallas kernel."""
+    """Whole chunks of ``chunk`` positions, as the Pallas kernel; ``state
+    [B, H P / 128, N, 128]``, in and out."""
     rows, full, heads, width = x.shape
     n = b.shape[-1]
     tile = min(HEAD_TILE, heads)
     tiles = heads // tile
+    lanes = state.shape[-1]
     k = full // chunk
     f32 = jnp.float32
     dx = (delta[..., None] * x.astype(f32)).astype(dtype).reshape(
@@ -183,7 +210,8 @@ def _scan_kernel(x, delta, a_log_rate, b, c, state, chunk, dtype):
     cum_t = cum_s.swapaxes(2, 3)                             # [B,tiles,tile,T]
     seq = lambda lanes: pl.BlockSpec((None, chunk, lanes),
                                      lambda r, j, k: (r, k, 0))
-    held = pl.BlockSpec((None, tile, width, n), lambda r, j, k: (r, j, 0, 0))
+    held = pl.BlockSpec((None, tile * width // lanes, n, lanes),
+                        lambda r, j, k: (r, j, 0, 0))
     by_head = pl.BlockSpec((None, chunk, tile * width),
                            lambda r, j, k: (r, k, j))
     y, state = pl.pallas_call(
@@ -199,7 +227,7 @@ def _scan_kernel(x, delta, a_log_rate, b, c, state, chunk, dtype):
         out_specs=[by_head, held],
         out_shape=[jax.ShapeDtypeStruct((rows, full, heads * width), f32),
                    jax.ShapeDtypeStruct(state.shape, f32)],
-        scratch_shapes=[pltpu.VMEM((tile, width, n), f32)],
+        scratch_shapes=[pltpu.VMEM((tile * width, n), f32)],
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(dx, cum_s, cum_t, b.astype(dtype), c.astype(dtype), state)

@@ -960,31 +960,76 @@ def test_grouped_expert_kernel_at_deepseek_v2_lites_widths(one_chip, tokens):
 # granite-4.0-h-micro at its published widths: 36 Mamba-2 layers of 64 heads
 # x 64 with a state of 128 in a pool of 1 + 64 slots; the benchmark cell's 64
 # decode slots and its 512-token chunks in scan chunks of 256
-def test_ssm_state_update_kernel_is_in_place_and_named(one_chip):
-    """The decode step's state update: the pool aliased to the kernel's
-    output (no second 2.45 GB), under the name the parked
-    ``ssm_decode_roofline_share`` reads."""
-    from deepspeed_tpu.ops.ssm_state_update import state_update_kernel
+def _largest_moved_bytes(text):
+    """The largest array a ``copy`` or a ``transpose`` of a compiled
+    program's text produces, in bytes (0 without one)."""
+    import re
+
+    sizes = {"bf16": 2, "f32": 4, "s32": 4, "f16": 2, "pred": 1, "s8": 1,
+             "u8": 1, "u32": 4}
+    worst = 0
+    for ln in text.splitlines():
+        found = re.search(
+            r"= (\w+)\[([\d,]*)\]\S* (?:copy|copy-start|transpose)\(", ln)
+        if found and found.group(1) in sizes:
+            dims = [int(d) for d in found.group(2).split(",") if d]
+            worst = max(worst, int(np.prod(dims)) * sizes[found.group(1)])
+    return worst
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_ssm_state_update_kernel_is_in_place_and_named(one_chip, program):
+    """The decode step's state update on the pool as its values lie
+    (``[36, 65, 32, 128, 128]``, a bitcast of the allocation): the pool
+    aliased to the kernel's output (no second 2.45 GB), ONE custom call a
+    layer under the name the parked ``ssm_decode_roofline_share`` reads;
+    and a prefill chunk's read and write of a row through the scan kernel,
+    which turns the lane groups in VMEM. Neither program copies or transposes an array of the pool's
+    size."""
+    from deepspeed_tpu.models.granite_hybrid import (scan_state_in,
+                                                     scan_state_out)
+    from deepspeed_tpu.ops.ssd_chunk_scan import ssd_chunk_scan
+    from deepspeed_tpu.ops.ssm_state_update import (kernel_serves,
+                                                    state_update_kernel)
 
     pattern = _reader_pattern("ssm_decode_roofline_share")
     pool = (36, 65, 64, 64, 128)
+    held = int(np.prod(pool)) * 2
+    assert kernel_serves(64, 64, 128)
 
     def step(pool, slots, a, dx, b, c):
         with jax.named_scope("ssm._state_update"):
             return state_update_kernel(pool, 7, slots, a, dx, b, c)
 
-    compiled = jax.jit(step, donate_argnums=0).lower(
-        _s(one_chip, pool), _s(one_chip, (64,), jnp.int32),
-        _s(one_chip, (64, 64), jnp.float32),
-        _s(one_chip, (64, 64, 64), jnp.float32),
-        _s(one_chip, (64, 128), jnp.float32),
-        _s(one_chip, (64, 128), jnp.float32)).compile()
-    calls = _custom_calls(compiled.as_text())
-    assert len(calls) == 1 and pattern.search(calls[0]), calls
+    def chunk(pool, rows, fresh, *terms):
+        _, state = ssd_chunk_scan(
+            *terms, scan_state_in(pool, 7, rows, fresh), 256, jnp.bfloat16,
+            use_kernel=True)
+        return scan_state_out(pool, 7, rows, state)
+
+    if program == "decode":
+        compiled = jax.jit(step, donate_argnums=0).lower(
+            _s(one_chip, pool), _s(one_chip, (64,), jnp.int32),
+            _s(one_chip, (64, 64), jnp.float32),
+            _s(one_chip, (64, 64, 64), jnp.float32),
+            _s(one_chip, (64, 128), jnp.float32),
+            _s(one_chip, (64, 128), jnp.float32)).compile()
+        calls = _custom_calls(compiled.as_text())
+        assert len(calls) == 1 and pattern.search(calls[0]), calls
+        assert "bf16[36,65,32,128,128]" in calls[0]
+    else:
+        compiled = jax.jit(chunk, donate_argnums=0).lower(
+            _s(one_chip, pool), _s(one_chip, (1,), jnp.int32),
+            _s(one_chip, (1,), jnp.bool_),
+            _s(one_chip, (1, 512, 64, 64), jnp.float32),
+            _s(one_chip, (1, 512, 64), jnp.float32),
+            _s(one_chip, (64,), jnp.float32),
+            _s(one_chip, (1, 512, 128), jnp.float32),
+            _s(one_chip, (1, 512, 128), jnp.float32)).compile()
     memory = compiled.memory_analysis()
-    held = int(np.prod(pool)) * 2
     assert memory.alias_size_in_bytes >= held
     assert memory.temp_size_in_bytes < held // 100
+    assert _largest_moved_bytes(compiled.as_text()) < held // 100
 
 
 def test_ssd_chunk_scan_kernel_at_a_prefill_chunks_shape(one_chip):
@@ -1004,7 +1049,8 @@ def test_ssd_chunk_scan_kernel_at_a_prefill_chunks_shape(one_chip):
         scan, _s(one_chip, (1, 512, 64, 64), f32),
         _s(one_chip, (1, 512, 64), f32), _s(one_chip, (64,), f32),
         _s(one_chip, (1, 512, 128), f32), _s(one_chip, (1, 512, 128), f32),
-        _s(one_chip, (1, 64, 64, 128), f32))
+        # the state as a pool row lies: 32 lane groups of [N, 128]
+        _s(one_chip, (1, 32, 128, 128), f32))
     calls = _custom_calls(text)
     assert len(calls) == 1 and pattern.search(calls[0]), calls
 

@@ -24,6 +24,7 @@ from deepspeed_tpu.models.granite_hybrid import (GraniteAttention,
                                                  Mamba2Mixer)
 from deepspeed_tpu.ops import ssm_state_update
 from deepspeed_tpu.ops.ssd_chunk_scan import ssd_chunk_scan
+from deepspeed_tpu.ops.ssm_state_update import from_lanes, to_lanes
 from deepspeed_tpu.parallel.topology import reset_topology
 from deepspeed_tpu.serving import ServingEngine
 from perfbench import reference_granite_hybrid as reference
@@ -201,10 +202,10 @@ def test_the_chunked_scan_is_the_recurrence_one_position_at_a_time(highest,
                                                                    chunk):
     x, delta, rate, b, c = _recurrence_inputs()
     want, last = reference.recurrence(x, delta, jnp.exp(delta * rate), b, c)
-    zero = jnp.zeros(last.shape, jnp.float32)
+    zero = to_lanes(jnp.zeros(last.shape, jnp.float32))
     got, state = ssd_chunk_scan(x, delta, rate, b, c, zero, chunk)
     assert np.abs(np.asarray(got - want)).max() <= 1e-5
-    assert np.abs(np.asarray(state - last)).max() <= 1e-5
+    assert np.abs(np.asarray(from_lanes(state, 4, 8) - last)).max() <= 1e-5
 
 
 def test_the_scan_in_pieces_of_uneven_num_valid_carries_the_state(highest):
@@ -213,7 +214,7 @@ def test_the_scan_in_pieces_of_uneven_num_valid_carries_the_state(highest):
     left, and the pieces' real positions are the whole's."""
     x, delta, rate, b, c = _recurrence_inputs(t=23)
     want, last = reference.recurrence(x, delta, jnp.exp(delta * rate), b, c)
-    state = jnp.zeros(last.shape, jnp.float32)
+    state = to_lanes(jnp.zeros(last.shape, jnp.float32))
     at, rows = 0, []
     for real in (8, 5, 8, 2):
         def piece(v):
@@ -226,7 +227,7 @@ def test_the_scan_in_pieces_of_uneven_num_valid_carries_the_state(highest):
         rows.append(y[:, :real])
         at += real
     assert np.abs(np.asarray(jnp.concatenate(rows, 1) - want)).max() <= 1e-5
-    assert np.abs(np.asarray(state - last)).max() <= 1e-5
+    assert np.abs(np.asarray(from_lanes(state, 4, 8) - last)).max() <= 1e-5
 
 
 def test_the_scan_kernel_is_the_einsum_form(highest):
@@ -237,9 +238,9 @@ def test_the_scan_kernel_is_the_einsum_form(highest):
     from deepspeed_tpu.utils.compat import tpu_interpret_mode
 
     x, delta, rate, b, c = _recurrence_inputs(2, 300, 8, 16, 128)
-    state = jnp.asarray(np.random.default_rng(1).normal(
-        size=(2, 8, 16, 128)), jnp.float32)
-    assert kernel_serves(128, 8, 16, 128)
+    state = to_lanes(jnp.asarray(np.random.default_rng(1).normal(
+        size=(2, 8, 16, 128)), jnp.float32))
+    assert kernel_serves(128, 8, 16, 128) and state.shape == (2, 1, 128, 128)
     want, last = ssd_chunk_scan(x, delta, rate, b, c, state, 128,
                                 use_kernel=False)
     with tpu_interpret_mode():
@@ -251,21 +252,50 @@ def test_the_scan_kernel_is_the_einsum_form(highest):
     assert np.abs(np.asarray(carried - last)).max() <= 1e-5
 
 
-@pytest.mark.parametrize("form", ["xla", "kernel"])
-def test_decode_steps_are_the_recurrence(highest, form):
+# (form, heads, width, state size): lane groups of 128 (one, and two in
+# tiles of one) and, for the XLA form, a row narrower than 128 lanes (L = H P)
+DECODE_SHAPES = [("xla", 8, 16, 128), ("kernel", 8, 16, 128),
+                 ("xla", 4, 8, 16), ("xla", 8, 32, 32),
+                 ("kernel", 8, 32, 32)]
+_ids = lambda case: "-".join(map(str, case))
+
+
+def _update(form):
+    """The decode update of one form, the kernel in tiles of ONE lane group
+    (two grid steps a row at 8 x 32)."""
+    if form == "xla":
+        return ssm_state_update.state_update_xla
+    return lambda *args: ssm_state_update.state_update_kernel(
+        *args, group_tile=1)
+
+
+def _held(pool, layer, rows):
+    """What ``rows`` of a layer of the pool hold, as the plain recurrence
+    writes a state: ``[rows, H, P, N]``."""
+    _, _, heads, width, _ = pool.shape
+    return np.asarray(ssm_state_update.from_lanes(
+        ssm_state_update.lane_view(pool)[layer, np.asarray(rows)], heads,
+        width))
+
+
+@pytest.mark.parametrize("case", DECODE_SHAPES, ids=_ids)
+def test_decode_steps_are_the_recurrence(highest, case):
     """The in-place state update, a step a position, on a pool of several
-    layers and slots: the rows' slots in any order, idle rows between
-    them; ``a = 0`` restarts a row. The kernel (interpret mode) leaves the
-    idle rows' row 0 and every other layer untouched."""
+    layers and slots, against a plain ``[H, P, N]`` float32 recurrence that
+    knows nothing of the pool's layout: the rows' slots in any order, idle
+    rows between them; ``a = 0`` restarts a row. The kernel (interpret
+    mode) leaves the idle rows' row 0 and every other layer untouched."""
     from deepspeed_tpu.utils.compat import tpu_interpret_mode
 
-    heads, width, n, t = 8, 16, 128, 6
+    form, heads, width, n = case
+    t = 6
     x, delta, rate, b, c = _recurrence_inputs(3, t, heads, width, n)
     want, last = reference.recurrence(x, delta, jnp.exp(delta * rate), b, c)
     rng = np.random.default_rng(0)
     pool = jnp.asarray(rng.normal(size=(2, 6, heads, width, n)), jnp.float32)
     slots = jnp.asarray([4, 0, 2, 0, 5], jnp.int32)     # rows 0, 2, 4 busy
     busy = np.asarray([0, 2, 4])
+    assert ssm_state_update.kernel_serves(heads, width, n) or form == "xla"
 
     def spread(v):
         out = jnp.zeros((5, *v.shape[1:]), v.dtype)
@@ -275,12 +305,9 @@ def test_decode_steps_are_the_recurrence(highest, form):
         a = jnp.exp(delta[:, k] * rate)
         if k == 0:
             a = jnp.zeros_like(a)          # the sequences start here
-        args = (pool, 1, slots, spread(a),
-                spread(delta[:, k, :, None] * x[:, k]), spread(b[:, k]),
-                spread(c[:, k]))
-        if form == "xla":
-            return ssm_state_update.state_update_xla(*args)
-        return ssm_state_update.state_update_kernel(*args, head_tile=4)
+        return _update(form)(
+            pool, 1, slots, spread(a), spread(delta[:, k, :, None] * x[:, k]),
+            spread(b[:, k]), spread(c[:, k]))
 
     before = np.asarray(pool)
     rows = []
@@ -289,14 +316,143 @@ def test_decode_steps_are_the_recurrence(highest, form):
             y, pool = jax.block_until_ready(jax.jit(step, static_argnums=1)(
                 pool, k))
             rows.append(np.asarray(y)[busy])
+    assert pool.shape == before.shape
     assert np.abs(np.stack(rows, 1) - np.asarray(want)).max() <= 1e-5
     after = np.asarray(pool)
-    assert np.abs(after[1, [4, 2, 5]] - np.asarray(last)).max() <= 1e-5
+    assert np.abs(_held(pool, 1, [4, 2, 5]) - np.asarray(last)).max() <= 1e-5
     assert (after[0] == before[0]).all() and (after[1, [1, 3]]
                                               == before[1, [1, 3]]).all()
     if form == "kernel":
         assert (after[1, 0] == before[1, 0]).all()
         assert (np.asarray(y)[[1, 3]] == 0).all()
+
+
+@pytest.mark.parametrize("case", [(8, 16, 32), (64, 64, 128), (4, 8, 16),
+                                  (3, 8, 16)], ids=_ids)
+def test_a_rows_lane_layout_is_its_own_inverse(case):
+    """``to_lanes`` / ``from_lanes`` between the scan's ``[H, P, N]`` and the
+    pool's ``[H P / L, N, L]``: lane groups of 128 where ``H P`` is a
+    multiple of 128, else one group of all ``H P``; value ``(h, p, n)`` lies
+    at group ``(h P + p) // L``, sublane ``n``, lane ``(h P + p) % L``; and
+    ``lane_view`` of the allocation is a reshape of a row's values as they
+    lie."""
+    heads, width, n = case
+    inner = heads * width
+    lanes = ssm_state_update.lane_width(heads, width)
+    assert lanes == (128 if inner % 128 == 0 else inner)
+    assert ssm_state_update.kernel_serves(*case) == (lanes == 128)
+    assert not ssm_state_update.kernel_serves(heads, width, n + 8)
+    rng = np.random.default_rng(1)
+    state = jnp.asarray(rng.normal(size=(2, 3, heads, width, n)), jnp.float32)
+    lying = ssm_state_update.to_lanes(state)
+    assert lying.shape == (2, 3, inner // lanes, n, lanes)
+    assert (ssm_state_update.from_lanes(lying, heads, width) == state).all()
+    column, at = int(rng.integers(inner)), int(rng.integers(n))
+    assert (np.asarray(lying)[:, :, column // lanes, at, column % lanes]
+            == np.asarray(state).reshape(2, 3, inner, n)[:, :, column,
+                                                          at]).all()
+    # the pool as allocated holds those values in that order, flat
+    pool = lying.reshape(state.shape)
+    assert ssm_state_update.lane_view(pool).shape == lying.shape
+    assert (ssm_state_update.lane_view(pool) == lying).all()
+    assert (np.asarray(pool).reshape(-1) == np.asarray(lying).reshape(
+        -1)).all()
+
+
+@pytest.mark.parametrize("order", ["chunk-then-steps", "steps-then-chunk",
+                                   "chunk-steps-chunk"])
+@pytest.mark.parametrize("case", [("xla", 8, 16, 128), ("kernel", 8, 32, 32),
+                                  ("xla", 4, 8, 16)], ids=_ids)
+def test_the_two_writers_of_the_pool_agree_on_its_layout(highest, case,
+                                                         order):
+    """A state WRITTEN by a prefill chunk (``scan_state_out``) and READ by
+    decode steps on the pool in place, and a state left by decode steps and
+    read by a second chunk (``scan_state_in``): twelve positions through
+    the pool equal the plain recurrence one position at a time, every
+    ``y`` and the state at the end."""
+    from deepspeed_tpu.utils.compat import tpu_interpret_mode
+
+    form, heads, width, n = case
+    t = 12
+    x, delta, rate, b, c = _recurrence_inputs(3, t, heads, width, n)
+    want, last = reference.recurrence(x, delta, jnp.exp(delta * rate), b, c)
+    pool = jnp.asarray(np.random.default_rng(0).normal(
+        size=(2, 6, heads, width, n)), jnp.float32)
+    slots = jnp.asarray([4, 2, 5], jnp.int32)
+    pieces = {"chunk-then-steps": [("chunk", 8), ("steps", 4)],
+              "steps-then-chunk": [("steps", 4), ("chunk", 8)],
+              "chunk-steps-chunk": [("chunk", 5), ("steps", 3),
+                                    ("chunk", 4)]}[order]
+
+    def chunk(pool, at, size):
+        piece = lambda v: v[:, at:at + size]
+        state = granite_hybrid.scan_state_in(
+            pool, 1, slots, jnp.full((3,), at == 0))
+        y, state = ssd_chunk_scan(piece(x), piece(delta), rate, piece(b),
+                                  piece(c), state, 4)
+        return y, granite_hybrid.scan_state_out(pool, 1, slots, state)
+
+    def step(pool, k):
+        a = jnp.exp(delta[:, k] * rate)
+        if k == 0:
+            a = jnp.zeros_like(a)
+        y, pool = _update(form)(pool, 1, slots, a,
+                                delta[:, k, :, None] * x[:, k], b[:, k],
+                                c[:, k])
+        return y[:, None], pool
+
+    at, rows = 0, []
+    with tpu_interpret_mode():
+        for kind, size in pieces:
+            if kind == "chunk":
+                y, pool = jax.block_until_ready(jax.jit(
+                    chunk, static_argnums=(1, 2))(pool, at, size))
+                rows.append(np.asarray(y))
+            else:
+                for k in range(at, at + size):
+                    y, pool = jax.block_until_ready(jax.jit(
+                        step, static_argnums=1)(pool, k))
+                    rows.append(np.asarray(y))
+            at += size
+    assert np.abs(np.concatenate(rows, 1) - np.asarray(want)).max() <= 1e-5
+    assert np.abs(_held(pool, 1, slots) - np.asarray(last)).max() <= 1e-5
+
+
+@pytest.mark.parametrize("case", [("xla", 8, 32, 32), ("kernel", 8, 32, 32),
+                                  ("xla", 4, 8, 16)], ids=_ids)
+def test_a_fresh_row_forgets_its_slots_last_tenant_and_an_idle_row_is_left(
+        highest, case):
+    """``a = 0`` on a slot whose last tenant left LARGE values: the state
+    after the step is ``(delta x) B^T`` alone and ``y`` its readout, to the
+    bit of a state of zeros; an idle row's ``y`` is 0 and, under the kernel,
+    no pool row but the busy ones changes by a bit."""
+    from deepspeed_tpu.utils.compat import tpu_interpret_mode
+
+    form, heads, width, n = case
+    rng = np.random.default_rng(4)
+    f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    slots = jnp.asarray([0, 3, 0, 1], jnp.int32)
+    a = jnp.zeros((4, heads), jnp.float32)
+    args = (1, slots, a, f(4, heads, width), f(4, n), f(4, n))
+    pool = 1e6 * (1 + jnp.abs(f(2, 5, heads, width, n)))
+    with tpu_interpret_mode():
+        run = jax.jit(_update(form))
+        y, after = jax.block_until_ready(run(pool, *args))
+        y0, zeros = jax.block_until_ready(run(jnp.zeros_like(pool), *args))
+    busy = np.asarray([1, 3])
+    assert (np.asarray(y)[busy] == np.asarray(y0)[busy]).all()
+    assert (_held(after, 1, [3, 1]) == _held(zeros, 1, [3, 1])).all()
+    dx, b, c = (np.asarray(v)[busy] for v in args[3:])
+    state = dx[..., None] * b[:, None, None, :]
+    assert np.abs(_held(after, 1, [3, 1]) - state).max() <= 1e-6
+    assert np.abs(np.asarray(y)[busy] - np.einsum(
+        "rhpn,rn->rhp", state, c)).max() <= 1e-4
+    before, after = np.asarray(pool), np.asarray(after)
+    assert (after[0] == before[0]).all()
+    assert (after[1, [2, 4]] == before[1, [2, 4]]).all()
+    if form == "kernel":
+        assert (after[1, 0] == before[1, 0]).all()
+        assert (np.asarray(y)[[0, 2]] == 0).all()
 
 
 def test_the_kernels_work_list_puts_the_busy_rows_first():

@@ -600,3 +600,95 @@ def cached_gqa(q, pos, paging, table, pools, widths, label, sink=None,
     out = acc / jnp.where(l == 0.0, 1.0, l)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, t, heads, dv).astype(
         q.dtype)
+
+
+def ring_blocks_for(window: int, block_size: int) -> int:
+    """Blocks in a decode slot's ring of a window layer: the window and one
+    more, so that the block being written never holds a key the window
+    still needs."""
+    return -(-window // block_size) + 1
+
+
+def ring_slot_state(ring: int, knob: str):
+    """``paged_slot_state_for``'s answer for a config whose window layers
+    keep a ring of ``ring`` blocks a decode slot (None for 0: no window
+    layer)."""
+    if not ring:
+        return None
+    return {"entries": ring, "knob": knob,
+            "what": "sliding-window layers keep their keys and values "
+                    "in a ring a decode slot"}
+
+
+def ring_kv_live_bytes(live, ring_rows: int, per_token: dict) -> dict:
+    """Bytes of keys and values a decode step reads, by kind of layer, for
+    busy rows of the lengths ``live``: a global layer every token of a
+    sequence, a window layer what the slot's ring of ``ring_rows`` rows
+    holds; ``per_token``: bytes a token keeps in each kind."""
+    import numpy as np
+
+    return {"global": int(live.sum()) * per_token["global"],
+            "window": int(np.minimum(live, ring_rows).sum())
+            * per_token["window"]}
+
+
+def ring_gqa(q, k, v, pos, paging, table, k_pool, v_pool, index, label,
+             sink=None, work=None, window: int = 0):
+    """:func:`paged_gqa` for a layer that sees the last ``window`` keys
+    (the query's own among them), in the slot's ring ``table [B, ring]``:
+    the table's last entries, whose rows a position takes by arithmetic
+    (``ops/hybrid_decode_attention.py``). A whole prompt
+    (``paging["prefill"]``) attends over its own keys; a decode step on a
+    TPU runs the paged kernel; a prompt's later chunk, and every step where
+    no TPU is, gathers the slot's ring and the step's own rows and takes
+    the masked XLA path. What a head is before it comes here (normed,
+    rotated or neither) is the caller's, as ``label`` is."""
+    from deepspeed_tpu.ops.attention import (record_dispatch,
+                                             use_decode_kernel)
+    from deepspeed_tpu.ops.hybrid_decode_attention import (
+        decode_attention_hybrid, ring_positions)
+
+    b, t = q.shape[:2]
+    kv, bs, ring = k.shape[2], k_pool.shape[2], table.shape[-1]
+    lengths, num_valid = paging["lengths"], paging["num_valid"]
+    # of this step's rows the ring keeps the last (ring - 1) blocks'
+    # worth: enough for the window, and never two rows on one place
+    kept = pos >= (lengths + num_valid)[:, None] - (ring - 1) * bs
+    real = (jnp.arange(t)[None] < num_valid[:, None]) & kept
+    blk = jnp.where(real, jnp.take_along_axis(
+        table, (pos // bs) % ring, axis=1), 0)
+    off = pos % bs
+
+    def write():
+        return (k_pool.at[index, blk, off].set(k.reshape(b, t, -1)),
+                v_pool.at[index, blk, off].set(v.reshape(b, t, -1)))
+
+    def gathered(pool, width):
+        """The ring's blocks of this layer, as rows in table order."""
+        return pool[index, table].reshape(b, -1, kv, width)
+
+    if paging.get("prefill"):
+        record_dispatch(f"{label}_prefill_xla")
+        k_pool, v_pool = write()
+        y = causal_gqa(q, k, v, window, sink)
+    elif t == 1 and use_decode_kernel():
+        record_dispatch(f"{label}_decode_kernel")
+        k_pool, v_pool = write()
+        with jax.named_scope("attn._hybrid_kv_attend"):
+            y = decode_attention_hybrid(
+                q, k_pool, v_pool, table, lengths, index, kv_heads=kv,
+                window=window, ring=True, sink=sink, work=work)
+    else:
+        record_dispatch(f"{label}_cached_xla")
+        # the ring as it stood BEFORE this step's rows, then the rows
+        # themselves: a chunk's own writes would land on keys its
+        # first queries still need
+        held = ring_positions(lengths, ring * bs)
+        y = masked_gqa(
+            q, jnp.concatenate([gathered(k_pool, k.shape[-1]), k], 1),
+            jnp.concatenate([gathered(v_pool, v.shape[-1]), v], 1),
+            pos, jnp.concatenate([held, pos], 1),
+            jnp.concatenate([held >= 0, jnp.arange(t)[None]
+                             < num_valid[:, None]], 1), window, sink)
+        k_pool, v_pool = write()
+    return y, k_pool, v_pool

@@ -14,8 +14,9 @@ A decoder composed of attention KIND x FFN KIND x a per-layer pattern:
 - pre-norm residual blocks, RMSNorm, no bias, an untied head.
 
 The norms, RoPE, SwiGLU, the attention arithmetic, the sparse FFN and the
-decoder shell are ``models/blocks.py``'s; this file holds the config, the
-hybrid attention (the window kind's ring with it) and the two kinds' pools.
+decoder shell are ``models/blocks.py``'s, the window kind's ring
+(``blocks.ring_gqa``) with them; this file holds the config, the hybrid
+attention and the two kinds' pools.
 Layers are unrolled (two kinds of attention and two of FFN do not scan).
 
 SERVING. ``for_paged_decode`` gives the module two pairs of KV pools, one
@@ -37,9 +38,7 @@ import functools
 from typing import Any, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
-import numpy as np
 
 from deepspeed_tpu.models import blocks
 from deepspeed_tpu.moe import dropless
@@ -134,29 +133,22 @@ class MiMoV2Config(blocks.ServedConfig):
         0 without window layers."""
         if not self.layers_of(True):
             return 0
-        return -(-self.sliding_window // block_size) + 1
+        return blocks.ring_blocks_for(self.sliding_window, block_size)
 
     def paged_slot_state_for(self, block_size: int):
         """What a decode slot keeps beside its block table (the engine's
         per-slot seam, ``serving/engine.py``): its ring, ``entries``
         blocks of the window pool. None without window layers."""
-        ring = self.paged_ring_blocks_for(block_size)
-        if not ring:
-            return None
-        return {"entries": ring, "knob": self.slot_knob,
-                "what": "sliding-window layers keep their keys and values "
-                        "in a ring a decode slot"}
+        return blocks.ring_slot_state(
+            self.paged_ring_blocks_for(block_size), self.slot_knob)
 
     def kv_live_bytes(self, live) -> dict:
         """Bytes of keys and values a decode step reads, by kind of layer,
         for busy rows of the lengths ``live``: a global layer every token
         of a sequence, a window layer what the slot's ring holds."""
-        per_token = self.kv_bytes_per_token()
-        held = (self.paged_ring_blocks_for(self.paged_block_size)
-                * self.paged_block_size)
-        return {"global": int(live.sum()) * per_token["global"],
-                "window": int(np.minimum(live, held).sum())
-                * per_token["window"]}
+        return blocks.ring_kv_live_bytes(
+            live, self.paged_ring_blocks_for(self.paged_block_size)
+            * self.paged_block_size, self.kv_bytes_per_token())
 
     def kv_bytes_per_token(self) -> dict:
         """Bytes of keys and values one token keeps, by kind of layer."""
@@ -229,13 +221,15 @@ class HybridAttention(nn.Module):
         """Write this step's keys and values where the kind of layer keeps
         them, and attend: a global layer through the sequence's block table
         (``blocks.paged_gqa``), a window layer in the slot's ring, the
-        table's last entries."""
+        table's last entries (``blocks.ring_gqa``)."""
         cfg = self.config
         tables = paging["block_tables"]
         seq_blocks = tables.shape[-1] - cfg.paged_ring_blocks_for(
             cfg.paged_block_size)
         kind = "window" if self.window else "global"
-        step = self._ring if self.window else blocks.paged_gqa
+        step = (functools.partial(blocks.ring_gqa,
+                                  window=cfg.sliding_window)
+                if self.window else blocks.paged_gqa)
         table = (tables[:, seq_blocks:] if self.window
                  else tables[:, :seq_blocks])
         y, k_pool, v_pool = step(
@@ -243,67 +237,6 @@ class HybridAttention(nn.Module):
             pools[f"{kind}_value_pool"], index, f"mimo_{kind}", sink, work)
         return y, {**pools, f"{kind}_key_pool": k_pool,
                    f"{kind}_value_pool": v_pool}
-
-    def _ring(self, q, k, v, pos, paging, table, k_pool, v_pool, index, label,
-              sink=None, work=None):
-        """``blocks.paged_gqa`` for a window layer, in the slot's ring
-        ``table [B, ring]``. A whole prompt (``paging["prefill"]``) attends
-        over its own keys; a decode step on a TPU runs the paged kernel; a
-        prompt's later chunk, and every step where no TPU is, gathers the
-        slot's ring and the step's own rows and takes the masked XLA
-        path."""
-        from deepspeed_tpu.ops.attention import (record_dispatch,
-                                                 use_decode_kernel)
-        from deepspeed_tpu.ops.hybrid_decode_attention import (
-            decode_attention_hybrid, ring_positions)
-
-        cfg = self.config
-        b, t = q.shape[:2]
-        kv, bs, ring = k.shape[2], cfg.paged_block_size, table.shape[-1]
-        window = cfg.sliding_window
-        lengths, num_valid = paging["lengths"], paging["num_valid"]
-        # of this step's rows the ring keeps the last (ring - 1) blocks'
-        # worth: enough for the window, and never two rows on one place
-        kept = pos >= (lengths + num_valid)[:, None] - (ring - 1) * bs
-        real = (jnp.arange(t)[None] < num_valid[:, None]) & kept
-        blk = jnp.where(real, jnp.take_along_axis(
-            table, (pos // bs) % ring, axis=1), 0)
-        off = pos % bs
-
-        def write():
-            return (k_pool.at[index, blk, off].set(k.reshape(b, t, -1)),
-                    v_pool.at[index, blk, off].set(v.reshape(b, t, -1)))
-
-        def gathered(pool, width):
-            """The ring's blocks of this layer, as rows in table order."""
-            return pool[index, table].reshape(b, -1, kv, width)
-
-        if paging.get("prefill"):
-            record_dispatch(f"{label}_prefill_xla")
-            k_pool, v_pool = write()
-            y = blocks.causal_gqa(q, k, v, window, sink)
-        elif t == 1 and use_decode_kernel():
-            record_dispatch(f"{label}_decode_kernel")
-            k_pool, v_pool = write()
-            with jax.named_scope("attn._hybrid_kv_attend"):
-                y = decode_attention_hybrid(
-                    q, k_pool, v_pool, table, lengths, index, kv_heads=kv,
-                    window=window, ring=True, sink=sink, work=work)
-        else:
-            record_dispatch(f"{label}_cached_xla")
-            # the ring as it stood BEFORE this step's rows, then the rows
-            # themselves: a chunk's own writes would land on keys its
-            # first queries still need
-            held = ring_positions(lengths, ring * bs)
-            y = blocks.masked_gqa(
-                q, jnp.concatenate([gathered(k_pool, cfg.head_dim), k], 1),
-                jnp.concatenate([gathered(v_pool, cfg.v_head_dim), v], 1),
-                pos, jnp.concatenate([held, pos], 1),
-                jnp.concatenate([held >= 0, jnp.arange(t)[None]
-                                 < num_valid[:, None]], 1), window, sink)
-            k_pool, v_pool = write()
-        return y, k_pool, v_pool
-
 
 def SparseExperts(config, **kw):
     """The sparse FFN of a config that says its own routing

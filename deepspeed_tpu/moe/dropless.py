@@ -143,6 +143,25 @@ def width_tile(f: int, tile_f: int = 512) -> int:
     return tile_f
 
 
+_noted_tiles = set()
+
+
+def _note_grouped_tile(rows_shape, gate_shape, tile_rows: int, tile_f: int):
+    """Log the grouped matmul's tile once a shape, while tracing (as the
+    decode kernels log their plans)."""
+    key = (tuple(rows_shape), tuple(gate_shape), tile_rows, tile_f)
+    if key in _noted_tiles:
+        return
+    _noted_tiles.add(key)
+    from deepspeed_tpu.utils.logging import logger
+
+    n_held, d, f = gate_shape
+    logger.info(f"grouped_ffn rows{tuple(rows_shape)} experts"
+                f"{tuple(gate_shape)}: tiles of {tile_rows} rows x {tile_f} "
+                f"columns, {f // tile_f} steps a tile of rows, three blocks "
+                f"of {d} x {tile_f}")
+
+
 def grouped_ffn(rows, tile_expert, live_tiles, gate, up, down, *,
                 tile_rows: int, tile_f: int = 512):
     """``rows [R, D]`` (each tile of ``tile_rows`` rows belongs to expert
@@ -156,6 +175,7 @@ def grouped_ffn(rows, tile_expert, live_tiles, gate, up, down, *,
     if n_rows % tile_rows or f % tile_f:
         raise ValueError(f"{n_rows} rows in tiles of {tile_rows}, width "
                          f"{f} in tiles of {tile_f}")
+    _note_grouped_tile(rows.shape, gate.shape, tile_rows, tile_f)
 
     def at_tile(i, n, expert):
         return (i, 0)

@@ -1146,3 +1146,68 @@ def test_other_cells_serving_programs_are_the_parents_text(
     assert _lowered_program_hash(monkeypatch, one_chip, config,
                                  program) == _PARENT_PROGRAMS[(config,
                                                                program)]
+
+
+# K-EXAONE-236B-A23B at its published widths: 64 query heads over 8 KV heads
+# of 128, keys and values alike, in BOTH kinds of layer (a 1,024-lane pool
+# row for keys and one for values: 2,048 lanes a token a layer), a window of
+# 128 in rings of 5 blocks of 32 with no sink; 16 held experts of 3 x 6144 x
+# 2048; the benchmark cell's 64 decode slots and 512-token chunks
+@pytest.mark.parametrize("kind", ["global", "window"])
+def test_hybrid_decode_kernel_at_one_row_shape_for_both_kinds(one_chip, kind):
+    """The GQA paged kernel at the row shape neither kind had met (1,024 +
+    1,024 lanes): the plan's tile is 16 blocks of a table and the whole
+    ring of 5, as at the narrower rows (two tiles of both pools are 2.1 M
+    values of the 4 M it may hold), the window kind runs with no sink, and
+    the kernel is the ONE instruction the benchmark's reader matches."""
+    from deepspeed_tpu.ops.hybrid_decode_attention import (
+        decode_attention_hybrid, hybrid_plan)
+
+    pattern = _reader_pattern("hybrid_decode_roofline_share")
+    slots, bs, kv, lanes = 64, 32, 8, 8 * 128
+    window = kind == "window"
+    layers, blocks, per_row = ((4, 1 + slots * 5, 5) if window
+                               else (1, 8193, 128))
+    assert hybrid_plan(bs, lanes, lanes, per_row).tile_blocks == (
+        5 if window else 16)
+
+    def step(q, k, v, tables, lengths):
+        with jax.named_scope("attn._hybrid_kv_attend"):
+            return decode_attention_hybrid(
+                q, k, v, tables, lengths, layers - 1, kv_heads=kv,
+                window=128 if window else 0, ring=window)
+
+    text = _compiled_text(
+        step, _s(one_chip, (slots, 1, 64, 128)),
+        _s(one_chip, (layers, blocks, bs, lanes)),
+        _s(one_chip, (layers, blocks, bs, lanes)),
+        _s(one_chip, (slots, per_row), jnp.int32),
+        _s(one_chip, (slots,), jnp.int32))
+    calls = _custom_calls(text)
+    assert len(calls) == 1 and pattern.search(calls[0]), calls
+
+
+@pytest.mark.parametrize("tokens", [64, 512], ids=["decode", "chunk"])
+def test_grouped_expert_kernel_at_6144_rows_deep(one_chip, tokens):
+    """The dropless grouped matmul over 16 held experts of 3 x 6144 x 2048:
+    ``width_tile`` bounds a step's block by COLUMNS (512), so a block is
+    6144 x 512 = 6.3 MB a matrix where the widest met was 4.2 MB: three
+    matrices double-buffered are 37.7 MB under the 96 MB the kernel asks
+    for, and it compiles at a decode step's 64 rows and a chunk's 512."""
+    from deepspeed_tpu.moe.dropless import expert_ffn, width_tile
+
+    assert width_tile(2048) == 512
+    pattern = _reader_pattern("expert_matmul_roofline_share")
+
+    def layer(x, experts, weights, gate, up, down):
+        return expert_ffn(x, experts, weights, gate, up, down,
+                          first_expert=0, n_routed=128, use_kernel=True)
+
+    text = _compiled_text(
+        layer, _s(one_chip, (tokens, 6144)),
+        _s(one_chip, (tokens, 8), jnp.int32),
+        _s(one_chip, (tokens, 8), jnp.float32),
+        _s(one_chip, (16, 6144, 2048)), _s(one_chip, (16, 6144, 2048)),
+        _s(one_chip, (16, 2048, 6144)))
+    calls = _custom_calls(text)
+    assert len(calls) == 1 and pattern.search(calls[0]), calls

@@ -268,7 +268,7 @@ class ServingEngine:
         self._last_tokens = np.zeros((self.config.decode_slots,), np.int32)
         self._lengths = np.zeros((self.config.decode_slots,), np.int32)
         self._prefill_fns: Dict[int, object] = {}
-        self._decode_fn = None
+        self._decode_fn = self._decode_py = self._decode_asked = None
         # the decode loop runs one step ahead (``_decode_step``): the step
         # in flight, the newest decode output (the next step's tokens, on
         # the device), the program that lays the host's tokens over it,
@@ -341,6 +341,9 @@ class ServingEngine:
             f"ServingEngine: slots={self.config.decode_slots} "
             f"block_size={bs} num_blocks={self.num_blocks} "
             f"buckets={self.buckets} max_len={self.max_len}", ranks=[0])
+        # where the weights lie: asked of the decode program and placed
+        # once, before any program is built (``stats()["weight_layouts"]``)
+        self._weight_layouts = self._lay_out_weights()
 
     # ------------------------------------------------------------------
     def _init_cache(self):
@@ -543,7 +546,18 @@ class ServingEngine:
         return self._jit(fn, f"serving_prefill_T{T}",
                          f"serving.prefill[T={T}]")
 
-    def _build_decode(self):
+    def _decode_program(self):
+        """The decode program as a Python function, not yet jitted: what
+        ``_build_decode`` compiles, and what ``_lay_out_weights`` asks how
+        the weights should lie. ONE function object for both, so that the
+        second ``jax.jit`` of it finds the first's trace."""
+        if self._decode_py is None:
+            self._decode_py = self._decode_program_of()
+            self._decode_py.__name__ = self._decode_py.__qualname__ = (
+                "serving_decode")
+        return self._decode_py
+
+    def _decode_program_of(self):
         jnp = self._jnp
         dmodule, dequant = self._dmodule, self.engine._dequantize
         logits_of = self.engine._logits_of
@@ -566,9 +580,7 @@ class ServingEngine:
                                    temps, top_ks, top_ps)
                 return self._with_counters(tok, out), vars_["cache"]
 
-            return self._jit(
-                kfn, "serving_decode",
-                f"serving.decode[slots={self.config.decode_slots}]")
+            return kfn
 
         def fn(qparams, cache, tokens, tables, lengths, rng):
             params = dequant(qparams)
@@ -581,9 +593,124 @@ class ServingEngine:
             return (self._with_counters(self._sample(logits, rng), out),
                     vars_["cache"])
 
-        return self._jit(
-            fn, "serving_decode",
+        return fn
+
+    def _build_decode(self):
+        """The decode program: where ``_lay_out_weights`` asked it how the
+        weights should lie, the executable that answered (the tree lies as
+        it asked: ``weight_layouts.AskedProgram``), unless telemetry
+        watches compiles, which compiles and counts its own."""
+        from deepspeed_tpu.serving.weight_layouts import AskedProgram
+        from deepspeed_tpu.telemetry.jit_watch import WatchedFunction
+
+        jitted = self._jit(
+            self._decode_program(), "serving_decode",
             f"serving.decode[slots={self.config.decode_slots}]")
+        asked, self._decode_asked = self._decode_asked, None
+        if asked is None or isinstance(jitted, WatchedFunction):
+            return jitted
+        return AskedProgram(asked, jitted)
+
+    def _decode_shapes(self):
+        """The decode program's arguments behind the weights, as shapes:
+        what ``_dispatch`` hands it (the pool as it lies, the feed's
+        tokens, a table row and a length a slot, the sampler's tail)."""
+        jax, jnp = self._jax, self._jnp
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        n = self.config.decode_slots
+        s = jax.ShapeDtypeStruct
+        tail = (tuple(s((n,), t) for t in (jnp.uint32, jnp.int32, jnp.float32,
+                                           jnp.int32, jnp.float32))
+                if self._keyed else (s(self._rng.shape, self._rng.dtype),))
+        return (self.cache,
+                s((n, 1), jnp.int32, sharding=NamedSharding(
+                    self.engine.mesh, PartitionSpec())),
+                s(self._tables.shape, jnp.int32), s((n,), jnp.int32), *tail)
+
+    def _asked_weight_formats(self):
+        """How the decode program would have each leaf of the weights lie
+        (``weight_layouts.ask``: one compile, nothing run); the compiled
+        program is kept for ``_build_decode``."""
+        from deepspeed_tpu.serving import weight_layouts
+
+        formats, self._decode_asked = weight_layouts.ask(
+            self._decode_program(), self.engine.params,
+            self._decode_shapes(), donate=self._donate())
+        return formats
+
+    def _lay_out_weights(self) -> dict:
+        """Lay each weight out ONCE as the decode program asks, before any
+        program is built: every program then compiles for the tree as it
+        lies (``jax.jit`` with no layout of its own takes a committed
+        argument's), and none re-lays a weight on every call.
+
+        THE RULE (one for every model, read off compiled programs only): a
+        leaf moves if the decode program, compiled with the layout of every
+        weight left to the compiler, names another layout for it than the
+        leaf has. Every leaf the decode program names, not only those it
+        would copy: compiled for the described chip, no program of any
+        serve cell copies more of its parameters a call over the tree so
+        laid than over the default one (``tools/probe_weight_layouts.py``;
+        the table is in PERF.md, PR 53). A leaf the program does not ask to
+        move stays a plain array in the backend's default layout, and no
+        program pins a format: a leaf replaced later by
+        ``jax.device_put(x, old.sharding)`` is served without a compile.
+
+        The values, shapes and types are untouched (``jax.device_put`` to a
+        ``Format``): ``np.asarray(leaf)``, a checkpoint and a reference
+        jitted over ``engine.params`` see the same arrays bit for bit.
+
+        Not engaged, and the log line says which, where the weights are not
+        plain arrays on one device (``tp_size`` > 1: a layout is a device's
+        own matter and the mesh's programs were not probed; a host tree), a
+        quantised tree (the program reads what ``_dequantize`` rebuilds, not
+        the leaves), or a proposer (the verify program decodes). Returns
+        ``stats()["weight_layouts"]``."""
+        jax = self._jax
+        from deepspeed_tpu.serving import weight_layouts
+
+        flat, treedef = jax.tree_util.tree_flatten_with_path(
+            self.engine.params)
+        counted = dict(weight_layouts.NOT_ENGAGED, leaves=len(flat))
+        why = ("a quantised tree" if self.engine._quantized else
+               "the verify program decodes" if self._proposer is not None
+               else "" if all(
+                   isinstance(leaf, jax.Array)
+                   and len(leaf.sharding.device_set) == 1
+                   for _, leaf in flat) else
+               f"not plain arrays on one device: tp_size "
+               f"{self.engine.mp_world_size}, a mesh of "
+               f"{self.engine.mesh.devices.size}")
+        if why:
+            log_dist(f"weights lie as they came ({why})", ranks=[0])
+            return counted
+        t0 = time.perf_counter()
+        asked = treedef.flatten_up_to(self._asked_weight_formats())
+        t1 = time.perf_counter()
+        names = [weight_layouts.leaf_name(path) for path, _ in flat]
+        leaves = [leaf for _, leaf in flat]
+        # the list is now the tree's only owner inside the engine: a leaf's
+        # old buffer goes as its new one takes its place
+        del flat
+        self.engine.params = None
+        try:
+            moved = weight_layouts.lay_out(leaves, asked)
+        finally:
+            self.engine.params = jax.tree_util.tree_unflatten(treedef,
+                                                              leaves)
+        counted.update(
+            asked_by="serving_decode", leaves_moved=len(moved),
+            bytes_moved=sum(int(leaves[i].nbytes) for i in moved))
+        log_dist(
+            f"weights laid out for serving_decode: {len(moved)} of "
+            f"{len(leaves)} leaves, {counted['bytes_moved']:,} bytes, asked "
+            f"in {t1 - t0:.2f} s and laid in {time.perf_counter() - t1:.2f} s"
+            + (" (" + ", ".join(
+                f"{names[i]} {tuple(leaves[i].shape)} -> "
+                f"{tuple(asked[i].layout.major_to_minor)}"
+                for i in moved) + ")" if moved else ""), ranks=[0])
+        return counted
 
     def _build_feed(self):
         """The decode program's token input, made on the device: the
@@ -1803,6 +1930,9 @@ class ServingEngine:
                 - base[f"kv_live_bytes.{kind}"]
                 for kind in self._kv_kinds},
             "attention_paths": dispatch_counts(),
+            # how many weights the engine laid out at start-up as its
+            # decode program asked, and their bytes (zeros: not engaged)
+            "weight_layouts": dict(self._weight_layouts),
             "prefix_cache": prefix_stats,
             "speculative": spec_stats,
             "finished": s["finished"], "shed": s["shed"],
@@ -1827,6 +1957,7 @@ class ServingEngine:
         self._prefill_fns.clear()
         self._chunk_fns.clear()
         self._decode_fn = self._feed_fn = self._prev_toks = None
+        self._decode_asked = None
         self._cow_fn = None
         self._migrate_fns.clear()
         self._verify_fn = None

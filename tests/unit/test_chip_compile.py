@@ -1211,3 +1211,71 @@ def test_grouped_expert_kernel_at_6144_rows_deep(one_chip, tokens):
         _s(one_chip, (16, 2048, 6144)))
     calls = _custom_calls(text)
     assert len(calls) == 1 and pattern.search(calls[0]), calls
+
+
+# Where served weights lie (PR 53). The bare steps of a serve cell, at its
+# own sizes and published widths (the depth cut to two layers for time),
+# compiled over the tree as the backend lays it and over the tree as
+# ``ServingEngine._lay_out_weights``'s rule lays it (every leaf for which
+# the decode program, its weights' layouts left to the compiler, names
+# another layout than the leaf has): ``tools/probe_weight_layouts.py``.
+_LAID_OUT = {}
+
+
+def _weight_layout_lines(monkeypatch, one_chip, cell_name, layers=2):
+    import functools
+
+    from deepspeed_tpu.moe import dropless
+    from deepspeed_tpu.ops import attention as attn_mod
+    from deepspeed_tpu.serving import weight_layouts
+    from perfbench import run as bench
+    from tools import probe_weight_layouts as probe
+
+    if cell_name not in _LAID_OUT:
+        monkeypatch.setattr(attn_mod, "_FORCE_DECODE_KERNEL", True)
+        monkeypatch.setattr(dropless, "expert_ffn", functools.partial(
+            dropless.expert_ffn, use_kernel=True))
+        cell = bench.load_cell(cell_name)
+        config_file = cell["config_file"]
+        depth = config_file["model"]["num_hidden_layers"]
+        for holder in (config_file["model"], config_file):
+            for key, value in list(holder.items()):
+                if key == "num_hidden_layers":
+                    holder[key] = layers
+                elif isinstance(value, list) and len(value) == depth:
+                    holder[key] = value[:layers]
+        lines = list(probe.probe_cell(cell, one_chip, weight_layouts))
+        _LAID_OUT[cell_name] = ({ln["program"]: ln for ln in lines[:-1]},
+                                lines[-1])
+    return _LAID_OUT[cell_name]
+
+
+@pytest.mark.parametrize("cell_name,program,turned,also", [
+    ("serve-kexaone-reasoning-out", "decode", [8192, 6144], "k_proj"),
+    ("serve-kexaone-reasoning-out", "chunk_T512", [8192, 6144], "k_proj"),
+    ("serve-mimo-hybrid-mixed", "decode", [4096, 12288], "k_proj"),
+])
+def test_no_program_relays_a_weight_the_engine_laid_out(
+        monkeypatch, one_chip, cell_name, program, turned, also):
+    """Over the tree as it lies every layer's ``q_proj`` kernel is copied
+    into the other order on EVERY call (K-EXAONE: 100.7 MB a layer for a
+    matmul of 64 rows; asserted, so that a compiler that stops doing so by
+    itself is noticed and the mechanism can go), and over the tree the
+    engine's rule lays out no program copies any parameter: the decode
+    step that was asked, and the chunk that was not. The leaves the rule
+    moves are the attention's ``q_proj`` and ``k_proj`` kernels, to
+    ``major_to_minor`` (1, 0), and nothing else."""
+    layers = 2
+    programs, cell = _weight_layout_lines(monkeypatch, one_chip, cell_name,
+                                          layers)
+    parent, change = programs[program]["parent"], programs[program]["change"]
+    relaid = [c for c in parent["copies"]
+              if "q_proj" in c[1] and c[2] == turned]
+    assert len(relaid) == layers, parent["copies"]
+    assert parent["parameter_bytes_copied"] >= layers * 2 * turned[0] * \
+        turned[1]
+    assert change == {"parameter_bytes_copied": 0, "copies": []}
+    moved = {m["leaf"].split("/", 1)[1] for m in cell["moved"]}
+    assert moved == {"q_proj/kernel", f"{also}/kernel"}
+    assert cell["leaves_moved"] == 2 * layers
+    assert {tuple(m["major_to_minor"]) for m in cell["moved"]} == {(1, 0)}

@@ -14,6 +14,8 @@ What the chip's compiler makes of the two forms at GPT-2 XL's widths is in
 ``test_chip_compile.py``.
 """
 
+from unittest import mock
+
 import numpy as np
 import pytest
 
@@ -235,7 +237,15 @@ def _served(layout, monkeypatch=None, max_tokens=None, **serving):
         serving={**_SERVING, **serving})
     if layout == "vocab-minor":
         engine.params["wte"] = _vocab_minor(engine.params["wte"])
-    srv = ServingEngine(engine)
+    # the chip's compiler, asked how the weights should lie (PR 53), leaves
+    # the table as it lies (``tools/probe_weight_layouts.py``: XL's decode
+    # program asks ``wpe`` alone to move); the CPU's names row-major for
+    # every leaf, and the engine would put the hand-laid table back
+    with mock.patch.object(
+            ServingEngine, "_asked_weight_formats",
+            lambda self: jax.tree_util.tree_map(lambda x: x.format,
+                                                self.engine.params)):
+        srv = ServingEngine(engine)
     # (building the engine traces the model's ``init`` for the pool's
     # shapes: one ``rows`` that is no program)
     before = dispatch_counts()

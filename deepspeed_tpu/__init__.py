@@ -5,6 +5,10 @@ capability surface of the reference DeepSpeed (``deepspeed/__init__.py``):
 ``initialize()`` / ``init_inference()`` / ``add_config_arguments()``.
 """
 
+import time as _time
+
+_IMPORT_BEGAN = _time.monotonic()  # the start-up ledger's ``import`` stamp
+
 from deepspeed_tpu.version import __version__, __version_info__
 
 from deepspeed_tpu import zero
@@ -315,3 +319,9 @@ def __getattr__(name):
         mod, sym = lazy[name]
         return getattr(importlib.import_module(mod), sym)
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
+
+# the last line: the package's import, into the process's start-up ledger
+from deepspeed_tpu.telemetry import process_ledger as _process_ledger
+
+_process_ledger.LEDGER.stamp_import(_IMPORT_BEGAN, _time.monotonic())

@@ -32,6 +32,7 @@ from deepspeed_tpu.module_inject.policies import get_tp_policy
 from deepspeed_tpu.parallel.topology import (AXIS_DATA, AXIS_MODEL,
                                              MeshTopology, get_topology,
                                              set_topology)
+from deepspeed_tpu.telemetry.manager import constructor_bracket
 from deepspeed_tpu.utils.logging import log_dist
 from deepspeed_tpu.utils.timer import SynchronizedWallClockTimer
 
@@ -178,6 +179,7 @@ class InferenceEngine:
     :class:`GPT2ForTraining`).
     """
 
+    @constructor_bracket("inference_init", span="startup.inference_init")
     def __init__(self,
                  model,
                  config: Optional[DeepSpeedInferenceConfig] = None,

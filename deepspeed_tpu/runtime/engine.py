@@ -55,6 +55,9 @@ from deepspeed_tpu.runtime.zero.partition import (
     build_zero_shardings,
     replicated,
 )
+from deepspeed_tpu.telemetry import process_ledger
+from deepspeed_tpu.telemetry.manager import (constructor_bracket,
+                                             startup_bracket)
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import (
     BACKWARD_GLOBAL_TIMER,
@@ -96,7 +99,8 @@ def _global_norm(tree):
     return jnp.sqrt(jnp.sum(jnp.stack(leaves)))
 
 
-class DeepSpeedEngine:
+class DeepSpeedEngine(process_ledger.FirstCalls):
+    @constructor_bracket("initialize", span="startup.initialize")
     def __init__(self,
                  args=None,
                  model=None,
@@ -549,6 +553,10 @@ class DeepSpeedEngine:
         self._state_shardings = None
         self._jit_micro = None
         self._jit_apply = None
+        # the step programs' call sites that have made no call yet: the
+        # first is bracketed in the process's start-up ledger
+        self._fwd_uncalled = self._apply_uncalled = True
+        self._eval_uncalled = True
         self._param_treedef = None
         self._zero3_program = None  # set as a stage-3 step is traced
         if model_parameters is not None:
@@ -556,7 +564,8 @@ class DeepSpeedEngine:
 
             # shared leniency for direct DeepSpeedEngine(...) construction
             # (initialize() already unwraps for all engine classes)
-            self._build_state(unwrap_variables_dict(model_parameters))
+            with startup_bracket("state", span="startup.state"):
+                self._build_state(unwrap_variables_dict(model_parameters))
 
         log_dist(f"DeepSpeedEngine configured: zero_stage={self.zero_optimization_stage()} "
                  f"mesh={self.topology} micro_batch={self.train_micro_batch_size_per_gpu()} "
@@ -1368,8 +1377,10 @@ class DeepSpeedEngine:
     # public training API
     def _ensure_state(self, batch):
         if self.state is None:
-            params = self._init_params(batch)
-            self._build_state(params)
+            with startup_bracket("params", span="startup.params"):
+                params = self._init_params(batch)
+            with startup_bracket("state", span="startup.state"):
+                self._build_state(params)
 
     def forward(self, batch):
         """Compute loss for a micro-batch (grads computed & accumulated too —
@@ -1401,6 +1412,12 @@ class DeepSpeedEngine:
         # span tracing: the fused fwd+bwd(+reduce) dispatch is ONE
         # host-observable phase (JAX compiles them into one program)
         with self._bracket("fwd_bwd", span="fwd_bwd"):
+            if self._fwd_uncalled:
+                self._fwd_uncalled = False
+                self._first_call(
+                    "train_onebit_step" if self._onebit else
+                    "train_fused_step" if self._fused_step else
+                    "train_micro_step")
             if self._onebit:
                 # fused fwd+bwd+compressed-update program, staged on the
                 # optimizer's warmup/compression flag
@@ -1412,6 +1429,8 @@ class DeepSpeedEngine:
                 self._fused_meta = (overflow, grad_norm)
             else:
                 self.state, loss = self._jit_micro(self.state, batch)
+            if self._first_open:
+                self._first_result_of(loss)
         self._last_loss = loss
         if self.wall_clock_breakdown_:
             self.timers(FORWARD_GLOBAL_TIMER).stop()
@@ -1520,8 +1539,13 @@ class DeepSpeedEngine:
                         self._last_grad_norm = self._fused_meta[1]
                         self._last_overflow = self._fused_meta[0]
                 else:
+                    if self._apply_uncalled:
+                        self._apply_uncalled = False
+                        self._first_call("train_apply_step")
                     self.state, overflow, grad_norm = self._jit_apply(
                         self.state, self._lr_override())
+                    if self._first_open:
+                        self._first_result_of(grad_norm)
                     self._last_grad_norm = grad_norm
                     self._last_overflow = overflow
             self.global_steps += 1
@@ -1554,6 +1578,10 @@ class DeepSpeedEngine:
                 micro_steps=self.micro_steps + 1)
             self._report_progress()
             self.tput_timer.stop(global_step=True)
+            if process_ledger.LEDGER.ready_at is None:
+                # the first optimizer step's boundary: training is ready,
+                # and the process's start-up ledger closes
+                process_ledger.LEDGER.ready("training", self.telemetry)
         else:
             self.tput_timer.stop(global_step=False)
         self.micro_steps += 1
@@ -1637,6 +1665,12 @@ class DeepSpeedEngine:
             losses.append(loss)
         return float(sum(float(l) for l in losses)) / gas
 
+    def _first_result_of(self, on_device):
+        """The first result of a program whose first call is open: on the
+        host, and the bracket closed. (Only that one call waits.)"""
+        jax.block_until_ready(on_device)
+        self._first_result()
+
     def eval_batch(self, batch):
         """Loss without touching grads/state."""
         batch = self._shard_batch(batch)
@@ -1659,7 +1693,13 @@ class DeepSpeedEngine:
                         in_shardings=(self._state_shardings.params, None),
                         out_shardings=replicated(self.mesh)),
                 "engine.eval_step")
-        return self._jit_eval(self.state.params, batch)
+        if self._eval_uncalled:
+            self._eval_uncalled = False
+            self._first_call("train_eval_step")
+        loss = self._jit_eval(self.state.params, batch)
+        if self._first_open:
+            self._first_result_of(loss)
+        return loss
 
     def _report_progress(self):
         if (self.wall_clock_breakdown_
@@ -2335,6 +2375,10 @@ class DeepSpeedEngine:
             # there avoids touching the live loader on every restore
             "data_pipeline": (self._data_pipeline_state()
                               if include_data else None),
+            # where the process's start went, to the first optimizer
+            # step's boundary (telemetry/process_ledger.py); no part of
+            # the saved-vs-current diff
+            "startup": process_ledger.LEDGER.snapshot(),
         }
         if self.state is not None:
             manifest["rng"] = [

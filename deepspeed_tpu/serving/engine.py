@@ -78,6 +78,7 @@ detected stall while an idle server is never judged hung.
 """
 
 import collections
+import heapq
 import time
 from typing import Dict, List, Optional
 
@@ -91,12 +92,23 @@ from deepspeed_tpu.serving.prefix_cache import PrefixCache
 from deepspeed_tpu.serving.request import FINISHED, Request
 from deepspeed_tpu.serving.scheduler import ContinuousBatchingScheduler
 from deepspeed_tpu.serving.spec_decode import build_proposer
+from deepspeed_tpu.telemetry import process_ledger
+from deepspeed_tpu.telemetry.manager import (constructor_bracket,
+                                             startup_bracket)
 from deepspeed_tpu.telemetry.tracing import StepTrace, end_span, to_ns
-from deepspeed_tpu.utils.logging import log_dist
+from deepspeed_tpu.utils.logging import log_dist, logger
 
 
 # the bracketed phases that tile a scheduler iteration: the ledger's keys
 _PHASES = ("schedule", "prefill", "decode", "emit")
+# what a slow step's row says of itself: the phases, and inside prefill and
+# decode the staging and program call against the wait for the tokens
+_STEP_PARTS = _PHASES + ("dispatch", "sync")
+
+# the slowest steps of the stats window that ``stats()["slow_steps"]``
+# keeps, and the wall time from which a step says so in the log
+SLOW_STEPS_KEPT = 8
+SLOW_STEP_LOG_MS = 250.0
 
 # an idle slot's row of the keyed sampler's five arrays (seed, flag,
 # temperature, top-k, top-p)
@@ -113,7 +125,8 @@ def _model_window(model_config) -> Optional[int]:
             or getattr(model_config, "max_position_embeddings", None))
 
 
-class ServingEngine:
+class ServingEngine(process_ledger.FirstCalls):
+    @constructor_bracket("serving_init", span="startup.serving_init")
     def __init__(self, model_or_engine, config=None, draft_model=None,
                  clock=time.monotonic, **kwargs):
         import jax
@@ -222,8 +235,11 @@ class ServingEngine:
         # holds two snapshots of it); stats() reports it against the
         # base reset_stats() takes, the registry gets what it gained
         # since the last publish.
-        self._ledger = {**dict.fromkeys(_PHASES, 0.0), "prefill_calls": 0,
-                        "busy_slot_steps": 0,
+        self._ledger = {**dict.fromkeys(_PHASES, 0.0), "step": 0.0,
+                        # inside prefill and decode: staging and the
+                        # program call; the wait for its tokens
+                        "dispatch": 0.0, "sync": 0.0,
+                        "prefill_calls": 0, "busy_slot_steps": 0,
                         # decode steps dispatched while another was still
                         # in flight, and rows fetched and not delivered
                         "decode_ahead_steps": 0, "decode_dropped_rows": 0}
@@ -248,6 +264,16 @@ class ServingEngine:
         self._ledger_base = dict(self._ledger)
         self._ledger_published = dict(self._ledger)
         self._busy = 0  # active slots of the latest decode step
+        # the slowest steps of the stats window (``_note_step``): a heap of
+        # (wall + seam, count, row); and where the last step ended: its
+        # end, the process's idle, collector seconds and first calls as
+        # they stood then, and whether it left work behind
+        self._slow: list = []
+        self._gc_base = process_ledger.LEDGER.host_pauses()
+        self._gc_published = process_ledger.LEDGER.gc["pause_secs"]
+        self._step_calls = 0
+        self._last_end = (0.0, 0.0, self._gc_published,
+                          process_ledger.LEDGER.first_calls, False)
         # THE bracket around every host phase of the step loop
         # (telemetry/tracing.py Brackets): ds.serve.<phase> on the
         # profiler's clock always, the JSONL span under tracing, the
@@ -261,7 +287,8 @@ class ServingEngine:
             clock=self.clock, prefix_cache=self.prefix,
             tracer=self._tracer)
 
-        self.cache = self._init_cache()
+        with startup_bracket("pool", span="startup.pool"):
+            self.cache = self._init_cache()
         self._tables = np.full(
             (self.config.decode_slots,
              self.blocks_per_seq + self.slot_entries), 0, np.int32)
@@ -343,7 +370,9 @@ class ServingEngine:
             f"buckets={self.buckets} max_len={self.max_len}", ranks=[0])
         # where the weights lie: asked of the decode program and placed
         # once, before any program is built (``stats()["weight_layouts"]``)
-        self._weight_layouts = self._lay_out_weights()
+        with startup_bracket("weight_layouts",
+                             span="startup.weight_layouts") as ph:
+            self._weight_layouts = self._lay_out_weights(ph)
 
     # ------------------------------------------------------------------
     def _init_cache(self):
@@ -639,7 +668,7 @@ class ServingEngine:
             self._decode_shapes(), donate=self._donate())
         return formats
 
-    def _lay_out_weights(self) -> dict:
+    def _lay_out_weights(self, ph) -> dict:
         """Lay each weight out ONCE as the decode program asks, before any
         program is built: every program then compiles for the tree as it
         lies (``jax.jit`` with no layout of its own takes a committed
@@ -685,9 +714,12 @@ class ServingEngine:
         if why:
             log_dist(f"weights lie as they came ({why})", ranks=[0])
             return counted
-        t0 = time.perf_counter()
-        asked = treedef.flatten_up_to(self._asked_weight_formats())
-        t1 = time.perf_counter()
+        # (the seconds come from the start-up bracket ``ph`` this runs in,
+        # ``ds.startup.weight_layouts``, and its clock)
+        clock = process_ledger.LEDGER.clock
+        with process_ledger.LEDGER.building("serving_decode"):
+            asked = treedef.flatten_up_to(self._asked_weight_formats())
+        t1 = clock()
         names = [weight_layouts.leaf_name(path) for path, _ in flat]
         leaves = [leaf for _, leaf in flat]
         # the list is now the tree's only owner inside the engine: a leaf's
@@ -705,7 +737,7 @@ class ServingEngine:
         log_dist(
             f"weights laid out for serving_decode: {len(moved)} of "
             f"{len(leaves)} leaves, {counted['bytes_moved']:,} bytes, asked "
-            f"in {t1 - t0:.2f} s and laid in {time.perf_counter() - t1:.2f} s"
+            f"in {t1 - ph.t0:.2f} s and laid in {clock() - t1:.2f} s"
             + (" (" + ", ".join(
                 f"{names[i]} {tuple(leaves[i].shape)} -> "
                 f"{tuple(asked[i].layout.major_to_minor)}"
@@ -741,6 +773,20 @@ class ServingEngine:
         return self.engine.telemetry.watch_jit(
             jax.jit(serving_decode_feed, in_shardings=everywhere,
                     out_shardings=everywhere), "serving.decode_feed"), zeros
+
+    def _first_feed(self):
+        """The feed program's miss path: built and, where this is its
+        first call in the process, run once over its own zeros inside its
+        ``program`` bracket (a call of its own: in the loop it is called
+        inside the decode program's dispatch, and that program's first
+        call has a bracket of its own)."""
+        self._first_call("serving_decode_feed")
+        self._feed_fn, self._prev_toks = self._build_feed()
+        if self._first_open:
+            self._jax.block_until_ready(self._feed_fn(
+                self._prev_toks,
+                np.full((self.config.decode_slots,), -1, np.int32)))
+            self._first_result()
 
     def _build_chunk(self, T: int):
         """One prefill chunk: write ``num_valid`` prompt tokens at the
@@ -915,8 +961,12 @@ class ServingEngine:
         requests finished this step."""
         # (what a flush between two calls finished is reported here too)
         done, self._late = self._late, []
-        with self._bracket("step", step=self._step_count + 1,
-                           busy=self._busy, queue_depth=len(self.sched.queue)):
+        led = self._ledger
+        before = (led["schedule"], led["prefill"], led["decode"],
+                  led["emit"], led["dispatch"], led["sync"])
+        with self._bracket("step", ledger="step", step=self._step_count + 1,
+                           busy=self._busy,
+                           queue_depth=len(self.sched.queue)) as whole:
             with self._bracket("schedule", span="schedule",
                                ledger="schedule") as ph:
                 now = ph.t0
@@ -946,12 +996,65 @@ class ServingEngine:
                 # the step in flight has no taker left (eos, a cancel,
                 # the deadline sweep): fetched now, its rows dropped
                 self._decode_step(done, ahead=False)
+        self._note_step(whole.t0, whole.t1, before)
         if self._step_trace.enabled:
             g = self.sched.gauges()
             self._step_trace.flush(self._step_count,
                                    busy=g.get("slots_busy"),
                                    queue_depth=g.get("queue_depth"))
         return done
+
+    def _note_step(self, t0: float, t1: float, before: tuple):
+        """Keep the step among the slowest of the stats window if it is
+        one, from the two clock reads its ``step`` bracket made: its wall
+        time, and the SEAM before it (the last step's end to this one's
+        start, less what the gateway's pump spent idle for want of work;
+        none after a step that left the engine with nothing to do).
+        A few subtractions a step; the row, with the phases' seconds as
+        differences of the ledger, is built only for a step that enters
+        the kept ones. One warning line for a step over
+        ``SLOW_STEP_LOG_MS``: an untraced run that stalls says in its own
+        log which step stood still, and in which phase."""
+        proc = process_ledger.LEDGER
+        idle, gc_secs = proc.seconds["pump_idle"], proc.gc["pause_secs"]
+        # (a step that leaves nothing in flight, prefilling or queued
+        # leaves an engine idle for want of work: no seam after it)
+        last, self._last_end = self._last_end, (
+            t1, idle, gc_secs, proc.first_calls,
+            bool(self._flight is not None or self._prefilling
+                 or self.sched.queue))
+        self._step_calls += 1
+        wall = t1 - t0
+        seam = max(t0 - last[0] - (idle - last[1]), 0.0) if last[4] else 0.0
+        slow = self._slow
+        if len(slow) == SLOW_STEPS_KEPT and wall + seam <= slow[0][0]:
+            return
+        led = self._ledger
+        # (a step that held a program's first call compiled or loaded it)
+        first_call = proc.first_calls != last[3]
+        row = {"at_s": round(t0 - proc.started_at, 6),
+               "step": self._step_calls,
+               "wall_ms": round(1e3 * wall, 3),
+               "seam_ms": round(1e3 * seam, 3),
+               **{f"{name}_ms": round(1e3 * (led[name] - was), 3)
+                  for name, was in zip(_STEP_PARTS, before)},
+               "gc_ms": round(1e3 * (gc_secs - last[2]), 3),
+               "busy": self._busy, "queue_depth": len(self.sched.queue),
+               "first_call": first_call}
+        entry = (wall + seam, self._step_calls, row)
+        if len(slow) < SLOW_STEPS_KEPT:
+            heapq.heappush(slow, entry)
+        else:
+            heapq.heapreplace(slow, entry)
+        if 1e3 * (wall + seam) > SLOW_STEP_LOG_MS and not first_call:
+            logger.warning(
+                f"serving step {row['step']} took {row['wall_ms']:.0f} ms "
+                f"after a seam of {row['seam_ms']:.0f} ms: schedule "
+                f"{row['schedule_ms']:.0f}, prefill {row['prefill_ms']:.0f}, "
+                f"decode {row['decode_ms']:.0f}, emit {row['emit_ms']:.0f}, "
+                f"gc {row['gc_ms']:.0f}; of prefill and decode, dispatch "
+                f"{row['dispatch_ms']:.0f} and sync {row['sync_ms']:.0f} "
+                f"(busy {row['busy']}, queued {row['queue_depth']})")
 
     def _begin(self, slot: int, req: Request, table: np.ndarray,
                done: List[Request]):
@@ -981,12 +1084,13 @@ class ServingEngine:
         jnp = self._jnp
         T = bucket_for(req.prompt_len, self.buckets)
         if T not in self._prefill_fns:
+            self._first_call(f"serving_prefill_T{T}")
             self._prefill_fns[T] = self._build_prefill(T)
         with self._bracket("prefill", span="prefill", trace=req.trace,
                            ledger="prefill", bucket=T,
                            prompt_len=req.prompt_len,
                            request_id=req.request_id) as ph:
-            with self._bracket("prefill.dispatch"):
+            with self._bracket("prefill.dispatch", ledger="dispatch"):
                 ids = np.zeros((1, T), np.int32)
                 ids[0, :req.prompt_len] = req.prompt
                 tail = (self._req_samp_args(req) if self._keyed
@@ -995,8 +1099,10 @@ class ServingEngine:
                     self.engine.params, self.cache, jnp.asarray(ids),
                     jnp.asarray(table[None]),
                     jnp.asarray([req.prompt_len], jnp.int32), *tail)
-            with self._bracket("prefill.sync"):
+            with self._bracket("prefill.sync", ledger="sync"):
                 tok = np.asarray(tok)
+        if self._first_open:
+            self._first_result()
         self._count("prefill", tok[1:])
         self._keep_routed(req, self._routed(tok, 1), req.prompt_len)
         tok = int(tok[0])
@@ -1058,11 +1164,12 @@ class ServingEngine:
                     step_len: int, T: int) -> int:
         jnp = self._jnp
         if T not in self._chunk_fns:
+            self._first_call(f"serving_chunk_T{T}")
             self._chunk_fns[T] = self._build_chunk(T)
         with self._bracket("prefill", span="prefill_chunk", trace=req.trace,
                            ledger="prefill", pos=pos, tokens=step_len,
                            bucket=T, request_id=req.request_id) as ph:
-            with self._bracket("prefill.dispatch"):
+            with self._bracket("prefill.dispatch", ledger="dispatch"):
                 ids = np.zeros((1, T), np.int32)
                 ids[0, :step_len] = req.prompt[pos:pos + step_len]
                 tail = (self._req_samp_args(req) if self._keyed
@@ -1071,8 +1178,10 @@ class ServingEngine:
                     self.engine.params, self.cache, jnp.asarray(ids),
                     jnp.asarray(table[None]), jnp.asarray([pos], jnp.int32),
                     jnp.asarray([step_len], jnp.int32), *tail)
-            with self._bracket("prefill.sync"):
+            with self._bracket("prefill.sync", ledger="sync"):
                 tok = np.asarray(tok)
+        if self._first_open:
+            self._first_result()
         self._count("prefill", tok[1:])
         self._keep_routed(req, self._routed(tok, 1), step_len)
         self._prefill_done(req, ph)
@@ -1102,12 +1211,14 @@ class ServingEngine:
                 self._finish(req, reason, self.clock(), done)
 
     def _ledger_mark(self):
-        """The four ledger numbers a request's record is made from,
-        O(1): seconds in prefill and decode brackets, decode steps and
-        busy-slot steps, all cumulative."""
+        """The five numbers a request's record is made from, O(1):
+        seconds in prefill and decode brackets, decode steps and busy-slot
+        steps, and the process's seconds inside the garbage collector, all
+        cumulative."""
         led = self._ledger
         return (led["prefill"], led["decode"], self._step_count,
-                led["busy_slot_steps"])
+                led["busy_slot_steps"],
+                process_ledger.LEDGER.gc["pause_secs"])
 
     def _mark_live(self, req: Request, now: float):
         """The request joins the decode batch at ``now``: its first
@@ -1115,10 +1226,10 @@ class ServingEngine:
         flight is counted at its fetch, inside this request's decode
         life, and is none of its steps: the mark counts it already."""
         req.first_token_ts = now
-        prefill, decode, steps, busy = self._ledger_mark()
+        prefill, decode, steps, busy, gc_secs = self._ledger_mark()
         if self._flight is not None:
             steps, busy = steps + 1, busy + len(self._flight.pairs)
-        req.live_mark = (prefill, decode, steps, busy)
+        req.live_mark = (prefill, decode, steps, busy, gc_secs)
 
     def _set_samp_slot(self, slot: int, req: Request):
         """Load one slot's sampling row from the request's (resolved)
@@ -1153,15 +1264,16 @@ class ServingEngine:
         one step is in flight on return (``step()`` then fetches one
         that no running sequence needs any more). ``ahead=False`` only
         fetches the step in flight: the flush (``_flush``)."""
-        if self._decode_fn is None:
-            self._decode_fn = self._build_decode()
         if self._feed_fn is None:
-            self._feed_fn, self._prev_toks = self._build_feed()
+            self._first_feed()
+        if self._decode_fn is None:
+            self._first_call("serving_decode")
+            self._decode_fn = self._build_decode()
         ready = self._decode_ready()
         with self._bracket("decode", span="decode_step", ledger="decode",
                            active=len(ready)) as ph:
             if ahead:
-                with self._bracket("decode.dispatch"):
+                with self._bracket("decode.dispatch", ledger="dispatch"):
                     # (the call that finds nothing in flight dispatches
                     # the step it will fetch, then the one ahead of it)
                     flight = (self._flight if self._flight is not None
@@ -1169,11 +1281,13 @@ class ServingEngine:
                     self._flight = self._dispatch(flight, ready)
             else:
                 flight, self._flight = self._flight, None
-            with self._bracket("decode.sync"):
+            with self._bracket("decode.sync", ledger="sync"):
                 # the ONE designed host sync per decode step: sampled
                 # tokens must reach the host to stream to callers and
                 # drive finish logic
                 toks = np.asarray(flight.toks)  # graft-lint: disable=GL04
+        if self._first_open:
+            self._first_result()
         now = ph.t1
         # counted here, at the fetch, from the fetched step's own view
         self._count("decode", toks[len(self._lengths):])
@@ -1306,6 +1420,7 @@ class ServingEngine:
         # plain path never runs beside it, so nothing is ever in flight
         assert self._flight is None
         if self._verify_fn is None:
+            self._first_call("serving_verify")
             self._verify_fn = self._build_verify()
         k = self.spec_k
         active = self._decode_ready()
@@ -1340,16 +1455,18 @@ class ServingEngine:
                 "speculative grant without a device table update"
         with self._bracket("decode", span="decode_step", ledger="decode",
                            active=len(active)) as ph:
-            with self._bracket("decode.dispatch"):
+            with self._bracket("decode.dispatch", ledger="dispatch"):
                 toks, self.cache = self._verify_fn(
                     self.engine.params, self.cache, jnp.asarray(tokens),
                     jnp.asarray(self._tables), jnp.asarray(self._lengths),
                     jnp.asarray(num_valid), self._next_rng())
-            with self._bracket("decode.sync"):
+            with self._bracket("decode.sync", ledger="sync"):
                 # the ONE designed host sync per decode step (same
                 # contract as the non-speculative loop): verified tokens
                 # drive commit/finish
                 toks = np.asarray(toks)  # graft-lint: disable=GL04
+        if self._first_open:
+            self._first_result()
         t0, now = ph.t0, ph.t1
         # chaos seam: a replica killed BETWEEN verify and commit has
         # emitted nothing from this window — host state is exactly the
@@ -1550,6 +1667,11 @@ class ServingEngine:
             now["decode_ahead_steps"] - was["decode_ahead_steps"])
         m.counter("ds_serving_decode_dropped_rows_total").inc(
             now["decode_dropped_rows"] - was["decode_dropped_rows"])
+        # (the process's, not the engine's: published by whoever steps)
+        gc_secs = process_ledger.LEDGER.gc["pause_secs"]
+        m.counter("ds_host_gc_pause_seconds_total").inc(
+            max(gc_secs - self._gc_published, 0.0))
+        self._gc_published = gc_secs
 
     # ------------------------------------------------------------------
     def cancel(self, request_id: str, reason: str = "cancelled") -> bool:
@@ -1859,6 +1981,8 @@ class ServingEngine:
         self._window_prompt_tokens = 0
         self._window_hit_tokens = 0
         self._ledger_base = dict(self._ledger)
+        self._slow = []
+        self._gc_base = process_ledger.LEDGER.host_pauses()
         self.sched.reset_stats()
 
     def stats(self) -> dict:
@@ -1933,6 +2057,15 @@ class ServingEngine:
             # how many weights the engine laid out at start-up as its
             # decode program asked, and their bytes (zeros: not engaged)
             "weight_layouts": dict(self._weight_layouts),
+            # the PROCESS's ledger (telemetry/process_ledger.py): where the
+            # seconds from the process's start to ``ready`` went (never
+            # reset), the collector's pauses over the stats window, and
+            # the window's slowest steps by phase, slowest first
+            "startup": process_ledger.LEDGER.snapshot(),
+            "host_pauses": process_ledger.LEDGER.host_pauses(
+                since=self._gc_base),
+            "slow_steps": [row for _, _, row in sorted(self._slow,
+                                                       reverse=True)],
             "prefix_cache": prefix_stats,
             "speculative": spec_stats,
             "finished": s["finished"], "shed": s["shed"],

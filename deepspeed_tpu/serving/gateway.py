@@ -46,6 +46,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from deepspeed_tpu.serving.config import GatewayConfig
 from deepspeed_tpu.serving.tenancy import Tenant, TenantTable
+from deepspeed_tpu.telemetry import process_ledger
 from deepspeed_tpu.telemetry.registry import NULL_REGISTRY
 from deepspeed_tpu.telemetry.prom import CONTENT_TYPE
 from deepspeed_tpu.telemetry.tracing import (NULL_TRACER, Brackets,
@@ -187,9 +188,13 @@ class ServingGateway:
         # backend's telemetry hands in the annotation factory; a backend
         # with none gets a bracket with no profiler sink (this module
         # never imports jax, GL01)
+        # (its one ledger key, ``pump_idle``, is the process's: a step
+        # loop takes it off the seam before a step, process_ledger.py)
         brackets = getattr(self.telemetry, "brackets", None)
-        self._bracket = (brackets("gateway", clock=clock) if brackets
-                         else Brackets("gateway", clock=clock))
+        idle = process_ledger.LEDGER.seconds
+        self._bracket = (brackets("gateway", clock=clock, ledger=idle)
+                         if brackets
+                         else Brackets("gateway", clock=clock, ledger=idle))
         self.tenants = TenantTable(self.config, clock=clock)
         self._routerlike = (hasattr(backend, "overload")
                             or hasattr(backend, "router"))
@@ -216,6 +221,15 @@ class ServingGateway:
     def start(self) -> "ServingGateway":
         if self._server is not None:
             return self
+        with process_ledger.LEDGER.startup_bracket(
+                "gateway_start", span="startup.gateway_start"):
+            self._start()
+        # serving is ready: the process's start-up ledger closes (the
+        # first gateway of a process; later ones change nothing)
+        process_ledger.LEDGER.ready("serving", self.telemetry)
+        return self
+
+    def _start(self):
         server = ThreadingHTTPServer((self.config.host, self.config.port),
                                      _Handler)
         server.daemon_threads = True
@@ -229,7 +243,6 @@ class ServingGateway:
             self._pump_thread = threading.Thread(
                 target=self._pump, name="ds-gateway-pump", daemon=True)
             self._pump_thread.start()
-        return self
 
     @property
     def port(self) -> int:
@@ -286,11 +299,15 @@ class ServingGateway:
         while self._running:
             # nothing pending: idle for want of work, which a trace must
             # tell apart from a slow host loop
-            with self._bracket("pump_idle"):
+            with self._bracket("pump_idle", ledger="pump_idle"):
                 self._wake.wait(self.config.poll_secs)
-            self._wake.clear()
-            while self._running and (self.pending or self._cancels):
-                self.step()
+            # wake -> nothing pending: the longest of the nested brackets,
+            # so it names an idle gap only where nothing inside it does
+            # (the seam between two steps)
+            with self._bracket("pump_turn"):
+                self._wake.clear()
+                while self._running and (self.pending or self._cancels):
+                    self.step()
 
     def _drain_cancels(self):
         with self._lock:

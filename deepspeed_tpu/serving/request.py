@@ -74,8 +74,9 @@ class Request:
     accepted_tokens: int = 0          # drafts the target model agreed with
     # ---- the engine's ledger at two moments (serving/engine.py): when
     # the request joined the decode batch and when it finished, each
-    # (prefill secs, decode secs, decode steps, busy-slot steps), all
-    # cumulative; prefill_secs is the time inside its OWN prefill calls.
+    # (prefill secs, decode secs, decode steps, busy-slot steps, the
+    # process's seconds inside the collector), all cumulative;
+    # prefill_secs is the time inside its OWN prefill calls.
     # record() turns the pair into where its decode life went. ----
     prefill_secs: float = 0.0
     # under serving.routed_experts_kept: the chosen experts of each call
@@ -123,12 +124,16 @@ class Request:
         (``decode_ms``), inside OTHER requests' prefills
         (``blocked_ms``), and the rest, the host loop (``host_ms``);
         the three add up to ``finish_ts - first_token_ts`` by
-        construction. All None for a request that never went live or
-        never finished (shed, cancelled, migrated away)."""
+        construction. ``gc_ms`` is the process's time inside the garbage
+        collector over the same life: a PART of the three (of whichever
+        bracket the collection fell in), not a fourth term. All None for
+        a request that never went live or never finished (shed,
+        cancelled, migrated away)."""
         if self.live_mark is None or self.finish_mark is None:
             return dict.fromkeys(("prefill_ms", "decode_steps", "decode_ms",
-                                  "blocked_ms", "host_ms", "batch_mean"))
-        prefill, decode, steps, busy = (
+                                  "blocked_ms", "host_ms", "batch_mean",
+                                  "gc_ms"))
+        prefill, decode, steps, busy, gc_secs = (
             b - a for a, b in zip(self.live_mark, self.finish_mark))
         life = max(self.finish_ts - self.first_token_ts, 0.0)
         return {
@@ -138,6 +143,7 @@ class Request:
             "blocked_ms": round(1e3 * prefill, 3),
             "host_ms": round(1e3 * (life - decode - prefill), 3),
             "batch_mean": round(busy / steps, 3) if steps else None,
+            "gc_ms": round(1e3 * gc_secs, 3),
         }
 
     def record(self) -> dict:

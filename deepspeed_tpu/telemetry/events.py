@@ -113,6 +113,21 @@ SPANS = (
     "fwd_bwd",        # fused forward+backward(+in-graph reduce) dispatch
     "optimizer",      # optimizer apply dispatch
     "ckpt_io",        # checkpoint save/load IO (own trace, between steps)
+    # process level (telemetry/process_ledger.py): one trace a process,
+    # emitted at ``ready`` from the timestamps the start-up ledger kept
+    "startup",        # root — the process's start (as the OS has it) ->
+    #                   ready (attrs: ready_s, outside_s)
+    "startup.import",          # deepspeed_tpu/__init__.py, first -> last line
+    "startup.inference_init",  # InferenceEngine.__init__
+    "startup.serving_init",    # ServingEngine.__init__
+    "startup.pool",            # the KV pool's allocation (in serving_init)
+    "startup.weight_layouts",  # weights laid out as decode asks (likewise)
+    "startup.gateway_start",   # ServingGateway.start()
+    "startup.initialize",      # DeepSpeedEngine.__init__
+    "startup.params",          # sharded parameter init
+    "startup.state",           # optimizer/train state build
+    "startup.program",         # one program's FIRST call: build -> first
+    #                            result on the host (attrs: program)
 )
 
 # the span event envelope's reserved ``data`` keys — everything else in

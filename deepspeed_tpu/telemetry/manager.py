@@ -23,10 +23,11 @@ Collectors (tentpole contract, ISSUE 2):
    exactly ``num_steps`` steps, with markers in the event stream.
 """
 
+import functools
 import os
 from typing import Dict, Optional
 
-from deepspeed_tpu.telemetry import compile_watch
+from deepspeed_tpu.telemetry import compile_watch, process_ledger
 from deepspeed_tpu.telemetry.events import make_event
 from deepspeed_tpu.telemetry.jit_watch import (WatchedFunction,
                                                compiled_cost_summary)
@@ -35,6 +36,29 @@ from deepspeed_tpu.telemetry.sink import JsonlSink, MonitorBridge
 from deepspeed_tpu.telemetry.tracing import (NULL_TRACER, Brackets,
                                              StepTrace, Tracer)
 from deepspeed_tpu.utils.logging import log_dist, logger
+
+
+def startup_bracket(phase: str, span: Optional[str] = None, **attrs):
+    """``with startup_bracket("pool", span="startup.pool")``: one bracket
+    of the process's start-up ledger (``telemetry/process_ledger.py``).
+    That module is jax-free: the annotation factory is handed in from
+    here, once a process."""
+    import jax
+
+    return process_ledger.install(
+        jax.profiler.TraceAnnotation).startup_bracket(phase, span=span,
+                                                      **attrs)
+
+
+def constructor_bracket(phase: str, span: Optional[str] = None):
+    """An engine's ``__init__`` inside :func:`startup_bracket`."""
+    def around(init):
+        @functools.wraps(init)
+        def __init__(self, *args, **kwargs):
+            with startup_bracket(phase, span=span):
+                init(self, *args, **kwargs)
+        return __init__
+    return around
 
 
 def _as_config(config):
@@ -448,6 +472,7 @@ class Telemetry:
         jax-free, so the annotation factory is handed in from here."""
         import jax
 
+        process_ledger.install(jax.profiler.TraceAnnotation)
         return Brackets(layer, annotate=jax.profiler.TraceAnnotation,
                         tracer=self.tracer,
                         step_trace=step_trace or self.step_trace,

@@ -72,6 +72,14 @@ NAMES = {
         "counter", "flight-recorder dumps written, by trigger reason"),
     "ds_scrapes_total": (
         "counter", "/metrics scrapes served by this process"),
+    "ds_startup_seconds": (
+        "gauge", "seconds of the process's start-up by phase, set once at "
+                 "ready (label phase: import|inference_init|serving_init|"
+                 "pool|weight_layouts|gateway_start|initialize|params|"
+                 "state|program|outside|ready)"),
+    "ds_host_gc_pause_seconds_total": (
+        "counter", "seconds this process spent inside the garbage "
+                   "collector, as its gc.callbacks entry timed them"),
     # -- serving engine + scheduler --
     "ds_serving_ttft_ms": (
         "histogram", "time to first token per finished request (ms)"),

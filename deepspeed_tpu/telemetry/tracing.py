@@ -108,10 +108,6 @@ class Tracer:
         # right step counter in the stream
         self._step_of = step_of
         self.dropped = 0
-        # lifetime spans successfully emitted: the bench series' window
-        # accounting (the manager's in-memory tail is a bounded ring —
-        # counting there undercounts any non-trivial window)
-        self.emitted = 0
 
     # ------------------------------------------------------------------
     def new_trace(self, hint: Optional[str] = None) -> str:
@@ -128,7 +124,6 @@ class Tracer:
                 data.update(attrs)
             step = self._step_of() if self._step_of is not None else None
             self._emit_fn("span", name, step=step, data=data)
-            self.emitted += 1
         except Exception:  # noqa: BLE001 — tracing must never break a step
             self.dropped += 1
 

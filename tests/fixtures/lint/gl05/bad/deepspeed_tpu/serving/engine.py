@@ -33,3 +33,15 @@ class ServingEngine:
         # registered span names are gateway / ingress / quota
         self.telemetry.emit("gatway", "request.finished", step=1)  # typo
         self._tracer.record_span("ingres", "t1", 0, 1)           # near-miss
+
+    def start_up(self):
+        # the process's start-up ledger: the registered names are
+        # startup.pool / startup.serving_init
+        with startup_bracket("pool", span="startup.pol"):          # near-miss
+            pass
+        with LEDGER.startup_bracket("pool", span="pool"):          # no prefix
+            pass
+
+    @constructor_bracket("serving_init", span="startup.serving")   # near-miss
+    def build(self):
+        pass

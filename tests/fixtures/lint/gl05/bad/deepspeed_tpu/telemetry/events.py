@@ -9,4 +9,5 @@ def make_event(kind, name, step, rank, data):
 
 
 SPANS = ("request", "queue", "decode", "draft", "verify",
-         "spec_commit", "migrate", "gateway", "ingress", "quota")
+         "spec_commit", "migrate", "gateway", "ingress", "quota",
+         "startup", "startup.pool", "startup.serving_init")

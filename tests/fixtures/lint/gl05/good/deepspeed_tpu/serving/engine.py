@@ -39,3 +39,14 @@ class ServingEngine:
         self._tracer.begin("gateway", "t1")
         self._tracer.record_span("ingress", "t1", 0, 1)
         self._tracer.record_span("quota", "t1", 0, 1)
+
+    def start_up(self):
+        # the process's start-up ledger's registered span names
+        with startup_bracket("pool", span="startup.pool"):
+            pass
+        with LEDGER.startup_bracket("anything", span="startup.pool"):
+            pass
+
+    @constructor_bracket("serving_init", span="startup.serving_init")
+    def build(self):
+        pass

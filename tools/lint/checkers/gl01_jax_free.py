@@ -40,6 +40,8 @@ JAX_FREE_MODULES = (
     "deepspeed_tpu/serving/tenancy.py",
     "deepspeed_tpu/telemetry/events.py",
     "deepspeed_tpu/telemetry/tracing.py",
+    "deepspeed_tpu/telemetry/process_ledger.py",
+    "deepspeed_tpu/telemetry/compile_watch.py",
     "deepspeed_tpu/telemetry/metrics.py",
     "deepspeed_tpu/telemetry/registry.py",
     "deepspeed_tpu/telemetry/prom.py",

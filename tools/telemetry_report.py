@@ -275,7 +275,7 @@ def _aggregate_spans(span_events: List[Dict]) -> Dict:
             by_name[e.get("name")] = h = Histogram()
         h.observe(dur)
         traces.setdefault(str(d.get("trace")), []).append(e)
-    steps, requests = [], []
+    steps, requests, startup = [], [], None
     for trace, evs in traces.items():
         root = next((e for e in evs
                      if e["data"].get("parent") is None), None)
@@ -296,8 +296,8 @@ def _aggregate_spans(span_events: List[Dict]) -> Dict:
                     row["phases"][e["name"]] = round(
                         row["phases"].get(e["name"], 0.0) + ms, 3)
             steps.append(row)
-        elif root["name"] in ("request", "serve"):
-            requests.append({
+        elif root["name"] in ("request", "serve", "startup"):
+            row = {
                 "trace": trace,
                 "request_id": d.get("request_id"),
                 "state": d.get("state"), "reason": d.get("reason"),
@@ -315,7 +315,13 @@ def _aggregate_spans(span_events: List[Dict]) -> Dict:
                      for e in evs),
                     # parents first at equal starts (outermost = longest)
                     key=lambda s: (s["start_ns"], -(s["end_ns"] or 0))),
-            })
+            }
+            if root["name"] == "startup":
+                # the process's start-up ledger: one trace a process
+                startup = {**row, "ready_s": d.get("ready_s"),
+                           "outside_s": d.get("outside_s")}
+            else:
+                requests.append(row)
     steps.sort(key=lambda r: r["step"] if r["step"] is not None else -1)
     return {
         "count": len(span_events),
@@ -323,6 +329,7 @@ def _aggregate_spans(span_events: List[Dict]) -> Dict:
                     for k, h in sorted(by_name.items())},
         "steps": steps[-20:],
         "requests": requests[-5:],
+        "startup": startup,
     }
 
 
@@ -714,6 +721,7 @@ def _waterfall_lines(req: Dict, pad: str) -> List[str]:
     if not spans:
         return []
     t0 = min(s["start_ns"] for s in spans)
+    width = max(14, *(len(s["name"]) for s in spans))
     depth = {}
     parents = {s["span"]: s["parent"] for s in spans}
     for s in spans:
@@ -729,10 +737,11 @@ def _waterfall_lines(req: Dict, pad: str) -> List[str]:
         hot = {k: v for k, v in (s.get("attrs") or {}).items()
                if k in ("attempt", "replica", "slot", "tokens", "reason",
                         "state", "outcome", "from_pos", "to_pos", "bucket",
-                        "pos", "proposed", "accepted", "proposer")}
+                        "pos", "proposed", "accepted", "proposer",
+                        "program")}
         detail = (" " + " ".join(f"{k}={v}" for k, v in hot.items())
                   if hot else "")
-        out.append(f"{pad}{'  ' * depth[s['span']]}{s['name']:<14} "
+        out.append(f"{pad}{'  ' * depth[s['span']]}{s['name']:<{width}} "
                    f"+{off:8.2f} ms  {dur:8.2f} ms{detail}")
     return out
 
@@ -781,6 +790,13 @@ def _span_lines(agg: Dict, markdown: bool) -> List[str]:
                 out.append("| " + " | ".join(cells) + " |")
             else:
                 out.append(pad + "  ".join(f"{c:>12}" for c in cells))
+    if s.get("startup"):
+        st = s["startup"]
+        out.append("")
+        out.append(pad + f"start-up: {st.get('ready_s')} s from the "
+                   f"process's start to ready, {st.get('outside_s')} s of "
+                   "it outside every bracket")
+        out.extend(_waterfall_lines(st, pad))
     for req in (s.get("requests") or [])[-3:]:
         out.append("")
         head = (f"request {req.get('request_id') or req['trace']}: "

@@ -112,10 +112,15 @@ def paged_write_slots(block_tables, positions, num_valid, block_size: int):
 
     Real tokens (``t < num_valid[b]``) map through the row's block table:
     block ``table[b, pos // bs]``, offset ``pos % bs``. The padded tail of
-    a bucketed prefill (and idle serving slots, ``num_valid == 0``) routes
-    to the reserved garbage block 0 instead — pads must never overwrite
-    another sequence's blocks, and clamping them onto real rows would
-    corrupt this sequence's own prefix."""
+    a bucketed prefill (and rows that bring nothing, ``num_valid == 0``)
+    routes to the reserved garbage block 0 instead — pads must never
+    overwrite another sequence's blocks, and clamping them onto real rows
+    would corrupt this sequence's own prefix. An idle serving slot's rows
+    land there too, through its table of garbage blocks: true of every
+    program that scatters (prefill, chunk, verify, the hybrid and latent
+    models' decode); GPT-2's decode step on the Pallas kernel does not pass
+    here, its call writes its busy rows' rows itself
+    (``ops/decode_attention.py:paged_call_writes``)."""
     B, T = positions.shape
     mb = block_tables.shape[-1]
     blk = jnp.clip(positions // block_size, 0, mb - 1)

@@ -308,16 +308,22 @@ class CausalSelfAttention(nn.Module):
     window: int = 0
 
     def _paged_kv_attend(self, q4, k, v, paging, kv, B, T, head_dim):
-        """Paged decode (serving): scatter this step's KV rows in place
-        into this layer of the stacked block pool, then attend — block-
-        table gather (Pallas kernel on TPU, dense gather oracle elsewhere)
-        for decode steps; prefill (``paging["prefill"]``, rows fresh at
-        length 0) falls through to the standard causal path over its own
-        keys, the same program the append-cache prefill compiles. ``kv``
-        is ``(pools, layer)`` as the block stack threads it (see
-        :func:`_paged_pool_vars`); the updated pools go back the same way.
-        ``paging["work"]``, where the model put it, is the kernel's work
-        list of this step's lengths, made once for every layer.
+        """Paged decode (serving): this step's KV rows go in place into
+        this layer of the stacked block pool, and the step attends them
+        with what the pool held. A decode step on the Pallas kernel hands
+        its ONE new row a sequence to the kernel's call, which writes it
+        for the rows of its work list only (``paged_call_writes``: an idle
+        slot has no step and no write, and the program holds no scatter on
+        a pool); a prefill (``paging["prefill"]``, rows fresh at length 0),
+        a ``k + 1``-row verify step and the dense gather oracle scatter
+        their rows first (pads and idle slots into the garbage block:
+        ``paged_write_slots``). Prefill then falls through to the standard
+        causal path over its own keys, the same program the append-cache
+        prefill compiles. ``kv`` is ``(pools, layer)`` as the block stack
+        threads it (see :func:`_paged_pool_vars`); the updated pools go
+        back the same way. ``paging["work"]``, where the model put it, is
+        the kernel's work list of this step's lengths (and, for a call
+        that writes, its write list), made once for every layer.
         Returns ``(q4, k4, v4, y, cached_attn, pools)``; ``y is None`` on
         the prefill fall-through."""
         cfg = self.config
@@ -356,36 +362,30 @@ class CausalSelfAttention(nn.Module):
             # placement truth
             rows["key_pool"], rows["key_scale"] = quantize_rowwise(k4)
             rows["value_pool"], rows["value_scale"] = quantize_rowwise(v4)
-        blk, off = paged_write_slots(tables, pos, paging["num_valid"], bs)
-        # B*T rows of H*D lanes (H for the scales) written where they lie:
-        # no layer is sliced out of the pool and nothing is re-stacked
-        pools = {name: pool.at[layer, blk, off].set(_pool_rows(
-            rows[name].reshape(B, T, -1), pool.shape[-1]))
-            for name, pool in pools.items()}
+        alibi = cfg.position_embedding == "alibi"
+        kernel = not paging.get("prefill") and _paged_kernel_serves(
+            cfg, self.window)
+        # who writes is read off what is static in the call (its query
+        # rows, the mesh it sits on), never off a model's name or a flag
+        in_call = kernel and _paged_call_writes(B, T, cfg.n_head)
+        if not in_call:
+            blk, off = paged_write_slots(tables, pos, paging["num_valid"], bs)
+            # B*T rows of H*D lanes (H for the scales) written where they
+            # lie: no layer is sliced out of the pool, nothing re-stacked
+            pools = {name: pool.at[layer, blk, off].set(_pool_rows(
+                rows[name].reshape(B, T, -1), pool.shape[-1]))
+                for name, pool in pools.items()}
         if paging.get("prefill"):
             return q4, k4, v4, None, False, pools
-        from deepspeed_tpu.ops.attention import use_decode_kernel
-
-        alibi = cfg.position_embedding == "alibi"
-        if use_decode_kernel() and not alibi and not self.window:
-            # heads partitioned over tp; per-shard KV pools
-            if quant:
-                from deepspeed_tpu.ops.decode_attention import (
-                    decode_attention_paged_int8_tp)
-
-                y4 = decode_attention_paged_int8_tp(
-                    q4, pools["key_pool"], pools["value_pool"],
-                    pools["key_scale"], pools["value_scale"], tables,
-                    lengths, layer, softmax_scale=cfg.attn_scale,
-                    work=paging.get("work"))
-            else:
-                from deepspeed_tpu.ops.decode_attention import (
-                    decode_attention_paged_tp)
-
-                y4 = decode_attention_paged_tp(
-                    q4, pools["key_pool"], pools["value_pool"], tables,
-                    lengths, layer, softmax_scale=cfg.attn_scale,
-                    work=paging.get("work"))
+        if kernel:
+            # heads partitioned over tp; per-shard KV pools. Where the
+            # call writes, the pools it hands back hold the step's rows
+            # and every other byte as it was (the garbage block too: an
+            # idle slot's row goes nowhere)
+            y4, pools = _paged_kernel_attend(
+                q4, pools, tables, lengths, layer, cfg.attn_scale,
+                paging.get("work"), paging["num_valid"],
+                rows if in_call else None)
             y = y4.transpose(0, 2, 1, 3)
         else:
             from deepspeed_tpu.ops.decode_attention import (
@@ -908,7 +908,7 @@ class GPT2LMHeadModel(nn.Module):
                     cfg.paged_block_size).tile_keys)
                 paging = {**paging, "work": paged_step_work(
                     paging["lengths"], paging["block_tables"], T,
-                    cfg.paged_block_size)}
+                    cfg.paged_block_size, _paged_valid(paging, cfg, T))}
         if cfg.remat and cfg.cpu_checkpointing:
             # cpu_checkpointing: ONE checkpoint over the whole stack whose
             # policy host-offloads the per-layer "block_in" residuals (the
@@ -1289,3 +1289,75 @@ def _stack_gathered_ahead(stack, x, deterministic, pld_theta, attention_mask):
         step, (x, jax.lax.stop_gradient(ahead_of(at(0)))),
         (jnp.arange(n, dtype=jnp.int32), shards, fracs, keys))
     return x
+
+
+# ---------------------------------------------------------------------------
+# the paged decode kernel's seam (here, at the file's end: a Pallas kernel's
+# lowered text carries its callers' line numbers, so what stands above keeps
+# its lines)
+def _paged_kernel_serves(cfg, window) -> bool:
+    """Whether a layer's paged decode step runs the Pallas kernel (else the
+    dense gather oracle: no TPU, ALiBi's bias, a local window)."""
+    from deepspeed_tpu.ops.attention import use_decode_kernel
+
+    return (use_decode_kernel() and cfg.position_embedding != "alibi"
+            and not window)
+
+
+def _paged_call_writes(batch: int, tq: int, heads: int) -> bool:
+    from deepspeed_tpu.ops.decode_attention import paged_call_writes
+
+    return paged_call_writes(batch, tq, heads)
+
+
+def _paged_valid(paging, cfg, tq: int):
+    """``paging["num_valid"]`` for the step's work list where the layers'
+    calls write the step's rows themselves (the list then carries where
+    each goes), else None."""
+    batch = paging["lengths"].shape[0]
+    return (paging["num_valid"]
+            if _paged_call_writes(batch, tq, cfg.n_head) else None)
+
+
+def _paged_kernel_attend(q4, pools, tables, lengths, layer, scale, work,
+                         num_valid, rows):
+    """One layer's paged kernel call over the ``pools`` dict (bf16, or int8
+    with its scale side pools). ``rows`` (the step's new rows by pool name,
+    ``[B, 1, H, D]``, a scale ``[B, 1, H]``) where the call writes them, or
+    None where the caller scattered them. Returns ``(y4, pools)``."""
+    from deepspeed_tpu.ops import decode_attention as ops
+
+    names = ("key_pool", "value_pool") + (
+        ("key_scale", "value_scale") if "key_scale" in pools else ())
+    attend = (ops.decode_attention_paged_int8_tp if len(names) == 4
+              else ops.decode_attention_paged_tp)
+    args = (q4, *(pools[n] for n in names), tables, lengths, layer)
+    if rows is None:
+        return attend(*args, softmax_scale=scale, work=work), pools
+    b = q4.shape[0]
+    y4, written = attend(
+        *args, softmax_scale=scale, work=work, valid=num_valid,
+        rows=tuple(_pool_rows(rows[n].reshape(b, 1, -1), pools[n].shape[-1])
+                   for n in names))
+    return y4, {**pools, **dict(zip(names, written))}
+
+
+def _kv_write_most(self, batch: int, tq: int):
+    """The most busy rows of a paged step over ``[batch, tq]`` tokens whose
+    KV rows every layer's kernel call writes itself (a step with more
+    scatters every slot's row: ``paged_most_writers``), or None where the
+    step's program always scatters. What ``ServingEngine`` counts its steps
+    by (``stats()["kv_write"]``)."""
+    from deepspeed_tpu.ops.decode_attention import paged_most_writers
+
+    cfg = self.config
+    windows = cfg.attention_windows or (0,)
+    if not (all(_paged_kernel_serves(cfg, w) for w in windows)
+            and _paged_call_writes(batch, tq, cfg.n_head)):
+        return None
+    return paged_most_writers(batch, 4 if cfg.paged_kv_dtype == "int8" else 2)
+
+
+# (assigned here and not in the class body, whose lines the flash kernels'
+# lowered text carries)
+GPT2LMHeadModel.kv_write_most = _kv_write_most

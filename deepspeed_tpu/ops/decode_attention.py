@@ -171,8 +171,9 @@ def decode_attention(q, k_cache, v_cache, cache_index, softmax_scale=None,
 # makes XLA convert the whole pool around every call. The kernel is handed
 # the STACKED pool and the layer index as a scalar-prefetch operand and
 # addresses ``(layer, table[b, j])`` itself, so no program ever slices a
-# layer out: writers scatter rows in place (``pool.at[layer, block,
-# offset]``) and this kernel reads in place. POOL_BLOCK_AXIS /
+# layer out: writers put rows in place (``pool.at[layer, block,
+# offset]``, or the write call, ``_paged_write``) and this kernel reads in
+# place. POOL_BLOCK_AXIS /
 # POOL_LANE_AXIS are the one spelling of "which axis" that the model, the
 # serving programs (cow, migrate, export) and the tp sharding rule share.
 #
@@ -180,8 +181,10 @@ def decode_attention(q, k_cache, v_cache, cache_index, softmax_scale=None,
 # rows per sequence, not 1 — query row r of sequence b sits at absolute
 # position lengths[b] + r and is causally masked to keys at positions
 # <= lengths[b] + r, including the OTHER rows of the same step (their KV
-# must already be scattered into the pool, which the paged write path
-# does before attending). T_q = 1 is plain decode; T_q = k + 1 is
+# is in the pool before the kernel attends: a T_q = 1 call handed the
+# step's rows, ``rows=``, puts them there itself, for the rows of its work
+# list only, and every other caller scatters them first, pads and idle
+# slots into the garbage block). T_q = 1 is plain decode; T_q = k + 1 is
 # speculative decoding's k-token verify step: the pending token plus k
 # proposed continuation tokens score in one dispatch, each row seeing
 # exactly the prefix it would have seen decoded sequentially — the
@@ -272,9 +275,10 @@ def paged_plan(block_size):
 _noted_plans = set()
 
 
-def _note_paged_plan(plan, q_shape, pool_shape, quant):
-    """Log the tile once a shape, while tracing (as ``flash_plan`` is)."""
-    key = (plan, tuple(q_shape), tuple(pool_shape), quant)
+def _note_paged_plan(plan, q_shape, pool_shape, quant, writes):
+    """Log the tile and who writes the step's rows once a shape, while
+    tracing (as ``flash_plan`` is)."""
+    key = (plan, tuple(q_shape), tuple(pool_shape), quant, writes)
     if key in _noted_plans:
         return
     _noted_plans.add(key)
@@ -282,7 +286,9 @@ def _note_paged_plan(plan, q_shape, pool_shape, quant):
 
     logger.info(f"decode_attention_paged q{tuple(q_shape)} pool"
                 f"{tuple(pool_shape)}{' int8' if quant else ''}: "
-                f"{plan.describe()}")
+                f"{plan.describe()}; the step's rows "
+                + ("written by the call, a busy row's only" if writes
+                   else "scattered by the caller"))
 
 
 def _paged_kernel(row_ref, first_ref, tables_ref, lens_ref, at_ref, q_ref,
@@ -418,25 +424,184 @@ def paged_work_list(lengths, tq, block_size, max_blocks, *, tile_blocks=1):
     return row_of, first
 
 
-def paged_step_work(lengths, block_tables, tq, block_size):
+def paged_step_work(lengths, block_tables, tq, block_size, valid=None):
     """:func:`paged_work_list` as a call of the paged kernel takes it: in
     :func:`paged_plan`'s tiles, idle slots without a step
     (:func:`paged_step_lengths`). What a program makes once a step, before
-    its layers, and hands to every layer's call as ``work=``."""
-    return paged_work_list(
+    its layers, and hands to every layer's call as ``work=``. With
+    ``valid`` (``[B]``: the new rows a sequence brings, 1 or 0) it is the
+    work of a call that writes them (``rows=``): :func:`paged_write_list`
+    behind the two."""
+    work = paged_work_list(
         paged_step_lengths(lengths, block_tables, tq), tq, block_size,
         block_tables.shape[-1],
         tile_blocks=paged_plan(block_size).tile_blocks)
+    if valid is None:
+        return work
+    return work + paged_write_list(lengths, block_tables, valid, block_size)
+
+
+def paged_write_list(lengths, block_tables, valid, block_size):
+    """Where a decode step's ONE new row a sequence goes, for the write
+    call (:func:`_paged_write`): ``(order [B + 1], count [1], block [B],
+    offset [B])``. ``order`` is the batch rows with the WRITING ones first,
+    in ascending order (one more entry for the pipeline's look at the step
+    after the last), ``count`` how many write; a writing row's new row lies
+    at ``offset`` of pool block ``block``: position ``lengths[b]`` through
+    its table (in the last block of a table that is full, as
+    ``paged_write_slots`` clips it). A row writes if it has a step
+    (:func:`paged_step_lengths`: an idle serving slot has none) and brings
+    a row (``valid``); every other row names row 0 of the garbage block,
+    which a call with no writer at all reads and puts back as it was."""
+    lens = jnp.asarray(lengths, jnp.int32)
+    tables = jnp.asarray(block_tables, jnp.int32)
+    writes = ((paged_step_lengths(lens, tables, 1) >= 0)
+              & (jnp.asarray(valid, jnp.int32) > 0))
+    order = jnp.argsort(~writes, stable=True).astype(jnp.int32)
+    last = jnp.minimum(lens // block_size, tables.shape[-1] - 1)
+    block = jnp.take_along_axis(tables, last[:, None], axis=1)[:, 0]
+    return (jnp.concatenate([order, order[-1:]]),
+            jnp.sum(writes, dtype=jnp.int32).reshape(1),
+            jnp.where(writes, block, GARBAGE_BLOCK),
+            jnp.where(writes, lens % block_size, 0))
+
+
+def _words(dtype):
+    """The 32-bit type that holds every value of ``dtype``: the write
+    kernel reads ONE row at a traced index, which Mosaic takes of whole
+    words only (a bfloat16 or int8 row is a part of a sublane)."""
+    return jnp.float32 if jnp.issubdtype(dtype, jnp.floating) else jnp.int32
+
+
+def _strip_rows(block_size, dtype):
+    """Rows of the strip of a block that the write call moves: one sublane
+    tile of the pool's dtype (8 rows of 32 bits, 16 of bfloat16, 32 of
+    int8), the least a block DMA can write of a tiled pool; the whole block
+    where that does not divide it."""
+    rows = 32 // jnp.dtype(dtype).itemsize
+    return rows if block_size % rows == 0 else block_size
+
+
+def _paged_write_kernel(order_ref, count_ref, block_ref, offset_ref, at_ref,
+                        *refs, strips):
+    del block_ref, at_ref
+    n = len(strips)
+    news, srcs, dsts, words = (refs[i * n:(i + 1) * n] for i in range(4))
+    step = pl.program_id(0)
+    row = order_ref[step]
+
+    @pl.when(step == 0)
+    def _as_words():
+        # the step's rows, value for value in 32 bits: a row a grid step
+        # is read from these at a traced index
+        for new, word in zip(news, words):
+            word[...] = new[...].astype(word.dtype)
+
+    # a call with no writer still runs the grid's one step: on the garbage
+    # block's first strip, which goes back as it came
+    writes = step < count_ref[0]
+    for word, src, dst, strip in zip(words, srcs, dsts, strips):
+        held = src[...]                                      # [strip, lanes]
+        here = (jax.lax.broadcasted_iota(jnp.int32, held.shape, 0)
+                == offset_ref[row] % strip) & writes
+        dst[...] = jnp.where(here, word[pl.ds(row, 1), :],
+                             held.astype(word.dtype)).astype(dst.dtype)
+
+
+def _paged_write(pools, rows, layer, put):
+    """The step's new rows into their places in ``layer`` of the stacked
+    ``pools``, by ``put`` (:func:`paged_write_list`): ONE call for all the
+    pools, each an operand ONCE and aliased to its result, so the program
+    holds one buffer a pool and no scatter on it. No DMA can write ONE
+    bfloat16 row of a tiled pool (it is half a sublane), so a grid step is
+    a writing row's strip: the sublane tile of its block that holds the
+    offset (:func:`_strip_rows`) comes in by the pipeline's block DMA
+    (which moves a row of any width, where a manual copy of any slice of
+    GPT-2 XL's 1600-lane rows is refused, whole rows too), the row is
+    replaced in VMEM, and the strip goes back; the rows themselves are
+    whole ``[B, lanes]`` arrays in VMEM, not an operand a step. Rows that
+    write nothing have no step. (An output block of the ATTENTION call
+    aliased to one of its four operands a pool would save this call, and
+    makes XLA copy the whole pool in and out: PERF.md section 6, PR 55.)"""
+    order, count, block, offset = put
+    bs = pools[0].shape[2]
+    strips = [_strip_rows(bs, p.dtype) for p in pools]
+
+    def strip_spec(width, strip):
+        def index(s, order, count, block, offset, at):
+            row = order[s]
+            return (at[0], block[row], offset[row] // strip, 0)
+        return pl.BlockSpec((None, None, strip, width), index)
+
+    specs = [strip_spec(p.shape[POOL_LANE_AXIS], strip)
+             for p, strip in zip(pools, strips)]
+    n = len(pools)
+    return tuple(pl.pallas_call(
+        functools.partial(_paged_write_kernel, strips=strips),
+        name="paged_kv_write",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(jnp.maximum(count[0], 1),),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * n + specs,
+            out_specs=specs,
+            scratch_shapes=[
+                pltpu.VMEM((r.shape[0], r.shape[-1]), _words(r.dtype))
+                for r in rows]),
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
+        input_output_aliases={5 + n + i: i for i in range(n)},
+        compiler_params=tpu_compiler_params(
+            dimension_semantics=("arbitrary",)),
+    )(order, count, block, offset,
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      *(r.reshape(r.shape[0], -1) for r in rows), *pools))
+
+
+def paged_most_writers(batch: int, pools: int) -> int:
+    """The most writing rows of ``batch`` that the write call takes; a
+    step with more scatters every row instead (one ``lax.cond`` on the
+    write list's count, :func:`_paged_put`). Read on a v5e at GPT-2 XL's
+    shapes, 32 slots (PERF.md section 6, PR 55, us a layer call): the
+    write call takes 1.8 + 0.53 a writer, a grid step each (0.55 with
+    int8's four pools), the scatters 14.0 (two pools) or 19.2 (four,
+    two of them a register wide) whatever the writers, every slot's row
+    one after another: they meet at 23 writers of 32 for two pools and at
+    the whole batch for four."""
+    return 3 * batch // 4 if pools == 2 else batch
+
+
+def _paged_put(pools, rows, layer, put):
+    """The step's new rows into ``layer`` of the ``pools``, by ``put``
+    (:func:`paged_write_list`): the write call (:func:`_paged_write`), or,
+    where more rows write than :func:`paged_most_writers`, every slot's
+    row scattered as a program without the write call does (a row that
+    writes nothing onto the garbage block). ONE algorithm chosen by what
+    the step holds; both branches write the pools in place."""
+    batch = rows[0].shape[0]
+    most = paged_most_writers(batch, len(pools))
+    if most >= batch:
+        return _paged_write(pools, rows, layer, put)
+    _, count, block, offset = put
+
+    def scatter(pools):
+        return tuple(p.at[layer, block, offset].set(r[:, 0])
+                     for p, r in zip(pools, rows))
+
+    return jax.lax.cond(
+        count[0] > most, scatter,
+        lambda pools: _paged_write(pools, rows, layer, put), tuple(pools))
 
 
 def _paged_call(q, pools, block_tables, lengths, layer, softmax_scale,
-                head0=None, work=None):
+                head0=None, work=None, rows=None, valid=None):
     """The one ``pallas_call`` behind both paged entry points: ``pools`` is
     ``(k_pool, v_pool)`` or ``(k_pool, v_pool, k_scale, v_scale)``.
     ``head0`` (tp shards only) is the first head this call's ``q`` and K/V
     lanes hold, for the scale rows, which stay whole. ``work`` is
     :func:`paged_step_work` of these ``lengths`` and tables, made here if
-    not given.
+    not given. With ``rows`` (and ``valid``: see
+    :func:`decode_attention_paged`) the step's new rows are put into the
+    pools first, under the same scope (:func:`_paged_put`), and the result
+    is ``(out, pools)``.
 
     THE GRID FOLLOWS ``lengths``, NOT ``block_tables.shape``: it is one
     axis whose (traced) length is the number of live TILES of all rows,
@@ -503,10 +668,22 @@ def _paged_call(q, pools, block_tables, lengths, layer, softmax_scale,
     tables = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32)
     plan = paged_plan(bs)
-    _note_paged_plan(plan, q.shape, pools[0].shape, quant)
+    _note_paged_plan(plan, q.shape, pools[0].shape, quant, rows is not None)
     tile = plan.tile_blocks
-    row_of, first = (paged_step_work(lens, tables, tq, bs) if work is None
-                     else work)
+    if rows is not None and (tq != 1 or len(rows) != len(pools) or any(
+            r.shape != (b, 1, p.shape[POOL_LANE_AXIS]) or r.dtype != p.dtype
+            for r, p in zip(rows, pools))):
+        raise ValueError(
+            f"a call writes ONE new row a sequence and pool (see "
+            f"paged_call_writes): rows {[(r.shape, r.dtype) for r in rows]} "
+            f"for T_q={tq} and pools {[(p.shape, p.dtype) for p in pools]}")
+    if work is None:
+        work = paged_step_work(lens, tables, tq, bs)
+    (row_of, first), put = work[:2], work[2:]
+    if rows is not None:
+        pools = _paged_put(pools, rows, layer, put or paged_write_list(
+            lens, tables, jnp.ones_like(lens) if valid is None else valid,
+            bs))
     if row_of.shape != (b * -(-mb // tile) + 1,) or first.shape != (b + 1,):
         raise ValueError(
             f"work list of shapes {row_of.shape}, {first.shape} is not "
@@ -554,7 +731,7 @@ def _paged_call(q, pools, block_tables, lengths, layer, softmax_scale,
     # the device trace already prints this kernel under the caller's scope
     # (``attn._paged_kv_attend.N``), which the benchmark's paged-decode
     # roofline reader matches by that name
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, tq, heads, d), q.dtype),
@@ -565,10 +742,12 @@ def _paged_call(q, pools, block_tables, lengths, layer, softmax_scale,
             dimension_semantics=("arbitrary",)),
     )(row_of, first, tables, lens, at, q,
       *(p for p in pools for _ in range(tile)), jnp.zeros_like(q))
+    return out if rows is None else (out, pools)
 
 
 def decode_attention_paged(q, k_pool, v_pool, block_tables, lengths,
-                           layer=0, softmax_scale=None, work=None):
+                           layer=0, softmax_scale=None, work=None, rows=None,
+                           valid=None):
     """Attend a decode (or k-token verify, or prefill-chunk) step against
     one layer of the paged KV pool.
 
@@ -580,10 +759,10 @@ def decode_attention_paged(q, k_pool, v_pool, block_tables, lengths,
         ``lengths[b] + r`` — bitwise the attention sequential decode
         would have computed, which is what makes greedy verify exact.
       k_pool / v_pool: the STACKED ``[layers, num_blocks, block_size,
-        H*D]`` pools (see "THE POOL'S ONE SHAPE" above); this step's keys
-        must already be scattered at each row's ``[lengths[b], lengths[b]
-        + T_q)`` logical positions of ``layer`` (verify pads scatter into
-        the garbage block and are never read).
+        H*D]`` pools (see "THE POOL'S ONE SHAPE" above). Without ``rows``
+        this step's keys must already be scattered at each row's
+        ``[lengths[b], lengths[b] + T_q)`` logical positions of ``layer``
+        (verify pads scatter into the garbage block and are never read).
       block_tables: ``[B, MB]`` int32 — row b's logical block j lives in
         pool block ``block_tables[b, j]``; entries past the allocation
         point at the reserved garbage block (their blocks skip compute).
@@ -592,7 +771,19 @@ def decode_attention_paged(q, k_pool, v_pool, block_tables, lengths,
         which layer of the stacked pool to read.
       work: :func:`paged_step_work` of these ``lengths`` and tables, for a
         caller that runs many layers on one step's lengths and makes it
-        once; made here if None.
+        once (with ``valid`` where the call writes); made here if None.
+      rows: ``(k, v)``, each ``[B, 1, H*D]`` in the pools' dtype: this
+        step's ONE new row a sequence (``T_q = 1``; see
+        :func:`paged_call_writes`). The call then leaves them in the pools
+        itself, at ``lengths[b]`` through each row's table, for the rows
+        of its work list that bring one (:func:`_paged_put`: an idle slot
+        has no step and no write, and no byte of the garbage block moves
+        unless the step is so crowded that every row is scattered), and
+        attends over them: output and pools are scatter-then-attend's to
+        the bit. Returns ``(out, (k_pool, v_pool))``.
+      valid: ``[B]`` int32 with ``rows``: 0 for a row that brings no row
+        (it attends what its pool holds); all ones if None. Not read where
+        ``work`` already carries the write list.
 
     The block table, lengths and layer are *scalar-prefetch* operands:
     the grid is one axis over the live blocks of all rows, a tile of
@@ -609,12 +800,13 @@ def decode_attention_paged(q, k_pool, v_pool, block_tables, lengths,
     Returns ``[B, T_q, H, D]`` in the query's dtype.
     """
     return _paged_call(q, (k_pool, v_pool), block_tables, lengths, layer,
-                       softmax_scale, work=work)
+                       softmax_scale, work=work, rows=rows, valid=valid)
 
 
 def decode_attention_paged_int8(q, k_pool, v_pool, k_scale, v_scale,
                                 block_tables, lengths, layer=0,
-                                softmax_scale=None, work=None):
+                                softmax_scale=None, work=None, rows=None,
+                                valid=None):
     """Attend a decode (or k-token verify) step against one layer of an
     int8-quantized paged KV pool.
 
@@ -629,9 +821,13 @@ def decode_attention_paged_int8(q, k_pool, v_pool, k_scale, v_scale,
     blocks *and* their scale rows, dequantizes in-register, and runs the
     identical fp32 online-softmax update; :func:`gather_paged_cache_int8`
     is the dense oracle it is tested against with a pinned tolerance.
+    ``rows`` is ``(k, v, k_scale, v_scale)`` rows, ``[B, 1, H*D]`` int8 and
+    ``[B, 1, scale_lanes(H)]`` f32, and the call returns ``(out, (k_pool,
+    v_pool, k_scale, v_scale))``.
     """
     return _paged_call(q, (k_pool, v_pool, k_scale, v_scale), block_tables,
-                       lengths, layer, softmax_scale, work=work)
+                       lengths, layer, softmax_scale, work=work, rows=rows,
+                       valid=valid)
 
 
 # ---------------------------------------------------------------------------
@@ -673,8 +869,26 @@ def decode_attention_tp(q, k_cache, v_cache, cache_index,
         q, k_cache, v_cache, jnp.asarray(cache_index, jnp.int32))
 
 
+def paged_call_writes(batch, tq, heads, mesh=None, axis=None) -> bool:
+    """Whether a paged call over ``[batch, tq, heads, ...]`` queries on this
+    mesh takes the step's new rows (``rows=``) and leaves them in the pool
+    itself, or its caller scatters them first (``paged_write_slots``).
+    Read off what is static in the call: ONE new row a sequence (a ``k +
+    1``-row verify step's rows may straddle two blocks, and a prefill's
+    are a whole prompt: both scatter), and a batch that is whole inside
+    the ``shard_map`` the call sits in (where data axes split it, each
+    shard would write its own rows into its own replica of the pool, and
+    the replicas would part; the scatter outside gathers the rows)."""
+    from deepspeed_tpu.ops.kernel_mesh import kernel_mesh_plan
+
+    if tq != 1:
+        return False
+    plan = kernel_mesh_plan(batch, heads, mesh=mesh, axis=axis)
+    return plan is None or plan.batch is None
+
+
 def _paged_tp(q, pools, block_tables, lengths, layer, softmax_scale,
-              mesh, axis, work=None):
+              mesh, axis, work=None, rows=None, valid=None):
     from jax.sharding import PartitionSpec as P
 
     from deepspeed_tpu.ops.kernel_mesh import kernel_mesh_plan
@@ -685,50 +899,64 @@ def _paged_tp(q, pools, block_tables, lengths, layer, softmax_scale,
     plan = kernel_mesh_plan(q.shape[0], q.shape[2], mesh=mesh, axis=axis)
     if plan is None:
         return _paged_call(q, pools, tables, lens, layer, softmax_scale,
-                           work=work)
+                           work=work, rows=rows, valid=valid)
+    if rows is not None and plan.batch is not None:
+        raise ValueError("a batch split over data axes scatters its rows "
+                         "outside the call: see paged_call_writes")
     # a work list made for the whole batch serves every shard only while
     # the batch is whole inside; where data axes split it, each shard lists
     # its own rows
     work = () if work is None or plan.batch is not None else tuple(work)
+    n = len(pools)
+    written = () if rows is None else (
+        *rows, jnp.ones_like(lens) if valid is None else valid)
 
     def kernel(qs, t, ln, ly, *rest):
-        ps, wk = rest[:len(pools)], rest[len(pools):]
+        ps, wk, new = rest[:n], rest[n:n + len(work)], rest[n + len(work):]
         head0 = (None if plan.heads is None else
                  jax.lax.axis_index(plan.heads) * qs.shape[2])
         return _paged_call(qs, ps, t, ln, ly, softmax_scale, head0,
-                           work=wk or None)
+                           work=wk or None, rows=new[:n] or None,
+                           valid=new[n] if new else None)
 
     # pools are the SHARED per-replica cache: the K/V lane axis split into
     # tp groups of heads/tp contiguous heads, replicated over data; the
     # scale rows (a lane a head, padded to whole registers) stay whole and
     # the kernel finds its heads in them; per-row operands follow the
-    # batch entry
+    # batch entry. The step's new rows lie as the pools' rows do, and a
+    # shard's call writes its own lanes of them (a scale row whole, into
+    # its own copy)
     qs_spec = P(plan.batch, None, plan.heads, None)
-    pool_specs = (P(None, None, None, plan.heads),) * 2 + (P(),) * (
-        len(pools) - 2 + len(work))
+    pool_specs = (P(None, None, None, plan.heads),) * 2 + (P(),) * (n - 2)
+    row_specs = () if rows is None else (
+        (P(None, None, plan.heads),) * 2 + (P(),) * (n - 2 + 1))
     return plan.shard_map(
-        kernel, (qs_spec, P(plan.batch), P(plan.batch), P()) + pool_specs,
-        qs_spec, name="paged_kv_attend")(q, tables, lens, layer, *pools,
-                                         *work)
+        kernel, (qs_spec, P(plan.batch), P(plan.batch), P()) + pool_specs
+        + (P(),) * len(work) + row_specs,
+        qs_spec if rows is None else (qs_spec, pool_specs),
+        name="paged_kv_attend")(q, tables, lens, layer, *pools, *work,
+                                *written)
 
 
 def decode_attention_paged_tp(q, k_pool, v_pool, block_tables, lengths,
                               layer=0, softmax_scale=None, mesh=None,
-                              axis=None, work=None):
+                              axis=None, work=None, rows=None, valid=None):
     """TP-aware :func:`decode_attention_paged`: the stacked pools live
     tp-sharded on their lane axis (per-shard KV pools — each tp shard
     holds heads/tp contiguous heads of every pool row), block
     tables/lengths follow the batch, the layer index and the work list
     are replicated."""
     return _paged_tp(q, (k_pool, v_pool), block_tables, lengths, layer,
-                     softmax_scale, mesh, axis, work)
+                     softmax_scale, mesh, axis, work, rows, valid)
 
 
 def decode_attention_paged_int8_tp(q, k_pool, v_pool, k_scale, v_scale,
                                    block_tables, lengths, layer=0,
                                    softmax_scale=None, mesh=None,
-                                   axis=None, work=None):
+                                   axis=None, work=None, rows=None,
+                                   valid=None):
     """TP-aware :func:`decode_attention_paged_int8`: int8 pools
     lane-sharded over ``axis``, their f32 scale side pools replicated."""
     return _paged_tp(q, (k_pool, v_pool, k_scale, v_scale), block_tables,
-                     lengths, layer, softmax_scale, mesh, axis, work)
+                     lengths, layer, softmax_scale, mesh, axis, work, rows,
+                     valid)

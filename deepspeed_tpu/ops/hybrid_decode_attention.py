@@ -2,9 +2,9 @@
 sliding-window layers held in per-slot rings, and a learnable sink.
 
 SPLIT from ``ops/decode_attention.py``, not an adaptation of it: that
-kernel serves GPT-2 (one head size, a KV head a query head, every live
-block) and its compiled program is what ``serve-xl-chat`` is judged on, so
-it stays byte for byte. What the two share is shared: the pool's one shape
+kernel serves GPT-2 (one head size, a KV head a query head; since PR 55
+its call also writes the step's rows, which the callers here scatter), and
+a change to either leaves the other's programs be. Shared: the pool's shape
 (``[layers, blocks, block_size, lanes]``, whole 128-lane registers, the
 stacked pool and the layer index handed to the kernel), the garbage block,
 and the work list (``paged_work_list``): a grid of one traced axis over the

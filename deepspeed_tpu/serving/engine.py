@@ -342,6 +342,10 @@ class ServingEngine(process_ledger.FirstCalls):
             self._top_ks = np.zeros((n,), np.int32)
             self._top_ps = np.zeros((n,), np.float32)
         self._step_count = 0
+        # steps by who wrote their KV rows (``_kv_write_form``), and the
+        # model's answer for a step of each width, asked once
+        self._kv_write = {"kernel": 0, "scatter": 0}
+        self._kv_most: Dict[int, Optional[int]] = {}
         # speculation counters over the stats window (reset_stats zeroes
         # them WITH the records deque — the bounded records alone would
         # decay any per-step ratio on a long-running server)
@@ -1292,7 +1296,7 @@ class ServingEngine(process_ledger.FirstCalls):
         # counted here, at the fetch, from the fetched step's own view
         self._count("decode", toks[len(self._lengths):])
         routed = self._routed(toks, len(self._lengths))
-        self._step_boundary(len(flight.pairs), flight.lengths)
+        self._step_boundary(len(flight.pairs), flight.lengths, 1)
         with self._bracket("emit", span="emit", ledger="emit"):
             for slot, req in flight.pairs:
                 if self.sched.slots[slot] is not req:
@@ -1379,12 +1383,27 @@ class ServingEngine(process_ledger.FirstCalls):
             self._decode_step(done, ahead=False)
         return done
 
-    def _step_boundary(self, active: int, lengths: np.ndarray):
-        """One decode (or verify) step is over, dispatched with
-        ``lengths`` for ``active`` live rows: the ledger's counts, the
-        telemetry step boundary, and, under telemetry only, the load
-        gauges (the slot scan is not paid with telemetry off)."""
+    def _kv_write_form(self, tq: int, active: int) -> str:
+        """Who wrote the KV rows of a step of ``tq`` tokens a slot with
+        ``active`` busy rows: ``"kernel"`` where the model says its paged
+        kernel call writes so many itself (``kv_write_most``: GPT-2's
+        decode program, for the rows of its work list only), else
+        ``"scatter"`` (XLA scatters of every slot's row)."""
+        if tq not in self._kv_most:
+            most = getattr(self._dmodule, "kv_write_most", None)
+            self._kv_most[tq] = (None if most is None else
+                                 most(self.config.decode_slots, tq))
+        most = self._kv_most[tq]
+        return "kernel" if most is not None and active <= most else "scatter"
+
+    def _step_boundary(self, active: int, lengths: np.ndarray, tq: int):
+        """One decode (or verify) step of ``tq`` tokens a slot is over,
+        dispatched with ``lengths`` for ``active`` live rows: the
+        ledger's counts, the telemetry step boundary, and, under
+        telemetry only, the load gauges (the slot scan is not paid with
+        telemetry off)."""
         self._step_count += 1
+        self._kv_write[self._kv_write_form(tq, active)] += 1
         self._busy = active
         self._ledger["busy_slot_steps"] += active
         if self._kv_kinds:
@@ -1474,7 +1493,7 @@ class ServingEngine(process_ledger.FirstCalls):
         # the router's exactly-once splice sees no speculative token
         raise_if("serving.spec_commit")
         self._spec_steps += 1
-        self._step_boundary(len(active), self._lengths)
+        self._step_boundary(len(active), self._lengths, k + 1)
         with self._bracket("emit", span="emit", ledger="emit"):
             self._spec_emit(active, proposals, toks, t0, now, done)
 
@@ -2075,6 +2094,9 @@ class ServingEngine(process_ledger.FirstCalls):
             "migrated_out": s["migrated_out"],
             "queue_peak": s["queue_peak"],
             "decode_steps": self._step_count,
+            # ... of which by who wrote the step's KV rows: the kernel's
+            # call for its busy rows, or XLA scatters of every slot's
+            "kv_write": dict(self._kv_write),
             "ttft_ms_p50": round(float(np.percentile(ttfts, 50)), 3)
             if ttfts else None,
             "ttft_ms_p95": round(float(np.percentile(ttfts, 95)), 3)

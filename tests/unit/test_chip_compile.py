@@ -314,8 +314,11 @@ def test_paged_decode_kernel_matches_the_benchmarks_reader(
     """The serving decode program of a GPT-2 (125M widths, two layers).
     Alone, its paged kernel is the instruction ``attn._paged_kv_attend.N``,
     which is the name the accepted reader of ``paged_decode_roofline_share``
-    matches (the pattern is read from the benchmark's own file); with the
-    heads over tp=4 it keeps ``paged_kv_attend`` in its name."""
+    matches (the pattern is read from the benchmark's own file), and ONLY
+    the attention kernel is: the call that writes the step's rows first
+    (PR 55) has a name of its own, ``paged_kv_write.N``, so the reader's
+    time is the kernel that reads the live KV, as its bytes are; with the
+    heads over tp=4 the kernel keeps ``paged_kv_attend`` in its name."""
     import json
     import os
     import re
@@ -370,12 +373,16 @@ def test_paged_decode_kernel_matches_the_benchmarks_reader(
         _s(place, (slots,), jnp.int32))
     names = _kernel_names(text)
     if mesh_shape is None:
-        assert [ln for ln in map(str.strip, text.splitlines())
-                if pattern.search(ln)], names
+        matched = [ln for ln in map(str.strip, text.splitlines())
+                   if pattern.search(ln)]
+        assert len(matched) == 1 and "paged_kv_write" not in matched[0], names
+        assert any(n.startswith("paged_kv_write") for n in names), names
     else:
         # per shard the kernel sits in a shard_map, whose body is scoped
-        # (it printed as ``shard_map.N``)
-        assert names and all("paged_kv_attend" in n for n in names), names
+        # (it printed as ``shard_map.N``); beside it the write call that
+        # puts the step's rows into each shard's lanes of the pools (PR 55)
+        assert sorted(n.split(".")[0] for n in names) == [
+            "paged_kv_attend", "paged_kv_write"], names
 
 
 # ---------------------------------------------------------------------------
@@ -419,16 +426,26 @@ def test_serving_programs_update_the_kv_pool_in_place(one_chip, monkeypatch,
     """The serving decode program and a prefill program at GPT-2 XL widths
     (25 heads of 64; 32 slots; the default pool of 1025 blocks of 32), the
     pool donated, bf16 and int8. The pool has ONE resident form that the
-    program writes and reads in place: in the compiled program only
-    parameters, loop plumbing and the row scatters have a result with the
-    pool's block dims (no ``copy``, ``dynamic-slice``,
-    ``dynamic-update-slice`` or ``AllocateBuffer`` over a pool or a
-    layer's slice of one), program temporaries stay below ONE layer's
-    pool, and the input-output alias covers every pool byte. On the parent
-    of PR 27 this found the layout conversions around the layer scan
-    (``copy.30-33``), the scan's second pool (``AllocateBuffer``) and the
-    copy back onto the donated argument (``copy.52/53``): two thirds of a
-    decode step.
+    program writes and reads in place: program temporaries stay below ONE
+    layer's pool and the input-output alias covers every pool byte. In the
+    DECODE program the only instructions with a result of the pool's block
+    dims are parameters, loop plumbing and the paged kernel's write call
+    (``paged_kv_write``, PR 55: it takes each pool once, hands it back, and
+    writes the step's rows for its busy rows only); with bf16's two pools
+    also the ONE conditional that sends a step of more writers than
+    ``paged_most_writers`` to the row scatters instead, the scatters its
+    other branch and nowhere else. int8's four pools meet the scatters
+    only at a full batch: no conditional, no scatter at all. No ``copy``,
+    ``dynamic-slice``, ``dynamic-update-slice`` or ``AllocateBuffer`` over
+    a pool or a layer's slice of one in any of them. A PREFILL program
+    keeps its row scatters (T rows a call, XLA attention): there only
+    parameters, loop plumbing and the scatters touch the pool. On the
+    parent of PR 27 this found the layout conversions around the layer
+    scan (``copy.30-33``), the scan's second pool (``AllocateBuffer``) and
+    the copy back onto the donated argument (``copy.52/53``): two thirds of
+    a decode step; in PR 55 it found that an attention call whose own
+    output block is aliased to one of its four operands a pool makes the
+    compiler copy the pool in and out.
 
     Eight layers, so that every pool leaf is larger than the chip's 128
     MiB of fast memory: the compiler prefetches a smaller one there whole
@@ -479,10 +496,14 @@ def test_serving_programs_update_the_kv_pool_in_place(one_chip, monkeypatch,
     touching = [(name, op, line) for name, op, res, line in _results(text)
                 if any((blocks, bs) in zip(dims, dims[1:]) for _, dims in res)]
     assert touching
-    plumbing = {"parameter", "get-tuple-element", "tuple", "while",
-                "bitcast", "scatter", "fusion"}
-    assert {op for _, op, _ in touching} <= plumbing, sorted(
-        (name, op) for name, op, _ in touching if op not in plumbing)
+    plumbing = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
+    chooses = program == "decode" and not kv
+    writers = ({"scatter", "fusion"} if program == "prefill" else
+               {"custom-call", "conditional", "scatter", "fusion"} if chooses
+               else {"custom-call"})
+    assert {op for _, op, _ in touching} <= plumbing | writers, sorted(
+        (name, op) for name, op, _ in touching
+        if op not in plumbing | writers)
     # a fusion with a pool-sized result is the in-place scatter and
     # nothing else: its computation's root is the scatter
     roots = _roots(text)
@@ -491,9 +512,36 @@ def test_serving_programs_update_the_kv_pool_in_place(one_chip, monkeypatch,
             called = re.search(r"calls=%([\w.\-]+)", line).group(1)
             assert roots.get(called) in ("scatter", "bitcast"), (
                 name, called, roots.get(called))
-    assert sum(op in ("scatter", "fusion") and roots.get(
+    scatters = sum(op in ("scatter", "fusion") and roots.get(
         (re.search(r"calls=%([\w.\-]+)", line) or [None, None])[1],
-        op) == "scatter" for _, op, line in touching) >= len(pool)
+        op) == "scatter" for _, op, line in touching)
+    if program == "decode":
+        # the kernel's write call, once a layer (one scanned body), every
+        # pool its operand once and its result
+        calls = [(name, line) for name, op, line in touching
+                 if op == "custom-call"]
+        assert len(calls) == 1 and calls[0][0].startswith(
+            "paged_kv_write"), calls
+        assert "tpu_custom_call" in calls[0][1]
+        aliased = re.search(r"output_to_operand_aliasing=\{(.*?\})\}",
+                            calls[0][1]).group(1)
+        assert len(re.findall(r"\{\d+\}: \(\d+, \{\}\)", aliased)) == len(
+            pool), aliased
+        conds = [line for _, op, line in touching if op == "conditional"]
+        assert len(conds) == int(chooses)
+        # where the program chooses, the scatters are its other branch: a
+        # scatter a pool, under the conditional's computations only
+        assert len(re.findall(r"\bscatter\(", text)) == (
+            len(pool) if chooses else 0)
+        if chooses:
+            branches = re.search(r"branch_computations=\{([^}]*)\}",
+                                 conds[0]).group(1).replace("%", "").split(
+                                     ", ")
+            assert len(branches) == 2 and sorted(
+                roots.get(b) for b in branches) == ["custom-call", "tuple"], (
+                branches, [roots.get(b) for b in branches])
+    else:
+        assert scatters >= len(pool)
 
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < pool_bytes // layers, (
@@ -1063,9 +1111,11 @@ def test_ssd_chunk_scan_kernel_at_a_prefill_chunks_shape(one_chip):
 # ``lower(...).as_text()`` at the cells' own sizes, the decode kernels
 # forced on, a kernel's Mosaic body without its debug info (it carries its
 # callers' line numbers): recorded at commit 46ccfdd (the parent of PR 50)
-# by the function below, and equal there and here.
+# by the function below, and equal there and here; but GPT-2's decode
+# program, which PR 55 meant to change (its paged kernel call writes the
+# step's rows: ``2387c053ad0ea1c7`` until then) and recorded anew.
 _PARENT_PROGRAMS = {
-    ("gpt2-xl", "decode"): "2387c053ad0ea1c7",
+    ("gpt2-xl", "decode"): "941ef119564ed3b4",
     ("gpt2-xl", "prefill"): "5cde9a5d74d5fc46",
     ("deepseek-v2-lite-l6", "decode"): "0454bbe358502ca6",
     ("deepseek-v2-lite-l6", "chunk"): "b47959bec6b2899b",

@@ -978,6 +978,220 @@ def test_hybrid_families_list_the_kernels_work_once_a_step(
     assert len(made) == 2 * len(tiles)
 
 
+# ---------------------------------------------------------------------------
+# the call writes the step's own rows (``rows=``), for busy rows only
+def _step_rows(seed, B, H, D, pools):
+    """A step's new rows as the pools take them: ``[B, 1, lanes]`` in the
+    pools' dtype, int8 with its scale rows."""
+    from deepspeed_tpu.ops.decode_attention import scale_lanes
+    from deepspeed_tpu.ops.quantizer import quantize_rowwise
+
+    rng = np.random.default_rng(seed)
+    k4, v4 = (jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
+              for _ in range(2))
+    if len(pools) == 2:
+        return tuple(r.reshape(B, 1, H * D).astype(pools[0].dtype)
+                     for r in (k4, v4))
+    held = [quantize_rowwise(r) for r in (k4, v4)]
+    return tuple(h.reshape(B, 1, H * D) for h, _ in held) + tuple(
+        jnp.pad(s.reshape(B, 1, H), ((0, 0), (0, 0), (0, scale_lanes(H) - H)))
+        for _, s in held)
+
+
+def _scattered(pools, rows, tables, lens, layer, valid=None):
+    """What the decode program did until the call wrote: every row
+    through ``paged_write_slots``, an idle slot's (and a row of ``valid``
+    0) onto the garbage block."""
+    from deepspeed_tpu.models.decode_utils import (paged_positions,
+                                                   paged_write_slots)
+
+    blk, off = paged_write_slots(
+        tables, paged_positions(lens, 1),
+        jnp.ones_like(lens) if valid is None else valid, pools[0].shape[2])
+    return tuple(p.at[layer, blk, off].set(r) for p, r in zip(pools, rows))
+
+
+def _writing_setup(kv, lengths, bs, mb, seed, H=2, D=64):
+    from deepspeed_tpu.ops import decode_attention as da
+
+    q4, k_pool, v_pool, tables, lens, layer = _paged_setup(
+        len(lengths), lengths, 1, bs=bs, mb=mb, H=H, D=D, seed=seed,
+        dtype=jnp.float32 if kv == "int8" else jnp.bfloat16)
+    pools, kernel = (k_pool, v_pool), da.decode_attention_paged
+    if kv == "int8":
+        pools, kernel = _int8_pools(k_pool, v_pool, H), \
+            da.decode_attention_paged_int8
+        q4 = q4.astype(jnp.bfloat16)
+    return kernel, q4, pools, _step_rows(seed, len(lengths), H, D, pools), \
+        tables, lens, layer
+
+
+def _bits(a):
+    return np.asarray(jax.lax.bitcast_convert_type(a, {
+        1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}[a.dtype.itemsize]))
+
+
+_BS = 32
+WRITE_CASES = {
+    # on, before and after a block boundary (and a tile's: 4 blocks)
+    "block-boundary": [_BS - 1, _BS, _BS + 1, IDLE, 4 * _BS - 1, 4 * _BS,
+                       IDLE, IDLE],
+    # a fresh row at length 0 on a block of its own, beside idle slots
+    "fresh-row": [0, IDLE, 5, IDLE],
+    # rows of many blocks: the last block is the third tile's second
+    "many-blocks": [9 * _BS + 7, IDLE, 10 * _BS - 1, 3, 8 * _BS, IDLE],
+    # no slot holds a sequence: the grid's one step puts back what it read
+    "idle-only": [IDLE, IDLE, IDLE],
+    # more writers than ``paged_most_writers`` of two pools: the program's
+    # other branch, every slot's row scattered (int8's four pools: the
+    # write call still)
+    "crowded": [5, 40, IDLE, 70, 100],
+}
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("case", sorted(WRITE_CASES))
+def test_paged_call_writes_the_steps_rows_as_the_scatter_did(kv, case):
+    """``rows=``: the call's output AND the pools it hands back are
+    scatter-then-attend's to the bit, in every layer and block but the
+    garbage block, which an idle slot's row went to and now no byte of
+    which moves (unless the step is so crowded that the call scatters
+    too: then the pools are the scatter's whole)."""
+    from deepspeed_tpu.ops.decode_attention import paged_most_writers
+
+    lengths = WRITE_CASES[case]
+    kernel, q4, pools, rows, tables, lens, layer = _writing_setup(
+        kv, lengths, _BS, 10, seed=len(case))
+    live = _live(lengths)
+    scatters = len(live) > paged_most_writers(len(lengths), len(pools))
+    assert scatters == (case == "crowded" and kv == "bf16")
+    want_pools = _scattered(pools, rows, tables, lens, layer)
+    with tpu_interpret_mode():
+        want = jax.block_until_ready(kernel(q4, *want_pools, tables, lens,
+                                            layer))
+        out, got_pools = jax.block_until_ready(kernel(
+            q4, *pools, tables, lens, layer, rows=rows))
+    np.testing.assert_array_equal(_bits(out)[live], _bits(want)[live])
+    np.testing.assert_array_equal(np.asarray(out, np.float32)[
+        [b for b in range(len(lengths)) if b not in live]], 0.0)
+    for before, got, scattered, new in zip(pools, got_pools, want_pools,
+                                           rows):
+        assert got.dtype == before.dtype and got.shape == before.shape
+        np.testing.assert_array_equal(_bits(got)[:, 1:],
+                                      _bits(scattered)[:, 1:])
+        np.testing.assert_array_equal(
+            _bits(got)[:, 0], _bits(scattered if scatters else before)[:, 0])
+        # ... and a busy row's new row is where its table says
+        for b in live:
+            ln = int(lens[b])
+            np.testing.assert_array_equal(
+                _bits(got[layer, tables[b, ln // _BS], ln % _BS]),
+                _bits(new[b, 0]))
+
+
+def test_paged_most_writers_is_where_the_two_writes_meet():
+    from deepspeed_tpu.ops.decode_attention import paged_most_writers
+
+    # (read on the chip at 32 slots: tools/probe_paged_kv_write.py)
+    assert paged_most_writers(32, 2) == 24 and paged_most_writers(32, 4) == 32
+    assert paged_most_writers(8, 2) == 6 and paged_most_writers(1, 2) == 0
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_paged_call_never_writes_a_block_two_tables_share(kv):
+    """The prefix cache's case: two rows whose tables start with the SAME
+    blocks, each appending to a block of its own (the engine copies on
+    write at admission). The shared blocks keep every byte, and a row
+    that brings no row (``valid`` 0) writes nothing and attends what its
+    pool holds."""
+    lengths = [2 * _BS + 3, 2 * _BS + 9, 5]
+    kernel, q4, pools, rows, tables, lens, layer = _writing_setup(
+        kv, lengths, _BS, 4, seed=11)
+    tables = tables.at[1, :2].set(tables[0, :2])
+    valid = jnp.asarray([1, 1, 0], jnp.int32)
+    want_pools = _scattered(pools, rows, tables, lens, layer, valid)
+    with tpu_interpret_mode():
+        want = jax.block_until_ready(kernel(q4, *want_pools, tables, lens,
+                                            layer))
+        out, got_pools = jax.block_until_ready(kernel(
+            q4, *pools, tables, lens, layer, rows=rows, valid=valid))
+    np.testing.assert_array_equal(_bits(out), _bits(want))
+    shared = np.asarray(tables[0, :2])
+    for before, got, scattered in zip(pools, got_pools, want_pools):
+        np.testing.assert_array_equal(_bits(got)[:, shared],
+                                      _bits(before)[:, shared])
+        np.testing.assert_array_equal(_bits(got)[:, 1:],
+                                      _bits(scattered)[:, 1:])
+        # the third row's block is as it was: it brought no row
+        np.testing.assert_array_equal(_bits(got)[:, tables[2, 0]],
+                                      _bits(before)[:, tables[2, 0]])
+
+
+def test_paged_write_list_names_the_writing_rows_and_their_places():
+    from deepspeed_tpu.ops import decode_attention as da
+
+    tables = jnp.asarray([[3, 4, 0], [0, 0, 0], [5, 6, 7], [8, 0, 0],
+                          [9, 1, 2]], jnp.int32)
+    lens = jnp.asarray([9, 0, 23, 0, 30], jnp.int32)
+    valid = jnp.asarray([1, 1, 1, 1, 0], jnp.int32)
+    order, count, block, offset = da.paged_write_list(lens, tables, valid, 8)
+    # row 1 is idle, row 4 brings no row; row 3 is fresh on its own block;
+    # row 2 has filled its table and writes into its last block
+    assert [int(x) for x in count] == [3]
+    assert [int(x) for x in order] == [0, 2, 3, 1, 4, 4]
+    assert [int(x) for x in block] == [4, da.GARBAGE_BLOCK, 7, 8,
+                                       da.GARBAGE_BLOCK]
+    assert [int(x) for x in offset] == [1, 0, 7, 0, 0]
+    work = da.paged_step_work(lens, tables, 1, 8, valid=valid)
+    assert len(work) == 6 and len(da.paged_step_work(lens, tables, 1, 8)) == 2
+    for a, b in zip(work[2:], (order, count, block, offset)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_paged_write_list_made_once_serves_every_layers_call(kv):
+    """``work=paged_step_work(..., valid=)``, made once a step, is what a
+    call makes for itself: every layer's call writes its own layer and no
+    other."""
+    from deepspeed_tpu.ops import decode_attention as da
+
+    lengths = [40, IDLE, 7]
+    kernel, q4, pools, rows, tables, lens, _ = _writing_setup(
+        kv, lengths, _BS, 4, seed=3)
+    work = da.paged_step_work(lens, tables, 1, _BS,
+                              valid=jnp.ones_like(lens))
+    with tpu_interpret_mode():
+        got = pools
+        for layer in range(LAYERS):
+            alone = jax.block_until_ready(kernel(
+                q4, *got, tables, lens, layer, rows=rows))
+            out, got = jax.block_until_ready(kernel(
+                q4, *got, tables, lens, layer, rows=rows, work=work))
+            np.testing.assert_array_equal(_bits(out), _bits(alone[0]))
+    want = pools
+    for layer in range(LAYERS):
+        want = _scattered(want, rows, tables, lens, layer)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(_bits(a)[:, 1:], _bits(b)[:, 1:])
+
+
+def test_paged_call_writes_one_row_a_sequence_or_refuses():
+    from deepspeed_tpu.ops import decode_attention as da
+
+    assert da.paged_call_writes(8, 1, 4) and not da.paged_call_writes(8, 3, 4)
+    q4, k_pool, v_pool, tables, lens, layer = _paged_setup(
+        2, [5, 9], 3, bs=8, mb=4)
+    rows = tuple(jnp.zeros((2, 3, 128), jnp.float32) for _ in range(2))
+    with pytest.raises(ValueError, match="ONE new row"):
+        da.decode_attention_paged(q4, k_pool, v_pool, tables, lens, layer,
+                                  rows=rows)
+    with pytest.raises(ValueError, match="ONE new row"):
+        da.decode_attention_paged(q4[:, :1], k_pool, v_pool, tables, lens,
+                                  layer, rows=tuple(
+                                      r[:, :1].astype(jnp.bfloat16)
+                                      for r in rows))
+
+
 def test_paged_live_row_on_the_garbage_block_is_attended():
     """Only a row of length 0 whose table starts at the garbage block is
     idle. A row that holds tokens is attended over whatever its table
@@ -1025,7 +1239,11 @@ def test_paged_model_steps_kernel_matches_dense(monkeypatch, scan_layers, kv):
     a 3-row verify step of a scanned stack (pool carried through the layer
     scan, the layer index scanned in) and of an unrolled one (static layer
     index), with the kernel reading the stacked pool at ``(layer, block)``,
-    give the dense gather path's logits and leave the same pool behind."""
+    give the dense gather path's logits and leave the same pool behind.
+    The decode steps run beside an idle third slot: the kernel's call
+    writes the two busy rows' own rows and not a byte of the garbage
+    block, which the dense path's scatter (and the verify step's, in both)
+    sends the idle slot's rows to."""
     from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
     from deepspeed_tpu.ops import attention as attn_mod
 
@@ -1038,8 +1256,11 @@ def test_paged_model_steps_kernel_matches_dense(monkeypatch, scan_layers, kv):
     n_prompt = jnp.asarray([6, 8], jnp.int32)
 
     def paging(lengths, num_valid, prefill=False):
-        return {"block_tables": tables, "lengths": lengths,
-                "num_valid": num_valid, "prefill": prefill}
+        # (a step's third slot is idle: length 0 on garbage blocks)
+        idle = lengths.shape[0] - tables.shape[0]
+        return {"block_tables": jnp.pad(tables, ((0, idle), (0, 0))),
+                "lengths": lengths, "num_valid": num_valid,
+                "prefill": prefill}
 
     variables = model.init(jax.random.PRNGKey(0), prompt,
                            paging=paging(jnp.zeros((2,), jnp.int32),
@@ -1054,7 +1275,8 @@ def test_paged_model_steps_kernel_matches_dense(monkeypatch, scan_layers, kv):
 
     def run(force):
         monkeypatch.setattr(attn_mod, "_FORCE_DECODE_KERNEL", force)
-        outs, cache, lengths = [], cache0, n_prompt
+        outs, cache, garbage = [], cache0, []
+        lengths = jnp.pad(n_prompt, (0, 1))
         with tpu_interpret_mode() if force else _null():
             _, vars_ = model.apply({**params, "cache": cache}, prompt,
                                    mutable=["cache"], paging=paging(
@@ -1063,18 +1285,26 @@ def test_paged_model_steps_kernel_matches_dense(monkeypatch, scan_layers, kv):
             cache = jax.block_until_ready(vars_["cache"])
             for t in (1, 1, 3):
                 tok = jnp.asarray(rng_tokens[len(outs)][:, :t])
+                garbage.append([np.asarray(leaf[:, 0]) for leaf in
+                                jax.tree_util.tree_leaves(cache)])
                 logits, vars_ = model.apply(
                     {**params, "cache": cache}, tok, mutable=["cache"],
-                    paging=paging(lengths, jnp.full((2,), t, jnp.int32)))
+                    paging=paging(lengths, jnp.full((3,), t, jnp.int32)))
                 logits, cache = jax.block_until_ready(
                     (logits, vars_["cache"]))
-                lengths = lengths + t
-                outs.append(np.asarray(logits))
-        return outs, cache
+                lengths = lengths + jnp.asarray([t, t, 0])
+                outs.append(np.asarray(logits)[:2])
+        return outs, cache, garbage
 
-    rng_tokens = rng.integers(0, 256, (3, 2, 3)).astype(np.int32)
-    dense, dense_cache = run(False)
-    kern, kern_cache = run(True)
+    rng_tokens = rng.integers(0, 256, (3, 3, 3)).astype(np.int32)
+    dense, dense_cache, dense_garbage = run(False)
+    kern, kern_cache, kern_garbage = run(True)
+    # the garbage block before the first decode step, the second and the
+    # verify step: the kernel's decode steps left it as the prefill did
+    for a, b in zip(kern_garbage[0], kern_garbage[2]):
+        np.testing.assert_array_equal(a, b)
+    assert any((a != b).any()
+               for a, b in zip(dense_garbage[0], dense_garbage[2]))
     for a, b in zip(dense, kern):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
     for a, b in zip(jax.tree_util.tree_leaves(dense_cache),

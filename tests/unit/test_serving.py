@@ -955,6 +955,42 @@ class TestServingFastPath:
         srv.drain()
         assert c.prefix_hit_tokens == 18
 
+    def test_a_decode_rows_write_block_is_never_shared(self):
+        """What GPT-2's paged decode call leans on (it rewrites the strip
+        of a busy row's LAST block that takes the step's row, and no other
+        block): at every decode step, every decode-ready row appends to a
+        block that it alone holds. Whole prompt blocks are shared and
+        never appended to; a shared partial tail is copied on write at
+        admission, before any step appends."""
+        from deepspeed_tpu.serving import ServingEngine
+
+        _, engine = _tiny_serving(serving={**_SERVING,
+                                           "prefix_cache": True})
+        srv = ServingEngine(engine)
+        bs = _SERVING["block_size"]
+        rng = np.random.default_rng(4)
+        system = rng.integers(1, 256, 2 * bs)       # two whole blocks
+        tail = rng.integers(1, 256, 3)              # ... and a partial one
+        donor = srv.submit(np.concatenate([system, tail]), max_new_tokens=2)
+        srv.drain()
+        reqs = [srv.submit(np.concatenate([system, tail, more]),
+                           max_new_tokens=bs + 3)   # over a block boundary
+                for more in (rng.integers(1, 256, 2), rng.integers(1, 256, 4),
+                             np.zeros(0, np.int64))]
+        seen = 0
+        while srv.pending:
+            srv.step()
+            for slot, req in srv._decode_ready():
+                block = int(srv._tables[slot][req.length // bs])
+                assert block != 0, (req.request_id, req.length)
+                assert srv.block_mgr.ref_count(block) == 1, (
+                    req.request_id, req.length, block)
+                seen += 1
+        assert seen > 3 * bs and donor.state == FINISHED
+        assert all(r.prefix_hit_tokens >= 2 * bs for r in reqs)
+        assert any(r.cow is not None or r.blocks_shared == 3 for r in reqs)
+        srv.destroy()
+
     def test_chunked_prefill_bitmatch_and_interleave(self):
         """Chunked prefill: a long prompt advances one budgeted chunk
         per step while decodes continue; a short request admitted behind

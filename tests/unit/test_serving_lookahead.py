@@ -354,6 +354,44 @@ def test_the_counters_say_how_often_the_loop_ran_ahead(tmp_path):
     srv.destroy()
 
 
+def test_the_steps_are_counted_by_who_wrote_their_kv_rows(monkeypatch):
+    """``stats()["kv_write"]`` beside ``decode_steps``: a step whose paged
+    kernel call wrote the step's rows itself (``"kernel"``: GPT-2's decode
+    program on the Pallas kernel, asked of the model here, since the loop
+    one step ahead cannot run under the interpreter) or whose program
+    scattered them (``"scatter"``: the dense gather path of a machine with
+    no TPU, every ``k + 1``-row verify step, and a step of more busy rows
+    than ``paged_most_writers``)."""
+    from deepspeed_tpu.ops import attention as attn_mod
+
+    _, srv = _serving()
+    for p, n in zip(_prompts((7, 5), seed=13), (4, 3)):
+        srv.submit(p, max_new_tokens=n)
+    srv.drain()
+    stats = srv.stats()
+    assert stats["decode_steps"] == 3
+    assert stats["kv_write"] == {"kernel": 0, "scatter": 3}
+    srv.reset_stats()
+    assert srv.stats()["kv_write"] == {"kernel": 0, "scatter": 3}
+    srv.destroy()
+
+    monkeypatch.setattr(attn_mod, "_FORCE_DECODE_KERNEL", True)
+    _, srv = _serving()
+    # three slots, two pools: two busy rows are written by the call, three
+    # are scattered; a verify step's rows always
+    assert [srv._kv_write_form(1, busy) for busy in (1, 2, 3)] == [
+        "kernel", "kernel", "scatter"]
+    assert srv._kv_write_form(3, 1) == "scatter"
+    srv._step_boundary(2, np.asarray([9, 0, 12]), 1)
+    srv._step_boundary(3, np.asarray([9, 4, 12]), 1)
+    assert srv.stats()["kv_write"] == {"kernel": 1, "scatter": 1}
+    assert srv.stats()["decode_steps"] == 2
+    srv.destroy()
+    _, srv = _serving({**_SERVING, "kv_cache_dtype": "int8"})
+    assert [srv._kv_write_form(1, busy) for busy in (2, 3)] == ["kernel"] * 2
+    srv.destroy()
+
+
 def test_with_a_proposer_nothing_is_ever_in_flight():
     engine, srv = _serving({**_SERVING, "speculative": {
         "enabled": True, "proposer": "prompt_lookup",
@@ -366,6 +404,9 @@ def test_with_a_proposer_nothing_is_ever_in_flight():
     for req, p in zip(reqs, prompts):
         assert req.tokens == _reference(engine, p, 7)
     assert srv.stats()["decode_ahead"] == {"steps": 0, "dropped_rows": 0}
+    # a verify step's k + 1 rows may straddle two blocks: they scatter
+    assert srv.stats()["kv_write"] == {
+        "kernel": 0, "scatter": srv.stats()["decode_steps"]}
     assert srv._feed_fn is None and srv._decode_fn is None
     srv.destroy()
 

@@ -83,12 +83,14 @@ class SwiGLU(nn.Module):
     hidden: int
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    limit: float = 0.0  # > 0: the clamped form (``dropless.glu``)
 
     @nn.compact
     def __call__(self, x):
         g = dense(self, "gate_proj", self.width)(x)
         u = dense(self, "up_proj", self.width)(x)
-        return dense(self, "down_proj", self.hidden)(nn.silu(g) * u)
+        return dense(self, "down_proj", self.hidden)(
+            dropless.glu(g, u, self.limit))
 
 
 # ---------------------------------------------------------------------------
@@ -233,10 +235,11 @@ class SparseFFN(nn.Module):
     weights of the share held here (rank ``ep_rank`` of ``ep_size``:
     ``moe/dropless.py``), and the shared experts where ``shared_width``
     says there are any, one SwiGLU of their summed width that every share
-    computes alike. ``scoring``, ``renormalize``, ``norm_eps`` and ``scale``
-    are ``dropless.route``'s; ``bias_std`` None is no selection bias (else
-    the std it is drawn with: a balancing term that training moves from
-    zero).
+    computes alike. ``scoring``, ``renormalize``, ``norm_eps``, ``scale``,
+    ``n_group`` and ``topk_group`` are ``dropless.route``'s; ``limit`` and
+    ``shared_limit`` clamp the experts' and the shared SwiGLU
+    (``dropless.glu``); ``bias_std`` None is no selection bias (else the
+    std it is drawn with: a balancing term that training moves from zero).
 
     Takes the float32 norm and returns the float32 sum of the held experts'
     terms, the layer's counters and the experts each token chose ``[B, T,
@@ -257,6 +260,10 @@ class SparseFFN(nn.Module):
     shared_width: int = 0
     ep_rank: int = 0
     ep_size: int = 1
+    n_group: int = 1
+    topk_group: int = 1
+    limit: float = 0.0
+    shared_limit: float = 0.0
 
     @nn.compact
     def __call__(self, x, valid=None):
@@ -280,15 +287,18 @@ class SparseFFN(nn.Module):
         experts, weights = dropless.route(
             rows, router, bias, self.top_k, norm_eps=self.norm_eps,
             scale=float(self.scale), scoring=self.scoring,
-            renormalize=self.renormalize)
+            renormalize=self.renormalize, n_group=self.n_group,
+            topk_group=self.topk_group)
         rows = rows.astype(dtype)
         y, counters = dropless.expert_ffn(
             rows, experts, weights, gate.astype(dtype), up.astype(dtype),
             down.astype(dtype), first_expert=first, n_routed=self.experts,
-            valid=None if valid is None else valid.reshape(b * t))
+            valid=None if valid is None else valid.reshape(b * t),
+            limit=self.limit)
         shared = ()
         if self.shared_width:
             shared = (SwiGLU(self.shared_width, d, dtype, self.param_dtype,
+                             self.shared_limit,
                              name="shared_experts")(x.astype(dtype)),)
         return (y.reshape(b, t, d), *(s.astype(jnp.float32) for s in shared),
                 counters, experts.reshape(b, t, -1))
@@ -320,7 +330,8 @@ class ServedConfig:
     fields ``decode``, ``paged``, ``paged_num_blocks``, ``paged_block_size``,
     ``paged_return_routed``, ``paged_<slot_knob>``; it says ``sparse(i)``
     (is layer ``i``'s FFN sparse) and ``sparse_ffn()`` (:class:`SparseFFN`'s
-    arguments), and may set the shell's scalars and ``embedding_std``."""
+    arguments; where they are a layer's own, ``sparse_ffn_at(i)``), and may
+    set the shell's scalars and ``embedding_std``."""
 
     # for_paged_decode's keyword for the decode slots (the seam's ``knob``)
     slot_knob = None
@@ -347,6 +358,12 @@ class ServedConfig:
         """Experts a token chooses over all its sparse layers: the width
         of a row of what ``paged_return_routed`` returns."""
         return self.sparse_layers * self.num_experts_per_tok
+
+    def sparse_ffn_at(self, i: int) -> dict:
+        """:class:`SparseFFN`'s arguments for layer ``i``: every sparse
+        layer's alike, unless the family says a layer's own (a clamp that
+        only some layers have)."""
+        return self.sparse_ffn()
 
     def pool_dims(self):
         """``(paged_num_blocks, paged_block_size)``, checked (block 0 is
@@ -418,11 +435,22 @@ class PagedDecoder(nn.Module):
     tied = False
     # the dense FFN's parameter type (None: the config's ``param_dtype``)
     dense_param_dtype = None
+    # a serving pool whose type is not the config's ``dtype`` (a float32
+    # state beside bfloat16 rows), by name
+    pool_dtypes = {}
 
     def pool_shapes(self, num_blocks: int, block_size: int) -> dict:
         """``{name: shape}`` of the serving pools (``cache`` variables of
-        ``config.dtype``), declared once by the model."""
+        ``config.dtype``, or of ``pool_dtypes[name]``), declared once by
+        the model."""
         raise NotImplementedError
+
+    def more_counters(self, routed, valid):
+        """What the family counts itself of a paged call's routed sets
+        (``routed``: each sparse layer's ``[B, T, k]``; ``valid [B, T]``),
+        ``int32[n]`` handed back behind the sparse layers' four and named
+        by the tail of its ``serve_counters``; None: the four alone."""
+        return None
 
     def step_work(self, paging):
         """What every layer's decode kernel shares of one step (the work
@@ -452,7 +480,7 @@ class PagedDecoder(nn.Module):
         if paged:
             variables = {
                 name: self.variable("cache", name, jnp.zeros, shape,
-                                    cfg.dtype)
+                                    self.pool_dtypes.get(name, cfg.dtype))
                 for name, shape in self.pool_shapes(*cfg.pool_dims()).items()}
             pools = {name: var.value for name, var in variables.items()}
             valid = paged_valid(paging, t)
@@ -478,7 +506,7 @@ class PagedDecoder(nn.Module):
             h = norm(f"{scope}_{self.norms[1]}")(x)
             if cfg.sparse(i):
                 y, *shared, c, chosen = SparseFFN(
-                    **cfg.sparse_ffn(), name=f"{scope}_mlp")(h, valid)
+                    **cfg.sparse_ffn_at(i), name=f"{scope}_mlp")(h, valid)
                 for term in shared:
                     y = y + term
                 counters = counters + c
@@ -500,6 +528,9 @@ class PagedDecoder(nn.Module):
         logits = _scaled(logits, 1.0 / cfg.logits_scaling)
         if not paged:
             return logits
+        more = self.more_counters(routed, valid)
+        if more is not None:
+            counters = jnp.concatenate([counters, more])
         aux = {"counters": counters}
         if cfg.paged_return_routed and routed:
             aux["routed"] = jnp.concatenate(routed, axis=-1)

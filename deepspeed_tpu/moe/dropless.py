@@ -11,7 +11,8 @@ experts it holds:
 - :func:`route` scores ALL ``n_routed`` experts in float32 (matmul,
   sigmoid, bias add and top-k: a routed set that flips at a near tie moves
   the output by more than rounding) and returns the chosen experts and
-  their weights;
+  their weights, chosen inside the best groups where the family routes by
+  groups (:func:`within_groups`);
 - :func:`expert_ffn` adds, for each token, the terms of the experts held
   HERE (``first_expert .. first_expert + E``) and nothing for the others:
   on one chip of an expert-parallel group that is the layer without its
@@ -26,6 +27,8 @@ that no token of the step chose is never read. The grid's length is the
 traced number of live tiles; buffers have the worst case's static shape.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -37,19 +40,46 @@ from deepspeed_tpu.utils.compat import tpu_compiler_params
 COUNTERS = ("experts_touched", "experts_held", "pairs_here", "pairs_all")
 
 
+def within_groups(select, top_k: int, n_group: int = 1, topk_group: int = 1):
+    """The ``top_k`` experts of each row of ``select [T, experts]`` (score
+    + bias), int ``[T, k]``. With ``n_group`` > 1 they are chosen INSIDE
+    the ``topk_group`` best of ``n_group`` groups of contiguous experts, a
+    group's score the sum of its two largest entries (group-limited
+    routing: a token's experts lie on at most ``topk_group`` of the
+    ``n_group`` chips that hold a group each). One group: the plain top
+    ``k``, the one operation there always was."""
+    if n_group > 1:
+        rows, experts = select.shape
+        grouped = select.reshape(rows, n_group, experts // n_group)
+        # (two maxima, the second with the first's place taken out: a
+        # ``top_k`` of 2 is a sort of every group on the chip)
+        first = jnp.argmax(grouped, axis=-1, keepdims=True)
+        rest = jnp.where(first == jnp.arange(grouped.shape[-1]), -jnp.inf,
+                         grouped)
+        score = jnp.max(grouped, axis=-1) + jnp.max(rest, axis=-1)
+        _, best = jax.lax.top_k(score, topk_group)
+        kept = jnp.any(best[..., None] == jnp.arange(n_group), axis=1)
+        select = jnp.where(kept[..., None], grouped, -jnp.inf).reshape(
+            rows, experts)
+    return jax.lax.top_k(select, top_k)[1]
+
+
 def route(x, router_kernel, selection_bias, top_k: int, *,
           norm_eps: float = 0.0, scale: float = 1.0,
-          scoring: str = "sigmoid", renormalize: bool = True):
+          scoring: str = "sigmoid", renormalize: bool = True,
+          n_group: int = 1, topk_group: int = 1):
     """``x [T, D]`` -> ``(experts [T, k] int32, weights [T, k] float32)``:
     ``s = scoring(x W_r)`` over every published expert, the top ``k`` of
-    ``s + bias`` chosen, ``w = scale * s[chosen] / (sum s[chosen] +
-    norm_eps)``. All float32. ``scoring`` is the family's own function of
-    the gate's logits: ``"sigmoid"``, a score an expert by itself (MiMo-V2,
-    LFM2-MoE), or ``"softmax"`` over all the experts (DeepSeek-V2, whose
-    chosen scores are the weights as they stand: ``renormalize=False``,
-    and which has no selection bias: ``None``). The two constants are a
-    family's own too (LFM2-MoE: 1e-6 and its ``routed_scaling_factor``).
-    At its defaults each argument adds no operation to the program."""
+    ``s + bias`` chosen (inside the ``topk_group`` best of ``n_group``
+    groups, where there are groups: :func:`within_groups`), ``w = scale *
+    s[chosen] / (sum s[chosen] + norm_eps)``. All float32. ``scoring`` is
+    the family's own function of the gate's logits: ``"sigmoid"``, a score
+    an expert by itself (MiMo-V2, LFM2-MoE), or ``"softmax"`` over all the
+    experts (DeepSeek-V2, whose chosen scores are the weights as they
+    stand: ``renormalize=False``, and which has no selection bias:
+    ``None``). The two constants are a family's own too (LFM2-MoE: 1e-6
+    and its ``routed_scaling_factor``). At its defaults each argument adds
+    no operation to the program."""
     logits = jnp.dot(
         x.astype(jnp.float32), router_kernel.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST)
@@ -62,7 +92,7 @@ def route(x, router_kernel, selection_bias, top_k: int, *,
     select = scores
     if selection_bias is not None:
         select = scores + selection_bias.astype(jnp.float32)[None]
-    _, experts = jax.lax.top_k(select, top_k)
+    experts = within_groups(select, top_k, n_group, topk_group)
     weights = jnp.take_along_axis(scores, experts, axis=1)
     if renormalize:
         total = jnp.sum(weights, axis=1, keepdims=True)
@@ -82,14 +112,24 @@ def _counters(local, held, n_held: int, valid):
         jnp.sum(counts), jnp.sum(valid, dtype=jnp.int32) * local.shape[1]])
 
 
-def _ffn_rows(x, gate, up, down):
+def glu(gate, up, limit: float = 0.0):
+    """``silu(gate) * up``; with ``limit`` > 0 the clamped form: ``gate <-
+    min(gate, limit)``, ``up <- clip(up, -limit, limit)`` first. At 0 the
+    operations there always were."""
+    if limit:
+        gate, up = jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+    return jax.nn.silu(gate) * up
+
+
+def _ffn_rows(x, gate, up, down, limit: float = 0.0):
     h = jnp.dot(x, gate, preferred_element_type=jnp.float32)
     u = jnp.dot(x, up, preferred_element_type=jnp.float32)
-    return jnp.dot((jax.nn.silu(h) * u).astype(x.dtype), down,
+    return jnp.dot(glu(h, u, limit).astype(x.dtype), down,
                    preferred_element_type=jnp.float32)
 
 
-def _expert_ffn_dense(x, local, held, weights, gate, up, down):
+def _expert_ffn_dense(x, local, held, weights, gate, up, down,
+                      limit: float = 0.0):
     """The plain form: every held expert over every token, weighted by the
     token's weight for it (0 where it did not choose it). Reads every
     held expert and multiplies ``E`` times too much: the oracle, and what
@@ -101,14 +141,14 @@ def _expert_ffn_dense(x, local, held, weights, gate, up, down):
 
     def one(carry, ew):
         g, u, d, c = ew
-        return carry + c[:, None] * _ffn_rows(x, g, u, d), None
+        return carry + c[:, None] * _ffn_rows(x, g, u, d, limit), None
 
     out, _ = jax.lax.scan(one, jnp.zeros(x.shape, jnp.float32),
                           (gate, up, down, combine.T))
     return out
 
 
-def _gmm_kernel(expert_ref, x_ref, g_ref, u_ref, d_ref, o_ref):
+def _gmm_kernel(expert_ref, x_ref, g_ref, u_ref, d_ref, o_ref, *, limit):
     # the output tile stays in VMEM across the steps over the experts'
     # width (same block index): it is the float32 accumulator
     @pl.when(pl.program_id(1) == 0)
@@ -118,7 +158,7 @@ def _gmm_kernel(expert_ref, x_ref, g_ref, u_ref, d_ref, o_ref):
     x = x_ref[...]
     h = jnp.dot(x, g_ref[...], preferred_element_type=jnp.float32)
     u = jnp.dot(x, u_ref[...], preferred_element_type=jnp.float32)
-    o_ref[...] += jnp.dot((jax.nn.silu(h) * u).astype(x.dtype), d_ref[...],
+    o_ref[...] += jnp.dot(glu(h, u, limit).astype(x.dtype), d_ref[...],
                           preferred_element_type=jnp.float32)
 
 
@@ -163,12 +203,13 @@ def _note_grouped_tile(rows_shape, gate_shape, tile_rows: int, tile_f: int):
 
 
 def grouped_ffn(rows, tile_expert, live_tiles, gate, up, down, *,
-                tile_rows: int, tile_f: int = 512):
+                tile_rows: int, tile_f: int = 512, limit: float = 0.0):
     """``rows [R, D]`` (each tile of ``tile_rows`` rows belongs to expert
-    ``tile_expert[i]``) -> ``down_e(silu(gate_e r) * up_e r)`` a row, in
-    float32 (the caller weights and sums them: rounding each term first
-    would cost what the float32 accumulator held), for the first
-    ``live_tiles`` tiles; rows of later tiles are not written."""
+    ``tile_expert[i]``) -> ``down_e(silu(gate_e r) * up_e r)`` a row
+    (:func:`glu` at ``limit``), in float32 (the caller weights and sums
+    them: rounding each term first would cost what the float32 accumulator
+    held), for the first ``live_tiles`` tiles; rows of later tiles are not
+    written."""
     n_rows, d = rows.shape
     n_held, _, f = gate.shape
     tile_f = width_tile(f, tile_f)
@@ -197,7 +238,7 @@ def grouped_ffn(rows, tile_expert, live_tiles, gate, up, down, *,
     # no ``name=``: the device trace prints the kernel under the caller's
     # scope (``moe._expert_matmul.N``), which the benchmark's reader matches
     return pl.pallas_call(
-        _gmm_kernel,
+        functools.partial(_gmm_kernel, limit=limit),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_rows, d), jnp.float32),
         compiler_params=tpu_compiler_params(
@@ -215,7 +256,7 @@ def tile_rows_for(tokens: int, top_k: int, n_routed: int) -> int:
 
 
 def _expert_ffn_grouped(x, local, held, weights, counts, gate, up, down,
-                        tile_rows: int):
+                        tile_rows: int, limit: float = 0.0):
     tokens, d = x.shape
     top_k = local.shape[1]
     n_held = gate.shape[0]
@@ -245,7 +286,8 @@ def _expert_ffn_grouped(x, local, held, weights, counts, gate, up, down,
         n_held - 1).astype(jnp.int32)
     with jax.named_scope("moe._expert_matmul"):
         out_rows = grouped_ffn(rows, tile_expert, ends[-1] // tile_rows,
-                               gate, up, down, tile_rows=tile_rows)
+                               gate, up, down, tile_rows=tile_rows,
+                               limit=limit)
     # back to the pairs; a pair whose expert lives elsewhere adds nothing
     # (its row index points past the rows, some of which no tile wrote)
     picked = out_rows[jnp.minimum(row_of_pair, n_rows - 1)].reshape(
@@ -255,7 +297,8 @@ def _expert_ffn_grouped(x, local, held, weights, counts, gate, up, down,
 
 
 def expert_ffn(x, experts, weights, gate, up, down, *, first_expert: int,
-               valid=None, n_routed: int = 0, use_kernel=None):
+               valid=None, n_routed: int = 0, use_kernel=None,
+               limit: float = 0.0):
     """The held experts' part of the layer's output.
 
     Args:
@@ -268,6 +311,7 @@ def expert_ffn(x, experts, weights, gate, up, down, *, first_expert: int,
       use_kernel: the Pallas grouped matmul (default: on a TPU) or the
         dense oracle; which of them a program took is counted under
         ``moe_experts_*`` beside the attention paths.
+      limit: > 0: the experts' SwiGLU clamped (:func:`glu`).
 
     Returns ``(y [T, D] float32, counters int32)``: ``COUNTERS``, this
     call's (``experts_held`` is ``E``: what ``experts_touched`` is out of).
@@ -290,9 +334,9 @@ def expert_ffn(x, experts, weights, gate, up, down, *, first_expert: int,
         tile_rows = tile_rows_for(x.shape[0], experts.shape[1],
                                   n_routed or n_held)
         y = _expert_ffn_grouped(x, local, held, weights, counts, gate, up,
-                                down, tile_rows)
+                                down, tile_rows, limit)
     else:
-        y = _expert_ffn_dense(x, local, held, weights, gate, up, down)
+        y = _expert_ffn_dense(x, local, held, weights, gate, up, down, limit)
     return y, counters
 
 

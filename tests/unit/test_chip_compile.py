@@ -1329,3 +1329,83 @@ def test_no_program_relays_a_weight_the_engine_laid_out(
     assert moved == {"q_proj/kernel", f"{also}/kernel"}
     assert cell["leaves_moved"] == 2 * layers
     assert {tuple(m["major_to_minor"]) for m in cell["moved"]} == {(1, 0)}
+
+
+# Ling-3.0-flash at its published widths: 32 KDA heads of 128 x 128 float32
+# state a slot a layer, 7 KDA layers and 128 decode slots in the benchmark
+# cell; a prefill chunk of 512 positions in 32 sub-chunks of 16; one latent
+# layer of 32 heads over rows of 640 lanes; 64 held experts of 3 x 2560 x 768
+def test_kda_state_update_kernel_is_in_place_and_named(one_chip):
+    """The decode step's delta-rule update compiles for the v5e over the
+    cell's whole state pool (1.9 GB of float32), the pool aliased to its
+    output (no copy of it in the program), under the name the parked
+    reader matches."""
+    from deepspeed_tpu.ops import kda_state_update
+
+    pattern = _reader_pattern("kda_decode_roofline_share")
+    rows, heads, width = 128, 32, 128
+    assert kda_state_update.kernel_serves(heads, width, width)
+
+    def step(pool, slots, alpha, k, v, q, beta):
+        return kda_state_update.state_update_kernel(
+            pool, 3, slots, alpha, k, v, q, beta)
+
+    f32 = jnp.float32
+    vec = _s(one_chip, (rows, heads, width), f32)
+    text = jax.jit(step, donate_argnums=0).lower(
+        _s(one_chip, (7, 1 + rows, heads, width, width), f32),
+        _s(one_chip, (rows,), jnp.int32), vec, vec, vec, vec,
+        _s(one_chip, (rows, heads), f32)).compile().as_text()
+    calls = _custom_calls(text)
+    assert len(calls) == 1 and pattern.search(calls[0]), calls
+    assert "f32[7,129,32,128,128]" in calls[0]
+    # the pool goes in and comes out as one buffer: nothing copies it
+    copies = [ln for ln in text.splitlines()
+              if " copy(" in ln and "f32[7,129,32,128,128]" in ln]
+    assert not copies, copies[:2]
+
+
+def test_kda_chunk_kernel_at_a_prefill_chunks_shape(one_chip):
+    """The chunk form at the cell's chunk of 512 positions: the terms by
+    XLA (one triangular solve a call), the carry through the sub-chunks by
+    the Pallas kernel, under the name the parked reader matches."""
+    from deepspeed_tpu.ops import kda_chunk
+
+    pattern = _reader_pattern("kda_chunk_roofline_share")
+    t, heads, width = 512, 32, 128
+    assert kda_chunk.kernel_serves(heads, width, width)
+
+    def chunk(q, k, v, g, beta, state):
+        return kda_chunk.kda_chunk(q, k, v, g, beta, state, use_kernel=True)
+
+    f32 = jnp.float32
+    vec = _s(one_chip, (1, t, heads, width), f32)
+    text = _compiled_text(chunk, vec, vec, vec, vec,
+                          _s(one_chip, (1, t, heads), f32),
+                          _s(one_chip, (1, heads, width, width), f32))
+    calls = [c for c in _custom_calls(text)]
+    assert len(calls) == 1 and pattern.search(calls[0]), calls
+    assert not _reader_pattern("kda_decode_roofline_share").search(calls[0])
+
+
+def test_the_clamped_grouped_expert_kernel_compiles(one_chip):
+    """The grouped matmul with the clamp on its SwiGLU (a layer whose
+    ``expert_swiglu_limit_list`` entry is not 0) at Ling-3.0-flash's
+    widths, a decode step's 128 rows, under the experts' reader's name."""
+    from deepspeed_tpu.moe.dropless import expert_ffn
+
+    pattern = _reader_pattern("expert_matmul_roofline_share")
+
+    def layer(x, experts, weights, gate, up, down):
+        return expert_ffn(x, experts, weights, gate, up, down,
+                          first_expert=0, n_routed=512, use_kernel=True,
+                          limit=4.0)
+
+    text = _compiled_text(
+        layer, _s(one_chip, (128, 2560)),
+        _s(one_chip, (128, 8), jnp.int32),
+        _s(one_chip, (128, 8), jnp.float32),
+        _s(one_chip, (64, 2560, 768)), _s(one_chip, (64, 2560, 768)),
+        _s(one_chip, (64, 768, 2560)))
+    calls = _custom_calls(text)
+    assert len(calls) == 1 and pattern.search(calls[0]), calls

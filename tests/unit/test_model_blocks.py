@@ -298,8 +298,9 @@ def test_the_sparse_ffn_calls_dropless_through_the_module(monkeypatch):
                              dtype=jnp.float32)
     layer.init_with_output(jax.random.PRNGKey(0), jnp.ones((1, 3, 8)))
     assert [name for name, _ in seen] == ["route", "expert_ffn"]
-    assert seen[0][1] == ["norm_eps", "renormalize", "scale", "scoring"]
-    assert seen[1][1] == ["first_expert", "n_routed", "valid"]
+    assert seen[0][1] == ["n_group", "norm_eps", "renormalize", "scale",
+                          "scoring", "topk_group"]
+    assert seen[1][1] == ["first_expert", "limit", "n_routed", "valid"]
 
 
 def test_the_paged_step_counts_under_its_callers_label():

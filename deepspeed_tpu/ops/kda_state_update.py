@@ -17,21 +17,34 @@ products and the rank-one update happen on the tile while it is in VMEM.
 
 THE LAYOUT is the allocation's: a head's ``[K, V]`` tile has the key
 channels down the sublanes and the values along the lanes (whole registers
-at 128 x 128 either way round). In it the two products ``S'^T k`` and ``S^T
-q`` are sums DOWN the sublanes (adds of whole registers and one sublane
-reduction) and ``v``, ``u`` and ``o`` are dense lane rows; what varies by key
-channel (``alpha``, ``k``, ``q``) is needed as a COLUMN broadcast along the
-lanes, which the kernel makes of each lane row by a transpose of its
-sublane broadcast (the one change of axis of the step, as
-``ops/ssm_state_update.py`` turns ``B`` and ``C``; no other operation
-crosses a lane).
+at 128 x 128 either way round). In it the two products ``S'^T k`` and
+``S'^T q`` are sums DOWN the sublanes (adds of whole registers and one
+sublane reduction) and ``v``, ``u`` and ``o`` are dense lane rows; what
+varies by key channel (``alpha``, ``k``, ``q``) arrives as lane rows and is
+needed as a COLUMN broadcast along the lanes. The kernel turns each of the
+three ONCE A GRID STEP, for all the step's heads together (``[tile, K] ->
+[K, tile]``: the one transpose of the step, into VMEM scratch), and a head
+then takes its own lane of the turned tile across all 128 (a lane
+broadcast a register: sixteen an operand). Until PR 58 each column was a
+transpose of a sublane-broadcast ``[128, 128]`` tile, three a head: sixteen
+registers in and sixteen out for 128 lanes that are all alike. Both
+products come off ONE pass over the decayed state, ``o = S'^T q + (k . q)
+u`` (``S^T q`` with ``S = S' + k u^T`` multiplied out), so the ``q`` product
+does not wait for the rank-one update; the state written is the same to the
+bit, ``o`` rounds one float32 sum otherwise. On a v5e every cross-lane
+operation (a transpose's register, a lane broadcast, a lane sum) is a push
+and a pop on one of three units that each take one every 6-7 cycles, so
+the 48 lane broadcasts a head keep them busy for some 110 cycles: the
+loop body a head is 139 bundles (169 until PR 58) against 154 cycles of a
+head's bytes (PERF.md, PR 58).
 
 - :func:`state_update_kernel`: the Pallas kernel. The pool is aliased to
-  its output; a grid step is a busy row and a tile of heads, the row's pool
-  row found through scalar prefetch (the block table's last entry), so a
-  state goes HBM -> VMEM -> HBM once, and an idle slot has no step (its
-  state is not touched, its ``o`` is zero). It serves where
-  :func:`kernel_serves` says the tiles are whole registers.
+  its output; a grid step is a busy row and a tile of heads (the whole row
+  at the published 32), the row's pool row found through scalar prefetch
+  (the block table's last entry), so a state goes HBM -> VMEM -> HBM once,
+  and an idle slot has no step (its state is not touched, its ``o`` is
+  zero). It serves where :func:`kernel_serves` says the tiles are whole
+  registers.
 - :func:`state_update_xla`: the same float32 arithmetic in XLA (a gather,
   the update, a scatter), elsewhere and where no TPU is.
 
@@ -53,16 +66,22 @@ from jax.experimental.pallas import tpu as pltpu
 from deepspeed_tpu.ops.ssm_state_update import busy_rows  # noqa: F401
 from deepspeed_tpu.utils.compat import tpu_compiler_params
 
-# heads a grid step: 8 x [128, 128] float32 are 0.5 MB a block, in and out
-# double-buffered 2 MB of VMEM
-HEAD_TILE = 8
+LANES = 128
+# heads a grid step, at most: the whole row at the published 32 heads. 32 x
+# [128, 128] float32 are 2 MB a block, in and out double-buffered 8 MB of
+# VMEM beside 192 kB of turned operands (inside the 16 MB a kernel may take
+# by default). On a v5e the probe (tools/probe_kda_state_update.py, 7 layer
+# calls on the cell's pool, 40 / 85 / 110 busy rows) read tiles of 8 / 16 /
+# 32 heads at 62.8-64.1% / 69.9-71.6% / 73.5-75.8% of the bytes' time
+# (PERF.md, PR 58): a grid step's own cost is paid once a row
+HEAD_TILE = 32
 
 
 def kernel_serves(heads: int, key: int, value: int) -> bool:
     """Whether the Pallas kernel's tiles are whole registers at these
-    sizes: a head's state 128 lanes of values (the column broadcast is a
-    transpose of a ``[128, 128]`` tile: ``K`` 128 too)."""
-    return key == 128 and value == 128 and heads >= 1
+    sizes: a head's state 128 lanes of values, and 128 key channels (a
+    turned operand's sublanes are the state tile's)."""
+    return key == LANES and value == LANES and heads >= 1
 
 
 def state_update_xla(pool, layer, slot_rows, alpha, k, v, q, beta):
@@ -80,25 +99,31 @@ def state_update_xla(pool, layer, slot_rows, alpha, k, v, q, beta):
 
 
 def _kernel(order_ref, count_ref, slots_ref, layer_ref, alpha_ref, k_ref,
-            q_ref, v_ref, beta_ref, pool_ref, o_ref, out_ref, *, tile):
+            q_ref, v_ref, beta_ref, pool_ref, o_ref, out_ref, alpha_t, k_t,
+            q_t, kq_row, *, tile):
     del order_ref, count_ref, slots_ref, layer_ref
-    keys, values = pool_ref.shape[-2:]
-
-    def column(ref, at):
-        # a lane row [1, K] -> the same values down the sublanes, every
-        # lane alike: [K, V]
-        return jnp.broadcast_to(ref[at, :], (values, keys)).T
+    keys = pool_ref.shape[-2]
+    # the step's one change of axis, for all its heads: [tile, K] -> [K,
+    # tile], head h in lane h
+    alpha_t[:, :tile] = alpha_ref[...].T
+    k_t[:, :tile] = k_ref[...].T
+    q_t[:, :tile] = q_ref[...].T
+    # k . q a head, across the lanes of its row
+    kq_row[...] = jnp.broadcast_to(jnp.sum(
+        k_ref[...] * q_ref[...], axis=1, keepdims=True), kq_row.shape)
 
     def head(h, carry):
         at = pl.ds(h, 1)
-        k_col = column(k_ref, at)
-        state = column(alpha_ref, at) * pool_ref[h].astype(jnp.float32)
-        u = beta_ref[at, :] * (v_ref[at, :] - jnp.sum(
-            state * k_col, axis=0, keepdims=True))            # [1, V]
-        state = state + k_col * u
-        o_ref[at, :] = jnp.sum(state * column(q_ref, at), axis=0,
-                               keepdims=True)
-        out_ref[h] = state.astype(out_ref.dtype)
+        lane = jnp.full((keys, LANES), h, jnp.int32)
+        # a head's lane of a turned tile across all the lanes: [K, V]
+        column = lambda turned: jnp.take_along_axis(turned[...], lane, axis=1)
+        k_col = column(k_t)
+        state = column(alpha_t) * pool_ref[h].astype(jnp.float32)
+        miss = v_ref[at, :] - jnp.sum(state * k_col, axis=0, keepdims=True)
+        read = jnp.sum(state * column(q_t), axis=0, keepdims=True)
+        u = beta_ref[at, :] * miss                            # [1, V]
+        o_ref[at, :] = read + kq_row[at, :] * u
+        out_ref[h] = (state + k_col * u).astype(out_ref.dtype)
         return carry
 
     jax.lax.fori_loop(0, tile, head, 0)
@@ -108,15 +133,16 @@ def state_update_kernel(pool, layer, slot_rows, alpha, k, v, q, beta,
                         work=None, head_tile: int = HEAD_TILE):
     """:func:`state_update_xla`'s arguments and result, the pool updated in
     place (aliased), idle rows skipped (their ``o`` is 0). ``work``:
-    :func:`busy_rows` of ``slot_rows``."""
+    :func:`busy_rows` of ``slot_rows``; ``head_tile``: the most heads a
+    grid step takes."""
     _, heads, keys = k.shape
     if not kernel_serves(heads, keys, v.shape[-1]):
         raise ValueError(
             f"{heads} heads of {keys} x {v.shape[-1]} are not whole "
             "registers (kernel_serves): state_update_xla serves them")
-    tile = min(head_tile, heads)
-    if heads % tile:
-        raise ValueError(f"{heads} heads in tiles of {tile}")
+    # the most heads a step that divide the row, not above ``head_tile``
+    tile = max(t for t in range(1, min(head_tile, heads) + 1)
+               if heads % t == 0)
     order, count = busy_rows(slot_rows) if work is None else work
     return _update(order, count, jnp.asarray(slot_rows, jnp.int32),
                    jnp.asarray(layer, jnp.int32).reshape(1), alpha, k, v, q,
@@ -146,6 +172,10 @@ def _update(order, count, slot_rows, at, alpha, k, v, q, beta, pool, *, tile):
         in_specs=[key_rows, key_rows, key_rows, value_rows, value_rows,
                   in_pool],
         out_specs=[value_rows, in_pool],
+        scratch_shapes=[pltpu.VMEM((keys, LANES), f32),      # alpha turned
+                        pltpu.VMEM((keys, LANES), f32),      # k
+                        pltpu.VMEM((keys, LANES), f32),      # q
+                        pltpu.VMEM((tile, LANES), f32)],     # k . q
     )
     # no ``name=``: the device trace prints the kernel under the innermost
     # scope (``kda_state_update.N``), which the benchmark's reader matches

@@ -299,6 +299,100 @@ def test_a_decode_step_is_the_recurrence(highest, form):
         assert np.array_equal(np.asarray(after[1, 0]), np.asarray(pool[1, 0]))
 
 
+@pytest.mark.parametrize("heads,head_tile,rows", [
+    (4, 2, [3, 0, 1, 5, 2]),     # two head tiles a row, five rows
+    (3, 1, [2, 4, 0]),           # a head a grid step
+    (32, 32, [0, 2]),            # the cell's 32 heads in ONE grid step
+    (32, 16, [1]),               # and in two
+    (2, 2, [0, 0, 0]),           # every row idle: the grid's one step
+], ids=["two-tiles", "a-head-a-step", "32-heads-a-step", "32-in-two",
+        "all-idle"])
+def test_the_step_kernel_in_its_tiles(highest, heads, head_tile, rows):
+    """The Pallas kernel's form by head tile and row count, layer 1 of 2,
+    against one step of the recurrence: the busy rows' output and states;
+    an idle row's output zero; every row no busy batch row names as it was
+    (pool row 0 is the idle rows' to write)."""
+    from deepspeed_tpu.utils.compat import tpu_interpret_mode
+
+    key = 128
+    held = 1 + max(max(rows), 2)
+    pool = jnp.asarray(np.random.default_rng(1).normal(
+        size=(2, held, heads, key, key)), jnp.float32)
+    slot_rows = jnp.asarray(rows, jnp.int32)
+    alpha, k, v, q, beta = _step_args(len(rows), heads, key, key)
+    want, left = _recurrence(q[:, None], k[:, None], v[:, None],
+                             jnp.log(alpha)[:, None], beta[:, None],
+                             pool[1, slot_rows])
+    with tpu_interpret_mode():
+        got, after = jax.block_until_ready(jax.jit(
+            lambda *a: kda_state_update.state_update_kernel(
+                a[0], 1, *a[1:], head_tile=head_tile))(
+                    pool, slot_rows, alpha, k, v, q, beta))
+    busy = np.flatnonzero(np.asarray(rows))
+    idle = np.flatnonzero(np.asarray(rows) == 0)
+    if len(busy):
+        assert np.abs(np.asarray(got - want[:, 0]))[busy].max() <= 1e-6
+        assert np.abs(np.asarray(after[1, slot_rows] - left))[
+            busy].max() <= 1e-6
+    assert not np.asarray(got)[idle].any()
+    assert np.array_equal(np.asarray(after[0]), np.asarray(pool[0]))
+    for row in set(range(1, held)) - set(rows):
+        assert np.array_equal(np.asarray(after[1, row]),
+                              np.asarray(pool[1, row]))
+
+
+@pytest.mark.parametrize("heads,head_tile,tile", [
+    (32, 32, 32), (32, 16, 16), (32, 8, 8), (4, 32, 4), (3, 2, 1),
+    (48, 32, 24), (40, 32, 20)])
+def test_a_grid_step_takes_the_most_heads_that_divide_the_row(
+        monkeypatch, heads, head_tile, tile):
+    """``head_tile`` is the most a step may take: the tile is the largest
+    divisor of the heads not above it (the whole row at the published
+    32)."""
+    seen = {}
+
+    def spy(*args, tile):
+        seen["tile"] = tile
+        return None, None
+
+    monkeypatch.setattr(kda_state_update, "_update", spy)
+    assert kda_state_update.HEAD_TILE == 32
+    pool = jnp.zeros((1, 2, heads, 128, 128), jnp.float32)
+    vec = jnp.zeros((1, heads, 128), jnp.float32)
+    kda_state_update.state_update_kernel(
+        pool, 0, jnp.asarray([1], jnp.int32), vec, vec, vec, vec,
+        jnp.zeros((1, heads), jnp.float32), head_tile=head_tile)
+    assert seen["tile"] == tile
+
+
+def test_a_fresh_row_forgets_its_slots_last_tenant_through_the_kernel(
+        highest):
+    """``alpha = 0`` through the Pallas kernel: the step is the recurrence
+    from zeros whatever the slot held (finite values: ``0 * S = 0``), and
+    the row beside it, not fresh, keeps its own."""
+    from deepspeed_tpu.utils.compat import tpu_interpret_mode
+
+    heads, key = 2, 128
+    pool = jnp.asarray(1e3 * np.random.default_rng(1).normal(
+        size=(1, 3, heads, key, key)), jnp.float32)
+    rows = jnp.asarray([2, 1], jnp.int32)
+    alpha, k, v, q, beta = _step_args(2, heads, key, key)
+    alpha = alpha.at[0].set(0.0)
+    start = pool[0, rows].at[0].set(0.0)
+    decay = jnp.log(alpha.at[0].set(1.0))
+    want, left = _recurrence(q[:, None], k[:, None], v[:, None],
+                             decay[:, None], beta[:, None], start)
+    with tpu_interpret_mode():
+        got, after = jax.block_until_ready(jax.jit(
+            lambda *a: kda_state_update.state_update_kernel(
+                a[0], 0, *a[1:]))(pool, rows, alpha, k, v, q, beta))
+    assert np.abs(np.asarray(got - want[:, 0]))[0].max() <= 1e-6
+    assert np.abs(np.asarray(after[0, rows] - left))[0].max() <= 1e-6
+    # the other row's state is of the order of 1e3: float32's last place
+    assert np.abs(np.asarray(after[0, rows] - left))[1].max() <= 1e-3
+    assert np.abs(np.asarray(got - want[:, 0]))[1].max() <= 1e-3
+
+
 def test_a_fresh_row_forgets_its_slots_last_tenant(highest):
     """``alpha = 0`` for a row whose sequence starts here: the step is the
     recurrence from zeros whatever the slot held."""

@@ -519,7 +519,7 @@ class BailingHybridForCausalLM(blocks.PagedDecoder):
     serve_counters = dropless.COUNTERS + ("tokens_group_here",
                                           "tokens_routed")
 
-    def more_counters(self, routed, valid):
+    def more_counters(self, routed, valid, pools):
         cfg = self.config
         first, count = dropless.held_range(cfg.num_experts, cfg.ep_rank,
                                            cfg.ep_size)

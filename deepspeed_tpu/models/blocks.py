@@ -445,9 +445,10 @@ class PagedDecoder(nn.Module):
         the model."""
         raise NotImplementedError
 
-    def more_counters(self, routed, valid):
-        """What the family counts itself of a paged call's routed sets
-        (``routed``: each sparse layer's ``[B, T, k]``; ``valid [B, T]``),
+    def more_counters(self, routed, valid, pools):
+        """What the family counts itself of a paged call: of its routed
+        sets (``routed``: each sparse layer's ``[B, T, k]``; ``valid [B,
+        T]``) or of what its mixers left in ``pools`` beside the pools;
         ``int32[n]`` handed back behind the sparse layers' four and named
         by the tail of its ``serve_counters``; None: the four alone."""
         return None
@@ -528,10 +529,12 @@ class PagedDecoder(nn.Module):
         logits = _scaled(logits, 1.0 / cfg.logits_scaling)
         if not paged:
             return logits
-        more = self.more_counters(routed, valid)
+        more = self.more_counters(routed, valid, pools)
         if more is not None:
             counters = jnp.concatenate([counters, more])
         aux = {"counters": counters}
+        if "selected" in pools:     # the keys each layer's queries chose
+            aux["selected"] = jnp.stack(pools["selected"], axis=2)
         if cfg.paged_return_routed and routed:
             aux["routed"] = jnp.concatenate(routed, axis=-1)
         return logits, aux

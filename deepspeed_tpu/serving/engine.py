@@ -117,7 +117,12 @@ _IDLE_SAMP = (0, 0, 1.0, 0, 0.0)
 # one decode step on the device's queue, its tokens not fetched yet: the
 # program's output (still on the device), the (slot, request) pairs it was
 # dispatched for, and the lengths it was dispatched with
-_Flight = collections.namedtuple("_Flight", "toks pairs lengths")
+# (``selected``: the step's chosen keys, still on the device, in a list
+# that is empty where the model's programs return none)
+_Flight = collections.namedtuple("_Flight", "toks pairs lengths selected")
+# finished requests whose chosen keys are kept (``keep_selected``): a
+# 30,000-token request's are 0.6 GB on the host
+SELECTED_KEYS_KEPT = 8
 
 
 def _model_window(model_config) -> Optional[int]:
@@ -212,6 +217,13 @@ class ServingEngine(process_ledger.FirstCalls):
                     f"{type(self.engine.module).__name__}'s serving "
                     "programs return no routed experts")
             knobs["return_routed"] = True
+        # request id -> uint32 [tokens processed, layers, words]: the keys
+        # each query chose, for the requests that asked
+        # (``submit(.., keep_selected=True)``), the oldest dropped past
+        # ``SELECTED_KEYS_KEPT``. A model whose programs return the sets
+        # returns them always (``serve_selected``); they stay on the device
+        # unless a request asks
+        self._selected_kept: Dict[str, np.ndarray] = {}
         dcfg = mcfg.for_paged_decode(self.num_blocks, bs, **knobs)
         self._routed_width = (dcfg.routed_width
                               if "return_routed" in knobs else 0)
@@ -487,6 +499,31 @@ class ServingEngine(process_ledger.FirstCalls):
         return tail.reshape(rows, -1, self._routed_width) if tail.size \
             else None
 
+    def _returns(self, tok, out, cache):
+        """What a serving program returns: the tokens (with the counters
+        behind them) and the pool; and, from a model whose calls hand back
+        the keys they chose, those, which stay on the device unless a
+        request asked for them."""
+        head = (self._with_counters(tok, out), cache)
+        aux = out[1] if isinstance(out, tuple) and len(out) > 1 else None
+        if isinstance(aux, dict) and "selected" in aux:
+            return head + (aux["selected"],)
+        return head
+
+    def _keep_selected(self, req: Request, selected, row: int, tokens: int):
+        """Fetch the chosen keys of ``tokens`` queries of one row of a
+        call, for a request that asked."""
+        if selected and req.keep_selected:
+            req.selected.append(np.asarray(selected[0][row, :tokens]))
+
+    def selected_keys(self, request_id: str) -> Optional[np.ndarray]:
+        """``uint32 [tokens, layers, words]``: the keys each query the
+        programs processed for a finished request chose, a layer (key ``j``
+        bit ``j % 32`` of word ``j // 32``); None for a request that did not
+        ask (``keep_selected``) or is not among the last
+        ``SELECTED_KEYS_KEPT`` that did."""
+        return self._selected_kept.get(request_id)
+
     def routed_experts(self, request_id: str) -> Optional[np.ndarray]:
         """``int32 [tokens, sparse layers x k]``: the experts each token
         the programs processed for a finished request chose (the prompt,
@@ -556,7 +593,7 @@ class ServingEngine(process_ledger.FirstCalls):
                 # prompt length — num_valid itself
                 tok = keyed_sample(last, seeds, num_valid, flags, temps,
                                    top_ks, top_ps)
-                return self._with_counters(tok, out), vars_["cache"]
+                return self._returns(tok, out, vars_["cache"])
 
             return self._jit(kfn, f"serving_prefill_T{T}",
                              f"serving.prefill[T={T}]")
@@ -573,8 +610,8 @@ class ServingEngine(process_ledger.FirstCalls):
             # (right padding: index num_valid-1)
             last = jnp.take_along_axis(
                 logits, (num_valid - 1)[:, None, None], axis=1)[:, 0]
-            return (self._with_counters(self._sample(last, rng), out),
-                    vars_["cache"])
+            return self._returns(self._sample(last, rng), out,
+                                 vars_["cache"])
 
         return self._jit(fn, f"serving_prefill_T{T}",
                          f"serving.prefill[T={T}]")
@@ -611,7 +648,7 @@ class ServingEngine(process_ledger.FirstCalls):
                 # last token sits at position lengths)
                 tok = keyed_sample(logits, seeds, lengths + 1, flags,
                                    temps, top_ks, top_ps)
-                return self._with_counters(tok, out), vars_["cache"]
+                return self._returns(tok, out, vars_["cache"])
 
             return kfn
 
@@ -623,8 +660,8 @@ class ServingEngine(process_ledger.FirstCalls):
                                        tokens, mutable=["cache"],
                                        paging=paging)
             logits = logits_of(out)[:, -1]
-            return (self._with_counters(self._sample(logits, rng), out),
-                    vars_["cache"])
+            return self._returns(self._sample(logits, rng), out,
+                                 vars_["cache"])
 
         return fn
 
@@ -821,7 +858,7 @@ class ServingEngine(process_ledger.FirstCalls):
                 # chunked and unchunked admission sample the same token
                 tok = keyed_sample(last, seeds, lengths + num_valid,
                                    flags, temps, top_ks, top_ps)
-                return self._with_counters(tok, out), vars_["cache"]
+                return self._returns(tok, out, vars_["cache"])
 
             return self._jit(kfn, f"serving_chunk_T{T}",
                              f"serving.chunk[T={T}]")
@@ -834,8 +871,8 @@ class ServingEngine(process_ledger.FirstCalls):
             logits = logits_of(out)
             last = jnp.take_along_axis(
                 logits, (num_valid - 1)[:, None, None], axis=1)[:, 0]
-            return (self._with_counters(self._sample(last, rng), out),
-                    vars_["cache"])
+            return self._returns(self._sample(last, rng), out,
+                                 vars_["cache"])
 
         return self._jit(fn, f"serving_chunk_T{T}",
                          f"serving.chunk[T={T}]")
@@ -947,6 +984,11 @@ class ServingEngine(process_ledger.FirstCalls):
         prompt = [int(t) for t in np.asarray(prompt).ravel()]
         req = Request(prompt=prompt, max_new_tokens=int(max_new_tokens),
                       **kwargs)
+        if req.keep_selected and not getattr(
+                type(self.engine.module), "serve_selected", False):
+            raise ValueError(
+                f"keep_selected: {type(self.engine.module).__name__}'s "
+                "serving programs return no selected keys")
         if self.sched.submit(req):
             self.resilience.serving_request_begin()
             self.telemetry.emit("serving", "request.queued",
@@ -1099,7 +1141,7 @@ class ServingEngine(process_ledger.FirstCalls):
                 ids[0, :req.prompt_len] = req.prompt
                 tail = (self._req_samp_args(req) if self._keyed
                         else (self._next_rng(),))
-                tok, self.cache = self._prefill_fns[T](
+                tok, self.cache, *selected = self._prefill_fns[T](
                     self.engine.params, self.cache, jnp.asarray(ids),
                     jnp.asarray(table[None]),
                     jnp.asarray([req.prompt_len], jnp.int32), *tail)
@@ -1109,6 +1151,7 @@ class ServingEngine(process_ledger.FirstCalls):
             self._first_result()
         self._count("prefill", tok[1:])
         self._keep_routed(req, self._routed(tok, 1), req.prompt_len)
+        self._keep_selected(req, selected, 0, req.prompt_len)
         tok = int(tok[0])
         self._prefill_done(req, ph)
         req.prefill_chunks = 1
@@ -1178,7 +1221,7 @@ class ServingEngine(process_ledger.FirstCalls):
                 ids[0, :step_len] = req.prompt[pos:pos + step_len]
                 tail = (self._req_samp_args(req) if self._keyed
                         else (self._next_rng(),))
-                tok, self.cache = self._chunk_fns[T](
+                tok, self.cache, *selected = self._chunk_fns[T](
                     self.engine.params, self.cache, jnp.asarray(ids),
                     jnp.asarray(table[None]), jnp.asarray([pos], jnp.int32),
                     jnp.asarray([step_len], jnp.int32), *tail)
@@ -1188,6 +1231,7 @@ class ServingEngine(process_ledger.FirstCalls):
             self._first_result()
         self._count("prefill", tok[1:])
         self._keep_routed(req, self._routed(tok, 1), step_len)
+        self._keep_selected(req, selected, 0, step_len)
         self._prefill_done(req, ph)
         return int(tok[0])
 
@@ -1314,6 +1358,7 @@ class ServingEngine(process_ledger.FirstCalls):
                     continue
                 if routed is not None:
                     req.routed.append(routed[slot])
+                self._keep_selected(req, flight.selected, slot, 1)
                 tok = int(toks[slot])
                 req.length += 1
                 self._lengths[slot] = req.length
@@ -1364,7 +1409,7 @@ class ServingEngine(process_ledger.FirstCalls):
         fresh = np.where(onward, -1, np.where(live, self._last_tokens, 0))
         tail = (self._slot_samp_args(live) if self._keyed
                 else (self._next_rng(),))
-        toks, self.cache = self._decode_fn(
+        toks, self.cache, *selected = self._decode_fn(
             self.engine.params, self.cache,
             self._feed_fn(self._prev_toks, fresh.astype(np.int32)),
             jnp.asarray(np.where(live[:, None], self._tables, 0)),
@@ -1372,7 +1417,7 @@ class ServingEngine(process_ledger.FirstCalls):
         self._prev_toks = toks
         if behind is not None:
             self._ledger["decode_ahead_steps"] += 1
-        return _Flight(toks, pairs, lengths)
+        return _Flight(toks, pairs, lengths, selected)
 
     def _flush(self) -> List[Request]:
         """Fetch and deliver the step in flight, if there is one: what
@@ -1591,6 +1636,12 @@ class ServingEngine(process_ledger.FirstCalls):
             kept[req.request_id] = np.concatenate(req.routed)
             req.routed = []
             while len(kept) > self.config.routed_experts_kept:
+                del kept[next(iter(kept))]
+        if req.selected:
+            kept = self._selected_kept
+            kept[req.request_id] = np.concatenate(req.selected)
+            req.selected = []
+            while len(kept) > SELECTED_KEYS_KEPT:
                 del kept[next(iter(kept))]
 
     def _record(self, req: Request, shed: bool, began: bool):

@@ -82,6 +82,10 @@ class Request:
     # under serving.routed_experts_kept: the chosen experts of each call
     # that processed tokens of this request, [tokens, layers x k] a call
     routed: list = dataclasses.field(default_factory=list)
+    # where the request asks (a model whose programs return them): the
+    # keys each processed query chose, [tokens, layers, words] a call
+    keep_selected: bool = False
+    selected: list = dataclasses.field(default_factory=list)
     live_mark: Optional[tuple] = None
     finish_mark: Optional[tuple] = None
     # ---- span-tracing context (telemetry/tracing.py) ----

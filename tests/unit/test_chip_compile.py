@@ -1409,3 +1409,30 @@ def test_the_clamped_grouped_expert_kernel_compiles(one_chip):
         _s(one_chip, (64, 768, 2560)))
     calls = _custom_calls(text)
     assert len(calls) == 1 and pattern.search(calls[0]), calls
+
+
+# DeepSeek-V3.2 at its published widths: 128 heads of 128 + 64 over a
+# latent row of 512 + 64 values in 640 lanes, a 512-token chunk against a
+# table of 32,768 keys; the benchmark cell's sizes
+def test_the_masked_chunk_attention_kernel_at_the_published_widths(one_chip):
+    """The sparse attention's chunk kernel (eight heads x 512 keys a grid
+    step: the tile's rows through the group's slice of ``W_kvb`` in VMEM,
+    1 MB of float32 scores a head) compiles for the v5e at 128 heads under
+    the name the parked reader matches, and is the program's one kernel."""
+    from deepspeed_tpu.ops import dsa_sparse_attend
+
+    pattern = _reader_pattern("dsa_attend_roofline_share")
+    t, heads, keys = 512, 128, 32768
+
+    def chunk(q_nope, q_pe, rows, w_kvb, mask, live):
+        return dsa_sparse_attend.attend_masked(
+            q_nope, q_pe, rows, w_kvb, mask, live, scale=0.1352)
+
+    text = _compiled_text(
+        chunk, _s(one_chip, (t, heads, 128)), _s(one_chip, (t, heads, 64)),
+        _s(one_chip, (keys, 640)), _s(one_chip, (512, heads, 256)),
+        _s(one_chip, (t, keys // 32), jnp.uint32),
+        _s(one_chip, (), jnp.int32))
+    calls = _custom_calls(text)
+    assert len(calls) == 1 and pattern.search(calls[0]), calls
+    assert "dsa_sparse_attend" in calls[0]

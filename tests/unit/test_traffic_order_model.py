@@ -83,3 +83,71 @@ def test_the_command_line(capsys, tmp_path):
     train = os.path.join(REPO, "perfbench", "traffic", "pretrain-1024.json")
     assert model.main([train]) == 2
     assert model.main([]) == 2
+
+
+def test_a_chunk_takes_longer_behind_more_keys():
+    """``key_s``: attention that reads every live key (a sparse-attention
+    model's masked chunk): the second chunk of a prompt has eight keys
+    behind it, the first four."""
+    flat = model.tokens_in_window([_request(0.0, 8, 3)], 3.1, **LOOP)
+    assert flat == 3                  # chunks end at 1, 2; steps at 2.5, 3
+    # 1 + 4 x 0.05 and 1 + 8 x 0.05: the first token at 2.6, then 3.1
+    assert model.tokens_in_window([_request(0.0, 8, 3)], 3.0, key_s=0.05,
+                                  **LOOP) == 1
+    assert model.tokens_in_window([_request(0.0, 8, 3)], 3.15, key_s=0.05,
+                                  **LOOP) == 2
+
+
+def test_the_two_programs_speeds_are_told_apart():
+    loop = {**LOOP, "slots": 6}
+    reqs = [_request(0.1 * i, 8, 12) for i in range(4)]
+    count = lambda **speeds: model.tokens_in_window(reqs, 12.0, **speeds,
+                                                    **loop)
+    # eight chunks, then four rows a step: both programs hold the count
+    assert (count(), count(chunk_speed=1.25), count(step_speed=1.25),
+            count(speed=1.25)) == (26, 42, 34, 47)
+    assert count(chunk_speed=1.25, step_speed=1.25) == count(speed=1.25)
+
+
+LONG_CTX = os.path.join(REPO, "perfbench", "traffic", "long-ctx-qa.json")
+# the chip's times for that cell's two programs (PERF.md, PR 59): a chunk
+# of 512 tokens 29 ms + 3.7 us a live key (44 / 87 / 140 ms at 4k / 16k /
+# 30k), a decode step 19 ms + 0.2 ms a busy row, 16 slots
+LONG_CTX_LOOP = dict(chunk_tokens=512, chunk_s=0.029, key_s=3.7e-6,
+                     step_s=0.019, row_s=0.0002, slots=16)
+
+
+def test_the_long_context_cells_count_hears_the_engine_and_stays_steady():
+    """``serve-dsv32-dsa-longctx`` is rated BELOW its knee, so its
+    ``served_tok_s`` moves with the engine's speed only through the order
+    of its mix. An order that ends the window on a lone prefill is deaf
+    (0.0-0.2 here: a chunk 10% slower passes a 1% bound); one that moves
+    one for one passes on the machine's own run-to-run variance whole (two
+    sets of six spread 0.66% and 1.48% on the chip, where a new cell is
+    admitted under 0.5%: PERF.md, PR 59). The committed order stands
+    between: a quarter to two fifths of a change of speed, the chunks'
+    part the larger, and nothing lost to a stall before the last quarter
+    of the window."""
+    with open(LONG_CTX) as f:
+        mix = json.load(f)
+    got = model.score(mix, mix["schedule_seed"], 50.0, 1.5, **LONG_CTX_LOOP)
+    assert 0.2 <= got["per_speed"] <= 0.45
+    assert 0.2 <= got["per_speed_tenth"] <= 0.45
+    assert got["per_chunk_speed"] >= 0.15
+    assert got["per_chunk_speed"] > got["per_step_speed"] > 0.05
+    assert got["stall_loss"] < 0.002
+    deaf = model.score(mix, 5900000014, 50.0, 1.5, **LONG_CTX_LOOP)
+    assert deaf["per_speed_tenth"] < 0.15 and deaf["per_step_speed"] < 0.06
+    whole = model.score(mix, 5900000499, 50.0, 1.5, **LONG_CTX_LOOP)
+    assert whole["per_speed"] > 0.8 and whole["stall_loss"] > 0.015
+
+
+def test_a_ranking_looks_for_the_sensitivity_it_is_asked_for(capsys):
+    assert model.main([LONG_CTX, "--rank", "12", "--from-seed", "5900000490",
+                       "--per-speed", "1.0", "--chunk-tokens", "512",
+                       "--chunk-s", "0.029", "--key-s", "3.7e-6", "--step-s",
+                       "0.019", "--row-s", "0.0002", "--slots", "16"]) == 0
+    rows = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+    assert len(rows) == 10
+    assert rows[0]["schedule_seed"] == 5900000499
+    assert abs(rows[0]["per_speed"] - 1.0) < abs(rows[-1]["per_speed"] - 1.0)

@@ -37,9 +37,13 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def tokens_in_window(reqs, window, chunk_tokens, chunk_s, step_s, row_s,
-                     slots, speed=1.0, stall=None):
+                     slots, speed=1.0, stall=None, key_s=0.0,
+                     chunk_speed=1.0, step_speed=1.0):
     """Tokens delivered by ``window`` seconds; ``reqs`` as
-    ``perfbench.traffic.requests`` gives them, ``stall = (at_s, for_s)``."""
+    ``perfbench.traffic.requests`` gives them, ``stall = (at_s, for_s)``;
+    a chunk takes ``chunk_s + key_s x the keys live behind it`` (attention
+    that reads every live key), ``chunk_speed`` and ``step_speed`` on top
+    of ``speed`` for the two programs apart."""
     due = [r["due_s"] for r in reqs]
     todo = [len(r["prompt"]) for r in reqs]
     new = [r["max_new_tokens"] for r in reqs]
@@ -59,7 +63,8 @@ def tokens_in_window(reqs, window, chunk_tokens, chunk_s, step_s, row_s,
             turn %= len(prefilling)
             item = prefilling[turn]
             item[1] += chunk_tokens
-            t += chunk_s / speed
+            t += (chunk_s + key_s * min(item[1], todo[item[0]])) / (
+                speed * chunk_speed)
             if item[1] >= todo[item[0]]:
                 prefilling.pop(turn)
                 count += t < window
@@ -68,7 +73,7 @@ def tokens_in_window(reqs, window, chunk_tokens, chunk_s, step_s, row_s,
             else:
                 turn += 1
         if live:
-            t += (step_s + row_s * len(live)) / speed
+            t += (step_s + row_s * len(live)) / (speed * step_speed)
             count += len(live) * (t < window)
             for i in [i for i in live if live[i] + 1 >= new[i]]:
                 del live[i]
@@ -87,10 +92,20 @@ def score(mix, order, window, stall_s, **loop):
     base = tokens_in_window(reqs, window, **loop)
     slow, fast = (tokens_in_window(reqs, window, speed=s, **loop)
                   for s in (0.98, 1.02))
+    # the two programs apart, and a tenth instead of a fiftieth: an order
+    # whose count moves by steps (a request falls inside the window or out)
+    # reads otherwise at the two widths
+    apart = {f"per_{name}": (
+        tokens_in_window(reqs, window, **{name: 1.02}, **loop)
+        - tokens_in_window(reqs, window, **{name: 0.98}, **loop))
+        / base / 0.04 for name in ("chunk_speed", "step_speed")}
+    wide = (tokens_in_window(reqs, window, speed=1.1, **loop)
+            - tokens_in_window(reqs, window, speed=0.9, **loop)) / base / 0.2
     stalled = [tokens_in_window(reqs, window, stall=(q * window, stall_s),
                                 **loop) for q in (0.25, 0.5, 0.75)]
     return {"schedule_seed": int(order), "tokens_s": base / window,
             "per_speed": (fast - slow) / base / 0.04,
+            "per_speed_tenth": wide, **apart,
             "stall_loss": max(base - s for s in stalled) / base}
 
 
@@ -100,12 +115,20 @@ def main(argv=None) -> int:
     parser.add_argument("--window", type=float, default=50.0)
     parser.add_argument("--rank", type=int, default=0,
                         help="score this many orders and print the ten "
-                             "that move least (0: the mix's own order)")
+                             "nearest --per-speed (0: the mix's own order)")
+    parser.add_argument("--per-speed", type=float, default=0.0,
+                        help="the change of the count over the change of "
+                             "speed a ranking looks for: 0 is a count deaf "
+                             "to the engine (a mix ABOVE its knee has the "
+                             "rate to say it), 1 is one for one")
     parser.add_argument("--from-seed", type=int, default=1)
     parser.add_argument("--chunk-tokens", type=int, default=512)
     parser.add_argument("--chunk-s", type=float, default=0.046)
     parser.add_argument("--step-s", type=float, default=0.018)
     parser.add_argument("--row-s", type=float, default=0.00015)
+    parser.add_argument("--key-s", type=float, default=0.0,
+                        help="seconds a chunk takes more for each key "
+                             "live behind it")
     parser.add_argument("--slots", type=int, default=64)
     parser.add_argument("--stall-s", type=float, default=1.5)
     try:
@@ -118,12 +141,15 @@ def main(argv=None) -> int:
         print(f"{args.mix}: not a mix of kind 'requests'", file=sys.stderr)
         return 2
     loop = dict(chunk_tokens=args.chunk_tokens, chunk_s=args.chunk_s,
-                step_s=args.step_s, row_s=args.row_s, slots=args.slots)
+                step_s=args.step_s, row_s=args.row_s, slots=args.slots,
+                key_s=args.key_s)
     orders = (range(args.from_seed, args.from_seed + args.rank) if args.rank
               else [mix.get("schedule_seed", 0)])
     rows = [score(mix, order, args.window, args.stall_s, **loop)
             for order in orders]
-    rows.sort(key=lambda r: r["per_speed"] + 10 * r["stall_loss"])
+    rows.sort(key=lambda r: abs(r["per_speed"] - args.per_speed)
+              + abs(r["per_speed_tenth"] - args.per_speed)
+              + 10 * r["stall_loss"])
     for row in rows[:10]:
         print(json.dumps(row))
     return 0

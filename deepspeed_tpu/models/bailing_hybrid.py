@@ -555,9 +555,9 @@ class BailingHybridForCausalLM(blocks.PagedDecoder):
         update's the busy rows."""
         from deepspeed_tpu.ops.latent_decode_attention import latent_step_work
 
-        tables = paging["block_tables"]
+        cfg, tables = self.config, paging["block_tables"]
         return (latent_step_work(paging["lengths"], tables[:, :-1],
-                                 self.config.paged_block_size),
+                                 cfg.paged_block_size, cfg.latent_lanes),
                 kda_state_update.busy_rows(tables[:, -1]))
 
     def mixer(self, i, u, paging, pools, work):

@@ -494,8 +494,9 @@ class DeepseekV2ForCausalLM(blocks.PagedDecoder):
         every layer."""
         from deepspeed_tpu.ops.latent_decode_attention import latent_step_work
 
+        cfg = self.config
         return latent_step_work(paging["lengths"], paging["block_tables"],
-                                self.config.paged_block_size)
+                                cfg.paged_block_size, cfg.latent_lanes)
 
     def mixer(self, i, u, paging, pools, work):
         a, pool = LatentAttention(self.config, name=f"layers_{i}_attn")(

@@ -953,29 +953,42 @@ def test_grouped_expert_kernel_at_lfm2s_widths(one_chip, tokens):
 # DeepSeek-V2-Lite at its published widths: 16 heads over ONE latent row a
 # token of 512 + 64 values in 640 lanes; 64 held experts of 3 x 2048 x 1408;
 # the benchmark cell's 48 decode slots of 512 blocks of 32
-def test_latent_decode_kernel_at_the_published_widths(one_chip):
+# ... and Ling-3.0-flash's one latent layer: 32 heads, 128 slots of 352
+# blocks over the default pool of a full context a slot
+@pytest.mark.parametrize("heads,slots,layers,per_row", [
+    (16, 48, 6, 512), (32, 128, 1, 352)], ids=["dsv2lite", "ling3"])
+def test_latent_decode_kernel_at_the_published_widths(one_chip, heads, slots,
+                                                      layers, per_row):
     """The absorbed-weights multi-query kernel compiles for the v5e over
-    rows of 640 lanes whose first 512 are also the values, at the cell's
-    pool (6 layers, 16,385 blocks) and tables, under the name the
-    benchmark's reader matches and no other reader does."""
+    rows of 640 lanes whose first 512 are also the values, at BOTH cells'
+    pools and tables (Mosaic's answer to the kernel's own copies of a
+    block of 32 x 640 into a tile of the plan's 32), under the name the
+    benchmark's reader matches and no other reader does; its two tiles
+    are all it takes of scoped VMEM beside a row's query and sums."""
     from deepspeed_tpu.ops.latent_decode_attention import (
-        decode_attention_latent)
+        decode_attention_latent, latent_plan)
 
     pattern = _reader_pattern("mla_decode_roofline_share")
-    slots, bs, blocks, per_row = 48, 32, 16385, 512
+    bs = 32
+    blocks = 16385 if layers == 6 else 1 + slots * per_row
+    plan = latent_plan(bs, 640, per_row)
+    assert (plan.tile_blocks, plan.tile_keys) == (32, 1024)
+    # two tiles of 1,024 keys of 640 lanes in bfloat16: 2.5 MiB
+    assert 2 * plan.tile_keys * 640 * 2 == 2_621_440
 
     def step(q, pool, tables, lengths):
         with jax.named_scope("attn._latent_kv_attend"):
-            return decode_attention_latent(q, pool, tables, lengths, 5,
-                                           rank=512, scale=0.1147)
+            return decode_attention_latent(q, pool, tables, lengths,
+                                           layers - 1, rank=512,
+                                           scale=0.1147)
 
     text = _compiled_text(
-        step, _s(one_chip, (slots, 1, 16, 640)),
-        _s(one_chip, (6, blocks, bs, 640)),
+        step, _s(one_chip, (slots, 1, heads, 640)),
+        _s(one_chip, (layers, blocks, bs, 640)),
         _s(one_chip, (slots, per_row), jnp.int32),
         _s(one_chip, (slots,), jnp.int32))
     calls = _custom_calls(text)
-    assert calls and all(pattern.search(ln) for ln in calls), calls
+    assert len(calls) == 1 and pattern.search(calls[0]), calls
     for other in ("hybrid_decode_roofline_share",
                   "paged_decode_roofline_share"):
         assert not any(_reader_pattern(other).search(ln) for ln in calls)
@@ -1113,11 +1126,13 @@ def test_ssd_chunk_scan_kernel_at_a_prefill_chunks_shape(one_chip):
 # callers' line numbers): recorded at commit 46ccfdd (the parent of PR 50)
 # by the function below, and equal there and here; but GPT-2's decode
 # program, which PR 55 meant to change (its paged kernel call writes the
-# step's rows: ``2387c053ad0ea1c7`` until then) and recorded anew.
+# step's rows: ``2387c053ad0ea1c7`` until then) and recorded anew, and
+# DeepSeek's, which PR 60 meant to (the latent kernel copies its own tiles,
+# one lowering for the six layers: ``0454bbe358502ca6`` until then).
 _PARENT_PROGRAMS = {
     ("gpt2-xl", "decode"): "941ef119564ed3b4",
     ("gpt2-xl", "prefill"): "5cde9a5d74d5fc46",
-    ("deepseek-v2-lite-l6", "decode"): "0454bbe358502ca6",
+    ("deepseek-v2-lite-l6", "decode"): "6ebb6607600491db",
     ("deepseek-v2-lite-l6", "chunk"): "b47959bec6b2899b",
 }
 # configuration -> slots, pool blocks, a sequence's blocks, the tokens of

@@ -489,6 +489,171 @@ def test_the_latent_kernel_is_the_absorbed_xla_path(monkeypatch):
     assert np.abs(np.asarray(want[1])).max() > 1e-3
 
 
+def _absorbed_by_hand(q, pool, tables, lengths, layer, rank, scale):
+    """The absorbed step in numpy, a busy row at a time: the row's keys
+    gathered through its table up to the query's position, one softmax a
+    head, the values the keys' first ``rank`` lanes; an idle row zeros."""
+    q, pool = np.asarray(q, np.float64), np.asarray(pool, np.float64)
+    out = np.zeros(q.shape[:3] + (rank,))
+    for b, (table, n) in enumerate(zip(tables, lengths)):
+        if table[0] == 0:
+            continue
+        rows = pool[layer, table].reshape(-1, pool.shape[-1])[:n + 1]
+        s = q[b, 0] @ rows.T * scale
+        p = np.exp(s - s.max(-1, keepdims=True))
+        out[b, 0] = (p / p.sum(-1, keepdims=True)) @ rows[:, :rank]
+    return out
+
+
+@pytest.mark.parametrize("lengths,heads,nan", [
+    ([21], 16, False),
+    ([2, 40], 16, False),
+    ([9, None, 33, None, 16], 32, False),
+    ([None, None, None], 16, False),
+    ([21, None, 3, 40], 32, True),
+    ([15, 31, 0], 16, True),
+], ids=["scrambled-ends-mid-block-mid-tile", "one-block-then-a-long-row",
+        "idle-between-busy-32-heads", "idle-slots-only",
+        "nan-in-dead-blocks-and-past-the-position",
+        "rows-that-fill-their-tiles-and-a-fresh-row"])
+def test_the_latent_kernel_copies_its_own_tiles(monkeypatch, lengths, heads,
+                                                nan):
+    """The kernel alone (interpret mode) in tiles of 4 blocks of 4 keys
+    against the absorbed step by hand, tables in scrambled block order
+    (``None``: an idle slot, length 0 on the garbage block): a row whose
+    live prefix ends inside a block and inside a tile (the tile's other
+    blocks are neither named nor copied); a row of one block before one of
+    three tiles (the next row's first tile is on its way across the
+    boundary); idle slots between busy rows and a batch of nothing else
+    (one step on no row); 16 and 32 heads; and a pool that holds NaN in
+    every row no query may see (dead blocks, the boundary block's tail)."""
+    from deepspeed_tpu.ops.latent_decode_attention import (
+        decode_attention_latent)
+    from deepspeed_tpu.utils.compat import tpu_interpret_mode
+
+    bs, lanes, rank, live_lanes, per_row, layers = 4, 256, 128, 192, 12, 2
+    rng = np.random.default_rng(len(lengths) * 100 + heads)
+    blocks = 1 + len(lengths) * per_row
+    free = 1 + rng.permutation(blocks - 1)
+    tables = np.zeros((len(lengths), per_row), np.int32)
+    seen = np.zeros((blocks, bs), bool)
+    for b, n in enumerate(lengths):
+        if n is None:
+            continue
+        mine = free[b * per_row:b * per_row + n // bs + 1]
+        tables[b, :len(mine)] = mine
+        seen[mine] = True
+        seen[mine[-1], n % bs + 1:] = False
+    lens = np.asarray([n or 0 for n in lengths], np.int32)
+    pool = rng.normal(size=(layers, blocks, bs, lanes)).astype(np.float32)
+    pool[..., live_lanes:] = 0.0
+    if nan:
+        pool[:, ~seen] = np.nan
+    q = rng.normal(size=(len(lengths), 1, heads, lanes)).astype(np.float32)
+    q[..., live_lanes:] = 0.0
+    monkeypatch.setattr(latent_decode_attention, "LATENT_TILE_KEYS", 16)
+    with tpu_interpret_mode():
+        got = np.asarray(jax.block_until_ready(decode_attention_latent(
+            jnp.asarray(q), jnp.asarray(pool), jnp.asarray(tables),
+            jnp.asarray(lens), 1, rank=rank, scale=0.2)))
+    want = _absorbed_by_hand(q, pool, tables, lens, 1, rank, 0.2)
+    assert got.shape == want.shape and np.isfinite(got).all()
+    assert np.abs(got - want).max() <= 1e-5
+    idle = [n is None for n in lengths]
+    assert (got[idle] == 0).all()
+    if not all(idle):
+        assert np.abs(want).max() > 1e-2
+
+
+def test_latent_plan_reads_its_tile_from_the_shapes(monkeypatch):
+    """As many blocks as hold 1,024 keys, no more than a table row has
+    and than two tiles fit their share of VMEM in: both cells' shapes
+    (rows of 640 lanes over tables of 512 and 352 blocks of 32), short
+    tables, larger blocks, rows too wide for a whole tile; and the constant
+    is read when the plan is made."""
+    from deepspeed_tpu.ops.latent_decode_attention import latent_plan
+
+    assert [latent_plan(32, 640, mb).tile_blocks
+            for mb in (512, 352, 8, 1)] == [32, 32, 8, 1]
+    assert latent_plan(32, 640, 512).tile_keys == 1024
+    assert latent_plan(128, 640, 64).tile_blocks == 8
+    assert latent_plan(2048, 640, 64).tile_blocks == 1
+    assert latent_plan(32, 8192, 128).tile_blocks == 8
+    monkeypatch.setattr(latent_decode_attention, "LATENT_TILE_KEYS", 128)
+    assert latent_plan(32, 640, 512) == (4, 32, 512)
+
+
+@pytest.mark.parametrize("family", ["deepseek_v2", "bailing_hybrid"])
+def test_latent_families_list_the_kernels_work_once_a_step(monkeypatch,
+                                                           family):
+    """A served latent family lists the kernel's work once a traced decode
+    step in the plan's tiles, whatever its layers, and counts the form it
+    took where ``stats()["attention_paths"]`` reads it; the latent layers
+    of the program are one trace of the kernel's call; a prefill step runs
+    no kernel and lists nothing."""
+    import importlib
+
+    from deepspeed_tpu.ops import attention as attn_mod
+
+    mod = importlib.import_module(f"deepspeed_tpu.models.{family}")
+    bs, slots, per_row = 4, 3, 4
+    if family == "deepseek_v2":
+        cfg = DeepseekV2Config.tiny().for_paged_decode(13, bs)
+        model, latent = DeepseekV2ForCausalLM(cfg), cfg.num_hidden_layers
+    else:
+        cfg = mod.BailingHybridConfig.tiny(
+            kv_lora_rank=128).for_paged_decode(13, bs, state_slots=slots)
+        model = mod.BailingHybridForCausalLM(cfg)
+        latent = len(cfg.layers_of(mod.LATENT))
+    entries = cfg.paged_slot_state_for(bs)["entries"] \
+        if family == "bailing_hybrid" else 0
+    tables = np.zeros((slots, per_row + entries), np.int32)
+    tables[0, :2], tables[2, :1] = [3, 5], [7]       # slot 1 is idle
+    if entries:
+        tables[[0, 2], per_row:] = [[1], [3]]
+
+    def paging(lengths, n, prefill):
+        return {"block_tables": jnp.asarray(tables),
+                "lengths": jnp.asarray(lengths, jnp.int32),
+                "num_valid": jnp.full((slots,), n, jnp.int32),
+                "prefill": prefill}
+
+    prompt = jnp.zeros((slots, 4), jnp.int32)
+    variables = model.init(jax.random.PRNGKey(0), prompt,
+                           paging=paging([0] * slots, 4, True))
+    made = []
+    real = latent_decode_attention.latent_step_work
+
+    def spy(lengths, block_tables, block_size, lanes):
+        made.append((block_tables.shape[-1], block_size, lanes))
+        return real(lengths, block_tables, block_size, lanes)
+
+    monkeypatch.setattr(latent_decode_attention, "latent_step_work", spy)
+    monkeypatch.setattr(attn_mod, "_FORCE_DECODE_KERNEL", True)
+    name = f"latent_decode_tile{per_row * bs}"
+    counted = attn_mod.dispatch_counts().get(name, 0)
+    bodies = []
+    body = latent_decode_attention._kernel
+    monkeypatch.setattr(latent_decode_attention, "_kernel", lambda *a, **kw:
+                        bodies.append(kw["tile"]) or body(*a, **kw))
+    latent_decode_attention._attend.clear_cache()
+
+    def step(ids, lengths, n, prefill):
+        return model.apply(variables, ids, mutable=["cache"],
+                           paging=paging(lengths, n, prefill))
+
+    jaxpr = jax.make_jaxpr(lambda ids, ln: step(ids, ln, 1, False))(
+        prompt[:, :1], jnp.asarray([6, 0, 2], jnp.int32))
+    # the sequence's own blocks, no slot's state entry; they are less than
+    # a tile of 1,024 keys: one tile a row
+    assert made == [(per_row, bs, cfg.latent_lanes)]
+    assert attn_mod.dispatch_counts()[name] == counted + 1
+    assert "pallas_call" in str(jaxpr)
+    assert latent > 1 and bodies == [per_row]
+    jax.make_jaxpr(lambda ids: step(ids, [0] * slots, 4, True))(prompt)
+    assert len(made) == 1
+
+
 def test_decode_through_both_kernels_matches_the_xla_paths(monkeypatch):
     """The decode program with the Pallas kernels in it (interpret mode):
     the latent multi-query kernel and the grouped expert matmul, against

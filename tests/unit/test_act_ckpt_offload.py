@@ -59,7 +59,7 @@ class TestCpuCheckpointing:
         m0 = GPT2ForTraining(base)
         m1 = GPT2ForTraining(
             dataclasses.replace(base, cpu_checkpointing=True))
-        p = m0.init(jax.random.PRNGKey(0), {"input_ids": IDS})["params"]
+        p = jax.jit(m0.init)(jax.random.PRNGKey(0), {"input_ids": IDS})["params"]
         chex.assert_trees_all_close(
             jax.grad(lambda q: m0.loss_fn(q, {"input_ids": IDS}))(p),
             jax.grad(lambda q: m1.loss_fn(q, {"input_ids": IDS}))(p),
@@ -78,7 +78,7 @@ class TestCpuCheckpointing:
         m0 = LlamaForTraining(
             dataclasses.replace(cfg, cpu_checkpointing=False))
         m1 = LlamaForTraining(cfg)
-        p = m0.init(jax.random.PRNGKey(0), {"input_ids": IDS})["params"]
+        p = jax.jit(m0.init)(jax.random.PRNGKey(0), {"input_ids": IDS})["params"]
         chex.assert_trees_all_close(
             jax.grad(lambda q: m0.loss_fn(q, {"input_ids": IDS}))(p),
             jax.grad(lambda q: m1.loss_fn(q, {"input_ids": IDS}))(p),
@@ -95,7 +95,7 @@ class TestCpuCheckpointing:
         m0 = BertForTraining(
             dataclasses.replace(cfg, cpu_checkpointing=False))
         m1 = BertForTraining(cfg)
-        p = m0.init(jax.random.PRNGKey(0), batch)["params"]
+        p = jax.jit(m0.init)(jax.random.PRNGKey(0), batch)["params"]
         chex.assert_trees_all_close(
             jax.grad(lambda q: m0.loss_fn(q, batch))(p),
             jax.grad(lambda q: m1.loss_fn(q, batch))(p),
@@ -118,7 +118,7 @@ class TestPartitionActivations:
 
         def temp_bytes(cfg):
             m = GPT2ForTraining(cfg)
-            p = m.init(jax.random.PRNGKey(0), {"input_ids": ids})["params"]
+            p = jax.jit(m.init)(jax.random.PRNGKey(0), {"input_ids": ids})["params"]
             f = jax.jit(lambda q: jax.grad(
                 lambda r: m.loss_fn(r, {"input_ids": ids}))(q))
             stats = f.lower(p).compile().memory_analysis()
@@ -145,7 +145,7 @@ class TestPartitionActivations:
         m0 = GPT2ForTraining(base)
         m1 = GPT2ForTraining(
             dataclasses.replace(base, partition_activations=True))
-        p = m0.init(jax.random.PRNGKey(0), {"input_ids": IDS})["params"]
+        p = jax.jit(m0.init)(jax.random.PRNGKey(0), {"input_ids": IDS})["params"]
         chex.assert_trees_all_close(
             jax.grad(lambda q: m0.loss_fn(q, {"input_ids": IDS}))(p),
             jax.grad(lambda q: m1.loss_fn(q, {"input_ids": IDS}))(p),

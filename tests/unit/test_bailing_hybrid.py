@@ -30,10 +30,8 @@ from deepspeed_tpu.models.bailing_hybrid import (BailingHybridConfig,
                                                  KdaMixer)
 from deepspeed_tpu.moe import dropless
 from deepspeed_tpu.ops import kda_chunk, kda_state_update
-from deepspeed_tpu.parallel.topology import reset_topology
-from deepspeed_tpu.serving import ServingEngine
 from perfbench import reference_bailing_hybrid as reference
-from tests.unit.test_lfm2_moe import _paged_logits
+from tests.unit.served_family import REFUSED, Family, highest, prompts  # noqa: F401
 
 # float32 program against the float32 reference, on logits of order 0.6:
 # what another order of summation leaves (the two agree to 6e-7 here)
@@ -61,40 +59,21 @@ def shape_of(cfg: BailingHybridConfig) -> dict:
                             for i in range(n)))
 
 
-def make(dtype=jnp.float32, seed=0, **kw):
-    cfg = BailingHybridConfig.tiny(dtype=dtype, **kw)
-    module = BailingHybridForCausalLM(cfg)
-    params = module.init(jax.random.PRNGKey(seed),
-                         jnp.zeros((1, 8), jnp.int32))["params"]
-    return cfg, module, params
-
-
-_REFERENCE = {}
-
-
-def reference_logits(cfg, params, ids):
-    """The reference over ``ids`` padded on the right to a whole 64 (causal:
-    unseen), compiled once a config and shape."""
-    ids = np.asarray(ids)
-    rows, t = ids.shape
-    wide = np.zeros((rows, -(-t // 64) * 64), ids.dtype)
-    wide[:, :t] = ids
-    fn = _REFERENCE.setdefault(cfg, jax.jit(
-        lambda p, i: reference.logits(p, i, shape_of(cfg))))
-    return np.asarray(fn(params, jnp.asarray(wide)))[:, :t]
-
-
-def _prompts(cfg, lengths, seed=5):
-    rng = np.random.default_rng(seed)
-    return [rng.integers(0, cfg.vocab_size, n).tolist() for n in lengths]
+FAMILY = Family(BailingHybridConfig, BailingHybridForCausalLM, reference,
+                shape_of, TOL,
+                serving={"decode_slots": 3, "block_size": BLOCK,
+                         "max_model_len": 64, "prefill_chunk_tokens": 8})
+engines = FAMILY.engines()
+make, reference_logits = FAMILY.make, FAMILY.reference_logits
 
 
 @pytest.fixture
-def highest():
-    # the CPU multiplies float32 exactly; the setting is the chip's, kept so
-    # that the test says what it compares
-    with jax.default_matmul_precision("highest"):
-        yield
+def served():
+    """``(cfg, params, engine)``: the shared engine, for the tests that
+    drive its paged module, pools and tables themselves or serve through
+    it."""
+    cfg, _, params = make()
+    return cfg, params, FAMILY.shared_engine(params, cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -103,7 +82,7 @@ def highest():
 def test_full_forward_matches_the_reference(highest):
     cfg, module, params = make()
     ids = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 37))
-    got = np.asarray(module.apply({"params": params}, jnp.asarray(ids)))
+    got = np.asarray(FAMILY.plain(cfg)(params, jnp.asarray(ids)))
     assert np.abs(got - reference_logits(cfg, params, ids)).max() <= TOL
     # untied; two KDA layers to a latent one, the dense layer first
     assert "lm_head" in params and "layers_2_attn" in params
@@ -610,26 +589,6 @@ def test_the_shares_routed_sums_add_up_to_the_uncut_layer(highest):
 # ---------------------------------------------------------------------------
 # serving: both seams in one model
 # ---------------------------------------------------------------------------
-def serving_engine(params, cfg, **serving):
-    reset_topology()
-    block = {"decode_slots": 3, "block_size": BLOCK, "max_model_len": 64,
-             "prefill_chunk_tokens": 8, **serving}
-    return ServingEngine(deepspeed_tpu.init_inference(
-        BailingHybridForCausalLM(cfg), params=params, dtype=cfg.dtype,
-        serving=block))
-
-
-@pytest.fixture(scope="module")
-def served():
-    """``(cfg, params, engine)``: one engine for the tests that drive its
-    paged module, pools and tables themselves (each traces its own
-    programs) or serve through it."""
-    cfg, _, params = make()
-    srv = serving_engine(params, cfg)
-    yield cfg, params, srv
-    srv.destroy()
-
-
 def test_the_engine_sees_both_seams(served):
     cfg, _, srv = served
     assert srv.slot_state["entries"] == 1 and srv.slot_entries == 1
@@ -654,17 +613,15 @@ def test_paged_logits_match_the_reference(highest, served, chunk):
     past the first starts from the stored state and the stored convolution
     rows, the last holds 3 real positions)."""
     cfg, params, srv = served
-    got, tokens = _paged_logits(srv, _prompts(cfg, [27])[0], 14, chunk=chunk)
-    want = reference_logits(cfg, params, np.asarray([tokens]))[0]
-    assert np.abs(got - want[:len(got)]).max() <= TOL
+    assert FAMILY.paged_logits_match(srv, cfg, params, prompts(cfg, [27])[0],
+                                     14, chunk=chunk) <= TOL
 
 
 def test_a_slots_second_tenant_does_not_see_the_firsts_state(highest, served):
     cfg, params, srv = served
-    for prompt in _prompts(cfg, [30, 7]):
-        got, tokens = _paged_logits(srv, prompt, 5, slot=2, chunk=8)
-        want = reference_logits(cfg, params, np.asarray([tokens]))[0]
-        assert np.abs(got - want[:len(got)]).max() <= TOL, len(prompt)
+    for prompt in prompts(cfg, [30, 7]):
+        assert FAMILY.paged_logits_match(srv, cfg, params, prompt, 5, slot=2,
+                                         chunk=8) <= TOL, len(prompt)
 
 
 def not_carried(pool, index, rows, fresh):
@@ -682,12 +639,10 @@ def test_a_wrong_state_moves_the_logits(highest, monkeypatch, served,
                                         control):
     cfg, params, srv = served
     monkeypatch.setattr(bailing_hybrid, "state_in", control)
-    worst = 0.0
-    for prompt in _prompts(cfg, [30, 7]):
-        got, tokens = _paged_logits(srv, prompt, 3, slot=2, chunk=8)
-        want = reference_logits(cfg, params, np.asarray([tokens]))[0]
-        worst = max(worst, np.abs(got - want[:len(got)]).max())
-    assert worst > 100 * TOL
+    # (the paged module traced anew under the patch)
+    assert max(FAMILY.paged_logits_match(srv, cfg, params, prompt, 3, slot=2,
+                                         chunk=8, retrace=True)
+               for prompt in prompts(cfg, [30, 7])) > 100 * TOL
 
 
 def test_prefill_chunks_and_decode_through_the_engine(highest, served):
@@ -698,19 +653,9 @@ def test_prefill_chunks_and_decode_through_the_engine(highest, served):
     request in its slot's row is the reference's; and the engine's
     counters, the three kinds of live bytes and the group counter."""
     cfg, params, srv = served
-    prompts = _prompts(cfg, [5, 19, 33, 9, 26])
-    news = [30, 12, 20, 25, 8]
-    srv.reset_stats()
-    reqs = [srv.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
-    srv.drain()
-    stats = srv.stats()
-    for req, prompt, n in zip(reqs, prompts, news):
-        assert len(req.tokens) == n, (req.state, req.finish_reason)
-        want = reference_logits(cfg, params,
-                                np.asarray([list(prompt) + req.tokens]))[0]
-        for k, tok in enumerate(req.tokens):
-            row = want[len(prompt) - 1 + k]
-            assert row.max() - row[tok] <= TOL, (k, tok, row.argmax())
+    stats, reqs = FAMILY.served_logits_match(
+        cfg, params, list(zip(prompts(cfg, [5, 19, 33, 9, 26]),
+                              [30, 12, 20, 25, 8])))
     assert max(r.prefill_chunks for r in reqs) == 5
     assert len({r.slot for r in reqs}) == 3
     # the last to finish: its slot's rows hold the recurrence's state after
@@ -719,8 +664,8 @@ def test_prefill_chunks_and_decode_through_the_engine(highest, served):
     ids = np.zeros((1, 64), np.int32)
     fed = list(last.prompt) + last.tokens[:-1]
     ids[0, :len(fed)] = fed
-    _, layers = reference.logits(params, jnp.asarray(ids), shape_of(cfg),
-                                 with_layers=True, stop=len(fed))
+    _, layers = FAMILY.reference_program(
+        cfg, with_layers=True, stop=len(fed))(params, jnp.asarray(ids))
     held = np.asarray(srv.cache["kda_state_pool"][:, 1 + last.slot])
     assert np.abs(held - np.asarray(layers["states"])[:, 0]).max() <= 1e-5
     kv = stats["kv_live_bytes"]
@@ -759,11 +704,11 @@ def test_the_group_counter_counts_the_tokens_that_chose_the_held_group(
     paging = {"block_tables": jnp.asarray([[1, 2, 3, 4, 1]], jnp.int32),
               "lengths": jnp.zeros((1,), jnp.int32),
               "num_valid": jnp.asarray([13], jnp.int32), "prefill": True}
-    cache = paged.init(jax.random.PRNGKey(0), jnp.asarray(ids),
-                       paging=paging)["cache"]
-    (_, aux), _ = paged.apply({"params": held, "cache": cache},
-                              jnp.asarray(ids), mutable=["cache"],
-                              paging=paging)
+    cache = jax.jit(lambda ids: paged.init(
+        jax.random.PRNGKey(0), ids, paging=paging)["cache"])(jnp.asarray(ids))
+    (_, aux), _ = jax.jit(lambda p, cache, ids: paged.apply(
+        {"params": p, "cache": cache}, ids, mutable=["cache"],
+        paging=paging))(held, cache, jnp.asarray(ids))
     routed = np.asarray(aux["routed"])[0, :13].reshape(13, 5, 4)
     here = ((routed >= per) & (routed < 2 * per)).any(-1).sum()
     counters = np.asarray(aux["counters"])
@@ -776,7 +721,7 @@ def test_the_state_pools_do_not_grow_with_the_context(highest):
     cfg, _, params = make()
     sizes = []
     for length in (64, 128):
-        srv = serving_engine(params, cfg, max_model_len=length)
+        srv = FAMILY.serving_engine(params, cfg, max_model_len=length)
         sizes.append({k: v.size for k, v in srv.cache.items()})
         srv.destroy()
     assert sizes[0]["kda_state_pool"] == sizes[1]["kda_state_pool"]
@@ -789,32 +734,17 @@ def test_decode_through_both_kernels_matches_the_xla_paths(monkeypatch):
     the state update on the pool in place (heads of 128 x 128) and the
     latent kernel over the block table, beside idle slots, against the same
     steps on the XLA paths."""
-    from deepspeed_tpu.ops import attention as ops_attention
-    from deepspeed_tpu.utils.compat import tpu_interpret_mode
-
     cfg, _, params = make(head_dim=128, num_attention_heads=2,
                           hidden_size=64, kv_lora_rank=128,
                           num_hidden_layers=3,
                           expert_swiglu_limit_list=(),
                           share_expert_swiglu_limit_list=())
-    prompt = _prompts(cfg, [11])[0]
-    plain = serving_engine(params, cfg)
-    want, _ = _paged_logits(plain, prompt, 2, chunk=8)
-    plain.destroy()
-    monkeypatch.setattr(ops_attention, "use_decode_kernel", lambda: True)
-    srv = serving_engine(params, cfg)
-    try:
-        one = jax.devices()[0]
-        srv.engine.params, srv.cache = jax.device_put(
-            (srv.engine.params, srv.cache), one)
-        with tpu_interpret_mode():
-            got, _ = _paged_logits(srv, prompt, 2, chunk=8)
-        paths = srv.stats()["attention_paths"]
-        assert paths.get("kda_decode_kernel") and paths.get(
-            "mla_decode_absorbed_kernel")
-        assert np.abs(got - want).max() <= 10 * TOL
-    finally:
-        srv.destroy()
+    got, want, paths = FAMILY.decode_through_the_kernels(
+        monkeypatch, cfg, params, prompts(cfg, [11])[0], 2, chunk=8,
+        experts=False)
+    assert paths.get("kda_decode_kernel") and paths.get(
+        "mla_decode_absorbed_kernel")
+    assert np.abs(got - want).max() <= 10 * TOL
 
 
 # ---------------------------------------------------------------------------
@@ -863,46 +793,20 @@ def test_importing_the_package_imports_neither_the_family_nor_its_ops():
     assert out.stdout.strip().splitlines()[-1] == "[]"
 
 
-@pytest.mark.parametrize("serving, mechanism", [
-    ({"prefix_cache": True}, "serving.prefix_cache"),
-    ({"speculative": {"num_speculative_tokens": 2}}, "serving.speculative"),
-    ({"kv_cache_dtype": "int8"}, "serving.kv_cache_dtype"),
-], ids=["prefix-cache", "speculation", "int8-kv"])
+@REFUSED
 def test_mechanisms_that_know_block_tables_only_refuse_the_model(serving,
                                                                  mechanism):
-    cfg, _, params = make()
-    with pytest.raises(Exception, match=mechanism.replace(".", r"\.")) as e:
-        serving_engine(params, cfg, **serving)
-    assert "BailingHybridForCausalLM" in str(e.value)
-    assert "delta-rule layers keep a state" in str(e.value)
+    assert "delta-rule layers keep a state" in FAMILY.mechanism_refusal(
+        serving, mechanism)
 
 
 def test_tensor_parallel_refuses_the_model():
-    cfg, _, params = make()
-    reset_topology()
-    with pytest.raises(Exception, match="tp_size > 1") as e:
-        ServingEngine(deepspeed_tpu.init_inference(
-            BailingHybridForCausalLM(cfg), params=params, dtype=cfg.dtype,
-            tensor_parallel={"tp_size": 2},
-            serving={"decode_slots": 2, "block_size": BLOCK,
-                     "max_model_len": 32}))
-    assert "delta-rule layers keep a state" in str(e.value)
-    reset_topology()
+    assert "delta-rule layers keep a state" in (
+        FAMILY.tensor_parallel_refusal())
 
 
 def test_migration_refuses_the_model():
-    cfg, _, params = make()
-    srv = serving_engine(params, cfg)
-    try:
-        req = srv.submit([1, 2, 3, 4, 5], max_new_tokens=8)
-        srv.step()
-        for call in (lambda: srv.export_sequence(req.request_id),
-                     lambda: srv.import_sequence({"request_id": "x"})):
-            with pytest.raises(NotImplementedError, match="migration") as e:
-                call()
-            assert "delta-rule" in str(e.value)
-    finally:
-        srv.destroy()
+    assert all("delta-rule" in said for said in FAMILY.migration_refusals())
 
 
 def test_the_config_refuses_what_the_family_does_not_implement():

@@ -4,6 +4,8 @@ Parity vs the dense masked path the model used before (reference capability:
 ``softmax_context``, ``csrc/transformer/inference/csrc/softmax.cu:488``).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -146,9 +148,11 @@ def _dense_ref(q4, kd, vd, tables, lengths, bs):
                                causal=False).transpose(0, 2, 1, 3)
 
 
+@jax.jit
 def _paged_dense_ref(q4, k_pool, v_pool, tables, lengths, layer):
     """Oracle: gather one layer of the pool into the dense logical
-    window."""
+    window (ONE program a shape, the layer traced: op by op it compiles
+    some dozens a case)."""
     from deepspeed_tpu.ops.decode_attention import gather_paged_cache
 
     H = q4.shape[2]
@@ -157,6 +161,7 @@ def _paged_dense_ref(q4, k_pool, v_pool, tables, lengths, layer):
                       tables, lengths, k_pool.shape[2])
 
 
+@jax.jit
 def _int8_dense_ref(q4, kq, vq, ks, vs, tables, lengths, layer):
     from deepspeed_tpu.ops.decode_attention import gather_paged_cache_int8
 
@@ -368,6 +373,7 @@ def test_paged_aliased_garbage_isolation():
 # ---------------------------------------------------------------------------
 # int8 paged variant (the serving kv_cache_dtype: "int8" codec)
 # ---------------------------------------------------------------------------
+@functools.partial(jax.jit, static_argnums=2)
 def _int8_pools(k_pool, v_pool, H):
     """The pools as the model's write path stores them: rows quantized per
     token x head (``quantize_rowwise`` over ``[..., H, D]``), int8 rows
@@ -425,6 +431,19 @@ def test_paged_int8_error_vs_f32_pinned():
     assert err < 0.05, f"int8 KV attention error {err} past the pinned budget"
 
 
+@functools.lru_cache(maxsize=None)
+def _lane_dense_inputs(tq, H, D, kv):
+    """One batch of every length of live prefix and its pools, made once
+    for the cases that differ only in ``layer``."""
+    lengths = _mixed(tq, bs=16)
+    q4, k_pool, v_pool, tables, lens, _ = _paged_setup(
+        len(lengths), lengths, tq, bs=16, mb=4, H=H, D=D, seed=H * D + tq,
+        dtype=jnp.bfloat16 if kv == "bf16" else np.float32)
+    pools = (k_pool, v_pool) if kv == "bf16" else _int8_pools(k_pool, v_pool,
+                                                              H)
+    return lengths, q4, pools, tables, lens
+
+
 @pytest.mark.parametrize("layer", [0, LAYERS - 1, "traced"])
 @pytest.mark.parametrize("tq", [1, 4])
 @pytest.mark.parametrize("H,D", [(2, 64), (2, 128), (3, 64), (5, 32)])
@@ -441,26 +460,20 @@ def test_stacked_lane_dense_pool_forms(layer, tq, H, D, kv):
     from deepspeed_tpu.ops.decode_attention import (
         decode_attention_paged, decode_attention_paged_int8)
 
-    dtype = jnp.bfloat16 if kv == "bf16" else np.float32
-    lengths = _mixed(tq, bs=16)
-    q4, k_pool, v_pool, tables, lens, _ = _paged_setup(
-        len(lengths), lengths, tq, bs=16, mb=4, H=H, D=D, seed=H * D + tq,
-        dtype=dtype)
+    lengths, q4, pools, tables, lens = _lane_dense_inputs(tq, H, D, kv)
     if kv == "bf16":
-        kernel, pools, tol = decode_attention_paged, (k_pool, v_pool), 2e-2
-        oracle = _paged_dense_ref
+        kernel, tol, oracle = decode_attention_paged, 2e-2, _paged_dense_ref
     else:
-        kernel, pools, tol = (decode_attention_paged_int8,
-                              _int8_pools(k_pool, v_pool, H), 2e-5)
-        oracle = _int8_dense_ref
+        kernel, tol, oracle = decode_attention_paged_int8, 2e-5, \
+            _int8_dense_ref
     if layer == "traced":
         layer = 1
-        kernel = jax.jit(kernel)
-        at = jnp.asarray(layer, jnp.int32)
+        run, at = jax.jit(kernel), (jnp.asarray(layer, jnp.int32),)
     else:
-        at = layer
+        # a Python int stays one inside the program
+        run, at = jax.jit(lambda *a: kernel(*a, layer)), ()
     with tpu_interpret_mode():
-        out = jax.block_until_ready(kernel(q4, *pools, tables, lens, at))
+        out = jax.block_until_ready(run(q4, *pools, tables, lens, *at))
     ref = oracle(q4, *pools, tables, lens, layer)
     assert out.shape == q4.shape and out.dtype == q4.dtype
     live = _live(lengths)
@@ -865,8 +878,8 @@ def test_model_lists_the_paged_kernels_work_once_a_step(monkeypatch,
                 "prefill": prefill}
 
     prompt = jnp.zeros((2, 8), jnp.int32)
-    variables = model.init(jax.random.PRNGKey(0), prompt,
-                           paging=paging([0, 0], 8, True))
+    variables = jax.jit(lambda ids: model.init(
+        jax.random.PRNGKey(0), ids, paging=paging([0, 0], 8, True)))(prompt)
     made = []
     real = da.paged_work_list
 
@@ -933,8 +946,9 @@ def test_hybrid_families_list_the_kernels_work_once_a_step(
                 "prefill": prefill}
 
     prompt = jnp.zeros((slots, 4), jnp.int32)
-    variables = model.init(jax.random.PRNGKey(0), prompt,
-                           paging=paging([0] * slots, 4, True))
+    variables = jax.jit(lambda ids: model.init(
+        jax.random.PRNGKey(0), ids, paging=paging([0] * slots, 4, True)))(
+            prompt)
     made = []
     real = hda.hybrid_work_list
 
@@ -998,6 +1012,7 @@ def _step_rows(seed, B, H, D, pools):
         for _, s in held)
 
 
+@jax.jit
 def _scattered(pools, rows, tables, lens, layer, valid=None):
     """What the decode program did until the call wrote: every row
     through ``paged_write_slots``, an idle slot's (and a row of ``valid``
@@ -1027,8 +1042,19 @@ def _writing_setup(kv, lengths, bs, mb, seed, H=2, D=64):
 
 
 def _bits(a):
-    return np.asarray(jax.lax.bitcast_convert_type(a, {
-        1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}[a.dtype.itemsize]))
+    a = np.asarray(a)
+    return a.view({1: np.uint8, 2: np.uint16, 4: np.uint32}[
+        a.dtype.itemsize])
+
+
+def _writing(kernel, layer=None):
+    """The call as ONE program: ``(q4, pools, rows, tables, lens[, layer],
+    **traced) -> kernel(q4, *pools, tables, lens, layer, rows=rows, ...)``;
+    ``layer`` given HERE stays a Python int inside it (a default that is not
+    passed is not traced), passed to the call it is traced."""
+    def call(q4, pools, rows, tables, lens, at=layer, **kw):
+        return kernel(q4, *pools, tables, lens, at, rows=rows, **kw)
+    return jax.jit(call)
 
 
 _BS = 32
@@ -1067,10 +1093,10 @@ def test_paged_call_writes_the_steps_rows_as_the_scatter_did(kv, case):
     assert scatters == (case == "crowded" and kv == "bf16")
     want_pools = _scattered(pools, rows, tables, lens, layer)
     with tpu_interpret_mode():
-        want = jax.block_until_ready(kernel(q4, *want_pools, tables, lens,
-                                            layer))
-        out, got_pools = jax.block_until_ready(kernel(
-            q4, *pools, tables, lens, layer, rows=rows))
+        want = jax.block_until_ready(_writing(kernel, layer)(
+            q4, want_pools, None, tables, lens))
+        out, got_pools = jax.block_until_ready(_writing(kernel, layer)(
+            q4, pools, rows, tables, lens))
     np.testing.assert_array_equal(_bits(out)[live], _bits(want)[live])
     np.testing.assert_array_equal(np.asarray(out, np.float32)[
         [b for b in range(len(lengths)) if b not in live]], 0.0)
@@ -1111,10 +1137,10 @@ def test_paged_call_never_writes_a_block_two_tables_share(kv):
     valid = jnp.asarray([1, 1, 0], jnp.int32)
     want_pools = _scattered(pools, rows, tables, lens, layer, valid)
     with tpu_interpret_mode():
-        want = jax.block_until_ready(kernel(q4, *want_pools, tables, lens,
-                                            layer))
-        out, got_pools = jax.block_until_ready(kernel(
-            q4, *pools, tables, lens, layer, rows=rows, valid=valid))
+        want = jax.block_until_ready(_writing(kernel, layer)(
+            q4, want_pools, None, tables, lens))
+        out, got_pools = jax.block_until_ready(_writing(kernel, layer)(
+            q4, pools, rows, tables, lens, valid=valid))
     np.testing.assert_array_equal(_bits(out), _bits(want))
     shared = np.asarray(tables[0, :2])
     for before, got, scattered in zip(pools, got_pools, want_pools):
@@ -1160,13 +1186,15 @@ def test_paged_write_list_made_once_serves_every_layers_call(kv):
         kv, lengths, _BS, 4, seed=3)
     work = da.paged_step_work(lens, tables, 1, _BS,
                               valid=jnp.ones_like(lens))
+    # (the layer traced: two programs serve the three layers)
+    call = _writing(kernel)
     with tpu_interpret_mode():
         got = pools
         for layer in range(LAYERS):
-            alone = jax.block_until_ready(kernel(
-                q4, *got, tables, lens, layer, rows=rows))
-            out, got = jax.block_until_ready(kernel(
-                q4, *got, tables, lens, layer, rows=rows, work=work))
+            alone = jax.block_until_ready(call(q4, got, rows, tables, lens,
+                                               layer))
+            out, got = jax.block_until_ready(call(q4, got, rows, tables, lens,
+                                                  layer, work=work))
             np.testing.assert_array_equal(_bits(out), _bits(alone[0]))
     want = pools
     for layer in range(LAYERS):
@@ -1262,9 +1290,9 @@ def test_paged_model_steps_kernel_matches_dense(monkeypatch, scan_layers, kv):
                 "lengths": lengths, "num_valid": num_valid,
                 "prefill": prefill}
 
-    variables = model.init(jax.random.PRNGKey(0), prompt,
-                           paging=paging(jnp.zeros((2,), jnp.int32),
-                                         n_prompt, True))
+    variables = jax.jit(lambda ids: model.init(
+        jax.random.PRNGKey(0), ids, paging=paging(
+            jnp.zeros((2,), jnp.int32), n_prompt, True)))(prompt)
     params = {"params": variables["params"]}
     cache0 = jax.tree_util.tree_map(jnp.zeros_like, variables["cache"])
     assert {k: v.shape for k, v in cache0["transformer"].items()} == {
@@ -1274,22 +1302,25 @@ def test_paged_model_steps_kernel_matches_dense(monkeypatch, scan_layers, kv):
             for n in ("key", "value")} if kv else {})}
 
     def run(force):
+        # each pass its own programs (one a step shape: the prefill, a
+        # decode step, the verify step), traced under its setting
         monkeypatch.setattr(attn_mod, "_FORCE_DECODE_KERNEL", force)
+        prefill = jax.jit(lambda p, cache: model.apply(
+            {**p, "cache": cache}, prompt, mutable=["cache"], paging=paging(
+                jnp.zeros((2,), jnp.int32), n_prompt, True)))
+        step = jax.jit(lambda p, cache, tok, lengths: model.apply(
+            {**p, "cache": cache}, tok, mutable=["cache"], paging=paging(
+                lengths, jnp.full((3,), tok.shape[1], jnp.int32))))
         outs, cache, garbage = [], cache0, []
         lengths = jnp.pad(n_prompt, (0, 1))
         with tpu_interpret_mode() if force else _null():
-            _, vars_ = model.apply({**params, "cache": cache}, prompt,
-                                   mutable=["cache"], paging=paging(
-                                       jnp.zeros((2,), jnp.int32), n_prompt,
-                                       True))
+            _, vars_ = prefill(params, cache)
             cache = jax.block_until_ready(vars_["cache"])
             for t in (1, 1, 3):
                 tok = jnp.asarray(rng_tokens[len(outs)][:, :t])
                 garbage.append([np.asarray(leaf[:, 0]) for leaf in
                                 jax.tree_util.tree_leaves(cache)])
-                logits, vars_ = model.apply(
-                    {**params, "cache": cache}, tok, mutable=["cache"],
-                    paging=paging(lengths, jnp.full((3,), t, jnp.int32)))
+                logits, vars_ = step(params, cache, tok, lengths)
                 logits, cache = jax.block_until_ready(
                     (logits, vars_["cache"]))
                 lengths = lengths + jnp.asarray([t, t, 0])
@@ -1341,22 +1372,25 @@ def test_model_decode_uses_kernel(monkeypatch):
     model = GPT2LMHeadModel(cfg)
     rng = np.random.default_rng(0)
     prompt = jnp.asarray(rng.integers(0, 256, (2, 16)), jnp.int32)
-    variables = model.init(jax.random.PRNGKey(0), prompt)
+    variables = jax.jit(model.init)(jax.random.PRNGKey(0), prompt)
     params = {"params": variables["params"]}
 
     def run(force):
+        # each pass its own program (one a step shape), traced under its
+        # setting
         monkeypatch.setattr(attn_mod, "_FORCE_DECODE_KERNEL", force)
+        apply = jax.jit(lambda p, cache, ids: model.apply(
+            {**p, "cache": cache}, ids, mutable=["cache"]))
         ctx = tpu_interpret_mode() if force else _null()
         outs = []
         with ctx:
-            logits, vars_ = model.apply(
-                {**params, "cache": variables["cache"]}, prompt,
-                mutable=["cache"])
+            logits, vars_ = jax.block_until_ready(apply(
+                params, variables["cache"], prompt))
             tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
             cache = vars_["cache"]
             for _ in range(4):
-                logits, vars_ = model.apply(
-                    {**params, "cache": cache}, tok, mutable=["cache"])
+                logits, vars_ = jax.block_until_ready(apply(params, cache,
+                                                            tok))
                 cache = vars_["cache"]
                 tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
                 outs.append(np.asarray(logits))

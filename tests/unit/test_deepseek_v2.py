@@ -18,7 +18,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu
 from deepspeed_tpu.models import deepseek_v2
 from deepspeed_tpu.models.deepseek_v2 import (DeepseekV2Config,
                                               DeepseekV2ForCausalLM,
@@ -26,9 +25,8 @@ from deepspeed_tpu.models.deepseek_v2 import (DeepseekV2Config,
                                               YarnScaling)
 from deepspeed_tpu.moe import dropless
 from deepspeed_tpu.ops import latent_decode_attention
-from deepspeed_tpu.parallel.topology import reset_topology
-from deepspeed_tpu.serving import ServingEngine
 from perfbench import reference_deepseek_v2 as reference
+from tests.unit.served_family import REFUSED, Family, highest, prompts  # noqa: F401
 
 # float32 program against the float32 reference, on logits of order 1: what
 # another order of summation leaves (the two agree to 3e-7 here)
@@ -50,30 +48,11 @@ def shape_of(cfg: DeepseekV2Config, first_expert: int = 0) -> dict:
                 dense=cfg.first_k_dense_replace, first_expert=first_expert)
 
 
-def make(dtype=jnp.float32, seed=0, **kw):
-    cfg = DeepseekV2Config.tiny(dtype=dtype, **kw)
-    module = DeepseekV2ForCausalLM(cfg)
-    params = module.init(jax.random.PRNGKey(seed),
-                         jnp.zeros((1, 8), jnp.int32))["params"]
-    return cfg, module, params
-
-
-def reference_logits(cfg, params, ids):
-    return np.asarray(reference.logits(params, jnp.asarray(ids),
-                                       shape_of(cfg)))
-
-
-def _prompts(cfg, lengths, seed=5):
-    rng = np.random.default_rng(seed)
-    return [rng.integers(0, cfg.vocab_size, n).tolist() for n in lengths]
-
-
-@pytest.fixture
-def highest():
-    # the CPU multiplies float32 exactly; the setting is the chip's, kept so
-    # that the test says what it compares
-    with jax.default_matmul_precision("highest"):
-        yield
+FAMILY = Family(DeepseekV2Config, DeepseekV2ForCausalLM, reference, shape_of,
+                TOL, serving={"decode_slots": 3, "block_size": BLOCK,
+                              "max_model_len": 64})
+engines = FAMILY.engines()
+make, reference_logits = FAMILY.make, FAMILY.reference_logits
 
 
 # ---------------------------------------------------------------------------
@@ -82,7 +61,7 @@ def highest():
 def test_full_forward_matches_the_reference(highest):
     cfg, module, params = make()
     ids = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 40))
-    got = np.asarray(module.apply({"params": params}, jnp.asarray(ids)))
+    got = np.asarray(FAMILY.plain(cfg)(params, jnp.asarray(ids)))
     assert np.abs(got - reference_logits(cfg, params, ids)).max() <= TOL
     # an untied head; layer 0 dense, the others sparse with shared experts
     assert "lm_head" in params and "router" not in params["layers_0_mlp"]
@@ -120,7 +99,7 @@ def test_every_operator_moves_the_logits(highest):
     cfg, module, params = make()
     ids = jnp.asarray(np.random.default_rng(1).integers(
         0, cfg.vocab_size, (1, 24)))
-    base = np.asarray(module.apply({"params": params}, ids))
+    base = np.asarray(FAMILY.plain(cfg)(params, ids))
 
     def zeroed(layer, leaf, keep=None):
         moved = dict(params)
@@ -129,7 +108,7 @@ def test_every_operator_moves_the_logits(highest):
         if keep is not None:       # zero only the columns past ``keep``
             new = {"kernel": was["kernel"].at[:, keep:].set(0.0)}
         moved[layer] = {**params[layer], leaf: new}
-        return np.asarray(module.apply({"params": moved}, ids))
+        return np.asarray(FAMILY.plain(cfg)(moved, ids))
 
     for layer, leaf, keep in (
             ("layers_1_attn", "kv_b_proj", None),
@@ -146,8 +125,8 @@ def test_bf16_fails_the_float32_tolerance():
     apart."""
     cfg, _, params = make()
     ids = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 40))
-    low = DeepseekV2ForCausalLM(dataclasses.replace(cfg, dtype=jnp.bfloat16))
-    got = np.asarray(low.apply({"params": params}, jnp.asarray(ids)))
+    low = FAMILY.plain(dataclasses.replace(cfg, dtype=jnp.bfloat16))
+    got = np.asarray(low(params, jnp.asarray(ids)))
     assert np.abs(got - reference_logits(cfg, params, ids)).max() > 10 * TOL
 
 
@@ -295,63 +274,6 @@ def test_a_width_of_eleven_registers_is_tiled_by_one(width, tile):
 # ---------------------------------------------------------------------------
 # through the paged latent pool
 # ---------------------------------------------------------------------------
-def serving_engine(params, cfg, **serving):
-    reset_topology()
-    block = {"decode_slots": 3, "block_size": BLOCK, "max_model_len": 64,
-             **serving}
-    return ServingEngine(deepspeed_tpu.init_inference(
-        DeepseekV2ForCausalLM(cfg), params=params, dtype=cfg.dtype,
-        serving=block))
-
-
-def _paged_logits(srv, prompt, steps, slot=1, chunk=0):
-    """Drive the engine's own paged module with its own pool and tables,
-    as its programs do, and keep the LOGITS: every prompt position (the
-    whole prompt right-padded into a bucket it does NOT fill, or chunks of
-    ``chunk``), then ``steps`` greedy decode steps in the decode program's
-    batch shape, the other slots idle. -> (logits [positions, vocab],
-    ids)."""
-    dm, params = srv._dmodule, srv.engine.params
-
-    def call(prefill):
-        def fn(cache, ids, tables, lengths, num_valid):
-            out, v = dm.apply(
-                {"params": params, "cache": cache}, ids, mutable=["cache"],
-                paging={"block_tables": tables, "lengths": lengths,
-                        "num_valid": num_valid, "prefill": prefill})
-            return out[0], v["cache"]
-        return jax.jit(fn)
-
-    whole, cached = call(True), call(False)
-    rid = f"direct-{slot}-{len(prompt)}"
-    table = srv._slot_table(slot, srv.block_mgr.allocate(
-        rid, len(prompt) + steps))
-    i32 = lambda x: jnp.asarray(x, jnp.int32)
-    rows, n = [], len(prompt)
-    for at in range(0, n, chunk or n):
-        m = min(chunk or n, n - at)
-        width = chunk or (-(-n // 8) * 8 + 8)     # never filled
-        ids = np.zeros((1, width), np.int32)
-        ids[0, :m] = prompt[at:at + m]
-        lg, srv.cache = (cached if chunk else whole)(
-            srv.cache, i32(ids), i32(table[None]), i32([at]), i32([m]))
-        rows.append(np.asarray(lg[0, :m]))
-    slots = srv.config.decode_slots
-    tables = np.zeros((slots, len(table)), np.int32)
-    tables[slot] = table
-    tokens = list(prompt)
-    for _ in range(steps):
-        tokens.append(int(rows[-1][-1].argmax()))
-        lengths, last = np.zeros(slots, np.int32), np.zeros((slots, 1),
-                                                            np.int32)
-        lengths[slot], last[slot] = len(tokens) - 1, tokens[-1]
-        lg, srv.cache = cached(srv.cache, i32(last), i32(tables),
-                               i32(lengths), jnp.ones(slots, jnp.int32))
-        rows.append(np.asarray(lg[slot]))
-    srv.block_mgr.release(rid)
-    return np.concatenate(rows), tokens
-
-
 @pytest.mark.parametrize("chunk", [0, 8, 7, 6], ids=[
     "whole-prompt", "chunks-of-8", "chunks-that-do-not-divide",
     "a-boundary-inside-a-block"])
@@ -362,32 +284,24 @@ def test_paged_logits_match_the_reference(highest, chunk):
     (27 = 3 x 7 + 6); of 6 (every other chunk starts inside a block of 4).
     The prefill is decompressed, the decode steps absorbed."""
     cfg, _, params = make()
-    srv = serving_engine(params, cfg)
-    try:
-        got, tokens = _paged_logits(srv, _prompts(cfg, [27])[0], 14,
-                                    chunk=chunk)
-        want = reference_logits(cfg, params, np.asarray([tokens]))[0]
-        assert np.abs(got - want[:len(got)]).max() <= TOL
-        paths = srv.stats()["attention_paths"]
-        assert paths.get("mla_decode_absorbed_xla")
-        assert paths.get("mla_chunk_decompressed_xla" if chunk
-                         else "mla_prefill_decompressed_xla")
-    finally:
-        srv.destroy()
+    srv = FAMILY.shared_engine(params, cfg)
+    assert FAMILY.paged_logits_match(srv, cfg, params, prompts(cfg, [27])[0],
+                                     14, chunk=chunk) <= TOL
+    paths = srv.stats()["attention_paths"]
+    assert paths.get("mla_decode_absorbed_xla")
+    assert paths.get("mla_chunk_decompressed_xla" if chunk
+                     else "mla_prefill_decompressed_xla")
 
 
 def test_a_slots_next_request_reads_none_of_its_last_ones_rows(highest):
     """Two requests one after the other over the SAME blocks (the pool has
     room for one), the second shorter: each is the reference's."""
     cfg, _, params = make()
-    srv = serving_engine(params, cfg, decode_slots=1, num_blocks=1 + 10)
-    try:
-        for prompt, chunk in zip(_prompts(cfg, [30, 7, 11]), (0, 0, 8)):
-            got, tokens = _paged_logits(srv, prompt, 5, slot=0, chunk=chunk)
-            want = reference_logits(cfg, params, np.asarray([tokens]))[0]
-            assert np.abs(got - want[:len(got)]).max() <= TOL, len(prompt)
-    finally:
-        srv.destroy()
+    srv = FAMILY.shared_engine(params, cfg, decode_slots=1,
+                               num_blocks=1 + 10)
+    for prompt, chunk in zip(prompts(cfg, [30, 7, 11]), (0, 0, 8)):
+        assert FAMILY.paged_logits_match(srv, cfg, params, prompt, 5, slot=0,
+                                         chunk=chunk) <= TOL, len(prompt)
 
 
 def test_a_pool_full_of_nan_outside_the_live_prefixes_stays_outside(highest):
@@ -396,28 +310,36 @@ def test_a_pool_full_of_nan_outside_the_live_prefixes_stays_outside(highest):
     logit of every row (the idle slots' too) is finite, and the busy
     slot's are the reference's."""
     cfg, _, params = make()
-    srv = serving_engine(params, cfg)
+    srv = FAMILY.shared_engine(params, cfg)
+    was = srv.cache                # put back at the end: the engine is shared
     try:
         srv.cache = jax.tree_util.tree_map(
             lambda x: jnp.full_like(x, jnp.nan), srv.cache)
         for chunk in (0, 6):
-            got, tokens = _paged_logits(srv, _prompts(cfg, [13])[0], 6,
-                                        chunk=chunk)
+            got, tokens = FAMILY.paged_logits(srv, prompts(cfg, [13])[0], 6,
+                                              chunk=chunk)
             assert np.isfinite(got).all()
-            want = reference_logits(cfg, params, np.asarray([tokens]))[0]
+            want = reference_logits(cfg, params, [tokens])[0]
             assert np.abs(got - want[:len(got)]).max() <= TOL
         # the decode program's whole batch: the idle rows' logits too
-        dm = srv._dmodule
-        out, _ = dm.apply(
-            {"params": srv.engine.params, "cache": srv.cache},
-            jnp.zeros((3, 1), jnp.int32), mutable=["cache"],
-            paging={"block_tables": jnp.zeros((3, 16), jnp.int32),
-                    "lengths": jnp.zeros((3,), jnp.int32),
-                    "num_valid": jnp.ones((3,), jnp.int32),
-                    "prefill": False})
+        out, _ = jax.jit(
+            lambda p, cache: srv._dmodule.apply(
+                {"params": p, "cache": cache}, jnp.zeros((3, 1), jnp.int32),
+                mutable=["cache"],
+                paging={"block_tables": jnp.zeros((3, 16), jnp.int32),
+                        "lengths": jnp.zeros((3,), jnp.int32),
+                        "num_valid": jnp.ones((3,), jnp.int32),
+                        "prefill": False}))(srv.engine.params, srv.cache)
         assert np.isfinite(np.asarray(out[0])).all()
     finally:
-        srv.destroy()
+        srv.cache = was
+
+
+def _applied(attn, paging):
+    """One layer's paged call under ``paging`` as ONE program, traced when
+    it is first called (after whatever the test patched)."""
+    return jax.jit(lambda p, x, pool: attn.apply({"params": p}, x, paging,
+                                                 pool, 1))
 
 
 def _pooled_step(cfg, params, n=21, seed=3):
@@ -437,7 +359,7 @@ def _pooled_step(cfg, params, n=21, seed=3):
     paging = {"block_tables": jnp.asarray(table),
               "lengths": jnp.zeros((2,), jnp.int32),
               "num_valid": jnp.asarray([0, n], jnp.int32), "prefill": True}
-    _, pool = attn.apply({"params": p}, x[:, :n], paging, pool, 1)
+    _, pool = _applied(attn, paging)(p, x[:, :n], pool)
     step = {"block_tables": jnp.asarray(table),
             "lengths": jnp.asarray([0, n], jnp.int32),
             "num_valid": jnp.ones((2,), jnp.int32), "prefill": False}
@@ -451,7 +373,7 @@ def test_absorbed_decode_is_the_decompressed_form(highest, monkeypatch):
     would run): the same function."""
     cfg, _, params = make()
     attn, p, x, pool, step, n = _pooled_step(cfg, params)
-    absorbed, _ = attn.apply({"params": p}, x[:, n:], step, pool, 1)
+    absorbed, _ = _applied(attn, step)(p, x[:, n:], pool)
     # a "chunk" of one token: the decompressed path over the gathered rows
     forced = dict(step)
     monkeypatch.setattr(
@@ -459,7 +381,7 @@ def test_absorbed_decode_is_the_decompressed_form(highest, monkeypatch):
         lambda self, *a: (_ for _ in ()).throw(AssertionError("absorbed")))
     two = {**forced, "num_valid": jnp.asarray([1, 1], jnp.int32)}
     padded = jnp.concatenate([x[:, n:], jnp.zeros_like(x[:, n:])], axis=1)
-    decompressed, _ = attn.apply({"params": p}, padded, two, pool, 1)
+    decompressed, _ = _applied(attn, two)(p, padded, pool)
     assert np.abs(np.asarray(absorbed[1, 0] - decompressed[1, 0])).max() \
         <= 1e-5
     # and both are the reference's attention at that position
@@ -476,15 +398,15 @@ def test_the_latent_kernel_is_the_absorbed_xla_path(monkeypatch):
 
     cfg, _, params = make()
     attn, p, x, pool, step, n = _pooled_step(cfg, params, n=29)
-    want, _ = attn.apply({"params": p}, x[:, n:], step, pool, 1)
+    want, _ = _applied(attn, step)(p, x[:, n:], pool)
     monkeypatch.setattr(ops_attention, "use_decode_kernel", lambda: True)
     # 4 blocks of 4 keys a grid step: the row's 30 keys are two tiles, the
     # second with two blocks past the live prefix (at the published 512
     # keys a step the interpreter would move 128 operands a step)
     monkeypatch.setattr(latent_decode_attention, "LATENT_TILE_KEYS", 16)
     with tpu_interpret_mode():
-        got, _ = attn.apply({"params": p}, x[:, n:], step, pool, 1)
-        got = jax.block_until_ready(got)
+        got, _ = jax.block_until_ready(_applied(attn, step)(p, x[:, n:],
+                                                            pool))
     assert np.abs(np.asarray(got[1] - want[1])).max() <= 1e-5
     assert np.abs(np.asarray(want[1])).max() > 1e-3
 
@@ -619,8 +541,9 @@ def test_latent_families_list_the_kernels_work_once_a_step(monkeypatch,
                 "prefill": prefill}
 
     prompt = jnp.zeros((slots, 4), jnp.int32)
-    variables = model.init(jax.random.PRNGKey(0), prompt,
-                           paging=paging([0] * slots, 4, True))
+    variables = jax.jit(lambda ids: model.init(
+        jax.random.PRNGKey(0), ids, paging=paging([0] * slots, 4, True)))(
+            prompt)
     made = []
     real = latent_decode_attention.latent_step_work
 
@@ -658,32 +581,13 @@ def test_decode_through_both_kernels_matches_the_xla_paths(monkeypatch):
     """The decode program with the Pallas kernels in it (interpret mode):
     the latent multi-query kernel and the grouped expert matmul, against
     the same steps on the XLA paths."""
-    from deepspeed_tpu.ops import attention as ops_attention
-    from deepspeed_tpu.utils.compat import tpu_interpret_mode
-
     cfg, _, params = make()
-    prompt = _prompts(cfg, [19])[0]
-    plain = serving_engine(params, cfg)
-    want, _ = _paged_logits(plain, prompt, 3)
-    plain.destroy()
-    monkeypatch.setattr(ops_attention, "use_decode_kernel", lambda: True)
     monkeypatch.setattr(latent_decode_attention, "LATENT_TILE_KEYS", 16)
-    ffn = dropless.expert_ffn
-    monkeypatch.setattr(dropless, "expert_ffn", lambda *a, **k: ffn(
-        *a, **{**k, "use_kernel": True}))
-    srv = serving_engine(params, cfg)
-    try:
-        one = jax.devices()[0]
-        srv.engine.params, srv.cache = jax.device_put(
-            (srv.engine.params, srv.cache), one)
-        with tpu_interpret_mode():
-            got, _ = _paged_logits(srv, prompt, 3)
-        paths = srv.stats()["attention_paths"]
-        assert paths.get("mla_decode_absorbed_kernel") and paths.get(
-            "moe_experts_grouped_kernel")
-        assert np.abs(got - want).max() <= TOL
-    finally:
-        srv.destroy()
+    got, want, paths = FAMILY.decode_through_the_kernels(
+        monkeypatch, cfg, params, prompts(cfg, [19])[0], 3)
+    assert paths.get("mla_decode_absorbed_kernel") and paths.get(
+        "moe_experts_grouped_kernel")
+    assert np.abs(got - want).max() <= TOL
 
 
 @pytest.mark.parametrize("control", ["latent", "kvb"])
@@ -703,17 +607,15 @@ def test_a_lower_precision_moves_the_logits(highest, monkeypatch, control):
             deepseek_v2, "absorbed_halves",
             lambda w, nope: tuple(low(h) for h in halves(w, nope)))
     cfg, _, params = make()
-    srv = serving_engine(params, cfg)
-    try:
-        prompt = _prompts(cfg, [27])[0]
-        got, tokens = _paged_logits(srv, prompt, 10, chunk=8)
-        want = reference_logits(cfg, params, np.asarray([tokens]))[0]
-        miss = np.abs(got - want[:len(got)]).max(-1)
-        assert miss[len(prompt):].max() > 20 * TOL
-        if control == "kvb":       # the prefill never reads those halves
-            assert miss[:len(prompt)].max() <= TOL
-    finally:
-        srv.destroy()
+    # the shared engine, its paged module traced anew under the patch
+    prompt = prompts(cfg, [27])[0]
+    got, tokens = FAMILY.paged_logits(FAMILY.shared_engine(params, cfg),
+                                      prompt, 10, chunk=8, retrace=True)
+    want = reference_logits(cfg, params, [tokens])[0]
+    miss = np.abs(got - want[:len(got)]).max(-1)
+    assert miss[len(prompt):].max() > 20 * TOL
+    if control == "kvb":       # the prefill never reads those halves
+        assert miss[:len(prompt)].max() <= TOL
 
 
 # ---------------------------------------------------------------------------
@@ -725,44 +627,34 @@ def test_the_engine_serves_chunked_prefill_and_counts_latent_bytes(highest):
     sets the reference's own, and the ledger counts 576 values a live
     token a layer at each step boundary."""
     cfg, _, params = make()
-    prompts = _prompts(cfg, [21, 6, 33])
-    srv = serving_engine(params, cfg, prefill_chunk_tokens=8,
-                         routed_experts_kept=4)
-    try:
-        reqs = [srv.submit(p, max_new_tokens=n)
-                for p, n in zip(prompts, [9, 12, 5])]
-        srv.drain()
-        sparse, k = cfg.sparse_layers, cfg.num_experts_per_tok
-        for req, prompt in zip(reqs, prompts):
-            ids = np.asarray([list(prompt) + req.tokens[:-1]])
-            want = reference_logits(cfg, params, ids)[0]
-            assert req.tokens == want[len(prompt) - 1:].argmax(-1).tolist()
-            got = srv.routed_experts(req.request_id)
-            assert got.shape == (ids.shape[1], sparse * k)
-            sets = np.asarray(reference.routed_sets(
-                params, jnp.asarray(ids), shape_of(cfg)))[:, 0]
-            got = got.reshape(-1, sparse, k).transpose(1, 0, 2)
-            assert (np.sort(got, -1) == np.sort(sets, -1)).all()
-        stats = srv.stats()
-        assert set(stats["kv_live_bytes"]) == {"latent"}
-        row = cfg.num_hidden_layers * cfg.latent_row * 4       # float32
-        assert stats["kv_live_bytes"]["latent"] > 0
-        assert stats["kv_live_bytes"]["latent"] % row == 0
-        assert srv._dmodule.config.kv_bytes_per_token() == {"latent": row}
-        # (the paths are counted as programs are traced, process-wide)
-        assert stats["attention_paths"].get("mla_chunk_decompressed_xla")
-        assert set(srv._chunk_fns) == {8} and not srv._prefill_fns
-        assert stats["model_counters"]["decode"]["experts_held"] > 0
-        assert (stats["model_counters"]["prefill"]["pairs_here"]
-                == stats["model_counters"]["prefill"]["pairs_all"])
-        # ONE pool, a latent row a token, through the block table; no
-        # state a slot
-        assert {k: v.shape for k, v in srv.cache.items()} == {
-            "latent_pool": (cfg.num_hidden_layers, srv.num_blocks, BLOCK,
-                            cfg.latent_lanes)}
-        assert srv.slot_state is None and srv.slot_entries == 0
-    finally:
-        srv.destroy()
+    asked = prompts(cfg, [21, 6, 33])
+    stats, reqs = FAMILY.served_logits_match(
+        cfg, params, list(zip(asked, [9, 12, 5])), prefill_chunk_tokens=8,
+        routed_experts_kept=4)
+    srv = FAMILY.shared_engine(params, cfg, prefill_chunk_tokens=8,
+                               routed_experts_kept=4)
+    for req, prompt in zip(reqs, asked):
+        want = reference_logits(cfg, params,
+                                [list(prompt) + req.tokens[:-1]])[0]
+        assert req.tokens == want[len(prompt) - 1:].argmax(-1).tolist()
+        FAMILY.routed_sets_are_the_references(srv, cfg, params, req, prompt)
+    assert set(stats["kv_live_bytes"]) == {"latent"}
+    row = cfg.num_hidden_layers * cfg.latent_row * 4       # float32
+    assert stats["kv_live_bytes"]["latent"] > 0
+    assert stats["kv_live_bytes"]["latent"] % row == 0
+    assert srv._dmodule.config.kv_bytes_per_token() == {"latent": row}
+    # (the paths are counted as programs are traced, process-wide)
+    assert stats["attention_paths"].get("mla_chunk_decompressed_xla")
+    assert set(srv._chunk_fns) == {8} and not srv._prefill_fns
+    assert stats["model_counters"]["decode"]["experts_held"] > 0
+    assert (stats["model_counters"]["prefill"]["pairs_here"]
+            == stats["model_counters"]["prefill"]["pairs_all"])
+    # ONE pool, a latent row a token, through the block table; no state a
+    # slot
+    assert {k: v.shape for k, v in srv.cache.items()} == {
+        "latent_pool": (cfg.num_hidden_layers, srv.num_blocks, BLOCK,
+                        cfg.latent_lanes)}
+    assert srv.slot_state is None and srv.slot_entries == 0
 
 
 def test_the_published_row_is_576_values_in_640_lanes():
@@ -780,48 +672,21 @@ def test_the_published_row_is_576_values_in_640_lanes():
 # ---------------------------------------------------------------------------
 # refusals, by name
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("serving, mechanism", [
-    ({"prefix_cache": True}, "serving.prefix_cache"),
-    ({"speculative": {"num_speculative_tokens": 2}}, "serving.speculative"),
-    ({"kv_cache_dtype": "int8"}, "serving.kv_cache_dtype"),
-], ids=["prefix-cache", "speculation", "int8-kv"])
+@REFUSED
 def test_mechanisms_that_read_rows_by_heads_refuse_the_model(serving,
                                                              mechanism):
-    cfg, _, params = make()
-    with pytest.raises(Exception, match=mechanism.replace(".", r"\.")) as e:
-        serving_engine(params, cfg, **serving)
-    assert "DeepseekV2ForCausalLM" in str(e.value)
-    assert "one latent row a token" in str(e.value)
-    assert "keys and values by heads" in str(e.value)
+    said = FAMILY.mechanism_refusal(serving, mechanism)
+    assert "one latent row a token" in said
+    assert "keys and values by heads" in said
 
 
 def test_tensor_parallel_refuses_the_model():
-    cfg, _, params = make()
-    reset_topology()
-    with pytest.raises(Exception, match="tp_size > 1") as e:
-        ServingEngine(deepspeed_tpu.init_inference(
-            DeepseekV2ForCausalLM(cfg), params=params, dtype=cfg.dtype,
-            tensor_parallel={"tp_size": 2},
-            serving={"decode_slots": 2, "block_size": BLOCK,
-                     "max_model_len": 32}))
-    assert "DeepseekV2ForCausalLM" in str(e.value)
-    assert "latent row" in str(e.value)
-    reset_topology()
+    said = FAMILY.tensor_parallel_refusal()
+    assert "DeepseekV2ForCausalLM" in said and "latent row" in said
 
 
 def test_migration_refuses_the_model():
-    cfg, _, params = make()
-    srv = serving_engine(params, cfg)
-    try:
-        req = srv.submit([1, 2, 3, 4, 5], max_new_tokens=8)
-        srv.step()
-        for call in (lambda: srv.export_sequence(req.request_id),
-                     lambda: srv.import_sequence({"request_id": "x"})):
-            with pytest.raises(NotImplementedError, match="migration") as e:
-                call()
-            assert "latent row" in str(e.value)
-    finally:
-        srv.destroy()
+    assert all("latent row" in said for said in FAMILY.migration_refusals())
 
 
 def test_for_paged_decode_refuses_what_it_cannot_size():

@@ -11,7 +11,6 @@ model by name. (The controls the comparisons are not blind to go through
 the cell's own check: ``tests/perfbench/test_deepseek_v32_cell.py``.)"""
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +28,7 @@ from deepspeed_tpu.ops import dsa_sparse_attend as attend_op
 from deepspeed_tpu.parallel.topology import reset_topology
 from deepspeed_tpu.serving import ServingEngine
 from perfbench import reference_deepseek_v32 as reference
+from tests.unit.served_family import REFUSED, Family, highest  # noqa: F401
 
 TOL = 1e-4
 BLOCK = 4
@@ -49,45 +49,25 @@ def shape_of(cfg: DeepseekV32Config, first_expert: int = 0) -> dict:
                 dense=cfg.first_k_dense_replace, first_expert=first_expert)
 
 
-@functools.lru_cache(maxsize=None)
-def make(dtype=jnp.float32, seed=0):
-    """(config, module, parameters), made once: every test reads them."""
-    cfg = DeepseekV32Config.tiny(dtype=dtype, param_dtype=dtype)
-    module = DeepseekV32ForCausalLM(cfg)
-    params = jax.jit(module.init)(jax.random.PRNGKey(seed),
-                                  jnp.zeros((1, 8), jnp.int32))["params"]
-    return cfg, module, params
+FAMILY = Family(DeepseekV32Config, DeepseekV32ForCausalLM, reference,
+                shape_of, TOL,
+                serving={"decode_slots": 3, "block_size": BLOCK,
+                         "max_model_len": 96},
+                aux_of=lambda aux: aux["selected"])
+engines = FAMILY.engines()
+make = FAMILY.make
 
 
-@functools.lru_cache(maxsize=None)
 def _programs(with_layers=False):
-    """The plain call and the reference, each ONE jitted program (op by op
-    an un-jitted pass compiles some hundreds of them)."""
-    cfg, module, _ = make()
-    shape = shape_of(cfg)
-    return (jax.jit(lambda p, ids: module.apply({"params": p}, ids)),
-            jax.jit(lambda p, ids: reference.logits(
-                p, ids, shape, with_layers=with_layers)))
+    """The plain call and the reference, each ONE jitted program."""
+    cfg, _, _ = make()
+    return FAMILY.plain(cfg), FAMILY.reference_program(
+        cfg, **({"with_layers": True} if with_layers else {}))
 
 
 def _ids(cfg, rows, length, seed=5):
     return np.random.default_rng(seed).integers(
         0, cfg.vocab_size, (rows, length)).astype(np.int32)
-
-
-@pytest.fixture
-def highest():
-    with jax.default_matmul_precision("highest"):
-        yield
-
-
-def serving_engine(params, cfg, **serving):
-    reset_topology()
-    block = {"decode_slots": 3, "block_size": BLOCK, "max_model_len": 96,
-             **serving}
-    return ServingEngine(deepspeed_tpu.init_inference(
-        DeepseekV32ForCausalLM(cfg), params=params, dtype=cfg.dtype,
-        serving=block))
 
 
 # ---------------------------------------------------------------------------
@@ -188,55 +168,6 @@ def test_the_indexers_rotations_are_the_published_ones():
 # ---------------------------------------------------------------------------
 # through the two pools
 # ---------------------------------------------------------------------------
-def _paged_logits(srv, prompt, steps, slot=1, chunk=16):
-    """Drive the engine's own paged module with its own pools and tables,
-    as its programs do, and keep the LOGITS: the prompt in chunks of
-    ``chunk`` (0: whole, in a bucket it does not fill), then ``steps``
-    greedy decode steps in the decode program's batch shape, the other
-    slots idle. -> (logits [positions, vocab], ids, chosen keys [positions,
-    layers, words])."""
-    dm, params = srv._dmodule, srv.engine.params
-
-    def call(prefill):
-        def fn(cache, ids, tables, lengths, num_valid):
-            (logits, aux), v = dm.apply(
-                {"params": params, "cache": cache}, ids, mutable=["cache"],
-                paging={"block_tables": tables, "lengths": lengths,
-                        "num_valid": num_valid, "prefill": prefill})
-            return logits, aux["selected"], v["cache"]
-        return jax.jit(fn)
-
-    whole, cached = call(True), call(False)
-    table = srv._slot_table(slot, srv.block_mgr.allocate(
-        f"direct-{slot}-{len(prompt)}", len(prompt) + steps))
-    i32 = lambda x: jnp.asarray(x, jnp.int32)
-    rows, sets, n = [], [], len(prompt)
-    for at in range(0, n, chunk or n):
-        m = min(chunk or n, n - at)
-        width = chunk or (-(-n // 8) * 8 + 8)
-        ids = np.zeros((1, width), np.int32)
-        ids[0, :m] = prompt[at:at + m]
-        lg, sel, srv.cache = (cached if chunk else whole)(
-            srv.cache, i32(ids), i32(table[None]), i32([at]), i32([m]))
-        rows.append(np.asarray(lg[0, :m]))
-        sets.append(np.asarray(sel[0, :m]))
-    ids = list(prompt)
-    slots = srv.config.decode_slots
-    for _ in range(steps):
-        ids.append(int(rows[-1][-1].argmax()))
-        tables = np.zeros((slots, len(table)), np.int32)
-        tables[slot] = table
-        lengths = np.zeros((slots,), np.int32)
-        lengths[slot] = len(ids) - 1
-        tokens = np.zeros((slots, 1), np.int32)
-        tokens[slot] = ids[-1]
-        lg, sel, srv.cache = cached(srv.cache, i32(tokens), i32(tables),
-                                    i32(lengths), i32(np.ones(slots)))
-        rows.append(np.asarray(lg[slot]))
-        sets.append(np.asarray(sel[slot]))
-    return np.concatenate(rows), ids, np.concatenate(sets)
-
-
 @pytest.mark.parametrize("chunk", [24, 0], ids=["chunks-of-24", "whole"])
 def test_paged_logits_match_the_reference(highest, chunk):
     """A prompt of 61 tokens (chunks that do not divide it, a boundary
@@ -244,35 +175,32 @@ def test_paged_logits_match_the_reference(highest, chunk):
     reference's ONE full forward pass over the same 73 tokens: logits, and
     the chosen keys of every query of every layer."""
     cfg, _, params = make()
-    srv = serving_engine(params, cfg)
-    try:
-        prompt = _ids(cfg, 1, 61)[0].tolist()
-        got, ids, chosen = _paged_logits(srv, prompt, 12, chunk=chunk)
-        want, seen = _programs(True)[1](params, jnp.asarray([ids]))
-        assert np.abs(got - np.asarray(want)[0, :len(got)]).max() <= TOL
-        n = len(got)
-        theirs = np.asarray(select_op.unpack_bits(seen["selected"], n))
-        mine = np.asarray(select_op.unpack_bits(jnp.asarray(chosen), n))
-        assert (mine == theirs[0, :n]).all()
-        assert (mine.sum(-1) == np.minimum(np.arange(n) + 1, 16)[:, None]
-                ).all()
-    finally:
-        srv.destroy()
+    prompt = _ids(cfg, 1, 61)[0].tolist()
+    got, ids, chosen = FAMILY.paged_logits(FAMILY.shared_engine(params, cfg),
+                                           prompt, 12, chunk=chunk)
+    want, seen = _programs(True)[1](params, jnp.asarray([ids]))
+    assert np.abs(got - np.asarray(want)[0, :len(got)]).max() <= TOL
+    n = len(got)
+    theirs = np.asarray(select_op.unpack_bits(seen["selected"], n))
+    mine = np.asarray(select_op.unpack_bits(jnp.asarray(chosen), n))
+    assert (mine == theirs[0, :n]).all()
+    assert (mine.sum(-1) == np.minimum(np.arange(n) + 1, 16)[:, None]).all()
 
 
 def test_a_pool_full_of_nan_outside_the_live_prefixes_stays_outside(highest):
     cfg, _, params = make()
-    srv = serving_engine(params, cfg)
+    srv = FAMILY.shared_engine(params, cfg)
+    was = srv.cache                # put back at the end: the engine is shared
     try:
         srv.cache = jax.tree_util.tree_map(
             lambda pool: jnp.full_like(pool, jnp.nan), srv.cache)
         prompt = _ids(cfg, 1, 37)[0].tolist()
-        got, ids, _ = _paged_logits(srv, prompt, 6, chunk=16)
+        got, ids, _ = FAMILY.paged_logits(srv, prompt, 6, chunk=16)
         want = _programs()[1](params, jnp.asarray([ids]))
         assert np.isfinite(got).all()
         assert np.abs(got - np.asarray(want)[0, :len(got)]).max() <= TOL
     finally:
-        srv.destroy()
+        srv.cache = was
 
 
 def test_the_chunk_kernel_is_the_xla_path(highest, monkeypatch):
@@ -298,16 +226,19 @@ def test_the_chunk_kernel_is_the_xla_path(highest, monkeypatch):
     paging = {"block_tables": jnp.arange(1, 17, dtype=jnp.int32)[None],
               "lengths": jnp.asarray([300], jnp.int32),
               "num_valid": jnp.asarray([128], jnp.int32), "prefill": False}
-    params = mixer.init(jax.random.PRNGKey(0), x, paging, pools, 0)["params"]
-    plain, _, seen = jax.jit(mixer.apply)({"params": params}, x, paging,
-                                          pools, 0)
+    # (``paging`` holds a Python bool: closed over, not traced)
+    params = jax.jit(lambda x, pools: mixer.init(
+        jax.random.PRNGKey(0), x, paging, pools, 0))(x, pools)["params"]
+    apply = lambda p, x, pools: mixer.apply({"params": p}, x, paging, pools,
+                                            0)
+    plain, _, seen = jax.jit(apply)(params, x, pools)
     before = attention.dispatch_counts().get(
         "dsa_chunk_masked_decompressed_kernel", 0)
     monkeypatch.setattr(attention, "_FORCE_DECODE_KERNEL", True)
     with tpu_interpret_mode():
-        kernel, _, seen_k = mixer.apply({"params": params}, x, paging, pools,
-                                        0)
-        kernel = jax.block_until_ready(kernel)
+        # (a jit of its own: traced under the patch)
+        kernel, _, seen_k = jax.block_until_ready(
+            jax.jit(lambda *a: apply(*a))(params, x, pools))
     assert attention.dispatch_counts()[
         "dsa_chunk_masked_decompressed_kernel"] == before + 1
     assert (np.asarray(seen[0]) == np.asarray(seen_k[0])).all()
@@ -322,8 +253,8 @@ def test_the_chunk_kernel_is_the_xla_path(highest, monkeypatch):
 def test_the_engine_serves_chunks_counts_and_hands_back_the_chosen_keys(
         highest):
     cfg, module, params = make()
-    srv = serving_engine(params, cfg, prefill_chunk_tokens=16,
-                         routed_experts_kept=4)
+    srv = FAMILY.serving_engine(params, cfg, prefill_chunk_tokens=16,
+                                routed_experts_kept=4)
     try:
         prompts = [_ids(cfg, 1, n, seed=n)[0].tolist() for n in (50, 41, 33)]
         reqs = [srv.submit(p, max_new_tokens=9, keep_selected=(i != 1))
@@ -337,8 +268,7 @@ def test_the_engine_serves_chunks_counts_and_hands_back_the_chosen_keys(
         seqs = np.zeros((3, 50 + 8), np.int32)
         for row, (prompt, req) in enumerate(zip(prompts, reqs)):
             seqs[row, :len(prompt) + 8] = prompt + list(req.tokens)[:-1]
-        want, seen = jax.jit(lambda ids: reference.logits(
-            params, ids, shape_of(cfg), with_layers=True))(jnp.asarray(seqs))
+        want, seen = _programs(True)[1](params, jnp.asarray(seqs))
         for row, (prompt, req) in enumerate(zip(prompts, reqs)):
             assert np.asarray(jnp.argmax(want[row, len(prompt) - 1:
                                               len(prompt) + 8], -1)
@@ -392,49 +322,22 @@ def test_the_published_rows_and_what_a_step_reads():
 # ---------------------------------------------------------------------------
 # refusals, by name
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("serving, mechanism", [
-    ({"prefix_cache": True}, "serving.prefix_cache"),
-    ({"speculative": {"num_speculative_tokens": 2}}, "serving.speculative"),
-    ({"kv_cache_dtype": "int8"}, "serving.kv_cache_dtype"),
-], ids=["prefix-cache", "speculation", "int8-kv"])
+@REFUSED
 def test_mechanisms_that_read_rows_by_heads_refuse_the_model(serving,
                                                              mechanism):
-    cfg, _, params = make()
-    with pytest.raises(Exception, match=mechanism.replace(".", r"\.")) as e:
-        serving_engine(params, cfg, **serving)
-    assert "DeepseekV32ForCausalLM" in str(e.value)
-    assert "one latent row a token" in str(e.value)
-    assert "an index row beside it" in str(e.value)
-    assert "keys and values by heads" in str(e.value)
+    said = FAMILY.mechanism_refusal(serving, mechanism)
+    assert "one latent row a token" in said
+    assert "an index row beside it" in said
+    assert "keys and values by heads" in said
 
 
 def test_tensor_parallel_refuses_the_model():
-    cfg, _, params = make()
-    reset_topology()
-    with pytest.raises(Exception, match="tp_size > 1") as e:
-        ServingEngine(deepspeed_tpu.init_inference(
-            DeepseekV32ForCausalLM(cfg), params=params, dtype=cfg.dtype,
-            tensor_parallel={"tp_size": 2},
-            serving={"decode_slots": 2, "block_size": BLOCK,
-                     "max_model_len": 32}))
-    assert "DeepseekV32ForCausalLM" in str(e.value)
-    assert "latent row" in str(e.value)
-    reset_topology()
+    said = FAMILY.tensor_parallel_refusal()
+    assert "DeepseekV32ForCausalLM" in said and "latent row" in said
 
 
 def test_migration_refuses_the_model():
-    cfg, _, params = make()
-    srv = serving_engine(params, cfg)
-    try:
-        req = srv.submit([1, 2, 3, 4, 5], max_new_tokens=8)
-        srv.step()
-        for call in (lambda: srv.export_sequence(req.request_id),
-                     lambda: srv.import_sequence({"request_id": "x"})):
-            with pytest.raises(NotImplementedError, match="migration") as e:
-                call()
-            assert "latent row" in str(e.value)
-    finally:
-        srv.destroy()
+    assert all("latent row" in said for said in FAMILY.migration_refusals())
 
 
 def test_a_model_without_chosen_keys_refuses_a_request_that_asks():

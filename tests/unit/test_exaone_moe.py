@@ -16,16 +16,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu
 from deepspeed_tpu.models import blocks
 from deepspeed_tpu.models.exaone_moe import (GLOBAL, WINDOW, ExaoneAttention,
                                              ExaoneMoeConfig,
                                              ExaoneMoeForCausalLM,
                                              SparseExperts)
 from deepspeed_tpu.moe import dropless
-from deepspeed_tpu.parallel.topology import reset_topology
-from deepspeed_tpu.serving import ServingEngine
 from perfbench import reference_exaone_moe as reference
+from tests.unit.served_family import REFUSED, Family, highest, prompts  # noqa: F401
 
 # float32 program against the float32 reference, on logits of order 1: the
 # two differ by the order of their sums (the program's attention is an
@@ -50,40 +48,26 @@ def shape_of(cfg: ExaoneMoeConfig, first_expert=None) -> dict:
         sparse=tuple(kind == "sparse" for kind in cfg.mlp_layer_types))
 
 
-def make(dtype=jnp.float32, seed=0, **kw):
-    cfg = ExaoneMoeConfig.tiny(dtype=dtype, **kw)
-    module = ExaoneMoeForCausalLM(cfg)
-    params = module.init(jax.random.PRNGKey(seed),
-                         jnp.zeros((1, 8), jnp.int32))["params"]
-    # norm weights away from 1, so that a norm left out or misplaced shows
-    params = jax.tree_util.tree_map_with_path(
+def _norms_away_from_one(params):
+    """Norm weights away from 1, so that a norm left out or misplaced
+    shows."""
+    return jax.tree_util.tree_map_with_path(
         lambda path, x: x * (1.0 + 0.3 * jnp.cos(jnp.arange(x.size)).reshape(
             x.shape)) if path[-1].key == "scale" else x, params)
-    return cfg, module, params
 
 
-def reference_logits(cfg, params, ids):
-    return np.asarray(reference.logits(params, jnp.asarray(ids),
-                                       shape_of(cfg)))
-
-
-@pytest.fixture
-def highest():
-    # the CPU multiplies float32 exactly; the setting is the chip's, kept so
-    # that the test says what it compares
-    with jax.default_matmul_precision("highest"):
-        yield
-
-
-def _prompts(cfg, lengths, seed=5):
-    rng = np.random.default_rng(seed)
-    return [rng.integers(0, cfg.vocab_size, n).tolist() for n in lengths]
+FAMILY = Family(ExaoneMoeConfig, ExaoneMoeForCausalLM, reference, shape_of,
+                TOL, serving={"decode_slots": 3, "block_size": BLOCK,
+                              "max_model_len": 64},
+                perturb=_norms_away_from_one, bucket_slack=0)
+engines = FAMILY.engines()
+make, reference_logits = FAMILY.make, FAMILY.reference_logits
 
 
 def test_full_forward_matches_the_reference(highest):
     cfg, module, params = make()
     ids = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 40))
-    got = np.asarray(module.apply({"params": params}, jnp.asarray(ids)))
+    got = np.asarray(FAMILY.plain(cfg)(params, jnp.asarray(ids)))
     want = reference_logits(cfg, params, ids)
     assert np.abs(want).max() > 0.1
     assert np.abs(got - want).max() <= TOL
@@ -100,8 +84,8 @@ def test_bf16_fails_the_float32_tolerance():
     apart."""
     cfg, module, params = make()
     ids = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 40))
-    low = ExaoneMoeForCausalLM(dataclasses.replace(cfg, dtype=jnp.bfloat16))
-    got = np.asarray(low.apply({"params": params}, jnp.asarray(ids)))
+    low = FAMILY.plain(dataclasses.replace(cfg, dtype=jnp.bfloat16))
+    got = np.asarray(low(params, jnp.asarray(ids)))
     assert np.abs(got - reference_logits(cfg, params, ids)).max() > 10 * TOL
 
 
@@ -112,11 +96,11 @@ def test_the_head_norms_move_the_logits(highest, part):
     cfg, module, params = make()
     ids = jnp.asarray(np.random.default_rng(1).integers(
         0, cfg.vocab_size, (1, 24)))
-    base = np.asarray(module.apply({"params": params}, ids))
+    base = np.asarray(FAMILY.plain(cfg)(params, ids))
     for layer in ("layers_3_attn", "layers_4_attn"):   # global, sliding
         moved = {**params, layer: {**params[layer], part: {
             "scale": params[layer][part]["scale"][::-1]}}}
-        got = np.asarray(module.apply({"params": moved}, ids))
+        got = np.asarray(FAMILY.plain(cfg)(moved, ids))
         assert np.abs(got - base).max() > 100 * TOL, layer
 
 
@@ -272,44 +256,14 @@ def test_a_config_is_refused_where_its_lists_do_not_fit():
 # ---------------------------------------------------------------------------
 # through the paged cache
 # ---------------------------------------------------------------------------
-def serving_engine(params, cfg, **serving):
-    reset_topology()
-    block = {"decode_slots": 3, "block_size": BLOCK, "max_model_len": 64,
-             **serving}
-    return ServingEngine(deepspeed_tpu.init_inference(
-        ExaoneMoeForCausalLM(cfg), params=params, dtype=cfg.dtype,
-        serving=block))
-
-
-def served_logits_match(cfg, params, requests, **serving):
-    """Serve ``requests`` [(prompt, new tokens)] greedily; every served
-    token has to be the reference's argmax at its position (the tiny
-    model's logits are separated by far more than the tolerance)."""
-    srv = serving_engine(params, cfg, **serving)
-    try:
-        reqs = [srv.submit(p, max_new_tokens=n) for p, n in requests]
-        srv.drain()
-        stats = srv.stats()
-        for req, (prompt, n) in zip(reqs, requests):
-            assert len(req.tokens) == n, (req.state, req.finish_reason)
-            ids = np.asarray([list(prompt) + req.tokens])
-            want = reference_logits(cfg, params, ids)[0]
-            for k, tok in enumerate(req.tokens):
-                row = want[len(prompt) - 1 + k]
-                assert row.max() - row[tok] <= TOL, (k, tok, row.argmax())
-        return stats
-    finally:
-        srv.destroy()
-
-
 def test_prefill_and_decode_through_the_cache(highest):
     """Contexts past the window and past a ring's 12 rows (the ring of 3
     blocks wraps several times), slots of unequal length, and a slot
     reused after a finish (5 requests over 3 slots)."""
     cfg, _, params = make()
-    prompts = _prompts(cfg, [5, 19, 33, 9, 26])
-    stats = served_logits_match(
-        cfg, params, list(zip(prompts, [30, 12, 20, 25, 8])))
+    asked = prompts(cfg, [5, 19, 33, 9, 26])
+    stats, _ = FAMILY.served_logits_match(
+        cfg, params, list(zip(asked, [30, 12, 20, 25, 8])))
     counted = stats["model_counters"]
     sparse = cfg.sparse_layers
     assert sparse == 4
@@ -317,7 +271,7 @@ def test_prefill_and_decode_through_the_cache(highest):
         stats["busy_slot_steps"] * sparse * cfg.num_experts_per_tok)
     assert counted["decode"]["pairs_here"] == counted["decode"]["pairs_all"]
     assert counted["prefill"]["pairs_all"] == (
-        sum(map(len, prompts)) * sparse * cfg.num_experts_per_tok)
+        sum(map(len, asked)) * sparse * cfg.num_experts_per_tok)
     kv = stats["kv_live_bytes"]
     assert 0 < kv["window"] and 0 < kv["global"]
     assert {"exaone_window_prefill_xla", "exaone_global_prefill_xla",
@@ -330,9 +284,9 @@ def test_a_prompt_through_chunked_prefill(highest):
     ring's seam or a lap of it, and the global layer takes its keys a tile
     at a time."""
     cfg, _, params = make()
-    prompts = _prompts(cfg, [37, 6])
-    stats = served_logits_match(cfg, params, list(zip(prompts, [14, 14])),
-                                prefill_chunk_tokens=8)
+    stats, _ = FAMILY.served_logits_match(
+        cfg, params, list(zip(prompts(cfg, [37, 6]), [14, 14])),
+        prefill_chunk_tokens=8)
     assert {"exaone_window_cached_xla", "exaone_global_cached_tiled_xla"} <= (
         set(stats["attention_paths"]))
 
@@ -344,8 +298,8 @@ def test_an_expert_share_serves_only_its_experts(highest):
     cfg, _, params = make(ep_size=8, ep_rank=3)
     assert params["layers_1_mlp"]["gate"].shape[0] == 4
     assert params["layers_1_mlp"]["router"].shape[1] == 32
-    stats = served_logits_match(
-        cfg, params, list(zip(_prompts(cfg, [11, 21]), [16, 16])))
+    stats, _ = FAMILY.served_logits_match(
+        cfg, params, list(zip(prompts(cfg, [11, 21]), [16, 16])))
     counted = stats["model_counters"]["decode"]
     assert 0 < counted["pairs_here"] < counted["pairs_all"] / 4
     assert 0 < counted["experts_touched"] <= counted["experts_held"]
@@ -355,31 +309,20 @@ def test_an_expert_share_serves_only_its_experts(highest):
 
 def test_the_engine_hands_back_the_routed_sets(highest):
     cfg, _, params = make()
-    prompts = _prompts(cfg, [21, 13])
-    srv = serving_engine(params, cfg, routed_experts_kept=4,
-                         prefill_chunk_tokens=8)
-    try:
-        reqs = [srv.submit(p, max_new_tokens=n)
-                for p, n in zip(prompts, [9, 5])]
-        srv.drain()
-        sparse, k = cfg.sparse_layers, cfg.num_experts_per_tok
-        for req, prompt in zip(reqs, prompts):
-            got = srv.routed_experts(req.request_id)
-            ids = np.asarray([list(prompt) + req.tokens[:-1]])
-            assert got.shape == (ids.shape[1], sparse * k)
-            want = np.asarray(reference.routed_sets(
-                params, jnp.asarray(ids), shape_of(cfg)))[:, 0]
-            got = got.reshape(-1, sparse, k).transpose(1, 0, 2)
-            assert (np.sort(got, -1) == np.sort(want, -1)).all()
-    finally:
-        srv.destroy()
+    asked = prompts(cfg, [21, 13])
+    srv = FAMILY.shared_engine(params, cfg, routed_experts_kept=4,
+                               prefill_chunk_tokens=8)
+    reqs = [srv.submit(p, max_new_tokens=n) for p, n in zip(asked, [9, 5])]
+    srv.drain()
+    for req, prompt in zip(reqs, asked):
+        FAMILY.routed_sets_are_the_references(srv, cfg, params, req, prompt)
 
 
 def test_the_window_pool_does_not_grow_with_the_context(highest):
     cfg, _, params = make()
     sizes = {}
     for longest in (32, 64):
-        srv = serving_engine(params, cfg, max_model_len=longest)
+        srv = FAMILY.serving_engine(params, cfg, max_model_len=longest)
         sizes[longest] = {k: v.shape for k, v in srv.cache.items()}
         ring = srv.slot_entries
         srv.destroy()
@@ -393,60 +336,6 @@ def test_the_window_pool_does_not_grow_with_the_context(highest):
     assert sizes[32]["global_key_pool"][3] == sizes[32]["window_key_pool"][3]
 
 
-def _paged_logits(srv, cfg, prompt, steps, slot=1, chunk=0, one_device=False,
-                  spoil=None):
-    """Drive the engine's own paged module with its own pool and tables,
-    as its programs do, and keep the LOGITS: every prompt position (whole
-    prompt, or chunks of ``chunk``), then ``steps`` greedy decode steps in
-    the decode program's batch shape; ``spoil(cache) -> cache`` runs between
-    the two. -> (logits [positions, vocab], ids)."""
-    dm, params = srv._dmodule, srv.engine.params
-    if one_device:
-        # the Pallas interpreter's callbacks do not go through the SPMD
-        # partitioner the engine's 8-device CPU mesh brings
-        params, srv.cache = jax.device_put((params, srv.cache),
-                                           jax.devices()[0])
-
-    def call(prefill):
-        def fn(cache, ids, tables, lengths, num_valid):
-            out, v = dm.apply(
-                {"params": params, "cache": cache}, ids, mutable=["cache"],
-                paging={"block_tables": tables, "lengths": lengths,
-                        "num_valid": num_valid, "prefill": prefill})
-            return out[0], v["cache"]
-        return jax.jit(fn)
-
-    whole, cached = call(True), call(False)
-    table = srv._slot_table(slot, srv.block_mgr.allocate(
-        "direct", len(prompt) + steps))
-    i32 = lambda x: jnp.asarray(x, jnp.int32)
-    rows, n = [], len(prompt)
-    for at in range(0, n, chunk or n):
-        m = min(chunk or n, n - at)
-        width = chunk or -(-n // 8) * 8
-        ids = np.zeros((1, width), np.int32)
-        ids[0, :m] = prompt[at:at + m]
-        lg, srv.cache = (cached if chunk else whole)(
-            srv.cache, i32(ids), i32(table[None]), i32([at]), i32([m]))
-        rows.append(np.asarray(lg[0, :m]))
-    if spoil is not None:
-        srv.cache = spoil(srv.cache)
-    slots = srv.config.decode_slots
-    tables = np.zeros((slots, len(table)), np.int32)
-    tables[slot] = table
-    tokens = list(prompt)
-    for _ in range(steps):
-        tokens.append(int(rows[-1][-1].argmax()))
-        lengths, last = np.zeros(slots, np.int32), np.zeros((slots, 1),
-                                                            np.int32)
-        lengths[slot], last[slot] = len(tokens) - 1, tokens[-1]
-        lg, srv.cache = cached(srv.cache, i32(last), i32(tables),
-                               i32(lengths), jnp.ones(slots, jnp.int32))
-        rows.append(np.asarray(lg[slot]))
-    srv.block_mgr.release("direct")
-    return np.concatenate(rows), tokens
-
-
 @pytest.mark.parametrize("chunk", [0, 8, 5],
                          ids=["whole-prompt", "chunks-of-8", "chunks-of-5"])
 def test_paged_logits_match_the_reference(highest, chunk):
@@ -457,15 +346,11 @@ def test_paged_logits_match_the_reference(highest, chunk):
     rows (12) (what ``tools/chip_logits_exaone_moe.py`` does on the chip
     at the published widths)."""
     cfg, _, params = make()
-    srv = serving_engine(params, cfg)
-    try:
-        prompt = _prompts(cfg, [27])[0]
-        got, tokens = _paged_logits(srv, cfg, prompt, 16, chunk=chunk)
-        want = reference_logits(cfg, params, np.asarray([tokens]))[0]
-        assert len(got) == 27 + 16 > 3 * 12
-        assert np.abs(got - want[:len(got)]).max() <= TOL
-    finally:
-        srv.destroy()
+    got, tokens = FAMILY.paged_logits(FAMILY.shared_engine(params, cfg),
+                                      prompts(cfg, [27])[0], 16, chunk=chunk)
+    want = reference_logits(cfg, params, [tokens])[0]
+    assert len(got) == 27 + 16 > 3 * 12
+    assert np.abs(got - want[:len(got)]).max() <= TOL
 
 
 def test_a_stale_ring_row_moves_the_logits(highest):
@@ -474,25 +359,23 @@ def test_a_stale_ring_row_moves_the_logits(highest):
     ring that missed a write would hold an older lap's) moves the next
     step's logits far outside the tolerance."""
     cfg, _, params = make()
-    srv = serving_engine(params, cfg)
-    try:
-        prompt = _prompts(cfg, [27])[0]
-        ring, slot = srv.slot_entries, 1
-        last = len(prompt) - 1
-        own = 1 + slot * ring + (last // BLOCK) % ring
+    srv = FAMILY.shared_engine(params, cfg)
+    prompt = prompts(cfg, [27])[0]
+    ring, slot = srv.slot_entries, 1
+    last = len(prompt) - 1
+    own = 1 + slot * ring + (last // BLOCK) % ring
 
-        def spoil(cache):
-            pool = cache["window_key_pool"]
-            return {**cache, "window_key_pool": pool.at[
-                :, own, last % BLOCK].set(0.0)}
+    def spoil(cache):
+        pool = cache["window_key_pool"]
+        return {**cache, "window_key_pool": pool.at[
+            :, own, last % BLOCK].set(0.0)}
 
-        got, tokens = _paged_logits(srv, cfg, prompt, 2, spoil=spoil)
-        want = reference_logits(cfg, params, np.asarray([tokens]))[0]
-        # the prompt's logits were made before the row went stale
-        assert np.abs(got[:len(prompt)] - want[:len(prompt)]).max() <= TOL
-        assert np.abs(got[len(prompt)] - want[len(prompt)]).max() > 100 * TOL
-    finally:
-        srv.destroy()
+    # (the shared engine: the row is the slot's next tenant's to overwrite)
+    got, tokens = FAMILY.paged_logits(srv, prompt, 2, spoil=spoil)
+    want = reference_logits(cfg, params, [tokens])[0]
+    # the prompt's logits were made before the row went stale
+    assert np.abs(got[:len(prompt)] - want[:len(prompt)]).max() <= TOL
+    assert np.abs(got[len(prompt)] - want[len(prompt)]).max() > 100 * TOL
 
 
 def test_decode_through_both_kernels_matches_the_xla_paths(monkeypatch):
@@ -500,74 +383,31 @@ def test_decode_through_both_kernels_matches_the_xla_paths(monkeypatch):
     the paged GQA kernel over the block table (not rotated) and over the
     ring (rotated, no sink), both at one row shape, and the grouped expert
     matmul, against the same steps on the XLA paths."""
-    from deepspeed_tpu.ops import attention as ops_attention
-    from deepspeed_tpu.utils.compat import tpu_interpret_mode
-
     cfg, _, params = make()
-    prompt = _prompts(cfg, [19])[0]
-    plain = serving_engine(params, cfg)
-    want, _ = _paged_logits(plain, cfg, prompt, 3)
-    plain.destroy()
-    monkeypatch.setattr(ops_attention, "use_decode_kernel", lambda: True)
-    ffn = dropless.expert_ffn
-    monkeypatch.setattr(dropless, "expert_ffn", lambda *a, **k: ffn(
-        *a, **{**k, "use_kernel": True}))
-    srv = serving_engine(params, cfg)
-    try:
-        with tpu_interpret_mode():
-            got, _ = _paged_logits(srv, cfg, prompt, 3, one_device=True)
-        paths = srv.stats()["attention_paths"]
-        assert paths.get("exaone_window_decode_kernel") and paths.get(
-            "exaone_global_decode_kernel")
-        assert paths.get("moe_experts_grouped_kernel")
-        assert np.abs(got - want).max() <= TOL
-    finally:
-        srv.destroy()
+    got, want, paths = FAMILY.decode_through_the_kernels(
+        monkeypatch, cfg, params, prompts(cfg, [19])[0], 3)
+    assert paths.get("exaone_window_decode_kernel") and paths.get(
+        "exaone_global_decode_kernel")
+    assert paths.get("moe_experts_grouped_kernel")
+    assert np.abs(got - want).max() <= TOL
 
 
 # ---------------------------------------------------------------------------
 # refusals, by name
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("serving, mechanism", [
-    ({"prefix_cache": True}, "serving.prefix_cache"),
-    ({"speculative": {"num_speculative_tokens": 2}}, "serving.speculative"),
-    ({"kv_cache_dtype": "int8"}, "serving.kv_cache_dtype"),
-], ids=["prefix-cache", "speculation", "int8-kv"])
+@REFUSED
 def test_mechanisms_that_know_one_kind_of_row_refuse_the_model(serving,
                                                                mechanism):
-    cfg, _, params = make()
-    with pytest.raises(Exception, match=mechanism.replace(".", r"\.")) as e:
-        serving_engine(params, cfg, **serving)
-    assert "ExaoneMoeForCausalLM" in str(e.value)
-    assert "ring a decode slot" in str(e.value)
+    assert "ring a decode slot" in FAMILY.mechanism_refusal(serving,
+                                                            mechanism)
 
 
 def test_tensor_parallel_refuses_the_model():
-    cfg, _, params = make()
-    reset_topology()
-    with pytest.raises(Exception, match="tp_size > 1") as e:
-        ServingEngine(deepspeed_tpu.init_inference(
-            ExaoneMoeForCausalLM(cfg), params=params, dtype=cfg.dtype,
-            tensor_parallel={"tp_size": 2},
-            serving={"decode_slots": 2, "block_size": BLOCK,
-                     "max_model_len": 32}))
-    assert "ExaoneMoeForCausalLM" in str(e.value)
-    reset_topology()
+    assert "ExaoneMoeForCausalLM" in FAMILY.tensor_parallel_refusal()
 
 
 def test_migration_refuses_the_model():
-    cfg, _, params = make()
-    srv = serving_engine(params, cfg)
-    try:
-        req = srv.submit([1, 2, 3, 4, 5], max_new_tokens=8)
-        srv.step()
-        with pytest.raises(NotImplementedError, match="migration") as e:
-            srv.export_sequence(req.request_id)
-        assert "ExaoneMoeForCausalLM" in str(e.value)
-        with pytest.raises(NotImplementedError, match="migration"):
-            srv.import_sequence({"request_id": "x"})
-    finally:
-        srv.destroy()
+    assert "ExaoneMoeForCausalLM" in FAMILY.migration_refusals()[0]
 
 
 def test_the_quantized_pool_is_refused_by_the_config_too():

@@ -29,9 +29,10 @@ def _check(flash, reference, qkv, grad, valid_from=0, tol=2e-3, gtol=5e-3):
     """``flash`` against ``reference`` on the query rows from
     ``valid_from`` on (the rows before see no key: the kernel gives 0 there,
     the XLA softmax a uniform row), forward and, with ``grad``, dq/dk/dv."""
+    # (each side ONE program: op by op the reference alone compiles dozens)
     with tpu_interpret_mode():
-        o = jax.block_until_ready(flash(*qkv))
-    o_ref = reference(*qkv)
+        o = jax.block_until_ready(jax.jit(flash)(*qkv))
+    o_ref = jax.jit(reference)(*qkv)
     np.testing.assert_allclose(np.asarray(o)[:, :, valid_from:],
                                np.asarray(o_ref)[:, :, valid_from:],
                                rtol=tol, atol=tol)
@@ -47,8 +48,8 @@ def _check(flash, reference, qkv, grad, valid_from=0, tol=2e-3, gtol=5e-3):
 
     with tpu_interpret_mode():
         got = jax.block_until_ready(
-            jax.grad(loss(flash), argnums=(0, 1, 2))(*qkv))
-    want = jax.grad(loss(reference), argnums=(0, 1, 2))(*qkv)
+            jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(*qkv))
+    want = jax.jit(jax.grad(loss(reference), argnums=(0, 1, 2)))(*qkv)
     for name, a, b in zip("qkv", got, want):
         scale = float(jnp.max(jnp.abs(b))) + 1e-9
         np.testing.assert_allclose(

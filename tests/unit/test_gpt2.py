@@ -29,8 +29,8 @@ class TestModel:
         cfg = GPT2Config.tiny(dtype=jnp.float32)
         m = GPT2LMHeadModel(cfg)
         ids = jnp.ones((2, 16), jnp.int32)
-        params = m.init(jax.random.PRNGKey(0), ids)["params"]
-        logits = m.apply({"params": params}, ids)
+        params = jax.jit(m.init)(jax.random.PRNGKey(0), ids)["params"]
+        logits = jax.jit(m.apply)({"params": params}, ids)
         assert logits.shape == (2, 16, cfg.vocab_size)
         assert logits.dtype == jnp.float32
 
@@ -39,8 +39,8 @@ class TestModel:
         for scan in (True, False):
             cfg = GPT2Config.tiny(dtype=jnp.float32, scan_layers=scan)
             m = GPT2LMHeadModel(cfg)
-            params = m.init(jax.random.PRNGKey(0), ids)["params"]
-            assert m.apply({"params": params}, ids).shape == (2, 16, 256)
+            params = jax.jit(m.init)(jax.random.PRNGKey(0), ids)["params"]
+            assert jax.jit(m.apply)({"params": params}, ids).shape == (2, 16, 256)
 
     @pytest.mark.parametrize("scan", [True, False])
     def test_train_forward_has_no_kv_pool(self, scan):
@@ -54,7 +54,7 @@ class TestModel:
                               remat=True, remat_policy="dots")
         m = GPT2LMHeadModel(cfg)
         ids = jnp.ones((2, 16), jnp.int32)
-        variables = m.init(jax.random.PRNGKey(0), ids)
+        variables = jax.jit(m.init)(jax.random.PRNGKey(0), ids)
         assert set(variables) == {"params"}
         logits, mutated = m.apply({"params": variables["params"]}, ids,
                                   mutable=["cache"])
@@ -77,10 +77,10 @@ class TestModel:
         m = GPT2LMHeadModel(cfg)
         rng = np.random.default_rng(0)
         ids = jnp.asarray(rng.integers(0, 256, (1, 16)), jnp.int32)
-        params = m.init(jax.random.PRNGKey(0), ids)["params"]
-        base = m.apply({"params": params}, ids)
+        params = jax.jit(m.init)(jax.random.PRNGKey(0), ids)["params"]
+        base = jax.jit(m.apply)({"params": params}, ids)
         ids2 = ids.at[0, 10].set((ids[0, 10] + 1) % 256)
-        pert = m.apply({"params": params}, ids2)
+        pert = jax.jit(m.apply)({"params": params}, ids2)
         np.testing.assert_allclose(base[0, :10], pert[0, :10], atol=1e-5)
         assert not np.allclose(base[0, 10:], pert[0, 10:], atol=1e-5)
 
@@ -95,10 +95,10 @@ class TestModel:
         cfg = GPT2Config.tiny(dtype=jnp.float32, remat=False)
         cfg_r = GPT2Config.tiny(dtype=jnp.float32, remat=True)
         m, mr = GPT2LMHeadModel(cfg), GPT2LMHeadModel(cfg_r)
-        params = m.init(jax.random.PRNGKey(0), ids)["params"]
+        params = jax.jit(m.init)(jax.random.PRNGKey(0), ids)["params"]
         np.testing.assert_allclose(
-            m.apply({"params": params}, ids),
-            mr.apply({"params": params}, ids), atol=1e-5)
+            jax.jit(m.apply)({"params": params}, ids),
+            jax.jit(mr.apply)({"params": params}, ids), atol=1e-5)
 
 
 class TestEndToEnd:
@@ -173,10 +173,11 @@ class TestBthdAttentionLayout:
                              attn_layout=layout)
             model = GPT2ForTraining(cfg)
             with tpu_interpret_mode():
-                params = model.init(jax.random.PRNGKey(0),
-                                    {"input_ids": ids})["params"]
-                loss, grads = jax.value_and_grad(
-                    lambda p: model.loss_fn(p, {"input_ids": ids}))(params)
+                params = jax.jit(model.init)(jax.random.PRNGKey(0),
+                                             {"input_ids": ids})["params"]
+                loss, grads = jax.block_until_ready(jax.jit(
+                    jax.value_and_grad(lambda p: model.loss_fn(
+                        p, {"input_ids": ids})))(params))
             outs[layout] = (float(loss), grads)
         assert outs["bhtd"][0] == pytest.approx(outs["bthd"][0], rel=1e-5)
         jax.tree_util.tree_map(
@@ -195,7 +196,7 @@ class TestBthdAttentionLayout:
                          attn_layout="bthd")
         model = GPT2LMHeadModel(cfg)
         with tpu_interpret_mode():
-            params = model.init(jax.random.PRNGKey(0), ids)["params"]
+            params = jax.jit(model.init)(jax.random.PRNGKey(0), ids)["params"]
             logits = model.apply({"params": params}, ids,
                                  attention_mask=jnp.asarray(mask))
         assert np.isfinite(np.asarray(logits)).all()

@@ -57,8 +57,8 @@ class TestGPTMoE:
         cfg = GPTMoEConfig.tiny(gpt_kw={"dtype": jnp.float32})
         model = GPTMoEModel(cfg)
         ids = _batch()["input_ids"]
-        params = model.init(jax.random.PRNGKey(0), ids)["params"]
-        logits, l_aux = model.apply({"params": params}, ids)
+        params = jax.jit(model.init)(jax.random.PRNGKey(0), ids)["params"]
+        logits, l_aux = jax.jit(model.apply)({"params": params}, ids)
         assert logits.shape == (8, 16, 256)
         assert float(l_aux) > 0  # load-balance loss is live, not a stub
         # scanned pair layout: expert params are [n_pairs, E, ...]
@@ -99,14 +99,14 @@ class TestGPTMoE:
                                         "n_positions": 16})
         model = GPTMoEModel(cfg)
         ids = np.array([[3, 17, 42, 99]], np.int32)
-        params = model.init(jax.random.PRNGKey(0), ids)["params"]
+        params = jax.jit(model.init)(jax.random.PRNGKey(0), ids)["params"]
         engine = deepspeed_tpu.init_inference(model, params=params)
         out = np.asarray(engine.generate(ids, max_new_tokens=3,
                                          do_sample=False))
         # reference chain: greedy-extend with the dense (non-cached) model
         cur = ids
         for _ in range(3):
-            logits, _ = model.apply({"params": params}, cur)
+            logits, _ = jax.jit(model.apply)({"params": params}, cur)
             nxt = np.argmax(np.asarray(logits[:, -1]), axis=-1)
             cur = np.concatenate([cur, nxt[:, None].astype(np.int32)], axis=1)
         np.testing.assert_array_equal(out, cur)
@@ -117,22 +117,21 @@ class TestGPTMoE:
                                         "n_positions": 16})
         model = GPTMoEModel(cfg)
         ids = np.array([[3, 17, 42, 99, 7, 23, 56, 1]], np.int32)
-        params = model.init(jax.random.PRNGKey(0), ids)["params"]
-        dense, _ = model.apply({"params": params}, ids)
+        params = jax.jit(model.init)(jax.random.PRNGKey(0), ids)["params"]
+        dense, _ = jax.jit(model.apply)({"params": params}, ids)
         dmodel = GPTMoEModel(cfg.for_decode())
-        vars0 = dmodel.init(jax.random.PRNGKey(0), ids[:, :1])
+        vars0 = jax.jit(dmodel.init)(jax.random.PRNGKey(0), ids[:, :1])
         cache = jax.tree_util.tree_map(jnp.zeros_like, vars0["cache"])
-        (logits, _), mut = dmodel.apply(
-            {"params": params, "cache": cache}, ids[:, :4],
-            mutable=["cache"])
+        # (one program a step shape: op by op a pass compiles some hundreds)
+        step = jax.jit(lambda p, cache, ids: dmodel.apply(
+            {"params": p, "cache": cache}, ids, mutable=["cache"]))
+        (logits, _), mut = step(params, cache, ids[:, :4])
         cache = mut["cache"]
         np.testing.assert_allclose(np.asarray(logits[:, -1]),
                                    np.asarray(dense[:, 3]),
                                    atol=3e-4, rtol=3e-4)
         for t in range(4, 8):
-            (logits, _), mut = dmodel.apply(
-                {"params": params, "cache": cache}, ids[:, t:t + 1],
-                mutable=["cache"])
+            (logits, _), mut = step(params, cache, ids[:, t:t + 1])
             cache = mut["cache"]
             np.testing.assert_allclose(np.asarray(logits[:, -1]),
                                        np.asarray(dense[:, t]),

@@ -53,18 +53,19 @@ def _decode_consistency(config, params, atol=3e-4):
     """Prefill + token-by-token decode reproduces the dense forward —
     exercises the rotate-before-cache rotary path."""
     model = GPT2LMHeadModel(config)
-    dense = np.asarray(model.apply({"params": params}, IDS))
+    dense = np.asarray(jax.jit(model.apply)({"params": params}, IDS))
     dmodel = GPT2LMHeadModel(config.for_decode())
-    vars0 = dmodel.init(jax.random.PRNGKey(0), IDS[:, :1])
+    vars0 = jax.jit(dmodel.init)(jax.random.PRNGKey(0), IDS[:, :1])
     cache = jax.tree_util.tree_map(jnp.zeros_like, vars0["cache"])
-    logits, mut = dmodel.apply({"params": params, "cache": cache},
-                               IDS[:, :4], mutable=["cache"])
+    # (one program a step shape: op by op a pass compiles some hundreds)
+    step = jax.jit(lambda p, cache, ids: dmodel.apply(
+        {"params": p, "cache": cache}, ids, mutable=["cache"]))
+    logits, mut = step(params, cache, IDS[:, :4])
     cache = mut["cache"]
     np.testing.assert_allclose(np.asarray(logits[:, -1]), dense[:, 3],
                                atol=atol, rtol=atol)
     for t in range(4, 8):
-        logits, mut = dmodel.apply({"params": params, "cache": cache},
-                                   IDS[:, t:t + 1], mutable=["cache"])
+        logits, mut = step(params, cache, IDS[:, t:t + 1])
         cache = mut["cache"]
         np.testing.assert_allclose(np.asarray(logits[:, -1]), dense[:, t],
                                    atol=atol, rtol=atol)

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeed_tpu
 from deepspeed_tpu.models import blocks, granite_hybrid
 from deepspeed_tpu.models.granite_hybrid import (GraniteAttention,
                                                  GraniteHybridConfig,
@@ -25,10 +24,8 @@ from deepspeed_tpu.models.granite_hybrid import (GraniteAttention,
 from deepspeed_tpu.ops import ssm_state_update
 from deepspeed_tpu.ops.ssd_chunk_scan import ssd_chunk_scan
 from deepspeed_tpu.ops.ssm_state_update import from_lanes, to_lanes
-from deepspeed_tpu.parallel.topology import reset_topology
-from deepspeed_tpu.serving import ServingEngine
 from perfbench import reference_granite_hybrid as reference
-from tests.unit.test_lfm2_moe import _paged_logits
+from tests.unit.served_family import REFUSED, Family, highest, prompts  # noqa: F401
 
 # float32 program against the float32 reference, on logits of order 0.2:
 # what another order of summation leaves (the two agree to 3e-8 here)
@@ -49,40 +46,22 @@ def shape_of(cfg: GraniteHybridConfig) -> dict:
                 logits_scaling=cfg.logits_scaling)
 
 
-def make(dtype=jnp.float32, seed=0, **kw):
-    cfg = GraniteHybridConfig.tiny(dtype=dtype, **kw)
-    module = GraniteHybridForCausalLM(cfg)
-    params = module.init(jax.random.PRNGKey(seed),
-                         jnp.zeros((1, 8), jnp.int32))["params"]
-    return cfg, module, params
-
-
-_REFERENCE = {}
-
-
-def reference_logits(cfg, params, ids):
-    """The reference over ``ids`` padded on the right to a whole 64 (causal:
-    unseen), compiled once a config and shape."""
-    ids = np.asarray(ids)
-    rows, t = ids.shape
-    wide = np.zeros((rows, -(-t // 64) * 64), ids.dtype)
-    wide[:, :t] = ids
-    fn = _REFERENCE.setdefault(cfg, jax.jit(
-        lambda p, i: reference.logits(p, i, shape_of(cfg))))
-    return np.asarray(fn(params, jnp.asarray(wide)))[:, :t]
-
-
-def _prompts(cfg, lengths, seed=5):
-    rng = np.random.default_rng(seed)
-    return [rng.integers(0, cfg.vocab_size, n).tolist() for n in lengths]
+# prompts in chunks of 8 = two scan chunks of 4
+FAMILY = Family(GraniteHybridConfig, GraniteHybridForCausalLM, reference,
+                shape_of, TOL,
+                serving={"decode_slots": 3, "block_size": BLOCK,
+                         "max_model_len": 64, "prefill_chunk_tokens": 8})
+engines = FAMILY.engines()
+make, reference_logits = FAMILY.make, FAMILY.reference_logits
 
 
 @pytest.fixture
-def highest():
-    # the CPU multiplies float32 exactly; the setting is the chip's, kept so
-    # that the test says what it compares
-    with jax.default_matmul_precision("highest"):
-        yield
+def served():
+    """``(cfg, params, engine)``: the shared engine, for the tests that
+    drive its paged module, pools and tables themselves or serve through
+    it."""
+    cfg, _, params = make()
+    return cfg, params, FAMILY.shared_engine(params, cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -91,7 +70,7 @@ def highest():
 def test_full_forward_matches_the_reference(highest):
     cfg, module, params = make()
     ids = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 37))
-    got = np.asarray(module.apply({"params": params}, jnp.asarray(ids)))
+    got = np.asarray(FAMILY.plain(cfg)(params, jnp.asarray(ids)))
     assert np.abs(got - reference_logits(cfg, params, ids)).max() <= TOL
     # tied: no head of its own; the Mamba layer's leaves
     assert "lm_head" not in params and "layers_2_attn" in params
@@ -141,8 +120,7 @@ def test_each_multiplier_is_in_the_program_and_the_reference(highest, field):
     ids = np.random.default_rng(1).integers(0, cfg.vocab_size, (1, 19))
     base = reference_logits(cfg, params, ids)
     moved = dataclasses.replace(cfg, **{field: 3.0 * getattr(cfg, field)})
-    got = np.asarray(GraniteHybridForCausalLM(moved).apply(
-        {"params": params}, jnp.asarray(ids)))
+    got = np.asarray(FAMILY.plain(moved)(params, jnp.asarray(ids)))
     want = reference_logits(moved, params, ids)
     assert np.abs(got - want).max() <= TOL
     assert np.abs(want - base).max() > 100 * TOL
@@ -179,9 +157,8 @@ def test_a_config_without_the_scalars_traces_no_multiply():
 def test_bf16_fails_the_float32_tolerance():
     cfg, _, params = make()
     ids = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 37))
-    low = GraniteHybridForCausalLM(dataclasses.replace(cfg,
-                                                       dtype=jnp.bfloat16))
-    got = np.asarray(low.apply({"params": params}, jnp.asarray(ids)))
+    low = FAMILY.plain(dataclasses.replace(cfg, dtype=jnp.bfloat16))
+    got = np.asarray(low(params, jnp.asarray(ids)))
     assert np.abs(got - reference_logits(cfg, params, ids)).max() > 100 * TOL
 
 
@@ -465,27 +442,6 @@ def test_the_kernels_work_list_puts_the_busy_rows_first():
 # ---------------------------------------------------------------------------
 # through the paged cache and the per-slot state
 # ---------------------------------------------------------------------------
-def serving_engine(params, cfg, **serving):
-    reset_topology()
-    block = {"decode_slots": 3, "block_size": BLOCK, "max_model_len": 64,
-             "prefill_chunk_tokens": 8, **serving}
-    return ServingEngine(deepspeed_tpu.init_inference(
-        GraniteHybridForCausalLM(cfg), params=params, dtype=cfg.dtype,
-        serving=block))
-
-
-@pytest.fixture(scope="module")
-def served():
-    """``(cfg, params, engine)``: one engine for the tests that drive its
-    paged module, pools and tables themselves (each traces its own
-    programs) or serve through it; prompts in chunks of 8 = two scan
-    chunks of 4."""
-    cfg, _, params = make()
-    srv = serving_engine(params, cfg)
-    yield cfg, params, srv
-    srv.destroy()
-
-
 @pytest.mark.parametrize("chunk", [0, 8], ids=["whole-prompt", "chunked"])
 def test_paged_logits_match_the_reference(highest, served, chunk):
     """Prefill then decode through the cache and the state against the
@@ -494,19 +450,17 @@ def test_paged_logits_match_the_reference(highest, served, chunk):
     padding's end), or in chunks of 8 (each past the first starts from the
     stored state, the last holds 3 real positions)."""
     cfg, params, srv = served
-    got, tokens = _paged_logits(srv, _prompts(cfg, [27])[0], 14, chunk=chunk)
-    want = reference_logits(cfg, params, np.asarray([tokens]))[0]
-    assert np.abs(got - want[:len(got)]).max() <= TOL
+    assert FAMILY.paged_logits_match(srv, cfg, params, prompts(cfg, [27])[0],
+                                     14, chunk=chunk) <= TOL
 
 
 def test_a_slots_second_tenant_does_not_see_the_firsts_state(highest, served):
     """Requests one after the other in ONE slot, the second shorter than
     the first: each is the reference's."""
     cfg, params, srv = served
-    for prompt in _prompts(cfg, [30, 7]):
-        got, tokens = _paged_logits(srv, prompt, 5, slot=2, chunk=8)
-        want = reference_logits(cfg, params, np.asarray([tokens]))[0]
-        assert np.abs(got - want[:len(got)]).max() <= TOL, len(prompt)
+    for prompt in prompts(cfg, [30, 7]):
+        assert FAMILY.paged_logits_match(srv, cfg, params, prompt, 5, slot=2,
+                                         chunk=8) <= TOL, len(prompt)
 
 
 def not_carried(pool, index, rows, fresh):
@@ -525,12 +479,10 @@ def test_a_wrong_state_moves_the_logits(highest, monkeypatch, served,
                                         control):
     cfg, params, srv = served
     monkeypatch.setattr(granite_hybrid, "state_in", control)
-    worst = 0.0
-    for prompt in _prompts(cfg, [30, 7]):
-        got, tokens = _paged_logits(srv, prompt, 3, slot=2, chunk=8)
-        want = reference_logits(cfg, params, np.asarray([tokens]))[0]
-        worst = max(worst, np.abs(got - want[:len(got)]).max())
-    assert worst > 1000 * TOL
+    # (the paged module traced anew under the patch)
+    assert max(FAMILY.paged_logits_match(srv, cfg, params, prompt, 3, slot=2,
+                                         chunk=8, retrace=True)
+               for prompt in prompts(cfg, [30, 7])) > 1000 * TOL
 
 
 def test_prefill_chunks_and_decode_through_the_engine(highest, served):
@@ -539,20 +491,10 @@ def test_prefill_chunks_and_decode_through_the_engine(highest, served):
     ``ServingEngine``: every served token the reference's argmax at its
     position, on the reference's logits over prompt + served tokens (a tie
     inside TOL aside); and the engine's counters."""
-    cfg, params, srv = served
-    prompts = _prompts(cfg, [5, 19, 33, 9, 26])
-    news = [30, 12, 20, 25, 8]
-    srv.reset_stats()
-    reqs = [srv.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
-    srv.drain()
-    stats = srv.stats()
-    for req, prompt, n in zip(reqs, prompts, news):
-        assert len(req.tokens) == n, (req.state, req.finish_reason)
-        want = reference_logits(cfg, params,
-                                np.asarray([list(prompt) + req.tokens]))[0]
-        for k, tok in enumerate(req.tokens):
-            row = want[len(prompt) - 1 + k]
-            assert row.max() - row[tok] <= TOL, (k, tok, row.argmax())
+    cfg, params, _ = served
+    stats, reqs = FAMILY.served_logits_match(
+        cfg, params, list(zip(prompts(cfg, [5, 19, 33, 9, 26]),
+                              [30, 12, 20, 25, 8])))
     assert max(r.prefill_chunks for r in reqs) == 5
     assert len({r.slot for r in reqs}) == 3
     kv = stats["kv_live_bytes"]
@@ -573,7 +515,7 @@ def test_the_state_pools_do_not_grow_with_the_context(highest):
     cfg, _, params = make()
     sizes = {}
     for longest in (32, 64):
-        srv = serving_engine(params, cfg, max_model_len=longest)
+        srv = FAMILY.serving_engine(params, cfg, max_model_len=longest)
         sizes[longest] = {k: v.shape for k, v in srv.cache.items()}
         entries = srv.slot_entries
         table = srv._slot_table(2, np.arange(3))
@@ -593,28 +535,13 @@ def test_decode_through_both_kernels_matches_the_xla_paths(monkeypatch):
     the state update on the pool in place (a state size of 128 lanes) and
     the paged GQA kernel over the block table, beside idle slots, against
     the same steps on the XLA paths."""
-    from deepspeed_tpu.ops import attention as ops_attention
-    from deepspeed_tpu.utils.compat import tpu_interpret_mode
-
     cfg, _, params = make(mamba_d_state=128)
-    prompt = _prompts(cfg, [19])[0]
-    plain = serving_engine(params, cfg)
-    want, _ = _paged_logits(plain, prompt, 3, chunk=8)
-    plain.destroy()
-    monkeypatch.setattr(ops_attention, "use_decode_kernel", lambda: True)
-    srv = serving_engine(params, cfg)
-    try:
-        one = jax.devices()[0]
-        srv.engine.params, srv.cache = jax.device_put(
-            (srv.engine.params, srv.cache), one)
-        with tpu_interpret_mode():
-            got, _ = _paged_logits(srv, prompt, 3, chunk=8)
-        paths = srv.stats()["attention_paths"]
-        assert paths.get("granite_ssm_decode_kernel") and paths.get(
-            "granite_attn_decode_kernel")
-        assert np.abs(got - want).max() <= TOL
-    finally:
-        srv.destroy()
+    got, want, paths = FAMILY.decode_through_the_kernels(
+        monkeypatch, cfg, params, prompts(cfg, [19])[0], 3, chunk=8,
+        experts=False)
+    assert paths.get("granite_ssm_decode_kernel") and paths.get(
+        "granite_attn_decode_kernel")
+    assert np.abs(got - want).max() <= TOL
 
 
 def test_a_chunks_attention_in_tiles_of_keys_is_the_whole_tables(highest):
@@ -677,52 +604,25 @@ def test_the_family_is_a_client_of_the_shared_blocks():
 # ---------------------------------------------------------------------------
 # refusals, by name
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("serving, mechanism", [
-    ({"prefix_cache": True}, "serving.prefix_cache"),
-    ({"speculative": {"num_speculative_tokens": 2}}, "serving.speculative"),
-    ({"kv_cache_dtype": "int8"}, "serving.kv_cache_dtype"),
-], ids=["prefix-cache", "speculation", "int8-kv"])
+@REFUSED
 def test_mechanisms_that_know_block_tables_only_refuse_the_model(serving,
                                                                  mechanism):
-    cfg, _, params = make()
-    with pytest.raises(Exception, match=mechanism.replace(".", r"\.")) as e:
-        serving_engine(params, cfg, **serving)
-    assert "GraniteHybridForCausalLM" in str(e.value)
-    assert "a matrix a head" in str(e.value)
+    assert "a matrix a head" in FAMILY.mechanism_refusal(serving, mechanism)
 
 
 def test_tensor_parallel_refuses_the_model():
-    cfg, _, params = make()
-    reset_topology()
-    with pytest.raises(Exception, match="tp_size > 1") as e:
-        ServingEngine(deepspeed_tpu.init_inference(
-            GraniteHybridForCausalLM(cfg), params=params, dtype=cfg.dtype,
-            tensor_parallel={"tp_size": 2},
-            serving={"decode_slots": 2, "block_size": BLOCK,
-                     "max_model_len": 32}))
-    assert "state-space layers keep a state" in str(e.value)
-    reset_topology()
+    assert "state-space layers keep a state" in (
+        FAMILY.tensor_parallel_refusal())
 
 
 def test_migration_refuses_the_model():
-    cfg, _, params = make()
-    srv = serving_engine(params, cfg)
-    try:
-        req = srv.submit([1, 2, 3, 4, 5], max_new_tokens=8)
-        srv.step()
-        for call in (lambda: srv.export_sequence(req.request_id),
-                     lambda: srv.import_sequence({"request_id": "x"})):
-            with pytest.raises(NotImplementedError, match="migration") as e:
-                call()
-            assert "state-space" in str(e.value)
-    finally:
-        srv.destroy()
+    assert all("state-space" in said for said in FAMILY.migration_refusals())
 
 
 def test_routed_sets_are_refused_there_are_none():
     cfg, _, params = make()
     with pytest.raises(Exception, match="routed_experts_kept"):
-        serving_engine(params, cfg, routed_experts_kept=4)
+        FAMILY.serving_engine(params, cfg, routed_experts_kept=4)
 
 
 def test_the_config_refuses_what_the_family_does_not_implement():

@@ -24,6 +24,7 @@ from deepspeed_tpu.moe import dropless
 from deepspeed_tpu.parallel.topology import reset_topology
 from deepspeed_tpu.serving import ServingEngine
 from perfbench import reference_lfm2_moe as reference
+from tests.unit.served_family import REFUSED, Family, highest, prompts  # noqa: F401
 
 # float32 program against the float32 reference, on logits of order 1: what
 # another order of summation leaves (the two agree to 4e-7 here)
@@ -42,30 +43,11 @@ def shape_of(cfg: Lfm2MoeConfig) -> dict:
                 types=cfg.layer_types, dense=cfg.num_dense_layers)
 
 
-def make(dtype=jnp.float32, seed=0, **kw):
-    cfg = Lfm2MoeConfig.tiny(dtype=dtype, **kw)
-    module = Lfm2MoeForCausalLM(cfg)
-    params = module.init(jax.random.PRNGKey(seed),
-                         jnp.zeros((1, 8), jnp.int32))["params"]
-    return cfg, module, params
-
-
-def reference_logits(cfg, params, ids):
-    return np.asarray(reference.logits(params, jnp.asarray(ids),
-                                       shape_of(cfg)))
-
-
-def _prompts(cfg, lengths, seed=5):
-    rng = np.random.default_rng(seed)
-    return [rng.integers(0, cfg.vocab_size, n).tolist() for n in lengths]
-
-
-@pytest.fixture
-def highest():
-    # the CPU multiplies float32 exactly; the setting is the chip's, kept so
-    # that the test says what it compares
-    with jax.default_matmul_precision("highest"):
-        yield
+FAMILY = Family(Lfm2MoeConfig, Lfm2MoeForCausalLM, reference, shape_of, TOL,
+                serving={"decode_slots": 3, "block_size": BLOCK,
+                         "max_model_len": 64})
+engines = FAMILY.engines()
+make, reference_logits = FAMILY.make, FAMILY.reference_logits
 
 
 # ---------------------------------------------------------------------------
@@ -74,7 +56,7 @@ def highest():
 def test_full_forward_matches_the_reference(highest):
     cfg, module, params = make()
     ids = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 40))
-    got = np.asarray(module.apply({"params": params}, jnp.asarray(ids)))
+    got = np.asarray(FAMILY.plain(cfg)(params, jnp.asarray(ids)))
     assert np.abs(got - reference_logits(cfg, params, ids)).max() <= TOL
     # tied: no head of its own
     assert "lm_head" not in params and "layers_2_attn" in params
@@ -116,13 +98,13 @@ def test_every_operator_moves_the_logits(highest):
     cfg, module, params = make()
     ids = jnp.asarray(np.random.default_rng(1).integers(
         0, cfg.vocab_size, (1, 24)))
-    base = np.asarray(module.apply({"params": params}, ids))
+    base = np.asarray(FAMILY.plain(cfg)(params, ids))
     for layer, leaf in (("layers_3_conv", "conv"), ("layers_2_attn", "v_proj"),
                         ("layers_4_mlp", "down")):
         moved = dict(params)
         moved[layer] = {**params[layer], leaf: jax.tree_util.tree_map(
             jnp.zeros_like, params[layer][leaf])}
-        got = np.asarray(module.apply({"params": moved}, ids))
+        got = np.asarray(FAMILY.plain(cfg)(moved, ids))
         assert np.abs(got - base).max() > 5 * TOL, layer
 
 
@@ -132,8 +114,8 @@ def test_bf16_fails_the_float32_tolerance():
     apart."""
     cfg, _, params = make()
     ids = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 40))
-    low = Lfm2MoeForCausalLM(dataclasses.replace(cfg, dtype=jnp.bfloat16))
-    got = np.asarray(low.apply({"params": params}, jnp.asarray(ids)))
+    low = FAMILY.plain(dataclasses.replace(cfg, dtype=jnp.bfloat16))
+    got = np.asarray(low(params, jnp.asarray(ids)))
     assert np.abs(got - reference_logits(cfg, params, ids)).max() > 10 * TOL
 
 
@@ -236,63 +218,6 @@ def test_an_experts_width_is_tiled_in_whole_registers(width, tile):
 # ---------------------------------------------------------------------------
 # through the paged cache and the per-slot state
 # ---------------------------------------------------------------------------
-def serving_engine(params, cfg, **serving):
-    reset_topology()
-    block = {"decode_slots": 3, "block_size": BLOCK, "max_model_len": 64,
-             **serving}
-    return ServingEngine(deepspeed_tpu.init_inference(
-        Lfm2MoeForCausalLM(cfg), params=params, dtype=cfg.dtype,
-        serving=block))
-
-
-def _paged_logits(srv, prompt, steps, slot=1, chunk=0):
-    """Drive the engine's own paged module with its own pools and tables,
-    as its programs do, and keep the LOGITS: every prompt position (the
-    whole prompt right-padded into a bucket it does NOT fill, or chunks of
-    ``chunk``), then ``steps`` greedy decode steps in the decode program's
-    batch shape, the other slots idle. -> (logits [positions, vocab],
-    ids)."""
-    dm, params = srv._dmodule, srv.engine.params
-
-    def call(prefill):
-        def fn(cache, ids, tables, lengths, num_valid):
-            out, v = dm.apply(
-                {"params": params, "cache": cache}, ids, mutable=["cache"],
-                paging={"block_tables": tables, "lengths": lengths,
-                        "num_valid": num_valid, "prefill": prefill})
-            return out[0], v["cache"]
-        return jax.jit(fn)
-
-    whole, cached = call(True), call(False)
-    rid = f"direct-{slot}-{len(prompt)}"
-    table = srv._slot_table(slot, srv.block_mgr.allocate(
-        rid, len(prompt) + steps))
-    i32 = lambda x: jnp.asarray(x, jnp.int32)
-    rows, n = [], len(prompt)
-    for at in range(0, n, chunk or n):
-        m = min(chunk or n, n - at)
-        width = chunk or (-(-n // 8) * 8 + 8)     # never filled
-        ids = np.zeros((1, width), np.int32)
-        ids[0, :m] = prompt[at:at + m]
-        lg, srv.cache = (cached if chunk else whole)(
-            srv.cache, i32(ids), i32(table[None]), i32([at]), i32([m]))
-        rows.append(np.asarray(lg[0, :m]))
-    slots = srv.config.decode_slots
-    tables = np.zeros((slots, len(table)), np.int32)
-    tables[slot] = table
-    tokens = list(prompt)
-    for _ in range(steps):
-        tokens.append(int(rows[-1][-1].argmax()))
-        lengths, last = np.zeros(slots, np.int32), np.zeros((slots, 1),
-                                                            np.int32)
-        lengths[slot], last[slot] = len(tokens) - 1, tokens[-1]
-        lg, srv.cache = cached(srv.cache, i32(last), i32(tables),
-                               i32(lengths), jnp.ones(slots, jnp.int32))
-        rows.append(np.asarray(lg[slot]))
-    srv.block_mgr.release(rid)
-    return np.concatenate(rows), tokens
-
-
 @pytest.mark.parametrize("chunk", [0, 8], ids=["whole-prompt", "chunked"])
 def test_paged_logits_match_the_reference(highest, chunk):
     """Prefill then decode through the cache and the state against the
@@ -301,29 +226,20 @@ def test_paged_logits_match_the_reference(highest, chunk):
     or in chunks of 8 (each past the first starts from the stored state,
     the last holds 3 real positions)."""
     cfg, _, params = make()
-    srv = serving_engine(params, cfg)
-    try:
-        got, tokens = _paged_logits(srv, _prompts(cfg, [27])[0], 14,
-                                    chunk=chunk)
-        want = reference_logits(cfg, params, np.asarray([tokens]))[0]
-        assert np.abs(got - want[:len(got)]).max() <= TOL
-    finally:
-        srv.destroy()
+    assert FAMILY.paged_logits_match(
+        FAMILY.shared_engine(params, cfg), cfg, params,
+        prompts(cfg, [27])[0], 14, chunk=chunk) <= TOL
 
 
 def test_a_slots_next_request_does_not_see_its_last_ones_state(highest):
     """Two requests one after the other in ONE slot, the second shorter
     than the first, whole-prompt and chunked: each is the reference's."""
     cfg, _, params = make()
-    srv = serving_engine(params, cfg)
-    try:
-        first, second, third = _prompts(cfg, [30, 7, 11])
-        for prompt, chunk in ((first, 0), (second, 0), (third, 8)):
-            got, tokens = _paged_logits(srv, prompt, 5, slot=2, chunk=chunk)
-            want = reference_logits(cfg, params, np.asarray([tokens]))[0]
-            assert np.abs(got - want[:len(got)]).max() <= TOL, len(prompt)
-    finally:
-        srv.destroy()
+    srv = FAMILY.shared_engine(params, cfg)
+    first, second, third = prompts(cfg, [30, 7, 11])
+    for prompt, chunk in ((first, 0), (second, 0), (third, 8)):
+        assert FAMILY.paged_logits_match(srv, cfg, params, prompt, 5, slot=2,
+                                         chunk=chunk) <= TOL, len(prompt)
 
 
 @pytest.mark.parametrize("control", ["stale", "bucket-end"])
@@ -342,37 +258,12 @@ def test_a_wrong_state_moves_the_logits(highest, monkeypatch, control):
             lfm2_moe, "short_conv",
             lambda z, taps, state, num_valid: plain(
                 z, taps, state, jnp.full_like(num_valid, z.shape[1])))
-    srv = serving_engine(params, cfg)
-    try:
-        first, second = _prompts(cfg, [30, 7])
-        _paged_logits(srv, first, 5, slot=2)
-        got, tokens = _paged_logits(srv, second, 5, slot=2)
-        want = reference_logits(cfg, params, np.asarray([tokens]))[0]
-        assert np.abs(got - want[:len(got)]).max() > 100 * TOL
-    finally:
-        srv.destroy()
-
-
-def served_logits_match(cfg, params, requests, **serving):
-    """Serve ``requests`` [(prompt, new tokens)] greedily through
-    ``ServingEngine``; every served token has to be the reference's argmax
-    at its position, on the reference's logits over prompt + served
-    tokens (a tie inside TOL aside)."""
-    srv = serving_engine(params, cfg, **serving)
-    try:
-        reqs = [srv.submit(p, max_new_tokens=n) for p, n in requests]
-        srv.drain()
-        stats = srv.stats()
-        for req, (prompt, n) in zip(reqs, requests):
-            assert len(req.tokens) == n, (req.state, req.finish_reason)
-            ids = np.asarray([list(prompt) + req.tokens])
-            want = reference_logits(cfg, params, ids)[0]
-            for k, tok in enumerate(req.tokens):
-                row = want[len(prompt) - 1 + k]
-                assert row.max() - row[tok] <= TOL, (k, tok, row.argmax())
-        return stats, reqs
-    finally:
-        srv.destroy()
+    # the shared engine, its paged module traced anew under the patch
+    srv = FAMILY.shared_engine(params, cfg)
+    first, second = prompts(cfg, [30, 7])
+    FAMILY.paged_logits(srv, first, 5, slot=2, retrace=True)
+    assert FAMILY.paged_logits_match(srv, cfg, params, second, 5, slot=2,
+                                     retrace=True) > 100 * TOL
 
 
 def test_prefill_and_decode_through_the_engine(highest):
@@ -381,9 +272,9 @@ def test_prefill_and_decode_through_the_engine(highest):
     requests over 3 slots), through ``init_inference`` ->
     ``ServingEngine``; and the engine's counters."""
     cfg, _, params = make()
-    prompts = _prompts(cfg, [5, 19, 33, 9, 26])
-    stats, _ = served_logits_match(
-        cfg, params, list(zip(prompts, [30, 12, 20, 25, 8])))
+    asked = prompts(cfg, [5, 19, 33, 9, 26])
+    stats, _ = FAMILY.served_logits_match(
+        cfg, params, list(zip(asked, [30, 12, 20, 25, 8])))
     counted = stats["model_counters"]
     sparse, k = cfg.sparse_layers, cfg.num_experts_per_tok
     assert counted["decode"]["pairs_all"] == (
@@ -391,7 +282,7 @@ def test_prefill_and_decode_through_the_engine(highest):
     # every expert held: every pair routed here
     assert counted["decode"]["pairs_here"] == counted["decode"]["pairs_all"]
     assert counted["prefill"]["pairs_all"] == (
-        sum(map(len, prompts)) * sparse * k)
+        sum(map(len, asked)) * sparse * k)
     kv = stats["kv_live_bytes"]
     # host arithmetic at each step boundary: busy slots x the state's bytes
     assert kv["state"] == stats["busy_slot_steps"] * cfg.state_bytes_per_slot()
@@ -404,10 +295,10 @@ def test_prefill_and_decode_through_the_engine(highest):
 
 def test_a_prompt_chunked_and_unchunked_serves_the_same_tokens(highest):
     cfg, _, params = make()
-    requests = list(zip(_prompts(cfg, [37, 6, 21]), [14, 14, 9]))
-    _, whole = served_logits_match(cfg, params, requests)
-    stats, chunked = served_logits_match(cfg, params, requests,
-                                         prefill_chunk_tokens=8)
+    requests = list(zip(prompts(cfg, [37, 6, 21]), [14, 14, 9]))
+    _, whole = FAMILY.served_logits_match(cfg, params, requests)
+    stats, chunked = FAMILY.served_logits_match(cfg, params, requests,
+                                                prefill_chunk_tokens=8)
     assert [r.tokens for r in whole] == [r.tokens for r in chunked]
     assert stats["attention_paths"].get("lfm2_conv_chunk")
     assert max(r.prefill_chunks for r in chunked) == 5
@@ -417,9 +308,9 @@ def test_two_requests_one_after_the_other_on_one_slot(highest):
     """One decode slot: the second request, shorter than the first, is
     spliced into the slot the first left."""
     cfg, _, params = make()
-    long, short = _prompts(cfg, [29, 6])
-    _, reqs = served_logits_match(cfg, params, [(long, 9), (short, 12)],
-                                  decode_slots=1)
+    long, short = prompts(cfg, [29, 6])
+    _, reqs = FAMILY.served_logits_match(
+        cfg, params, [(long, 9), (short, 12)], decode_slots=1)
     assert reqs[0].slot == reqs[1].slot == 0
 
 
@@ -428,8 +319,8 @@ def test_sixty_four_decode_steps_beside_idle_slots(highest):
     them idle (their rows read and write state row 0 and route nowhere);
     a second joins and leaves meanwhile."""
     cfg, _, params = make()
-    a, b = _prompts(cfg, [9, 5])
-    stats, _ = served_logits_match(
+    a, b = prompts(cfg, [9, 5])
+    stats, _ = FAMILY.served_logits_match(
         cfg, params, [(a, 65), (b, 7)], decode_slots=4, max_model_len=96)
     assert stats["decode_steps"] >= 64
     assert stats["busy_slot_steps"] < 2 * stats["decode_steps"]
@@ -439,7 +330,7 @@ def test_the_state_pool_does_not_grow_with_the_context(highest):
     cfg, _, params = make()
     sizes = {}
     for longest in (32, 64):
-        srv = serving_engine(params, cfg, max_model_len=longest)
+        srv = FAMILY.serving_engine(params, cfg, max_model_len=longest)
         sizes[longest] = {k: v.shape for k, v in srv.cache.items()}
         entries = srv.slot_entries
         table = srv._slot_table(2, np.arange(3))
@@ -454,23 +345,12 @@ def test_the_state_pool_does_not_grow_with_the_context(highest):
 
 def test_the_engine_hands_back_the_routed_sets(highest):
     cfg, _, params = make()
-    prompts = _prompts(cfg, [21, 6])
-    srv = serving_engine(params, cfg, routed_experts_kept=4)
-    try:
-        reqs = [srv.submit(p, max_new_tokens=n)
-                for p, n in zip(prompts, [9, 12])]
-        srv.drain()
-        sparse, k = cfg.sparse_layers, cfg.num_experts_per_tok
-        for req, prompt in zip(reqs, prompts):
-            got = srv.routed_experts(req.request_id)
-            ids = np.asarray([list(prompt) + req.tokens[:-1]])
-            assert got.shape == (ids.shape[1], sparse * k)
-            want = np.asarray(reference.routed_sets(
-                params, jnp.asarray(ids), shape_of(cfg)))[:, 0]
-            got = got.reshape(-1, sparse, k).transpose(1, 0, 2)
-            assert (np.sort(got, -1) == np.sort(want, -1)).all()
-    finally:
-        srv.destroy()
+    asked = prompts(cfg, [21, 6])
+    srv = FAMILY.shared_engine(params, cfg, routed_experts_kept=4)
+    reqs = [srv.submit(p, max_new_tokens=n) for p, n in zip(asked, [9, 12])]
+    srv.drain()
+    for req, prompt in zip(reqs, asked):
+        FAMILY.routed_sets_are_the_references(srv, cfg, params, req, prompt)
 
 
 def test_decode_through_both_kernels_matches_the_xla_paths(monkeypatch):
@@ -478,76 +358,31 @@ def test_decode_through_both_kernels_matches_the_xla_paths(monkeypatch):
     the paged GQA kernel over the block table (its global kind, keys and
     values both 64... here 8 wide) and the grouped expert matmul, against
     the same steps on the XLA paths."""
-    from deepspeed_tpu.ops import attention as ops_attention
-    from deepspeed_tpu.utils.compat import tpu_interpret_mode
-
     cfg, _, params = make()
-    prompt = _prompts(cfg, [19])[0]
-    plain = serving_engine(params, cfg)
-    want, _ = _paged_logits(plain, prompt, 3)
-    plain.destroy()
-    monkeypatch.setattr(ops_attention, "use_decode_kernel", lambda: True)
-    ffn = dropless.expert_ffn
-    monkeypatch.setattr(dropless, "expert_ffn", lambda *a, **k: ffn(
-        *a, **{**k, "use_kernel": True}))
-    srv = serving_engine(params, cfg)
-    try:
-        one = jax.devices()[0]
-        srv.engine.params, srv.cache = jax.device_put(
-            (srv.engine.params, srv.cache), one)
-        with tpu_interpret_mode():
-            got, _ = _paged_logits(srv, prompt, 3)
-        paths = srv.stats()["attention_paths"]
-        assert paths.get("lfm2_attn_decode_kernel") and paths.get(
-            "moe_experts_grouped_kernel")
-        assert np.abs(got - want).max() <= TOL
-    finally:
-        srv.destroy()
+    got, want, paths = FAMILY.decode_through_the_kernels(
+        monkeypatch, cfg, params, prompts(cfg, [19])[0], 3)
+    assert paths.get("lfm2_attn_decode_kernel") and paths.get(
+        "moe_experts_grouped_kernel")
+    assert np.abs(got - want).max() <= TOL
 
 
 # ---------------------------------------------------------------------------
 # refusals, by name
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("serving, mechanism", [
-    ({"prefix_cache": True}, "serving.prefix_cache"),
-    ({"speculative": {"num_speculative_tokens": 2}}, "serving.speculative"),
-    ({"kv_cache_dtype": "int8"}, "serving.kv_cache_dtype"),
-], ids=["prefix-cache", "speculation", "int8-kv"])
+@REFUSED
 def test_mechanisms_that_know_block_tables_only_refuse_the_model(serving,
                                                                  mechanism):
-    cfg, _, params = make()
-    with pytest.raises(Exception, match=mechanism.replace(".", r"\.")) as e:
-        serving_engine(params, cfg, **serving)
-    assert "Lfm2MoeForCausalLM" in str(e.value)
-    assert "short-convolution layers keep a state" in str(e.value)
+    assert "short-convolution layers keep a state" in (
+        FAMILY.mechanism_refusal(serving, mechanism))
 
 
 def test_tensor_parallel_refuses_the_model():
-    cfg, _, params = make()
-    reset_topology()
-    with pytest.raises(Exception, match="tp_size > 1") as e:
-        ServingEngine(deepspeed_tpu.init_inference(
-            Lfm2MoeForCausalLM(cfg), params=params, dtype=cfg.dtype,
-            tensor_parallel={"tp_size": 2},
-            serving={"decode_slots": 2, "block_size": BLOCK,
-                     "max_model_len": 32}))
-    assert "Lfm2MoeForCausalLM" in str(e.value)
-    reset_topology()
+    assert "Lfm2MoeForCausalLM" in FAMILY.tensor_parallel_refusal()
 
 
 def test_migration_refuses_the_model():
-    cfg, _, params = make()
-    srv = serving_engine(params, cfg)
-    try:
-        req = srv.submit([1, 2, 3, 4, 5], max_new_tokens=8)
-        srv.step()
-        for call in (lambda: srv.export_sequence(req.request_id),
-                     lambda: srv.import_sequence({"request_id": "x"})):
-            with pytest.raises(NotImplementedError, match="migration") as e:
-                call()
-            assert "short-convolution" in str(e.value)
-    finally:
-        srv.destroy()
+    assert all("short-convolution" in said
+               for said in FAMILY.migration_refusals())
 
 
 def test_the_other_familys_refusal_names_its_ring():
@@ -557,8 +392,8 @@ def test_the_other_familys_refusal_names_its_ring():
 
     cfg = MiMoV2Config.tiny(dtype=jnp.float32)
     module = MiMoV2ForCausalLM(cfg)
-    params = module.init(jax.random.PRNGKey(0),
-                         jnp.zeros((1, 8), jnp.int32))["params"]
+    params = jax.jit(module.init)(jax.random.PRNGKey(0),
+                                  jnp.zeros((1, 8), jnp.int32))["params"]
     reset_topology()
     with pytest.raises(Exception, match=r"serving\.prefix_cache") as e:
         ServingEngine(deepspeed_tpu.init_inference(

@@ -49,7 +49,7 @@ class TestHFParity:
         assert config.kv_heads == kv_heads
         model = LlamaModel(config)
         ids = np.array([[3, 17, 42, 99, 7, 23, 56, 1]], np.int32)
-        ours = np.asarray(model.apply({"params": params}, ids))
+        ours = np.asarray(jax.jit(model.apply)({"params": params}, ids))
         with torch.no_grad():
             theirs = hf(torch.tensor(ids, dtype=torch.long)).logits.numpy()
         np.testing.assert_allclose(ours, theirs, atol=3e-4, rtol=3e-4)
@@ -70,7 +70,7 @@ class TestHFParity:
                 max_position_embeddings=32)
             ids = np.array([[1, 2, 3, 4]], np.int32)
             out.append(np.asarray(
-                LlamaModel(config).apply({"params": params}, ids)))
+                jax.jit(LlamaModel(config).apply)({"params": params}, ids)))
         np.testing.assert_allclose(out[0], out[1], atol=1e-5)
 
 
@@ -120,24 +120,25 @@ class TestDecode:
                                scan_layers=True)
         model = LlamaModel(cfg)
         ids = np.array([[5, 9, 2, 7, 3, 8]], np.int32)
-        params = model.init(jax.random.PRNGKey(0), ids)["params"]
-        dense = np.asarray(model.apply({"params": params}, ids))
+        params = jax.jit(model.init)(jax.random.PRNGKey(0), ids)["params"]
+        dense = np.asarray(jax.jit(model.apply)({"params": params}, ids))
 
         dcfg = cfg.for_decode()
         dmodel = LlamaModel(dcfg)
-        vars0 = dmodel.init(jax.random.PRNGKey(0), ids[:, :1])
+        vars0 = jax.jit(dmodel.init)(jax.random.PRNGKey(0), ids[:, :1])
+        # (one program a step shape: op by op a pass compiles some hundreds)
+        step = jax.jit(lambda p, cache, ids: dmodel.apply(
+            {"params": p, "cache": cache}, ids, mutable=["cache"]))
         # init runs a forward: reset the cache (index included) to zero
         cache = jax.tree_util.tree_map(jnp.zeros_like, vars0["cache"])
         # prefill on the first 3 tokens
-        logits, mut = dmodel.apply({"params": params, "cache": cache},
-                                   ids[:, :3], mutable=["cache"])
+        logits, mut = step(params, cache, ids[:, :3])
         cache = mut["cache"]
         np.testing.assert_allclose(np.asarray(logits[:, -1]),
                                    dense[:, 2], atol=2e-4, rtol=2e-4)
         # decode the rest one token at a time
         for t in range(3, 6):
-            logits, mut = dmodel.apply({"params": params, "cache": cache},
-                                       ids[:, t:t + 1], mutable=["cache"])
+            logits, mut = step(params, cache, ids[:, t:t + 1])
             cache = mut["cache"]
             np.testing.assert_allclose(np.asarray(logits[:, -1]),
                                        dense[:, t], atol=2e-4, rtol=2e-4)

@@ -19,6 +19,7 @@ from deepspeed_tpu.moe import dropless
 from deepspeed_tpu.parallel.topology import reset_topology
 from deepspeed_tpu.serving import ServingEngine
 from perfbench import reference_mimo_v2 as reference
+from tests.unit.served_family import REFUSED, Family, highest, prompts  # noqa: F401
 
 TOL = 1e-4   # float32 program against the float32 reference, on logits
 WINDOW, BLOCK = 8, 4
@@ -40,32 +41,18 @@ def shape_of(cfg: MiMoV2Config, first_expert=None) -> dict:
         pattern=cfg.hybrid_layer_pattern, moe=cfg.moe_layer_freq)
 
 
-def make(dtype=jnp.float32, seed=0, **kw):
-    cfg = MiMoV2Config.tiny(dtype=dtype, **kw)
-    module = MiMoV2ForCausalLM(cfg)
-    params = module.init(jax.random.PRNGKey(seed),
-                         jnp.zeros((1, 8), jnp.int32))["params"]
-    return cfg, module, params
-
-
-def reference_logits(cfg, params, ids):
-    return np.asarray(reference.logits(params, jnp.asarray(ids),
-                                       shape_of(cfg)))
-
-
-@pytest.fixture
-def highest():
-    # the CPU multiplies float32 exactly; the setting is the chip's, kept so
-    # that the test says what it compares
-    with jax.default_matmul_precision("highest"):
-        yield
+FAMILY = Family(MiMoV2Config, MiMoV2ForCausalLM, reference, shape_of, TOL,
+                serving={"decode_slots": 3, "block_size": BLOCK,
+                         "max_model_len": 64}, bucket_slack=0)
+engines = FAMILY.engines()
+make, reference_logits = FAMILY.make, FAMILY.reference_logits
 
 
 @pytest.mark.parametrize("sink", [True, False], ids=["sink", "no-sink"])
 def test_full_forward_matches_the_reference(highest, sink):
     cfg, module, params = make(add_swa_attention_sink_bias=sink)
     ids = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 40))
-    got = np.asarray(module.apply({"params": params}, jnp.asarray(ids)))
+    got = np.asarray(FAMILY.plain(cfg)(params, jnp.asarray(ids)))
     want = reference_logits(cfg, params, ids)
     assert np.abs(got - want).max() <= TOL
     has_sink = "sink" in params["layers_1_attn"]
@@ -80,8 +67,7 @@ def test_the_sink_moves_the_logits(highest):
         0, cfg.vocab_size, (1, 24)))
     moved = jax.tree_util.tree_map_with_path(
         lambda path, x: x + 2.0 if path[-1].key == "sink" else x, params)
-    a, b = (np.asarray(module.apply({"params": p}, ids))
-            for p in (params, moved))
+    a, b = (np.asarray(FAMILY.plain(cfg)(p, ids)) for p in (params, moved))
     assert np.abs(a - b).max() > 100 * TOL
 
 
@@ -91,8 +77,8 @@ def test_bf16_fails_the_float32_tolerance():
     apart."""
     cfg, module, params = make()
     ids = np.random.default_rng(1).integers(0, cfg.vocab_size, (2, 40))
-    low = MiMoV2ForCausalLM(dataclasses.replace(cfg, dtype=jnp.bfloat16))
-    got = np.asarray(low.apply({"params": params}, jnp.asarray(ids)))
+    low = FAMILY.plain(dataclasses.replace(cfg, dtype=jnp.bfloat16))
+    got = np.asarray(low(params, jnp.asarray(ids)))
     assert np.abs(got - reference_logits(cfg, params, ids)).max() > 10 * TOL
 
 
@@ -134,50 +120,14 @@ def test_the_shares_add_up_to_the_uncut_layer(highest):
 # ---------------------------------------------------------------------------
 # through the paged cache
 # ---------------------------------------------------------------------------
-def serving_engine(params, cfg, **serving):
-    reset_topology()
-    block = {"decode_slots": 3, "block_size": BLOCK, "max_model_len": 64,
-             **serving}
-    return ServingEngine(deepspeed_tpu.init_inference(
-        MiMoV2ForCausalLM(cfg), params=params, dtype=cfg.dtype,
-        serving=block))
-
-
-def served_logits_match(cfg, params, requests, **serving):
-    """Serve ``requests`` [(prompt, new tokens)] greedily; every served
-    token has to be the reference's argmax at its position (the tiny
-    model's logits are separated by far more than the tolerance), and the
-    reference's logits over prompt + served tokens reproduce the stream."""
-    srv = serving_engine(params, cfg, **serving)
-    try:
-        reqs = [srv.submit(p, max_new_tokens=n) for p, n in requests]
-        srv.drain()
-        stats = srv.stats()
-        for req, (prompt, n) in zip(reqs, requests):
-            assert len(req.tokens) == n, (req.state, req.finish_reason)
-            ids = np.asarray([list(prompt) + req.tokens])
-            want = reference_logits(cfg, params, ids)[0]
-            for k, tok in enumerate(req.tokens):
-                row = want[len(prompt) - 1 + k]
-                assert row.max() - row[tok] <= TOL, (k, tok, row.argmax())
-        return stats
-    finally:
-        srv.destroy()
-
-
-def _prompts(cfg, lengths, seed=5):
-    rng = np.random.default_rng(seed)
-    return [rng.integers(0, cfg.vocab_size, n).tolist() for n in lengths]
-
-
 def test_prefill_and_decode_through_the_cache(highest):
     """Contexts past the window (the ring of 3 blocks wraps several
     times), slots of unequal length, and a slot reused after a finish
     (5 requests over 3 slots)."""
     cfg, _, params = make()
-    prompts = _prompts(cfg, [5, 19, 33, 9, 26])
-    stats = served_logits_match(
-        cfg, params, list(zip(prompts, [30, 12, 20, 25, 8])))
+    asked = prompts(cfg, [5, 19, 33, 9, 26])
+    stats, _ = FAMILY.served_logits_match(
+        cfg, params, list(zip(asked, [30, 12, 20, 25, 8])))
     counted = stats["model_counters"]
     sparse = sum(cfg.moe_layer_freq)
     # every decode step routed every busy slot's token in every sparse
@@ -186,7 +136,7 @@ def test_prefill_and_decode_through_the_cache(highest):
         stats["busy_slot_steps"] * sparse * cfg.num_experts_per_tok)
     assert counted["decode"]["pairs_here"] == counted["decode"]["pairs_all"]
     assert counted["prefill"]["pairs_all"] == (
-        sum(map(len, prompts)) * sparse * cfg.num_experts_per_tok)
+        sum(map(len, asked)) * sparse * cfg.num_experts_per_tok)
     kv = stats["kv_live_bytes"]
     assert 0 < kv["window"] and 0 < kv["global"]
     assert {"mimo_window_cached_xla", "mimo_global_prefill_xla",
@@ -195,16 +145,16 @@ def test_prefill_and_decode_through_the_cache(highest):
 
 def test_a_prompt_through_chunked_prefill(highest):
     cfg, _, params = make()
-    prompts = _prompts(cfg, [37, 6])
-    served_logits_match(cfg, params, list(zip(prompts, [14, 14])),
-                        prefill_chunk_tokens=8)
+    FAMILY.served_logits_match(
+        cfg, params, list(zip(prompts(cfg, [37, 6]), [14, 14])),
+        prefill_chunk_tokens=8)
 
 
 def test_the_window_pool_does_not_grow_with_the_context(highest):
     cfg, _, params = make()
     sizes = {}
     for longest in (32, 64):
-        srv = serving_engine(params, cfg, max_model_len=longest)
+        srv = FAMILY.serving_engine(params, cfg, max_model_len=longest)
         sizes[longest] = {k: v.shape for k, v in srv.cache.items()}
         ring = srv.slot_entries
         srv.destroy()
@@ -221,8 +171,8 @@ def test_an_expert_share_serves_only_its_experts(highest):
     of the pairs as its own."""
     cfg, _, params = make(ep_size=4, ep_rank=1)
     assert params["layers_1_mlp"]["gate"].shape[0] == 8
-    stats = served_logits_match(
-        cfg, params, list(zip(_prompts(cfg, [11, 21]), [16, 16])))
+    stats, _ = FAMILY.served_logits_match(
+        cfg, params, list(zip(prompts(cfg, [11, 21]), [16, 16])))
     counted = stats["model_counters"]["decode"]
     assert 0 < counted["pairs_here"] < counted["pairs_all"]
     assert 0 < counted["experts_touched"] < counted["experts_held"]
@@ -237,24 +187,21 @@ def test_the_engine_hands_back_the_routed_sets(highest, chunk):
     layer: the prompt's through either kind of prefill, then each decode
     step's, in order. In float32 they are the reference's own."""
     cfg, _, params = make()
-    prompts = _prompts(cfg, [21, 6, 13])
-    srv = serving_engine(params, cfg, routed_experts_kept=2,
-                         **({"prefill_chunk_tokens": chunk} if chunk else {}))
+    asked = prompts(cfg, [21, 6, 13])
+    # an engine of its own: which sets are kept depends on what finished
+    # before
+    srv = FAMILY.serving_engine(
+        params, cfg, routed_experts_kept=2,
+        **({"prefill_chunk_tokens": chunk} if chunk else {}))
     try:
         reqs = [srv.submit(p, max_new_tokens=n)
-                for p, n in zip(prompts, [9, 12, 5])]
+                for p, n in zip(asked, [9, 12, 5])]
         srv.drain()
         # finished in the order 2, 0, 1: the oldest of three is dropped
         assert srv.routed_experts(reqs[2].request_id) is None
-        sparse, k = sum(cfg.moe_layer_freq), cfg.num_experts_per_tok
-        for req, prompt in list(zip(reqs, prompts))[:2]:
-            got = srv.routed_experts(req.request_id)
-            ids = np.asarray([list(prompt) + req.tokens[:-1]])
-            assert got.shape == (ids.shape[1], sparse * k)
-            want = np.asarray(reference.routed_sets(
-                params, jnp.asarray(ids), shape_of(cfg)))[:, 0]
-            got = got.reshape(-1, sparse, k).transpose(1, 0, 2)
-            assert (np.sort(got, -1) == np.sort(want, -1)).all()
+        for req, prompt in list(zip(reqs, asked))[:2]:
+            FAMILY.routed_sets_are_the_references(srv, cfg, params, req,
+                                                  prompt)
             assert req.routed == []
     finally:
         srv.destroy()
@@ -262,14 +209,11 @@ def test_the_engine_hands_back_the_routed_sets(highest, chunk):
 
 def test_without_the_knob_the_programs_return_no_routed_sets(highest):
     cfg, _, params = make()
-    srv = serving_engine(params, cfg)
-    try:
-        req = srv.submit(_prompts(cfg, [7])[0], max_new_tokens=4)
-        srv.drain()
-        assert req.routed == [] and srv.routed_experts(req.request_id) is None
-        assert not srv._dmodule.config.paged_return_routed
-    finally:
-        srv.destroy()
+    srv = FAMILY.shared_engine(params, cfg)
+    req = srv.submit(prompts(cfg, [7])[0], max_new_tokens=4)
+    srv.drain()
+    assert req.routed == [] and srv.routed_experts(req.request_id) is None
+    assert not srv._dmodule.config.paged_return_routed
 
 
 def test_a_dense_model_refuses_routed_experts_kept():
@@ -279,8 +223,8 @@ def test_a_dense_model_refuses_routed_experts_kept():
     gcfg = GPT2Config(vocab_size=64, n_positions=32, n_embd=16, n_layer=1,
                       n_head=2, dtype=jnp.float32)
     module = GPT2LMHeadModel(gcfg)
-    params = module.init(jax.random.PRNGKey(0),
-                         jnp.zeros((1, 4), jnp.int32))["params"]
+    params = jax.jit(module.init)(jax.random.PRNGKey(0),
+                                  jnp.zeros((1, 4), jnp.int32))["params"]
     with pytest.raises(Exception, match="routed_experts_kept") as e:
         ServingEngine(deepspeed_tpu.init_inference(
             module, params=params, dtype=jnp.float32,
@@ -294,82 +238,35 @@ def test_the_reference_takes_routed_sets_handed_in(highest):
     is swapped for another moves that position's logits, is flagged, and
     its margin says how far from a tie the swap was."""
     cfg, _, params = make(ep_size=1)
-    ids = jnp.asarray([_prompts(cfg, [12])[0]])
+    ids = jnp.asarray([prompts(cfg, [12])[0]])
     shape = shape_of(cfg)
-    own = np.asarray(reference.routed_sets(params, ids, shape))   # [L,1,T,k]
+    # (each form of the call ONE program: with sets handed in, without)
+    handed = jax.jit(lambda given: reference.logits(
+        params, ids, shape, given, with_layers=True))
+    own = np.asarray(jax.jit(lambda: reference.routed_sets(
+        params, ids, shape))())                                   # [L,1,T,k]
     given = own.transpose(1, 2, 0, 3).copy()
-    base = np.asarray(reference.logits(params, ids, shape))
-    same, seen = reference.logits(params, ids, shape, jnp.asarray(given),
-                                  with_layers=True)
+    base = np.asarray(jax.jit(lambda: reference.logits(params, ids,
+                                                       shape))())
+    same, seen = handed(jnp.asarray(given))
     assert np.abs(np.asarray(same) - base).max() == 0.0
     assert float(seen["margin"].max()) == 0.0 and not bool(
         seen["differs"].any())
     assert seen["inputs"].shape == (own.shape[0], 1, 12, cfg.hidden_size)
     # negative: the reference's own
     unset = np.full_like(given, -1)
-    assert np.abs(np.asarray(reference.logits(
-        params, ids, shape, jnp.asarray(unset))) - base).max() == 0.0
+    assert np.abs(np.asarray(handed(jnp.asarray(unset))[0])
+                  - base).max() == 0.0
     # position 5, second sparse layer: an expert it did not choose
     other = next(e for e in range(cfg.n_routed_experts)
                  if e not in given[0, 5, 1])
     given[0, 5, 1, -1] = other
-    moved, seen = reference.logits(params, ids, shape, jnp.asarray(given),
-                                   with_layers=True)
+    moved, seen = handed(jnp.asarray(given))
     moved = np.abs(np.asarray(moved) - base).max(-1)[0]
     assert moved[5] > 10 * TOL and moved[:5].max() == 0.0
     differs = np.asarray(seen["differs"])[:, 0]
     assert differs[1, 5] and differs.sum() == 1
     assert float(seen["margin"][1, 0, 5]) > 0.0
-
-
-def _paged_logits(srv, cfg, prompt, steps, slot=1, chunk=0, one_device=False):
-    """Drive the engine's own paged module with its own pool and tables,
-    as its programs do, and keep the LOGITS: every prompt position (whole
-    prompt, or chunks of ``chunk``), then ``steps`` greedy decode steps in
-    the decode program's batch shape. -> (logits [positions, vocab], ids)."""
-    dm, params = srv._dmodule, srv.engine.params
-    if one_device:
-        # the Pallas interpreter's callbacks do not go through the SPMD
-        # partitioner the engine's 8-device CPU mesh brings
-        params, srv.cache = jax.device_put((params, srv.cache),
-                                           jax.devices()[0])
-
-    def call(prefill):
-        def fn(cache, ids, tables, lengths, num_valid):
-            out, v = dm.apply(
-                {"params": params, "cache": cache}, ids, mutable=["cache"],
-                paging={"block_tables": tables, "lengths": lengths,
-                        "num_valid": num_valid, "prefill": prefill})
-            return out[0], v["cache"]
-        return jax.jit(fn)
-
-    whole, cached = call(True), call(False)
-    table = srv._slot_table(slot, srv.block_mgr.allocate(
-        "direct", len(prompt) + steps))
-    i32 = lambda x: jnp.asarray(x, jnp.int32)
-    rows, n = [], len(prompt)
-    for at in range(0, n, chunk or n):
-        m = min(chunk or n, n - at)
-        width = chunk or -(-n // 8) * 8
-        ids = np.zeros((1, width), np.int32)
-        ids[0, :m] = prompt[at:at + m]
-        lg, srv.cache = (cached if chunk else whole)(
-            srv.cache, i32(ids), i32(table[None]), i32([at]), i32([m]))
-        rows.append(np.asarray(lg[0, :m]))
-    slots = srv.config.decode_slots
-    tables = np.zeros((slots, len(table)), np.int32)
-    tables[slot] = table
-    tokens = list(prompt)
-    for _ in range(steps):
-        tokens.append(int(rows[-1][-1].argmax()))
-        lengths, last = np.zeros(slots, np.int32), np.zeros((slots, 1),
-                                                            np.int32)
-        lengths[slot], last[slot] = len(tokens) - 1, tokens[-1]
-        lg, srv.cache = cached(srv.cache, i32(last), i32(tables),
-                               i32(lengths), jnp.ones(slots, jnp.int32))
-        rows.append(np.asarray(lg[slot]))
-    srv.block_mgr.release("direct")
-    return np.concatenate(rows), tokens
 
 
 @pytest.mark.parametrize("chunk", [0, 8], ids=["whole-prompt", "chunked"])
@@ -379,85 +276,38 @@ def test_paged_logits_match_the_reference(highest, chunk):
     steps both past the window (what ``tools/chip_logits_mimo_v2.py`` does
     on the chip at the published widths)."""
     cfg, _, params = make()
-    srv = serving_engine(params, cfg)
-    try:
-        prompt = _prompts(cfg, [27])[0]
-        got, tokens = _paged_logits(srv, cfg, prompt, 14, chunk=chunk)
-        want = reference_logits(cfg, params, np.asarray([tokens]))[0]
-        assert np.abs(got - want[:len(got)]).max() <= TOL
-    finally:
-        srv.destroy()
+    assert FAMILY.paged_logits_match(
+        FAMILY.shared_engine(params, cfg), cfg, params,
+        prompts(cfg, [27])[0], 14, chunk=chunk) <= TOL
 
 
 def test_decode_through_both_kernels_matches_the_xla_paths(monkeypatch):
     """The decode program with the Pallas kernels in it (interpret mode):
     the paged GQA kernel over the block table and over the ring, and the
     grouped expert matmul, against the same steps on the XLA paths."""
-    from deepspeed_tpu.ops import attention as ops_attention
-    from deepspeed_tpu.utils.compat import tpu_interpret_mode
-
     cfg, _, params = make()
-    prompt = _prompts(cfg, [19])[0]
-    plain = serving_engine(params, cfg)
-    want, _ = _paged_logits(plain, cfg, prompt, 3)
-    plain.destroy()
-    monkeypatch.setattr(ops_attention, "use_decode_kernel", lambda: True)
-    ffn = dropless.expert_ffn
-    monkeypatch.setattr(dropless, "expert_ffn", lambda *a, **k: ffn(
-        *a, **{**k, "use_kernel": True}))
-    srv = serving_engine(params, cfg)
-    try:
-        with tpu_interpret_mode():
-            got, _ = _paged_logits(srv, cfg, prompt, 3, one_device=True)
-        paths = srv.stats()["attention_paths"]
-        assert paths.get("mimo_window_decode_kernel") and paths.get(
-            "mimo_global_decode_kernel")
-        assert np.abs(got - want).max() <= TOL
-    finally:
-        srv.destroy()
+    got, want, paths = FAMILY.decode_through_the_kernels(
+        monkeypatch, cfg, params, prompts(cfg, [19])[0], 3)
+    assert paths.get("mimo_window_decode_kernel") and paths.get(
+        "mimo_global_decode_kernel")
+    assert np.abs(got - want).max() <= TOL
 
 
 # ---------------------------------------------------------------------------
 # refusals, by name
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("serving, mechanism", [
-    ({"prefix_cache": True}, "serving.prefix_cache"),
-    ({"speculative": {"num_speculative_tokens": 2}}, "serving.speculative"),
-    ({"kv_cache_dtype": "int8"}, "serving.kv_cache_dtype"),
-], ids=["prefix-cache", "speculation", "int8-kv"])
+@REFUSED
 def test_mechanisms_that_know_one_kind_of_row_refuse_the_model(serving,
                                                                mechanism):
-    cfg, _, params = make()
-    with pytest.raises(Exception, match=mechanism.replace(".", r"\.")) as e:
-        serving_engine(params, cfg, **serving)
-    assert "MiMoV2ForCausalLM" in str(e.value)
+    FAMILY.mechanism_refusal(serving, mechanism)
 
 
 def test_tensor_parallel_refuses_the_model():
-    cfg, _, params = make()
-    reset_topology()
-    with pytest.raises(Exception, match="tp_size > 1") as e:
-        ServingEngine(deepspeed_tpu.init_inference(
-            MiMoV2ForCausalLM(cfg), params=params, dtype=cfg.dtype,
-            tensor_parallel={"tp_size": 2},
-            serving={"decode_slots": 2, "block_size": BLOCK,
-                     "max_model_len": 32}))
-    assert "MiMoV2ForCausalLM" in str(e.value)
-    reset_topology()
+    assert "MiMoV2ForCausalLM" in FAMILY.tensor_parallel_refusal()
 
 
 def test_migration_refuses_the_model():
-    cfg, _, params = make()
-    srv = serving_engine(params, cfg)
-    try:
-        req = srv.submit([1, 2, 3, 4, 5], max_new_tokens=8)
-        srv.step()
-        with pytest.raises(NotImplementedError, match="migration"):
-            srv.export_sequence(req.request_id)
-        with pytest.raises(NotImplementedError, match="migration"):
-            srv.import_sequence({"request_id": "x"})
-    finally:
-        srv.destroy()
+    assert len(FAMILY.migration_refusals()) == 2
 
 
 # ---------------------------------------------------------------------------

@@ -196,10 +196,10 @@ def test_a_swapped_pair_of_parameters_moves_the_checksum():
 # (c) the contract is written once
 
 def test_the_engine_keeps_no_ring_of_its_own():
-    from tests.unit.test_mimo_v2 import make, serving_engine
+    from tests.unit.test_mimo_v2 import FAMILY
 
-    cfg, _, params = make()
-    srv = serving_engine(params, cfg)
+    cfg, _, params = FAMILY.make()
+    srv = FAMILY.serving_engine(params, cfg)
     try:
         assert not hasattr(srv, "ring_blocks")
         assert srv.slot_entries == cfg.paged_ring_blocks_for(

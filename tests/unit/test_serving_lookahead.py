@@ -456,12 +456,12 @@ def test_mimo_counters_are_the_serial_sums_on_the_same_schedule():
     import jax
     import jax.numpy as jnp
 
-    from tests.unit.test_mimo_v2 import make, serving_engine
+    from tests.unit.test_mimo_v2 import FAMILY
 
     with jax.default_matmul_precision("highest"):
-        cfg, _, params = make(ep_size=4, ep_rank=1)
-        srv = serving_engine(params, cfg, routed_experts_kept=8)
-        twin = serving_engine(params, cfg, routed_experts_kept=8)
+        cfg, _, params = FAMILY.make(ep_size=4, ep_rank=1)
+        srv = FAMILY.serving_engine(params, cfg, routed_experts_kept=8)
+        twin = FAMILY.serving_engine(params, cfg, routed_experts_kept=8)
         log = []
         build_prefill = srv._build_prefill
         srv._build_prefill = lambda T: _recording(build_prefill(T), log, T)

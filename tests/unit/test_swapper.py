@@ -221,10 +221,20 @@ class TestOverlapAndRSS:
         # bound pays io+compute per layer; the pipelined stream pays
         # ~max(io, compute). Margins are loose (CI timing noise) but a
         # stream that stopped prefetching ahead would land at ~1.0x.
-        r = measure(n_layers=24, mb_per_layer=16, compute_s=0.010,
-                    workdir=str(tmp_path))
-        assert r["overlap_speedup"] > 1.05, r
-        # host RSS growth stays pool-sized, not model-sized: the 384MB
-        # of streamed parameters must not accumulate in RAM
-        assert r["rss_growth_mb"] < r["pool_mb"] + 64, r
-        assert r["total_mb"] > 4 * r["pool_mb"]
+        # The overlap is read on a wall clock this test does not own:
+        # beside five busy workers the read-ahead thread may wait for a core
+        # through a whole sweep. So the best of up to three readings is held
+        # to the bound (a stream that does not read ahead is ~1.0x in every
+        # one), and EVERY reading to the memory bound.
+        readings = []
+        for _ in range(3):
+            r = measure(n_layers=24, mb_per_layer=16, compute_s=0.010,
+                        workdir=str(tmp_path))
+            readings.append(r)
+            # host RSS growth stays pool-sized, not model-sized: the 384MB
+            # of streamed parameters must not accumulate in RAM
+            assert r["rss_growth_mb"] < r["pool_mb"] + 64, r
+            assert r["total_mb"] > 4 * r["pool_mb"]
+            if r["overlap_speedup"] > 1.05:
+                break
+        assert max(r["overlap_speedup"] for r in readings) > 1.05, readings

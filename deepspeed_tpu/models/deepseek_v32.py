@@ -367,18 +367,13 @@ class SparseLatentAttention(nn.Module):
         scores = select_op.index_scores(q_i, w_i, index_rows, tiles, tile,
                                         cap)
 
-        def valid_of(first, count):
-            k_pos = first + jnp.arange(count, dtype=jnp.int32)
-            return ((k_pos[None, None] <= pos[:, :, None])
-                    & (k_pos[None, None] < live[:, None, None])).reshape(
-                        b * t, count)
-
-        mask, chosen = select_op.select_mask(
-            scores.reshape(b * t, cap), valid_of, cfg.index_topk,
-            jnp.max(live))
+        # causal and live: a query may choose from a prefix of the keys
+        could = jnp.minimum(pos + 1, live[:, None])
+        mask, chosen, bias = select_op.select_mask(
+            scores.reshape(b * t, cap), could.reshape(b * t),
+            cfg.index_topk, jnp.max(live))
         mask = mask.reshape(b, t, -1)
 
-        could = jnp.minimum(pos + 1, live[:, None])
         seen = (mask, could, chosen.reshape(b, t))
         if b == 1 and attend_op.kernel_serves(
                 t, cfg.num_attention_heads, nope, cfg.qk_rope_head_dim,
@@ -392,7 +387,7 @@ class SparseLatentAttention(nn.Module):
             with jax.named_scope("dsa_sparse_attend.masked"):
                 out = attend_op.attend_masked(
                     q_nope[0], q_pe[0], rows, w_kvb, mask[0], jnp.max(live),
-                    scale=cfg.softmax_scale)
+                    scale=cfg.softmax_scale, bias=bias)
             return out[None], seen
         record_dispatch("dsa_chunk_masked_decompressed_xla")
 

@@ -34,9 +34,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.ops.dsa_index_select import unpack_bits
+from deepspeed_tpu.ops.dsa_index_select import UNCHOSEN, unpack_bits
 
-_NEG = -1e30
+_NEG = UNCHOSEN
 # the kernel's tiles: keys a grid step, heads a group
 KERNEL_KEYS = 512
 KERNEL_HEADS = 8
@@ -154,13 +154,15 @@ def _masked_kernel(tiles_ref, qn_ref, qp_ref, rows_ref, w_ref, bias_ref,
 
 
 def attend_masked(q_nope, q_pe, rows, w_kvb, mask, live_keys, *,
-                  scale: float):
+                  scale: float, bias=None):
     """One row's chunk: ``q_nope [T, H, nope]``, ``q_pe [T, H, rope]``
     (rotated) over the sequence's latent rows as they lie side by side
     (``rows [S, lanes]``, ``[c | k_pe | zeros]``: gathered through the
     block table by the caller), ``w_kvb [rank, H, nope + dv]``, ``mask [T,
-    S / 32]`` the selection's packed mask (causal and live inside it);
-    tiles of keys past ``live_keys`` are not computed. -> ``[T, H, dv]``."""
+    S / 32]`` the selection's packed mask (causal and live inside it), or
+    ``bias [T, S]`` the same set as the selection's kernel laid it (0 for
+    a chosen key, ``UNCHOSEN`` for every other); tiles of keys past
+    ``live_keys`` are not computed. -> ``[T, H, dv]``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -176,8 +178,10 @@ def attend_masked(q_nope, q_pe, rows, w_kvb, mask, live_keys, *,
     qp = jnp.pad(q_pe, ((0, 0), (0, 0), (0, lanes - rank - q_pe.shape[-1]))
                  ).swapaxes(0, 1)
     w = w_kvb.reshape(rank, groups, group * (nope + dv)).swapaxes(0, 1)
-    # the mask as a bias: 0 for a chosen key, -1e30 for every other
-    bias = jnp.where(unpack_bits(mask, s), 0.0, _NEG).astype(jnp.bfloat16)
+    if bias is None:
+        # the mask as a bias: 0 for a chosen key, -1e30 for every other
+        bias = jnp.where(unpack_bits(mask, s), 0.0, _NEG).astype(
+            jnp.bfloat16)
     tiles = jnp.reshape((live_keys + tk - 1) // tk, (1,)).astype(jnp.int32)
 
     def key_tile(j, tiles_ref):

@@ -1451,3 +1451,22 @@ def test_the_masked_chunk_attention_kernel_at_the_published_widths(one_chip):
     calls = _custom_calls(text)
     assert len(calls) == 1 and pattern.search(calls[0]), calls
     assert "dsa_sparse_attend" in calls[0]
+
+
+def test_the_selection_kernel_at_the_published_widths(one_chip, monkeypatch):
+    """The chunk selection's kernel (64 queries' 32,768 keys held in VMEM
+    as integers of the scores' order, 8 MB; the k-th settled by signed
+    compares) compiles for the v5e at the cell's sizes under the name the
+    trace shows, beside the one XLA pass that lays the mask."""
+    from deepspeed_tpu.ops import attention, dsa_index_select
+
+    t, keys, k = 512, 32768, 2048
+    monkeypatch.setattr(attention, "_FORCE_DECODE_KERNEL", True)
+    assert dsa_index_select.kernel_serves(t, keys)
+    text = _compiled_text(
+        lambda scores, could, live: dsa_index_select.select_mask(
+            scores, could, k, live),
+        _s(one_chip, (t, keys), jnp.float32), _s(one_chip, (t,), jnp.int32),
+        _s(one_chip, (), jnp.int32))
+    calls = _custom_calls(text)
+    assert len(calls) == 1 and "dsa_index_select" in calls[0], calls

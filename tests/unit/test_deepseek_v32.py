@@ -295,7 +295,7 @@ def test_the_engine_serves_chunks_counts_and_hands_back_the_chosen_keys(
         decode = stats["model_counters"]["decode"]
         assert decode["dsa_keys_selected"] == (
             2 * 16 * stats["busy_slot_steps"])
-        assert {"dsa_chunk_masked_decompressed_xla",
+        assert {"dsa_chunk_masked_decompressed_xla", "dsa_select_passes_xla",
                 "dsa_decode_absorbed_gathered_xla"} <= set(
                     stats["attention_paths"])
     finally:

@@ -3,8 +3,9 @@
 scores against the plain sum; the EXACT selection against a plain sort,
 with ties, at ``live`` below, at and above ``k``; the chunk form's sets
 equal to the step form's for the same queries; the order the scores' bits
-keep; the packed mask there and back; and the attention over gathered rows
-against a plain softmax over the same rows."""
+keep; the packed mask there and back; the chunk form's Pallas kernel (the
+interpreter here) against its XLA form, word for word; and the attention
+over gathered rows against a plain softmax over the same rows."""
 
 import jax
 import jax.numpy as jnp
@@ -26,11 +27,14 @@ def plain_selection(scores, valid, k):
     return out
 
 
-def causal_valid(pos, live):
-    def valid_of(first, count):
-        k_pos = first + jnp.arange(count, dtype=jnp.int32)
-        return (k_pos[None] <= pos[:, None]) & (k_pos[None] < live)
-    return valid_of
+def causal_prefix(pos, live):
+    """``could [N]``: the keys a query at ``pos`` may choose from are its
+    first ``min(pos + 1, live)``."""
+    return jnp.minimum(jnp.asarray(pos, jnp.int32) + 1, live)
+
+
+def prefix_valid(could, cap):
+    return np.arange(cap)[None] < np.asarray(could)[:, None]
 
 
 def scores_with_ties(n, cap, seed, levels=0):
@@ -52,10 +56,11 @@ def test_the_chunk_selection_is_a_plain_sorts(first, k, levels):
     scores = scores_with_ties(n, cap, first + k, levels)
     pos = first + jnp.arange(n, dtype=jnp.int32)
     live = first + n
-    mask, chosen = jax.jit(lambda s: select_op.select_mask(
-        s, causal_valid(pos, live), k, jnp.asarray(live)))(scores)
+    could = causal_prefix(pos, live)
+    mask, chosen, _ = jax.jit(lambda s: select_op.select_mask(
+        s, could, k, jnp.asarray(live)))(scores)
     got = np.asarray(select_op.unpack_bits(mask, cap))
-    valid = np.asarray(causal_valid(pos, live)(0, cap))
+    valid = prefix_valid(could, cap)
     want = plain_selection(scores, valid, k)
     assert (got == want).all()
     assert (np.asarray(chosen) == want.sum(-1)).all()
@@ -70,10 +75,10 @@ def test_the_selection_passes_stop_at_the_live_keys(monkeypatch):
     scores = scores_with_ties(n, cap, 3, 2)
     scores[:, 128:] = np.nan
     pos = live - n + jnp.arange(n, dtype=jnp.int32)
-    mask, _ = select_op.select_mask(jnp.asarray(scores),
-                                    causal_valid(pos, live), k,
-                                    jnp.asarray(live))
-    valid = np.asarray(causal_valid(pos, live)(0, cap))
+    could = causal_prefix(pos, live)
+    mask, _, _ = select_op.select_mask(jnp.asarray(scores), could, k,
+                                       jnp.asarray(live))
+    valid = prefix_valid(could, cap)
     assert (np.asarray(select_op.unpack_bits(mask, cap))
             == plain_selection(scores, valid, k)).all()
 
@@ -97,10 +102,9 @@ def test_the_step_form_chooses_the_chunk_forms_sets(live, levels):
         laid[row, places[places >= 0]] = True
     assert (laid == step).all()
     for row in range(n):
-        mask, count = select_op.select_mask(
-            jnp.asarray(scores[row:row + 1]),
-            lambda first, c: jax.lax.dynamic_slice_in_dim(
-                valid[row:row + 1], first, c, 1), k, lives[row])
+        mask, count, _ = select_op.select_mask(
+            jnp.asarray(scores[row:row + 1]), lives[row:row + 1], k,
+            lives[row])
         assert (np.asarray(select_op.unpack_bits(mask, cap))[0]
                 == step[row]).all()
         assert int(count[0]) == int(chosen[row]) == step[row].sum()
@@ -113,6 +117,111 @@ def test_the_step_form_chooses_the_chunk_forms_sets(live, levels):
         assert len(real) == min(k, int(lives[row]))
         keys = [(-scores[row, j], j) for j in real]
         assert keys == sorted(keys) and (at[row][len(real):] == -1).all()
+
+
+# ---------------------------------------------------------------------------
+# the chunk form's kernel (the interpreter here) against its XLA form
+# ---------------------------------------------------------------------------
+N_KERNEL, CAP_KERNEL = 64, 1024      # a tile of queries, four tiles of 256
+
+
+def _signed_zeros(rng):
+    return rng.choice(np.asarray([-0.0, 0.0, -1.0, -2.5, -1e-30, 0.5],
+                                 np.float32), (N_KERNEL, CAP_KERNEL))
+
+
+# name: (scores of a generator, the first query's position, live, k)
+KERNEL_CASES = {
+    "random": (lambda rng: rng.standard_normal(
+        (N_KERNEL, CAP_KERNEL)).astype(np.float32), 936, 1000, 16),
+    "all-equal": (lambda rng: np.full((N_KERNEL, CAP_KERNEL), 1.5,
+                                      np.float32), 936, 1000, 16),
+    "ties-at-the-kth": (lambda rng: scores_with_ties(
+        N_KERNEL, CAP_KERNEL, 5, 2), 936, 1000, 16),
+    "signed-zeros-and-negatives": (_signed_zeros, 936, 1000, 16),
+    "fewer-than-k": (lambda rng: scores_with_ties(
+        N_KERNEL, CAP_KERNEL, 6, 2), 0, 64, 100),
+    "live-inside-a-tile-and-a-dead-tile": (lambda rng: scores_with_ties(
+        N_KERNEL, CAP_KERNEL, 7, 4), 536, 600, 16),
+    "prefix-ends-inside-a-register": (lambda rng: scores_with_ties(
+        N_KERNEL, CAP_KERNEL, 8, 0), 70, 1000, 16),
+    "k-over-all": (lambda rng: scores_with_ties(
+        N_KERNEL, CAP_KERNEL, 9, 2), 200, 264, CAP_KERNEL),
+}
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_the_selection_kernel_is_the_xla_form_word_for_word(case,
+                                                            monkeypatch):
+    """``dsa_index_select`` settles the k-th key over a tile of queries'
+    scores in VMEM and lays the set from there: the mask and ``chosen``
+    are the XLA passes' to the bit, ties to the lower position, ``-0.0``
+    as ``0.0``, no key past a query's prefix or the live keys (NaN there
+    changes nothing)."""
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.utils.compat import tpu_interpret_mode
+
+    make, first, live, k = KERNEL_CASES[case]
+    monkeypatch.setattr(select_op, "SELECT_TILE", 256)
+    scores = make(np.random.default_rng(11)).copy()
+    dead = -(-live // 256) * 256
+    scores[:, dead:] = np.nan
+    could = causal_prefix(first + np.arange(N_KERNEL), live)
+    pick = lambda s: select_op.select_mask(s, could, k, jnp.asarray(live))
+    assert not select_op.kernel_serves(N_KERNEL, CAP_KERNEL)
+    mask, chosen, none = jax.jit(pick)(scores)
+    assert none is None
+    before = attention.dispatch_counts().get("dsa_select_vmem_kernel", 0)
+    monkeypatch.setattr(attention, "_FORCE_DECODE_KERNEL", True)
+    assert select_op.kernel_serves(N_KERNEL, CAP_KERNEL)
+    assert not select_op.kernel_serves(N_KERNEL - 8, CAP_KERNEL)
+    with tpu_interpret_mode():
+        # (a jit of its own: traced under the patch)
+        mask_k, chosen_k, bias = jax.block_until_ready(
+            jax.jit(lambda s: pick(s))(scores))
+    assert attention.dispatch_counts()["dsa_select_vmem_kernel"] == before + 1
+    assert mask_k.dtype == mask.dtype and mask_k.shape == mask.shape
+    assert (np.asarray(mask_k) == np.asarray(mask)).all()
+    assert (np.asarray(chosen_k) == np.asarray(chosen)).all()
+    valid = prefix_valid(could, CAP_KERNEL)
+    sets = np.asarray(select_op.unpack_bits(mask_k, CAP_KERNEL))
+    assert (sets == plain_selection(np.nan_to_num(scores), valid, k)).all()
+    # the same set as the attention kernel's bias, in the tiles laid
+    assert bias.dtype == jnp.bfloat16 and bias.shape == scores.shape
+    unchosen = np.asarray(jnp.asarray(select_op.UNCHOSEN, jnp.bfloat16),
+                          np.float32)
+    assert (np.asarray(bias, np.float32)[:, :dead]
+            == np.where(sets[:, :dead], 0.0, unchosen)).all()
+
+
+def test_a_chunk_of_the_mixer_takes_the_selection_kernel(monkeypatch):
+    """Through ``SparseLatentAttention._chunk``: 128 queries over their
+    own 128 keys take the kernel where it serves, the path is counted once
+    a layer a chunk, and the chosen sets are the XLA form's."""
+    from deepspeed_tpu.models.deepseek_v32 import (DeepseekV32Config,
+                                                   SparseLatentAttention)
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.utils.compat import tpu_interpret_mode
+
+    cfg = DeepseekV32Config.tiny(dtype=jnp.float32, param_dtype=jnp.float32)
+    mixer = SparseLatentAttention(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, 128, cfg.hidden_size))
+    params = jax.jit(lambda x: mixer.init(jax.random.PRNGKey(0), x))(x)
+    apply = lambda p, x: mixer.apply(p, x)
+    plain, _, seen = jax.jit(apply)(params, x)
+    counts = attention.dispatch_counts()
+    monkeypatch.setattr(attention, "_FORCE_DECODE_KERNEL", True)
+    with tpu_interpret_mode():
+        kernel, _, seen_k = jax.block_until_ready(
+            jax.jit(lambda *a: apply(*a))(params, x))
+    after = attention.dispatch_counts()
+    assert after["dsa_select_vmem_kernel"] == counts.get(
+        "dsa_select_vmem_kernel", 0) + 1
+    assert after["dsa_select_passes_xla"] == counts["dsa_select_passes_xla"]
+    for a, b in zip(seen, seen_k):
+        assert (np.asarray(a) == np.asarray(b)).all()
+    assert int(seen_k[2].max()) == cfg.index_topk
+    assert np.abs(np.asarray(kernel - plain)).max() <= 1e-5
 
 
 def test_the_scores_bits_keep_their_order():

@@ -2,7 +2,9 @@
 published widths: the serving programs of ``deepseek-v3.2-ep32``'s cut (a
 512-token chunk at 4k, 16k and 30k live keys; a decode step of 14 busy rows)
 and, apart, the pieces a layer of them is made of: a chunk's index scores,
-its selection (the bisection), its attention under the mask; a step's
+its selection in both forms (the XLA passes; the kernel
+``dsa_index_select``, which serves on a TPU: the two must choose the same
+sets), its attention under the mask; a step's
 scores, ``lax.top_k``, the gather and the attention over the gathered rows.
 
     chiprun -- python tools/probe_dsa_ops.py [--layers 5] [--reps 5]
@@ -123,24 +125,46 @@ def main():
             keys, j * tile, tile, 0)[None],
         (live + tile - 1) // tile, tile, MAX_LEN)[0])
 
-    def picked(scores, live):
-        pos = live - CHUNK + jnp.arange(CHUNK, dtype=jnp.int32)
+    def picked(kernel):
+        """``select_mask`` in one of its two forms: the kernel
+        ``dsa_index_select`` where it serves, or the XLA passes."""
+        def pick(scores, live):
+            could = jnp.minimum(
+                live - CHUNK + 1 + jnp.arange(CHUNK, dtype=jnp.int32), live)
+            serves = select_op.kernel_serves
+            select_op.kernel_serves = (
+                serves if kernel else lambda queries, cap: False)
+            try:
+                return select_op.select_mask(scores, could, k, live)
+            finally:
+                select_op.kernel_serves = serves
+        return jax.jit(pick)
 
-        def valid_of(first, count):
-            k_pos = first + jnp.arange(count, dtype=jnp.int32)
-            return (k_pos[None] <= pos[:, None]) & (k_pos[None] < live)
-        return select_op.select_mask(scores, valid_of, k, live)
-
-    pick = jax.jit(picked)
+    forms = {"XLA passes": picked(False),
+             "kernel dsa_index_select": picked(True)}
     top = jax.jit(lambda s: jax.lax.top_k(s, k))
+    # a layer's queries each: ``--layers`` selections in ONE program are a
+    # chunk program's (a call alone is a millisecond of launch besides)
+    qs = [jax.random.normal(jax.random.PRNGKey(10 + i), q.shape, q.dtype)
+          for i in range(args.layers)]
     for live in lives:
         at = jnp.asarray(live, jnp.int32)
         say(what="chunk index scores (XLA)", live=live,
             ms=timed(lambda: scores_of(q, w, keys, at), args.reps))
-        scores = scores_of(q, w, keys, at)
-        say(what="chunk selection (bisection)", live=live,
-            ms=timed(lambda: pick(scores, at), args.reps),
-            chosen=int(pick(scores, at)[1].sum()))
+        stack = [scores_of(u, w, keys, at) for u in qs]
+        scores = stack[0]
+        sets = {}
+        for name, pick in forms.items():
+            sets[name] = pick(scores, at)
+            layers = jax.jit(lambda stack, at: [pick(u, at) for u in stack])
+            say(what=f"chunk selection ({name})", live=live,
+                ms=timed(lambda: pick(scores, at), args.reps),
+                ms_all_layers=timed(lambda: layers(stack, at), args.reps),
+                chosen=int(sets[name][1].sum()))
+        mask, chosen, _ = sets["XLA passes"]
+        if not all(bool((m == mask).all()) and bool((c == chosen).all())
+                   for m, c, _ in sets.values()):
+            raise SystemExit(f"the forms' sets differ at {live} live keys")
     say(what="chunk selection (lax.top_k, for comparison)",
         ms=timed(lambda: top(scores), args.reps))
     # the mixer of one layer, whole, through the pools

@@ -186,7 +186,7 @@ def causal_gqa(q, k, v, window: int = 0, sink=None):
 
 
 def paged_gqa(q, k, v, pos, paging, table, k_pool, v_pool, index, label,
-              sink=None, work=None, key_tile: int = 0):
+              sink=None, work=None, key_tile: int = 0, value_group: int = 1):
     """One layer's grouped-query step of a serving program over rows a
     block table addresses: write this call's keys and values into layer
     ``index`` of the pools (``[layers, blocks, block_size, kv_heads *
@@ -195,8 +195,10 @@ def paged_gqa(q, k, v, pos, paging, table, k_pool, v_pool, index, label,
     on a TPU runs the paged kernel over the work list ``work``; a prompt's
     later chunk, and every step where no TPU is, takes :func:`cached_gqa`
     (``key_tile`` is its). ``label`` prefixes what ``record_dispatch``
-    counts (the caller's: ``stats()`` reads it).
-    -> ``(y [B, T, H, dv], k_pool, v_pool)``."""
+    counts (the caller's: ``stats()`` reads it). ``value_group``: a head
+    keeps the values of that many adjacent KV heads side by side
+    (:func:`value_groups`).
+    -> ``(y [B, T, H, value_group * dv], k_pool, v_pool)``."""
     from deepspeed_tpu.ops.attention import record_dispatch, use_decode_kernel
     from deepspeed_tpu.ops.hybrid_decode_attention import (
         decode_attention_hybrid)
@@ -208,13 +210,13 @@ def paged_gqa(q, k, v, pos, paging, table, k_pool, v_pool, index, label,
     v_pool = v_pool.at[index, blk, off].set(v.reshape(b, t, -1))
     if paging.get("prefill"):
         record_dispatch(f"{label}_prefill_xla")
-        y = causal_gqa(q, k, v, 0, sink)
+        y = causal_gqa(q, k, value_groups(v, value_group), 0, sink)
     elif t == 1 and use_decode_kernel():
         record_dispatch(f"{label}_decode_kernel")
         with jax.named_scope("attn._hybrid_kv_attend"):
             y = decode_attention_hybrid(
                 q, k_pool, v_pool, table, paging["lengths"], index,
-                kv_heads=kv, sink=sink, work=work)
+                kv_heads=kv, sink=sink, work=work, value_group=value_group)
     else:
         # (the masked XLA path over the sequence's blocks, and its form
         # in tiles of keys, are at the file's end, below the call sites
@@ -223,7 +225,7 @@ def paged_gqa(q, k, v, pos, paging, table, k_pool, v_pool, index, label,
             q, pos, paging, table,
             (k_pool, v_pool, index),
             (kv, k.shape[-1], v.shape[-1]),
-            label, sink, key_tile)
+            label, sink, key_tile, value_group)
     return y, k_pool, v_pool
 
 
@@ -319,10 +321,16 @@ class ServedConfig:
     - the family's own, each optional: ``paged_slot_state_for(block_size)``
       -> None or ``{"entries", "knob", "what"}``, state of fixed size a
       decode slot keeps beside its block table (``entries`` of the table a
-      slot hands its programs address it, after the sequence's blocks);
+      slot hands its programs address it, after the sequence's blocks). A
+      slot that keeps TWO kinds of state (a ring of blocks of one pool and
+      a row of another) also says ``"parts"``: how many of the ``entries``
+      each kind takes, in the table's order; each part counts its own pool
+      from 1 (slot ``s``: ``1 + s * part ..``), under the ONE knob;
       ``paged_row_kind()`` -> ``{"kind", "what"}`` where a pool row is no
-      keys and values by heads; ``kv_bytes_per_token()`` and
-      ``kv_live_bytes(live)`` -> bytes by kind of row.
+      keys and values by heads; ``kv_bytes_per_token()``, bytes a token
+      KEEPS by kind of row, and ``kv_live_bytes(live)``, bytes a decode
+      step READS by kind: a pool counts once for EACH LAYER THAT READS IT
+      (a cache several layers share is kept once and read by each).
 
     The family's dataclass declares ``vocab_size``, ``hidden_size``,
     ``num_hidden_layers``, ``num_attention_heads``, ``intermediate_size``,
@@ -409,7 +417,16 @@ class PagedDecoder(nn.Module):
     """The module's half of the contract, the decoder shell: embedding (x
     ``embedding_multiplier``) -> per layer the family's mixer and a dense or
     sparse FFN, pre-norm, each term x ``residual_multiplier`` onto a float32
-    stream -> final RMSNorm -> tied or untied head (/ ``logits_scaling``).
+    stream -> final norm (``norm_class``: RMSNorm) -> tied or untied head (/
+    ``logits_scaling``).
+
+    A mixer hands a LATER layer something of this call through ``pools``
+    (a key that is no pool: the shell writes back only the pools it
+    declared): the keys a layer's queries chose (``selected``), a layer's
+    scan output (``memory``). Where :meth:`rows_from` says so, a paged call
+    of ``T > 1`` carries ONE row a sequence from that layer on (the row at
+    ``num_valid - 1``, ``pools["row_at"]``): the later layers and the head
+    see ``[B, 1, d]``, and the logits are ``[B, 1, vocab]``.
 
     Plain call: ``[B, T, vocab]`` float32 logits. Paged (serving) call,
     ``paging = {"block_tables", "lengths", "num_valid", "prefill"}`` with
@@ -427,10 +444,14 @@ class PagedDecoder(nn.Module):
     serve_counters = dropless.COUNTERS
     # for_paged_decode takes ``return_routed``
     serve_routed = True
-    # the two norms of a layer (``layers_<i>_<name>``) and their epsilon's
-    # field in the config
+    # the two norms of a layer (``layers_<i>_<name>``), their epsilon's
+    # field in the config and their class (``(eps, dtype, name=)``)
     norms = ("input_layernorm", "post_attention_layernorm")
     eps_field = "rms_norm_eps"
+    norm_class = RMSNorm
+    # a plain call's ``pools`` is an empty dict, not None: its mixers hand
+    # a later layer something of the call there too
+    carries = False
     # the head is the embedding
     tied = False
     # the dense FFN's parameter type (None: the config's ``param_dtype``)
@@ -467,6 +488,14 @@ class PagedDecoder(nn.Module):
     def lookup(self, table, ids, paging):
         return table[ids]
 
+    def rows_from(self, i: int) -> bool:
+        """Whether layer ``i`` is the first that a paged call of ``T > 1``
+        runs for ONE row a sequence (the row at ``num_valid - 1``): a model
+        whose later layers only read what the earlier ones cached needs
+        them, and the head, for a prompt's last position alone. No layer:
+        every position runs through every layer."""
+        return False
+
     @nn.compact
     def __call__(self, input_ids, deterministic=True, paging=None):
         cfg = self.config
@@ -478,6 +507,8 @@ class PagedDecoder(nn.Module):
         x = self.lookup(embed, input_ids, paging).astype(cfg.dtype)
         t = input_ids.shape[1]
         pools = valid = work = None
+        if self.carries:
+            pools = {}
         if paged:
             variables = {
                 name: self.variable("cache", name, jnp.zeros, shape,
@@ -496,10 +527,12 @@ class PagedDecoder(nn.Module):
         # round the stream once a layer, and a norm's rounding moves the
         # router's near ties); what a matmul reads is cfg.dtype
         x = _scaled(x.astype(jnp.float32), cfg.embedding_multiplier)
-        norm = lambda name: RMSNorm(getattr(cfg, self.eps_field),
-                                    jnp.float32, name=name)
+        norm = lambda name: self.norm_class(getattr(cfg, self.eps_field),
+                                            jnp.float32, name=name)
         for i in range(cfg.num_hidden_layers):
             scope = f"layers_{i}"
+            if paged and t > 1 and self.rows_from(i):
+                x, valid, pools = _last_rows(x, valid, pools, paging)
             a, pools = self.mixer(
                 i, norm(f"{scope}_{self.norms[0]}")(x).astype(cfg.dtype),
                 paging, pools, work)
@@ -568,12 +601,27 @@ def causal_conv(z, taps, state, num_valid):
     return c, jnp.take_along_axis(line, at[..., None], axis=1)
 
 
+def value_groups(v, value_group: int):
+    """``v [B, S, KV, dv]`` as each KV head's queries keep it: its own
+    values (``value_group`` 1: ``v`` itself), or those of the
+    ``value_group`` ADJACENT KV heads its head belongs to, side by side:
+    ``[B, S, KV, value_group * dv]``, KV head ``h`` holding heads
+    ``value_group * (h // value_group) ..`` (differential attention: either
+    key of a pair weighs ``[v1 | v2]``)."""
+    if value_group == 1:
+        return v
+    b, s, kv, dv = v.shape
+    wide = v.reshape(b, s, kv // value_group, value_group * dv)
+    return jnp.repeat(wide, value_group, axis=2)
+
+
 def cached_gqa(q, pos, paging, table, pools, widths, label, sink=None,
-               key_tile: int = 0):
+               key_tile: int = 0, value_group: int = 1):
     """Queries ``q [B, T, H, dk]`` at positions ``pos [B, T]`` over the
     keys and values their sequences keep in layer ``index`` of ``pools =
     (k_pool, v_pool, index)`` through ``table``; ``widths = (kv_heads, dk,
-    dv)``. -> ``[B, T, H, dv]``.
+    dv)``; ``value_group``: :func:`value_groups`. -> ``[B, T, H,
+    value_group * dv]``.
 
     The plain form gathers EVERY block the table has room for and masks
     (``<label>_cached_xla``): a 512-token chunk of an 8,192-token table
@@ -598,7 +646,8 @@ def cached_gqa(q, pos, paging, table, pools, widths, label, sink=None,
             jnp.arange(rows, dtype=jnp.int32)[None], (b, rows))
         return masked_gqa(
             q, k_pool[index, table].reshape(b, rows, kv, dk),
-            v_pool[index, table].reshape(b, rows, kv, dv),
+            value_groups(v_pool[index, table].reshape(b, rows, kv, dv),
+                         value_group),
             pos, key_pos, None, 0, sink)
     record_dispatch(f"{label}_cached_tiled_xla")
     group = heads // kv
@@ -611,7 +660,8 @@ def cached_gqa(q, pos, paging, table, pools, widths, label, sink=None,
         m, l, acc = carry
         blocks = jax.lax.dynamic_slice_in_dim(table, j * per, per, axis=1)
         keys = k_pool[index, blocks].reshape(b, key_tile, kv, dk)
-        values = v_pool[index, blocks].reshape(b, key_tile, kv, dv)
+        values = value_groups(
+            v_pool[index, blocks].reshape(b, key_tile, kv, dv), value_group)
         s = jnp.einsum("btkgd,bskd->bkgts", qg, keys,
                        preferred_element_type=jnp.float32) * dk ** -0.5
         seen = ((j * key_tile + offsets)[None, None, :]
@@ -630,10 +680,10 @@ def cached_gqa(q, pos, paging, table, pools, widths, label, sink=None,
         0, tiles, one_tile,
         (jnp.full((*lead, 1), _NEG, jnp.float32),
          jnp.zeros((*lead, 1), jnp.float32),
-         jnp.zeros((*lead, dv), jnp.float32)))
+         jnp.zeros((*lead, value_group * dv), jnp.float32)))
     out = acc / jnp.where(l == 0.0, 1.0, l)
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, t, heads, dv).astype(
-        q.dtype)
+    return out.transpose(0, 3, 1, 2, 4).reshape(
+        b, t, heads, value_group * dv).astype(q.dtype)
 
 
 def ring_blocks_for(window: int, block_size: int) -> int:
@@ -667,7 +717,7 @@ def ring_kv_live_bytes(live, ring_rows: int, per_token: dict) -> dict:
 
 
 def ring_gqa(q, k, v, pos, paging, table, k_pool, v_pool, index, label,
-             sink=None, work=None, window: int = 0):
+             sink=None, work=None, window: int = 0, value_group: int = 1):
     """:func:`paged_gqa` for a layer that sees the last ``window`` keys
     (the query's own among them), in the slot's ring ``table [B, ring]``:
     the table's last entries, whose rows a position takes by arithmetic
@@ -676,7 +726,8 @@ def ring_gqa(q, k, v, pos, paging, table, k_pool, v_pool, index, label,
     TPU runs the paged kernel; a prompt's later chunk, and every step where
     no TPU is, gathers the slot's ring and the step's own rows and takes
     the masked XLA path. What a head is before it comes here (normed,
-    rotated or neither) is the caller's, as ``label`` is."""
+    rotated or neither) is the caller's, as ``label`` is; ``value_group``:
+    :func:`value_groups`."""
     from deepspeed_tpu.ops.attention import (record_dispatch,
                                              use_decode_kernel)
     from deepspeed_tpu.ops.hybrid_decode_attention import (
@@ -704,14 +755,15 @@ def ring_gqa(q, k, v, pos, paging, table, k_pool, v_pool, index, label,
     if paging.get("prefill"):
         record_dispatch(f"{label}_prefill_xla")
         k_pool, v_pool = write()
-        y = causal_gqa(q, k, v, window, sink)
+        y = causal_gqa(q, k, value_groups(v, value_group), window, sink)
     elif t == 1 and use_decode_kernel():
         record_dispatch(f"{label}_decode_kernel")
         k_pool, v_pool = write()
         with jax.named_scope("attn._hybrid_kv_attend"):
             y = decode_attention_hybrid(
                 q, k_pool, v_pool, table, lengths, index, kv_heads=kv,
-                window=window, ring=True, sink=sink, work=work)
+                window=window, ring=True, sink=sink, work=work,
+                value_group=value_group)
     else:
         record_dispatch(f"{label}_cached_xla")
         # the ring as it stood BEFORE this step's rows, then the rows
@@ -720,9 +772,47 @@ def ring_gqa(q, k, v, pos, paging, table, k_pool, v_pool, index, label,
         held = ring_positions(lengths, ring * bs)
         y = masked_gqa(
             q, jnp.concatenate([gathered(k_pool, k.shape[-1]), k], 1),
-            jnp.concatenate([gathered(v_pool, v.shape[-1]), v], 1),
+            value_groups(jnp.concatenate(
+                [gathered(v_pool, v.shape[-1]), v], 1), value_group),
             pos, jnp.concatenate([held, pos], 1),
             jnp.concatenate([held >= 0, jnp.arange(t)[None]
                              < num_valid[:, None]], 1), window, sink)
         k_pool, v_pool = write()
     return y, k_pool, v_pool
+
+
+class LayerNorm(nn.Module):
+    """Layer norm with a learned weight and bias, float32 statistics:
+    :class:`RMSNorm`'s arguments, for a shell whose ``norm_class`` it is."""
+
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        width = x.shape[-1]
+        scale = self.param("scale", nn.initializers.ones, (width,),
+                           jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros, (width,),
+                          jnp.float32)
+        x32 = x.astype(jnp.float32)
+        x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+        x32 = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1,
+                                           keepdims=True) + self.eps)
+        return (x32 * scale + bias).astype(self.dtype)
+
+
+def last_rows(x, at):
+    """``x [B, T, ..]`` at each row's position ``at [B]``: ``[B, 1, ..]``."""
+    index = at.reshape(-1, *(1,) * (x.ndim - 1))
+    return jnp.take_along_axis(x, index, axis=1)
+
+
+def _last_rows(x, valid, pools, paging):
+    """The shell's cut (:meth:`PagedDecoder.rows_from`): the stream and the
+    validity of each sequence's last real row, and in ``pools`` where that
+    row lies (``row_at [B]``; an idle row's is 0) and which tokens the
+    layers before ran (``self_valid [B, T]``)."""
+    at = jnp.maximum(paging["num_valid"] - 1, 0)
+    return (last_rows(x, at), last_rows(valid, at),
+            {**pools, "row_at": at, "self_valid": valid})

@@ -20,8 +20,12 @@ What differs:
   rest, so the scores of all heads are ONE matmul against the tile's rows
   as they lie (``[H, lanes] x [keys, lanes]``), with no slicing of a pool
   row into heads; the values' matmul gives ``[H, kv_heads * dv]`` and a
-  head keeps its own KV head's lanes of it at the row's end. The zeros
-  cost matrix-unit passes the kernel has to spare and add exact zeros.
+  head keeps its VALUE GROUP's lanes of it at the row's end: its own KV
+  head's, or (``value_group`` > 1: differential attention, whose query
+  pair weighs ``[v1 | v2]`` under either key) those of that many adjacent
+  KV heads side by side. A head's key lanes and its value lanes are named
+  apart; the matmuls are the same. The zeros cost matrix-unit passes the
+  kernel has to spare and add exact zeros.
 - TWO WIDTHS. A key row is ``kv_heads * dk`` lanes, a value row
   ``kv_heads * dv``: two pools, one table.
 - THE KERNEL COPIES ITS OWN TILES. The pools stay in HBM; a step waits for
@@ -153,7 +157,7 @@ def hybrid_work_list(lengths, block_tables, plan: HybridPlan):
 
 def _kernel(row_ref, first_ref, tables_ref, lens_ref, at_ref, q_ref, k_hbm,
             v_hbm, *rest, scale, bs, kv_heads, group, dk, dv, window, ring,
-            has_sink, tile, batch, mb):
+            has_sink, tile, batch, mb, value_group):
     if has_sink:
         sink_ref, rest = rest[0], rest[1:]
     # (the zeros the output starts as are never read here)
@@ -212,8 +216,9 @@ def _kernel(row_ref, first_ref, tables_ref, lens_ref, at_ref, q_ref, k_hbm,
     def _idle():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    def own_lanes(shape, width):
-        """Head ``h`` (a row) against the lanes of its KV head."""
+    def own_lanes(shape, width, group=group):
+        """Head ``h`` (a row) against the lanes of its KV head (of its
+        value group: ``group`` query heads over ``width`` lanes)."""
         row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
         lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
         return row // group == lane // width
@@ -279,14 +284,20 @@ def _kernel(row_ref, first_ref, tables_ref, lens_ref, at_ref, q_ref, k_hbm,
     def _finish():
         l = l_scr[:, 0:1]
         out = acc_scr[...] / jnp.where(l == 0.0, 1.0, l)
-        out = jnp.where(own_lanes(out.shape, dv), out, 0.0)
-        out = sum(out[:, h * dv:(h + 1) * dv] for h in range(kv_heads))
+        # a head keeps its VALUE GROUP's lanes: its own KV head's, or
+        # those of ``value_group`` adjacent KV heads side by side
+        wide = value_group * dv
+        out = jnp.where(own_lanes(out.shape, wide, value_group * group), out,
+                        0.0)
+        out = sum(out[:, h * wide:(h + 1) * wide]
+                  for h in range(kv_heads // value_group))
         o_ref[...] = out.reshape(o_ref.shape).astype(o_ref.dtype)
 
 
 def decode_attention_hybrid(q, k_pool, v_pool, block_tables, lengths, layer,
                             *, kv_heads: int, window: int = 0,
-                            ring: bool = False, sink=None, work=None):
+                            ring: bool = False, sink=None, work=None,
+                            value_group: int = 1):
     """One decode step of one layer against its paged keys and values.
 
     Args:
@@ -305,6 +316,11 @@ def decode_attention_hybrid(q, k_pool, v_pool, block_tables, lengths, layer,
       work: :func:`hybrid_work_list` in :func:`hybrid_plan`'s tiles for
         these pools and ``MB``; made here if None, idle slots read from
         ``block_tables`` (a ring's caller hands the list in).
+      value_group: a query head scores against its own KV head's key lanes
+        (head ``h``: KV head ``h // G``) and keeps the value lanes of
+        ``value_group`` ADJACENT KV heads side by side (those of KV heads
+        ``value_group * (h // (value_group * G)) ..``): 1, a head's own; 2,
+        differential attention's pair ``[v1 | v2]`` under either key.
 
     THE GRID is one traced axis over the live TILES of all rows, row after
     row (``row_of`` and ``first``, the work list, are scalar-prefetch
@@ -320,13 +336,14 @@ def decode_attention_hybrid(q, k_pool, v_pool, block_tables, lengths, layer,
     zeros or an older tile's live rows, so nothing a dead block holds
     (NaN included) reaches a matmul.
 
-    Returns ``[B, 1, H, dv]`` in the query's dtype.
+    Returns ``[B, 1, H, value_group * dv]`` in the query's dtype.
     """
     b, tq, heads, dk = q.shape
     if tq != 1:
         raise ValueError(f"one query row a sequence, got {tq}")
-    if heads % kv_heads:
-        raise ValueError(f"{heads} query heads over {kv_heads} KV heads")
+    if heads % kv_heads or kv_heads % value_group:
+        raise ValueError(f"{heads} query heads over {kv_heads} KV heads in "
+                         f"value groups of {value_group}")
     _, _, bs, klanes = k_pool.shape
     if klanes != kv_heads * dk or v_pool.shape[:3] != k_pool.shape[:3]:
         raise ValueError(
@@ -351,13 +368,15 @@ def decode_attention_hybrid(q, k_pool, v_pool, block_tables, lengths, layer,
     return _attend(row_of, first, tables, lens,
                    jnp.asarray(layer, jnp.int32).reshape(1), q, k_pool,
                    v_pool, sink, kv_heads=kv_heads, window=int(window),
-                   ring=bool(ring), tile=plan.tile_blocks)
+                   ring=bool(ring), tile=plan.tile_blocks,
+                   value_group=int(value_group))
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("kv_heads", "window", "ring", "tile"))
+                   static_argnames=("kv_heads", "window", "ring", "tile",
+                                    "value_group"))
 def _attend(row_of, first, tables, lens, at, q, k_pool, v_pool, sink, *,
-            kv_heads, window, ring, tile):
+            kv_heads, window, ring, tile, value_group=1):
     """The kernel call behind :func:`decode_attention_hybrid`, a jitted
     function of its own with the layer index an argument: the layers of a
     kind in one program are ONE trace and ONE lowering of the kernel (a
@@ -383,7 +402,8 @@ def _attend(row_of, first, tables, lens, at, q, k_pool, v_pool, sink, *,
             (heads, 128), lambda s, row_of, first, tab, ln, at: (0, 0)))
         operands.append(jnp.broadcast_to(
             jnp.asarray(sink, jnp.float32).reshape(heads, 1), (heads, 128)))
-    out_shape = jax.ShapeDtypeStruct((b, 1, heads, dv), q.dtype)
+    out_shape = jax.ShapeDtypeStruct((b, 1, heads, value_group * dv),
+                                     q.dtype)
     in_specs.append(in_hbm)
     operands.append(jnp.zeros(out_shape.shape, out_shape.dtype))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -391,7 +411,7 @@ def _attend(row_of, first, tables, lens, at, q, k_pool, v_pool, sink, *,
         # a batch of idle slots only has no step: one, on no row, runs
         grid=(jnp.maximum(first[b], 1),),
         in_specs=in_specs,
-        out_specs=row_spec(dv),
+        out_specs=row_spec(value_group * dv),
         scratch_shapes=[
             pltpu.VMEM((2, tile * bs, klanes), k_pool.dtype),  # key tiles
             pltpu.VMEM((2, tile * bs, vlanes), v_pool.dtype),  # value tiles
@@ -406,7 +426,7 @@ def _attend(row_of, first, tables, lens, at, q, k_pool, v_pool, sink, *,
         _kernel, scale=dk ** -0.5, bs=bs, kv_heads=kv_heads,
         group=heads // kv_heads, dk=dk, dv=dv, window=window,
         ring=mb if ring else 0, has_sink=sink is not None, tile=tile,
-        batch=b, mb=mb)
+        batch=b, mb=mb, value_group=value_group)
     # no ``name=``, and the callers' scope again here, inside the jitted
     # function: the device trace prints the kernel under the innermost
     # scope (``attn._hybrid_kv_attend.N``), which the benchmark's reader

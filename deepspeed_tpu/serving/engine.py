@@ -465,13 +465,17 @@ class ServingEngine(process_ledger.FirstCalls):
         for a model that keeps state a slot, the slot's own entries of
         that state's pool: slot ``s`` has ``1 + s * entries ..`` (a ring's
         blocks of the window pool; the one row of the convolutions' state
-        pool), 0 being the pool's garbage block or row, which an idle
-        row's zeroed table names."""
+        pool; for a model that keeps both, the ring's blocks and then the
+        state's row, each counted in its own pool), 0 being the pool's
+        garbage block or row, which an idle row's zeroed table names."""
         if not self.slot_entries:
             return table
-        own = 1 + slot * self.slot_entries + np.arange(
-            self.slot_entries, dtype=np.int32)
-        return np.concatenate([table.astype(np.int32), own])
+        # (a slot that keeps two kinds of state says its ``parts``: each
+        # counts its own pool from 1)
+        own = [1 + slot * part + np.arange(part, dtype=np.int32)
+               for part in self.slot_state.get("parts",
+                                               (self.slot_entries,))]
+        return np.concatenate([table.astype(np.int32), *own])
 
     def _with_counters(self, tok, out):
         """The sampled tokens and, behind them in the same array, what the
@@ -563,6 +567,17 @@ class ServingEngine(process_ledger.FirstCalls):
                 self.engine.params.get(self._lookup_table), ids.size)
         return paging
 
+    def _last_row(self, logits, ids, num_valid):
+        """The logits a prompt's next token is sampled from: the row at
+        each sequence's LAST REAL position (right padding: index
+        ``num_valid - 1``) of ``[B, T, vocab]``; a model whose paged call
+        hands back that row alone (``PagedDecoder.rows_from``: ``[B, 1,
+        vocab]`` for ``T > 1`` tokens) has taken it already."""
+        if logits.shape[1] != ids.shape[1]:
+            return logits[:, 0]
+        return self._jnp.take_along_axis(
+            logits, (num_valid - 1)[:, None, None], axis=1)[:, 0]
+
     def _sample(self, logits, rng):
         from deepspeed_tpu.inference.engine import sample_logits
 
@@ -587,8 +602,7 @@ class ServingEngine(process_ledger.FirstCalls):
                     {"params": params, "cache": cache}, ids,
                     mutable=["cache"], paging=paging)
                 logits = logits_of(out)
-                last = jnp.take_along_axis(
-                    logits, (num_valid - 1)[:, None, None], axis=1)[:, 0]
+                last = self._last_row(logits, ids, num_valid)
                 # the first generated token's absolute position is the
                 # prompt length — num_valid itself
                 tok = keyed_sample(last, seeds, num_valid, flags, temps,
@@ -608,8 +622,7 @@ class ServingEngine(process_ledger.FirstCalls):
             logits = logits_of(out)
             # the request's next token depends on its LAST REAL position
             # (right padding: index num_valid-1)
-            last = jnp.take_along_axis(
-                logits, (num_valid - 1)[:, None, None], axis=1)[:, 0]
+            last = self._last_row(logits, ids, num_valid)
             return self._returns(self._sample(last, rng), out,
                                  vars_["cache"])
 
@@ -835,7 +848,11 @@ class ServingEngine(process_ledger.FirstCalls):
         already pooled (shared prefix blocks included) plus themselves,
         causally. The sampled token at the last REAL position is
         meaningful only on the final chunk — it is the request's first
-        generated token."""
+        generated token. Which rows reach the head is the model's: all
+        ``T`` (``[B, T, vocab]`` logits, of which ``_last_row`` keeps the
+        one at ``num_valid - 1``), or, from a model whose later layers only
+        read what the earlier ones cached (``PagedDecoder.rows_from``),
+        that row alone."""
         jnp = self._jnp
         dmodule, dequant = self._dmodule, self.engine._dequantize
         logits_of = self.engine._logits_of
@@ -850,8 +867,7 @@ class ServingEngine(process_ledger.FirstCalls):
                     {"params": params, "cache": cache}, ids,
                     mutable=["cache"], paging=paging)
                 logits = logits_of(out)
-                last = jnp.take_along_axis(
-                    logits, (num_valid - 1)[:, None, None], axis=1)[:, 0]
+                last = self._last_row(logits, ids, num_valid)
                 # only the FINAL chunk's token is consumed, at absolute
                 # position lengths + num_valid = the full prompt length
                 # — identical to the whole-prompt prefill's fold-in, so
@@ -869,8 +885,7 @@ class ServingEngine(process_ledger.FirstCalls):
             out, vars_ = dmodule.apply({"params": params, "cache": cache},
                                        ids, mutable=["cache"], paging=paging)
             logits = logits_of(out)
-            last = jnp.take_along_axis(
-                logits, (num_valid - 1)[:, None, None], axis=1)[:, 0]
+            last = self._last_row(logits, ids, num_valid)
             return self._returns(self._sample(last, rng), out,
                                  vars_["cache"])
 

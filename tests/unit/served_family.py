@@ -228,7 +228,10 @@ class Family:
         then ``steps`` greedy decode steps in the decode program's batch
         shape, the other slots idle; ``spoil(cache) -> cache`` runs between
         the two. -> (logits [positions, vocab], ids), and with ``aux_of`` the
-        rows it kept a position."""
+        rows it kept a position. A model whose paged call hands back ONE row
+        a sequence (``PagedDecoder.rows_from``) gives a chunk's last
+        position alone: ``self.positions`` says which positions the rows
+        are."""
         params = srv.engine.params
         if one_device:
             # the Pallas interpreter's callbacks do not go through the SPMD
@@ -241,6 +244,7 @@ class Family:
             rid, len(prompt) + steps))
         i32 = lambda x: jnp.asarray(x, jnp.int32)
         rows, kept, n = [], [], len(prompt)
+        self.positions = where = []
         try:
             for at in range(0, n, chunk or n):
                 m = min(chunk or n, n - at)
@@ -250,7 +254,12 @@ class Family:
                 lg, aux, srv.cache = (cached if chunk else whole)(
                     params, srv.cache, i32(ids), i32(table[None]), i32([at]),
                     i32([m]))
-                rows.append(np.asarray(lg[0, :m]))
+                if lg.shape[1] == ids.shape[1]:
+                    rows.append(np.asarray(lg[0, :m]))
+                    where.extend(range(at, at + m))
+                else:                   # the call's last real row alone
+                    rows.append(np.asarray(lg[0]))
+                    where.append(at + m - 1)
                 if aux is not None:
                     kept.append(np.asarray(aux[0, :m]))
             if spoil is not None:
@@ -268,6 +277,7 @@ class Family:
                     params, srv.cache, i32(last), i32(tables), i32(lengths),
                     jnp.ones(slots, jnp.int32))
                 rows.append(np.asarray(lg[slot]))
+                where.append(len(tokens) - 1)
                 if aux is not None:
                     kept.append(np.asarray(aux[slot]))
         finally:
@@ -281,7 +291,7 @@ class Family:
         over the same tokens: the largest difference on logits."""
         got, tokens = self.paged_logits(srv, prompt, steps, **kw)[:2]
         want = self.reference_logits(cfg, params, [tokens])[0]
-        return np.abs(got - want[:len(got)]).max()
+        return np.abs(got - want[self.positions]).max()
 
     def decode_through_the_kernels(self, monkeypatch, cfg, params, prompt,
                                    steps, chunk=0, experts=True):

@@ -1470,3 +1470,90 @@ def test_the_selection_kernel_at_the_published_widths(one_chip, monkeypatch):
         _s(one_chip, (), jnp.int32))
     calls = _custom_calls(text)
     assert len(calls) == 1 and "dsa_index_select" in calls[0], calls
+
+
+# ---------------------------------------------------------------------------
+# Phi-4-mini-flash at its published widths: nine Mamba-1 layers of 5,120
+# channels x 16 states (a float32 row of 40 lane groups a slot), eight
+# window layers and ONE shared pool of 20 KV heads of 64, a query head
+# keeping the values of TWO adjacent KV heads; the benchmark cell's 96 slots
+# and 512-token chunks
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_mamba1_kernels_compile_in_place_and_named(one_chip, program):
+    """The decode step's state update on the pool (``[9, 97, 40, 16, 128]``
+    float32) aliased to the kernel's output (no second 286 MB), ONE custom
+    call under the name the parked ``mamba1_decode_roofline_share`` reads;
+    and the chunk scan of a 512-token call, one kernel under the name
+    ``mamba1_scan_roofline_share`` reads, with no array of ``T x channels x
+    states``."""
+    from deepspeed_tpu.ops import mamba1_scan as ms
+
+    f32, i32 = jnp.float32, jnp.int32
+    slots, c, n, groups = 96, 5120, 16, 40
+    assert ms.kernel_serves(c, n) and ms.pool_row_shape(c, n) == (groups, n,
+                                                                  128)
+    if program == "decode":
+        pool = (9, 1 + slots, groups, n, 128)
+        compiled = jax.jit(
+            lambda pool, rows, d, x, fresh, a, b, cc: ms.mamba1_state_update(
+                pool, 3, rows, d, x, fresh, a, b, cc, use_kernel=True),
+            donate_argnums=0).lower(
+                _s(one_chip, pool, f32), _s(one_chip, (slots,), i32),
+                _s(one_chip, (slots, c), f32), _s(one_chip, (slots, c), f32),
+                _s(one_chip, (slots,), jnp.bool_),
+                _s(one_chip, (groups, n, 128), f32),
+                _s(one_chip, (slots, n), f32),
+                _s(one_chip, (slots, n), f32)).compile()
+        held = int(np.prod(pool)) * 4
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes >= held
+        assert memory.temp_size_in_bytes < held // 10
+        metric = "mamba1_decode_roofline_share"
+    else:
+        compiled = jax.jit(
+            lambda x, d, a, b, cc, state: ms.mamba1_chunk_scan(
+                x, d, a, b, cc, state, use_kernel=True)).lower(
+                _s(one_chip, (1, 512, c), f32), _s(one_chip, (1, 512, c), f32),
+                _s(one_chip, (groups, n, 128), f32),
+                _s(one_chip, (1, 512, n), f32), _s(one_chip, (1, 512, n), f32),
+                _s(one_chip, (1, groups, n, 128), f32)).compile()
+        # x, delta, y and the broadcast columns: nothing of 512 x 5120 x 16
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+        metric = "mamba1_scan_roofline_share"
+    calls = _custom_calls(compiled.as_text())
+    assert len(calls) == 1 and _reader_pattern(metric).search(calls[0]), calls
+
+
+@pytest.mark.parametrize("kind", ["global", "window"])
+def test_hybrid_decode_kernel_with_a_value_group_of_two(one_chip, kind):
+    """Differential attention's form of the hybrid kernel at the cell's
+    shapes (40 query heads over 20 KV heads of 64, rows of 1,280 lanes, a
+    table of 224 blocks and a ring of 17): a head keeps the 128 value lanes
+    of its KV pair, and the call is still the ONE instruction
+    ``hybrid_decode_roofline_share`` matches."""
+    from deepspeed_tpu.ops.hybrid_decode_attention import (
+        decode_attention_hybrid, hybrid_plan)
+
+    slots, bs = 96, 32
+    window = kind == "window"
+    layers, blocks, per_row = ((8, 1 + slots * 17, 17) if window
+                               else (1, 11265, 224))
+    assert hybrid_plan(bs, 1280, 1280, per_row).tile_blocks == 16
+
+    def step(q, k, v, tables, lengths):
+        with jax.named_scope("attn._hybrid_kv_attend"):
+            return decode_attention_hybrid(
+                q, k, v, tables, lengths, layers - 1, kv_heads=20,
+                window=512 if window else 0, ring=window, value_group=2)
+
+    compiled = jax.jit(step).lower(
+        _s(one_chip, (slots, 1, 40, 64)),
+        _s(one_chip, (layers, blocks, bs, 1280)),
+        _s(one_chip, (layers, blocks, bs, 1280)),
+        _s(one_chip, (slots, per_row), jnp.int32),
+        _s(one_chip, (slots,), jnp.int32)).compile()
+    assert compiled.output_shardings is not None
+    calls = _custom_calls(compiled.as_text())
+    pattern = _reader_pattern("hybrid_decode_roofline_share")
+    assert len(calls) == 1 and pattern.search(calls[0]), calls
+    assert "bf16[96,1,40,128]" in calls[0]

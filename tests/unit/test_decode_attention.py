@@ -1408,3 +1408,78 @@ class _null:
 
     def __exit__(self, *a):
         return False
+
+
+# (heads, KV heads, value group, window): differential attention's pairs
+# (two query heads a KV head, a value group of two), a wider group, and the
+# group of one that every other family runs, over a table and over a ring
+VALUE_GROUP_CASES = [(8, 4, 2, 0), (8, 4, 2, 6), (8, 4, 4, 0), (4, 4, 2, 0),
+                     (8, 4, 1, 0), (8, 4, 1, 6)]
+
+
+@pytest.mark.parametrize("case", VALUE_GROUP_CASES,
+                         ids=lambda c: "-".join(map(str, c)))
+def test_hybrid_kernel_keeps_a_heads_value_group(case):
+    """``value_group``: a head scores against its own KV head's key lanes
+    and keeps the value lanes of ``value_group`` ADJACENT KV heads side by
+    side: ``[B, 1, H, value_group * dv]``, against the masked XLA path over
+    ``blocks.value_groups``; rows of unequal length, an idle one, a ring."""
+    from deepspeed_tpu.models.blocks import masked_gqa, value_groups
+    from deepspeed_tpu.ops import hybrid_decode_attention as hda
+
+    heads, kv, group, window = case
+    dk = dv = 16
+    bs, mb = 4, 3 if window else 6
+    lengths = np.asarray([0, 9, 3, 10 if window else 22], np.int32)
+    idle = np.asarray([True, False, False, False])
+    b = len(lengths)
+    rng = np.random.default_rng(3)
+    k_pool = rng.standard_normal((2, 1 + b * mb, bs, kv * dk), np.float32)
+    v_pool = rng.standard_normal((2, 1 + b * mb, bs, kv * dv), np.float32)
+    tables = 1 + np.arange(b * mb, dtype=np.int32).reshape(b, mb)
+    said_by = tables.copy()
+    said_by[idle] = 0
+    if not window:
+        tables = said_by.copy()
+    q = rng.standard_normal((b, 1, heads, dk), np.float32)
+    plan = hda.hybrid_plan(bs, kv * dk, kv * dv, mb)
+    with tpu_interpret_mode():
+        got = np.asarray(jax.block_until_ready(jax.jit(
+            lambda q, k, v, t, said, n: hda.decode_attention_hybrid(
+                q, k, v, t, n, 1, kv_heads=kv, window=window,
+                ring=bool(window), value_group=group,
+                work=hda.hybrid_work_list(n, said, plan)))(
+                    *map(jnp.asarray, (q, k_pool, v_pool, tables, said_by,
+                                       lengths)))))
+    assert got.shape == (b, 1, heads, group * dv)
+    rows = mb * bs
+    keys = k_pool[1][tables].reshape(b, rows, kv, dk)
+    vals = v_pool[1][tables].reshape(b, rows, kv, dv)
+    held = (np.asarray(hda.ring_positions(lengths + 1, rows)) if window
+            else np.broadcast_to(np.arange(rows), (b, rows)))
+    want = np.asarray(masked_gqa(
+        jnp.asarray(q), jnp.asarray(keys),
+        value_groups(jnp.asarray(vals), group),
+        jnp.asarray(lengths)[:, None], jnp.asarray(held),
+        jnp.asarray(held >= 0), window))
+    assert np.abs(got[~idle] - want[~idle]).max() <= 1e-5
+    assert not got[idle].any()
+    # head h keeps the values of KV heads group * (h // (group * G)) ..:
+    # with one-hot scores the lanes say whose values they are
+    per = heads // kv
+    for h in (0, heads - 1):
+        first = group * (h // (group * per))
+        own = np.asarray(value_groups(jnp.asarray(vals), group))[
+            :, :, h // per]
+        assert (own[..., :dv] == vals[:, :, first]).all()
+
+
+def test_hybrid_kernel_refuses_a_value_group_that_does_not_divide():
+    from deepspeed_tpu.ops.hybrid_decode_attention import (
+        decode_attention_hybrid)
+
+    with pytest.raises(ValueError, match="value groups of 3"):
+        decode_attention_hybrid(
+            jnp.zeros((1, 1, 8, 16)), jnp.zeros((1, 4, 4, 64)),
+            jnp.zeros((1, 4, 4, 64)), jnp.zeros((1, 3), jnp.int32),
+            jnp.zeros((1,), jnp.int32), 0, kv_heads=4, value_group=3)
